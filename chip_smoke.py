@@ -1,0 +1,580 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the repo's main path once on the attached TPU, through the entry
+points a user would call (``flexflow_tpu.apps.*.main(argv)``), at the
+full width of models the repo supports, and checks what comes out by
+the repo's own means:
+
+    python3 chip_smoke.py            # one chip: train x3, serve x4
+    python3 chip_smoke.py --chips 4  # ONLY the cross-chip paths and
+                                     # their one-device comparison
+
+One process: it touches jax itself and starts no child that needs the
+chip.  With no TPU it exits non-zero at once and prints no result.  A
+phase that raises, yields a non-finite loss, fails a request, degrades
+the serving engine, loses a Pallas kernel from its compiled program or
+disagrees with its reference makes the run exit 1; the other phases
+still run, so one call shows every fault.  The last stdout line of a
+passing run is ``{"ok": true, "device": {...}}``; earlier lines carry
+per-phase wall times (set-up with compilation against the steady step)
+as information, not as metrics.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import dataclasses
+import json
+import math
+import os
+import sys
+import time
+import traceback
+from typing import Any, Callable, Dict, List, NamedTuple, Sequence, Tuple
+
+import jax
+import numpy as np
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+#: Decode logits tolerance (f32) — the bar tests/test_serving.py holds
+#: the decode kernel to (``DECODE_TOL`` there; the rehearsal test pins
+#: the two equal).
+DECODE_TOL = 1e-4
+#: Loss-trajectory tolerance of tests/test_sharding_equivalence.py's
+#: ``assert_same`` (the dp / tp / spatial / hybrid family).
+SHARD_RTOL, SHARD_ATOL = 2e-4, 1e-5
+#: Sparse (row kernels) against dense (jnp) DLRM loss trajectory.  The
+#: two differ only in how the table update rounds (scatter-add of
+#: -lr*row_grad against a full-table axpy); tests/test_sparse_update.py
+#: holds them to 1e-6 in exact f32 on the CPU, and the chip's default
+#: f32 matmul precision leaves room for a little more.
+DLRM_RTOL = 1e-4
+
+_DLRM_ARCH = [
+    "--arch-sparse-feature-size", "64",
+    "--arch-embedding-size", "1000000-1000000-1000000-1000000",
+    "--arch-mlp-bot", "64-512-512-64",
+    "--arch-mlp-top", "320-1024-1024-1024-1",
+]
+_LM_SHAPE = ["--vocab", "32768", "--d-model", "512", "--heads", "8",
+             "--layers", "4"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """The argv of every phase.  ``FULL`` is what the chip runs; the
+    CPU rehearsal (tests/test_chip_compile.py) passes tiny ones."""
+
+    alexnet: Tuple[str, ...]
+    transformer: Tuple[str, ...]
+    dlrm: Tuple[str, ...]
+    serve: Tuple[str, ...]
+    #: --chips 4: the cross-chip argv of each app; the comparison run
+    #: is the same argv on one device (strategy / mesh flags dropped).
+    alexnet4: Tuple[str, ...]
+    alexnet4_strategy: Tuple[str, ...]
+    transformer4: Tuple[str, ...]
+    transformer4_mesh: Tuple[str, ...]
+    serve4_shard: Tuple[str, ...]
+
+
+FULL = Sizes(
+    # README's canonical run: 229x229x3, 1000 classes.
+    alexnet=("-b", "256", "--dtype", "bfloat16", "-i", "10"),
+    # The app's own defaults (seq 512, vocab 32768, d 512, 8 heads,
+    # 4 layers), named so the log shows them.
+    transformer=("-b", "8", "--seq", "512", *_LM_SHAPE,
+                 "--dtype", "bfloat16", "-i", "10"),
+    # README's DLRM shape (run_random.sh), under plain SGD: the
+    # row-sparse path — the one that reaches the Pallas row kernels —
+    # is exact only without momentum and weight decay, and the
+    # executor keeps the CLI's defaults (0.9, 1e-4) on the dense path.
+    dlrm=("-b", "1024", "-i", "10", "--momentum", "0", "--wd", "0",
+          *_DLRM_ARCH),
+    serve=("--max-seq", "512", "--max-batch", "8", "--requests", "12",
+           *_LM_SHAPE),
+    # float32 and three steps (one warm-up + two), the protocol of the
+    # strategy-equivalence tests whose tolerance is applied: the
+    # trajectories start equal to seven digits and round-off grows ~10x
+    # a step on AlexNet's README flags (1e-3 by step four on the chip).
+    alexnet4=("-b", "256", "-i", "2"),
+    alexnet4_strategy=(
+        "-s", os.path.join(ROOT, "strategies", "alexnet_readme_4dev.json"),
+    ),
+    transformer4=("-b", "8", "--seq", "512", *_LM_SHAPE, "-i", "2"),
+    transformer4_mesh=("--dp", "2", "--tp", "2"),
+    serve4_shard=("--shard", "2,2"),
+)
+
+
+# -- the device ---------------------------------------------------------------
+
+
+def require_tpu() -> Dict[str, object]:
+    """The device as jax reports it, or exit non-zero when it is not a
+    TPU.  No ``JAX_PLATFORMS`` rewriting, no probe child."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: jax found platform {dev.platform!r}, not a "
+              f"TPU; nothing was run", file=sys.stderr)
+        raise SystemExit(2)
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def has_mosaic_call(compiled_text: str) -> bool:
+    """Whether a compiled program's text holds a Mosaic kernel — the
+    proof that a Pallas call neither routed to jnp nor ran under the
+    interpreter (which lowers to plain HLO)."""
+    return "tpu_custom_call" in compiled_text
+
+
+# -- plumbing -----------------------------------------------------------------
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(what)
+
+
+def info(phase: str, **fields) -> None:
+    print(f"[{phase}] " + " ".join(f"{k}={v}" for k, v in fields.items()),
+          flush=True)
+
+
+@contextlib.contextmanager
+def recorded(cls, method: str):
+    """Record ``(instance, args, result)`` of every ``cls.method`` call
+    made inside the block.  The apps' ``main(argv)`` returns an exit
+    code only; the checks need the exact objects it built (the
+    executor behind the trainer, the server and its results)."""
+    calls: List[tuple] = []
+    orig = getattr(cls, method)
+
+    def wrapper(self, *args, **kw):
+        out = orig(self, *args, **kw)
+        calls.append((self, args, out))
+        return out
+
+    setattr(cls, method, wrapper)
+    try:
+        yield calls
+    finally:
+        setattr(cls, method, orig)
+
+
+def _ms(seconds: float) -> str:
+    return f"{seconds * 1e3:.2f}"
+
+
+def build_native() -> None:
+    """Compile and load the three C++ components with the machine's
+    g++ — from a clean checkout nothing is prebuilt, and a
+    ``NativeBuildError`` here is a failure, never a silent Python
+    path."""
+    from flexflow_tpu import native
+    from flexflow_tpu.parallel.strategy import StrategyStore
+
+    for name in ("ffsim", "ffproto", "ffdata"):
+        native._build(name)
+    native.load_ffsim()
+    native.load_ffdata()
+    # One call through each codec the main path can reach.
+    pb = StrategyStore.load_pb(
+        os.path.join(ROOT, "strategies", "dlrm_8chip.pb"), num_devices=8
+    )
+    js = StrategyStore.load(
+        os.path.join(ROOT, "strategies", "dlrm_8chip.json"), num_devices=8
+    )
+    check(pb.table == js.table, "ffproto: .pb and .json strategies differ")
+    src = np.arange(64, dtype=np.float32).reshape(16, 4)
+    idx = np.array([3, 0, 15, 3])
+    check(np.array_equal(native.gather_rows(src, idx), src[idx]),
+          "ffdata: gather_rows != numpy")
+    info("native", built="ffsim,ffproto,ffdata")
+
+
+# -- training -----------------------------------------------------------------
+
+
+def replay(trainer, steps: int, batch=None, start=None):
+    """Re-run ``steps`` train steps of the trainer's executor from its
+    seed's init (or from ``start``) on one fixed batch — the compiled
+    program ``fit`` just ran, so nothing compiles — fencing each step.
+    Returns (losses, per-step wall seconds, final (params, opt_state,
+    state))."""
+    ex = trainer.ex
+    if batch is None:
+        batch = trainer.synthetic_batch()
+    params, opt_state, state = start if start is not None else ex.init()
+    losses, walls = [], []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        params, opt_state, state, m = ex.train_step(
+            params, opt_state, state, batch
+        )
+        losses.append(float(jax.device_get(m["train_loss"])))
+        walls.append(time.perf_counter() - t0)
+    return losses, walls, (params, opt_state, state)
+
+
+def train_phase(phase: str, app, argv: Sequence[str], kernels: bool = False):
+    """Run ``app.main(argv)`` (the user's path: flags, strategy,
+    executor, ``Trainer.fit`` on the fixed synthetic batch), then
+    replay the same compiled step from the same seed to read the loss
+    trajectory: finite, falling, and ending where ``fit`` ended.
+    Falling means below where it started, not monotone: AlexNet's
+    README flags (lr 0.01, momentum 0.9) overshoot on one repeated
+    batch of two label values after four steps — in f32 and bf16, on
+    the CPU exactly as on the chip (PERF.md "Findings").
+    ``kernels``: the compiled step must hold its Pallas calls."""
+    from flexflow_tpu.runtime.trainer import Trainer
+
+    t0 = time.perf_counter()
+    with recorded(Trainer, "fit") as fits:
+        rc = app.main(list(argv))
+    wall = time.perf_counter() - t0
+    check(rc == 0, f"{phase}: main exited {rc}")
+    check(len(fits) == 1, f"{phase}: expected one Trainer.fit, saw {len(fits)}")
+    trainer, _, stats = fits[0]
+    check(math.isfinite(stats["loss"]), f"{phase}: loss {stats['loss']}")
+    # fit() ran 1 warm-up + the timed iterations, all real updates.
+    steps = 1 + stats["iterations"]
+    batch = trainer.synthetic_batch()
+    losses, walls, final = replay(trainer, steps, batch)
+    check(all(math.isfinite(x) for x in losses),
+          f"{phase}: non-finite loss in {losses}")
+    check(min(losses) < losses[0], f"{phase}: loss not falling: {losses}")
+    check(math.isclose(losses[-1], stats["loss"], rel_tol=1e-5, abs_tol=1e-6),
+          f"{phase}: replayed loss {losses[-1]} != fit's {stats['loss']}")
+    if kernels:
+        text = trainer.ex.train_step.lower(*final, batch).compile().as_text()
+        check(has_mosaic_call(text),
+              f"{phase}: no Pallas kernel in the compiled train step "
+              f"(a *_supported gate routed to jnp, or interpret mode)")
+    info(phase,
+         setup_s=f"{wall - stats['elapsed_s']:.1f}",
+         step_ms=_ms(stats["elapsed_s"] / stats["iterations"]),
+         replay_step_ms=_ms(float(np.median(walls))),
+         losses=[round(x, 4) for x in losses])
+    return trainer, losses, final
+
+
+def dlrm_phase(argv: Sequence[str]) -> None:
+    """DLRM through its app, then the row-sparse path (Pallas
+    gather/scatter kernels on one TPU) against the dense jnp path on a
+    batch whose ids span the tables (the app's fixed synthetic batch
+    only ever names rows 0 and 1)."""
+    from flexflow_tpu.apps import dlrm
+    from flexflow_tpu.apps.common import make_optimizer
+    from flexflow_tpu.data.loader import synthetic_host_batch
+    from flexflow_tpu.models.dlrm import DLRMConfig, build_dlrm
+    from flexflow_tpu.runtime.pipeline import make_executor
+    from flexflow_tpu.runtime.trainer import Trainer
+
+    trainer, _, _ = train_phase("train/dlrm", dlrm, argv, kernels=True)
+    ex = trainer.ex
+    check(bool(ex._sparse_ops),
+          "train/dlrm: no op took the row-sparse path (dense fallback)")
+    arch = DLRMConfig.parse_args(list(argv))
+    host = synthetic_host_batch(
+        ex.model, np.random.default_rng(ex.config.seed),
+        int_high={"sparse_input": min(arch.embedding_size)},
+    )
+    dense_cfg = dataclasses.replace(ex.config, sparse_embedding_updates=False)
+    dense_ex = make_executor(
+        build_dlrm(batch_size=dense_cfg.batch_size, dlrm=arch,
+                   config=dense_cfg),
+        ex.strategy, config=dense_cfg, optimizer=make_optimizer(dense_cfg),
+    )
+    check(not dense_ex._sparse_ops, "train/dlrm: dense reference went sparse")
+    sparse_l, _, _ = replay(trainer, 4, ex.shard_batch(host))
+    dense_l, _, _ = replay(Trainer(dense_ex), 4, dense_ex.shard_batch(host))
+    np.testing.assert_allclose(
+        sparse_l, dense_l, rtol=DLRM_RTOL,
+        err_msg="train/dlrm: row-kernel path left the dense jnp path",
+    )
+    info("train/dlrm", sparse_ops=",".join(op.name for op in ex._sparse_ops),
+         sparse_losses=[round(x, 6) for x in sparse_l],
+         dense_losses=[round(x, 6) for x in dense_l])
+
+
+# -- serving ------------------------------------------------------------------
+
+
+class ServeRun(NamedTuple):
+    srv: Any                      # Server or ScheduledServer
+    requests: list
+    tokens: Dict[int, List[int]]  # request id -> generated tokens
+    stats: dict
+
+
+def serve_run(phase: str, argv: Sequence[str]) -> ServeRun:
+    """One ``apps.serve`` run: every request completes, none fails,
+    the engine never degrades."""
+    from flexflow_tpu.apps import serve
+    from flexflow_tpu.runtime.serving import Server
+    from flexflow_tpu.serving import ScheduledServer
+
+    t0 = time.perf_counter()
+    with recorded(Server, "run") as plain, \
+            recorded(ScheduledServer, "run") as sched:
+        rc = serve.main(list(argv))
+    wall = time.perf_counter() - t0
+    check(rc == 0, f"{phase}: main exited {rc}")
+    runs = plain + sched
+    check(len(runs) == 1, f"{phase}: expected one server run, saw {len(runs)}")
+    srv, (requests,), (results, stats) = runs[0]
+    check(stats["requests"] == len(requests)
+          and stats["completed"] == len(requests),
+          f"{phase}: {stats['completed']}/{len(requests)} completed")
+    check(stats["failed"] == 0, f"{phase}: {stats['failed']} failed")
+    check(not stats.get("degraded_rungs")
+          and not getattr(srv, "degraded_rungs", None),
+          f"{phase}: degraded_mode: {stats.get('degraded_rungs')}")
+    tokens = {rid: list(r.tokens) for rid, r in results.items()}
+    check(all(tokens[r.id] for r in requests), f"{phase}: empty generation")
+    n_tok = sum(len(t) for t in tokens.values())
+    info(phase, wall_s=f"{wall:.1f}", requests=len(requests), tokens=n_tok,
+         decode_supersteps=stats["decode_supersteps"],
+         k=stats["decode_steps_per_call"])
+    return ServeRun(srv, requests, tokens, stats)
+
+
+def check_decode_kernel(phase: str, run: ServeRun) -> None:
+    """The decode superstep the run dispatched, as compiled, holds the
+    flash_decode kernel."""
+    sex = run.srv.ex
+    params, state = sex.init(sex.config.seed)
+    zeros = np.zeros((sex.max_batch,), np.int32)
+    # k is what the server dispatched, clamped there already.
+    k = int(run.stats["decode_steps_per_call"])
+    fn = sex.build_decode_superstep(k)  # fflint: disable=FF006
+    text = fn.lower(
+        params, state, sex.init_cache(), zeros, zeros
+    ).compile().as_text()
+    check(has_mosaic_call(text),
+          f"{phase}: no flash_decode kernel in the compiled decode "
+          f"superstep (einsum oracle or interpret mode)")
+
+
+def next_logits(sex, params, state, prefix: Sequence[int]) -> np.ndarray:
+    """Logits of the token after ``prefix``, through the decode path:
+    prefill all but the last token into slot 0, then one decode step
+    (tests/test_serving.py's ``_decode_logits_vs_full`` recipe)."""
+    n = len(prefix) - 1
+    bucket = sex.bucket_for(n)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :n] = prefix[:n]
+    rows, _tok, ok = sex.build_prefill(bucket)(
+        params, state, padded, np.int32(n)
+    )
+    check(bool(ok), "prefill produced non-finite logits")
+    caches = sex.install(sex.init_cache(), rows, 0)
+    pos = np.zeros((sex.max_batch,), np.int32)
+    tok = np.zeros((sex.max_batch,), np.int32)
+    pos[0], tok[0] = n, prefix[n]
+    _, _, _, (_nxt, _okf, logits) = sex.build_decode_superstep(
+        1, return_logits=True
+    )(params, state, caches, pos, tok)
+    return np.asarray(logits, np.float32)[0, 0]
+
+
+def compare_tokens(phase: str, got: ServeRun, want: ServeRun) -> None:
+    """Generated tokens equal the reference run's on the same seed.
+    Where one argmax flips, it must be a near-tie and not an error: at
+    the first divergence the two engines' logits, recomputed at
+    ``highest`` matmul precision (the chip's default f32 matmul rounds
+    operands to bf16, which the CPU tolerance never saw), agree within
+    ``DECODE_TOL``."""
+    flips = []
+    engines = None  # [(executor, params, state)] x2, built at the first flip
+    for r in got.requests:
+        a, b = got.tokens[r.id], want.tokens[r.id]
+        if a == b:
+            continue
+        j = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                 min(len(a), len(b)))
+        prefix = [int(t) for t in r.prompt] + a[:j]
+        if engines is None:
+            engines = [(run.srv.ex, *run.srv.ex.init(run.srv.ex.config.seed))
+                       for run in (got, want)]
+        with jax.default_matmul_precision("highest"):
+            la, lb = (next_logits(*e, prefix) for e in engines)
+        err = float(np.max(np.abs(la - lb)))
+        top = np.sort(la)[-2:]
+        flips.append((r.id, j, err, float(top[1] - top[0])))
+        check(err <= DECODE_TOL,
+              f"{phase}: request {r.id} diverges at token {j} and the "
+              f"logits there differ by {err} > {DECODE_TOL}")
+    info(phase, token_parity="exact" if not flips else
+         "near-tie flips (id, at, |dlogits|, top-2 gap): "
+         + str([(i, j, f"{e:.2e}", f"{g:.2e}") for i, j, e, g in flips]))
+
+
+def serve_phase(argv: Sequence[str]) -> None:
+    """Padded KV, greedy: the plain loop and the scheduled loop decode
+    through the kernel and agree with the einsum oracle; one paged run
+    completes."""
+    plain = serve_run("serve/plain", argv)
+    check_decode_kernel("serve/plain", plain)
+    sched = serve_run("serve/sched", [*argv, "--sched", "slo"])
+    check_decode_kernel("serve/sched", sched)
+    oracle = serve_run("serve/oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/plain", plain, oracle)
+    compare_tokens("serve/sched", sched, oracle)
+    serve_run("serve/paged", [*argv, "--kv-block", "16"])
+
+
+# -- four chips ---------------------------------------------------------------
+
+
+def holders(tree) -> set:
+    """Devices that hold a shard of any array in ``tree``."""
+    return {d for x in jax.tree.leaves(tree) if hasattr(x, "devices")
+            for d in x.devices()}
+
+
+def start_from(ex, ref_params):
+    """(params, opt_state, state) for ``ex`` holding the one-device
+    run's initial values.  The full-mesh executor's own init already
+    does (the RNG is sharding-invariant: one seed draws one set of
+    values on any mesh); a layer-wise executor draws per stage, so
+    there the reference values are split by op name onto the stages —
+    tests/test_pipeline.py's recipe."""
+    from flexflow_tpu.runtime.pipeline import PipelineExecutor
+
+    params, opt_state, state = ex.init()
+    if isinstance(ex, PipelineExecutor):
+        for si, st in enumerate(ex.stages):
+            params[si] = {
+                op.name: jax.device_put(
+                    ref_params[op.name],
+                    {k: ex.stage_ex[si].param_sharding(op, spec)
+                     for k, spec in op.param_specs().items()},
+                )
+                for op in st.ops if op.param_specs()
+            }
+            opt_state[si] = ex.optimizer.init(params[si])
+    return params, opt_state, state
+
+
+def four_chip_train(phase: str, app, argv: Sequence[str],
+                    cross: Sequence[str]) -> None:
+    """``argv + cross`` over the whole mesh against ``argv`` on one
+    device of the same host, same seed and initial values: loss
+    trajectories within the strategy-equivalence tolerance, and every
+    device holds live buffers of the cross-chip run."""
+    one, single, _ = train_phase(f"{phase}/one-device", app,
+                                 [*argv, "-ll:tpu", "1"])
+    mesh, _, final = train_phase(f"{phase}/mesh", app, [*argv, *cross])
+    missing = set(jax.devices()) - holders(final)
+    check(not missing, f"{phase}: no live buffer on {sorted(map(str, missing))}")
+    ref = jax.device_get(one.ex.init()[0])
+    multi, _, _ = replay(mesh, len(single), start=start_from(mesh.ex, ref))
+    np.testing.assert_allclose(
+        multi, single, rtol=SHARD_RTOL, atol=SHARD_ATOL,
+        err_msg=f"{phase}: mesh and one-device loss trajectories differ",
+    )
+    info(phase, mesh_losses=[round(x, 6) for x in multi],
+         one_device_losses=[round(x, 6) for x in single])
+
+
+def four_chip_serve(argv: Sequence[str], shard: Sequence[str]) -> None:
+    """Sharded decode (batch on n, KV heads on c; flash_decode under
+    shard_map) against the one-device engine."""
+    mesh = serve_run("serve/shard", [*argv, *shard])
+    sex = mesh.srv.ex
+    check(sex.shard is not None, "serve/shard: fell back to a single mesh")
+    check_decode_kernel("serve/shard", mesh)
+    missing = set(jax.devices()) - holders(sex.init_cache())
+    check(not missing,
+          f"serve/shard: no KV shard on {sorted(map(str, missing))}")
+    compare_tokens("serve/shard", mesh, serve_run("serve/one-device", argv))
+
+
+# -- driver -------------------------------------------------------------------
+
+Phase = Tuple[str, Callable[[], None]]
+
+
+def one_chip_phases(sz: Sizes) -> List[Phase]:
+    from flexflow_tpu.apps import alexnet, transformer
+
+    return [
+        ("native", build_native),
+        ("train/alexnet",
+         lambda: train_phase("train/alexnet", alexnet, sz.alexnet)),
+        # The path that reaches flash_attention fwd/bwd and softmax_xent.
+        ("train/transformer",
+         lambda: train_phase("train/transformer", transformer,
+                             sz.transformer, kernels=True)),
+        ("train/dlrm", lambda: dlrm_phase(sz.dlrm)),
+        ("serve", lambda: serve_phase(sz.serve)),
+    ]
+
+
+def four_chip_phases(sz: Sizes) -> List[Phase]:
+    from flexflow_tpu.apps import alexnet, transformer
+
+    return [
+        ("native", build_native),
+        ("train/alexnet4",
+         lambda: four_chip_train("train/alexnet4", alexnet, sz.alexnet4,
+                                 sz.alexnet4_strategy)),
+        ("train/transformer4",
+         lambda: four_chip_train("train/transformer4", transformer,
+                                 sz.transformer4, sz.transformer4_mesh)),
+        ("serve4", lambda: four_chip_serve(sz.serve, sz.serve4_shard)),
+    ]
+
+
+def run_phases(phases: Sequence[Phase]) -> List[str]:
+    """Run every phase; returns the names of those that failed."""
+    failed = []
+    for name, fn in phases:
+        t0 = time.perf_counter()
+        try:
+            fn()
+        except Exception:
+            traceback.print_exc()
+            failed.append(name)
+            print(f"[{name}] FAILED after {time.perf_counter() - t0:.1f}s",
+                  flush=True)
+        else:
+            print(f"[{name}] ok in {time.perf_counter() - t0:.1f}s",
+                  flush=True)
+    return failed
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 = only the cross-chip phases and their "
+                         "one-device comparison")
+    args = ap.parse_args(argv)
+    device = require_tpu()
+    check(device["count"] >= args.chips,
+          f"--chips {args.chips} needs {args.chips} devices, jax has "
+          f"{device['count']}")
+
+    from flexflow_tpu.apps.common import enable_compile_cache
+
+    cache = enable_compile_cache()
+    warm = len(os.listdir(cache)) if os.path.isdir(cache) else 0
+    info("device", **device, compile_cache=cache, cache_entries=warm)
+    phases = (four_chip_phases if args.chips == 4 else one_chip_phases)(FULL)
+    failed = run_phases(phases)
+    if failed:
+        print(f"chip_smoke: FAILED phases: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -17,34 +17,6 @@ C++/CUDA/Legion system, reference at /root/reference) designed TPU-first:
   (reference: ``scripts/simulator.cc``).
 """
 
-import jax as _jax
-
-# Sharding-invariant RNG: with the legacy (non-partitionable) threefry,
-# jitting an initializer with sharded out_shardings draws DIFFERENT
-# values than the unsharded trace — Executor.init then breaks the
-# DP≡strategy numerics invariant before the first step runs.  The
-# partitionable implementation is sharding-invariant by construction
-# (and is the default on newer jax); force it on the baked-in version.
-try:
-    _jax.config.update("jax_threefry_partitionable", True)
-except Exception:
-    pass  # flag retired (newer jax: always partitionable)
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.6 ships shard_map under jax.experimental with the
-    # replication check named check_rep (renamed check_vma at
-    # promotion).  The ops call the promoted spelling; bridge it here
-    # so one spelling works on every jax the container bakes in.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=True, **kw):
-        kw.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel, TensorSpec
 from flexflow_tpu.initializers import (

@@ -3,7 +3,8 @@
 ONE audit surface, two layers:
 
 - **AST lint** (:mod:`~flexflow_tpu.analysis.lint`): repo-wide rules
-  FF001–FF007 encoding the CLAUDE.md hazards as checkable code
+  FF001–FF008 (FF002 and FF007 retired) encoding the repo's code
+  invariants as checkable
   properties, with inline ``# fflint: disable=FF0xx`` suppression.
   Imports no jax — runs anywhere, instantly.
 - **Program audit** (:mod:`~flexflow_tpu.analysis.program_audit`):

@@ -141,7 +141,12 @@ import os
 import sys
 import time
 
-from flexflow_tpu.apps.common import check_help, pop_float, pop_int
+from flexflow_tpu.apps.common import (
+    check_help,
+    enable_compile_cache,
+    pop_float,
+    pop_int,
+)
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.models.transformer import build_transformer_lm
 
@@ -313,6 +318,7 @@ def main(argv=None) -> int:
     serve_max_restarts = pop_int(argv, "--serve-max-restarts", -1)
     expire_waiting = _pop_flag(argv, "--expire-waiting")
     cfg = FFConfig.parse_args(argv)
+    enable_compile_cache()
     try:
         lo, hi = (int(v) for v in plen_s.split(":"))
     except ValueError:
@@ -492,7 +498,7 @@ def _run_scheduled(cfg, *, max_seq, max_batch, decode_steps, n_requests,
         ServingCrashLoop,
         ServingExecutor,
     )
-    from flexflow_tpu.runtime.trainer import relay_safe_steps
+    from flexflow_tpu.runtime.trainer import clamp_fused_steps
     from flexflow_tpu.serving import (
         EXIT_FLEET_FAILURE,
         FleetCrashLoop,
@@ -510,7 +516,7 @@ def _run_scheduled(cfg, *, max_seq, max_batch, decode_steps, n_requests,
         uniform_workload,
     )
 
-    decode_steps = relay_safe_steps(decode_steps, what="decode_steps")
+    decode_steps = clamp_fused_steps(decode_steps, what="decode_steps")
     resilience = ServingResilience(
         max_retries=serve_retries,
         retry_backoff_ms=retry_backoff_ms,

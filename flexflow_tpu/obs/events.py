@@ -38,10 +38,9 @@ EVENT_CATALOG = frozenset({
     "rollback",
     "replay",
     "preempt",
-    # watchdog / profiling
+    # watchdog
     "stall",
     "stall_recovered",
-    "profile_skipped",
     # static analysis + execution search
     "analysis",
     "search",

@@ -82,8 +82,8 @@ def box_fingerprint() -> Dict[str, Any]:
             pass
         # Backend identity: platform + device count.  This initializes
         # the backend if nothing has yet — callers (Telemetry, bench)
-        # run on an already-probed/claimed backend, so this never adds
-        # a first-touch of the relay the run itself would not do.
+        # run on a backend they already hold, so this never adds a
+        # first touch of the device the run itself would not make.
         fp["platform"] = jax.default_backend()
         fp["devices"] = jax.device_count()
         # World identity: which process of how many (1/1 single-host).

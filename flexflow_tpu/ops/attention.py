@@ -424,7 +424,7 @@ class MultiHeadAttention(Op):
             use = self.decode_kernel
             if use is None:
                 use = pallas_kernels.flash_decode_supported(
-                    ck.shape, q1.dtype
+                    ck.shape, ck.dtype
                 )
             if use:
                 return pallas_kernels.flash_decode(q1, ck, cv, pos + 1)
@@ -436,7 +436,7 @@ class MultiHeadAttention(Op):
         local = (b // max(n_deg, 1), s, h // max(c_deg, 1), hd)
         supported = (
             b % max(n_deg, 1) == 0 and h % max(c_deg, 1) == 0
-            and pallas_kernels.flash_decode_supported(local, q1.dtype)
+            and pallas_kernels.flash_decode_supported(local, ck.dtype)
         )
         use = self.decode_kernel
         if use is None:

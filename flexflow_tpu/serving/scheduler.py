@@ -24,7 +24,7 @@ scheduler (SERVING.md "Scheduler policy"):
 - **Adaptive k.**  Per superstep, k minimizes modeled system-time per
   useful token: ``decode_ms(k) * (active + waiting) / sum_j min(k,
   remaining_j)`` over a bounded candidate set (compile cache stays
-  small; relay clamp applies).  Deep queues push k down (slots free
+  small; the fused-step bound applies).  Deep queues push k down (slots free
   and admit sooner); drained queues push k up (dispatch amortization,
   the superstep thesis).
 - **Preemption.**  A waiting request whose deadline is infeasible
@@ -84,7 +84,7 @@ from flexflow_tpu.obs import spans as _spans
 _log = logging.getLogger("ff.serving.sched")
 
 #: Decode-k candidates the adaptive policy may choose from (unioned
-#: with the configured k, filtered to the relay-safe clamp): bounded
+#: with the configured k, filtered to the fused-step bound): bounded
 #: so the compiled decode-program cache stays small.
 ADAPTIVE_K_CANDIDATES = (1, 2, 4, 8, 16)
 
@@ -454,18 +454,18 @@ class ScheduledServer:
         draft_params=None,
         _engine=None,
     ):
-        from flexflow_tpu.runtime.trainer import relay_safe_steps
+        from flexflow_tpu.runtime.trainer import clamp_fused_steps
 
         self.ex = executor
         self.policy = policy or SchedulerPolicy()
         self.model = latency_model or ServingLatencyModel.from_calibration()
-        self.decode_steps = relay_safe_steps(
+        self.decode_steps = clamp_fused_steps(
             decode_steps, what="decode_steps", log=_log
         )
         #: Speculative draft depth (0 = plain fused decode).  The
-        #: clamp site stays relay_safe_steps — the draft chain counts
+        #: clamp site stays clamp_fused_steps — the draft chain counts
         #: against it like every other fused chain.
-        self.speculate = relay_safe_steps(
+        self.speculate = clamp_fused_steps(
             speculate, what="speculate", log=_log
         ) if speculate else 0
         self._draft_params = draft_params

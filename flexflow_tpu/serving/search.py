@@ -47,7 +47,7 @@ class ServingConfig:
     """One executor-legal serving configuration.  Construction IS the
     legality check: :class:`SlotShape` re-runs the executor's bucket
     validation, and the k/batch bounds mirror ``ServingExecutor`` +
-    the relay clamp."""
+    the fused-step bound."""
 
     buckets: Tuple[int, ...]
     decode_steps: int

@@ -79,6 +79,7 @@ def test_candle_uno_app(capsys):
     assert "THROUGHPUT =" in capsys.readouterr().out
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_candle_uno_app_resilient_superstep(tmp_path, capsys):
     """--resilient --save-every --steps-per-call wired together: the
     ResilientTrainer loop drives superstep dispatch with periodic
@@ -153,6 +154,7 @@ def test_candle_app_reads_csv_dir(tmp_path, capsys):
     assert "THROUGHPUT =" in capsys.readouterr().out
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_nmt_app_pipeline_placement(capsys):
     """--pipeline: encoder on the first half of devices, decoder on the
     second (``nmt.cc:269-308``), driven through PipelineExecutor."""
@@ -192,6 +194,7 @@ def test_alexnet_app_auto_strategy(capsys):
     assert "tp =" in out  # trained under the winner
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_candle_uno_app_auto_strategy_with_telemetry(tmp_path, capsys):
     """``-s auto`` + ``--telemetry``: the choice lands in the JSONL as
     a ``search`` event (reconstructable from the log alone), and a
@@ -291,6 +294,7 @@ def test_apps_print_help(mod, capsys):
     assert "Common flags" in out and "-ll:tpu" in out
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_alexnet_app_eval_iters(capsys):
     assert alexnet.main([
         "-b", "4", "-i", "1", "--image-size", "67", "--eval-iters", "2",

@@ -34,12 +34,14 @@ def stubbed_bench(monkeypatch):
         print("tp = 1.00 samples/s")
         return value
 
-    monkeypatch.setattr(bench, "probe_backend", lambda: ("cpu", 0, None))
+    # The suite runs under JAX_PLATFORMS=cpu (tests/conftest.py): the
+    # one case in which bench.py accepts the CPU backend.
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setattr(
-        bench, "bench_alexnet", lambda n, t: chatty((100.0, 0.1, 32))
+        bench, "bench_alexnet", lambda n, t: chatty((100.0, None, 32))
     )
     monkeypatch.setattr(
-        bench, "bench_dlrm", lambda n, t: chatty((50.0, 0.05, None))
+        bench, "bench_dlrm", lambda n, t: chatty((50.0, None))
     )
     monkeypatch.setattr(
         bench, "bench_transformer", lambda t: chatty((1000.0, 0.2))
@@ -312,6 +314,10 @@ def test_bench_stdout_is_exactly_one_json_line(stubbed_bench, monkeypatch):
                        "devices", "host", "process_id", "process_count"}
     assert fp["jax"] is not None
     assert fp["platform"] == "cpu"
+    # Every result names its device; a CPU run carries no MFU.
+    assert record["extra"]["platform"] == "cpu"
+    assert record["extra"]["device_kind"] == "cpu"
+    assert record["extra"]["alexnet_mfu"] is None
     # The chatter landed on stderr, not stdout.
     assert "tp = " in err.getvalue()
 
@@ -331,7 +337,9 @@ def test_bench_stdout_json_even_when_legs_fail(stubbed_bench, monkeypatch):
     out, err = io.StringIO(), io.StringIO()
     monkeypatch.setattr(sys, "stdout", out)
     monkeypatch.setattr(sys, "stderr", err)
-    assert stubbed_bench.main() == 0
+    # The report still lands on stdout; the failures make the exit
+    # code non-zero after it.
+    assert stubbed_bench.main() == 1
     lines = [l for l in out.getvalue().splitlines() if l.strip()]
     assert len(lines) == 1
     record = json.loads(lines[0])
@@ -342,3 +350,44 @@ def test_bench_stdout_json_even_when_legs_fail(stubbed_bench, monkeypatch):
     assert "leg exploded" in record["extra"]["serving_error"]
     assert "leg exploded" in record["extra"]["search_error"]
     assert "leg exploded" in record["extra"]["data_plane_error"]
+
+
+def test_bench_one_failed_leg_is_a_nonzero_exit(stubbed_bench, monkeypatch,
+                                                capsys):
+    """A leg that raises: the JSON line is still printed, with the
+    other legs' numbers and the failure under ``<leg>_error``, and the
+    run exits non-zero — never a quiet 0 over a missing measurement."""
+    def boom(*a, **k):
+        raise RuntimeError("leg exploded")
+
+    monkeypatch.setattr(stubbed_bench, "bench_transformer", boom)
+    assert stubbed_bench.main() == 1
+    (line,) = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
+    extra = json.loads(line)["extra"]
+    assert "leg exploded" in extra["transformer_error"]
+    assert "transformer_tokens_per_s" not in extra
+    assert extra["transformer_8k_tokens_per_s"] == 500.0
+    assert extra["dlrm_samples_per_s"] == 50.0
+
+
+def test_bench_without_a_chip_is_a_nonzero_exit(stubbed_bench, monkeypatch,
+                                                capsys):
+    """jax found only the CPU and JAX_PLATFORMS=cpu was not asked for:
+    no leg runs, nothing is printed on stdout, the exit is non-zero.
+    No probe child, no CPU fallback, no older record stapled in."""
+    ran = []
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(stubbed_bench, "bench_alexnet",
+                        lambda n, t: ran.append(1))
+    rc = stubbed_bench.main()
+    assert rc not in (0, None)
+    assert ran == []
+    assert capsys.readouterr().out.strip() == ""
+
+
+def test_bench_unknown_device_kind_is_an_error(stubbed_bench, monkeypatch):
+    """MFU divides by a published peak looked up by device kind; a kind
+    that is not in the table raises instead of assuming a v5e."""
+    assert stubbed_bench.DEVICE_PEAKS["TPU v5 lite"]["bf16_flops"] == 1.97e14
+    with pytest.raises(KeyError, match="no published peak"):
+        stubbed_bench.peak_bf16_flops()  # the suite's device kind is "cpu"

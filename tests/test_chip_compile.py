@@ -1,0 +1,273 @@
+"""What the chip would refuse, found without the chip.
+
+- AOT compiles for a DESCRIBED TPU v5e (``on-chip-measurement`` guide
+  §2, rehearsal 3): the Pallas kernels of the main path at the shapes
+  ``chip_smoke.py`` runs them at, forced out of interpret mode, through
+  the TPU compiler that is installed here.  Interpret-mode tests
+  (test_pallas.py, test_serving.py) cannot see a block shape Mosaic
+  rejects or a kernel past scoped VMEM; these can.  Nothing runs, so
+  they say nothing about results or times.  Skipped where the
+  topology cannot be described.  tests/conftest.py keeps the suite off
+  the persistent compilation cache, which such a compile could write
+  to but never read back.
+- The CPU rehearsal of ``chip_smoke.py``: its real phases at tiny
+  sizes, with the two things only a chip can answer (the device check,
+  the Mosaic call in compiled text) stubbed HERE, not by an option of
+  the script.
+- The compile-cache rule (apps/common.enable_compile_cache).
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from flexflow_tpu.apps import common
+from flexflow_tpu.ops import pallas_kernels as pk
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+# -- AOT compiles for a described v5e ----------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=_one_chip())
+
+
+def _flash(shape, dtype, grad):
+    def fwd(q, k, v):
+        return pk.flash_attention_lse_auto(q, k, v, True, interpret=False)[0]
+
+    fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(F32).sum(),
+                  argnums=(0, 1, 2)) if grad else fwd
+    x = _sds(shape, dtype)
+    return (lambda: pk.flash_any_supported(shape, dtype)), fn, (x, x, x)
+
+
+def _xent(n, v, dtype, grad):
+    def fwd(logits, labels):
+        return pk.softmax_xent(logits, labels, interpret=False)[0]
+
+    fn = jax.grad(lambda lg, lb: fwd(lg, lb).sum()) if grad else fwd
+    return (lambda: pk.xent_supported(n, v)), fn, (
+        _sds((n, v), dtype), _sds((n,), jnp.int32))
+
+
+def _decode(b, s, h, hd, dtype):
+    fn = functools.partial(pk.flash_decode, interpret=False)
+    cache = _sds((b, s, h, hd), dtype)
+    return (lambda: pk.flash_decode_supported((b, s, h, hd), dtype)), fn, (
+        _sds((b, h, hd), dtype), cache, cache, _sds((b,), jnp.int32))
+
+
+def _rows(kind, rows, dim, n_ids):
+    table, ids = _sds((rows, dim), F32), _sds((n_ids,), jnp.int32)
+    gate = lambda: pk.rows_supported(n_ids, dim, F32, num_rows=rows, kind=kind)
+    if kind == "gather":
+        return gate, functools.partial(pk.gather_rows, interpret=False), (
+            table, ids)
+    return gate, functools.partial(pk.scatter_add_rows, interpret=False), (
+        table, ids, _sds((n_ids, dim), F32))
+
+
+#: name -> () -> (gate, fn, abstract args): the kernels of the smoke's
+#: phases at their real shapes (transformer b8 x 8 heads x seq 512 x
+#: hd 64 and its 4096 x 32768 logits; serve's 8 x 512 x 8 x 64 cache;
+#: DLRM's 1M-row d=64 tables), plus the long-context flash shape and
+#: the largest decode shape ISSUE 21 names.
+CASES = {
+    "flash_fwd-8x8x512x64-bf16": lambda: _flash((8, 8, 512, 64), BF16, False),
+    "flash_grad-8x8x512x64-bf16": lambda: _flash((8, 8, 512, 64), BF16, True),
+    "flash_fwd-2x8x8192x64-bf16": lambda: _flash((2, 8, 8192, 64), BF16, False),
+    "flash_grad-2x8x8192x64-bf16": lambda: _flash((2, 8, 8192, 64), BF16, True),
+    "xent_fwd-4096x32768-bf16": lambda: _xent(4096, 32768, BF16, False),
+    "xent_grad-4096x32768-bf16": lambda: _xent(4096, 32768, BF16, True),
+    "decode-8x512x8x64-f32": lambda: _decode(8, 512, 8, 64, F32),
+    "decode-8x512x8x64-bf16": lambda: _decode(8, 512, 8, 64, BF16),
+    "decode-16x4096x16x128-bf16": lambda: _decode(16, 4096, 16, 128, BF16),
+    "gather_rows-1Mx64-1024ids": lambda: _rows("gather", 1 << 20, 64, 1024),
+    "scatter_add_rows-1Mx64-1024ids":
+        lambda: _rows("scatter", 1 << 20, 64, 1024),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_text(name: str) -> str:
+    _gate, fn, args = CASES[name]()
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name):
+    """Mosaic accepts the kernel at this shape, and the compiled
+    program holds it (not an interpreted lowering)."""
+    assert chip_smoke.has_mosaic_call(_compiled_text(name))
+
+
+def test_supported_gates_match_the_compiler():
+    """No ``*_supported`` gate says True for a shape the compiler
+    refuses: every case above is admitted by its gate AND compiles,
+    and so does every decode shape of the issue's (h, hd, dtype) grid
+    that the gate admits — the hole ``flash_decode_supported`` had
+    (True at every shape, a block of 1 on the heads axis at all of
+    them)."""
+    for name, case in CASES.items():
+        gate, _fn, _args = case()
+        assert gate(), f"{name}: gate refuses a shape the smoke runs"
+        _compiled_text(name)
+    for h in (8, 16):
+        for hd in (64, 128):
+            for dtype in (F32, BF16):
+                gate, fn, args = _decode(4, 512, h, hd, dtype)
+                assert gate(), (h, hd, dtype)
+                jax.jit(fn).lower(*args).compile()
+    # Past one block, a cache length with no 8-aligned divisor has no
+    # legal k-block.
+    assert not pk.flash_decode_supported((4, 1030, 8, 64), F32)
+
+
+# -- chip_smoke.py, rehearsed on the CPU --------------------------------------
+
+_TINY_LM = ("--vocab", "256", "--d-model", "32", "--heads", "2",
+            "--layers", "2")
+_TINY = chip_smoke.Sizes(
+    alexnet=("-b", "4", "-i", "3", "--image-size", "67", "-ll:tpu", "1"),
+    # seq 128: the smallest the flash kernel's gate takes.
+    transformer=("-b", "2", "--seq", "128", *_TINY_LM, "-i", "3",
+                 "-ll:tpu", "1"),
+    dlrm=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
+          "-ll:tpu", "1",
+          "--arch-sparse-feature-size", "8",
+          "--arch-embedding-size", "100-100-100-100",
+          "--arch-mlp-bot", "8-16-8", "--arch-mlp-top", "40-16-1"),
+    serve=("--max-seq", "32", "--max-batch", "2", "--requests", "3",
+           "--max-new", "6", *_TINY_LM),
+    alexnet4=("-b", "4", "-i", "2", "--image-size", "67"),
+    alexnet4_strategy=chip_smoke.FULL.alexnet4_strategy,
+    transformer4=("-b", "4", "--seq", "128", *_TINY_LM, "-i", "2"),
+    transformer4_mesh=("--dp", "2", "--tp", "2"),
+    serve4_shard=("--shard", "2,2"),
+)
+
+
+@pytest.fixture
+def on_a_pretend_chip(monkeypatch):
+    """Stub what only a chip can answer.  The CPU lowers Pallas calls
+    through the interpreter, so no compiled text here holds a Mosaic
+    call; the real check is exercised by the AOT cases above."""
+    monkeypatch.setattr(chip_smoke, "has_mosaic_call", lambda text: True)
+
+
+def _phases(which):
+    return dict(which(_TINY))
+
+
+@pytest.mark.parametrize(
+    "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
+              "serve"])
+def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
+    """Each one-chip phase runs to its end at a tiny size: the apps'
+    mains, the replayed loss trajectories, the sparse-vs-dense DLRM
+    comparison, the four serve runs and the oracle token parity."""
+    _phases(chip_smoke.one_chip_phases)[phase]()
+    assert f"[{phase}" in capsys.readouterr().out
+
+
+@pytest.mark.slow  # ~50 s: six app runs and their replays
+def test_chip_smoke_four_chip_phases(on_a_pretend_chip, monkeypatch):
+    """--chips 4's phases on four of the virtual devices: the README's
+    layer-wise AlexNet strategy, the dp2 x tp2 transformer and the
+    2,2-sharded server, each against one device."""
+    four = jax.devices()[:4]
+    monkeypatch.setattr(jax, "devices", lambda *a: four)
+    failed = chip_smoke.run_phases(
+        [p for p in chip_smoke.four_chip_phases(_TINY) if p[0] != "native"]
+    )
+    assert failed == []
+
+
+def test_chip_smoke_refuses_without_a_tpu(capsys):
+    """No accelerator: a non-zero exit and no result line."""
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main([])
+    assert e.value.code not in (0, None)
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_chip_smoke_failed_phase_is_a_nonzero_exit(monkeypatch, capsys):
+    """A phase that raises fails the run, the later phases still run,
+    and the result line is not printed."""
+    ran = []
+
+    def boom():
+        raise RuntimeError("planted")
+
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda: {
+        "platform": "tpu", "kind": "stub", "count": 1})
+    monkeypatch.setattr(chip_smoke, "one_chip_phases", lambda sz: [
+        ("bad", boom), ("after", lambda: ran.append(True))])
+    monkeypatch.setattr(common, "enable_compile_cache", lambda: "/nowhere")
+    assert chip_smoke.main([]) == 1
+    assert ran == [True]
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_chip_smoke_tolerance_is_the_serving_suite_s():
+    import test_serving
+
+    assert chip_smoke.DECODE_TOL == test_serving.DECODE_TOL
+
+
+# -- the compile-cache rule ---------------------------------------------------
+
+
+@pytest.fixture
+def cache_dir_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_set_sets_nothing(monkeypatch, cache_dir_config):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, the code
+    sets no directory of its own."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert common.enable_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_compile_cache_env_unset_is_checkout_ffcache(monkeypatch,
+                                                     cache_dir_config):
+    """Unset: <checkout>/.ffcache — the path ffcompile.sh's launcher
+    exports and .gitignore lists; never a temp name, pid or time."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(ROOT, ".ffcache")
+    assert common.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert common.enable_compile_cache() == want  # stable across calls

@@ -144,6 +144,7 @@ def test_world_single_process():
     assert world() == (0, 1)
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_moe_expert_parallel_on_hybrid_mesh(rng):
     """Expert parallelism composes with the DCN-outer pod layout: dp
     rides the d0 (DCN) axis, the experts' c-shard stays on ICI axes,

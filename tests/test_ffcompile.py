@@ -14,7 +14,7 @@ def test_ffcompile_emits_launcher(tmp_path):
     out = tmp_path / "alexnet_launcher"
     proc = subprocess.run(
         ["bash", os.path.join(REPO, "ffcompile.sh"), "alexnet", str(out)],
-        capture_output=True, text=True, cwd=REPO,
+        capture_output=True, text=True, cwd=REPO, timeout=300,
     )
     assert proc.returncode == 0, proc.stderr
     assert out.exists()
@@ -33,7 +33,7 @@ def test_ffcompile_rejects_unknown_app(tmp_path):
     proc = subprocess.run(
         ["bash", os.path.join(REPO, "ffcompile.sh"), "nosuchapp",
          str(tmp_path / "x")],
-        capture_output=True, text=True, cwd=REPO,
+        capture_output=True, text=True, cwd=REPO, timeout=300,
     )
     assert proc.returncode != 0
     assert "unknown app" in proc.stderr

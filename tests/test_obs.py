@@ -16,7 +16,7 @@ What is pinned here:
   ``ok``.
 - **Catalog sync**: fflint FF008's dependency-free event-name copy
   must equal ``obs.events.EVENT_CATALOG`` (same precedent as
-  RELAY_CAP).
+  FUSED_STEPS_CAP).
 - **Attribution**: a synthetic perfetto trace summarizes to exact
   device-ms numbers; a real ``--trace`` + ``--telemetry`` run folds a
   ``trace_summary`` block and ``program_cost`` events into its log.

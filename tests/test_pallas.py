@@ -208,7 +208,11 @@ def test_xent_supported_gating():
 # -- chunked flash (sequences past the single-launch VMEM cap) --------------
 
 
-@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize(
+    "causal",
+    # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
+    [pytest.param(False, marks=pytest.mark.slow), True],
+)
 def test_flash_chunked_matches_naive(rng, causal, monkeypatch):
     # Force chunking at a small shape by shrinking the chunk picker
     # (real chunking triggers at bf16 t=16384, too big for CPU tests).

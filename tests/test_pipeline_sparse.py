@@ -216,6 +216,7 @@ def test_compiled_sparse_bit_identical():
 # -- clip-norm ----------------------------------------------------------------
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_clip_norm_sparse_chunk_invariant():
     """Unique-row gsum**2 folds into the batched clip fence; the global
     norm (and the scaled row update) is chunk-invariant bitwise and
@@ -247,6 +248,7 @@ def test_compiled_clip_norm_sparse():
 # -- stateful (lazy) optimizers ----------------------------------------------
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 @pytest.mark.parametrize("opt", ["lazy_mom", "lazy_adam"])
 def test_lazy_sparse_chunk_and_compiled_invariant(opt):
     """The stateful row path (``_sparse_stateful_apply`` on touched
@@ -263,6 +265,7 @@ def test_lazy_sparse_chunk_and_compiled_invariant(opt):
     )
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_lazy_cold_rows_frozen():
     """Lazy semantics survive the pipeline: rows no microbatch touched
     keep their initial value (dense momentum would still decay them

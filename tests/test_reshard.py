@@ -132,6 +132,7 @@ def _boundary_model(batch=8):
     return ff, store
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_boundary_numerics_match_dp(rng):
     """Spatial+table strategies with decomposed reshard hops produce
     the same step numerics as plain DP (the strategy-invariance
@@ -224,6 +225,7 @@ def _compile_transition(frm: str, to: str, mode: str):
     return _run_probe(_TRANSITION_PROBE, frm, to, mode)
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_hops_avoid_remat_gspmd_would_do():
     """The mechanism's value, pinned end to end: a TP-output ->
     hybrid-DP boundary (axes move dims AND an axis drops — the
@@ -238,6 +240,7 @@ def test_hops_avoid_remat_gspmd_would_do():
     assert not _compile_transition(frm, to, "hops")
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_declined_transitions_do_not_remat_today():
     """Documents what GSPMD does on transitions ``reshard_hops``
     DECLINES (and now warns about): on current XLA these compile
@@ -268,6 +271,7 @@ print("COMPILED")
 """
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_no_involuntary_full_remat():
     """The spatial->DP and table-parallel->DP boundaries compile
     without any GSPMD involuntary-full-rematerialization fallback."""

@@ -241,6 +241,7 @@ class TestEndToEndSearch:
         loaded = StrategyStore.load(str(out))
         assert loaded.num_devices == 4
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_searched_strategy_runs_on_executor(self, alexnet):
         """The emitted table must be consumable by the runtime: compile
         and run one train step under the searched strategy on the
@@ -395,6 +396,7 @@ class TestMeasuredDegrees:
         assert ps["kernel"] == (256, 1024)
         assert ps["bias"] == (256,)
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_structural_cache_dedupes(self):
         """Identical shard geometries (same type/attrs/local shapes)
         are measured once — the reference's computeTime[] keyed by op
@@ -427,6 +429,7 @@ class TestMeasuredDegrees:
         assert not any(name == "fc2" for name, _ in calls)
         assert all(us > 0 for v in table.values() for us in v.values())
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_measured_search_diverges_from_roofline(self):
         """The VERDICT-item acceptance: measured per-degree costs make
         the search pick a different (simulated-better-under-measure)
@@ -453,6 +456,7 @@ class TestMeasuredDegrees:
         assert measured.assignment["fc"].c == 1
         assert measured.assignment["fc"] != roofline.assignment["fc"]
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_measured_bwd_asymmetry_changes_strategy(self):
         """VERDICT r4 acceptance: an op whose BACKWARD cost scales
         differently from its forward must steer the search away from
@@ -488,6 +492,7 @@ class TestMeasuredDegrees:
         assert measured.assignment["fc"].c == 1
         assert measured.assignment["fc"] != legacy.assignment["fc"]
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_real_timing_smoke(self):
         """The real two-point fori_loop timer produces positive,
         finite per-degree times on the CPU backend for a tiny model
@@ -832,6 +837,7 @@ class TestExecutionSearch:
     legality REUSED from the runtime so every emitted candidate is
     executor-legal (ISSUE 6 acceptance)."""
 
+    @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
     def test_every_emitted_candidate_is_runnable(self, caplog):
         """Each config the searcher emits executes without a loud
         fallback — built via make_executor and trained one superstep's
@@ -910,19 +916,19 @@ class TestExecutionSearch:
 
     def test_calibration_steers_the_winner(self):
         """The dispatch term must actually steer: an expensive
-        per-program host (relay-like) pushes the winner to the fused
+        per-program host pushes the winner to the fused
         minimum-dispatch form; a free-dispatch host ranks by compute
         alone and keeps programs-per-step irrelevant."""
         from flexflow_tpu.search import Calibration, search_execution_config
 
         ff = _mlp()
-        relay = search_execution_config(
+        costly = search_execution_config(
             ff, 4, iters=0, seed=0, ks=(1, 8),
             stage_options=(2,), microbatch_options=(2,),
             calibration=Calibration(dispatch_ms=16.0, fence_ms=16.0,
                                     calibrated=True),
         )
-        assert relay.best.programs_per_step() <= 1 / 8
+        assert costly.best.programs_per_step() <= 1 / 8
         free = search_execution_config(
             ff, 4, iters=0, seed=0, ks=(1, 8),
             stage_options=(2,), microbatch_options=(2,),

@@ -242,6 +242,7 @@ def test_serving_fault_isolation(sex, weights):
     assert stats["failed"] == 1 and stats["completed"] == 1
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_train_serve_checkpoint_handoff(lm, tmp_path):
     """Params trained + checkpointed by the TRAINING stack restore
     into the serving executor (strategy-portable restore) and produce
@@ -269,9 +270,9 @@ def test_train_serve_checkpoint_handoff(lm, tmp_path):
     assert from_ckpt[0].tokens == from_live[0].tokens
 
 
-def test_decode_steps_relay_clamp(sex, weights):
-    """decode_steps clamps at the relay-safe fence cap (CLAUDE.md
-    keep-chains-short hazard), same as training supersteps."""
+def test_decode_steps_clamp(sex, weights):
+    """decode_steps clamps at the fused-step bound, same as training
+    supersteps."""
     params, state = weights
     srv = Server(sex, params, state, decode_steps=64)
     assert srv.decode_steps == 20
@@ -722,9 +723,9 @@ def test_spec_sampled_replayable(sex, weights):
     assert alone[1].tokens == base[1].tokens
 
 
-def test_spec_relay_clamp(sex, weights):
-    """The draft chain counts against the relay-safe fence cap: d
-    clamps at 20 exactly like decode_steps and training supersteps."""
+def test_spec_clamp(sex, weights):
+    """The draft chain counts against the fused-step bound: d clamps
+    at 20 exactly like decode_steps and training supersteps."""
     params, state = weights
     srv = Server(sex, params, state, speculate=64)
     assert srv.speculate == 20

@@ -353,7 +353,7 @@ def test_serving_config_legality():
                       max_seq=32, policy=pol)
     with pytest.raises(ValueError):
         ServingConfig(buckets=(8, 32), decode_steps=99, max_batch=2,
-                      max_seq=32, policy=pol)  # relay clamp
+                      max_seq=32, policy=pol)  # the fused-step bound
 
 
 def test_serve_auto_emits_only_legal_configs_and_chosen_runs(lm, weights):
@@ -954,6 +954,7 @@ def test_sim_matches_real_through_retry_and_restart(sex, weights):
     assert _virt(sim_st) == _virt(real_st)
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_degraded_decode_oracle_rung(lm, weights):
     """Degraded-mode ladder rung 1: after ``kernel_fault_rung``
     decode-phase engine faults the flash_decode kernel is disabled and

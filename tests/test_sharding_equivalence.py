@@ -184,21 +184,29 @@ def test_sharded_embedding_tight_vs_unsharded():
 def test_lazy_adam_sharded_rows():
     """Lazy-sparse Adam over the c-sharded table: the row-sparse
     update (touched rows only) lands on the owning shards; the table
-    trajectory matches the unsharded lazy run to ≤ a few ULP (the
-    per-row Adam math is identical — only the scatter's shard-local
-    RMW differs)."""
+    trajectory matches the unsharded lazy run (the per-row Adam math
+    is identical — only the scatter's shard-local RMW differs).
+
+    Tolerance: 2 ULP at the scale of one Adam step (lr = 0.05, so
+    atol 2**-27), not a ULP count on the entries themselves.  On jax
+    0.9.0 XLA:CPU rounds the row update once differently inside the
+    shard_map'd local program than in the unsharded one: after three
+    steps exactly one of the 512 entries differs, by 3.7e-9 = one ULP
+    of the 0.05 step, while the losses, the dense params and the other
+    511 entries stay bit-identical.  An entry is the small residue of
+    steps that nearly cancel (0.0059 here), so the same last-bit
+    rounding reads as 8 ULP of the entry; a wrong or doubled row
+    update would be off by the step itself."""
     from flexflow_tpu.optim import AdamOptimizer
 
+    lr = 0.05
     mk = lambda c: emb_train(
         {"emb": ParallelConfig(n=2, c=c)}, 8,
-        optimizer=AdamOptimizer(lr=0.05, lazy_sparse=True),
+        optimizer=AdamOptimizer(lr=lr, lazy_sparse=True),
     )
     a = mk(1)
     b = mk(4)
-    np.testing.assert_allclose(a[0], b[0], rtol=1e-6)
-    np.testing.assert_array_max_ulp(
-        np.asarray(a[1]["emb"]["table"]),
-        np.asarray(b[1]["emb"]["table"]).reshape(
-            np.asarray(a[1]["emb"]["table"]).shape),
-        maxulp=4,
-    )
+    np.testing.assert_array_equal(a[0], b[0])
+    ta = np.asarray(a[1]["emb"]["table"])
+    tb = np.asarray(b[1]["emb"]["table"]).reshape(ta.shape)
+    np.testing.assert_allclose(ta, tb, rtol=0, atol=2.0 ** -27)

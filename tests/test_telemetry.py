@@ -321,7 +321,7 @@ def test_watchdog_notifies_external_supervisor(tmp_path):
 
 def test_watchdog_refuses_self_notification():
     """The escalation hook never signals the process it watches
-    (in-process kill is the relay-wedge hazard)."""
+    (the observe-and-warn contract)."""
     with Telemetry(stall_deadline_s=0.0, notify_pid=os.getpid()) as tel:
         assert tel._notify_pid == 0
 
@@ -389,7 +389,7 @@ def test_pipeline_clip_norm_fence_is_instrumented():
         )
         jax.device_get(m)
         # The per-step clip-norm device_get is a REAL fence; the
-        # watchdog/counters must see it (the relay-wedge signature).
+        # watchdog/counters must see it.
         assert tel.counts["fences"] == 1
 
 

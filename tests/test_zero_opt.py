@@ -78,6 +78,7 @@ def test_zero_opt_moments_actually_sharded():
     assert not rep_spec or not rep_spec[0]
 
 
+@pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
 def test_zero_opt_composes_with_tp():
     """Under hybrid n x c: a c-sharded weight's moments keep the c
     shard AND gain the DP split on the free leading dim; numerics

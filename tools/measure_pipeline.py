@@ -97,9 +97,9 @@ def time_fused_superstep(pipe, batch, k, iters=32, warmup=1):
     (``PipelineExecutor.build_superstep`` on the compiled-step path)."""
     import jax
 
-    from flexflow_tpu.runtime.trainer import relay_safe_steps
+    from flexflow_tpu.runtime.trainer import clamp_fused_steps
 
-    k = relay_safe_steps(k)
+    k = clamp_fused_steps(k)
     params, opt_state, state = pipe.init(seed=0)
     fn = pipe.build_superstep(k)
     stacked = pipe.stack_steps([batch] * k)

@@ -10,8 +10,7 @@ Variants:
 
 Both run the kernels DIRECTLY (no custom-vjp wrapper): the chain step
 is (dq, dk, dv) = bwd(q, ...) with dq fed back as the next q — 2
-dependent pallas calls per iteration, chains (2, 8) = 16 calls, under
-the <=24-call relay cap (MEASURED_r4/README.md).
+dependent pallas calls per iteration, chains (2, 8) = 16 calls.
 
 Usage: python tools/probe_flash_bwd_variants.py [b h t hd] [--blocks 256,512]
 """
