@@ -35,7 +35,11 @@ Rule catalog (ANALYSIS.md has the full rationale table):
 - FF008 telemetry ``emit`` with an unregistered event name — every
   event type must be a row in the OBSERVABILITY.md schema table
   (``obs/events.py::EVENT_CATALOG``); an ad-hoc name is silent
-  schema drift the reader cannot validate.
+  schema drift the reader cannot validate.  The same rule holds the
+  names a profiler trace is read by: a ``span(`` literal
+  (``SPAN_CATALOG``), a ``pallas_call(name=`` literal
+  (``KERNEL_CATALOG``) and an ``ff_*`` ``named_scope`` literal
+  (``SCOPE_CATALOG``).
 
 FF002 (named ``jax.devices("tpu")`` lookup) and FF007 (``timeout=`` in
 ``tools/``) are retired with their code: both guarded a forwarding
@@ -316,6 +320,21 @@ FF008_EVENT_NAMES = frozenset({
     "distributed_init", "elastic_resize",
 })
 
+#: The names a profiler trace is read by, under the same pin
+#: (``SPAN_CATALOG``, ``KERNEL_CATALOG``, ``SCOPE_CATALOG``).
+FF008_SPAN_NAMES = frozenset({
+    "ff/serve/admit", "ff/serve/prefill_dispatch", "ff/serve/prefill_fence",
+    "ff/serve/install", "ff/serve/decode_pack", "ff/serve/decode_dispatch",
+    "ff/serve/decode_fence", "ff/serve/bookkeep",
+})
+FF008_KERNEL_NAMES = frozenset({
+    "ff_flash_fwd", "ff_flash_fwd_stream", "ff_flash_dq",
+    "ff_flash_dq_stream", "ff_flash_dkv", "ff_flash_dkv_stream",
+    "ff_flash_decode", "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
+    "ff_gather_rows", "ff_scatter_add_rows",
+})
+FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt"})
+
 #: Receiver names that mark an ``.emit(...)`` call as a telemetry
 #: emission (vs some unrelated emit API).
 _TELEMETRY_RECEIVERS = frozenset({"tel", "telemetry", "_telemetry"})
@@ -350,6 +369,49 @@ def _check_emit_event_names(tree: ast.AST, path: str):
                         f"every emitted name must be a row in the "
                         f"OBSERVABILITY.md schema table "
                         f"(obs/events.py EVENT_CATALOG)"))
+    return out
+
+
+def _literal(node) -> Optional[str]:
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return node.value
+    return None
+
+
+def _check_trace_names(tree: ast.AST, path: str):
+    """The other three catalogs: what a ``span(`` opens, what a
+    ``pallas_call`` is named, what an ``ff_*`` scope is called."""
+    out = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        callee = _dotted(node.func).split(".")[-1]
+        first = _literal(node.args[0]) if node.args else None
+        if callee == "span" and first is not None \
+                and first not in FF008_SPAN_NAMES:
+            out.append((node.lineno,
+                        f"unregistered host span {first!r} "
+                        f"(obs/events.py SPAN_CATALOG)"))
+        elif callee == "named_scope" and first is not None \
+                and first.startswith("ff_") \
+                and first not in FF008_SCOPE_NAMES:
+            out.append((node.lineno,
+                        f"unregistered device scope {first!r} "
+                        f"(obs/events.py SCOPE_CATALOG)"))
+        elif callee == "pallas_call":
+            for kw in node.keywords:
+                name = _literal(kw.value) if kw.arg == "name" else None
+                if name is not None and name not in FF008_KERNEL_NAMES:
+                    out.append((node.lineno,
+                                f"unregistered kernel name {name!r} "
+                                f"(obs/events.py KERNEL_CATALOG)"))
+    return out
+
+
+def _check_registered_names(tree: ast.AST, path: str):
+    out = _check_trace_names(tree, path)
+    if path != "flexflow_tpu/runtime/telemetry.py":
+        out += _check_emit_event_names(tree, path)
     return out
 
 
@@ -392,11 +454,11 @@ RULES: List[Rule] = [
     ),
     Rule(
         "FF008", "unregistered telemetry event name",
-        "OBSERVABILITY.md: the event-name catalog (obs/events.py) is "
-        "the schema; an ad-hoc emit name is silent schema drift",
-        lambda p: p.endswith(".py") and not _is_test(p)
-        and p != "flexflow_tpu/runtime/telemetry.py",
-        _check_emit_event_names,
+        "OBSERVABILITY.md: the name catalogs (obs/events.py) are the "
+        "schema; an ad-hoc event, span, kernel or ff_ scope name is "
+        "silent drift no reader finds",
+        lambda p: p.endswith(".py") and not _is_test(p),
+        _check_registered_names,
     ),
 ]
 

@@ -4,8 +4,8 @@
 (OBSERVABILITY.md); this package is the one way those streams are
 read back — the typed reader (``obs.reader``), the cross-run
 comparator + paired measurement protocol (``obs.compare``), the
-box-fingerprint/run registry (``obs.registry``), the perfetto
-device-time attribution (``obs.trace``), and the CLI
+box-fingerprint/run registry (``obs.registry``), the device-time
+attribution from a profiler trace (``obs.trace``), and the CLI
 (``python -m flexflow_tpu.obs report|compare|history``).
 
 Import discipline: nothing here imports jax at module load (the CLI

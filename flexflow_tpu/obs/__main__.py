@@ -125,14 +125,16 @@ def cmd_report(args) -> int:
                   + (f"  {extra}" if extra else ""))
     ts = log.trace_summary()
     if ts:
-        print(f"trace summary (device total "
-              f"{ts.get('device_ms_total')} ms):")
-        for row in ts.get("top_ops", []):
-            print(f"  {row['op']:<40} {row['device_ms']:>10.3f} ms "
-                  f"x{row['count']}")
-        for name, a in (ts.get("annotations") or {}).items():
-            print(f"  step '{name}': {a['count']} windows, host "
-                  f"{a['host_ms']} ms, device {a['device_ms']} ms")
+        print(f"trace summary (device busy "
+              f"{ts.get('device_ms_total')} ms of "
+              f"{ts.get('window_ms')} ms):")
+        for name, k in (ts.get("kernels") or {}).items():
+            print(f"  kernel {name:<32} {k['device_ms']:>10.3f} ms "
+                  f"x{k['count']}")
+        for name, ms in (ts.get("scopes") or {}).items():
+            print(f"  scope  {name:<32} {ms:>10.3f} ms")
+        for name, ms in (ts.get("idle_ms_by_span") or {}).items():
+            print(f"  idle under {name:<28} {ms:>10.3f} ms")
     search = log.first("search")
     if search is not None:
         print("execution search: "

@@ -9,6 +9,13 @@ schema-drift guard.  Adding an event = add the OBSERVABILITY.md row
 AND the name here (the lint module keeps a dependency-free copy,
 sync-pinned by ``tests/test_obs.py``).
 
+Beside the events, the three kinds of name a profiler trace carries
+(OBSERVABILITY.md "Spans, kernels, scopes"), under the same rule and
+the same pin: host spans (``telemetry.span``), Pallas kernels
+(``pallas_call(name=...)``) and device phases (``jax.named_scope``).
+What reads a trace (``obs/trace.py``, the benchmark's metric files)
+finds them by these names and by nothing else.
+
 This module imports nothing (no jax) so every reader — the obs CLI,
 the lint sync pin, offline tools — can load it anywhere.
 """
@@ -71,6 +78,44 @@ EVENT_CATALOG = frozenset({
     # multi-host / elastic (RESILIENCE.md "Host loss & elastic resize")
     "distributed_init",
     "elastic_resize",
+})
+
+#: Host spans (``telemetry.span``: a ``jax.profiler.TraceAnnotation``,
+#: so on the profiler's clock beside the device operations).  The eight
+#: of ``Server.run`` tile its loop; ``ScheduledServer``'s real engine
+#: opens the dispatch and fence names at the same calls.
+SPAN_CATALOG = frozenset({
+    "ff/serve/admit",
+    "ff/serve/prefill_dispatch",
+    "ff/serve/prefill_fence",
+    "ff/serve/install",
+    "ff/serve/decode_pack",
+    "ff/serve/decode_dispatch",
+    "ff/serve/decode_fence",
+    "ff/serve/bookkeep",
+})
+
+#: ``name=`` of every ``pl.pallas_call`` (``ops/pallas_kernels.py``).
+#: The streamed variants (``FF_FLASH_*``) share their family's prefix.
+KERNEL_CATALOG = frozenset({
+    "ff_flash_fwd",
+    "ff_flash_fwd_stream",
+    "ff_flash_dq",
+    "ff_flash_dq_stream",
+    "ff_flash_dkv",
+    "ff_flash_dkv_stream",
+    "ff_flash_decode",
+    "ff_softmax_xent_fwd",
+    "ff_softmax_xent_bwd",
+    "ff_gather_rows",
+    "ff_scatter_add_rows",
+})
+
+#: ``jax.named_scope`` phases of a train step beside the per-op scopes
+#: (``op.name``): the loss ops, and every optimizer update.
+SCOPE_CATALOG = frozenset({
+    "ff_loss",
+    "ff_opt",
 })
 
 #: ``run_end.exit`` classifications (the reader adds ``truncated`` for

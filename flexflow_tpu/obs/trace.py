@@ -1,166 +1,163 @@
-"""Device-time attribution from a perfetto trace (stdlib-only).
+"""Device-time attribution from the profiler's ``.xplane.pb``, by the
+names of ``obs/events.py`` (OBSERVABILITY.md "Spans, kernels, scopes").
 
-``--trace DIR --telemetry DIR2`` together close the ROADMAP XProf
-follow-on: the profiler writes a perfetto trace
-(``plugins/profile/<ts>/perfetto_trace.json.gz``,
-``create_perfetto_trace=True``), this module parses it with stdlib
-gzip+json (NO TensorFlow/TensorBoard dependency), and the trainer
-folds the result into ``run_end`` as its ``trace_summary`` block:
+``--trace DIR --telemetry DIR2``: at trace stop the trainer folds
+:func:`summarize_trace_dir` into ``run_end`` as ``trace_summary``: device
+ms by Pallas kernel (``KERNEL_CATALOG``), by step phase (``SCOPE_CATALOG``),
+and idle ms by the ``ff/`` host span over each gap's middle.
 
-- ``top_ops``: top-N op names by summed device-lane duration — the
-  "where did device time go" answer the reference always had from
-  per-task cudaEvent timing.
-- ``annotations``: per-``StepTraceAnnotation`` name (``train`` /
-  ``superstep``), event count, summed host wall, and the device time
-  that overlapped those windows — the host/device split per step.
-
-Lane classification: a perfetto process named ``/device:...`` is a
-device; on the CPU backend (tests' 8-dev virtual mesh) there is no
-``/device:`` process — XLA execution shows up under threads named
-``tf_XLA...``, so a thread whose name contains ``XLA`` counts as a
-device-side stand-in.  Infra events (``Foo::Bar`` scopes, ``$``-keyed
-internals, the annotation events themselves) are excluded from op
-totals.
+What a TPU trace holds (read on the chip, PERF.md PR 25): a device event
+is named by its HLO text, so a kernel is ``%ff_flash_fwd.24 = ...
+custom-call(``; its scope is the ``tf_op`` stat of its metadata record,
+which ``jax.profiler.ProfileData`` (imported in the call, nothing here
+needs jax to load) does not surface, so ``_op_scopes`` reads those
+records from the file's wire format.  A CPU run has no device plane.
 """
 
 from __future__ import annotations
 
 import bisect
 import glob
-import gzip
-import json
 import logging
 import os
-from typing import Any, Dict, List, Optional, Tuple
+import re
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from flexflow_tpu.obs.events import KERNEL_CATALOG, SCOPE_CATALOG
 
 _log = logging.getLogger("ff.obs")
-
-#: How many ops the ``top_ops`` table keeps.
-DEFAULT_TOP_N = 10
-
-
-def find_perfetto_trace(log_dir: str) -> Optional[str]:
-    """Newest ``perfetto_trace.json.gz`` under an XProf log dir."""
-    pattern = os.path.join(
-        log_dir, "plugins", "profile", "*", "perfetto_trace.json.gz"
-    )
-    paths = glob.glob(pattern)
-    if not paths:
-        # A caller may hand the session dir directly.
-        paths = glob.glob(
-            os.path.join(log_dir, "**", "perfetto_trace.json.gz"),
-            recursive=True,
-        )
-    if not paths:
-        return None
-    return max(paths, key=os.path.getmtime)
+_KERNEL = re.compile(r"^%(\w+?)(?:\.\d+)? = ")
+_CONTAINER = re.compile(r" (while|conditional|call)\(")
+_DEVICE = "/device:TPU:0"
 
 
-def _load_events(path: str) -> List[Dict[str, Any]]:
-    with gzip.open(path, "rt") as f:
-        doc = json.load(f)
-    ev = doc.get("traceEvents", [])
-    return ev if isinstance(ev, list) else []
+def _varint(buf, i: int) -> Tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return out, i
 
 
-def _is_infra(name: str) -> bool:
-    return "::" in name or name.startswith("$")
+def _fields(buf) -> Iterator[Tuple[int, Any]]:
+    """``(field number, int or memoryview)`` of one protobuf message."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            value, i = _varint(buf, i)
+        else:
+            size, i = (8, i) if kind == 1 else (4, i) if kind == 5 else _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        yield key >> 3, value
 
 
-def summarize_trace(path: str, top_n: int = DEFAULT_TOP_N) -> Dict[str, Any]:
-    """Parse one perfetto trace file into the ``trace_summary`` block.
-    Durations are perfetto microseconds, reported as ms (3 dp)."""
-    events = _load_events(path)
-    pnames: Dict[Any, str] = {}
-    tnames: Dict[Tuple[Any, Any], str] = {}
-    for e in events:
-        if e.get("ph") != "M":
+def _op_scopes(path: str) -> Dict[str, Dict[str, str]]:
+    """``{plane: {event name: scope path}}`` (``XSpace``: plane 1; in it
+    name 2, event metadata 4, stat metadata 5, each a map entry with its
+    value at 2; a record's name 2, stats 5; a stat's metadata id 1,
+    string 5, or reference 7 to a stat metadata's name)."""
+    text = lambda v: bytes(v).decode("utf-8", "replace")
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: Dict[str, Dict[str, str]] = {}
+    for num, plane in _fields(space):
+        if num != 1:
             continue
-        args = e.get("args") or {}
-        if e.get("name") == "process_name":
-            pnames[e.get("pid")] = str(args.get("name", ""))
-        elif e.get("name") == "thread_name":
-            tnames[(e.get("pid"), e.get("tid"))] = str(args.get("name", ""))
+        name, records, stat_names = "", [], {}
+        for num, value in _fields(plane):
+            if num == 2:
+                name = text(value)
+            elif num in (4, 5):
+                rec = list(_fields(dict(_fields(value))[2]))
+                if num == 4:
+                    records.append(rec)
+                else:
+                    stat_names[dict(rec).get(1, 0)] = text(dict(rec).get(2, b""))
+        scopes = {}
+        for rec in records:
+            for st in (dict(_fields(v)) for n, v in rec if n == 5):
+                if stat_names.get(st.get(1)) == "tf_op":
+                    scope = text(st[5]) if 5 in st else stat_names.get(st.get(7), "")
+                    scopes[text(dict(rec).get(2, b""))] = scope.rstrip(":")
+        if scopes:
+            out[name] = scopes
+    return out
 
-    def device_lane(pid, tid) -> bool:
-        if pnames.get(pid, "").startswith("/device:"):
-            return True
-        return "XLA" in tnames.get((pid, tid), "")
 
-    op_totals: Dict[str, float] = {}
-    op_counts: Dict[str, int] = {}
-    device_ops: List[Tuple[float, float]] = []  # (ts, dur) us
-    annotations: Dict[str, Dict[str, Any]] = {}
-    ann_windows: Dict[str, List[Tuple[float, float]]] = {}
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        name = str(e.get("name", ""))
-        dur = float(e.get("dur", 0.0))
-        ts = float(e.get("ts", 0.0))
-        args = e.get("args") or {}
-        if "step_num" in args:
-            # A StepTraceAnnotation window (host wall of one step).
-            a = annotations.setdefault(
-                name, {"count": 0, "host_ms": 0.0, "device_ms": 0.0}
-            )
-            a["count"] += 1
-            a["host_ms"] += dur
-            ann_windows.setdefault(name, []).append((ts, ts + dur))
-            continue
-        if not device_lane(e.get("pid"), e.get("tid")):
-            continue
-        device_ops.append((ts, dur))
-        if _is_infra(name) or not name:
-            continue
-        op_totals[name] = op_totals.get(name, 0.0) + dur
-        op_counts[name] = op_counts.get(name, 0) + 1
+def _innermost(spans) -> List[Tuple[float, float, str]]:
+    """Disjoint ``(start, end, name)`` pieces: at each instant the span
+    that started last and has not ended."""
+    pieces, stack, cur = [], [], 0.0
+    for a, b, name in sorted(spans, key=lambda s: (s[0], -s[1])) + [(float("inf"), 0.0, "")]:
+        while stack and stack[-1][0] <= a:
+            if stack[-1][0] > cur:
+                pieces.append((cur, stack[-1][0], stack[-1][1]))
+            cur = max(cur, stack.pop()[0])
+        if stack and a > cur:
+            pieces.append((cur, a, stack[-1][1]))
+        cur = max(cur, a)
+        stack.append((b, name))
+    return pieces
 
-    # Device time inside each annotation window (attribute by the op
-    # event's START time — an op belongs to the step that launched it).
-    for aname, windows in ann_windows.items():
-        windows.sort()
-        starts = [w[0] for w in windows]
-        dev_us = 0.0
-        for ts, dur in device_ops:
-            i = bisect.bisect_right(starts, ts) - 1
-            if i >= 0 and ts < windows[i][1]:
-                dev_us += dur
-        annotations[aname]["device_ms"] = round(dev_us / 1e3, 3)
-    for a in annotations.values():
-        a["host_ms"] = round(a["host_ms"] / 1e3, 3)
 
-    top = sorted(op_totals.items(), key=lambda kv: -kv[1])[:top_n]
+def summarize_trace(path: str) -> Dict[str, Any]:
+    """One ``.xplane.pb`` into the ``trace_summary`` block (ms, 3 dp)."""
+    from jax.profiler import ProfileData
+
+    ops, spans = [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if plane.name == _DEVICE and line.name == "XLA Ops":
+                ops = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name) for e in line.events)
+            elif plane.name.startswith("/host:"):
+                spans += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                          for e in line.events if e.name.startswith("ff/")]
+    scope_of = _op_scopes(path).get(_DEVICE, {})
+    pieces = _innermost(spans)
+    kernels, scopes, idle = {}, {}, {}
+    busy, cur = 0.0, ops[0][0] if ops else 0.0
+    for a, b, name in ops:
+        if a > cur:  # a gap: to the innermost span over its middle
+            mid = 0.5 * (cur + a)
+            i = bisect.bisect_right(pieces, (mid, float("inf"), "")) - 1
+            owner = pieces[i][2] if i >= 0 and pieces[i][1] >= mid else "<none>"
+            idle[owner] = idle.get(owner, 0.0) + (a - cur) * 1e-6
+        busy += max(0.0, b - max(a, cur)) * 1e-6
+        cur = max(cur, b)
+        if _CONTAINER.search(name):
+            continue  # its children are events too
+        m = _KERNEL.match(name)
+        if m and m.group(1) in KERNEL_CATALOG:
+            k = kernels.setdefault(m.group(1), {"device_ms": 0.0, "count": 0})
+            k["device_ms"] += (b - a) * 1e-6
+            k["count"] += 1
+        for part in set(re.split(r"[/();]", scope_of.get(name, ""))) & SCOPE_CATALOG:
+            scopes[part] = scopes.get(part, 0.0) + (b - a) * 1e-6
     return {
         "trace_file": path,
-        "device_ms_total": round(sum(d for _, d in device_ops) / 1e3, 3),
-        "top_ops": [
-            {"op": name, "device_ms": round(us / 1e3, 3),
-             "count": op_counts[name]}
-            for name, us in top
-        ],
-        "annotations": annotations,
+        "device_ms_total": round(busy, 3),
+        "window_ms": round((cur - ops[0][0]) * 1e-6, 3) if ops else 0.0,
+        "kernels": {k: {"device_ms": round(v["device_ms"], 3), "count": v["count"]}
+                    for k, v in kernels.items()},
+        "scopes": {k: round(v, 3) for k, v in scopes.items()},
+        "idle_ms_by_span": {k: round(v, 3) for k, v in idle.items()},
     }
 
 
-def summarize_trace_dir(log_dir: str,
-                        top_n: int = DEFAULT_TOP_N,
-                        ) -> Optional[Dict[str, Any]]:
-    """The trainer's entry point: newest perfetto trace under the
-    XProf dir -> summary block, or None (with one warning) when the
-    trace is absent or unparsable — attribution must never fail the
-    run that produced it."""
+def summarize_trace_dir(log_dir: str) -> Optional[Dict[str, Any]]:
+    """The trainer's entry point: the newest trace under the XProf dir,
+    summarized, or None with one warning — attribution must never fail
+    the run that produced it."""
     try:
-        path = find_perfetto_trace(log_dir)
-        if path is None:
-            _log.warning(
-                "trace summary: no perfetto_trace.json.gz under %s "
-                "(profiler too old, or the trace was not written?)",
-                log_dir,
-            )
-            return None
-        return summarize_trace(path, top_n=top_n)
-    except (OSError, ValueError, KeyError) as e:
-        _log.warning("trace summary: cannot parse trace under %s: %s",
-                     log_dir, e)
-        return None
+        paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+        if paths:
+            return summarize_trace(max(paths, key=os.path.getmtime))
+        _log.warning("trace summary: no .xplane.pb under %s", log_dir)
+    except (OSError, RuntimeError, ValueError, KeyError, IndexError) as e:
+        _log.warning("trace summary: cannot read the trace under %s: %s", log_dir, e)
+    return None

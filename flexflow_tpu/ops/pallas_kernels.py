@@ -13,6 +13,11 @@ the reference fusing softmax+loss into one kernel
 kernels are Pallas, with f32 accumulation regardless of input dtype.
 On non-TPU backends the same kernels run under the Pallas interpreter,
 so the unit tests exercise the identical code path the chip runs.
+
+Every ``pallas_call`` carries a ``name=`` from
+``obs/events.py::KERNEL_CATALOG`` (fflint FF008): it is the custom
+call's instruction name in a TPU trace (``%ff_flash_fwd.24``), which is
+how the per-kernel metrics find it whatever scope or jit encloses it.
 """
 
 from __future__ import annotations
@@ -354,6 +359,7 @@ def _fwd_call(q, k, v, causal, interpret):
             jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
             jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
         ],
+        name="ff_flash_fwd",
         interpret=interpret,
     )(q, k, v)
 
@@ -457,6 +463,7 @@ def _fwd_stream_call(q, k, v, causal, interpret, block_q, block_k):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
+        name="ff_flash_fwd_stream",
         interpret=interpret,
     )(q, k, v)
 
@@ -692,6 +699,7 @@ def _bwd_stream_call(q, k, v, do, lse, delta, causal, interpret,
         out_specs=qb(True),
         out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+        name="ff_flash_dq_stream",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -711,6 +719,7 @@ def _bwd_stream_call(q, k, v, do, lse, delta, causal, interpret,
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
+        name="ff_flash_dkv_stream",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -733,6 +742,7 @@ def _bwd_call(q, k, v, do, lse, delta, causal, interpret):
         in_specs=[q_blocked, full, full, q_blocked, q_blocked_r, q_blocked_r],
         out_specs=q_blocked,
         out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
+        name="ff_flash_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -745,6 +755,7 @@ def _bwd_call(q, k, v, do, lse, delta, causal, interpret):
             jax.ShapeDtypeStruct((bh, t, hd), k.dtype),
             jax.ShapeDtypeStruct((bh, t, hd), v.dtype),
         ],
+        name="ff_flash_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -1215,6 +1226,7 @@ def flash_decode(q, cache_k, cache_v, lengths,
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, hd), jnp.float32),
         ],
+        name="ff_flash_decode",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, cache_k, cache_v)
 
@@ -1328,6 +1340,7 @@ def _xent_calls(n, v, dtype, interpret):
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.int32),
         ],
+        name="ff_softmax_xent_fwd",
         interpret=interpret,
     )
     bwd = pl.pallas_call(
@@ -1336,6 +1349,7 @@ def _xent_calls(n, v, dtype, interpret):
         in_specs=[blk, row, row, row, row],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((n, v), dtype),
+        name="ff_softmax_xent_bwd",
         interpret=interpret,
     )
     return fwd, bwd
@@ -1456,6 +1470,7 @@ def gather_rows(table, flat_idx, interpret: Optional[bool] = None):
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, d), table.dtype),
+        name="ff_gather_rows",
         interpret=interpret,
     )(flat_idx.astype(jnp.int32), table.reshape(-1, 1, d))
     return out.reshape(n, d)
@@ -1605,6 +1620,7 @@ def _scatter_rows_128(table, flat_idx, updates, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         input_output_aliases={1: 0},  # inputs incl. scalar prefetch
+        name="ff_scatter_add_rows",
         interpret=interpret,
     )(meta, table, upd_runs)
 

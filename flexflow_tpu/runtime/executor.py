@@ -14,6 +14,7 @@ Legion coherence + the mapper produced on GPUs.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -53,6 +54,19 @@ def _unique_row_sums(flat_ids, flat_g):
     uids = jnp.zeros((n,), sid.dtype).at[seg].set(sid)
     mask = jnp.arange(n) <= seg[-1]
     return uids, gsum, mask
+
+
+@contextlib.contextmanager
+def _op_scope(op: Op):
+    """``jax.named_scope(op.name)``; a terminal loss op (not MoE, whose
+    loss term is a byproduct of its FFN) also under ``ff_loss``, so its
+    forward and its transpose are found as one phase of the step
+    (``obs/events.py::SCOPE_CATALOG``)."""
+    with contextlib.ExitStack() as stack:
+        if op.is_loss and not op.allow_remat:
+            stack.enter_context(jax.named_scope("ff_loss"))
+        stack.enter_context(jax.named_scope(op.name))
+        yield
 
 
 def _merge_metrics(acc: Dict[str, jax.Array], m: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -358,8 +372,9 @@ class Executor:
             # The named scope lands in HLO instruction metadata
             # (op_name="…/opname/…"), which is what lets the post-SPMD
             # audit attribute collectives — and their bytes — to model
-            # ops (analysis/hlo.py collective_bytes_by_op).
-            with jax.named_scope(op.name):
+            # ops (analysis/hlo.py collective_bytes_by_op), and a trace
+            # reader device time (obs/trace.py).
+            with _op_scope(op):
                 xs = [
                     self._reshard_input(env[t.name], env_spec.get(t.name), t, op)
                     for t in op.inputs
@@ -460,9 +475,11 @@ class Executor:
                 (loss, (metrics, new_state)), grads = jax.value_and_grad(
                     self._loss_fn, has_aux=True
                 )(params, state, batch)
-                grads = self._clip_grads(grads)
-                new_params, new_opt = self.optimizer.update(params, opt_state, grads)
-                return new_params, self._constrain_zero_opt(new_opt), new_state, metrics
+                with jax.named_scope("ff_opt"):
+                    grads = self._clip_grads(grads)
+                    new_params, new_opt = self.optimizer.update(params, opt_state, grads)
+                    new_opt = self._constrain_zero_opt(new_opt)
+                return new_params, new_opt, new_state, metrics
 
             return train_step
 
@@ -475,7 +492,8 @@ class Executor:
             for op in sparse_ops:
                 op.bind_mesh(self.plan, self._pc(op))
                 xs = [batch[t.name] for t in op.inputs]
-                rows[op.name] = op.sparse_rows(params[op.name], xs)
+                with jax.named_scope(op.name):
+                    rows[op.name] = op.sparse_rows(params[op.name], xs)
             dense = {k: v for k, v in params.items() if k not in sparse_names}
 
             def loss_fn(dense_params, rows):
@@ -489,63 +507,66 @@ class Executor:
                 loss_fn, argnums=(0, 1), has_aux=True
             )(dense, rows)
 
-            # Duplicate-id row sums per sparse op — needed by exact
-            # global-norm clipping (the dense gradient's norm sums
-            # duplicate-id cotangents BEFORE squaring) and by stateful
-            # (lazy momentum/Adam) row updates (nonlinear in g, so one
-            # update per unique row).
-            uniq = {}
-            if clip > 0.0 or not stateless:
+            with jax.named_scope("ff_opt"):
+                # Duplicate-id row sums per sparse op — needed by exact
+                # global-norm clipping (the dense gradient's norm sums
+                # duplicate-id cotangents BEFORE squaring) and by stateful
+                # (lazy momentum/Adam) row updates (nonlinear in g, so one
+                # update per unique row).
+                uniq = {}
+                if clip > 0.0 or not stateless:
+                    for op in sparse_ops:
+                        xs = [batch[t.name] for t in op.inputs]
+                        ids = op.sparse_flat_ids(params[op.name], xs)
+                        g = rg[op.name]
+                        uniq[op.name] = _unique_row_sums(
+                            ids.reshape(-1), g.reshape(-1, g.shape[-1])
+                        )
+
+                scale = None
+                if clip > 0.0:
+                    extra_sq = sum(
+                        jnp.sum(jnp.square(gsum.astype(jnp.float32)))
+                        for (_, gsum, _) in uniq.values()
+                    )
+                    scale = self._clip_scale(dg, extra_sq)
+                    dg = jax.tree.map(
+                        lambda g: (g * scale).astype(g.dtype), dg
+                    )
+
+                # Dense update over the non-sparse params; sparse subtrees
+                # of the optimizer state are filtered out and row-updated
+                # below (SGD: None state passes through untouched).
+                opt_dense = self.optimizer.map_param_states(
+                    opt_state,
+                    lambda tree: {
+                        k: v for k, v in tree.items() if k not in sparse_names
+                    },
+                )
+                new_params, new_opt = self.optimizer.update(dense, opt_dense, dg)
+                new_opt = self.optimizer.restore_param_states(
+                    new_opt, opt_state, sparse_names
+                ) if new_opt is not None else None
+
+                lr = self.optimizer.lr
                 for op in sparse_ops:
-                    xs = [batch[t.name] for t in op.inputs]
-                    ids = op.sparse_flat_ids(params[op.name], xs)
-                    g = rg[op.name]
-                    uniq[op.name] = _unique_row_sums(
-                        ids.reshape(-1), g.reshape(-1, g.shape[-1])
-                    )
-
-            scale = None
-            if clip > 0.0:
-                extra_sq = sum(
-                    jnp.sum(jnp.square(gsum.astype(jnp.float32)))
-                    for (_, gsum, _) in uniq.values()
-                )
-                scale = self._clip_scale(dg, extra_sq)
-                dg = jax.tree.map(
-                    lambda g: (g * scale).astype(g.dtype), dg
-                )
-
-            # Dense update over the non-sparse params; sparse subtrees
-            # of the optimizer state are filtered out and row-updated
-            # below (SGD: None state passes through untouched).
-            opt_dense = self.optimizer.map_param_states(
-                opt_state,
-                lambda tree: {
-                    k: v for k, v in tree.items() if k not in sparse_names
-                },
-            )
-            new_params, new_opt = self.optimizer.update(dense, opt_dense, dg)
-            new_opt = self.optimizer.restore_param_states(
-                new_opt, opt_state, sparse_names
-            ) if new_opt is not None else None
-
-            lr = self.optimizer.lr
-            for op in sparse_ops:
-                if stateless:
-                    xs = [batch[t.name] for t in op.inputs]
-                    g = rg[op.name]
-                    if scale is not None:
-                        g = g * scale
-                    # Linear update: per-occurrence scatter-add
-                    # (duplicates distribute), Pallas row-DMA kernels.
-                    new_params[op.name] = op.sparse_apply(
-                        params[op.name], xs, g, lr
-                    )
-                else:
-                    new_params[op.name], new_opt = self._sparse_stateful_apply(
-                        op, params[op.name], new_opt, uniq[op.name], scale
-                    )
-            return new_params, self._constrain_zero_opt(new_opt), new_state, metrics
+                    with jax.named_scope(op.name):
+                        if stateless:
+                            xs = [batch[t.name] for t in op.inputs]
+                            g = rg[op.name]
+                            if scale is not None:
+                                g = g * scale
+                            # Linear update: per-occurrence scatter-add
+                            # (duplicates distribute), Pallas row-DMA kernels.
+                            new_params[op.name] = op.sparse_apply(
+                                params[op.name], xs, g, lr
+                            )
+                        else:
+                            new_params[op.name], new_opt = self._sparse_stateful_apply(
+                                op, params[op.name], new_opt, uniq[op.name], scale
+                            )
+                new_opt = self._constrain_zero_opt(new_opt)
+            return new_params, new_opt, new_state, metrics
 
         return sparse_train_step
 
@@ -647,12 +668,14 @@ class Executor:
                 return new_state, (metrics, grads)
 
             new_state, (metrics, grads) = jax.lax.scan(micro, state, stacked)
-            g = self._clip_grads(
-                jax.tree.map(lambda x: jnp.mean(x, axis=0), grads)
-            )
             m = mean_metrics(metrics, stacked=True)
-            new_params, new_opt = self.optimizer.update(params, opt_state, g)
-            return new_params, self._constrain_zero_opt(new_opt), new_state, m
+            with jax.named_scope("ff_opt"):
+                g = self._clip_grads(
+                    jax.tree.map(lambda x: jnp.mean(x, axis=0), grads)
+                )
+                new_params, new_opt = self.optimizer.update(params, opt_state, g)
+                new_opt = self._constrain_zero_opt(new_opt)
+            return new_params, new_opt, new_state, m
 
         return step
 

@@ -103,16 +103,13 @@ def report(profiles: List[OpProfile]) -> str:
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, perfetto: bool = False):
+def trace(log_dir: str):
     """Capture a TensorBoard/XProf trace of everything run inside the
     block (the jitted step as XLA executes it — fusions, collectives,
-    real device timelines).  View with ``tensorboard --logdir``.
-
-    ``perfetto=True`` additionally writes ``perfetto_trace.json.gz``
-    (plain gzip+json, no TensorBoard needed to read it) — what
-    ``obs/trace.py`` parses into the ``run_end`` ``trace_summary``
-    device-time attribution when telemetry is on."""
-    jax.profiler.start_trace(log_dir, create_perfetto_trace=perfetto)
+    real device timelines).  View with ``tensorboard --logdir``; with
+    telemetry on, ``obs/trace.py`` reads the ``.xplane.pb`` it leaves
+    into the ``run_end`` ``trace_summary`` device-time attribution."""
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:

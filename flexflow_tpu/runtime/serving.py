@@ -1963,187 +1963,192 @@ class Server:
                 # -- admissions (between decode supersteps) --
                 while queue and None in slots:
                     r = queue[0]
-                    plen = len(r.prompt)
-                    prior = carried_map.get(r.id, [])
-                    flen = plen + len(prior)
-                    if prior and resume_complete(r, prior):
-                        queue.popleft()
-                        carried_map.pop(r.id, None)
-                        continue
-                    try:
-                        bucket = ex.bucket_for(flen)
-                    except ValueError as e:
-                        queue.popleft()
-                        carried_map.pop(r.id, None)
-                        reject(r, str(e))
-                        continue
-                    plan = None
-                    if ledger is not None:
-                        need = ledger.blocks_for(plen, r.max_new_tokens)
-                        if need > ledger.capacity_blocks:
+                    with _telemetry.span("ff/serve/admit", id=r.id):
+                        plen = len(r.prompt)
+                        prior = carried_map.get(r.id, [])
+                        flen = plen + len(prior)
+                        if prior and resume_complete(r, prior):
                             queue.popleft()
-                            reject(r, (
-                                f"request needs {need} KV blocks but "
-                                f"the paged pool holds "
-                                f"{ledger.capacity_blocks}"
-                            ))
+                            carried_map.pop(r.id, None)
                             continue
-                        # Prefix sharing: shared blocks don't leave the
-                        # free list, so admission only needs the
-                        # non-shared tail — a hit can admit where a
-                        # miss would head-of-line wait.
-                        plan = ledger.plan_prefix(r.prompt,
-                                                  total_len=flen)
-                        if not ledger.can_admit(need - plan.use):
-                            # Head-of-line wait: blocks free up when an
-                            # active slot finishes (deterministic FIFO —
-                            # no reorder, no livelock: the whole pool
-                            # covers any single admissible request).
-                            break
-                    queue.popleft()
-                    carried_map.pop(r.id, None)
-                    slot_i = slots.index(None)
-                    tel.emit("request_start", id=r.id, prompt_len=plen,
-                             bucket=bucket, slot=slot_i)
-                    # Re-prefill over (prompt ‖ carried) — the
-                    # loss-free resume primitive, shared with the
-                    # scheduler's preemption path.
-                    padded = np.zeros((1, bucket), np.int32)
-                    padded[0, :plen] = np.asarray(r.prompt, np.int32)
-                    if prior:
-                        padded[0, plen:flen] = np.asarray(
-                            prior, np.int32
+                        try:
+                            bucket = ex.bucket_for(flen)
+                        except ValueError as e:
+                            queue.popleft()
+                            carried_map.pop(r.id, None)
+                            reject(r, str(e))
+                            continue
+                        plan = None
+                        if ledger is not None:
+                            need = ledger.blocks_for(plen, r.max_new_tokens)
+                            if need > ledger.capacity_blocks:
+                                queue.popleft()
+                                reject(r, (
+                                    f"request needs {need} KV blocks but "
+                                    f"the paged pool holds "
+                                    f"{ledger.capacity_blocks}"
+                                ))
+                                continue
+                            # Prefix sharing: shared blocks don't leave the
+                            # free list, so admission only needs the
+                            # non-shared tail — a hit can admit where a
+                            # miss would head-of-line wait.
+                            plan = ledger.plan_prefix(r.prompt,
+                                                      total_len=flen)
+                            if not ledger.can_admit(need - plan.use):
+                                # Head-of-line wait: blocks free up when an
+                                # active slot finishes (deterministic FIFO —
+                                # no reorder, no livelock: the whole pool
+                                # covers any single admissible request).
+                                break
+                        queue.popleft()
+                        carried_map.pop(r.id, None)
+                        slot_i = slots.index(None)
+                        tel.emit("request_start", id=r.id, prompt_len=plen,
+                                 bucket=bucket, slot=slot_i)
+                        # Re-prefill over (prompt ‖ carried) — the
+                        # loss-free resume primitive, shared with the
+                        # scheduler's preemption path.
+                        with _telemetry.span("ff/serve/prefill_dispatch",
+                                             id=r.id, bucket=bucket):
+                            padded = np.zeros((1, bucket), np.int32)
+                            padded[0, :plen] = np.asarray(r.prompt, np.int32)
+                            if prior:
+                                padded[0, plen:flen] = np.asarray(
+                                    prior, np.int32
+                                )
+                            digests = (
+                                prefix_digests(r.prompt, ledger.block)
+                                if ledger is not None and ledger.prefix_cache
+                                else []
+                            )
+                            t0 = time.perf_counter()
+                            pf = rows = okf = None
+                            if plan is not None and plan.full_hit:
+                                # -- ZERO-dispatch admission: the whole prompt
+                                # is resident full blocks and the greedy first
+                                # token is memoized — no prefill program runs
+                                # at all (the prefix-sharing headline).
+                                tok0 = plan.tok0
+                            elif plan is not None and plan.use > 0:
+                                # -- partial hit: gather the shared span from
+                                # the pool, compute only the tail through the
+                                # offset prefill (same fence discipline).
+                                pf = ex.build_prefill_from(
+                                    bucket, plan.offset, sample=self.sample
+                                )
+                                shared_ids = np.asarray(plan.shared, np.int32)
+                                pf_args = (self.params, self.op_state, caches,
+                                           shared_ids, padded, np.int32(flen))
+                            else:
+                                # Sampled runs prefill through the sampled
+                                # variant so a RESUMED position replays the
+                                # decode head's exact draw (greedy when
+                                # flen == plen, i.e. a fresh admission).
+                                pf = ex.build_prefill(bucket,
+                                                      sample=self.sample)
+                                pf_args = (self.params, self.op_state, padded,
+                                           np.int32(flen))
+                            if pf is not None:
+                                if self.sample is not None:
+                                    pf_args += (np.int32(plen), np.int32(r.id))
+                                tel.program_cost("prefill", pf, pf_args,
+                                                 bucket=bucket)
+                                rows, tok0, okf = pf(*pf_args)
+                        if okf is None:
+                            ok, pf_s = True, 0.0
+                            prefix_hits += 1
+                            full_hits += 1
+                            prefill_tokens_saved += plan.offset
+                            tel.emit("prefix_hit", id=r.id,
+                                     blocks=plan.use, full=True,
+                                     tokens_saved=plan.offset)
+                        else:
+                            with _telemetry.span("ff/serve/prefill_fence",
+                                                 id=r.id):
+                                tok0, ok = tel.fence((tok0, okf), "prefill")
+                            pf_s = time.perf_counter() - t0
+                            prefills += 1
+                            if plan is not None and plan.use > 0:
+                                prefix_hits += 1
+                                prefill_tokens_saved += plan.offset
+                                tel.emit("prefill", id=r.id, bucket=bucket,
+                                         offset=plan.offset,
+                                         wall_s=round(pf_s, 6))
+                                tel.emit("prefix_hit", id=r.id,
+                                         blocks=plan.use, full=False,
+                                         tokens_saved=plan.offset)
+                                if plan.cow:
+                                    kv_cows += plan.cow
+                                    tel.emit("kv_cow", id=r.id,
+                                             blocks=plan.cow)
+                            else:
+                                tel.emit("prefill", id=r.id, bucket=bucket,
+                                         wall_s=round(pf_s, 6))
+                        if jr is not None:
+                            jr.admit(r.id, plen,
+                                     int(tok0) if bool(ok) else None,
+                                     resumed=len(prior))
+                        if not bool(ok):
+                            sl = _Slot(r, flen, 0, [], t_run0, pf_s,
+                                       carried=list(prior))
+                            slots[slot_i] = sl
+                            finish(slot_i,
+                                   error="non-finite logits in prefill")
+                            continue
+                        with _telemetry.span("ff/serve/install", id=r.id):
+                            if ledger is not None:
+                                row = ledger.alloc(slot_i, need,
+                                                   shared=plan.shared)
+                                block_table[slot_i] = row
+                                if rows is not None:
+                                    # Masked install: shared entries write
+                                    # their (all-zero) chunks into scratch
+                                    # block 0 — the donor's blocks are never
+                                    # touched; the table row keeps the real
+                                    # shared ids for decode.
+                                    masked = row.copy()
+                                    masked[: plan.use] = 0
+                                    caches = ex.install_paged(caches, rows,
+                                                              masked)
+                                if digests:
+                                    # Index only AFTER the fence validated the
+                                    # install (never make never-written blocks
+                                    # shareable); memoize the first token when
+                                    # the prompt is exactly block-aligned and
+                                    # fresh — the future full-hit upgrade.
+                                    ledger.register_prefix(slot_i, digests,
+                                                           start=plan.use)
+                                    if flen == plen and \
+                                            plen % ledger.block == 0 and \
+                                            not plan.full_hit:
+                                        ledger.record_next(digests[-1],
+                                                           int(tok0))
+                            else:
+                                caches = ex.install(caches, rows, slot_i)
+                            if spec_d:
+                                # Populate the DRAFT model's own cache rows —
+                                # one extra dispatch per admission, priced by
+                                # the latency model's draft_prefill_ms.  No
+                                # fence: nothing to read back, and the next
+                                # spec round synchronizes.
+                                dpf = ex.build_draft_prefill(bucket)
+                                dargs = (self.draft_params, self.op_state,
+                                         padded)
+                                tel.program_cost("draft_prefill", dpf, dargs,
+                                                 bucket=bucket)
+                                drows = dpf(*dargs)
+                                dcaches = ex.install(dcaches, drows, slot_i)
+                                draft_prefills += 1
+                        sl = _Slot(
+                            request=r, pos=flen, last_tok=int(tok0),
+                            tokens=[int(tok0)], t_eligible=t_run0,
+                            prefill_s=pf_s, carried=list(prior),
                         )
-                    digests = (
-                        prefix_digests(r.prompt, ledger.block)
-                        if ledger is not None and ledger.prefix_cache
-                        else []
-                    )
-                    t0 = time.perf_counter()
-                    if plan is not None and plan.full_hit:
-                        # -- ZERO-dispatch admission: the whole prompt
-                        # is resident full blocks and the greedy first
-                        # token is memoized — no prefill program runs
-                        # at all (the prefix-sharing headline).
-                        tok0, ok, rows = plan.tok0, True, None
-                        pf_s = 0.0
-                        prefix_hits += 1
-                        full_hits += 1
-                        prefill_tokens_saved += plan.offset
-                        tel.emit("prefix_hit", id=r.id,
-                                 blocks=plan.use, full=True,
-                                 tokens_saved=plan.offset)
-                    elif plan is not None and plan.use > 0:
-                        # -- partial hit: gather the shared span from
-                        # the pool, compute only the tail through the
-                        # offset prefill (same fence discipline).
-                        pf = ex.build_prefill_from(
-                            bucket, plan.offset, sample=self.sample
-                        )
-                        shared_ids = np.asarray(plan.shared, np.int32)
-                        pf_args = (self.params, self.op_state, caches,
-                                   shared_ids, padded, np.int32(flen))
-                        if self.sample is not None:
-                            pf_args += (np.int32(plen), np.int32(r.id))
-                        tel.program_cost("prefill", pf, pf_args,
-                                         bucket=bucket)
-                        rows, tok0, okf = pf(*pf_args)
-                        tok0, ok = tel.fence((tok0, okf), "prefill")
-                        pf_s = time.perf_counter() - t0
-                        prefills += 1
-                        prefix_hits += 1
-                        prefill_tokens_saved += plan.offset
-                        tel.emit("prefill", id=r.id, bucket=bucket,
-                                 offset=plan.offset,
-                                 wall_s=round(pf_s, 6))
-                        tel.emit("prefix_hit", id=r.id,
-                                 blocks=plan.use, full=False,
-                                 tokens_saved=plan.offset)
-                        if plan.cow:
-                            kv_cows += plan.cow
-                            tel.emit("kv_cow", id=r.id,
-                                     blocks=plan.cow)
-                    else:
-                        # Sampled runs prefill through the sampled
-                        # variant so a RESUMED position replays the
-                        # decode head's exact draw (greedy when
-                        # flen == plen, i.e. a fresh admission).
-                        pf = ex.build_prefill(bucket, sample=self.sample)
-                        pf_args = (self.params, self.op_state, padded,
-                                   np.int32(flen))
-                        if self.sample is not None:
-                            pf_args += (np.int32(plen), np.int32(r.id))
-                        tel.program_cost("prefill", pf, pf_args,
-                                         bucket=bucket)
-                        rows, tok0, okf = pf(*pf_args)
-                        tok0, ok = tel.fence((tok0, okf), "prefill")
-                        pf_s = time.perf_counter() - t0
-                        prefills += 1
-                        tel.emit("prefill", id=r.id, bucket=bucket,
-                                 wall_s=round(pf_s, 6))
-                    if jr is not None:
-                        jr.admit(r.id, plen,
-                                 int(tok0) if bool(ok) else None,
-                                 resumed=len(prior))
-                    if not bool(ok):
-                        sl = _Slot(r, flen, 0, [], t_run0, pf_s,
-                                   carried=list(prior))
+                        total_tokens += 1
                         slots[slot_i] = sl
-                        finish(slot_i,
-                               error="non-finite logits in prefill")
-                        continue
-                    if ledger is not None:
-                        row = ledger.alloc(slot_i, need,
-                                           shared=plan.shared)
-                        block_table[slot_i] = row
-                        if rows is not None:
-                            # Masked install: shared entries write
-                            # their (all-zero) chunks into scratch
-                            # block 0 — the donor's blocks are never
-                            # touched; the table row keeps the real
-                            # shared ids for decode.
-                            masked = row.copy()
-                            masked[: plan.use] = 0
-                            caches = ex.install_paged(caches, rows,
-                                                      masked)
-                        if digests:
-                            # Index only AFTER the fence validated the
-                            # install (never make never-written blocks
-                            # shareable); memoize the first token when
-                            # the prompt is exactly block-aligned and
-                            # fresh — the future full-hit upgrade.
-                            ledger.register_prefix(slot_i, digests,
-                                                   start=plan.use)
-                            if flen == plen and \
-                                    plen % ledger.block == 0 and \
-                                    not plan.full_hit:
-                                ledger.record_next(digests[-1],
-                                                   int(tok0))
-                    else:
-                        caches = ex.install(caches, rows, slot_i)
-                    if spec_d:
-                        # Populate the DRAFT model's own cache rows —
-                        # one extra dispatch per admission, priced by
-                        # the latency model's draft_prefill_ms.  No
-                        # fence: nothing to read back, and the next
-                        # spec round synchronizes.
-                        dpf = ex.build_draft_prefill(bucket)
-                        dargs = (self.draft_params, self.op_state,
-                                 padded)
-                        tel.program_cost("draft_prefill", dpf, dargs,
-                                         bucket=bucket)
-                        drows = dpf(*dargs)
-                        dcaches = ex.install(dcaches, drows, slot_i)
-                        draft_prefills += 1
-                    sl = _Slot(
-                        request=r, pos=flen, last_tok=int(tok0),
-                        tokens=[int(tok0)], t_eligible=t_run0,
-                        prefill_s=pf_s, carried=list(prior),
-                    )
-                    total_tokens += 1
-                    slots[slot_i] = sl
-                    if slot_done(sl):
-                        finish(slot_i)
+                        if slot_done(sl):
+                            finish(slot_i)
 
                 active = [i for i, sl in enumerate(slots)
                           if sl is not None]
@@ -2151,128 +2156,131 @@ class Server:
                     break
 
                 # -- one fused decode superstep over the whole batch --
-                if self.injector is not None:
-                    try:
-                        caches, _nan = self.injector.before_superstep(
-                            superstep_idx, caches, block_table
-                        )
-                    except ServingFault as f:
-                        superstep_idx += 1
-                        if slots[f.slot] is not None:
-                            finish(f.slot, error=f"raised fault: {f}")
-                        continue
-                pos_vec = np.array(
-                    [sl.pos if sl else 0 for sl in slots], np.int32
-                )
-                tok_vec = np.array(
-                    [sl.last_tok if sl else 0 for sl in slots], np.int32
-                )
-                req_vec = None
-                if self.sample is not None:
-                    req_vec = np.array(
-                        [sl.request.id if sl else 0 for sl in slots],
-                        np.int32
+                with _telemetry.span("ff/serve/decode_pack",
+                                     superstep=superstep_idx,
+                                     active=len(active)):
+                    if self.injector is not None:
+                        try:
+                            caches, _nan = self.injector.before_superstep(
+                                superstep_idx, caches, block_table
+                            )
+                        except ServingFault as f:
+                            superstep_idx += 1
+                            if slots[f.slot] is not None:
+                                finish(f.slot, error=f"raised fault: {f}")
+                            continue
+                    pos_vec = np.array(
+                        [sl.pos if sl else 0 for sl in slots], np.int32
                     )
-                t_call = time.perf_counter()
-                if spec_d:
-                    # -- one fused speculative round: d+1 draft steps
-                    # + d+1 verify steps, one dispatch, one fence
-                    # reading (tokens, finite, accepted).
-                    args = (self.params, self.draft_params,
-                            self.op_state, caches, dcaches)
+                    tok_vec = np.array(
+                        [sl.last_tok if sl else 0 for sl in slots], np.int32
+                    )
+                    t_call = time.perf_counter()
+                    args = (self.params, self.draft_params, self.op_state,
+                            caches, dcaches) if spec_d else \
+                        (self.params, self.op_state, caches)
                     if block_table is not None:
                         args += (block_table.copy(),)
                     args += (pos_vec, tok_vec)
-                    if req_vec is not None:
-                        args += (req_vec,)
-                    tel.program_cost("spec_verify", spec_fn, args,
-                                     d=spec_d)
-                    caches, dcaches, _pos, _tok, (toks, oks, acc) = \
-                        spec_fn(*args)
-                    host_toks, host_oks, host_acc = tel.fence(
-                        (toks, oks, acc), "spec_verify"
-                    )
-                    k_eff = spec_d + 1
-                else:
-                    args = (self.params, self.op_state, caches)
-                    if block_table is not None:
-                        args += (block_table.copy(),)
-                    args += (pos_vec, tok_vec)
-                    if req_vec is not None:
-                        args += (req_vec,)
-                    tel.program_cost("decode_superstep", decode_fn,
-                                     args, k=k)
-                    caches, _pos, _tok, (toks, oks) = decode_fn(*args)
-                    host_toks, host_oks = tel.fence(
-                        (toks, oks), "decode_superstep"
-                    )
-                    host_acc = None
-                    k_eff = k
-                wall = time.perf_counter() - t_call
-                decode_s += wall
-                supersteps += 1
-                superstep_idx += 1
-                # Training-superstep accounting: ONE host program and
-                # one fence covered k_eff decode steps (programs/step
-                # == 1/k_eff).
-                tel.add_programs(1, steps=k_eff)
-                # `slots`: per-superstep occupancy by request id — the
-                # span layer's decode attribution (this loop carries no
-                # vclock stamps; ids still tell WHO was in the batch
-                # each dispatch).  Captured before finish() frees slots.
-                occ = [slots[i].request.id for i in active]
-                if not spec_d:
-                    tel.emit("decode_superstep", k=k, active=len(active),
-                             slots=occ, wall_s=round(wall, 6))
-                for j in range(k_eff):
-                    tel.record_step((supersteps - 1) * k_eff + j,
-                                    wall_s=wall / k_eff)
-                n_active = len(active)
-                emitted_round = 0
-                for i in active:
-                    sl = slots[i]
-                    err = None
-                    appended: List[int] = []
+                    if self.sample is not None:
+                        args += (np.array(
+                            [sl.request.id if sl else 0 for sl in slots],
+                            np.int32
+                        ),)
+                with _telemetry.span("ff/serve/decode_dispatch",
+                                     superstep=superstep_idx):
                     if spec_d:
-                        n_take = int(host_acc[i]) + 1
-                        spec_accept_total += int(host_acc[i])
+                        # -- one fused speculative round: d+1 draft steps
+                        # + d+1 verify steps, one dispatch, one fence
+                        # reading (tokens, finite, accepted).
+                        tel.program_cost("spec_verify", spec_fn, args,
+                                         d=spec_d)
+                        caches, dcaches, _pos, _tok, fetch = spec_fn(*args)
                     else:
-                        n_take = k
-                    for j in range(n_take):
-                        if not bool(host_oks[j, i]):
-                            err = "non-finite logits in decode"
-                            break
-                        tok = int(host_toks[j, i])
-                        sl.tokens.append(tok)
-                        appended.append(tok)
-                        sl.pos += 1
-                        total_tokens += 1
-                        if slot_done(sl):
-                            break
-                    sl.last_tok = sl.tokens[-1] if sl.tokens else 0
-                    decode_tokens += len(appended)
-                    emitted_round += len(appended)
-                    # Journal the fence-validated delta BEFORE any done
-                    # record so replay accumulation sees tokens first —
-                    # under speculation, ``appended`` holds ACCEPTED
-                    # tokens only (rejected draft never reaches the
-                    # host), so resume semantics are unchanged.
-                    if jr is not None and appended:
-                        jr.tokens(sl.request.id, appended)
-                    if err is not None:
-                        finish(i, error=err)
-                    elif slot_done(sl):
-                        finish(i)
-                if spec_d:
-                    acc_round = int(sum(
-                        int(host_acc[i]) for i in active
-                    ))
-                    spec_draft_total += spec_d * n_active
-                    tel.emit("spec_verify", d=spec_d, active=n_active,
-                             accepted=acc_round,
-                             draft=spec_d * n_active,
-                             emitted=emitted_round, slots=occ,
-                             wall_s=round(wall, 6))
+                        tel.program_cost("decode_superstep", decode_fn,
+                                         args, k=k)
+                        caches, _pos, _tok, fetch = decode_fn(*args)
+                with _telemetry.span("ff/serve/decode_fence",
+                                     superstep=superstep_idx):
+                    if spec_d:
+                        host_toks, host_oks, host_acc = tel.fence(
+                            fetch, "spec_verify"
+                        )
+                        k_eff = spec_d + 1
+                    else:
+                        host_toks, host_oks = tel.fence(
+                            fetch, "decode_superstep"
+                        )
+                        host_acc = None
+                        k_eff = k
+                with _telemetry.span("ff/serve/bookkeep",
+                                     superstep=superstep_idx):
+                    wall = time.perf_counter() - t_call
+                    decode_s += wall
+                    supersteps += 1
+                    superstep_idx += 1
+                    # Training-superstep accounting: ONE host program and
+                    # one fence covered k_eff decode steps (programs/step
+                    # == 1/k_eff).
+                    tel.add_programs(1, steps=k_eff)
+                    # `slots`: per-superstep occupancy by request id — the
+                    # span layer's decode attribution (this loop carries no
+                    # vclock stamps; ids still tell WHO was in the batch
+                    # each dispatch).  Captured before finish() frees slots.
+                    occ = [slots[i].request.id for i in active]
+                    if not spec_d:
+                        tel.emit("decode_superstep", k=k, active=len(active),
+                                 capacity=B, slots=occ,
+                                 wall_s=round(wall, 6))
+                    for j in range(k_eff):
+                        tel.record_step((supersteps - 1) * k_eff + j,
+                                        wall_s=wall / k_eff)
+                    n_active = len(active)
+                    emitted_round = 0
+                    for i in active:
+                        sl = slots[i]
+                        err = None
+                        appended: List[int] = []
+                        if spec_d:
+                            n_take = int(host_acc[i]) + 1
+                            spec_accept_total += int(host_acc[i])
+                        else:
+                            n_take = k
+                        for j in range(n_take):
+                            if not bool(host_oks[j, i]):
+                                err = "non-finite logits in decode"
+                                break
+                            tok = int(host_toks[j, i])
+                            sl.tokens.append(tok)
+                            appended.append(tok)
+                            sl.pos += 1
+                            total_tokens += 1
+                            if slot_done(sl):
+                                break
+                        sl.last_tok = sl.tokens[-1] if sl.tokens else 0
+                        decode_tokens += len(appended)
+                        emitted_round += len(appended)
+                        # Journal the fence-validated delta BEFORE any done
+                        # record so replay accumulation sees tokens first —
+                        # under speculation, ``appended`` holds ACCEPTED
+                        # tokens only (rejected draft never reaches the
+                        # host), so resume semantics are unchanged.
+                        if jr is not None and appended:
+                            jr.tokens(sl.request.id, appended)
+                        if err is not None:
+                            finish(i, error=err)
+                        elif slot_done(sl):
+                            finish(i)
+                    if spec_d:
+                        acc_round = int(sum(
+                            int(host_acc[i]) for i in active
+                        ))
+                        spec_draft_total += spec_d * n_active
+                        tel.emit("spec_verify", d=spec_d, active=n_active,
+                                 accepted=acc_round,
+                                 draft=spec_d * n_active,
+                                 emitted=emitted_round, slots=occ,
+                                 wall_s=round(wall, 6))
         finally:
             preempt.__exit__(None, None, None)
             if jr is not None:

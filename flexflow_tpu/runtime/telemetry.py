@@ -150,6 +150,18 @@ def current():
     return _current if _current is not None else NULL
 
 
+def span(name: str, **kw):
+    """Open a host span named from ``obs/events.py::SPAN_CATALOG``: a
+    ``jax.profiler.TraceAnnotation``, so it lands in the profiler's
+    ``.xplane.pb`` beside the device operations, on their clock, and
+    costs a check of the profiler's flag when no profile is running.
+    ``kw`` (``id``, ``bucket``, ``superstep``, ``active``) go on as the
+    annotation's keywords and join the trace to the JSONL stream.  A
+    module function, not a :class:`Telemetry` method: a span exists in
+    a traced run whether or not a stream is open."""
+    return jax.profiler.TraceAnnotation(name, **kw)
+
+
 def process_tag() -> str:
     """``-p<N>`` when this process is one of a multi-host world
     (``JAX_PROCESS_ID`` set — the elastic rig, TPU pods), else empty:
@@ -478,10 +490,10 @@ class Telemetry:
                        kind, e)
 
     def attach_trace_summary(self, log_dir: str) -> None:
-        """Fold device-time attribution from an XProf perfetto trace
-        (``--trace DIR`` + telemetry together) into the coming
-        ``run_end`` — the ROADMAP XProf follow-on.  Parsing failures
-        warn and attach nothing."""
+        """Fold device-time attribution from an XProf trace's
+        ``.xplane.pb`` (``--trace DIR`` + telemetry together) into the
+        coming ``run_end``.  Reading failures warn and attach
+        nothing."""
         from flexflow_tpu.obs.trace import summarize_trace_dir
 
         summary = summarize_trace_dir(log_dir)

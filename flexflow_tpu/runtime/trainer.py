@@ -245,10 +245,7 @@ class Trainer:
                 # per-task cudaEvent prints could not give).
                 from flexflow_tpu.runtime.profiler import trace
 
-                # perfetto sidecar only when telemetry will consume it
-                # (the run_end trace_summary attribution, obs/trace.py).
-                trace_ctx = trace(ex.config.trace_dir,
-                                  perfetto=tel.enabled)
+                trace_ctx = trace(ex.config.trace_dir)
             ckpt_s = 0.0  # checkpoint I/O time, excluded from throughput
             with trace_ctx:
                 # Both timestamps live INSIDE the trace context so neither
@@ -323,8 +320,8 @@ class Trainer:
                 elapsed = time.perf_counter() - start - ckpt_s
 
             if ex.config.trace_dir and tel.enabled:
-                # Device-time attribution: parse the perfetto trace the
-                # block above just wrote into run_end's trace_summary.
+                # Device-time attribution: read the .xplane.pb the block
+                # above just wrote into run_end's trace_summary.
                 tel.attach_trace_summary(ex.config.trace_dir)
             self.metrics.update(final_m)
             if checkpoint is not None:
@@ -511,8 +508,7 @@ class Trainer:
             if ex.config.trace_dir:
                 from flexflow_tpu.runtime.profiler import trace
 
-                trace_ctx = trace(ex.config.trace_dir,
-                                  perfetto=tel.enabled)
+                trace_ctx = trace(ex.config.trace_dir)
             ckpt_s = 0.0
             timed = plan[warm_calls:]
             steps_done = 0
@@ -720,8 +716,7 @@ class Trainer:
             if ex.config.trace_dir:
                 from flexflow_tpu.runtime.profiler import trace
 
-                trace_ctx = trace(ex.config.trace_dir,
-                                  perfetto=tel.enabled)
+                trace_ctx = trace(ex.config.trace_dir)
             ckpt_s = 0.0
             steps_done = 0
             supersteps = 0

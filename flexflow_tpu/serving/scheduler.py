@@ -248,7 +248,8 @@ class SlotShape:
 class _RealEngine:
     """Device-backed engine: the ServingExecutor program families,
     with the legacy loop's telemetry discipline (program_cost at call
-    sites, labeled fences)."""
+    sites, labeled fences, and ``Server.run``'s dispatch and fence
+    span names at the same calls)."""
 
     simulated = False
 
@@ -299,9 +300,12 @@ class _RealEngine:
         if self.sample is not None:
             pf_args += (np.int32(flen if plen is None else plen),
                         np.int32(rid))
-        tel.program_cost("prefill", pf, pf_args, bucket=bucket)
-        rows, tok0, okf = pf(*pf_args)
-        tok0, ok = tel.fence((tok0, okf), "prefill")
+        with _telemetry.span("ff/serve/prefill_dispatch", id=rid,
+                             bucket=bucket):
+            tel.program_cost("prefill", pf, pf_args, bucket=bucket)
+            rows, tok0, okf = pf(*pf_args)
+        with _telemetry.span("ff/serve/prefill_fence", id=rid):
+            tok0, ok = tel.fence((tok0, okf), "prefill")
         wall = time.perf_counter() - t0
         if bool(ok):
             if row is not None:
@@ -324,9 +328,12 @@ class _RealEngine:
         if self.sample is not None:
             args += (np.asarray(req_ids, np.int32),)
         t0 = time.perf_counter()
-        tel.program_cost("decode_superstep", fn, args, k=k)
-        self.caches, _pos, _tok, (toks, oks) = fn(*args)
-        host_toks, host_oks = tel.fence((toks, oks), "decode_superstep")
+        with _telemetry.span("ff/serve/decode_dispatch"):
+            tel.program_cost("decode_superstep", fn, args, k=k)
+            self.caches, _pos, _tok, (toks, oks) = fn(*args)
+        with _telemetry.span("ff/serve/decode_fence"):
+            host_toks, host_oks = tel.fence((toks, oks),
+                                            "decode_superstep")
         return host_toks, host_oks, time.perf_counter() - t0
 
     def draft_prefill(self, prompt: np.ndarray, bucket: int,
@@ -341,8 +348,9 @@ class _RealEngine:
         t0 = time.perf_counter()
         dpf = ex.build_draft_prefill(bucket)
         dargs = (self.draft_params, self.op_state, padded)
-        tel.program_cost("draft_prefill", dpf, dargs, bucket=bucket)
-        drows = dpf(*dargs)
+        with _telemetry.span("ff/serve/prefill_dispatch", bucket=bucket):
+            tel.program_cost("draft_prefill", dpf, dargs, bucket=bucket)
+            drows = dpf(*dargs)
         self.dcaches = ex.install(self.dcaches, drows, slot_i)
         return time.perf_counter() - t0
 
@@ -362,12 +370,14 @@ class _RealEngine:
         if self.sample is not None:
             args += (np.asarray(req_ids, np.int32),)
         t0 = time.perf_counter()
-        tel.program_cost("spec_verify", fn, args, d=d)
-        self.caches, self.dcaches, _pos, _tok, (toks, oks, acc) = \
-            fn(*args)
-        host_toks, host_oks, host_acc = tel.fence(
-            (toks, oks, acc), "spec_verify"
-        )
+        with _telemetry.span("ff/serve/decode_dispatch"):
+            tel.program_cost("spec_verify", fn, args, d=d)
+            self.caches, self.dcaches, _pos, _tok, (toks, oks, acc) = \
+                fn(*args)
+        with _telemetry.span("ff/serve/decode_fence"):
+            host_toks, host_oks, host_acc = tel.fence(
+                (toks, oks, acc), "spec_verify"
+            )
         return host_toks, host_oks, host_acc, time.perf_counter() - t0
 
 

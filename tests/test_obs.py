@@ -17,12 +17,11 @@ What is pinned here:
 - **Catalog sync**: fflint FF008's dependency-free event-name copy
   must equal ``obs.events.EVENT_CATALOG`` (same precedent as
   FUSED_STEPS_CAP).
-- **Attribution**: a synthetic perfetto trace summarizes to exact
-  device-ms numbers; a real ``--trace`` + ``--telemetry`` run folds a
+- **Attribution**: a synthetic ``.xplane.pb`` summarizes to exact
+  device-ms numbers by kernel, scope and host span; a real ``--trace`` + ``--telemetry`` run folds a
   ``trace_summary`` block and ``program_cost`` events into its log.
 """
 
-import gzip
 import io
 import json
 import os
@@ -38,7 +37,12 @@ from flexflow_tpu.obs.compare import (
     compare_runs,
     paired_measure,
 )
-from flexflow_tpu.obs.events import EVENT_CATALOG
+from flexflow_tpu.obs.events import (
+    EVENT_CATALOG,
+    KERNEL_CATALOG,
+    SCOPE_CATALOG,
+    SPAN_CATALOG,
+)
 from flexflow_tpu.obs.reader import RunLog, latest_run, resolve_run, run_files
 from flexflow_tpu.obs.registry import (
     box_fingerprint,
@@ -47,7 +51,7 @@ from flexflow_tpu.obs.registry import (
     history,
     index_path,
 )
-from flexflow_tpu.obs.trace import find_perfetto_trace, summarize_trace_dir
+from flexflow_tpu.obs.trace import summarize_trace_dir
 from flexflow_tpu.optim import SGDOptimizer
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.telemetry import Telemetry
@@ -119,6 +123,38 @@ def test_ff008_catalog_matches_event_catalog():
     assert not lint_source(bad, "flexflow_tpu/runtime/telemetry.py")
     assert not lint_source('tel.emit(name, x=1)\n',
                            "flexflow_tpu/runtime/foo.py")
+
+
+@pytest.mark.parametrize("copy,catalog,bad,ok,dynamic", [
+    ("FF008_SPAN_NAMES", "SPAN_CATALOG",
+     '_telemetry.span("ff/serve/made_up", id=1)\n',
+     'with telemetry.span("ff/serve/admit", id=1):\n    pass\n',
+     '_telemetry.span(name, id=1)\n'),
+    ("FF008_KERNEL_NAMES", "KERNEL_CATALOG",
+     'pl.pallas_call(k, name="ff_made_up", grid=(1,))\n',
+     'pl.pallas_call(k, name="ff_flash_fwd", grid=(1,))\n',
+     'pl.pallas_call(k, name=n, grid=(1,))\n'),
+    ("FF008_SCOPE_NAMES", "SCOPE_CATALOG",
+     'with jax.named_scope("ff_made_up"):\n    pass\n',
+     'with jax.named_scope("ff_opt"), jax.named_scope("blk0_attn"):\n'
+     '    pass\n',
+     'with jax.named_scope(op.name):\n    pass\n'),
+])
+def test_ff008_trace_name_catalogs(copy, catalog, bad, ok, dynamic):
+    """The same pin and the same rule for the three kinds of name a
+    profiler trace is read by; a kernel library or the telemetry
+    module is no exemption, a dynamic name is."""
+    from flexflow_tpu.analysis import lint
+    from flexflow_tpu.obs import events
+
+    assert getattr(lint, copy) == getattr(events, catalog)
+    def ff008(src, path):
+        return [v for v in lint.lint_source(src, path) if v.rule == "FF008"]
+
+    for path in ("flexflow_tpu/ops/pallas_kernels.py",
+                 "flexflow_tpu/runtime/telemetry.py"):
+        assert len(ff008(bad, path)) == 1
+        assert not ff008(ok, path) and not ff008(dynamic, path)
 
 
 # -- reader ----------------------------------------------------------------
@@ -336,59 +372,86 @@ def test_fingerprint_diff():
 # -- device-time attribution ----------------------------------------------
 
 
-def _write_perfetto(tmp_path, events):
+#: A TPU trace in small: six device operations over 60 us (a named
+#: kernel, the loss's forward and transpose inside a ``while`` that only
+#: holds them, a merged optimizer fusion whose scope is given by
+#: reference, a copy with no scope), and ``Server.run``'s spans round the
+#: gaps [10,14) [30,34) and [50,52).
+_XSPACE = """
+planes { id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 6 offset_ps: 14000000 duration_ps: 16000000 }
+    events { metadata_id: 2 offset_ps: 14000000 duration_ps: 8000000 }
+    events { metadata_id: 3 offset_ps: 22000000 duration_ps: 8000000 }
+    events { metadata_id: 4 offset_ps: 34000000 duration_ps: 16000000 }
+    events { metadata_id: 5 offset_ps: 52000000 duration_ps: 8000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%ff_flash_decode.7 = bf16[2,2,8]{2,1,0} custom-call(s32[2]{0} %p.1)"
+    stats { metadata_id: 1 str_value: "jit(decode)/blk0_attn/ff_flash_decode/pallas_call:" } } }
+  event_metadata { key: 2 value { id: 2 name: "%convert_reduce_fusion = f32[8]{0} fusion(bf16[8,64]{1,0} %p.2), kind=kLoop"
+    stats { metadata_id: 1 str_value: "jit(train_step)/jvp(ff_loss)/softmax/reduce_max:" } } }
+  event_metadata { key: 3 value { id: 3 name: "%convert_subtract_fusion = f32[8,64]{1,0} fusion(bf16[8,64]{1,0} %p.2), kind=kLoop"
+    stats { metadata_id: 1 str_value: "jit(train_step)/transpose(jvp(ff_loss))/softmax/sub:" } } }
+  event_metadata { key: 4 value { id: 4 name: "%fusion.7 = f32[8,16]{1,0} fusion(f32[8,16]{1,0} %p.3), kind=kLoop"
+    stats { metadata_id: 1 ref_value: 2 } } }
+  event_metadata { key: 5 value { id: 5 name: "%copy.9 = f32[8,16]{0,1} copy(f32[8,16]{1,0} %fusion.7)" } }
+  event_metadata { key: 6 value { id: 6 name: "%while.2 = (s32[], f32[8,64]{1,0}) while((s32[], f32[8,64]{1,0}) %t.3), body=%b.1"
+    stats { metadata_id: 1 str_value: "jit(train_step)/jvp(ff_loss)/softmax/while:" } } }
+  stat_metadata { key: 1 value { id: 1 name: "tf_op" } }
+  stat_metadata { key: 2 value { id: 2 name: "jit(train_step)/ff_opt/mul;jit(train_step)/ff_opt/add:" } } }
+planes { id: 2 name: "/host:CPU"
+  lines { id: 1 name: "main" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 8000000 duration_ps: 28000000 }
+    events { metadata_id: 2 offset_ps: 9000000 duration_ps: 4000000 }
+    events { metadata_id: 3 offset_ps: 31500000 duration_ps: 3500000 }
+    events { metadata_id: 4 offset_ps: 38000000 duration_ps: 11000000 }
+    events { metadata_id: 5 offset_ps: 49000000 duration_ps: 1500000 } }
+  event_metadata { key: 1 value { id: 1 name: "ff/serve/admit" } }
+  event_metadata { key: 2 value { id: 2 name: "ff/serve/prefill_dispatch" } }
+  event_metadata { key: 3 value { id: 3 name: "ff/serve/install" } }
+  event_metadata { key: 4 value { id: 4 name: "ff/serve/decode_fence" } }
+  event_metadata { key: 5 value { id: 5 name: "$not/a/span" } } }
+"""
+
+
+def _write_xplane(tmp_path, text=_XSPACE):
+    from jax.profiler import ProfileData
+
     d = tmp_path / "plugins" / "profile" / "20250101"
     d.mkdir(parents=True)
-    path = str(d / "perfetto_trace.json.gz")
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    return path
+    path = d / "host.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    return str(path)
 
 
 def test_trace_summary_synthetic_exact(tmp_path):
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 1,
-         "args": {"name": "tf_XLATfrtCpuClient"}},  # device stand-in
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 2,
-         "args": {"name": "main"}},
-        # Two StepTraceAnnotation windows (host lane, step_num arg).
-        {"ph": "X", "name": "train", "pid": 1, "tid": 2, "ts": 0,
-         "dur": 1000, "args": {"step_num": 0}},
-        {"ph": "X", "name": "train", "pid": 1, "tid": 2, "ts": 2000,
-         "dur": 1000, "args": {"step_num": 1}},
-        # Device ops: two fusions, a copy, an infra scope.
-        {"ph": "X", "name": "fusion", "pid": 1, "tid": 1, "ts": 100,
-         "dur": 300},
-        {"ph": "X", "name": "fusion", "pid": 1, "tid": 1, "ts": 2100,
-         "dur": 200},
-        {"ph": "X", "name": "copy", "pid": 1, "tid": 1, "ts": 500,
-         "dur": 100},
-        {"ph": "X", "name": "Foo::Bar", "pid": 1, "tid": 1, "ts": 600,
-         "dur": 50},
-        # Host-lane op: never device time.
-        {"ph": "X", "name": "hostwork", "pid": 1, "tid": 2, "ts": 700,
-         "dur": 500},
-    ]
-    path = _write_perfetto(tmp_path, events)
-    assert find_perfetto_trace(str(tmp_path)) == path
+    path = _write_xplane(tmp_path)
     s = summarize_trace_dir(str(tmp_path))
-    # Totals include infra device events; the op table excludes them.
-    assert s["device_ms_total"] == pytest.approx(0.65)
-    assert s["top_ops"] == [
-        {"op": "fusion", "device_ms": 0.5, "count": 2},
-        {"op": "copy", "device_ms": 0.1, "count": 1},
-    ]
-    # Host/device split per annotation: ops attributed to the window
-    # containing their start ts.
-    ann = s["annotations"]["train"]
-    assert ann["count"] == 2
-    assert ann["host_ms"] == pytest.approx(2.0)
-    assert ann["device_ms"] == pytest.approx(0.65)
+    assert s["trace_file"] == path
+    # busy: [0,10) [14,30) [34,50) [52,60) of a window of 60 us
+    assert s["device_ms_total"] == pytest.approx(0.05)
+    assert s["window_ms"] == pytest.approx(0.06)
+    assert s["kernels"] == {"ff_flash_decode": {"device_ms": 0.01, "count": 1}}
+    # The while is left out (its two children are counted); the merged
+    # fusion's scope came by reference; the copy has none.
+    assert s["scopes"] == {"ff_loss": 0.016, "ff_opt": 0.016}
+    # [10,14) under prefill_dispatch (inside admit), [30,34) under
+    # install, [50,52): its middle lies past decode_fence's end.
+    assert s["idle_ms_by_span"] == {
+        "ff/serve/prefill_dispatch": 0.004, "ff/serve/install": 0.004,
+        "<none>": 0.002,
+    }
+    assert set(s["idle_ms_by_span"]) - {"<none>"} <= SPAN_CATALOG
+    assert set(s["kernels"]) <= KERNEL_CATALOG
+    assert set(s["scopes"]) <= SCOPE_CATALOG
 
 
 def test_trace_summary_absent_is_none(tmp_path):
+    assert summarize_trace_dir(str(tmp_path)) is None
+    # ... and one that cannot be read warns and attaches nothing.
+    d = tmp_path / "plugins" / "profile" / "x"
+    d.mkdir(parents=True)
+    (d / "bad.xplane.pb").write_bytes(b"\x0a\xff\xff")
     assert summarize_trace_dir(str(tmp_path)) is None
 
 
@@ -408,9 +471,10 @@ def test_trace_and_program_cost_end_to_end(tmp_path):
     assert c["flops"] > 0 and c["bytes_accessed"] > 0
     ts = log.trace_summary()
     assert ts, "run_end must carry trace_summary for a traced tel run"
-    assert ts["device_ms_total"] >= 0
-    assert "train" in ts["annotations"]
-    assert ts["annotations"]["train"]["count"] >= 3  # timed steps
+    assert ts["trace_file"].endswith(".xplane.pb")
+    # A CPU run has no device plane: nothing is written as device time.
+    assert ts["device_ms_total"] == 0.0 and ts["kernels"] == {}
+    assert ts["scopes"] == {} and ts["idle_ms_by_span"] == {}
 
 
 def test_superstep_program_cost(tmp_path):

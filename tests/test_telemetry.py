@@ -250,6 +250,65 @@ def test_telemetry_off_is_bit_identical():
     assert tel.counts["fences"] == 2
 
 
+@pytest.fixture(scope="module")
+def tiny_server():
+    from flexflow_tpu.models.transformer import build_transformer_lm
+    from flexflow_tpu.runtime.serving import Server, ServingExecutor
+    from flexflow_tpu.serving import uniform_workload
+
+    lm = build_transformer_lm(batch_size=2, seq_len=16, vocab_size=64,
+                              d_model=32, num_heads=2, num_layers=2,
+                              config=FFConfig(batch_size=2))
+    sex = ServingExecutor(lm, max_batch=2, max_seq=16, buckets=(8, 16),
+                          decode_kernel=False)
+    params, state = sex.init(seed=0)
+    reqs = uniform_workload(5, 64, prompt_len=(3, 6), max_new_tokens=6,
+                            seed=5)
+    srv = Server(sex, params, state, decode_steps=4)
+    # The baseline: no stream, no profile (and every program built).
+    res, st = srv.run(reqs)
+    return (srv, reqs, {i: r.tokens for i, r in res.items()},
+            st["prefills"] + st["decode_supersteps"])
+
+
+@pytest.mark.parametrize("stream,profile", [
+    (False, False), (True, False), (False, True), (True, True)])
+def test_serving_spans_add_no_fence_and_change_no_token(
+        tiny_server, stream, profile, tmp_path, monkeypatch):
+    """The same pin for the serving loop and its ``ff/serve/*`` spans:
+    with a profile running or not, with a stream open or not, the
+    tokens are the same bit for bit and the loop fences exactly once
+    an admission and once a superstep."""
+    import contextlib
+
+    import jax
+
+    srv, reqs, want_tokens, want_fences = tiny_server
+    real, calls = jax.device_get, []
+    monkeypatch.setattr(jax, "device_get",
+                        lambda v: (calls.append(1), real(v))[1])
+    tel = Telemetry(str(tmp_path / "tel")) if stream \
+        else contextlib.nullcontext()
+    if profile:
+        jax.profiler.start_trace(str(tmp_path / "xprof"))
+    try:
+        with tel:
+            res, st = srv.run(reqs)
+    finally:
+        if profile:
+            jax.profiler.stop_trace()
+    assert {i: r.tokens for i, r in res.items()} == want_tokens
+    assert len(calls) == want_fences
+    assert st["prefills"] + st["decode_supersteps"] == want_fences
+    if stream:
+        assert st["telemetry"]["fences"] == want_fences
+        sup = [e for e in _events(tel.path)
+               if e["ev"] == "decode_superstep"]
+        assert sup and all(e["capacity"] == 2 >= e["active"] for e in sup)
+    else:
+        assert "telemetry" not in st
+
+
 def test_null_telemetry_fence_is_device_get():
     import jax.numpy as jnp
 
