@@ -29,6 +29,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 import sys
 import time
 import traceback
@@ -88,8 +89,9 @@ FULL = Sizes(
     # 4 layers), named so the log shows them.
     transformer=("-b", "8", "--seq", "512", *_LM_SHAPE,
                  "--dtype", "bfloat16", "-i", "10"),
-    # README's DLRM shape (run_random.sh), under plain SGD: the
-    # row-sparse path — the one that reaches the Pallas row kernels —
+    # README's DLRM shape (4 of run_random.sh's 8 tables, at its 1M
+    # rows: 7812 whole 128-row blocks and an edge block of 64), under
+    # plain SGD: the row-sparse path — the one that reaches the Pallas row kernels —
     # is exact only without momentum and weight decay, and the
     # executor keeps the CLI's defaults (0.9, 1e-4) on the dense path.
     dlrm=("-b", "1024", "-i", "10", "--momentum", "0", "--wd", "0",
@@ -130,6 +132,31 @@ def has_mosaic_call(compiled_text: str) -> bool:
     proof that a Pallas call neither routed to jnp nor ran under the
     interpreter (which lowers to plain HLO)."""
     return "tpu_custom_call" in compiled_text
+
+
+_RELAYOUT_OPS = ("copy", "copy-start", "reshape", "transpose", "fusion")
+_HLO_SHAPE = r"\w+\[[\d,]*\](?:\{[^}]*\})?"
+_HLO_INSTRUCTION = re.compile(
+    rf"^\s*(?:ROOT\s+)?%\S+ = (\(?{_HLO_SHAPE}(?:, {_HLO_SHAPE})*\)?) ([\w-]+)\(")
+
+
+def table_sized_relayouts(compiled_text: str, elements: int) -> List[str]:
+    """The instructions of an optimised HLO text that move a whole
+    table: a ``copy``, ``reshape``, ``transpose`` or fusion with a
+    result of ``elements`` elements.  A ``bitcast`` moves nothing and
+    the aliased scatter call is the update itself; what this names is
+    a view of the table that is not the order the chip stores it in,
+    paid for on every step (PERF.md §6, PR 28)."""
+    found = []
+    for line in compiled_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or m.group(2) not in _RELAYOUT_OPS:
+            continue
+        sizes = (math.prod(int(x) for x in dims.split(",") if x)
+                 for dims in re.findall(r"\[([\d,]*)\]", m.group(1)))
+        if elements in sizes:
+            found.append(line.strip()[:160])
+    return found
 
 
 # -- plumbing -----------------------------------------------------------------
@@ -255,6 +282,12 @@ def train_phase(phase: str, app, argv: Sequence[str], kernels: bool = False):
         check(has_mosaic_call(text),
               f"{phase}: no Pallas kernel in the compiled train step "
               f"(a *_supported gate routed to jnp, or interpret mode)")
+        for op in trainer.ex._sparse_ops:
+            for key in op.sparse_keys():
+                moved = table_sized_relayouts(
+                    text, math.prod(op.param_specs()[key].shape))
+                check(not moved, f"{phase}: the compiled step moves the "
+                      f"whole of {op.name}/{key} every step: {moved}")
     info(phase,
          setup_s=f"{wall - stats['elapsed_s']:.1f}",
          step_ms=_ms(stats["elapsed_s"] / stats["iterations"]),

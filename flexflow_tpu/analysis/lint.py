@@ -306,6 +306,7 @@ FF008_EVENT_NAMES = frozenset({
     "run_start", "run_end",
     "step", "input_wait", "superstep", "fence", "compiled_step",
     "program_cost", "embedding_gather", "embedding_combine",
+    "embedding_rows",
     "ckpt_save", "ckpt_restore", "ckpt_torn",
     "fault", "rollback", "replay", "preempt",
     "stall", "stall_recovered",
@@ -521,10 +522,12 @@ def iter_python_files(root: Optional[str] = None) -> List[str]:
     for dirpath, dirnames, filenames in os.walk(root):
         dirnames[:] = [
             d for d in dirnames
-            # ``chiprun_out``/``_checkout``: the chip tool's output and
-            # the git-archive proof copy (.gitignore lists both).
+            # ``chiprun_out``, ``_checkout``/``_parent``/``_scratch``:
+            # the chip tool's output, the git-archive copies of this
+            # tree and its parent, a builder's probes (.gitignore
+            # lists all four).
             if d not in ("__pycache__", ".git", ".claude", "ckpts",
-                         "chiprun_out", "_checkout")
+                         "chiprun_out", "_checkout", "_parent", "_scratch")
         ]
         for f in filenames:
             if f.endswith(".py"):

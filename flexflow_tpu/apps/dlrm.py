@@ -5,7 +5,8 @@ of the common FFConfig surface, places embedding tables with the
 reference's table-parallel strategy by default, and prints the
 ``THROUGHPUT = ... samples/s`` line (``dlrm.cc:165-166``).
 
-Example (the run_random.sh benchmark shape)::
+Example (README's shape: 4 of ``run_random.sh``'s 8 tables, at its
+widths)::
 
     python -m flexflow_tpu.apps.dlrm -b 1024 -i 20 \
         --arch-sparse-feature-size 64 \

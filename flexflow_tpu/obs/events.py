@@ -37,6 +37,7 @@ EVENT_CATALOG = frozenset({
     "program_cost",
     "embedding_gather",
     "embedding_combine",
+    "embedding_rows",
     # checkpoint / resilience
     "ckpt_save",
     "ckpt_restore",

@@ -174,66 +174,76 @@ def _sharded_scatter_add(op: Op, table, flat_ids, upd, shard):
     )(table, flat_ids, upd)
 
 
-def _row_kernels_ok(op: Op, n_ids: int, table, kind: str = "scatter") -> bool:
-    """Use the Pallas row-DMA kernels (pallas_kernels.gather_rows /
-    scatter_add_rows): XLA's TPU lowering of gather/scatter over a big
-    table is a full-table sweep, the kernels touch only the addressed
-    rows.  Single-device TPU only (under GSPMD sharding the jnp path
-    lets the partitioner place the op), and only outside autodiff —
-    jax has no AD rule for scalar-prefetch pallas_call, so ONLY the
-    executor's sparse protocol (never ``forward``) may dispatch here.
-    """
+def _row_addressing(op: Op, n_ids: int, table, kind: str) -> str:
+    """How the executor's sparse protocol reaches ``table``'s rows:
+    ``"lane_major"`` or ``"row_major"`` — the Pallas row kernels
+    (pallas_kernels.gather_rows / scatter_add_rows) in the order the
+    chip stores a table of this shape — or ``"xla"``.  XLA's TPU
+    lowering of gather/scatter over a big table is a full-table sweep,
+    the kernels touch only the addressed rows.  Single-device TPU only
+    (under GSPMD sharding the jnp path lets the partitioner place the
+    op), and only outside autodiff — jax has no AD rule for
+    scalar-prefetch pallas_call, so ONLY the executor's sparse
+    protocol (never ``forward``) may dispatch here.  Announced once an
+    op at build (``embedding_rows``)."""
     import jax
+    from flexflow_tpu.ops import pallas_kernels as pk
 
-    if jax.default_backend() != "tpu":
-        return False
     plan = getattr(op, "_plan", None)
-    if plan is not None and plan.num_devices > 1:
-        return False
     rows = 1
     for s in table.shape[:-1]:
         rows *= s
-    if rows >= 2**31:  # kernel ids are int32 (SMEM)
-        return False
-    from flexflow_tpu.ops import pallas_kernels as pk
-
-    return pk.rows_supported(n_ids, table.shape[-1], table.dtype,
-                             num_rows=rows, kind=kind)
+    how = None
+    if (jax.default_backend() == "tpu"
+            and not (plan is not None and plan.num_devices > 1)
+            and rows < 2**31):  # kernel ids are int32 (SMEM)
+        how = pk.rows_addressing(n_ids, table.shape, table.dtype, kind)
+    how = how or "xla"
+    _note_shard_event(op, "embedding_rows", addressing=how, kind=kind,
+                      dim=int(table.shape[-1]), ids=int(n_ids))
+    return how
 
 
 def _gather_dispatch(op: Op, table, flat_ids):
     """``table[(R, D)][flat_ids] -> flat_ids.shape + (D,)`` — the
     row-sharded ``shard_map`` gather when the op's table is range
     sharded, else the Pallas row kernel when eligible, else
-    ``jnp.take``.  Executor sparse path only (the Pallas branch is not
+    ``jnp.take``.  ``table`` may arrive stacked, ``(T, V, D)`` with
+    ``flat_ids`` over its ``T*V`` rows: flattening a narrow-row table
+    is a copy of it on the chip, which only the paths that need the
+    2-D view pay.  Executor sparse path only (the Pallas branch is not
     differentiable through)."""
-    d = table.shape[1]
+    d = table.shape[-1]
     shard = _row_sharding(op, op.sparse_keys()[0])
     if shard is not None:
-        return _sharded_gather(op, table, flat_ids, shard)
-    if _row_kernels_ok(op, flat_ids.size, table, kind="gather"):
+        return _sharded_gather(op, table.reshape(-1, d), flat_ids, shard)
+    if _row_addressing(op, flat_ids.size, table, "gather") != "xla":
         from flexflow_tpu.ops import pallas_kernels as pk
 
         rows = pk.gather_rows(table, flat_ids.reshape(-1))
         return rows.reshape(flat_ids.shape + (d,))
-    return jnp.take(table, flat_ids, axis=0)
+    return jnp.take(table.reshape(-1, d), flat_ids, axis=0)
 
 
 def _scatter_add_dispatch(op: Op, table, flat_ids, upd):
-    """``table.at[flat_ids].add(upd)`` — the local per-shard scatter
+    """``table.at[flat_ids].add(upd)`` in ``table``'s own shape (2-D or
+    stacked, see ``_gather_dispatch``) — the local per-shard scatter
     when the op's table is row-sharded, else the in-place Pallas row
     kernel when eligible.  Executor sparse path only."""
+    d = table.shape[-1]
     upd = upd.astype(table.dtype)
     shard = _row_sharding(op, op.sparse_keys()[0])
     if shard is not None:
-        return _sharded_scatter_add(op, table, flat_ids, upd, shard)
-    if _row_kernels_ok(op, flat_ids.size, table):
+        return _sharded_scatter_add(
+            op, table.reshape(-1, d), flat_ids, upd, shard
+        ).reshape(table.shape)
+    if _row_addressing(op, flat_ids.size, table, "scatter") != "xla":
         from flexflow_tpu.ops import pallas_kernels as pk
 
         return pk.scatter_add_rows(
-            table, flat_ids.reshape(-1), upd.reshape(-1, table.shape[1])
+            table, flat_ids.reshape(-1), upd.reshape(-1, d)
         )
-    return table.at[flat_ids].add(upd)
+    return table.reshape(-1, d).at[flat_ids].add(upd).reshape(table.shape)
 
 
 class Embedding(Op):
@@ -400,10 +410,7 @@ class MultiEmbedding(Op):
     def sparse_rows(self, params, xs):
         (idx,) = xs  # (batch, T)
         tables = params["tables"]  # (T, vocab, dim)
-        T, V, D = tables.shape
-        return _gather_dispatch(
-            self, tables.reshape(T * V, D), self._flat_ids(tables, idx)
-        )
+        return _gather_dispatch(self, tables, self._flat_ids(tables, idx))
 
     def sparse_forward(self, rows, xs, state, training):
         return [rows.astype(self.outputs[0].dtype)], state
@@ -411,12 +418,10 @@ class MultiEmbedding(Op):
     def sparse_apply(self, params, xs, row_grads, lr):
         (idx,) = xs  # (batch, T)
         tables = params["tables"]
-        T, V, D = tables.shape
         new = _scatter_add_dispatch(
-            self, tables.reshape(T * V, D), self._flat_ids(tables, idx),
-            -lr * row_grads,
+            self, tables, self._flat_ids(tables, idx), -lr * row_grads
         )
-        return {**params, "tables": new.reshape(T, V, D)}
+        return {**params, "tables": new}
 
     def sparse_flat_ids(self, params, xs):
         (idx,) = xs

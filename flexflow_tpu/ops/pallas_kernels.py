@@ -1389,12 +1389,99 @@ softmax_xent.defvjp(_xent_fwd, _xent_bwd)
 # full-table sweep (measured ~12 ms gather / ~250 ms scatter on a
 # 2 GB table for 2k rows — the reference's DLRM embedding path,
 # ``embedding.cu:128-158``).  These kernels move only the touched
-# rows: the gather pipelines one row-DMA per grid step with the row
-# id scalar-prefetched into the BlockSpec index_map; the scatter is a
-# sequential in-kernel read-modify-write loop over HBM (correct for
-# duplicate ids, like the reference's atomicAdd but deterministic),
-# aliasing the table in place.
+# rows, addressed by scalar-prefetched ids: the gather as DMAs that
+# run ahead of their use; the scatter as an in-kernel
+# read-modify-write loop over HBM (correct for duplicate ids, like
+# the reference's atomicAdd but deterministic), aliasing the table in
+# place.
+#
+# Two addressings, chosen from the table's shape (``rows_addressing``),
+# because the chip stores the two kinds of table differently and a
+# view that is not the stored order costs a copy of the whole table
+# every step (PERF.md §6 PR 28: 76 of 79 ms of DLRM's step):
+#
+# - ``row_major``: ``D % 128 == 0``.  The chip keeps ``(R, D)`` row
+#   major, ``{1,0:T(8,128)}``; a row is ``D/128`` whole lane tiles and
+#   the unit moved is one row (or one 128-lane piece of it).
+# - ``lane_major``: ``128 % D == 0``.  A minor dimension under 128
+#   would waste most of every 128-lane tile, so the TPU compiler keeps
+#   ``(V, D)`` as ``{0,1:T(8,128)}`` and ``(T, V, D)`` as ``{1,2,0}``:
+#   physically ``(T, D, V)``, rows along the lanes.  ``swapaxes(-1, -2)``
+#   of such a table is a bitcast, and on that view a logical row is one
+#   lane of ``D/8`` tiles; Mosaic moves whole 128-lane tiles only, so
+#   the unit is the ``(D, 128)`` block of 128 neighbouring rows.
 # ---------------------------------------------------------------------------
+
+_LANES = 128
+_ROWS_SMEM_BYTES = 512 * 1024        # scalar-prefetched ids
+_ROWS_VMEM_BYTES = 8 * 1024 * 1024   # the update matrix held in VMEM
+
+
+def _lane_major_shape(shape: Tuple[int, ...]) -> bool:
+    """A 2-D ``(V, D)`` or stacked 3-D ``(T, V, D)`` table the chip
+    stores rows-along-lanes and the block kernels can address: ``D`` a
+    divisor of 128 made of whole sublane tiles, at least one full
+    128-row block per table."""
+    if len(shape) not in (2, 3):
+        return False
+    v, d = shape[-2], shape[-1]
+    return d < _LANES and _LANES % d == 0 and d % 8 == 0 and v >= _LANES
+
+
+def rows_addressing(
+    n_ids: int,
+    shape: Tuple[int, ...],
+    dtype=jnp.float32,
+    kind: str = "scatter",
+) -> Optional[str]:
+    """How gather_rows/scatter_add_rows address a table of logical
+    ``shape`` (``(R, D)`` or stacked ``(T, V, D)``) on hardware:
+    ``"lane_major"``, ``"row_major"``, or None when neither kernel
+    form serves it (the caller takes XLA's gather/scatter).
+
+    ``lane_major`` (see the section comment) prefetches ``3n`` (gather)
+    or ``3n + 1`` (scatter) scalars and holds the transposed row or
+    update matrix in VMEM.  ``row_major``: the gather's (1, 1, D) pipelined
+    row blocks compile at any width (v5e-measured at 64, 128 and 256);
+    the scatter's manual HBM row DMAs require 128-lane slices (Mosaic
+    rejects anything else — d=64 and d=256 both fail, d=128 compiles),
+    so it runs on a (P, 128) view — free for ``D == 128``, column
+    blocks with expanded ids for other multiples of 128, and for
+    ``128 % D == 0`` tables the lane-major form cannot take (a table
+    under 128 rows, ``D`` under 8) a packed view that costs a relayout
+    and needs the table volume 128-aligned."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if n_ids < 1 or len(shape) < 2 or shape[-1] < 1:
+        return None
+    if itemsize != 4:
+        # Mosaic packs sub-32-bit dtypes 2/4-per-sublane in VMEM and
+        # then cannot statically prove dynamic one-row slices aligned
+        # ("index in dimension 0 is a multiple of 4", v5e round-4
+        # probe on bf16).  The row kernels are f32-only; smaller
+        # dtypes take the dense XLA path.
+        return None
+    dim = shape[-1]
+    if _lane_major_shape(shape):
+        words = 3 * n_ids if kind == "gather" else 3 * n_ids + 1
+        held = _round_up(n_ids, _LANES) * dim  # rows out / updates in
+        if (words * 4 <= _ROWS_SMEM_BYTES
+                and held * itemsize <= _ROWS_VMEM_BYTES):
+            return "lane_major"
+    num_rows = 1
+    for s in shape[:-1]:
+        num_rows *= s
+    if kind == "gather" or dim % _LANES == 0:
+        upd_lanes = dim
+        ids = n_ids * (dim // _LANES if kind != "gather" and dim > _LANES
+                       else 1)
+    elif _LANES % dim == 0 and (num_rows * dim) % _LANES == 0:
+        upd_lanes, ids = _LANES, n_ids
+    else:
+        return None
+    if (ids * 4 <= _ROWS_SMEM_BYTES
+            and n_ids * upd_lanes * itemsize <= _ROWS_VMEM_BYTES):
+        return "row_major"
+    return None
 
 
 def rows_supported(
@@ -1404,60 +1491,146 @@ def rows_supported(
     num_rows: Optional[int] = None,
     kind: str = "scatter",
 ) -> bool:
-    """Gate for gather_rows/scatter_add_rows.
-
-    ``kind="gather"`` needs only the on-chip bounds: its (1, 1, dim)
-    pipelined row blocks compile at any width (v5e-measured at 64, 128
-    and 256).  The scatter's manual HBM row DMAs require 128-lane
-    slices (Mosaic rejects anything else — d=64 and d=256 both fail,
-    d=128 compiles), so ``scatter_add_rows`` repacks the table to a
-    (P, 128) physical view; that works when ``dim`` is a multiple of
-    128 (column blocks) or divides 128 evenly with the table volume
-    128-aligned — the latter requires ``num_rows``, and the gate is
-    conservatively False without it.  Remaining limits for both kinds:
-    the prefetched id vector must fit SMEM and the (packed) update
-    matrix VMEM."""
-    itemsize = jnp.dtype(dtype).itemsize
-    if n_ids < 1 or dim < 1:
-        return False
-    if itemsize != 4:
-        # Mosaic packs sub-32-bit dtypes 2/4-per-sublane in VMEM and
-        # then cannot statically prove dynamic one-row slices aligned
-        # ("index in dimension 0 is a multiple of 4", v5e round-4
-        # probe on bf16).  The row kernels are f32-only; smaller
-        # dtypes take the dense XLA path.
-        return False
-    if kind == "gather" or dim % 128 == 0:
-        upd_lanes = max(dim, 1)
-        ids = n_ids * (dim // 128 if kind != "gather" and dim > 128 else 1)
-    elif 128 % dim == 0:
-        if num_rows is None or (num_rows * dim) % 128 != 0:
-            return False
-        upd_lanes, ids = 128, n_ids
-    else:
-        return False
-    return (
-        ids * 4 <= 512 * 1024                       # ids in SMEM
-        and n_ids * upd_lanes * itemsize <= 8 * 1024 * 1024  # upds in VMEM
-    )
+    """Gate for gather_rows/scatter_add_rows on a 2-D ``(num_rows,
+    dim)`` table: some addressing of ``rows_addressing`` serves it.
+    Without ``num_rows`` the packed and lane-major forms cannot be
+    checked and the gate is conservatively False for them."""
+    shape = (num_rows if num_rows is not None else 1, dim)
+    return rows_addressing(n_ids, shape, dtype, kind) is not None
 
 
 def _gather_kernel(idx_ref, row_ref, out_ref):
     out_ref[...] = row_ref[...]
 
 
-def gather_rows(table, flat_idx, interpret: Optional[bool] = None):
-    """``table[(R, D)][flat_idx (N,)] -> (N, D)`` moving only N rows.
+def _lane_iota(d: int):
+    return lax.broadcasted_iota(jnp.int32, (d, _LANES), 1)
 
-    The table is viewed as (R, 1, D) so the (1, 1, D) row block meets
-    the TPU block rule (last two block dims full-size); the row id
-    comes scalar-prefetched into the index_map, and the per-step row
-    DMAs are pipelined by the grid machinery.
+
+def _lane_major_view(table, interpret):
+    """``(V, D)`` or ``(T, V, D)`` -> ``(T, D, V)``: for the layout the
+    chip gives a narrow-row table a bitcast, no data moves.
+
+    Mosaic addresses the HBM buffer at its tiled extent (V rounded up
+    to whole 128-lane tiles: ``memref<4x64x1000064xf32>`` for a million
+    rows), so a table's last, partial block is a whole block there.
+    The interpreter sees the logical extent and is handed that padding
+    explicitly."""
+    t = jnp.swapaxes(table, -1, -2)
+    t = t.reshape((-1,) + t.shape[-2:])
+    edge_pad = (-t.shape[-1]) % _LANES if interpret else 0
+    if edge_pad:
+        t = jnp.pad(t, ((0, 0), (0, 0), (0, edge_pad)))
+    return t
+
+
+def _block_ids(flat_idx, v: int):
+    """Global row ids over ``(T*V)`` -> table, 128-row block, lane."""
+    idx = flat_idx.astype(jnp.int32)
+    t, row = idx // v, idx % v
+    return t, row // _LANES, row % _LANES
+
+
+def _move_lane(block, src, dst):
+    """``block`` (D, 128) with lane ``src`` brought to lane ``dst``
+    (one rotate a vreg; the other lanes are for the caller to mask)."""
+    return pltpu.roll(block, lax.rem(dst - src + _LANES, _LANES), axis=1)
+
+
+def _gather_lane_kernel(t_ref, blk_ref, lane_ref, table_ref, out_ref,
+                        ring, sem):
+    # table_ref: the (T, D, V) view in HBM.  Id i wants lane
+    # ``lane[i]`` of the (D, 128) block ``blk[i]`` of table ``t[i]``
+    # and lands in column i of out_ref (D, n_pad), resident in VMEM.
+    # Reads only, so the ring of block DMAs runs ``depth`` ahead with
+    # no ordering to keep.
+    n = t_ref.shape[0]
+    depth = ring.shape[0]
+    lanes = _lane_iota(ring.shape[1])
+
+    def load(i, slot):
+        start = pl.multiple_of(blk_ref[i] * _LANES, _LANES)
+        return pltpu.make_async_copy(
+            table_ref.at[t_ref[i], :, pl.ds(start, _LANES)],
+            ring.at[slot], sem.at[slot],
+        )
+
+    for i in range(min(depth, n)):
+        load(i, i).start()
+
+    def body(i, carry):
+        slot = lax.rem(i, depth)
+        load(i, slot).wait()
+        dst = lax.rem(i, _LANES)
+        cols = pl.ds(pl.multiple_of(i - dst, _LANES), _LANES)
+        out_ref[:, cols] = jnp.where(
+            lanes == dst, _move_lane(ring[slot], lane_ref[i], dst),
+            out_ref[:, cols],
+        )
+
+        @pl.when(i + depth < n)
+        def _():
+            load(i + depth, slot).start()
+
+        return carry
+
+    lax.fori_loop(0, n, body, 0)
+
+
+#: Slots of (D, 128) blocks in the lane-major kernels' DMA rings: 16 x
+#: 32 KB of VMEM at D = 64.  v5e-measured at 8,192 ids over (8, 2M, 64):
+#: the scatter 2.89 / 1.79 / 1.37 / 1.35 ms at 4 / 8 / 16 / 32 slots,
+#: the gather 1.04 / 0.79 / 0.79 / 0.79 (PERF.md §6, PR 28).
+_LANE_RING = 16
+
+
+def _gather_rows_lane_major(table, flat_idx, interpret):
+    n = flat_idx.shape[0]
+    v, d = table.shape[-2:]
+    n_pad = _round_up(n, _LANES)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],       # table (HBM)
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),    # rows, transposed
+        scratch_shapes=[
+            pltpu.VMEM((_LANE_RING, d, _LANES), table.dtype),
+            pltpu.SemaphoreType.DMA((_LANE_RING,)),
+        ],
+    )
+    out = pl.pallas_call(
+        _gather_lane_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((d, n_pad), table.dtype),
+        name="ff_gather_rows",
+        interpret=interpret,
+    )(*_block_ids(flat_idx, v), _lane_major_view(table, interpret))
+    return out[:, :n].T
+
+
+def gather_rows(table, flat_idx, interpret: Optional[bool] = None):
+    """``table[(R, D)][flat_idx (N,)] -> (N, D)`` moving only what the
+    N rows need.  ``table`` may be a stacked ``(T, V, D)`` with
+    ``flat_idx`` over its ``T*V`` rows: a narrow-row table has to
+    arrive unflattened for the lane-major addressing (its ``(T*V, D)``
+    view is already a copy).
+
+    ``row_major``: the table is viewed as (R, 1, D) so the (1, 1, D)
+    row block meets the TPU block rule (last two block dims full-size);
+    the row id comes scalar-prefetched into the index_map, and the
+    per-step row DMAs are pipelined by the grid machinery.
+    ``lane_major``: one kernel step over all ids, a ring of
+    ``(D, 128)`` block DMAs from the transposed view running ahead of
+    the lane select that picks each row out of its block.
     """
     if interpret is None:
         interpret = _interpret_default()
     n = flat_idx.shape[0]
-    d = table.shape[1]
+    d = table.shape[-1]
+    if n == 0:  # a static shape; no kernel form has a zero-step grid
+        return jnp.zeros((0, d), table.dtype)
+    if _lane_major_shape(table.shape):
+        return _gather_rows_lane_major(table, flat_idx, interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
@@ -1538,30 +1711,157 @@ def _scatter_add_kernel(meta_ref, table_ref, upd_ref, out_ref, row_vmem,
     store(nr - 1, lax.rem(nr - 1, 2)).wait()
 
 
+def _scatter_add_lane_kernel(meta_ref, table_ref, upd_ref, out_ref, ring,
+                             sem_in, sem_out, *, n, t_shift):
+    # out_ref aliases table_ref, the (T, D, V) view in HBM: RMW of one
+    # (D, 128) block of 128 neighbouring rows per run.  The caller has
+    # SORTED the ids by block, so every block is one run and no two
+    # DMAs of a call touch the same memory: loads run ``ahead`` runs in
+    # front and stores drain behind with nothing to order but the
+    # ring's own slots.  meta_ref holds [num_runs, start[n], key[n],
+    # val[n]], the last two in sorted order: run k owns the positions
+    # ``start[k] .. start[k+1]`` (the last run up to n), all of block
+    # ``key & mask`` of table ``key >> t_shift``; position j is column
+    # ``val >> 7`` of upd_ref (the update matrix transposed, (D, n_pad),
+    # in the caller's order) and lane ``val & 127`` of its block.  Each
+    # column is lane-placed here, so no (n, D, 128) expansion exists,
+    # and a run's columns are added in the caller's order (the sort is
+    # stable): the same row twice gets the same adds in the same order
+    # as one id at a time would give it.
+    nr = meta_ref[0]
+    depth = ring.shape[0]
+    ahead = depth // 2
+    lanes = _lane_iota(ring.shape[1])
+
+    def block(k):
+        key = meta_ref[1 + n + meta_ref[1 + k]]
+        start = pl.multiple_of((key & ((1 << t_shift) - 1)) * _LANES, _LANES)
+        return out_ref.at[key >> t_shift, :, pl.ds(start, _LANES)]
+
+    def load(k):
+        slot = lax.rem(k, depth)
+        return pltpu.make_async_copy(block(k), ring.at[slot], sem_in.at[slot])
+
+    def store(k):
+        slot = lax.rem(k, depth)
+        return pltpu.make_async_copy(ring.at[slot], block(k), sem_out.at[slot])
+
+    for k in range(ahead):
+        @pl.when(k < nr)
+        def _():
+            load(k).start()
+
+    def member(j, acc):
+        val = meta_ref[1 + 2 * n + j]
+        col, dst = val >> 7, val & (_LANES - 1)
+        src = col & (_LANES - 1)
+        chunk = upd_ref[:, pl.ds(pl.multiple_of(col - src, _LANES), _LANES)]
+        return acc + jnp.where(lanes == dst, _move_lane(chunk, src, dst), 0.0)
+
+    def body(k, carry):
+        slot = lax.rem(k, depth)
+        load(k).wait()
+        # meta_ref[2 + k] at the last run is key[0]: in bounds, unused.
+        end = jnp.where(k + 1 < nr, meta_ref[2 + k], n)
+        ring[slot] = lax.fori_loop(meta_ref[1 + k], end, member, ring[slot])
+        store(k).start()
+
+        @pl.when(k + ahead < nr)
+        def _():
+            # The slot load(k + ahead) fills was run k + ahead - depth's.
+            @pl.when(k + ahead >= depth)
+            def _():
+                store(k + ahead - depth).wait()
+
+            load(k + ahead).start()
+
+        return carry
+
+    def drain(k, carry):
+        store(k).wait()
+        return carry
+
+    lax.fori_loop(0, nr, body, 0)
+    # The stores no later load had to wait for: the last ``depth`` runs'.
+    lax.fori_loop(jnp.maximum(nr - depth, 0), nr, drain, 0)
+
+
+def _scatter_rows_lane_major(table, flat_idx, updates, interpret):
+    n = flat_idx.shape[0]
+    v, d = table.shape[-2:]
+    n_pad = _round_up(n, _LANES)
+    # Sorts only: an s32[n] gather or scatter costs the chip 40-60 us
+    # at n = 8192, a sort 7 (PERF.md §6, PR 28).
+    t, blk, lane = _block_ids(flat_idx, v)
+    t_shift = max(pl.cdiv(v, _LANES) - 1, 1).bit_length()
+    pos = jnp.arange(n, dtype=jnp.int32)
+    key, val = lax.sort(
+        ((t << t_shift) | blk, (pos << 7) | lane), num_keys=1, is_stable=True
+    )
+    new = jnp.concatenate([jnp.ones((1,), bool), key[1:] != key[:-1]])
+    starts = lax.sort(jnp.where(new, pos, n + pos))  # the runs' first, in order
+    meta = jnp.concatenate(
+        [jnp.sum(new, dtype=jnp.int32)[None], starts, key, val]
+    )
+    upd_t = jnp.pad(updates.astype(table.dtype).T, ((0, 0), (0, n_pad - n)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(1,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),      # table (HBM)
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # updates, transposed
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((_LANE_RING, d, _LANES), table.dtype),
+            pltpu.SemaphoreType.DMA((_LANE_RING,)),
+            pltpu.SemaphoreType.DMA((_LANE_RING,)),
+        ],
+    )
+    lane_major = _lane_major_view(table, interpret)
+    out = pl.pallas_call(
+        functools.partial(_scatter_add_lane_kernel, n=n, t_shift=t_shift),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(lane_major.shape, table.dtype),
+        input_output_aliases={1: 0},  # inputs incl. scalar prefetch
+        name="ff_scatter_add_rows",
+        interpret=interpret,
+    )(meta, lane_major, upd_t)
+    out = out[:, :, :v].reshape(table.shape[:-2] + (d, v))
+    return jnp.swapaxes(out, -1, -2)
+
+
 def scatter_add_rows(table, flat_idx, updates,
                      interpret: Optional[bool] = None):
     """``table.at[flat_idx].add(updates)`` touching only the N rows;
     the table buffer is aliased (donated) and updated in place.
+    ``table`` is ``(R, D)`` or a stacked ``(T, V, D)`` with ``flat_idx``
+    over its ``T*V`` rows (see ``gather_rows``); the result has the
+    table's shape.
 
-    Mosaic only accepts 128-lane HBM row slices (v5e-measured: d=64
-    and d=256 both reject, d=128 compiles), so the kernel always runs
+    ``lane_major`` tables (``rows_addressing``) are read-modify-written
+    in ``(D, 128)`` blocks of their transposed view.  Otherwise: Mosaic
+    only accepts 128-lane HBM row slices (v5e-measured: d=64
+    and d=256 both reject, d=128 compiles), so the kernel runs
     on a ``(P, 128)`` physical view: ``d`` a multiple of 128 splits
     each row into column blocks with expanded ids; ``d`` dividing 128
     packs ``128/d`` logical rows per physical row, lane-placing each
     update by one-hot expansion (exact: one-hot multiply adds zeros).
-    Duplicate physical rows — duplicate ids OR distinct logical rows
-    sharing a packed row — stay correct because ``_collapse_runs``
-    folds adjacent duplicates into single runs (so the pipelined
-    kernel's overlapping load/store never touch the same row) and the
-    kernel orders non-adjacent runs via its store-wait protocol; the
-    kernel must ONLY be fed run-collapsed indices.  The same reduction
+    Duplicate physical targets — duplicate ids OR distinct logical rows
+    sharing a block or a packed row — stay correct because
+    ``_collapse_runs`` folds adjacent duplicates into single runs (so
+    the pipelined kernel's overlapping load/store never touch the same
+    target) and the kernel orders non-adjacent runs via its store-wait
+    protocol; the lane-major kernel is fed block-sorted ids, so each
+    block is one run.  The kernels must ONLY be fed run-collapsed
+    indices.  The same reduction
     runs under ``interpret`` so CPU tests cover it; dims fitting
     neither case (e.g. 96) are interpret-only and raise on TPU
-    (``rows_supported`` gates them off)."""
+    (``rows_addressing`` gates them off)."""
     if interpret is None:
         interpret = _interpret_default()
     n = flat_idx.shape[0]
-    num_rows, d = table.shape
+    d = table.shape[-1]
     if n == 0:
         # Degenerate batch: the pipelined kernel unconditionally starts
         # load(0) and waits the drain store(nr-1), both invalid at
@@ -1569,30 +1869,28 @@ def scatter_add_rows(table, flat_idx, updates,
         # Static shape, so a Python-level no-op preserves the old
         # sequential kernel's behavior.
         return table
-    if d != 128:
-        if d % 128 == 0:
-            c = d // 128
-            idx = (flat_idx[:, None] * c + jnp.arange(c)[None, :]).reshape(-1)
-            out = _scatter_rows_128(
-                table.reshape(num_rows * c, 128), idx,
-                updates.reshape(n * c, 128), interpret,
-            )
-            return out.reshape(num_rows, d)
-        if 128 % d == 0 and (num_rows * d) % 128 == 0:
-            k = 128 // d
-            phys = flat_idx // k
-            onehot = jax.nn.one_hot(flat_idx % k, k, dtype=table.dtype)
-            upd = (onehot[:, :, None] * updates[:, None, :]).reshape(n, 128)
-            out = _scatter_rows_128(
-                table.reshape(num_rows * d // 128, 128), phys, upd, interpret
-            )
-            return out.reshape(num_rows, d)
-        if not interpret:
-            raise ValueError(
-                f"scatter_add_rows: row dim {d} needs d % 128 == 0 or "
-                f"128 % d == 0 (with 128-aligned table volume) on TPU"
-            )
-    return _scatter_rows_128(table, flat_idx, updates, interpret)
+    if _lane_major_shape(table.shape):
+        return _scatter_rows_lane_major(table, flat_idx, updates, interpret)
+    shape, table = table.shape, table.reshape(-1, d)
+    num_rows = table.shape[0]
+    if d % _LANES == 0:
+        c = d // _LANES
+        idx = (flat_idx[:, None] * c + jnp.arange(c)[None, :]).reshape(-1)
+        upd = updates.reshape(n * c, _LANES)
+    elif _LANES % d == 0 and (num_rows * d) % _LANES == 0:
+        k = _LANES // d
+        idx = flat_idx // k
+        onehot = jax.nn.one_hot(flat_idx % k, k, dtype=table.dtype)
+        upd = (onehot[:, :, None] * updates[:, None, :]).reshape(n, _LANES)
+    elif interpret:
+        return _scatter_rows_128(table, flat_idx, updates, interpret).reshape(shape)
+    else:
+        raise ValueError(
+            f"scatter_add_rows: row dim {d} needs d % 128 == 0 or "
+            f"128 % d == 0 (with 128-aligned table volume) on TPU"
+        )
+    out = _scatter_rows_128(table.reshape(-1, _LANES), idx, upd, interpret)
+    return out.reshape(shape)
 
 
 def _scatter_rows_128(table, flat_idx, updates, interpret):

@@ -586,30 +586,23 @@ class Executor:
             gsum = gsum * scale
         key = op.sparse_keys()[0]
         table = op_params[key]
-        flat = table.reshape(-1, table.shape[-1])
         safe = jnp.where(mask, uids, 0)
-        p_rows = _gather_dispatch(op, flat, safe)
+        p_rows = _gather_dispatch(op, table, safe)
         bufs = self.optimizer.sparse_state_buffers(opt_state, op.name, key)
-        buf_rows = {
-            k: _gather_dispatch(op, b.reshape(-1, b.shape[-1]), safe)
-            for k, b in bufs.items()
-        }
+        buf_rows = {k: _gather_dispatch(op, b, safe) for k, b in bufs.items()}
         t = self.optimizer.sparse_step_count(opt_state)
         d_p, d_bufs = self.optimizer.sparse_row_step(
             p_rows, gsum, buf_rows, t=t
         )
         m = mask[:, None]
-        new_flat = _scatter_add_dispatch(
-            op, flat, safe, jnp.where(m, d_p, 0)
+        new_table = _scatter_add_dispatch(
+            op, table, safe, jnp.where(m, d_p, 0)
         )
-        new_bufs = {}
-        for k, b in bufs.items():
-            b2 = b.reshape(-1, b.shape[-1])
-            nb = _scatter_add_dispatch(
-                op, b2, safe, jnp.where(m, d_bufs[k], 0)
-            )
-            new_bufs[k] = nb.reshape(b.shape)
-        new_params = {**op_params, key: new_flat.reshape(table.shape)}
+        new_bufs = {
+            k: _scatter_add_dispatch(op, b, safe, jnp.where(m, d_bufs[k], 0))
+            for k, b in bufs.items()
+        }
+        new_params = {**op_params, key: new_table}
         if new_bufs:
             opt_state = self.optimizer.with_sparse_state_buffers(
                 opt_state, op.name, key, new_bufs

@@ -886,10 +886,9 @@ class PipelineExecutor:
                     g = g * scale
                 key = op.sparse_keys()[0]
                 table = params[op.name][key]
-                flat = table.reshape(-1, table.shape[-1])
-                new_flat = _scatter_add_dispatch(op, flat, ids, -lr * g)
                 new_params[op.name] = {
-                    **params[op.name], key: new_flat.reshape(table.shape)
+                    **params[op.name],
+                    key: _scatter_add_dispatch(op, table, ids, -lr * g),
                 }
             else:
                 uniq = _unique_row_sums(ids, g)

@@ -18,7 +18,9 @@
 """
 
 import functools
+import math
 import os
+import re
 import sys
 
 import jax
@@ -85,21 +87,27 @@ def _decode(b, s, h, hd, dtype):
         _sds((b, h, hd), dtype), cache, cache, _sds((b,), jnp.int32))
 
 
-def _rows(kind, rows, dim, n_ids):
-    table, ids = _sds((rows, dim), F32), _sds((n_ids,), jnp.int32)
-    gate = lambda: pk.rows_supported(n_ids, dim, F32, num_rows=rows, kind=kind)
+def _rows(kind, shape, n_ids, addressing):
+    """A row kernel over a table of ``shape`` ((R, D) or stacked
+    (T, V, D)); the gate also holds the shape to the addressing it is
+    listed under."""
+    table, ids = _sds(shape, F32), _sds((n_ids,), jnp.int32)
+    gate = lambda: pk.rows_addressing(n_ids, shape, F32, kind) == addressing
     if kind == "gather":
         return gate, functools.partial(pk.gather_rows, interpret=False), (
             table, ids)
     return gate, functools.partial(pk.scatter_add_rows, interpret=False), (
-        table, ids, _sds((n_ids, dim), F32))
+        table, ids, _sds((n_ids, shape[-1]), F32))
 
 
 #: name -> () -> (gate, fn, abstract args): the kernels of the smoke's
 #: phases at their real shapes (transformer b8 x 8 heads x seq 512 x
 #: hd 64 and its 4096 x 32768 logits; serve's 8 x 512 x 8 x 64 cache;
-#: DLRM's 1M-row d=64 tables), plus the long-context flash shape and
-#: the largest decode shape ISSUE 21 names.
+#: DLRM's 4 stacked 1M-row d=64 tables, whose last 128-row block is
+#: partial), plus the long-context flash shape, the largest decode
+#: shape ISSUE 21 names, and the row kernels in both addressings: the
+#: ``dlrm.random.b1024`` cell's 8 x 2M x 64 at 8192 ids, 2-D narrow
+#: tables, and the row-major widths (128, and GPT-2's 1024).
 CASES = {
     "flash_fwd-8x8x512x64-bf16": lambda: _flash((8, 8, 512, 64), BF16, False),
     "flash_grad-8x8x512x64-bf16": lambda: _flash((8, 8, 512, 64), BF16, True),
@@ -110,16 +118,39 @@ CASES = {
     "decode-8x512x8x64-f32": lambda: _decode(8, 512, 8, 64, F32),
     "decode-8x512x8x64-bf16": lambda: _decode(8, 512, 8, 64, BF16),
     "decode-16x4096x16x128-bf16": lambda: _decode(16, 4096, 16, 128, BF16),
-    "gather_rows-1Mx64-1024ids": lambda: _rows("gather", 1 << 20, 64, 1024),
+    "gather_rows-1Mx64-1024ids":
+        lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
-        lambda: _rows("scatter", 1 << 20, 64, 1024),
+        lambda: _rows("scatter", (1 << 20, 64), 1024, "lane_major"),
+    "gather_rows-8x2Mx64-8192ids":
+        lambda: _rows("gather", (8, 2000000, 64), 8192, "lane_major"),
+    "scatter_add_rows-8x2Mx64-8192ids":
+        lambda: _rows("scatter", (8, 2000000, 64), 8192, "lane_major"),
+    "gather_rows-4x1000000x64-4096ids":
+        lambda: _rows("gather", (4, 1000000, 64), 4096, "lane_major"),
+    "scatter_add_rows-4x1000000x64-4096ids":
+        lambda: _rows("scatter", (4, 1000000, 64), 4096, "lane_major"),
+    "gather_rows-1000000x16-1024ids":
+        lambda: _rows("gather", (1000000, 16), 1024, "lane_major"),
+    "scatter_add_rows-1000000x16-1024ids":
+        lambda: _rows("scatter", (1000000, 16), 1024, "lane_major"),
+    "gather_rows-1Mx128-1024ids":
+        lambda: _rows("gather", (1 << 20, 128), 1024, "row_major"),
+    "scatter_add_rows-1Mx128-1024ids":
+        lambda: _rows("scatter", (1 << 20, 128), 1024, "row_major"),
+    "gather_rows-50257x1024-1024ids":
+        lambda: _rows("gather", (50257, 1024), 1024, "row_major"),
+    "scatter_add_rows-50257x1024-1024ids":
+        lambda: _rows("scatter", (50257, 1024), 1024, "row_major"),
 }
 
 
 @functools.lru_cache(maxsize=None)
 def _compiled_text(name: str) -> str:
     _gate, fn, args = CASES[name]()
-    return jax.jit(fn).lower(*args).compile().as_text()
+    # The table donated, as the train step donates its parameters.
+    donate = (0,) if name.startswith("scatter_add_rows") else ()
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
@@ -127,6 +158,89 @@ def test_kernel_compiles_for_v5e(name):
     """Mosaic accepts the kernel at this shape, and the compiled
     program holds it (not an interpreted lowering)."""
     assert chip_smoke.has_mosaic_call(_compiled_text(name))
+
+
+#: The kernel cases whose table the compiled program must not move: the
+#: lane-major ones and D = 128.  (Wider row-major tables still pay a
+#: reshape to the (P, 128) view: PERF.md §7.)
+_IN_PLACE = sorted(
+    n for n in CASES
+    if "rows-" in n and "50257x1024" not in n
+)
+
+
+@pytest.mark.parametrize("name", _IN_PLACE)
+def test_row_kernel_reads_the_table_where_it_lies(name):
+    """Every view between the program's table argument and the kernel
+    is a bitcast: no ``copy``, ``reshape``, ``transpose`` or fusion of
+    the table's size in the optimised HLO."""
+    _gate, _fn, args = CASES[name]()
+    assert chip_smoke.table_sized_relayouts(
+        _compiled_text(name), math.prod(args[0].shape)) == []
+
+
+def _dlrm_random_step_text(monkeypatch, tables, rows):
+    """The one-chip sparse train step at ``dlrm-random``'s widths
+    (benchmark/configs/dlrm-random.json, traffic random.b1024: batch
+    1024, plain SGD), compiled for the described v5e."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.dlrm import DLRMConfig, build_dlrm
+    from flexflow_tpu.optim import SGDOptimizer
+    from flexflow_tpu.runtime.executor import Executor
+
+    chip = _one_chip()
+    # Steer the code that asks where it runs (.claude/skills/verify).
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = FFConfig(batch_size=1024, sparse_embedding_updates=True)
+    arch = DLRMConfig(
+        sparse_feature_size=64, embedding_size=[rows] * tables,
+        mlp_bot=[64, 512, 512, 64],
+        mlp_top=[64 * (tables + 1), 1024, 1024, 1024, 1],
+    )
+    ex = Executor(build_dlrm(batch_size=1024, dlrm=arch, config=cfg),
+                  config=cfg, optimizer=SGDOptimizer(lr=0.01),
+                  devices=list(chip.device_set))
+    assert [op.name for op in ex._sparse_ops] == ["embeddings"]
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype), tree)
+    args = (*ex._abstract_init(), ex._abstract_batch())
+    return ex.train_step.lower(*map(on_chip, args)).compile().as_text()
+
+
+@pytest.mark.parametrize("tables, rows", [(8, 2000000), (4, 1000000)],
+                         ids=["dlrm-random", "readme-4x1M"])
+def test_sparse_dlrm_step_holds_no_table_sized_relayout(
+        monkeypatch, tables, rows):
+    """The relayout cannot come back unseen (PERF.md §6, PR 28: four
+    of them were 76 of the step's 79 ms): besides the parameter, its
+    bitcasts and the aliased scatter call, nothing in the compiled
+    step has the table's element count."""
+    text = _dlrm_random_step_text(monkeypatch, tables, rows)
+    assert chip_smoke.has_mosaic_call(text)
+    for name in ("ff_gather_rows", "ff_scatter_add_rows"):
+        assert re.search(rf"^\s*(ROOT )?%{name}\S* = .* custom-call\(",
+                         text, re.M), name
+    assert chip_smoke.table_sized_relayouts(text, tables * rows * 64) == []
+
+
+def test_table_sized_relayouts_names_what_pr28_removed():
+    """The detector on the four instructions the parent's step held
+    (PERF.md §6, PR 28), a fusion with a tuple result, and what must
+    NOT count: the bitcast views and the aliased kernel call."""
+    text = """
+  %copy.28 = f32[8,2000000,64]{2,1,0:T(8,128)} copy(f32[8,2000000,64]{1,2,0:T(8,128)} %p)
+  %reshape.32 = f32[8000000,128]{1,0:T(8,128)} reshape(%copy.28), metadata={op_name="x"}
+  %reshape.33 = f32[8,2000000,64]{2,1,0:T(8,128)} reshape(%ff_scatter_add_rows.1)
+  ROOT %copy.34 = f32[8,2000000,64]{1,2,0:T(8,128)} copy(%reshape.33)
+  %fusion.9 = (f32[4]{0}, f32[8,2000000,64]{1,2,0:T(8,128)S(1)}) fusion(%a), kind=kLoop
+  %bitcast = f32[8,64,2000000]{2,1,0:T(8,128)} bitcast(%p)
+  %ff_scatter_add_rows.1 = f32[8,64,2000000]{2,1,0:T(8,128)} custom-call(%c, %bitcast)
+  %fusion.2 = s32[8192]{0:T(1024)S(1)} fusion(%ids), kind=kLoop
+"""
+    found = chip_smoke.table_sized_relayouts(text, 8 * 2000000 * 64)
+    assert [re.search(r"%(\S+) =", line).group(1) for line in found] == [
+        "copy.28", "reshape.32", "reshape.33", "copy.34", "fusion.9"]
 
 
 def test_supported_gates_match_the_compiler():
@@ -181,6 +295,9 @@ def on_a_pretend_chip(monkeypatch):
     through the interpreter, so no compiled text here holds a Mosaic
     call; the real check is exercised by the AOT cases above."""
     monkeypatch.setattr(chip_smoke, "has_mosaic_call", lambda text: True)
+    # ... and the CPU's XLA scatter is a fusion of the table's size.
+    monkeypatch.setattr(chip_smoke, "table_sized_relayouts",
+                        lambda text, elements: [])
 
 
 def _phases(which):

@@ -454,3 +454,157 @@ def test_streamed_env_dispatch(monkeypatch):
     # Oversized head dim: VMEM-unsafe at any streamed block — fall
     # through (here: to None, nothing else supports it either).
     assert pk._stream_default_block(512) == 0
+
+
+# -- row kernels: the two addressings ----------------------------------------
+#
+# ``lane_major`` for 128 % D == 0 (the chip stores such a table rows along
+# the lanes; the kernels move (D, 128) blocks of its transposed view),
+# ``row_major`` for D % 128 == 0.  Interpret mode runs the same kernel
+# bodies; what Mosaic accepts is tests/test_chip_compile.py's.
+
+_ROW_SHAPES = [
+    shape
+    for d in (16, 32, 64, 128, 256)
+    # 300 rows: two whole 128-row blocks and an edge block of 44.
+    for shape in ((300, d), (3, 300, d))
+] + [(256, 64), (2, 128, 32)]
+
+
+def _np_rows(table):
+    return np.asarray(table).reshape(-1, table.shape[-1])
+
+
+def _row_ids(rng, num_rows, n=41):
+    """Random ids behind the ones that matter: first and last row (the
+    edge block), duplicates at distance 1 and 2, neighbours in a block."""
+    head = [0, 1, 1, 5, num_rows - 1, num_rows - 1, 2, 5, 130, num_rows - 2]
+    return np.concatenate(
+        [head, rng.integers(0, num_rows, size=n - len(head))]
+    ).astype(np.int32)
+
+
+@pytest.mark.parametrize("shape", _ROW_SHAPES, ids=str)
+def test_gather_rows_matches_numpy(rng, shape):
+    table = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    idx = _row_ids(rng, int(np.prod(shape[:-1])))
+    got = pk.gather_rows(table, jnp.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(got), _np_rows(table)[idx])
+
+
+@pytest.mark.parametrize("shape", _ROW_SHAPES, ids=str)
+def test_scatter_add_rows_matches_numpy(rng, shape):
+    table = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    idx = _row_ids(rng, int(np.prod(shape[:-1])))
+    upd = rng.standard_normal((len(idx), shape[-1])).astype(np.float32)
+    got = pk.scatter_add_rows(table, jnp.asarray(idx), jnp.asarray(upd))
+    assert got.shape == shape
+    ref = _np_rows(table).copy()
+    np.add.at(ref, idx, upd)
+    np.testing.assert_allclose(_np_rows(got), ref, rtol=1e-5, atol=1e-6)
+
+
+#: ids over a (2, 384, 32) table: 3 blocks of 128 rows a table.
+_BLOCK_HAZARDS = {
+    "same_id_distance_1": [7, 7, 7, 200, 7],
+    "same_id_distance_2": [7, 200, 7, 300, 7, 9, 7],
+    "same_id_far": [7, 200, 300, 400, 500, 600, 700, 7],
+    # Different rows of one 128-row block are ONE physical target.
+    "one_block_adjacent": [5, 6, 100, 127, 200, 201],
+    "one_block_distance_2": [5, 200, 6, 300, 127, 400, 0],
+    "one_block_far": [5, 200, 300, 400, 500, 600, 700, 100],
+    "same_lane_other_blocks": [5, 133, 261, 389, 5, 133],
+    "one_run_only": [64, 65, 66, 64],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_HAZARDS))
+def test_scatter_lane_major_orders_every_block_hazard(rng, case):
+    """The two-deep pipeline must order read-modify-writes of one
+    BLOCK at every distance — the same id again, or a neighbour in the
+    block — and fold adjacent ones into a run; a lost update shows as
+    a missing addend."""
+    shape = (2, 384, 32)
+    assert pk.rows_addressing(8, shape) == "lane_major"
+    table = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    idx = np.asarray(_BLOCK_HAZARDS[case], np.int32)
+    upd = rng.standard_normal((len(idx), 32)).astype(np.float32)
+    got = pk.scatter_add_rows(table, jnp.asarray(idx), jnp.asarray(upd))
+    ref = _np_rows(table).copy()
+    np.add.at(ref, idx, upd)
+    np.testing.assert_allclose(_np_rows(got), ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape", [(256, 64), (3, 300, 16), (64, 128)], ids=str)
+def test_row_kernels_empty_batch(shape):
+    """n = 0 under either addressing: nothing gathered, the table
+    returned as it came (also under jit, where it is a static shape)."""
+    table = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape)
+    idx = jnp.zeros((0,), jnp.int32)
+    upd = jnp.zeros((0, shape[-1]), jnp.float32)
+    assert pk.gather_rows(table, idx).shape == (0, shape[-1])
+    for fn in (pk.scatter_add_rows, jax.jit(pk.scatter_add_rows)):
+        np.testing.assert_array_equal(np.asarray(fn(table, idx, upd)),
+                                      np.asarray(table))
+
+
+def _pallas_eqns(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_eqns(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("shape", [(256, 64), (4, 256, 64), (256, 128),
+                                   (256, 256)], ids=str)
+def test_scatter_add_rows_aliases_the_table_under_jit(rng, shape):
+    """The table operand is the kernel's output buffer (operand 1
+    after the prefetched scalars), and every view between the jitted
+    function's argument and that operand keeps the table's element
+    count: with the argument donated the update is in place."""
+    table = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    idx = jnp.asarray(_row_ids(rng, int(np.prod(shape[:-1]))))
+    upd = jnp.asarray(rng.standard_normal((idx.shape[0], shape[-1])),
+                      jnp.float32)
+    ref = _np_rows(table).copy()
+    np.add.at(ref, np.asarray(idx), np.asarray(upd))
+    (eqn,) = _pallas_eqns(
+        jax.make_jaxpr(pk.scatter_add_rows)(table, idx, upd).jaxpr, [])
+    assert tuple(eqn.params["input_output_aliases"]) == ((1, 0),)
+    assert eqn.invars[1].aval.size == eqn.outvars[0].aval.size == table.size
+    got = jax.jit(pk.scatter_add_rows, donate_argnums=(0,))(table, idx, upd)
+    assert table.is_deleted()
+    np.testing.assert_allclose(_np_rows(got), ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_ids, shape, kind, want", [
+    (8192, (8, 2000000, 64), "scatter", "lane_major"),   # dlrm-random
+    (8192, (8, 2000000, 64), "gather", "lane_major"),
+    (4096, (4, 1000000, 64), "scatter", "lane_major"),   # README's: an edge block
+    (1024, (1 << 20, 16), "scatter", "lane_major"),
+    (1024, (1 << 20, 128), "scatter", "row_major"),
+    (1024, (50257, 1024), "scatter", "row_major"),       # GPT-2's token table
+    (1024, (50257, 1024), "gather", "row_major"),
+    (4096, (50257, 1024), "scatter", None),              # 16 MB of updates
+    (6, (40, 64), "scatter", "row_major"),               # under one block: packed
+    (6, (40, 4), "scatter", None),                       # volume not 128-aligned
+    (6, (4096, 4), "scatter", "row_major"),              # D under a sublane tile
+    (6, (41, 96), "scatter", None),
+    (6, (41, 96), "gather", "row_major"),
+    (20000, (8, 2000000, 64), "scatter", "lane_major"),
+    (40000, (8, 2000000, 64), "scatter", None),          # 5n+1 scalars past SMEM
+    (30000, (8, 2000000, 64), "gather", "lane_major"),
+    (40000, (8, 2000000, 64), "gather", None),           # 10 MB of rows
+    (0, (256, 64), "scatter", None),
+], ids=str)
+def test_rows_addressing_follows_the_shape(n_ids, shape, kind, want):
+    assert pk.rows_addressing(n_ids, shape, jnp.float32, kind) == want
+    assert pk.rows_addressing(n_ids, shape, jnp.bfloat16, kind) is None
+    if len(shape) == 2:
+        assert pk.rows_supported(n_ids, shape[1], jnp.float32,
+                                 num_rows=shape[0], kind=kind) == (want is not None)

@@ -318,3 +318,131 @@ def test_lazy_untouched_rows_frozen(rng):
     np.testing.assert_array_equal(p0[:, 1:], p1[:, 1:])  # cold rows frozen
     m1 = jax.device_get(opt_state["m"]["tables"]["tables"])
     assert np.all(m1[:, 1:] == 0)
+
+
+# -- the dispatchers on the kernels' side -------------------------------------
+#
+# On the CPU ``_row_addressing`` answers "xla", so everything above
+# runs jnp.take / .at[].add.  Here the dispatchers are told they sit on
+# one TPU and the kernels run under the interpreter: the executor's
+# sparse step through gather_rows / scatter_add_rows in the addressing
+# each table's shape picks.
+
+
+@pytest.fixture
+def row_kernels_on_the_cpu(monkeypatch):
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret_default", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+#: name -> (tables, rows a table, width, the addressing its shape picks)
+_KERNEL_TABLES = {
+    "stacked-d16-edge-block": (4, 160, 16, "lane_major"),
+    "stacked-d64": (2, 256, 64, "lane_major"),
+    "stacked-d128": (2, 24, 128, "row_major"),
+    "stacked-d8-short-table": (4, 16, 8, "row_major"),
+}
+
+
+def _kernel_model(sparse, tables, rows, dim, batch=8):
+    cfg = FFConfig(batch_size=batch, sparse_embedding_updates=sparse)
+    ff = FFModel(cfg)
+    ids = ff.create_tensor((batch, tables), dtype=jnp.int32, name="ids")
+    bag = ff.create_tensor((batch, 3), dtype=jnp.int32, name="bag")
+    lbl = ff.create_tensor((batch,), dtype=jnp.int32, name="label")
+    e1 = ff.multi_embedding(ids, tables, rows, dim, name="tables")
+    e1 = ff.reshape(e1, (batch, tables * dim), name="r1")
+    e2 = ff.embedding(bag, 200, 32, aggr="avg", name="bagged")  # 2-D, lane-major
+    t = ff.concat([e1, e2], axis=1, name="cat")
+    t = ff.dense(t, 4, name="fc")
+    ff.softmax(t, lbl, name="softmax")
+    return ff
+
+
+def _kernel_batch(rng, tables, rows, batch=8):
+    ids = rng.integers(0, rows, size=(batch, tables)).astype(np.int32)
+    ids[1] = ids[0]          # the same rows again, far apart in flat order
+    ids[2] = (ids[0] + 1) % rows  # and their neighbours in the block
+    ids[-1] = rows - 1       # the last (edge) block
+    return {
+        "ids": ids,
+        "bag": rng.integers(190, 200, size=(batch, 3)).astype(np.int32),
+        "label": rng.integers(0, 4, size=(batch,)).astype(np.int32),
+    }
+
+
+def _events(path, name):
+    import json
+
+    with open(path) as f:
+        return [ev for ev in map(json.loads, f) if ev.get("ev") == name]
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_TABLES))
+def test_sparse_step_through_the_row_kernels_matches_dense(
+        rng, case, row_kernels_on_the_cpu, tmp_path):
+    """Plain SGD: the per-occurrence scatter-add of -lr * row_grad
+    through the kernels against the dense jnp step, and the build-time
+    ``embedding_rows`` event naming each op's addressing."""
+    from flexflow_tpu.runtime.telemetry import Telemetry
+
+    tables, rows, dim, addressing = _KERNEL_TABLES[case]
+    batch = _kernel_batch(rng, tables, rows)
+    _, pd, ld = _run(_kernel_model(False, tables, rows, dim), batch)
+    with Telemetry(str(tmp_path)) as tel:
+        ex_s, ps, ls = _run(_kernel_model(True, tables, rows, dim), batch)
+        path = tel.path
+    assert {op.name for op in ex_s._sparse_ops} == {"tables", "bagged"}
+    assert ld == pytest.approx(ls, rel=1e-6)
+    for opn in pd:
+        for k in pd[opn]:
+            np.testing.assert_allclose(
+                pd[opn][k], ps[opn][k], rtol=1e-6, atol=1e-7,
+                err_msg=f"{opn}/{k}",
+            )
+    noted = {ev["op"]: ev for ev in _events(path, "embedding_rows")}
+    assert len(_events(path, "embedding_rows")) == 2  # one an op
+    assert noted["tables"]["addressing"] == addressing
+    assert (noted["tables"]["dim"], noted["tables"]["ids"]) == (dim, 8 * tables)
+    assert noted["bagged"]["addressing"] == "lane_major"
+    assert (noted["bagged"]["dim"], noted["bagged"]["ids"]) == (32, 24)
+
+
+@pytest.mark.parametrize("optimizer", ["momentum", "adam"])
+def test_lazy_row_step_through_the_row_kernels(rng, optimizer,
+                                               row_kernels_on_the_cpu):
+    """The stateful path gathers and scatters the table AND its
+    optimizer-state buffers, all stacked (T, V, D): through the
+    lane-major kernels it must land where the XLA path lands."""
+    from flexflow_tpu.optim import AdamOptimizer
+
+    tables, rows, dim = 4, 160, 16
+    batch = _kernel_batch(rng, tables, rows)
+    batch.pop("bag")
+
+    def build():
+        cfg = FFConfig(batch_size=8, sparse_embedding_updates=True)
+        ff = FFModel(cfg)
+        ids = ff.create_tensor((8, tables), dtype=jnp.int32, name="ids")
+        lbl = ff.create_tensor((8,), dtype=jnp.int32, name="label")
+        e = ff.multi_embedding(ids, tables, rows, dim, name="tables")
+        e = ff.reshape(e, (8, tables * dim), name="r1")
+        ff.softmax(ff.dense(e, 4, name="fc"), lbl, name="softmax")
+        return ff
+
+    def opt():
+        if optimizer == "adam":
+            return AdamOptimizer(lr=0.05, lazy_sparse=True)
+        return SGDOptimizer(lr=0.2, momentum=0.9, lazy_sparse=True)
+
+    ex_k, pk_, lk = _run_opt(build(), batch, opt())
+    assert [op.name for op in ex_k._sparse_ops] == ["tables"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "cpu")
+        _, px, lx = _run_opt(build(), batch, opt())
+    assert lk == pytest.approx(lx, rel=1e-6)
+    np.testing.assert_allclose(
+        pk_["tables"]["tables"], px["tables"]["tables"], rtol=1e-6, atol=1e-7
+    )
