@@ -73,6 +73,8 @@ class Sizes:
     transformer: Tuple[str, ...]
     dlrm: Tuple[str, ...]
     serve: Tuple[str, ...]
+    #: ``apps.serve --model-config``: the DeepSeek-V3 family's preset.
+    serve_latent: Tuple[str, ...]
     #: --chips 4: the cross-chip argv of each app; the comparison run
     #: is the same argv on one device (strategy / mesh flags dropped).
     alexnet4: Tuple[str, ...]
@@ -98,6 +100,12 @@ FULL = Sizes(
           *_DLRM_ARCH),
     serve=("--max-seq", "512", "--max-batch", "8", "--requests", "12",
            *_LM_SHAPE),
+    # 256 positions: two blocks of the uneven flash forward, two lane
+    # tiles of the latent cache; bf16 as the benchmark's cell runs it.
+    serve_latent=("--model-config", "deepseek-v3-smoke", "--max-seq", "256",
+                  "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                  "--prompt-len", "100:200", "--buckets", "256",
+                  "--dtype", "bfloat16"),
     # float32 and three steps (one warm-up + two), the protocol of the
     # strategy-equivalence tests whose tolerance is applied: the
     # trajectories start equal to seven digits and round-off grows ~10x
@@ -138,6 +146,12 @@ _RELAYOUT_OPS = ("copy", "copy-start", "reshape", "transpose", "fusion")
 _HLO_SHAPE = r"\w+\[[\d,]*\](?:\{[^}]*\})?"
 _HLO_INSTRUCTION = re.compile(
     rf"^\s*(?:ROOT\s+)?%\S+ = (\(?{_HLO_SHAPE}(?:, {_HLO_SHAPE})*\)?) ([\w-]+)\(")
+
+
+def has_kernel(compiled_text: str, name: str) -> bool:
+    """Whether the compiled program calls the Pallas kernel ``name``
+    (its instruction is named after it: ``%ff_mla_decode.3 = ``)."""
+    return re.search(rf"%{re.escape(name)}[.\d]* = ", compiled_text) is not None
 
 
 def table_sized_relayouts(compiled_text: str, elements: int) -> List[str]:
@@ -402,7 +416,7 @@ def next_logits(sex, params, state, prefix: Sequence[int]) -> np.ndarray:
     bucket = sex.bucket_for(n)
     padded = np.zeros((1, bucket), np.int32)
     padded[0, :n] = prefix[:n]
-    rows, _tok, ok = sex.build_prefill(bucket)(
+    rows, _tok, ok, *_routed = sex.build_prefill(bucket)(
         params, state, padded, np.int32(n)
     )
     check(bool(ok), "prefill produced non-finite logits")
@@ -410,10 +424,10 @@ def next_logits(sex, params, state, prefix: Sequence[int]) -> np.ndarray:
     pos = np.zeros((sex.max_batch,), np.int32)
     tok = np.zeros((sex.max_batch,), np.int32)
     pos[0], tok[0] = n, prefix[n]
-    _, _, _, (_nxt, _okf, logits) = sex.build_decode_superstep(
+    _, _, _, fetched = sex.build_decode_superstep(
         1, return_logits=True
     )(params, state, caches, pos, tok)
-    return np.asarray(logits, np.float32)[0, 0]
+    return np.asarray(fetched[2], np.float32)[0, 0]
 
 
 def compare_tokens(phase: str, got: ServeRun, want: ServeRun) -> None:
@@ -460,6 +474,47 @@ def serve_phase(argv: Sequence[str]) -> None:
     compare_tokens("serve/plain", plain, oracle)
     compare_tokens("serve/sched", sched, oracle)
     serve_run("serve/paged", [*argv, "--kv-block", "16"])
+
+
+def latent_phase(argv: Sequence[str]) -> None:
+    """The DeepSeek-V3 family's preset through ``apps.serve``: the
+    expert op is in the served graph, the cache is one column of
+    ``kv_rank + rope`` values a token a layer, prefill compiles the
+    expanded path and decode the absorbed one, each with its kernel."""
+    from flexflow_tpu.ops.attention import LatentAttention
+
+    run = serve_run("serve/latent", argv)
+    sex = run.srv.ex
+    names = [op.name for op in sex._layers]
+    check(any(n.endswith("_moe") for n in names),
+          "serve/latent: the served graph dropped the expert op")
+    attn = sex.attn_ops[0]
+    check(isinstance(attn, LatentAttention), "serve/latent: no latent op")
+    caches = sex.init_cache()
+    want = (sex.max_batch, attn.row_width, sex.max_seq)
+    shapes = {tuple(c.shape) for ents in caches.values() for c in ents.values()}
+    check(shapes == {want} and all(list(e) == ["ckr"] for e in caches.values()),
+          f"serve/latent: cache {shapes}, expected one 'ckr' of {want} a layer")
+    check(sex._attention_paths(False) == "latent_expanded"
+          and sex._attention_paths(True) == "latent_absorbed",
+          "serve/latent: prefill is not expanded or decode not absorbed")
+    params, state = sex.init(sex.config.seed)
+    zeros = np.zeros((sex.max_batch,), np.int32)
+    k = int(run.stats["decode_steps_per_call"])
+    decode = sex.build_decode_superstep(k).lower(  # fflint: disable=FF006
+        params, state, caches, zeros, zeros).compile().as_text()
+    bucket = sex.buckets[-1]
+    prefill = sex.build_prefill(bucket).lower(  # fflint: disable=FF006
+        params, state, np.zeros((1, bucket), np.int32), np.int32(bucket)
+    ).compile().as_text()
+    for text, kernels, what in (
+            (decode, ("ff_mla_decode", "ff_grouped_matmul"), "decode superstep"),
+            (prefill, ("ff_flash_fwd_uneven", "ff_grouped_matmul"), "prefill")):
+        check(has_mosaic_call(text) and all(
+            has_kernel(text, name) for name in kernels),
+            f"serve/latent: the compiled {what} lacks one of {kernels}")
+    oracle = serve_run("serve/latent-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/latent", run, oracle)
 
 
 # -- four chips ---------------------------------------------------------------
@@ -547,6 +602,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
                              sz.transformer, kernels=True)),
         ("train/dlrm", lambda: dlrm_phase(sz.dlrm)),
         ("serve", lambda: serve_phase(sz.serve)),
+        ("serve/latent", lambda: latent_phase(sz.serve_latent)),
     ]
 
 

@@ -331,7 +331,8 @@ FF008_SPAN_NAMES = frozenset({
 FF008_KERNEL_NAMES = frozenset({
     "ff_flash_fwd", "ff_flash_fwd_stream", "ff_flash_dq",
     "ff_flash_dq_stream", "ff_flash_dkv", "ff_flash_dkv_stream",
-    "ff_flash_decode", "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
+    "ff_flash_decode", "ff_flash_fwd_uneven", "ff_mla_decode",
+    "ff_grouped_matmul", "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
     "ff_gather_rows", "ff_scatter_add_rows",
 })
 FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt"})

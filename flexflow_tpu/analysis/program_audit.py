@@ -297,6 +297,14 @@ def _transformer_graph():
     )
 
 
+def _latent_moe_graph():
+    """RMSNorm, LatentAttention, Multiply, the sorted MixtureOfExperts."""
+    from flexflow_tpu.models.transformer import DEEPSEEK_V3_TINY, build_lm
+
+    return build_lm({**DEEPSEEK_V3_TINY, "num_hidden_layers": 2}, 8, 8,
+                    _tiny_config(batch_size=8))
+
+
 def _serving_graph():
     """The graph ServingExecutor is audited on (no MoE: serving drives
     the plain transformer LM, apps/serve.py)."""
@@ -352,6 +360,7 @@ def catalog_models():
         ("dlrm", _dlrm_graph()),
         ("transformer_moe", _transformer_graph()),
         ("nmt", _rnn_graph()),
+        ("deepseek_v3", _latent_moe_graph()),
     ]
 
 
@@ -658,18 +667,10 @@ def _serving_cache_avals(sex):
     or the paged block pool (SERVING.md "Cache layout")."""
     import jax
 
-    B, S = sex.max_batch, sex.max_seq
-
-    def aval(h, hd, dt):
-        if sex.paged:
-            return jax.ShapeDtypeStruct(
-                (sex.kv_blocks, sex.kv_block, h, hd), dt)
-        return jax.ShapeDtypeStruct((B, S, h, hd), dt)
-
-    return {
-        name: {"k": aval(h, hd, dt), "v": aval(h, hd, dt)}
-        for name, (h, hd, dt) in sex._cache_specs.items()
-    }
+    return sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: jax.ShapeDtypeStruct(
+            sex._cache_shape(ce, sex.paged)[0], ce.dtype))
 
 
 def _serving_decode_args(sex, params, op_state, caches):
@@ -810,13 +811,10 @@ def _audit_spec(sex, d: int, prefix: str, sample,
         out += purity_violations(jaxpr, name)
     # The draft model's own caches are ALWAYS the padded layout
     # (init_draft_cache), whatever the verify caches use.
-    dcaches = {
-        name: {
-            "k": jax.ShapeDtypeStruct((B, S, h, hd), dt),
-            "v": jax.ShapeDtypeStruct((B, S, h, hd), dt),
-        }
-        for name, (h, hd, dt) in sex._draft_cache_specs.items()
-    }
+    dcaches = sex._cache_tree(
+        sex._draft_cache_specs,
+        lambda ce: jax.ShapeDtypeStruct(
+            sex._cache_shape(ce, False)[0], ce.dtype))
     pos = jax.ShapeDtypeStruct((B,), jnp.int32)
     tok = jax.ShapeDtypeStruct((B,), jnp.int32)
     args = (params, params, op_state, caches, dcaches)

@@ -26,6 +26,10 @@ Flags beyond the common set:
   --eos ID           greedy EOS token id (unset = budget-bounded)
   --no-decode-kernel force the pure-jnp decode oracle (A/B, tests)
   --vocab --d-model --heads --layers   model shape (transformer app)
+  --model-config PATH|PRESET   build the graph from a model's own
+                               configuration keys instead (a JSON file,
+                               or a preset of models/transformer.py:
+                               deepseek-v3-tiny, deepseek-v3-smoke)
 
 Capacity flags (SERVING.md "Cache layout"):
   --kv-block N       paged KV caches: N-token blocks + per-slot block
@@ -148,7 +152,11 @@ from flexflow_tpu.apps.common import (
     pop_int,
 )
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.models.transformer import build_transformer_lm
+from flexflow_tpu.models.transformer import (
+    build_lm,
+    build_transformer_lm,
+    load_model_config,
+)
 
 
 def _pop_str(argv, flag, default):
@@ -283,6 +291,10 @@ def main(argv=None) -> int:
     d_model = pop_int(argv, "--d-model", 512)
     heads = pop_int(argv, "--heads", 8)
     layers = pop_int(argv, "--layers", 4)
+    model = _pop_str(argv, "--model-config", "")
+    if model:
+        model = load_model_config(model)
+        vocab = model["vocab_size"]
     plen_s = _pop_str(argv, "--prompt-len", "4:12")
     buckets_s = _pop_str(argv, "--buckets", "")
     no_kernel = _pop_flag(argv, "--no-decode-kernel")
@@ -376,7 +388,7 @@ def main(argv=None) -> int:
         return _run_legacy(
             cfg, max_seq=max_seq, max_batch=max_batch,
             decode_steps=decode_steps, n_requests=n_requests,
-            max_new=max_new, eos=eos, vocab=vocab, d_model=d_model,
+            max_new=max_new, eos=eos, vocab=vocab, model_cfg=model, d_model=d_model,
             heads=heads, layers=layers, lo=lo, hi=hi, buckets=buckets,
             no_kernel=no_kernel, kv_block=kv_block, kv_blocks=kv_blocks,
             prefix_cache=prefix_cache,
@@ -388,7 +400,7 @@ def main(argv=None) -> int:
     return _run_scheduled(
         cfg, max_seq=max_seq, max_batch=max_batch,
         decode_steps=decode_steps, n_requests=n_requests,
-        max_new=max_new, eos=eos, vocab=vocab, d_model=d_model,
+        max_new=max_new, eos=eos, vocab=vocab, model_cfg=model, d_model=d_model,
         heads=heads, layers=layers, lo=lo, hi=hi, buckets=buckets,
         no_kernel=no_kernel, kv_block=kv_block, kv_blocks=kv_blocks,
         prefix_cache=prefix_cache,
@@ -406,8 +418,20 @@ def main(argv=None) -> int:
     )
 
 
+def _build_model(model, cfg, max_batch, max_seq, vocab, d_model, heads,
+                 layers):
+    """The served graph: from ``--model-config``'s keys, else the GPT-2
+    block family at the positional widths."""
+    if model:
+        return build_lm(model, max_batch, max_seq, cfg)
+    return build_transformer_lm(
+        batch_size=max_batch, seq_len=max_seq, vocab_size=vocab,
+        d_model=d_model, num_heads=heads, num_layers=layers, config=cfg,
+    )
+
+
 def _run_legacy(cfg, *, max_seq, max_batch, decode_steps, n_requests,
-                max_new, eos, vocab, d_model, heads, layers, lo, hi,
+                max_new, eos, vocab, d_model, heads, layers, lo, hi, model_cfg=None,
                 buckets, no_kernel, kv_block, kv_blocks, shard,
                 temperature, top_k, sample_seed, prefix_cache=False,
                 journal_path="", speculate=0, draft_ckpt="",
@@ -422,10 +446,8 @@ def _run_legacy(cfg, *, max_seq, max_batch, decode_steps, n_requests,
     )
     from flexflow_tpu.serving import RequestJournal
 
-    ff = build_transformer_lm(
-        batch_size=max_batch, seq_len=max_seq, vocab_size=vocab,
-        d_model=d_model, num_heads=heads, num_layers=layers, config=cfg,
-    )
+    ff = _build_model(model_cfg, cfg, max_batch, max_seq, vocab, d_model,
+                      heads, layers)
     sex = ServingExecutor(
         ff, cfg, max_batch=max_batch, max_seq=max_seq, buckets=buckets,
         decode_kernel=False if no_kernel else None,
@@ -481,7 +503,7 @@ def _run_legacy(cfg, *, max_seq, max_batch, decode_steps, n_requests,
 
 
 def _run_scheduled(cfg, *, max_seq, max_batch, decode_steps, n_requests,
-                   max_new, eos, vocab, d_model, heads, layers, lo, hi,
+                   max_new, eos, vocab, d_model, heads, layers, lo, hi, model_cfg=None,
                    buckets, no_kernel, kv_block, kv_blocks, shard,
                    temperature, top_k, sample_seed, policy_name,
                    prefix_cache=False,
@@ -607,11 +629,8 @@ def _run_scheduled(cfg, *, max_seq, max_batch, decode_steps, n_requests,
                 wall_s=round(res.wall_s, 3),
             )
 
-        ff = build_transformer_lm(
-            batch_size=max_batch, seq_len=max_seq, vocab_size=vocab,
-            d_model=d_model, num_heads=heads, num_layers=layers,
-            config=cfg,
-        )
+        ff = _build_model(model_cfg, cfg, max_batch, max_seq, vocab,
+                          d_model, heads, layers)
 
         def make_executor():
             return ServingExecutor(

@@ -28,16 +28,19 @@ from flexflow_tpu.ops import (
     Embedding,
     Flat,
     HeteroEmbedding,
+    LatentAttention,
     LayerNorm,
     Linear,
     MixtureOfExperts,
     MSELoss,
     MultiEmbedding,
     MultiHeadAttention,
+    Multiply,
     Op,
     Pool2D,
     PositionEmbedding,
     Reshape,
+    RMSNorm,
     SoftmaxCrossEntropy,
     TensorSpec,
     WordEmbedding,
@@ -291,7 +294,10 @@ class FFModel:
         **kw,
     ) -> TensorSpec:
         """Mixture-of-experts FFN (``top_k=1`` switch routing, the
-        default; ``top_k=2`` GShard top-2 with renormalized gates); a
+        default; ``top_k=2`` GShard top-2 with renormalized gates;
+        ``dispatch="sorted"`` the dropless grouped-product formulation
+        with its routers, gated and shared experts and
+        ``held_experts``); a
         'c' strategy degree shards experts across the mesh (the
         reference's per-table expert placement, ``dlrm_strategy.cc:5-36``,
         generalized — see ``ops/moe.py``)."""
@@ -299,6 +305,22 @@ class FFModel:
             MixtureOfExperts(self._unique("moe", name), x, num_experts,
                              ffn_dim, capacity_factor=capacity_factor, **kw)
         )
+
+    def latent_attention(self, x: TensorSpec, num_heads: int,
+                         name: Optional[str] = None, **kw) -> TensorSpec:
+        """Causal multi-head latent attention (``ops/attention.py``
+        ``LatentAttention``: ``kv_rank``, ``nope_dim``, ``rope_dim``,
+        ``v_dim``, ``rope_theta``)."""
+        return self._add(
+            LatentAttention(self._unique("latent_attention", name), x,
+                            num_heads, **kw)
+        )
+
+    def rms_norm(self, x: TensorSpec, name: Optional[str] = None, **kw) -> TensorSpec:
+        return self._add(RMSNorm(self._unique("rmsnorm", name), x, **kw))
+
+    def multiply(self, a: TensorSpec, b: TensorSpec, name: Optional[str] = None) -> TensorSpec:
+        return self._add(Multiply(self._unique("multiply", name), a, b))
 
     def layer_norm(self, x: TensorSpec, name: Optional[str] = None, **kw) -> TensorSpec:
         return self._add(LayerNorm(self._unique("layernorm", name), x, **kw))

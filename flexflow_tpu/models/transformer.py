@@ -11,7 +11,7 @@ Megatron-style tensor parallelism (``c`` on the MLP/projection dims).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, Dict, Optional
 
 import jax.numpy as jnp
 
@@ -32,34 +32,157 @@ def build_transformer_lm(
     moe_capacity_factor: float = 1.25,
     config: Optional[FFConfig] = None,
 ) -> FFModel:
-    """``moe_experts > 0`` swaps every block's dense MLP for a
-    switch-style mixture-of-experts FFN (``ops/moe.py``) — expert
-    parallelism at transformer scale (a 'c' degree on the moe ops
-    shards experts across the mesh)."""
-    d_ff = d_ff or 4 * d_model
+    """The GPT-2 block family from positional widths: the configuration
+    below handed to :func:`build_lm`.  ``moe_experts > 0`` swaps every
+    block's dense MLP for a switch-style mixture-of-experts FFN
+    (``ops/moe.py``) — expert parallelism at transformer scale (a 'c'
+    degree on the moe ops shards experts across the mesh)."""
+    return build_lm(
+        {"model_type": "gpt2", "vocab_size": vocab_size, "n_embd": d_model,
+         "n_head": num_heads, "n_layer": num_layers, "n_inner": d_ff,
+         "moe_experts": moe_experts,
+         "moe_capacity_factor": moe_capacity_factor},
+        batch_size, seq_len, config,
+    )
+
+
+def build_lm(model: Dict[str, Any], batch_size: int, seq_len: int,
+             config: Optional[FFConfig] = None) -> FFModel:
+    """A decoder-only LM from a configuration's keys, as the model's
+    own ``config.json`` names them; ``model_type`` picks the block
+    family.  What a key cannot say here is an error, not a default."""
+    kind = model.get("model_type", "gpt2")
+    if kind not in _BLOCKS:
+        raise ValueError(
+            f"no block family for model_type {kind!r}: {sorted(_BLOCKS)}")
     ff = FFModel(config or FFConfig(batch_size=batch_size))
     tok = ff.create_tensor((batch_size, seq_len), dtype=jnp.int32,
                            name="tokens", dim_axes=("n", "s"))
     lbl = ff.create_tensor((batch_size, seq_len), dtype=jnp.int32,
                            name="label", dim_axes=("n", "s"))
-    x = ff.word_embedding(tok, vocab_size, d_model, name="embed")
-    x = ff.position_embedding(x, name="pos")
-    for i in range(num_layers):
-        a = ff.layer_norm(x, name=f"blk{i}_ln1")
-        a = ff.multihead_attention(a, num_heads, causal=True, name=f"blk{i}_attn")
-        x = ff.add(x, a, name=f"blk{i}_res1")
-        m = ff.layer_norm(x, name=f"blk{i}_ln2")
-        if moe_experts:
-            m = ff.moe(m, moe_experts, d_ff,
-                       capacity_factor=moe_capacity_factor, name=f"blk{i}_moe")
-        else:
-            m = ff.dense(m, d_ff, activation="gelu", name=f"blk{i}_mlp_up")
-            m = ff.dense(m, d_model, name=f"blk{i}_mlp_down")
-        x = ff.add(x, m, name=f"blk{i}_res2")
-    x = ff.layer_norm(x, name="ln_f")
-    logits = ff.dense(x, vocab_size, name="lm_head")
+    logits = _BLOCKS[kind](ff, tok, model)
     ff.softmax(logits, lbl, name="softmax")
     return ff
+
+
+def _gpt2_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """GPT-2 (Radford et al. 2019): learned positions, pre-LN blocks of
+    full multi-head attention and a 4x GELU MLP, LayerNorm, a head."""
+    d_model, moe_experts = m["n_embd"], m.get("moe_experts", 0)
+    d_ff = m.get("n_inner") or 4 * d_model
+    x = ff.word_embedding(tok, m["vocab_size"], d_model, name="embed")
+    x = ff.position_embedding(x, name="pos")
+    for i in range(m["n_layer"]):
+        a = ff.layer_norm(x, name=f"blk{i}_ln1")
+        a = ff.multihead_attention(a, m["n_head"], causal=True,
+                                   name=f"blk{i}_attn")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.layer_norm(x, name=f"blk{i}_ln2")
+        if moe_experts:
+            h = ff.moe(h, moe_experts, d_ff,
+                       capacity_factor=m.get("moe_capacity_factor", 1.25),
+                       name=f"blk{i}_moe")
+        else:
+            h = ff.dense(h, d_ff, activation="gelu", name=f"blk{i}_mlp_up")
+            h = ff.dense(h, d_model, name=f"blk{i}_mlp_down")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.layer_norm(x, name="ln_f")
+    return ff.dense(x, m["vocab_size"], name="lm_head")
+
+
+def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """The DeepSeek-V3 block family (``transformers``' ``DeepseekV3``):
+    RMSNorm, latent attention with rotary positions on a sub-width of
+    the head, ``first_k_dense_replace`` leading gated-SiLU dense layers,
+    then expert layers under a sigmoid top-k router with a selection
+    bias and shared experts; no bias anywhere, an untied head.
+    ``held_experts`` (not a key of the source: the deployment's) names
+    the routed experts this chip holds, default all."""
+    for key, want in (("q_lora_rank", None), ("rope_scaling", None),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("moe_layer_freq", 1), ("attention_bias", False),
+                      ("hidden_act", "silu"), ("rope_interleave", True),
+                      ("tie_word_embeddings", False)):
+        if m.get(key, want) != want:
+            raise ValueError(
+                f"deepseek_v3 builder: {key}={m[key]!r} is not built yet "
+                f"(only {want!r})")
+    if m.get("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring_func {m['scoring_func']!r}")
+    d, eps = m["hidden_size"], m["rms_norm_eps"]
+    # A table in the compute dtype (the family policy keeps it f32 for
+    # the sparse-update kernels, which this family does not train with).
+    x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
+                          dtype=jnp.dtype(ff.config.compute_dtype))
+    for i in range(m["num_hidden_layers"]):
+        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
+        a = ff.latent_attention(
+            a, m["num_attention_heads"], kv_rank=m["kv_lora_rank"],
+            nope_dim=m["qk_nope_head_dim"], rope_dim=m["qk_rope_head_dim"],
+            v_dim=m["v_head_dim"], rope_theta=m["rope_theta"], norm_eps=eps,
+            name=f"blk{i}_attn")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
+        if i < m["first_k_dense_replace"]:
+            g = ff.dense(h, m["intermediate_size"], activation="silu",
+                         use_bias=False, name=f"blk{i}_mlp_gate")
+            u = ff.dense(h, m["intermediate_size"], use_bias=False,
+                         name=f"blk{i}_mlp_up")
+            h = ff.dense(ff.multiply(g, u, name=f"blk{i}_mlp_act"), d,
+                         use_bias=False, name=f"blk{i}_mlp_down")
+        else:
+            h = ff.moe(
+                h, m["n_routed_experts"], m["moe_intermediate_size"],
+                top_k=m["num_experts_per_tok"], dispatch="sorted",
+                router=m.get("scoring_func", "sigmoid"), gated=True,
+                activation="silu", shared_experts=m["n_shared_experts"],
+                selection_bias=m.get("topk_method") == "noaux_tc",
+                norm_topk_prob=m["norm_topk_prob"],
+                routed_scale=m["routed_scaling_factor"],
+                held_experts=m.get("held_experts"), name=f"blk{i}_moe")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
+
+
+_BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm}
+
+#: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
+#: audit catalog): every mechanism of the block, no published width.
+DEEPSEEK_V3_TINY: Dict[str, Any] = {
+    "model_type": "deepseek_v3", "vocab_size": 512, "hidden_size": 64,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "intermediate_size": 128, "num_attention_heads": 4, "kv_lora_rank": 32,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "rope_theta": 1e6, "rms_norm_eps": 1e-6, "n_routed_experts": 8,
+    "num_experts_per_tok": 2, "n_shared_experts": 1,
+    "moe_intermediate_size": 32, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "norm_topk_prob": True,
+    "routed_scaling_factor": 2.448,
+}
+
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip (whole 128-lane tiles in the expert products): chip_smoke.py.
+DEEPSEEK_V3_SMOKE: Dict[str, Any] = {
+    **DEEPSEEK_V3_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "intermediate_size": 512, "moe_intermediate_size": 128,
+    "kv_lora_rank": 128, "qk_nope_head_dim": 64, "qk_rope_head_dim": 32,
+    "v_head_dim": 64,
+}
+
+PRESETS = {"deepseek-v3-tiny": DEEPSEEK_V3_TINY,
+           "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE}
+
+
+def load_model_config(name_or_path: str) -> Dict[str, Any]:
+    """A preset by name, else the JSON file at the path."""
+    if name_or_path in PRESETS:
+        return dict(PRESETS[name_or_path])
+    import json
+
+    with open(name_or_path) as f:
+        return json.load(f)
 
 
 def transformer_strategy(

@@ -106,6 +106,9 @@ KERNEL_CATALOG = frozenset({
     "ff_flash_dkv",
     "ff_flash_dkv_stream",
     "ff_flash_decode",
+    "ff_flash_fwd_uneven",
+    "ff_mla_decode",
+    "ff_grouped_matmul",
     "ff_softmax_xent_fwd",
     "ff_softmax_xent_bwd",
     "ff_gather_rows",

@@ -1,15 +1,28 @@
-from flexflow_tpu.ops.attention import LayerNorm, MultiHeadAttention, PositionEmbedding
-from flexflow_tpu.ops.base import Op, ParamSpec, TensorSpec
+from flexflow_tpu.ops.attention import (
+    LatentAttention,
+    LayerNorm,
+    MultiHeadAttention,
+    PositionEmbedding,
+)
+from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
 from flexflow_tpu.ops.conv import Conv2D, Flat, Pool2D
 from flexflow_tpu.ops.embedding import Embedding, HeteroEmbedding, MultiEmbedding, WordEmbedding
 from flexflow_tpu.ops.linear import Linear
 from flexflow_tpu.ops.losses import MSELoss, SoftmaxCrossEntropy
 from flexflow_tpu.ops.moe import MixtureOfExperts
-from flexflow_tpu.ops.norm import BatchNorm
+from flexflow_tpu.ops.norm import BatchNorm, RMSNorm
 from flexflow_tpu.ops.rnn import LSTM
-from flexflow_tpu.ops.tensor_ops import Add, Concat, DotInteraction, Dropout, Reshape
+from flexflow_tpu.ops.tensor_ops import (
+    Add,
+    Concat,
+    DotInteraction,
+    Dropout,
+    Multiply,
+    Reshape,
+)
 
 __all__ = [
+    "CacheEntry",
     "Op",
     "ParamSpec",
     "TensorSpec",
@@ -27,11 +40,14 @@ __all__ = [
     "Concat",
     "DotInteraction",
     "Dropout",
+    "LatentAttention",
     "LayerNorm",
     "MixtureOfExperts",
     "MultiHeadAttention",
     "PositionEmbedding",
     "Reshape",
+    "RMSNorm",
+    "Multiply",
     "SoftmaxCrossEntropy",
     "MSELoss",
 ]

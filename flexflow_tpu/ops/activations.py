@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-VALID_ACTIVATIONS = (None, "none", "relu", "sigmoid", "tanh", "gelu")
+VALID_ACTIVATIONS = (None, "none", "relu", "sigmoid", "tanh", "gelu", "silu")
 
 
 def check_activation(activation) -> None:
@@ -34,4 +34,8 @@ def apply_activation(x, activation):
         import jax
 
         return jax.nn.gelu(x)
+    if activation == "silu":
+        import jax
+
+        return jax.nn.silu(x)
     raise ValueError(f"unknown activation {activation!r}")

@@ -29,7 +29,8 @@ from jax.sharding import PartitionSpec
 
 from flexflow_tpu.initializers import GlorotUniform, OnesInitializer, ZeroInitializer
 from flexflow_tpu.ops import pallas_kernels
-from flexflow_tpu.ops.base import Op, ParamSpec, TensorSpec
+from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
+from flexflow_tpu.ops.norm import rms_norm
 
 _NEG_INF = -1e30
 
@@ -197,6 +198,20 @@ class MultiHeadAttention(Op):
             specs["bv"] = ParamSpec((d,), dt, ZeroInitializer(), ("c",))
             specs["bo"] = ParamSpec((d,), dt, ZeroInitializer())
         return specs
+
+    cache_paged = True
+
+    def cache_entries(self, max_seq: int) -> Dict[str, CacheEntry]:
+        d = self.inputs[0].shape[-1]
+        h = self.attrs["num_heads"]
+        row = CacheEntry((max_seq, h, d // h), self.outputs[0].dtype,
+                         (None, "c", None))
+        return {"k": row, "v": row}
+
+    def serving_path(self, decode: bool) -> str:
+        """Which attention formulation a serving program of this op
+        compiles (the ``serving_program`` event's ``attention``)."""
+        return "kv_decode" if decode else "kv_dense"
 
     # -- helpers -----------------------------------------------------------
 
@@ -627,3 +642,202 @@ class MultiHeadAttention(Op):
                 o_j, lse_j = attend()
             o, lse = _merge_lse(o, lse, o_j, lse_j)
         return self._merge_heads(o, dtype)
+
+
+def rope_interleaved(x, pos, theta: float):
+    """Rotary embedding over adjacent pairs ``(2i, 2i+1)`` of the last
+    dim (DeepSeek's ``rope_interleave``), in f32: pair i turns by
+    ``pos * theta^(-2i/d)``.  ``x``: (..., t, d) with ``pos`` (..., t)
+    broadcasting against its leading dims."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
+    ang = pos.astype(jnp.float32)[..., None] * inv                 # (..., t, d/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(Op):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA) over
+    (batch, seq, dim), causal.
+
+    Keys and values of every head are expanded from one low-rank latent
+    a token: ``[c~ | k~_r] = x W_kva`` (``kv_rank + rope``), ``c =
+    RMSNorm(c~)``, ``k_r = RoPE(k~_r)`` (one rotary key shared by every
+    head), ``[k_nope | v] = c W_kvb``.  A head's score is ``(q_nope .
+    k_nope + RoPE(q_rope) . k_r) / sqrt(nope + rope)``.
+
+    Two formulations of the same attention:
+
+    - **expanded** (training, eval, serving prefill): K and V are
+      materialised a head and ordinary causal attention runs at q.k
+      width ``nope + rope`` and v width ``v_dim``.
+    - **absorbed** (serving decode): ``W_kvb`` is folded into the query
+      and the output (``q^_h = q_nope,h W_K,h^T``, ``o_h = (sum_j p_j
+      c_j) W_V,h``), so attention runs over the latent itself and the
+      cache holds ``[c_j | k_r,j]``: ``kv_rank + rope`` values a token,
+      shared by every head.
+
+    Strategy axes: ``c`` shards heads (the q, kv-expansion and output
+    projections carry the tag on their head dim); ``n`` the batch.
+    """
+
+    serving_aware = True
+
+    def __init__(self, name: str, x: TensorSpec, num_heads: int,
+                 kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                 kernel_initializer=None):
+        super().__init__(name, [x])
+        assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
+        assert rope_dim % 2 == 0, rope_dim
+        self.attrs = dict(num_heads=num_heads, kv_rank=kv_rank,
+                          nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                          rope_theta=float(rope_theta), norm_eps=norm_eps,
+                          causal=True)
+        self.kernel_initializer = kernel_initializer or GlorotUniform()
+        self._make_output(x.shape, x.dtype, x.dim_axes)
+
+    #: None = the decode kernel where the cache shape allows it.
+    decode_kernel: Optional[bool] = None
+
+    def param_specs(self) -> Dict[str, ParamSpec]:
+        a = self.attrs
+        d = self.inputs[0].shape[-1]
+        h, r = a["num_heads"], a["kv_rank"]
+        dt = self.outputs[0].dtype
+        ki = self.kernel_initializer
+        return {
+            "wq": ParamSpec((d, h * (a["nope_dim"] + a["rope_dim"])), dt, ki,
+                            (None, "c")),
+            "wkv_a": ParamSpec((d, r + a["rope_dim"]), dt, ki),
+            "kv_norm": ParamSpec((r,), dt, OnesInitializer()),
+            "wkv_b": ParamSpec((r, h * (a["nope_dim"] + a["v_dim"])), dt, ki,
+                               (None, "c")),
+            "wo": ParamSpec((h * a["v_dim"], d), dt, ki, ("c", None)),
+        }
+
+    @property
+    def row_width(self) -> int:
+        return self.attrs["kv_rank"] + self.attrs["rope_dim"]
+
+    def cache_entries(self, max_seq: int) -> Dict[str, CacheEntry]:
+        # One column a token, positions last: see pallas_kernels.mla_decode.
+        return {"ckr": CacheEntry((self.row_width, max_seq),
+                                  self.outputs[0].dtype)}
+
+    def serving_path(self, decode: bool) -> str:
+        return "latent_absorbed" if decode else "latent_expanded"
+
+    # -- shared pieces -------------------------------------------------------
+
+    def _latent(self, params, x, pos):
+        """``(q_nope, q_rope, c, k_r)``: queries a head (b, t, h, .),
+        rotary parts turned to their positions ``pos`` (b, t); the
+        normalised latent (b, t, kv_rank) and the shared rotary key
+        (b, t, rope)."""
+        a = self.attrs
+        b, t, _ = x.shape
+        h, r = a["num_heads"], a["kv_rank"]
+        q = (x @ params["wq"]).reshape(b, t, h, a["nope_dim"] + a["rope_dim"])
+        q_nope, q_rope = q[..., :a["nope_dim"]], q[..., a["nope_dim"]:]
+        ckr = x @ params["wkv_a"]
+        c = rms_norm(ckr[..., :r], params["kv_norm"], a["norm_eps"])
+        k_r = rope_interleaved(ckr[..., r:], pos, a["rope_theta"])
+        q_rope = rope_interleaved(
+            q_rope.transpose(0, 2, 1, 3), pos[:, None, :], a["rope_theta"]
+        ).transpose(0, 2, 1, 3)
+        return q_nope, q_rope, c, k_r
+
+    def _expanded(self, params, q_nope, q_rope, c, k_r, serving: bool):
+        """Causal attention with K and V expanded a head; (b, t, h*v)."""
+        a = self.attrs
+        b, t, h, _ = q_nope.shape
+        kv = (c @ params["wkv_b"]).reshape(b, t, h, a["nope_dim"] + a["v_dim"])
+        k_nope, v = kv[..., :a["nope_dim"]], kv[..., a["nope_dim"]:]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, a["rope_dim"]))],
+            axis=-1,
+        ).transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        plan = getattr(self, "_plan", None)
+        if serving and (plan is None or plan.num_devices == 1) and \
+                pallas_kernels.flash_uneven_supported(q.shape, a["v_dim"]):
+            out = pallas_kernels.flash_fwd_uneven(
+                q, k, v, 1.0 / math.sqrt(q.shape[-1]))
+        else:
+            out = _einsum_attention(q, k, v, True)
+        return out.transpose(0, 2, 1, 3).reshape(b, t, h * a["v_dim"])
+
+    def forward(self, params, xs, state, training):
+        (x,) = xs
+        if "cache_ckr" in state:
+            return self._forward_cached(params, x, state)
+        b, t, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+        out = self._expanded(params, *self._latent(params, x, pos),
+                             serving=False)
+        return [out @ params["wo"]], state
+
+    # -- the latent cache (runtime/serving.py) -------------------------------
+
+    def _forward_cached(self, params, x, state):
+        """Prefill (t > 1): the expanded attention over the call's own
+        tokens, writing post-norm ``c`` and post-RoPE ``k_r`` into cache
+        columns ``0..t-1``.  Decode (t == 1): the token at ``pos``
+        writes its column and attends columns ``<= pos`` absorbed."""
+        a = self.attrs
+        cache = state["cache_ckr"]                      # (B, row, S)
+        b, t, _ = x.shape
+        h, r = a["num_heads"], a["kv_rank"]
+        if "block_table" in state or "chunk" in state:
+            raise NotImplementedError(
+                f"{self.name}: the latent cache has no paged pool or "
+                f"offset prefill yet (ROADMAP Queue B)")
+        new_state = dict(state)
+        if t > 1:
+            pos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+            q_nope, q_rope, c, k_r = self._latent(params, x, pos)
+            col = jnp.concatenate([c, k_r], axis=-1).astype(cache.dtype)
+            new_state["cache_ckr"] = cache.at[:, :, :t].set(
+                col.transpose(0, 2, 1))
+            out = self._expanded(params, q_nope, q_rope, c, k_r, serving=True)
+            return [out @ params["wo"]], new_state
+        pos = state["pos"]                              # (B,)
+        q_nope, q_rope, c, k_r = self._latent(params, x, pos[:, None])
+        col = jnp.concatenate([c, k_r], axis=-1)[:, 0].astype(cache.dtype)
+        # One in-place column write a slot.  A scatter along the last
+        # axis makes the compiler hold the whole cache positions-major
+        # and copy it back for the kernel, every layer of every step.
+        for i in range(b):
+            cache = lax.dynamic_update_slice(
+                cache, col[i][None, :, None], (i, 0, pos[i]))
+        new_state["cache_ckr"] = cache
+        wkv_b = params["wkv_b"].reshape(r, h, a["nope_dim"] + a["v_dim"])
+        w_k, w_v = wkv_b[..., :a["nope_dim"]], wkv_b[..., a["nope_dim"]:]
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k)
+        q_cat = jnp.concatenate([q_lat.astype(x.dtype), q_rope[:, 0]], axis=-1)
+        scale = 1.0 / math.sqrt(a["nope_dim"] + a["rope_dim"])
+        use = self.decode_kernel
+        supported = pallas_kernels.mla_decode_supported(cache.shape, r)
+        if use is None or (use and not supported):
+            use = supported
+        if use:
+            o_lat = pallas_kernels.mla_decode(q_cat, cache, pos + 1, r, scale)
+        else:
+            o_lat = _latent_decode(q_cat, cache, pos, r, scale)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
+        return [o.reshape(b, 1, h * a["v_dim"]) @ params["wo"]], new_state
+
+
+def _latent_decode(q_cat, cache, pos, v_width: int, scale: float):
+    """Dense oracle of ``pallas_kernels.mla_decode``: f32 scores over
+    the whole (B, row, S) cache, masked to positions ``<= pos``."""
+    cf = cache.astype(jnp.float32)
+    s = jnp.einsum("bhr,brs->bhs", q_cat.astype(jnp.float32), cf) * scale
+    mask = jnp.arange(cache.shape[-1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, _NEG_INF), axis=-1)
+    return jnp.einsum("bhs,bvs->bhv", p, cf[:, :v_width]).astype(q_cat.dtype)

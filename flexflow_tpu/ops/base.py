@@ -39,6 +39,26 @@ class ParamSpec:
             self.dim_axes = tuple(None for _ in self.shape)
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheEntry:
+    """One array an attention-like op keeps for a slot while serving
+    (``Op.cache_entries``): the serving executor allocates ``(slots,) +
+    shape`` of ``dtype`` for it (SERVING.md "Cache layout") and hands it
+    to ``forward`` as ``state["cache_<entry>"]``.  Where the sequence
+    axis lies in ``shape`` is the op's business (keys and values are
+    ``(max_seq, heads, d_head)``; the latent cache puts the sequence
+    last, the order the chip stores a 576-wide row in anyway).  ``axes``
+    tags the dims for sharded decode ('c' = heads)."""
+
+    shape: Tuple[int, ...]
+    dtype: Any
+    axes: Tuple[Optional[str], ...] = ()
+
+    def __post_init__(self):
+        if not self.axes:
+            object.__setattr__(self, "axes", tuple(None for _ in self.shape))
+
+
 @dataclasses.dataclass
 class TensorSpec:
     """Symbolic tensor in the op graph (the reference's ``Tensor`` /
@@ -82,6 +102,28 @@ class Op:
 
     def state_specs(self) -> Dict[str, ParamSpec]:
         """Non-trained mutable state (e.g. batchnorm running stats)."""
+        return {}
+
+    # -- serving ----------------------------------------------------------
+
+    #: Whether the op's cache entries can live in the paged block pool
+    #: (``kv_block > 0``); an op that cannot is refused there by name.
+    cache_paged = False
+    #: Ops that take a forward-only kernel path, or report counters,
+    #: when serving get ``state["serving"] = True`` from the serving
+    #: executor (training and eval never set it).
+    serving_aware = False
+    #: Names of the scalar counters a serving-aware op hands back as
+    #: ``new_state["stats"]`` when serving (empty = none): the serving
+    #: programs stack them beside the tokens, and ``Server.run`` writes
+    #: them on the step's event.
+    serving_stats: Tuple[str, ...] = ()
+
+    def cache_entries(self, max_seq: int) -> Dict[str, "CacheEntry"]:
+        """What the op keeps for a slot of ``max_seq`` positions while
+        serving: entry name -> array (empty = the op holds no cache).
+        The serving executor holds and carries whatever is declared,
+        by name."""
         return {}
 
     # -- mesh binding -----------------------------------------------------

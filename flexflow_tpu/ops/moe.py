@@ -24,16 +24,36 @@ Design notes (TPU-first):
   loss-op protocol of the consumer; here it is exposed as an output
   metric hook: `aux_loss_weight` > 0 adds it into the training loss
   through ``is_loss`` accounting.
+
+A second formulation inside the same op, ``dispatch="sorted"`` (what
+the DeepSeek-V3 family's expert layers need): no capacity, no dropped
+token, no ``(S, E, C)`` one-hot tensors.  The (token, choice)
+assignments are sorted by expert, padded so that every tile of rows
+belongs to one expert, and one grouped matrix product runs over the
+experts that received any (``pallas_kernels.grouped_matmul`` when
+serving, ``lax.ragged_dot`` otherwise: differentiable, and the oracle).
+With it come the routers beyond softmax-top-k (``router="sigmoid"``
+scores, a selection bias that chooses but does not weigh, normalised and
+scaled weights), gated expert MLPs, shared experts every token passes
+through, and ``held_experts``: the experts THIS chip holds.  The router
+keeps its full width and its experts per token; the op computes its own
+experts' part of the result (plus the shared experts, which every chip
+computes alike) and leaves the rest out.  A router without an auxiliary
+loss makes the op an ordinary one (``is_loss`` false), which is what
+lets the serving executor keep it.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional, Sequence
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.initializers import GlorotUniform, ZeroInitializer
+from flexflow_tpu.ops import pallas_kernels
 from flexflow_tpu.ops.activations import apply_activation, check_activation
 from flexflow_tpu.ops.base import Op, ParamSpec, TensorSpec
 
@@ -66,8 +86,39 @@ class MixtureOfExperts(Op):
         aux_loss_weight: float = 1e-2,
         top_k: int = 1,
         kernel_initializer=None,
+        dispatch: str = "capacity",
+        router: str = "softmax",
+        gated: bool = False,
+        shared_experts: int = 0,
+        held_experts: Optional[Sequence[int]] = None,
+        selection_bias: bool = False,
+        norm_topk_prob: bool = True,
+        routed_scale: float = 1.0,
     ):
         super().__init__(name, [x])
+        if dispatch not in ("capacity", "sorted"):
+            raise ValueError(f"moe dispatch {dispatch!r}: capacity or sorted")
+        if router not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe router {router!r}: softmax or sigmoid")
+        if dispatch == "capacity" and (
+                router != "softmax" or gated or shared_experts
+                or held_experts is not None or selection_bias
+                or routed_scale != 1.0):
+            raise ValueError(
+                f"moe {name}: sigmoid routing, gated or shared experts, a "
+                f"selection bias and held_experts need dispatch='sorted'")
+        held = tuple(range(num_experts)) if held_experts is None else \
+            tuple(sorted(int(e) for e in held_experts))
+        if not held or len(set(held)) != len(held) or \
+                held[0] < 0 or held[-1] >= num_experts:
+            raise ValueError(
+                f"moe {name}: held_experts {held_experts!r} must be distinct "
+                f"ids in [0, {num_experts})")
+        #: The sorted formulation has no auxiliary loss: an ordinary op.
+        self.is_loss = dispatch == "capacity"
+        if dispatch == "sorted":
+            self.serving_stats = ("experts_touched", "expert_load_max")
+        self.held = held
         assert x.ndim == 3, f"moe input must be (batch, seq, d), got {x.shape}"
         check_activation(activation)
         b, t, d = x.shape
@@ -93,6 +144,13 @@ class MixtureOfExperts(Op):
             # with gates renormalized over the chosen k).  Static
             # shapes: k one-hot dispatch slots, no dynamic scatter.
             top_k=top_k,
+            dispatch=dispatch,
+            router=router,
+            gated=gated,
+            shared_experts=int(shared_experts),
+            selection_bias=bool(selection_bias),
+            norm_topk_prob=bool(norm_topk_prob),
+            routed_scale=float(routed_scale),
         )
         self.d_model = d
         self.kernel_initializer = kernel_initializer or GlorotUniform()
@@ -120,6 +178,8 @@ class MixtureOfExperts(Op):
         f = self.attrs["ffn_dim"]
         dt = self.outputs[0].dtype
         ki = self.kernel_initializer
+        if self.attrs["dispatch"] == "sorted":
+            return self._sorted_param_specs(d, e, f, dt, ki)
         return {
             # Router stays replicated (tiny).
             "gate": ParamSpec((d, e), dt, ki),
@@ -134,6 +194,8 @@ class MixtureOfExperts(Op):
 
     def forward(self, params, xs, state, training):
         (x,) = xs
+        if self.attrs["dispatch"] == "sorted":
+            return self._forward_sorted(params, x, state)
         b, t, d = x.shape
         e = self.attrs["num_experts"]
         s = b * t
@@ -205,3 +267,144 @@ class MixtureOfExperts(Op):
             f"{self.name}_dropped": jnp.float32(s * k) - keep_total,
         }
         return (loss, metrics, [y.reshape(b, t, d)]), state
+
+    # -- the sorted (dropless) formulation -----------------------------------
+
+    serving_aware = True
+
+    def _sorted_param_specs(self, d, e, f, dt, ki) -> Dict[str, ParamSpec]:
+        a = self.attrs
+        eh = len(self.held)
+        tag = ("c", None, None)
+        # The router is held and run in f32, whatever the model's dtype.
+        specs = {"gate": ParamSpec((d, e), jnp.float32, ki)}
+        if a["selection_bias"]:
+            specs["e_bias"] = ParamSpec((e,), jnp.float32, ZeroInitializer())
+        if a["gated"]:
+            specs["w_gate"] = ParamSpec((eh, d, f), dt, ki, tag)
+            specs["w_up"] = ParamSpec((eh, d, f), dt, ki, tag)
+            specs["w_down"] = ParamSpec((eh, f, d), dt, ki, tag)
+        else:
+            specs["w1"] = ParamSpec((eh, d, f), dt, ki, tag)
+            specs["w2"] = ParamSpec((eh, f, d), dt, ki, tag)
+        fs = a["shared_experts"] * f
+        if fs:
+            if a["gated"]:
+                specs["s_gate"] = ParamSpec((d, fs), dt, ki, (None, "c"))
+            specs["s_up"] = ParamSpec((d, fs), dt, ki, (None, "c"))
+            specs["s_down"] = ParamSpec((fs, d), dt, ki, ("c", None))
+        return specs
+
+    def route(self, params, xf):
+        """``(idx (T, k) int32, w (T, k) f32)``: the experts a token
+        chooses, over the router's full width, and the weights of
+        their outputs.  All in f32, the product at full precision (a
+        bf16 product flips choices between near-equal scores)."""
+        a = self.attrs
+        logits = jnp.dot(xf.astype(jnp.float32), params["gate"],
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if a["router"] == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        choice = scores + params["e_bias"] if a["selection_bias"] else scores
+        _, idx = jax.lax.top_k(choice, a["top_k"])
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if a["top_k"] > 1 and a["norm_topk_prob"]:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx, w * a["routed_scale"]
+
+    def _mlp(self, x, p, names, product, fused_gate=None):
+        """One (gated) MLP over the leaves ``p[names]`` (``(in, out)``,
+        or gated ``(gate, up, down)``) given how to multiply:
+        ``product(x, w)``, and where a kernel has it ``fused_gate(x,
+        w_gate, w_up) = silu(x w_gate) * (x w_up)``."""
+        act = self.attrs["activation"]
+        if not self.attrs["gated"]:
+            w_in, w_out = (p[n] for n in names)
+            return product(apply_activation(product(x, w_in), act), w_out)
+        w_gate, w_up, w_down = (p[n] for n in names)
+        if fused_gate is not None and act == "silu":
+            return product(fused_gate(x, w_gate, w_up), w_down)
+        return product(apply_activation(product(x, w_gate), act)
+                       * product(x, w_up), w_down)
+
+    def _forward_sorted(self, params, x, state):
+        a = self.attrs
+        b, t, d = x.shape
+        T, k, e, eh = b * t, a["top_k"], a["num_experts"], len(self.held)
+        A = T * k
+        xf = x.reshape(T, d)
+        idx, w = self.route(params, xf)
+        # Global expert id -> row of this chip's expert arrays, or eh
+        # for an expert held elsewhere (its assignments sort last and
+        # are left out).
+        local_of = np.full((e,), eh, np.int32)
+        local_of[list(self.held)] = np.arange(eh, dtype=np.int32)
+        local = jnp.asarray(local_of)[idx].reshape(A)
+        here = local < eh
+        counts = jnp.sum(local[:, None] == jnp.arange(eh)[None, :], axis=0,
+                         dtype=jnp.int32)                        # (eh,)
+        tm = pallas_kernels.grouped_tile_rows(A, eh)
+        rows = -(-(A + min(eh, A) * (tm - 1)) // tm) * tm
+        padded = -(-counts // tm) * tm
+        p_end = jnp.cumsum(padded)
+        start = jnp.cumsum(counts) - counts
+        # Stable sort by expert: position p of the sorted order, whose
+        # expert is key[p], goes to row p_start[key] + (p - start[key]).
+        key, tok, slot = jax.lax.sort(
+            (local, jnp.repeat(jnp.arange(T, dtype=jnp.int32), k),
+             jnp.arange(A, dtype=jnp.int32)), num_keys=1)
+        kc = jnp.minimum(key, eh - 1)
+        dest = jnp.where(key < eh,
+                         (p_end - padded)[kc] + jnp.arange(A) - start[kc],
+                         rows)
+        src_tok = jnp.zeros((rows,), jnp.int32).at[dest].set(tok, mode="drop")
+        row_of = jnp.zeros((A,), jnp.int32).at[slot].set(
+            jnp.minimum(dest, rows - 1))
+        xs = xf[src_tok]                                         # (rows, d)
+
+        serving = bool(state.get("serving"))
+        f = a["ffn_dim"]
+        if serving and pallas_kernels.grouped_matmul_supported(d, f, x.dtype) \
+                and pallas_kernels.grouped_matmul_supported(f, d, x.dtype):
+            n_tiles = rows // tm
+            used = p_end[-1] // tm
+            tile_e = jnp.sum(
+                p_end[None, :] <= (jnp.arange(n_tiles) * tm)[:, None], axis=1)
+            last_e = jnp.minimum(tile_e[jnp.maximum(used - 1, 0)], eh - 1)
+            tile_e = jnp.where(jnp.arange(n_tiles) < used, tile_e, last_e)
+
+            def product(x, w, w_up=None):
+                return pallas_kernels.grouped_matmul(
+                    x, w, tile_e, used, tm, w_up=w_up)
+
+            fused_gate = product
+        else:
+            fused_gate = None
+
+            def product(x, w):
+                return jax.lax.ragged_dot(x, w, padded)
+
+        routed = ("w_gate", "w_up", "w_down") if a["gated"] else ("w1", "w2")
+        ys = self._mlp(xs, params, routed, product, fused_gate)
+        # Back to (token, choice) order; an assignment held elsewhere
+        # reads some row and is masked (not multiplied: the row may be
+        # one the kernel never wrote).
+        y_tk = ys[row_of].reshape(T, k, d).astype(jnp.float32)
+        y = jnp.sum(jnp.where(here.reshape(T, k, 1), y_tk * w[..., None], 0.0),
+                    axis=1)
+        if a["shared_experts"]:
+            shared = ("s_gate", "s_up", "s_down") if a["gated"] else \
+                ("s_up", "s_down")
+            y = y + self._mlp(xf, params, shared,
+                              lambda x, w: x @ w).astype(jnp.float32)
+        out = [y.astype(x.dtype).reshape(b, t, d)]
+        if not serving:
+            return out, state
+        # Routing counters, fetched at the fence the step already has.
+        new_state = dict(state)
+        new_state["stats"] = {
+            "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
+            "expert_load_max": jnp.max(counts).astype(jnp.float32)
+            * eh / jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32),
+        }
+        return out, new_state

@@ -1,4 +1,4 @@
-"""Batch normalization.
+"""Batch normalization, and RMS normalization.
 
 Reference: ``src/ops/batch_norm.cu`` — cudnnBatchNormalizationForward
 Training/Backward with per-shard running mean/var cached in
@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.initializers import OnesInitializer, ZeroInitializer
@@ -75,3 +76,27 @@ class BatchNorm(Op):
         if self.attrs["relu"]:
             y = apply_activation(y, "relu")
         return [y], new_state
+
+
+class RMSNorm(Op):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last dim, in f32
+    (no mean, no bias: the norm of the Llama/DeepSeek block family)."""
+
+    def __init__(self, name: str, x: TensorSpec, eps: float = 1e-6):
+        super().__init__(name, [x])
+        self.attrs = dict(eps=eps)
+        self._make_output(x.shape, x.dtype, x.dim_axes)
+
+    def param_specs(self) -> Dict[str, ParamSpec]:
+        d = self.inputs[0].shape[-1]
+        return {"scale": ParamSpec((d,), self.outputs[0].dtype, OnesInitializer())}
+
+    def forward(self, params, xs, state, training):
+        (x,) = xs
+        return [rms_norm(x, params["scale"], self.attrs["eps"])], state
+
+
+def rms_norm(x, scale, eps: float):
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)

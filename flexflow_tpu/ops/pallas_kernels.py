@@ -1942,3 +1942,324 @@ def _collapse_runs(flat_idx, updates):
     upd_runs = jax.ops.segment_sum(updates, run_id, num_segments=n)
     meta = jnp.concatenate([num_runs[None], run_row])
     return meta, upd_runs
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA) and grouped expert products: forward-only serving
+# kernels, reachable from ops/attention.py::LatentAttention and
+# ops/moe.py::MixtureOfExperts only when the serving executor drives them
+# ---------------------------------------------------------------------------
+
+_UNEVEN_BLOCK = 512
+
+
+def flash_uneven_supported(q_shape: Tuple[int, ...], v_width: int) -> bool:
+    """Whether ``flash_fwd_uneven`` applies to (b, h, t, qk) queries and
+    keys with ``v_width``-wide values: whole 128-row blocks of the
+    sequence (the test suite's AOT compile holds the gate to what the
+    TPU compiler accepts)."""
+    if len(q_shape) != 4:
+        return False
+    _, _, t, qk = q_shape
+    return t >= 128 and t % 128 == 0 and qk >= 8 and v_width >= 8
+
+
+def _fwd_uneven_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                       *, block_q, block_k, scale, num_kb):
+    """Causal streamed forward, 3D grid (bh, q-block, k-block), with
+    q·k and v of different widths.  K/V blocks above the diagonal are
+    neither fetched (the index map clamps to the last block a query
+    block needs) nor computed; the output is written at that block."""
+    qi = pl.program_id(1)
+    kb = pl.program_id(2)
+    q_start = qi * block_q
+    k_start = kb * block_k
+    last = jnp.minimum(lax.div(q_start + block_q - 1, block_k), num_kb - 1)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                       # (bq, bk) f32
+        if masked:
+            q_pos = q_start + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = k_start + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+
+    # Blocks wholly below the diagonal need no mask.
+    below = k_start + block_k - 1 <= q_start
+    pl.when(jnp.logical_and(kb <= last, below))(lambda: step(False))
+    pl.when(jnp.logical_and(kb <= last, jnp.logical_not(below)))(
+        lambda: step(True))
+
+    @pl.when(kb == last)
+    def _emit():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def flash_fwd_uneven(q, k, v, scale: float,
+                     interpret: Optional[bool] = None):
+    """Causal attention ``softmax(q k^T * scale) v`` on (b, h, t, qk)
+    queries and keys and (b, h, t, dv) values, ``qk != dv`` allowed
+    (latent attention's expanded path: 192 against 128).  Forward only.
+    Callers gate on :func:`flash_uneven_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, t, qk = q.shape
+    dv = v.shape[-1]
+    block = _UNEVEN_BLOCK
+    while t % block:
+        block //= 2
+    num_kb = t // block
+    kernel = functools.partial(
+        _fwd_uneven_kernel, block_q=block, block_k=block, scale=scale,
+        num_kb=num_kb,
+    )
+
+    def kv_map(bi, i, j):
+        return (bi, jnp.minimum(j, i), 0)   # block_q == block_k
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(b * h, num_kb, num_kb),
+        in_specs=[
+            pl.BlockSpec((1, block, qk), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, block, qk), kv_map),
+            pl.BlockSpec((1, block, dv), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, block, dv), lambda bi, i, j: (bi, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, dv), jnp.float32),
+        ],
+        name="ff_flash_fwd_uneven",
+        interpret=interpret,
+    )(q.reshape(b * h, t, qk), k.reshape(b * h, t, qk),
+      v.reshape(b * h, t, dv))
+    return out.reshape(b, h, t, dv)
+
+
+_MLA_DECODE_BLOCK = 2048
+_MLA_DECODE_CHUNK = 256
+
+
+def _mla_decode_block(s: int) -> int:
+    """Largest block of whole 128-position lane tiles dividing ``s``."""
+    b = min(s, _MLA_DECODE_BLOCK)
+    b -= b % 128
+    while b >= 128 and s % b:
+        b -= 128
+    return b
+
+
+def mla_decode_supported(cache_shape: Tuple[int, ...], v_width: int) -> bool:
+    """Whether ``mla_decode`` applies to a (B, row, max_seq) latent
+    cache whose first ``v_width`` values of a row are the value."""
+    if len(cache_shape) != 3:
+        return False
+    _, row, s = cache_shape
+    return (_mla_decode_block(s) >= 128 and 0 < v_width <= row
+            and v_width % 8 == 0 and row % 8 == 0)
+
+
+def _mla_decode_kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr,
+                       *, block_k, chunk, scale, num_kb, dv):
+    b = pl.program_id(0)
+    kb = pl.program_id(1)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = len_ref[b]
+
+    @pl.when(kb * block_k < length)
+    def _accumulate():
+        q = q_ref[0]                                    # (h, row)
+        h = q.shape[0]
+
+        def body(i, carry):
+            m, l, acc = carry
+            start = pl.multiple_of(i * chunk, chunk)
+            rows = c_ref[0, :, pl.ds(start, chunk)]     # (row, chunk)
+            s = jnp.dot(q, rows, preferred_element_type=jnp.float32) * scale
+            k_pos = kb * block_k + start + lax.broadcasted_iota(
+                jnp.int32, (h, chunk), 1)
+            s = jnp.where(k_pos < length, s, _NEG_INF)  # (h, chunk) f32
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr + lax.dot_general(
+                p.astype(rows.dtype), rows[:dv], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                           # (h, dv)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            return m_new, l, acc
+
+        m, l, acc = lax.fori_loop(
+            0, block_k // chunk, body,
+            (m_scr[...], l_scr[...], acc_scr[...]),
+        )
+        m_scr[...] = m
+        l_scr[...] = l
+        acc_scr[...] = acc
+
+    @pl.when(kb == num_kb - 1)
+    def _emit():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def mla_decode(q, cache, lengths, v_width: int, scale: float,
+               interpret: Optional[bool] = None):
+    """Absorbed latent-attention decode: one query ``q`` (B, h, row) a
+    head against the latent cache ``cache`` (B, row, max_seq): one
+    ``row``-value column a token, shared by every head, positions along
+    the lanes (the order the chip stores a row narrower than a whole
+    number of lane tiles in: a (B, max_seq, 576) array would be copied
+    into this order in front of every call).  The score of position j
+    is ``q . cache[:, j] * scale`` over the whole column (latent part
+    and rotary key), the value its first ``v_width`` entries.
+    ``lengths`` (B,) int32: positions ``< lengths[b]`` are attended (at
+    least one).  Returns (B, h, v_width) in ``q.dtype``; blocks past a
+    slot's length are neither fetched nor computed.  Callers gate on
+    :func:`mla_decode_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, row, s = cache.shape
+    h = q.shape[1]
+    block_k = _mla_decode_block(s)
+    chunk = _MLA_DECODE_CHUNK if block_k % _MLA_DECODE_CHUNK == 0 else 128
+    num_kb = s // block_k
+    kernel = functools.partial(
+        _mla_decode_kernel, block_k=block_k, chunk=chunk, scale=scale,
+        num_kb=num_kb, dv=v_width,
+    )
+
+    def cache_map(bi, ki, lens):
+        return (bi, 0, jnp.minimum(ki, lax.div(lens[bi] - 1, block_k)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, num_kb),
+        in_specs=[
+            pl.BlockSpec((1, h, row), lambda bi, ki, lens: (bi, 0, 0)),
+            pl.BlockSpec((1, row, block_k), cache_map),
+        ],
+        out_specs=pl.BlockSpec((1, h, v_width), lambda bi, ki, lens: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, v_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        name="ff_mla_decode",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), q, cache)
+
+
+#: VMEM the grouped product may use: two (K, N) expert blocks, double
+#: buffered, are 12.6 MB at K=2048, N=768 in bf16 — past the 16 MB a
+#: kernel gets by default once the row tiles are added (v5e has 128 MiB).
+_GMM_VMEM_BYTES = 64 << 20
+
+
+def grouped_matmul_supported(k: int, n: int, dtype) -> bool:
+    """Whether ``grouped_matmul`` applies to (rows, k) x (experts, k, n):
+    whole 128-lane tiles on both widths."""
+    return k % 128 == 0 and n % 128 == 0 and jnp.dtype(dtype).itemsize <= 4
+
+
+def grouped_tile_rows(assignments: int, experts: int) -> int:
+    """Rows of one tile of the grouped product: 128 where an expert
+    sees a tile's worth of rows on average (prefill), else the 16 rows
+    of one packed bf16 sublane tile (decode: a handful of rows an
+    expert, where the expert's weights are the traffic)."""
+    return 128 if assignments >= 128 * experts else 16
+
+
+def _gmm_kernel(te_ref, nu_ref, x_ref, *refs, gated):
+    del te_ref
+    o_ref = refs[-1]
+
+    @pl.when(pl.program_id(0) < nu_ref[0])
+    def _tile():
+        x = x_ref[...]
+        y = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
+        if gated:
+            up = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
+            y = y * jax.nn.sigmoid(y) * up              # silu(gate) * up
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int,
+                   w_up=None, interpret: Optional[bool] = None):
+    """Grouped matrix product over rows sorted by expert and padded so
+    that every tile of ``tile_rows`` rows belongs to one expert:
+    ``out[i] = x[i] @ w[tile_expert[i // tile_rows]]`` for the first
+    ``tiles_used`` tiles (rows of later tiles are left unwritten).
+    With ``w_up`` the gated form ``silu(x @ w[e]) * (x @ w_up[e])``.
+
+    ``x`` (rows, K), rows a multiple of ``tile_rows``; ``w`` (E, K, N);
+    ``tile_expert`` (rows // tile_rows,) int32, with the tiles past
+    ``tiles_used`` repeating the last used tile's expert, so that an
+    unused tile moves nothing: an expert's block is fetched once for
+    each run of its tiles, i.e. once a call for every touched expert.
+    Forward only.  Callers gate on :func:`grouped_matmul_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    rows, k = x.shape
+    n = w.shape[-1]
+    n_tiles = rows // tile_rows
+    gated = w_up is not None
+
+    def row_map(i, te, nu):
+        return (jnp.minimum(i, jnp.maximum(nu[0] - 1, 0)), 0)
+
+    def w_map(i, te, nu):
+        return (te[i], 0, 0)
+
+    weights = (w, w_up) if gated else (w,)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((tile_rows, k), row_map)]
+        + [pl.BlockSpec((1, k, n), w_map) for _ in weights],
+        out_specs=pl.BlockSpec((tile_rows, n), row_map),
+    )
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, gated=gated),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_GMM_VMEM_BYTES),
+        name="ff_grouped_matmul",
+        interpret=interpret,
+    )(tile_expert.astype(jnp.int32),
+      jnp.reshape(tiles_used, (1,)).astype(jnp.int32), x, *weights)

@@ -57,6 +57,20 @@ class Add(Op):
         return [a + b], state
 
 
+class Multiply(Op):
+    """Elementwise product (the gate of a gated MLP)."""
+
+    def __init__(self, name: str, a: TensorSpec, b: TensorSpec):
+        super().__init__(name, [a, b])
+        assert a.shape == b.shape, (a.shape, b.shape)
+        assert a.dtype == b.dtype, (a.dtype, b.dtype)
+        self._make_output(a.shape, a.dtype, a.dim_axes)
+
+    def forward(self, params, xs, state, training):
+        a, b = xs
+        return [a * b], state
+
+
 class Reshape(Op):
     """Free-form reshape; batch dim must be preserved."""
 

@@ -99,6 +99,7 @@ import collections
 import dataclasses
 import functools
 import logging
+import math
 import re
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -110,6 +111,7 @@ import numpy as np
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel
 from flexflow_tpu.ops.attention import MultiHeadAttention, PositionEmbedding
+from flexflow_tpu.ops.linear import Linear
 from flexflow_tpu.runtime import telemetry as _telemetry
 
 #: Bound on the fused decode superstep — THE training supersteps'
@@ -239,7 +241,8 @@ class ServingFaultInjector:
             if caches is None:
                 return None, slot  # simulate mode: no device caches
             name = next(iter(caches))
-            k = caches[name]["k"]
+            entry = next(iter(caches[name]))
+            k = caches[name][entry]
             if block_table is not None:
                 dest = int(block_table[slot][0])
                 if dest == 0:  # slot owns no blocks: nothing to corrupt
@@ -248,7 +251,7 @@ class ServingFaultInjector:
             else:
                 k = k.at[slot].set(jnp.nan)
             caches = dict(caches)
-            caches[name] = {"k": k, "v": caches[name]["v"]}
+            caches[name] = {**caches[name], entry: k}
             return caches, slot
         return caches, None
 
@@ -632,16 +635,26 @@ class ServingExecutor:
                 f"{[t.name for t in feed]}"
             )
         self._tokens_name = feed[0].name
+        self.max_batch = int(max_batch)
+        self.max_seq = int(max_seq or feed[0].shape[1])
+        #: What each attention-like op declares it keeps for a slot
+        #: (``Op.cache_entries``): name -> {entry: CacheEntry}.  The
+        #: executor allocates, installs and carries exactly this.
+        declared = ((op.name, op.cache_entries(self.max_seq))
+                    for op in self._layers)
+        self._cache_specs: Dict[str, Dict[str, Any]] = {
+            name: entries for name, entries in declared if entries
+        }
         self.attn_ops = [
-            op for op in self._layers if isinstance(op, MultiHeadAttention)
+            op for op in self._layers if op.name in self._cache_specs
         ]
         if not self.attn_ops:
             raise ValueError(
-                "serving needs at least one MultiHeadAttention op "
-                "(the KV-cache decode protocol lives there)"
+                "serving needs at least one op that declares a cache "
+                "(Op.cache_entries: the decode protocol lives there)"
             )
-        self.max_batch = int(max_batch)
-        self.max_seq = int(max_seq or feed[0].shape[1])
+        #: Whether any op reports counters when serving (``Op.serving_stats``).
+        self.has_stats = any(op.serving_stats for op in self._layers)
         # Pad buckets for prefill (ascending); every bucket compiles
         # its own prefill program, so keep the list short.
         bks = sorted(set(int(b) for b in (buckets or (self.max_seq,))))
@@ -650,16 +663,19 @@ class ServingExecutor:
         self.buckets: Tuple[int, ...] = tuple(bks)
         self.decode_kernel = decode_kernel
         self.device = device if device is not None else jax.devices()[0]
-        #: Per-attention-op cache specs: name -> (heads, d_head, dtype).
-        self._cache_specs: Dict[str, Tuple[int, int, Any]] = {}
-        for op in self.attn_ops:
-            d = op.inputs[0].shape[-1]
-            h = op.attrs["num_heads"]
-            self._cache_specs[op.name] = (h, d // h, op.outputs[0].dtype)
         # -- paged KV layout --
         self.kv_block = int(kv_block or 0)
         self.paged = self.kv_block > 0
         if self.paged:
+            unpaged = [op.name for op in self.attn_ops if not op.cache_paged]
+            if unpaged:
+                raise ValueError(
+                    f"the paged KV layout (kv_block > 0) holds keys and "
+                    f"values a head; {unpaged} "
+                    f"({type(self.attn_ops[0]).__name__}) declare another "
+                    f"cache and have no paged pool yet (ROADMAP Queue B): "
+                    f"serve them padded (kv_block=0)"
+                )
             if self.max_seq % self.kv_block:
                 raise ValueError(
                     f"kv_block must divide max_seq: kv_block="
@@ -712,9 +728,19 @@ class ServingExecutor:
                         f"shard batch degree n={n} must divide "
                         f"max_batch={self.max_batch}"
                     )
+                unsharded = [
+                    op.name for op in self.attn_ops
+                    if not isinstance(op, MultiHeadAttention)
+                ]
+                if unsharded:
+                    raise ValueError(
+                        f"sharded decode (shard=) is built for "
+                        f"MultiHeadAttention caches; {unsharded} declare "
+                        f"another cache (ROADMAP Queue B)"
+                    )
                 bad = [
-                    name for name, (h, _hd, _dt) in self._cache_specs.items()
-                    if h % c
+                    op.name for op in self.attn_ops
+                    if op.attrs["num_heads"] % c
                 ]
                 if bad:
                     raise ValueError(
@@ -812,10 +838,10 @@ class ServingExecutor:
     @property
     def _bytes_per_token(self) -> int:
         """Bytes one cached token position costs across ALL layers
-        (K and V)."""
+        (every declared entry: K and V, or the latent column)."""
         return sum(
-            2 * h * hd * jnp.dtype(dt).itemsize
-            for (h, hd, dt) in self._cache_specs.values()
+            math.prod(ce.shape) // self.max_seq * jnp.dtype(ce.dtype).itemsize
+            for ents in self._cache_specs.values() for ce in ents.values()
         )
 
     def cache_total_bytes(self) -> int:
@@ -913,65 +939,48 @@ class ServingExecutor:
     def init_cache(self):
         """Preallocated per-layer KV caches on the serving device(s).
 
-        Padded: ``{op: {"k"/"v": (max_batch, max_seq, heads,
-        d_head)}}`` (``NamedSharding``-placed batch-on-'n'/
-        heads-on-'c' when sharded).  Paged: ``{op: {"k"/"v":
-        (kv_blocks, kv_block, heads, d_head)}}`` — the global block
-        pool; slot structure lives in the block table."""
+        Padded: ``{op: {entry: (max_batch,) + declared shape}}`` — for
+        ``MultiHeadAttention`` ``"k"``/``"v"`` of ``(max_batch,
+        max_seq, heads, d_head)`` (``NamedSharding``-placed
+        batch-on-'n'/heads-on-'c' when sharded), for ``LatentAttention``
+        one ``"ckr"`` of ``(max_batch, kv_rank + rope, max_seq)``.
+        Paged: ``{op: {"k"/"v": (kv_blocks, kv_block, heads,
+        d_head)}}`` — the global block pool; slot structure lives in
+        the block table."""
         self._budget_check()
-        if self.paged:
-            NB, bs = self.kv_blocks, self.kv_block
-            if self._plan is not None:
-                # Paged + sharded: the pool shards its HEAD axis on
-                # 'c' (block and position axes stay whole so the
-                # host-int block table indexes locally); 'n'
-                # replicates the pool.
-                def put(h, hd, dt):
-                    return jax.device_put(
-                        jnp.zeros((NB, bs, h, hd), dt),
-                        self._plan.sharding(
-                            self._pc, (None, None, "c", None),
-                            (NB, bs, h, hd),
-                        ),
-                    )
+        return self._cache_tree(self._cache_specs, self._make_cache(
+            paged=self.paged))
 
-                return {
-                    name: {"k": put(h, hd, dt), "v": put(h, hd, dt)}
-                    for name, (h, hd, dt) in self._cache_specs.items()
-                }
-            return {
-                name: {
-                    "k": self._place(jnp.zeros((NB, bs, h, hd), dt)),
-                    "v": self._place(jnp.zeros((NB, bs, h, hd), dt)),
-                }
-                for name, (h, hd, dt) in self._cache_specs.items()
-            }
-        B, S = self.max_batch, self.max_seq
-        if self._plan is not None:
-            return {
-                name: {
-                    "k": jax.device_put(
-                        jnp.zeros((B, S, h, hd), dt),
-                        self._plan.sharding(
-                            self._pc, ("n", None, "c", None), (B, S, h, hd)
-                        ),
-                    ),
-                    "v": jax.device_put(
-                        jnp.zeros((B, S, h, hd), dt),
-                        self._plan.sharding(
-                            self._pc, ("n", None, "c", None), (B, S, h, hd)
-                        ),
-                    ),
-                }
-                for name, (h, hd, dt) in self._cache_specs.items()
-            }
+    def _cache_tree(self, specs, make):
+        """``{op: {entry: make(CacheEntry)}}`` over ``specs``."""
         return {
-            name: {
-                "k": self._place(jnp.zeros((B, S, h, hd), dt)),
-                "v": self._place(jnp.zeros((B, S, h, hd), dt)),
-            }
-            for name, (h, hd, dt) in self._cache_specs.items()
+            name: {e: make(ce) for e, ce in ents.items()}
+            for name, ents in specs.items()
         }
+
+    def _cache_shape(self, ce, paged: bool):
+        """``(shape, axes)`` of one declared entry in the padded layout
+        (a leading slot axis, on 'n') or the paged pool (``kv_blocks x
+        kv_block`` in place of the leading sequence axis)."""
+        if paged:
+            return ((self.kv_blocks, self.kv_block) + tuple(ce.shape[1:]),
+                    (None, None) + tuple(ce.axes[1:]))
+        return (self.max_batch,) + tuple(ce.shape), ("n",) + tuple(ce.axes)
+
+    def _make_cache(self, paged: bool, batch_axis: bool = True):
+        """Zeros on the serving device(s) for one declared entry."""
+        def make(ce):
+            shape, axes = self._cache_shape(ce, paged)
+            if self._plan is None:
+                return self._place(jnp.zeros(shape, ce.dtype))
+            if not batch_axis:
+                axes = (None,) + axes[1:]
+            return jax.device_put(
+                jnp.zeros(shape, ce.dtype),
+                self._plan.sharding(self._pc, axes, shape),
+            )
+
+        return make
 
     def init_draft_cache(self):
         """The DRAFT model's own per-layer KV caches for the
@@ -980,33 +989,10 @@ class ServingExecutor:
         not a capacity-accounted one: it covers only the truncation's
         kept layers, and a stale draft cache can never corrupt output
         — draft quality affects acceptance, never correctness)."""
-        B, S = self.max_batch, self.max_seq
-        if self._plan is not None:
-            # Paged engines never validated max_batch % n (the pool
-            # has no batch axis), so the padded draft cache shards
-            # heads only there.
-            axes = (
-                (None, None, "c", None) if self.paged
-                else ("n", None, "c", None)
-            )
-
-            def put(h, hd, dt):
-                return jax.device_put(
-                    jnp.zeros((B, S, h, hd), dt),
-                    self._plan.sharding(self._pc, axes, (B, S, h, hd)),
-                )
-
-            return {
-                name: {"k": put(h, hd, dt), "v": put(h, hd, dt)}
-                for name, (h, hd, dt) in self._draft_cache_specs.items()
-            }
-        return {
-            name: {
-                "k": self._place(jnp.zeros((B, S, h, hd), dt)),
-                "v": self._place(jnp.zeros((B, S, h, hd), dt)),
-            }
-            for name, (h, hd, dt) in self._draft_cache_specs.items()
-        }
+        # Paged engines never validated max_batch % n (the pool has no
+        # batch axis), so the padded draft cache shards heads only there.
+        return self._cache_tree(self._draft_cache_specs, self._make_cache(
+            paged=False, batch_axis=not self.paged))
 
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.buckets:
@@ -1020,7 +1006,8 @@ class ServingExecutor:
     # -- the forward walk ---------------------------------------------------
 
     def _forward(self, params, op_state, tokens, caches, pos,
-                 block_table=None, skip=None, chunk=0):
+                 block_table=None, skip=None, chunk=0, last=None,
+                 stats=None):
         """Forward-only walk over the non-loss op graph in inference
         mode: attention ops get their caches + the per-slot position
         vector through the existing ``state`` mechanism
@@ -1036,7 +1023,12 @@ class ServingExecutor:
         that ``tokens`` starts at absolute row ``chunk`` of an
         already-populated cache — KV writes land at
         ``[chunk, chunk + t)`` and queries attend the full
-        ``[0, chunk + t)`` span.  Returns ``(logits, new_caches)``."""
+        ``[0, chunk + t)`` span.  ``last`` (a traced position, the
+        slim prefill) feeds the op that produces the logits that one
+        row alone.  ``stats`` (a dict the caller hands in) collects
+        what serving-aware ops report beside their outputs
+        (``state["stats"]``: the expert layers' routing counters).
+        Returns ``(logits, new_caches)``."""
         env: Dict[str, Any] = {self._tokens_name: tokens}
         new_caches: Dict[str, Any] = {}
         for op in self._layers:
@@ -1056,13 +1048,18 @@ class ServingExecutor:
                 op.bind_mesh(self._plan, self._pc)
             else:
                 op.bind_mesh(None, None)
-            if isinstance(op, MultiHeadAttention):
+            if op.name in self._cache_specs:
                 op.decode_kernel = self.decode_kernel
             xs = [env[t.name] for t in op.inputs]
+            if last is not None and op.outputs[0].name == self._logits_name:
+                xs = [jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+                      for x in xs]
             s = dict(op_state.get(op.name, {}))
+            if op.serving_aware:
+                s["serving"] = True
             if op.name in caches:
-                s["cache_k"] = caches[op.name]["k"]
-                s["cache_v"] = caches[op.name]["v"]
+                for entry, c in caches[op.name].items():
+                    s[f"cache_{entry}"] = c
                 s["pos"] = pos
                 if block_table is not None:
                     s["block_table"] = block_table
@@ -1072,12 +1069,16 @@ class ServingExecutor:
                 s["pos"] = pos
                 if chunk:
                     s["chunk"] = int(chunk)
-            ys, s_new = op.forward(params.get(op.name, {}), xs, s,
-                                   training=False)
+            with jax.named_scope(op.name):
+                ys, s_new = op.forward(params.get(op.name, {}), xs, s,
+                                       training=False)
             if op.name in caches:
                 new_caches[op.name] = {
-                    "k": s_new["cache_k"], "v": s_new["cache_v"],
+                    entry: s_new[f"cache_{entry}"]
+                    for entry in caches[op.name]
                 }
+            if stats is not None and "stats" in s_new:
+                stats[op.name] = s_new["stats"]
             for t, y in zip(op.outputs, ys):
                 env[t.name] = y
         return env[self._logits_name], new_caches
@@ -1138,30 +1139,27 @@ class ServingExecutor:
         fn = self._prefill_fns.get(key)
         if fn is not None:
             return fn
-        S = self.max_seq
         pick_first = self._pick_first(sample)
+        slim = self._slim_head(bucket)
 
         def run(params, op_state, tokens, length, plen, rid):
-            caches = {
-                name: {
-                    "k": jnp.zeros((1, S, h, hd), dt),
-                    "v": jnp.zeros((1, S, h, hd), dt),
-                }
-                for name, (h, hd, dt) in self._cache_specs.items()
-            }
+            caches = self._cache_tree(
+                self._cache_specs,
+                lambda ce: jnp.zeros((1,) + tuple(ce.shape), ce.dtype))
             pos = jnp.zeros((1,), jnp.int32)
+            stats = {} if self.has_stats else None
             logits, caches = self._forward(
-                params, op_state, tokens, caches, pos
+                params, op_state, tokens, caches, pos,
+                last=length - 1 if slim else None, stats=stats,
             )
-            last = jax.lax.dynamic_index_in_dim(
+            last = logits[0, 0] if slim else jax.lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
             )
             tok = pick_first(last, length, plen, rid)
             ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
-            rows = {
-                name: {"k": c["k"][0], "v": c["v"][0]}
-                for name, c in caches.items()
-            }
+            rows = jax.tree.map(lambda c: c[0], caches)
+            if stats:
+                return rows, tok, ok, self._mean_stats(stats)
             return rows, tok, ok
 
         if sample is not None:
@@ -1174,8 +1172,41 @@ class ServingExecutor:
         fn = self._prefill_fns[key] = jax.jit(prefill)
         _telemetry.current().emit("serving_program", kind="prefill",
                                   bucket=int(bucket),
-                                  sampled=sample is not None)
+                                  sampled=sample is not None,
+                                  attention=self._attention_paths(False),
+                                  head_rows="last" if slim else "all")
         return fn
+
+    #: A prefill whose whole-bucket logits would pass this many bytes
+    #: projects only the row it reads (a 16k-token bucket of a 128k
+    #: vocabulary is 4 GB of logits for one argmax).  Smaller ones keep
+    #: the full-sequence head: bit-identical to the training forward.
+    SLIM_HEAD_BYTES = 1 << 28
+
+    def _slim_head(self, bucket: int) -> bool:
+        head = self.model.find_op(self._logits_name.split(":")[0])
+        out = head.outputs[0]
+        return isinstance(head, Linear) and (
+            bucket * out.shape[-1] * jnp.dtype(out.dtype).itemsize
+            > self.SLIM_HEAD_BYTES
+        )
+
+    def _attention_paths(self, decode: bool) -> str:
+        """Which attention formulation this program's cache-holding ops
+        compile (``serving_program.attention``)."""
+        return "+".join(sorted({
+            op.serving_path(decode) for op in self.attn_ops
+        }))
+
+    @staticmethod
+    def _mean_stats(stats):
+        """The routing counters of one forward, mean over the layers
+        that report them."""
+        keys = sorted(next(iter(stats.values())))
+        return {
+            k: jnp.mean(jnp.stack([s[k] for s in stats.values()]))
+            for k in keys
+        }
 
     def build_prefill_from(
         self, bucket: int, offset: int,
@@ -1223,20 +1254,20 @@ class ServingExecutor:
         fn = self._prefill_fns.get(key)
         if fn is not None:
             return fn
-        S = self.max_seq
         o = offset
         pick_first = self._pick_first(sample)
 
         def run(params, op_state, pool, shared_ids, tokens, length,
                 plen, rid):
-            caches = {}
-            for name, (h, hd, dt) in self._cache_specs.items():
-                gk = pool[name]["k"][shared_ids].reshape(o, h, hd)
-                gv = pool[name]["v"][shared_ids].reshape(o, h, hd)
-                caches[name] = {
-                    "k": jnp.zeros((1, S, h, hd), dt).at[0, :o].set(gk),
-                    "v": jnp.zeros((1, S, h, hd), dt).at[0, :o].set(gv),
+            caches = {
+                name: {
+                    e: jnp.zeros((1,) + tuple(ce.shape), ce.dtype)
+                    .at[0, :o].set(pool[name][e][shared_ids].reshape(
+                        (o,) + tuple(ce.shape[1:])))
+                    for e, ce in ents.items()
                 }
+                for name, ents in self._cache_specs.items()
+            }
             pos = jnp.full((1,), o, jnp.int32)
             logits, caches = self._forward(
                 params, op_state, tokens[:, o:], caches, pos, chunk=o
@@ -1246,10 +1277,7 @@ class ServingExecutor:
             )
             tok = pick_first(last, length, plen, rid)
             ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
-            rows = {
-                name: {"k": c["k"][0], "v": c["v"][0]}
-                for name, c in caches.items()
-            }
+            rows = jax.tree.map(lambda c: c[0], caches)
             return rows, tok, ok
 
         if sample is not None:
@@ -1383,9 +1411,10 @@ class ServingExecutor:
                      req_ids):
             def body(carry, _):
                 caches, pos, tok = carry
+                stats = {} if self.has_stats else None
                 logits, caches = self._forward(
                     params, op_state, tok[:, None], caches, pos,
-                    block_table=block_table,
+                    block_table=block_table, stats=stats,
                 )
                 logits = logits[:, 0]                      # (B, V)
                 nxt = pick_token(logits, req_ids, pos)
@@ -1394,6 +1423,8 @@ class ServingExecutor:
                 )
                 pos = jnp.minimum(pos + 1, S - 1)
                 out = (nxt, ok, logits) if return_logits else (nxt, ok)
+                if stats:
+                    out += (self._mean_stats(stats),)
                 return (caches, pos, nxt), out
 
             (caches, pos, tok), outs = jax.lax.scan(
@@ -1431,6 +1462,7 @@ class ServingExecutor:
             layout="paged" if self.paged else "padded",
             sharded=self.shard is not None,
             sampled=sample is not None,
+            attention=self._attention_paths(True),
         )
         return fn
 
@@ -1447,25 +1479,17 @@ class ServingExecutor:
         fn = self._prefill_fns.get(key)
         if fn is not None:
             return fn
-        S = self.max_seq
 
         def prefill(params, op_state, tokens):
-            caches = {
-                name: {
-                    "k": jnp.zeros((1, S, h, hd), dt),
-                    "v": jnp.zeros((1, S, h, hd), dt),
-                }
-                for name, (h, hd, dt) in self._draft_cache_specs.items()
-            }
+            caches = self._cache_tree(
+                self._draft_cache_specs,
+                lambda ce: jnp.zeros((1,) + tuple(ce.shape), ce.dtype))
             pos = jnp.zeros((1,), jnp.int32)
             _logits, caches = self._forward(
                 params, op_state, tokens, caches, pos,
                 skip=self._draft_skip,
             )
-            return {
-                name: {"k": c["k"][0], "v": c["v"][0]}
-                for name, c in caches.items()
-            }
+            return jax.tree.map(lambda c: c[0], caches)
 
         fn = self._prefill_fns[key] = jax.jit(prefill)
         _telemetry.current().emit(
@@ -1650,46 +1674,39 @@ class ServingExecutor:
         params, _opt, op_state = Executor(
             self.model, config=self.config
         )._abstract_init()
-        B, S = self.max_batch, self.max_seq
+        B = self.max_batch
 
-        def cache_aval(h, hd, dt):
-            if self.paged:
-                return jax.ShapeDtypeStruct(
-                    (self.kv_blocks, self.kv_block, h, hd), dt
-                )
-            return jax.ShapeDtypeStruct((B, S, h, hd), dt)
+        def cache_aval(ce):
+            return jax.ShapeDtypeStruct(
+                self._cache_shape(ce, self.paged)[0], ce.dtype)
 
+        caches = self._cache_tree(self._cache_specs, cache_aval)
         out: Dict[str, Any] = {"prefill": {}, "cache": {}}
-        for name, (h, hd, dt) in self._cache_specs.items():
-            out["cache"][name] = cache_aval(h, hd, dt)
+        for name, ents in caches.items():
+            # One row a cache-holding op: its first declared entry (K
+            # of K/V; the latent column).
+            out["cache"][name] = next(iter(ents.values()))
         for bucket in self.buckets:
             toks = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
             ln = jax.ShapeDtypeStruct((), jnp.int32)
-            rows, tok, okf = jax.eval_shape(
+            tok = jax.eval_shape(
                 self.build_prefill(bucket), params, op_state, toks, ln
-            )
+            )[1]
             out["prefill"][bucket] = tok
-        caches = {
-            name: {
-                "k": cache_aval(h, hd, dt),
-                "v": cache_aval(h, hd, dt),
-            }
-            for name, (h, hd, dt) in self._cache_specs.items()
-        }
         pos = jax.ShapeDtypeStruct((B,), jnp.int32)
         tok = jax.ShapeDtypeStruct((B,), jnp.int32)
         if self.paged:
             bt = jax.ShapeDtypeStruct((B, self.blocks_per_slot), jnp.int32)
-            _, _, _, (toks, okf) = jax.eval_shape(
+            fetch = jax.eval_shape(
                 self.build_decode_superstep(decode_steps),
                 params, op_state, caches, bt, pos, tok,
-            )
+            )[3]
         else:
-            _, _, _, (toks, okf) = jax.eval_shape(
+            fetch = jax.eval_shape(
                 self.build_decode_superstep(decode_steps),
                 params, op_state, caches, pos, tok,
-            )
-        out["decode"] = toks
+            )[3]
+        out["decode"] = fetch[0]
         if self.paged and self.prefix_cache:
             # Prefix sharing: trace the offset prefill at one
             # representative offset (kv_block) per bucket that can
@@ -1708,13 +1725,10 @@ class ServingExecutor:
                 )
                 out["prefill_from"][bucket] = tok_a
         if speculate:
-            dcaches = {
-                name: {
-                    "k": jax.ShapeDtypeStruct((B, S, h, hd), dt),
-                    "v": jax.ShapeDtypeStruct((B, S, h, hd), dt),
-                }
-                for name, (h, hd, dt) in self._draft_cache_specs.items()
-            }
+            dcaches = self._cache_tree(
+                self._draft_cache_specs,
+                lambda ce: jax.ShapeDtypeStruct(
+                    self._cache_shape(ce, False)[0], ce.dtype))
             for bucket in self.buckets:
                 toks_in = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
                 jax.eval_shape(
@@ -2054,7 +2068,7 @@ class Server:
                                     pf_args += (np.int32(plen), np.int32(r.id))
                                 tel.program_cost("prefill", pf, pf_args,
                                                  bucket=bucket)
-                                rows, tok0, okf = pf(*pf_args)
+                                rows, tok0, okf, *pstats = pf(*pf_args)
                         if okf is None:
                             ok, pf_s = True, 0.0
                             prefix_hits += 1
@@ -2066,8 +2080,14 @@ class Server:
                         else:
                             with _telemetry.span("ff/serve/prefill_fence",
                                                  id=r.id):
-                                tok0, ok = tel.fence((tok0, okf), "prefill")
+                                tok0, ok, *pstats = tel.fence(
+                                    (tok0, okf, *pstats), "prefill")
                             pf_s = time.perf_counter() - t0
+                            # The expert layers' routing counters ride
+                            # the fence the prefill already has.
+                            routed = {k: round(float(v), 4)
+                                      for k, v in pstats[0].items()} \
+                                if pstats else {}
                             prefills += 1
                             if plan is not None and plan.use > 0:
                                 prefix_hits += 1
@@ -2084,7 +2104,7 @@ class Server:
                                              blocks=plan.cow)
                             else:
                                 tel.emit("prefill", id=r.id, bucket=bucket,
-                                         wall_s=round(pf_s, 6))
+                                         wall_s=round(pf_s, 6), **routed)
                         if jr is not None:
                             jr.admit(r.id, plen,
                                      int(tok0) if bool(ok) else None,
@@ -2208,7 +2228,7 @@ class Server:
                         )
                         k_eff = spec_d + 1
                     else:
-                        host_toks, host_oks = tel.fence(
+                        host_toks, host_oks, *dstats = tel.fence(
                             fetch, "decode_superstep"
                         )
                         host_acc = None
@@ -2229,9 +2249,14 @@ class Server:
                     # each dispatch).  Captured before finish() frees slots.
                     occ = [slots[i].request.id for i in active]
                     if not spec_d:
+                        # Routing counters (expert layers only): the
+                        # mean over the K steps of the layers' mean.
+                        routed = {key: round(float(np.mean(v)), 4)
+                                  for key, v in dstats[0].items()} \
+                            if dstats else {}
                         tel.emit("decode_superstep", k=k, active=len(active),
                                  capacity=B, slots=occ,
-                                 wall_s=round(wall, 6))
+                                 wall_s=round(wall, 6), **routed)
                     for j in range(k_eff):
                         tel.record_step((supersteps - 1) * k_eff + j,
                                         wall_s=wall / k_eff)

@@ -303,7 +303,9 @@ class _RealEngine:
         with _telemetry.span("ff/serve/prefill_dispatch", id=rid,
                              bucket=bucket):
             tel.program_cost("prefill", pf, pf_args, bucket=bucket)
-            rows, tok0, okf = pf(*pf_args)
+            # (a graph with expert layers appends its routing counters,
+            # which only Server.run's events carry)
+            rows, tok0, okf = pf(*pf_args)[:3]
         with _telemetry.span("ff/serve/prefill_fence", id=rid):
             tok0, ok = tel.fence((tok0, okf), "prefill")
         wall = time.perf_counter() - t0
@@ -330,7 +332,7 @@ class _RealEngine:
         t0 = time.perf_counter()
         with _telemetry.span("ff/serve/decode_dispatch"):
             tel.program_cost("decode_superstep", fn, args, k=k)
-            self.caches, _pos, _tok, (toks, oks) = fn(*args)
+            self.caches, _pos, _tok, (toks, oks, *_routed) = fn(*args)
         with _telemetry.span("ff/serve/decode_fence"):
             host_toks, host_oks = tel.fence((toks, oks),
                                             "decode_superstep")

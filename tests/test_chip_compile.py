@@ -87,6 +87,38 @@ def _decode(b, s, h, hd, dtype):
         _sds((b, h, hd), dtype), cache, cache, _sds((b,), jnp.int32))
 
 
+def _flash_uneven(t, h, qk, dv):
+    q = _sds((1, h, t, qk), BF16)
+    fn = functools.partial(pk.flash_fwd_uneven, scale=qk ** -0.5,
+                           interpret=False)
+    return (lambda: pk.flash_uneven_supported((1, h, t, qk), dv)), fn, (
+        q, q, _sds((1, h, t, dv), BF16))
+
+
+def _mla_decode(b, h, row, dv, s):
+    fn = lambda q, c, n: pk.mla_decode(q, c, n, dv, 0.07, interpret=False)
+    return (lambda: pk.mla_decode_supported((b, row, s), dv)), fn, (
+        _sds((b, h, row), BF16), _sds((b, row, s), BF16),
+        _sds((b,), jnp.int32))
+
+
+def _grouped(rows, e, k, n, tm, gated):
+    """One grouped product as the expert layer calls it: ``rows`` the
+    padded bound of ``grouped_tile_rows``'s tile at that many
+    assignments."""
+    x, w = _sds((rows, k), BF16), _sds((e, k, n), BF16)
+    tiles = _sds((rows // tm,), jnp.int32)
+    used = _sds((), jnp.int32)
+    gate = lambda: pk.grouped_matmul_supported(k, n, BF16)
+    if gated:
+        fn = lambda x, w, u, te, nu: pk.grouped_matmul(
+            x, w, te, nu, tm, w_up=u, interpret=False)
+        return gate, fn, (x, w, w, tiles, used)
+    fn = lambda x, w, te, nu: pk.grouped_matmul(x, w, te, nu, tm,
+                                                interpret=False)
+    return gate, fn, (x, w, tiles, used)
+
+
 def _rows(kind, shape, n_ids, addressing):
     """A row kernel over a table of ``shape`` ((R, D) or stacked
     (T, V, D)); the gate also holds the shape to the addressing it is
@@ -118,6 +150,31 @@ CASES = {
     "decode-8x512x8x64-f32": lambda: _decode(8, 512, 8, 64, F32),
     "decode-8x512x8x64-bf16": lambda: _decode(8, 512, 8, 64, BF16),
     "decode-16x4096x16x128-bf16": lambda: _decode(16, 4096, 16, 128, BF16),
+    # The kanana2.serve.closed16.p4k-15k cell's kernels at its widths
+    # (32 heads, q.k 192 against v 128, a 576-value column, 128 experts
+    # of 2048 x 768): the 4608 and 16384 prefill buckets, 16 slots of
+    # 16384 positions, 96 assignments a decode step (16-row tiles) and
+    # a 16k prefill's 98304 (128-row tiles); and the smoke preset's.
+    "flash_uneven-32x4608x192v128-bf16":
+        lambda: _flash_uneven(4608, 32, 192, 128),
+    "flash_uneven-32x16384x192v128-bf16":
+        lambda: _flash_uneven(16384, 32, 192, 128),
+    "flash_uneven-4x256x96v64-bf16": lambda: _flash_uneven(256, 4, 96, 64),
+    "mla_decode-16x32x576x16384-bf16":
+        lambda: _mla_decode(16, 32, 576, 512, 16384),
+    "mla_decode-4x4x160x256-bf16": lambda: _mla_decode(4, 4, 160, 128, 256),
+    "grouped_matmul-gated-decode-1536x2048x768":
+        lambda: _grouped(1536, 128, 2048, 768, 16, True),
+    "grouped_matmul-down-decode-1536x768x2048":
+        lambda: _grouped(1536, 128, 768, 2048, 16, False),
+    "grouped_matmul-gated-prefill-114560x2048x768":
+        lambda: _grouped(114560, 128, 2048, 768, 128, True),
+    "grouped_matmul-down-prefill-114560x768x2048":
+        lambda: _grouped(114560, 128, 768, 2048, 128, False),
+    "grouped_matmul-gated-smoke-128x256x128":
+        lambda: _grouped(128, 8, 256, 128, 16, True),
+    "grouped_matmul-down-smoke-128x128x256":
+        lambda: _grouped(128, 8, 128, 256, 16, False),
     "gather_rows-1Mx64-1024ids":
         lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
@@ -263,6 +320,21 @@ def test_supported_gates_match_the_compiler():
     # Past one block, a cache length with no 8-aligned divisor has no
     # legal k-block.
     assert not pk.flash_decode_supported((4, 1030, 8, 64), F32)
+    # The latent kernels work on whole 128-position lane tiles and
+    # whole 128-lane expert widths, and say so.
+    assert not pk.mla_decode_supported((4, 160, 200), 128)
+    assert not pk.flash_uneven_supported((1, 4, 200, 96), 64)
+    assert not pk.grouped_matmul_supported(64, 32, BF16)
+
+
+def test_latent_decode_reads_the_cache_where_it_lies():
+    """No copy of the latent cache stands in front of the decode kernel:
+    ``(slots, 576, max_seq)`` is the order the chip holds it in.  (A
+    ``(slots, max_seq, 576)`` cache is held positions-major and copied
+    into the kernel's order every call: 302 MB a layer a step at the
+    cell's size.)"""
+    text = _compiled_text("mla_decode-16x32x576x16384-bf16")
+    assert chip_smoke.table_sized_relayouts(text, 16 * 576 * 16384) == []
 
 
 # -- chip_smoke.py, rehearsed on the CPU --------------------------------------
@@ -281,6 +353,10 @@ _TINY = chip_smoke.Sizes(
           "--arch-mlp-bot", "8-16-8", "--arch-mlp-top", "40-16-1"),
     serve=("--max-seq", "32", "--max-batch", "2", "--requests", "3",
            "--max-new", "6", *_TINY_LM),
+    # 128 positions: the smallest the latent kernels' gates take.
+    serve_latent=("--model-config", "deepseek-v3-tiny", "--max-seq", "128",
+                  "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                  "--prompt-len", "20:60", "--buckets", "128"),
     alexnet4=("-b", "4", "-i", "2", "--image-size", "67"),
     alexnet4_strategy=chip_smoke.FULL.alexnet4_strategy,
     transformer4=("-b", "4", "--seq", "128", *_TINY_LM, "-i", "2"),
@@ -295,6 +371,7 @@ def on_a_pretend_chip(monkeypatch):
     through the interpreter, so no compiled text here holds a Mosaic
     call; the real check is exercised by the AOT cases above."""
     monkeypatch.setattr(chip_smoke, "has_mosaic_call", lambda text: True)
+    monkeypatch.setattr(chip_smoke, "has_kernel", lambda text, name: True)
     # ... and the CPU's XLA scatter is a fusion of the table's size.
     monkeypatch.setattr(chip_smoke, "table_sized_relayouts",
                         lambda text, elements: [])
@@ -306,7 +383,7 @@ def _phases(which):
 
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
-              "serve"])
+              "serve", "serve/latent"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

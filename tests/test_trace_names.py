@@ -68,6 +68,19 @@ _ENTRY_POINTS = {
         lambda x: jax.grad(lambda l: pk.softmax_xent(l, jnp.zeros((128,), jnp.int32))[0].sum())(x),
         lambda: jnp.ones((128, 512), jnp.float32),
         {"ff_softmax_xent_fwd", "ff_softmax_xent_bwd"}),
+    "flash_fwd_uneven": (
+        lambda q: pk.flash_fwd_uneven(jnp.ones((1, 2, 128, 24)), jnp.ones((1, 2, 128, 24)),
+                                      jnp.ones((1, 2, 128, 16)), 0.2),
+        _qkv, {"ff_flash_fwd_uneven"}),
+    "mla_decode": (
+        lambda q: pk.mla_decode(jnp.ones((1, 2, 40)), jnp.ones((1, 40, 128)),
+                                jnp.array([5], jnp.int32), 32, 0.2),
+        _qkv, {"ff_mla_decode"}),
+    "grouped_matmul": (
+        lambda q: pk.grouped_matmul(jnp.ones((32, 128)), jnp.ones((2, 128, 128)),
+                                    jnp.array([0, 1], jnp.int32), jnp.int32(2), 16,
+                                    w_up=jnp.ones((2, 128, 128))),
+        _qkv, {"ff_grouped_matmul"}),
     "gather_rows": (lambda t: pk.gather_rows(*t), _rows, {"ff_gather_rows"}),
     "scatter_add_rows": (lambda t: pk.scatter_add_rows(t[0], t[1], jnp.ones((8, 128))), _rows,
                          {"ff_scatter_add_rows"}),
