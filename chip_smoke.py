@@ -77,6 +77,7 @@ class Sizes:
     serve_latent: Tuple[str, ...]
     #: --chips 4: the cross-chip argv of each app; the comparison run
     #: is the same argv on one device (strategy / mesh flags dropped).
+    dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
     transformer4: Tuple[str, ...]
@@ -106,6 +107,10 @@ FULL = Sizes(
                   "--max-batch", "4", "--requests", "6", "--max-new", "12",
                   "--prompt-len", "100:200", "--buckets", "256",
                   "--dtype", "bfloat16"),
+    # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
+    # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
+    dlrm4=("-b", "1024", "-i", "3", "--momentum", "0", "--wd", "0",
+           *_DLRM_ARCH),
     # float32 and three steps (one warm-up + two), the protocol of the
     # strategy-equivalence tests whose tolerance is applied: the
     # trajectories start equal to seven digits and round-off grows ~10x
@@ -154,17 +159,19 @@ def has_kernel(compiled_text: str, name: str) -> bool:
     return re.search(rf"%{re.escape(name)}[.\d]* = ", compiled_text) is not None
 
 
-def table_sized_relayouts(compiled_text: str, elements: int) -> List[str]:
+def table_sized_relayouts(compiled_text: str, elements: int,
+                          ops: Sequence[str] = _RELAYOUT_OPS) -> List[str]:
     """The instructions of an optimised HLO text that move a whole
-    table: a ``copy``, ``reshape``, ``transpose`` or fusion with a
-    result of ``elements`` elements.  A ``bitcast`` moves nothing and
-    the aliased scatter call is the update itself; what this names is
-    a view of the table that is not the order the chip stores it in,
-    paid for on every step (PERF.md §6, PR 28)."""
+    table: a ``copy``, ``reshape``, ``transpose`` or fusion (or the
+    opcodes ``ops`` names instead) with a result of ``elements``
+    elements.  A ``bitcast`` moves nothing and the aliased scatter call
+    is the update itself; what this names is a view of the table that
+    is not the order the chip stores it in, paid for on every step
+    (PERF.md §6, PR 28)."""
     found = []
     for line in compiled_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
-        if not m or m.group(2) not in _RELAYOUT_OPS:
+        if not m or m.group(2) not in ops:
             continue
         sizes = (math.prod(int(x) for x in dims.split(",") if x)
                  for dims in re.findall(r"\[([\d,]*)\]", m.group(1)))
@@ -298,8 +305,9 @@ def train_phase(phase: str, app, argv: Sequence[str], kernels: bool = False):
               f"(a *_supported gate routed to jnp, or interpret mode)")
         for op in trainer.ex._sparse_ops:
             for key in op.sparse_keys():
-                moved = table_sized_relayouts(
-                    text, math.prod(op.param_specs()[key].shape))
+                table = final[0][op.name][key]  # what one device holds of it
+                moved = table_sized_relayouts(text, math.prod(
+                    table.sharding.shard_shape(table.shape)))
                 check(not moved, f"{phase}: the compiled step moves the "
                       f"whole of {op.name}/{key} every step: {moved}")
     info(phase,
@@ -310,14 +318,23 @@ def train_phase(phase: str, app, argv: Sequence[str], kernels: bool = False):
     return trainer, losses, final
 
 
+def spanning_batch(ex, arch) -> Dict[str, np.ndarray]:
+    """A host batch whose ids span the tables (the app's fixed
+    synthetic batch only ever names rows 0 and 1)."""
+    from flexflow_tpu.data.loader import synthetic_host_batch
+
+    return synthetic_host_batch(
+        ex.model, np.random.default_rng(ex.config.seed),
+        int_high={"sparse_input": min(arch.embedding_size)},
+    )
+
+
 def dlrm_phase(argv: Sequence[str]) -> None:
     """DLRM through its app, then the row-sparse path (Pallas
     gather/scatter kernels on one TPU) against the dense jnp path on a
-    batch whose ids span the tables (the app's fixed synthetic batch
-    only ever names rows 0 and 1)."""
+    batch whose ids span the tables."""
     from flexflow_tpu.apps import dlrm
     from flexflow_tpu.apps.common import make_optimizer
-    from flexflow_tpu.data.loader import synthetic_host_batch
     from flexflow_tpu.models.dlrm import DLRMConfig, build_dlrm
     from flexflow_tpu.runtime.pipeline import make_executor
     from flexflow_tpu.runtime.trainer import Trainer
@@ -327,10 +344,7 @@ def dlrm_phase(argv: Sequence[str]) -> None:
     check(bool(ex._sparse_ops),
           "train/dlrm: no op took the row-sparse path (dense fallback)")
     arch = DLRMConfig.parse_args(list(argv))
-    host = synthetic_host_batch(
-        ex.model, np.random.default_rng(ex.config.seed),
-        int_high={"sparse_input": min(arch.embedding_size)},
-    )
+    host = spanning_batch(ex, arch)
     dense_cfg = dataclasses.replace(ex.config, sparse_embedding_updates=False)
     dense_ex = make_executor(
         build_dlrm(batch_size=dense_cfg.batch_size, dlrm=arch,
@@ -571,6 +585,36 @@ def four_chip_train(phase: str, app, argv: Sequence[str],
          one_device_losses=[round(x, 6) for x in single])
 
 
+def dlrm4_phase(argv: Sequence[str]) -> None:
+    """The stacked DLRM with its tables over the mesh (the app's own
+    ``dlrm_strategy``: ``embeddings`` at c = 4, a table a chip) against
+    one device: inside the row-sharded ``shard_map`` each chip reaches
+    its own table's rows with the row kernels, in place, and on a batch
+    whose ids span the tables the two loss trajectories agree."""
+    from flexflow_tpu.apps import dlrm
+    from flexflow_tpu.models.dlrm import DLRMConfig
+
+    one, _, _ = train_phase("train/dlrm4/one-device", dlrm,
+                            [*argv, "-ll:tpu", "1"], kernels=True)
+    mesh, _, final = train_phase("train/dlrm4/mesh", dlrm, argv, kernels=True)
+    ex = mesh.ex
+    check([op.name for op in ex._sparse_ops] == ["embeddings"]
+          and ex._pc(ex._sparse_ops[0]).c == len(jax.devices()),
+          "train/dlrm4: the tables are not row-sparse at c = the mesh")
+    missing = set(jax.devices()) - holders(final)
+    check(not missing,
+          f"train/dlrm4: no live buffer on {sorted(map(str, missing))}")
+    host = spanning_batch(ex, DLRMConfig.parse_args(list(argv)))
+    multi, _, _ = replay(mesh, 4, ex.shard_batch(host))
+    single, _, _ = replay(one, 4, one.ex.shard_batch(host))
+    np.testing.assert_allclose(
+        multi, single, rtol=SHARD_RTOL, atol=SHARD_ATOL,
+        err_msg="train/dlrm4: mesh and one-device loss trajectories differ",
+    )
+    info("train/dlrm4", mesh_losses=[round(x, 6) for x in multi],
+         one_device_losses=[round(x, 6) for x in single])
+
+
 def four_chip_serve(argv: Sequence[str], shard: Sequence[str]) -> None:
     """Sharded decode (batch on n, KV heads on c; flash_decode under
     shard_map) against the one-device engine."""
@@ -611,6 +655,7 @@ def four_chip_phases(sz: Sizes) -> List[Phase]:
 
     return [
         ("native", build_native),
+        ("train/dlrm4", lambda: dlrm4_phase(sz.dlrm4)),
         ("train/alexnet4",
          lambda: four_chip_train("train/alexnet4", alexnet, sz.alexnet4,
                                  sz.alexnet4_strategy)),

@@ -10,7 +10,12 @@ is XLA's gather transpose (deterministic, no atomics).  Table/expert
 parallelism is first-class via :class:`MultiEmbedding`, which stacks
 all tables into one (T, vocab, dim) parameter sharded T-ways on the
 ``c`` axis — the GSPMD equivalent of per-table placement, with the
-all-to-all the mapper's copies implied now emitted by XLA.
+all-to-all the mapper's copies implied now emitted by XLA.  The
+executor's sparse protocol (``sparse_rows`` / ``sparse_apply``, outside
+autodiff) reaches the rows with the Pallas row kernels instead where
+``_row_addressing`` finds a form for the array a device holds: the
+whole table on one chip, its shard inside the row-sharded
+``shard_map``, always in the shape and order it is stored.
 
 Row sharding (SHARDING.md "Sharded embedding tables"): any table whose
 LEADING param dim is tagged ``c`` (``MultiEmbedding``'s stacked T dim,
@@ -28,7 +33,11 @@ Its transpose is a LOCAL masked scatter-add into the owning shard
 atomics and without any collective), so the row-sparse update path
 composes with sharding unchanged.  Both directions are value-exact vs
 the replicated forms: the psum adds structural zeros and the local
-scatter applies the same per-occurrence adds in the same order.
+scatter applies the same per-occurrence adds in the same order.  A
+stacked ``MultiEmbedding`` under the sparse protocol needs neither mask
+nor psum: column ``t`` of its ids addresses table ``t``, so the ids
+split over ``c`` with the tables and each shard resolves its own
+columns (``by_table`` below).
 """
 
 from __future__ import annotations
@@ -97,153 +106,217 @@ def _shard_offset(plan, c_axes, local_rows):
     return k * local_rows
 
 
-def _sharded_gather(op: Op, table, flat_ids, shard):
-    """Row-range-sharded ``table[(R, D)][flat_ids]``: each shard takes
-    the ids in its range (masked, clipped), a ``psum`` over the c
-    group assembles full rows.  Never a full-table all-gather; the
-    psum adds only structural zeros, so values are bit-identical to
-    the replicated ``jnp.take``.  Differentiable (pure shard_map +
-    psum), so both the dense-grad forward AND the executor sparse
-    protocol may route here."""
+def _table_spec(c_axes, ndim):
+    from jax.sharding import PartitionSpec
+
+    return PartitionSpec(c_axes, *(None,) * (ndim - 1))
+
+
+def _local_addressing(op: Op, table, n_ids: int, c_deg: int, kind: str) -> str:
+    """``_row_addressing`` of one shard of ``table`` (leading dim over
+    ``c_deg`` devices) for the ``n_ids`` ids a device resolves there."""
+    local_shape = (table.shape[0] // c_deg,) + tuple(table.shape[1:])
+    return _row_addressing(op, n_ids, local_shape, table.dtype, kind,
+                           in_shard_map=True)
+
+
+def _take_rows(tbl, loc, how: str):
+    """Rows ``loc`` (local flat ids, any shape) of the shard ``tbl`` as
+    it lies, ``(R, D)`` or stacked ``(T, V, D)``."""
+    d = tbl.shape[-1]
+    if how == "xla":
+        return jnp.take(tbl.reshape(-1, d), loc, axis=0)
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    return pk.gather_rows(tbl, loc.reshape(-1)).reshape(loc.shape + (d,))
+
+
+def _add_rows(tbl, loc, upd, how: str):
+    """``tbl.at[loc].add(upd)`` over the shard's flat rows, in the
+    shard's own shape (in place through the kernels)."""
+    d = tbl.shape[-1]
+    if how == "xla":
+        return tbl.reshape(-1, d).at[loc].add(upd).reshape(tbl.shape)
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    return pk.scatter_add_rows(tbl, loc.reshape(-1), upd.reshape(-1, d))
+
+
+def _sharded_gather(op: Op, table, flat_ids, shard, *, sparse=False,
+                    by_table=False):
+    """Row-range-sharded ``table[flat_ids]`` (``flat_ids`` over the
+    table's flat rows; the table ``(R, D)`` or stacked ``(T, V, D)``, as
+    it lies): the owning shard resolves each id inside a ``shard_map``,
+    never a full-table all-gather, values bit-identical to the
+    replicated ``jnp.take``.
+
+    ``by_table`` (``MultiEmbedding``: ids ``(B, T)``, column ``t``
+    addresses stacked table ``t``, the stacked dim on ``c``): the ids'
+    table axis splits over ``c`` like the tables, so a shard sees only
+    its own columns, masks nothing, and the result stays on ``c`` along
+    that axis, which is the op's output tag.  Otherwise the owner
+    depends on the id's value: every shard takes all ids (masked,
+    clipped) and a ``psum`` over the c group, adding structural zeros,
+    assembles full rows.
+
+    Differentiable (``shard_map`` + ``jnp.take`` + psum) as ``forward``
+    calls it.  ``sparse`` (the executor's sparse protocol, outside
+    autodiff) lets the shard's shape pick the Pallas row kernels."""
     import jax
     from jax.sharding import PartitionSpec
 
     c_axes, c_deg, local_rows = shard
     plan = op._plan
-    (n_axes, _), = plan.local_degrees(op._pc, "n")
+    (n_axes, n_deg), = plan.local_degrees(op._pc, "n")
     # Batch-shaped ids keep their leading dim on n; 1-D id vectors
     # (the stateful sparse path's unique rows) replicate.
     n_entry = n_axes if (n_axes and flat_ids.ndim > 1) else None
+    how = "xla"
+    if sparse:
+        n_ids = flat_ids.size // (n_deg if n_entry else 1)
+        how = _local_addressing(
+            op, table, n_ids // (c_deg if by_table else 1), c_deg, "gather")
 
     def local_fn(tbl, ids):
-        start = _shard_offset(plan, c_axes, local_rows)
-        loc = ids - start
+        loc = ids - _shard_offset(plan, c_axes, local_rows)
+        if by_table:  # this shard's own columns: every id is in range
+            return _take_rows(tbl, loc, how)
         ok = (loc >= 0) & (loc < local_rows)
-        got = jnp.take(tbl, jnp.clip(loc, 0, local_rows - 1), axis=0)
+        got = _take_rows(tbl, jnp.clip(loc, 0, local_rows - 1), how)
         got = jnp.where(ok[..., None], got, 0.0)
         return jax.lax.psum(got, c_axes)
 
     _note_shard_event(op, "embedding_gather", shards=int(c_deg),
-                      rows_per_shard=int(local_rows), combine="psum")
-    id_spec = (n_entry,) + (None,) * (flat_ids.ndim - 1)
+                      rows_per_shard=int(local_rows),
+                      combine="table_axis" if by_table else "psum",
+                      addressing=how)
+    id_spec = (n_entry, c_axes) if by_table else (
+        (n_entry,) + (None,) * (flat_ids.ndim - 1))
     return jax.shard_map(
         local_fn,
         mesh=plan.mesh,
-        in_specs=(PartitionSpec(c_axes, None), PartitionSpec(*id_spec)),
+        in_specs=(_table_spec(c_axes, table.ndim), PartitionSpec(*id_spec)),
         out_specs=PartitionSpec(*id_spec, None),
         check_vma=False,
     )(table, flat_ids)
 
 
-def _sharded_scatter_add(op: Op, table, flat_ids, upd, shard):
+def _sharded_scatter_add(op: Op, table, flat_ids, upd, shard, *,
+                         by_table=False):
     """Transpose of :func:`_sharded_gather`: each shard scatter-adds
-    the updates whose ids fall in its row range — a LOCAL masked
-    read-modify-write, no collective (ids/updates are batch-sized and
-    replicate into the shard_map; only the table stays sharded).
-    Out-of-range slots add exact zeros to local row 0, the same
-    no-op-compatible convention the stateful sparse path uses for its
-    padding slots."""
+    the updates whose ids fall in its row range into its own rows, in
+    the table's own shape — a LOCAL read-modify-write, no collective
+    (only the table stays sharded).  ``by_table``: ids and updates
+    split over ``c`` along their table axis, so a shard applies its own
+    columns and nothing else.  Otherwise ids and updates replicate into
+    the ``shard_map`` and out-of-range slots add exact zeros to local
+    row 0, the same no-op-compatible convention the stateful sparse
+    path uses for its padding slots.  Executor sparse path only: the
+    shard's shape may pick the in-place Pallas row kernel."""
     import jax
     from jax.sharding import PartitionSpec
 
     c_axes, c_deg, local_rows = shard
     plan = op._plan
     d = table.shape[-1]
+    how = _local_addressing(
+        op, table, flat_ids.size // (c_deg if by_table else 1), c_deg,
+        "scatter")
 
     def local_fn(tbl, ids, u):
-        start = _shard_offset(plan, c_axes, local_rows)
-        loc = ids.reshape(-1) - start
-        ok = (loc >= 0) & (loc < local_rows)
-        safe = jnp.where(ok, loc, 0)
-        u = jnp.where(ok[:, None], u.reshape(-1, d), 0.0)
-        return tbl.at[safe].add(u)
+        loc = ids.reshape(-1) - _shard_offset(plan, c_axes, local_rows)
+        u = u.reshape(-1, d)
+        if not by_table:  # another shard's ids: exact zeros to local row 0
+            ok = (loc >= 0) & (loc < local_rows)
+            loc, u = jnp.where(ok, loc, 0), jnp.where(ok[:, None], u, 0.0)
+        return _add_rows(tbl, loc, u, how)
 
     _note_shard_event(op, "embedding_combine", shards=int(c_deg),
                       rows_per_shard=int(local_rows),
-                      combine="local_scatter_add")
+                      combine="local_scatter_add", addressing=how)
+    split = (None, c_axes) if by_table else (None,) * flat_ids.ndim
+    tspec = _table_spec(c_axes, table.ndim)
     return jax.shard_map(
         local_fn,
         mesh=plan.mesh,
         in_specs=(
-            PartitionSpec(c_axes, None),
-            PartitionSpec(*(None,) * flat_ids.ndim),
-            PartitionSpec(*(None,) * upd.ndim),
+            tspec,
+            PartitionSpec(*split),
+            PartitionSpec(*split, *(None,) * (upd.ndim - len(split))),
         ),
-        out_specs=PartitionSpec(c_axes, None),
+        out_specs=tspec,
         check_vma=False,
     )(table, flat_ids, upd)
 
 
-def _row_addressing(op: Op, n_ids: int, table, kind: str) -> str:
-    """How the executor's sparse protocol reaches ``table``'s rows:
-    ``"lane_major"`` or ``"row_major"`` — the Pallas row kernels
-    (pallas_kernels.gather_rows / scatter_add_rows) in the order the
-    chip stores a table of this shape — or ``"xla"``.  XLA's TPU
-    lowering of gather/scatter over a big table is a full-table sweep,
-    the kernels touch only the addressed rows.  Single-device TPU only
-    (under GSPMD sharding the jnp path lets the partitioner place the
-    op), and only outside autodiff — jax has no AD rule for
-    scalar-prefetch pallas_call, so ONLY the executor's sparse
-    protocol (never ``forward``) may dispatch here.  Announced once an
-    op at build (``embedding_rows``)."""
+def _row_addressing(op: Op, n_ids: int, shape, dtype, kind: str,
+                    in_shard_map: bool = False) -> str:
+    """How the executor's sparse protocol reaches the rows of the
+    ``shape``/``dtype`` array a device sees: ``"lane_major"`` or
+    ``"row_major"`` — the Pallas row kernels (pallas_kernels.gather_rows
+    / scatter_add_rows) in the order the chip stores a table of this
+    shape — or ``"xla"``.  XLA's TPU lowering of gather/scatter over a
+    big table is a full-table sweep, the kernels touch only the
+    addressed rows.  TPU only; on one device, or ``in_shard_map`` on the
+    shard a device holds of a row-sharded table (a plain local array
+    there).  A multi-device table outside a ``shard_map`` keeps the jnp
+    path: GSPMD places that op, and cannot partition a kernel.  Only
+    outside autodiff — jax has no AD rule for scalar-prefetch
+    pallas_call, so ONLY the executor's sparse protocol (never
+    ``forward``) may dispatch here.  Announced once an op at build
+    (``embedding_rows``)."""
     import jax
     from flexflow_tpu.ops import pallas_kernels as pk
 
     plan = getattr(op, "_plan", None)
     rows = 1
-    for s in table.shape[:-1]:
+    for s in shape[:-1]:
         rows *= s
     how = None
-    if (jax.default_backend() == "tpu"
-            and not (plan is not None and plan.num_devices > 1)
+    placed_by_gspmd = (plan is not None and plan.num_devices > 1
+                       and not in_shard_map)
+    if (jax.default_backend() == "tpu" and not placed_by_gspmd
             and rows < 2**31):  # kernel ids are int32 (SMEM)
-        how = pk.rows_addressing(n_ids, table.shape, table.dtype, kind)
+        how = pk.rows_addressing(n_ids, tuple(shape), dtype, kind)
     how = how or "xla"
     _note_shard_event(op, "embedding_rows", addressing=how, kind=kind,
-                      dim=int(table.shape[-1]), ids=int(n_ids))
+                      dim=int(shape[-1]), ids=int(n_ids))
     return how
 
 
-def _gather_dispatch(op: Op, table, flat_ids):
+def _gather_dispatch(op: Op, table, flat_ids, by_table: bool = False):
     """``table[(R, D)][flat_ids] -> flat_ids.shape + (D,)`` — the
     row-sharded ``shard_map`` gather when the op's table is range
     sharded, else the Pallas row kernel when eligible, else
     ``jnp.take``.  ``table`` may arrive stacked, ``(T, V, D)`` with
-    ``flat_ids`` over its ``T*V`` rows: flattening a narrow-row table
-    is a copy of it on the chip, which only the paths that need the
-    2-D view pay.  Executor sparse path only (the Pallas branch is not
-    differentiable through)."""
-    d = table.shape[-1]
+    ``flat_ids`` over its ``T*V`` rows, and stays as it lies on every
+    path but XLA's own: flattening a narrow-row table is a copy of it
+    on the chip.  ``by_table``: see ``_sharded_gather``.  Executor
+    sparse path only (the Pallas branches are not differentiable
+    through)."""
     shard = _row_sharding(op, op.sparse_keys()[0])
     if shard is not None:
-        return _sharded_gather(op, table.reshape(-1, d), flat_ids, shard)
-    if _row_addressing(op, flat_ids.size, table, "gather") != "xla":
-        from flexflow_tpu.ops import pallas_kernels as pk
-
-        rows = pk.gather_rows(table, flat_ids.reshape(-1))
-        return rows.reshape(flat_ids.shape + (d,))
-    return jnp.take(table.reshape(-1, d), flat_ids, axis=0)
+        return _sharded_gather(op, table, flat_ids, shard, sparse=True,
+                               by_table=by_table)
+    how = _row_addressing(op, flat_ids.size, table.shape, table.dtype,
+                          "gather")
+    return _take_rows(table, flat_ids, how)
 
 
-def _scatter_add_dispatch(op: Op, table, flat_ids, upd):
+def _scatter_add_dispatch(op: Op, table, flat_ids, upd,
+                          by_table: bool = False):
     """``table.at[flat_ids].add(upd)`` in ``table``'s own shape (2-D or
     stacked, see ``_gather_dispatch``) — the local per-shard scatter
     when the op's table is row-sharded, else the in-place Pallas row
     kernel when eligible.  Executor sparse path only."""
-    d = table.shape[-1]
     upd = upd.astype(table.dtype)
     shard = _row_sharding(op, op.sparse_keys()[0])
     if shard is not None:
-        return _sharded_scatter_add(
-            op, table.reshape(-1, d), flat_ids, upd, shard
-        ).reshape(table.shape)
-    if _row_addressing(op, flat_ids.size, table, "scatter") != "xla":
-        from flexflow_tpu.ops import pallas_kernels as pk
-
-        return pk.scatter_add_rows(
-            table, flat_ids.reshape(-1), upd.reshape(-1, d)
-        )
-    return table.reshape(-1, d).at[flat_ids].add(upd).reshape(table.shape)
+        return _sharded_scatter_add(op, table, flat_ids, upd, shard,
+                                    by_table=by_table)
+    how = _row_addressing(op, flat_ids.size, table.shape, table.dtype,
+                          "scatter")
+    return _add_rows(table, flat_ids, upd, how)
 
 
 class Embedding(Op):
@@ -410,7 +483,9 @@ class MultiEmbedding(Op):
     def sparse_rows(self, params, xs):
         (idx,) = xs  # (batch, T)
         tables = params["tables"]  # (T, vocab, dim)
-        return _gather_dispatch(self, tables, self._flat_ids(tables, idx))
+        return _gather_dispatch(
+            self, tables, self._flat_ids(tables, idx), by_table=True
+        )
 
     def sparse_forward(self, rows, xs, state, training):
         return [rows.astype(self.outputs[0].dtype)], state
@@ -419,7 +494,8 @@ class MultiEmbedding(Op):
         (idx,) = xs  # (batch, T)
         tables = params["tables"]
         new = _scatter_add_dispatch(
-            self, tables, self._flat_ids(tables, idx), -lr * row_grads
+            self, tables, self._flat_ids(tables, idx), -lr * row_grads,
+            by_table=True,
         )
         return {**params, "tables": new}
 

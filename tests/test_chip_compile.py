@@ -43,10 +43,10 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 
 
 @functools.lru_cache(maxsize=None)
-def _one_chip():
+def _four_chips():
+    """The four described devices of one v5e host (a 2x2 mesh)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -54,7 +54,13 @@ def _one_chip():
         )
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e topology: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return list(topo.devices)
+
+
+def _one_chip():
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(_four_chips()[0])
 
 
 def _sds(shape, dtype):
@@ -236,49 +242,65 @@ def test_row_kernel_reads_the_table_where_it_lies(name):
         _compiled_text(name), math.prod(args[0].shape)) == []
 
 
-def _dlrm_random_step_text(monkeypatch, tables, rows):
-    """The one-chip sparse train step at ``dlrm-random``'s widths
-    (benchmark/configs/dlrm-random.json, traffic random.b1024: batch
-    1024, plain SGD), compiled for the described v5e."""
+def _dlrm_step_text(monkeypatch, tables, rows, batch, chips):
+    """The sparse train step at ``dlrm-random``'s widths (benchmark/
+    configs/dlrm-random*.json: plain SGD, ``dlrm_strategy`` over
+    ``chips`` devices), compiled for the described v5e."""
     from flexflow_tpu.config import FFConfig
-    from flexflow_tpu.models.dlrm import DLRMConfig, build_dlrm
+    from flexflow_tpu.models.dlrm import DLRMConfig, build_dlrm, dlrm_strategy
     from flexflow_tpu.optim import SGDOptimizer
     from flexflow_tpu.runtime.executor import Executor
 
-    chip = _one_chip()
+    devices = _four_chips()[:chips]
     # Steer the code that asks where it runs (.claude/skills/verify).
     monkeypatch.setattr(pk, "_interpret_default", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = FFConfig(batch_size=1024, sparse_embedding_updates=True)
+    cfg = FFConfig(batch_size=batch, sparse_embedding_updates=True)
     arch = DLRMConfig(
         sparse_feature_size=64, embedding_size=[rows] * tables,
         mlp_bot=[64, 512, 512, 64],
         mlp_top=[64 * (tables + 1), 1024, 1024, 1024, 1],
     )
-    ex = Executor(build_dlrm(batch_size=1024, dlrm=arch, config=cfg),
-                  config=cfg, optimizer=SGDOptimizer(lr=0.01),
-                  devices=list(chip.device_set))
+    ex = Executor(build_dlrm(batch_size=batch, dlrm=arch, config=cfg),
+                  strategy=dlrm_strategy(chips, arch), config=cfg,
+                  optimizer=SGDOptimizer(lr=0.01), devices=devices)
     assert [op.name for op in ex._sparse_ops] == ["embeddings"]
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype), tree)
-    args = (*ex._abstract_init(), ex._abstract_batch())
-    return ex.train_step.lower(*map(on_chip, args)).compile().as_text()
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, shardings)
+
+    params, opt_state, state = ex._abstract_init()
+    assert not jax.tree.leaves((opt_state, state))  # plain SGD, no op state
+    return ex.train_step.lower(
+        placed(params, ex.params_shardings()), opt_state, state,
+        placed(ex._abstract_batch(), ex.batch_shardings()),
+    ).compile().as_text()
 
 
-@pytest.mark.parametrize("tables, rows", [(8, 2000000), (4, 1000000)],
-                         ids=["dlrm-random", "readme-4x1M"])
+@pytest.mark.parametrize(
+    "tables, rows, batch, chips",
+    [(8, 2000000, 1024, 1), (4, 1000000, 1024, 1), (8, 8000000, 4096, 4)],
+    ids=["dlrm-random", "readme-4x1M", "dlrm-random-8m-c4"])
 def test_sparse_dlrm_step_holds_no_table_sized_relayout(
-        monkeypatch, tables, rows):
+        monkeypatch, tables, rows, batch, chips):
     """The relayout cannot come back unseen (PERF.md §6, PR 28: four
-    of them were 76 of the step's 79 ms): besides the parameter, its
-    bitcasts and the aliased scatter call, nothing in the compiled
-    step has the table's element count."""
-    text = _dlrm_random_step_text(monkeypatch, tables, rows)
+    of them were 76 of the one-chip step's 79 ms; PR 30: five were 88%
+    of the four-chip step): besides the parameter, its bitcasts and the
+    aliased scatter call, nothing in the compiled step has the element
+    count of the table a chip holds (on four chips its ``T/c`` tables),
+    and no all-gather assembles a table."""
+    text = _dlrm_step_text(monkeypatch, tables, rows, batch, chips)
     assert chip_smoke.has_mosaic_call(text)
     for name in ("ff_gather_rows", "ff_scatter_add_rows"):
         assert re.search(rf"^\s*(ROOT )?%{name}\S* = .* custom-call\(",
                          text, re.M), name
-    assert chip_smoke.table_sized_relayouts(text, tables * rows * 64) == []
+    whole = tables * rows * 64
+    assert chip_smoke.table_sized_relayouts(text, whole // chips) == []
+    for elements in {whole, whole // chips}:
+        assert chip_smoke.table_sized_relayouts(
+            text, elements, ops=("all-gather", "all-gather-start")) == []
 
 
 def test_table_sized_relayouts_names_what_pr28_removed():
@@ -357,6 +379,10 @@ _TINY = chip_smoke.Sizes(
     serve_latent=("--model-config", "deepseek-v3-tiny", "--max-seq", "128",
                   "--max-batch", "2", "--requests", "3", "--max-new", "6",
                   "--prompt-len", "20:60", "--buckets", "128"),
+    dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
+           "--arch-sparse-feature-size", "8",
+           "--arch-embedding-size", "100-100-100-100",
+           "--arch-mlp-bot", "8-16-8", "--arch-mlp-top", "40-16-1"),
     alexnet4=("-b", "4", "-i", "2", "--image-size", "67"),
     alexnet4_strategy=chip_smoke.FULL.alexnet4_strategy,
     transformer4=("-b", "4", "--seq", "128", *_TINY_LM, "-i", "2"),

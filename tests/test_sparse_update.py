@@ -446,3 +446,160 @@ def test_lazy_row_step_through_the_row_kernels(rng, optimizer,
     np.testing.assert_allclose(
         pk_["tables"]["tables"], px["tables"]["tables"], rtol=1e-6, atol=1e-7
     )
+
+
+# -- the row-sharded dispatchers on the kernels' side --------------------------
+#
+# Inside the row-sharded ``shard_map`` a device sees a plain local
+# array, so the shard's own shape picks the kernels (ISSUE 30): here on
+# the CPU's virtual devices, the kernels under the interpreter.
+
+
+def _sharded_op(ff, name, n, c):
+    """(executor, bound op, its placed params) with ``name`` at (n, c)
+    over ``n * c`` virtual devices."""
+    store = StrategyStore(n * c)
+    store.set(name, ParallelConfig(n=n, c=c))
+    ex = Executor(ff, strategy=store, optimizer=SGDOptimizer(lr=0.5),
+                  devices=jax.devices()[:n * c])
+    (op,) = [o for o in ex.model.layers if o.name == name]
+    op.bind_mesh(ex.plan, ex._pc(op))
+    return ex, op, ex.init()[0][name]
+
+
+def _sparse_protocol(op, params, ids, grads, lr=0.5):
+    """One ``sparse_rows`` and one ``sparse_apply`` as the executor's
+    step makes them (jitted, the table donated)."""
+    rows = jax.jit(lambda p, i: op.sparse_rows(p, [i]))(params, ids)
+    new = jax.jit(lambda p, i, g: op.sparse_apply(p, [i], g, lr),
+                  donate_argnums=0)(params, ids, grads)
+    return jax.device_get(rows), jax.device_get(new)
+
+
+_STACKED = (4, 160, 16)  # tables, rows (a partial last 128-row block), width
+
+
+def _stacked_model(batch=8):
+    tables, rows, dim = _STACKED
+    ff = FFModel(FFConfig(batch_size=batch, sparse_embedding_updates=True))
+    ids = ff.create_tensor((batch, tables), dtype=jnp.int32, name="ids")
+    lbl = ff.create_tensor((batch,), dtype=jnp.int32, name="label")
+    e = ff.multi_embedding(ids, tables, rows, dim, name="tables")
+    e = ff.reshape(e, (batch, tables * dim), name="r1")
+    ff.softmax(ff.dense(e, 4, name="fc"), lbl, name="softmax")
+    return ff
+
+
+def _stacked_ids(duplicates, batch=8):
+    """Every table's first and last row (so every shard's), rows of the
+    partial last block, and distinct rows otherwise; ``duplicates``
+    repeats rows within the batch, next to each other and far apart."""
+    tables, rows, _ = _STACKED
+    ids = np.stack([(37 * np.arange(batch) + 11 * t) % 120 + 1
+                    for t in range(tables)], axis=1).astype(np.int32)
+    ids[0], ids[1], ids[2], ids[3] = 0, rows - 1, 128, 141
+    if duplicates:
+        ids[4], ids[5], ids[7] = ids[1], ids[1], ids[0]
+    return ids
+
+
+@pytest.mark.parametrize("duplicates", [False, True],
+                         ids=["unique", "duplicates"])
+@pytest.mark.parametrize("n, c", [(1, 2), (1, 4), (2, 4)])
+def test_sharded_multi_embedding_through_the_row_kernels(
+        rng, n, c, duplicates, row_kernels_on_the_cpu, tmp_path):
+    """A shard resolves its own tables' columns of the ids, and only
+    those, with the kernels on the ``(T/c, V, D)`` shard as it lies:
+    ``sparse_rows`` is the replicated take exactly, ``sparse_apply``
+    the replicated scatter-add (exactly where no row repeats)."""
+    from flexflow_tpu.runtime.telemetry import Telemetry
+
+    tables, rows, dim = _STACKED
+    ids = _stacked_ids(duplicates)
+    grads = rng.standard_normal((8, tables, dim)).astype(np.float32)
+    with Telemetry(str(tmp_path)) as tel:
+        _, op, params = _sharded_op(_stacked_model(), "tables", n, c)
+        table = jax.device_get(params["tables"])
+        got_rows, new = _sparse_protocol(op, params, ids, grads)
+        path = tel.path
+    t_range = np.arange(tables)[None, :]
+    np.testing.assert_array_equal(got_rows, table[t_range, ids])
+    want = table.copy()
+    np.add.at(want, (t_range.repeat(8, 0), ids), np.float32(-0.5) * grads)
+    if duplicates:
+        np.testing.assert_allclose(new["tables"], want, rtol=1e-6, atol=1e-7)
+    else:
+        np.testing.assert_array_equal(new["tables"], want)
+    (gather,) = _events(path, "embedding_gather")
+    (combine,) = _events(path, "embedding_combine")
+    assert (gather["addressing"], gather["combine"]) == (
+        "lane_major", "table_axis")
+    assert (combine["addressing"], combine["shards"]) == ("lane_major", c)
+    # The ids a device resolves: its batch rows times its own tables.
+    (noted,) = _events(path, "embedding_rows")
+    assert (noted["addressing"], noted["ids"]) == (
+        "lane_major", 8 // n * tables // c)
+
+
+@pytest.mark.parametrize("owners", ["every-shard", "one-shard"])
+def test_shard_rows_embedding_masked_form_through_the_row_kernels(
+        rng, owners, row_kernels_on_the_cpu, tmp_path):
+    """A vocab-sharded table's owner depends on the id's value: every
+    shard runs the kernels over all ids, clipped and masked, with ids
+    in every shard's range (its first and last row among them) and with
+    ids three of the four shards own none of."""
+    from flexflow_tpu.runtime.telemetry import Telemetry
+
+    vocab, dim, c = 512, 16, 4  # a (128, 16) shard: one lane-major block
+    ff = FFModel(FFConfig(batch_size=8, sparse_embedding_updates=True,
+                          shard_embeddings=True))
+    bag = ff.create_tensor((8, 3), dtype=jnp.int32, name="bag")
+    lbl = ff.create_tensor((8,), dtype=jnp.int32, name="label")
+    e = ff.embedding(bag, vocab, dim, aggr="sum", name="emb")
+    ff.softmax(ff.dense(e, 4, name="fc"), lbl, name="softmax")
+    if owners == "every-shard":
+        ids = rng.integers(0, vocab, size=(8, 3)).astype(np.int32)
+        ids[0] = [0, 127, 128]
+        ids[1] = [255, 256, 511]
+        ids[2] = ids[0]  # the same rows again
+    else:
+        ids = rng.integers(128, 256, size=(8, 3)).astype(np.int32)
+    grads = rng.standard_normal((8, 3, dim)).astype(np.float32)
+    with Telemetry(str(tmp_path)) as tel:
+        _, op, params = _sharded_op(ff, "emb", 2, c)
+        table = jax.device_get(params["table"])
+        got_rows, new = _sparse_protocol(op, params, ids, grads)
+        path = tel.path
+    np.testing.assert_array_equal(got_rows, table[ids])
+    want = table.copy()
+    np.add.at(want, ids, np.float32(-0.5) * grads)
+    np.testing.assert_allclose(new["table"], want, rtol=1e-6, atol=1e-7)
+    (gather,) = _events(path, "embedding_gather")
+    assert (gather["addressing"], gather["combine"]) == ("lane_major", "psum")
+
+
+def test_sharded_forward_stays_on_the_differentiable_take(
+        rng, row_kernels_on_the_cpu, monkeypatch):
+    """``forward`` is what autodiff traces: on a TPU too it reaches the
+    shard's rows with ``jnp.take``, never a row kernel (jax has no AD
+    rule for a scalar-prefetch ``pallas_call``)."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    def refuse(*a, **k):
+        raise AssertionError("forward() dispatched to a row kernel")
+
+    monkeypatch.setattr(pk, "gather_rows", refuse)
+    monkeypatch.setattr(pk, "scatter_add_rows", refuse)
+    tables, rows, dim = _STACKED
+    _, op, params = _sharded_op(_stacked_model(), "tables", 1, 4)
+    ids = _stacked_ids(duplicates=True)
+    weight = rng.standard_normal((8, tables, dim)).astype(np.float32)
+
+    def loss(p):
+        (y,), _ = op.forward(p, [ids], {}, True)
+        return jnp.sum(y * weight)
+
+    grad = jax.device_get(jax.jit(jax.grad(loss))(params))["tables"]
+    want = np.zeros((tables, rows, dim), np.float32)
+    np.add.at(want, (np.arange(tables)[None, :].repeat(8, 0), ids), weight)
+    np.testing.assert_allclose(grad, want, rtol=1e-6, atol=1e-7)
