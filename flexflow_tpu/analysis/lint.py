@@ -158,11 +158,15 @@ def _is_jit_expr(node: ast.AST) -> bool:
 def _traced_functions(tree: ast.AST) -> List[ast.AST]:
     """Function defs the lint treats as jit-traced: decorated with jit,
     or passed directly to a ``jax.jit(...)`` call as the first argument
-    (resolved to a def in the same module).  A static approximation —
-    the program audit (layer 2) checks the real traced programs."""
+    (resolved to a def in the same module; never to a method, which no
+    bare name reaches).  A static approximation — the program audit
+    (layer 2) checks the real traced programs."""
+    methods = {id(f) for c in ast.walk(tree) if isinstance(c, ast.ClassDef)
+               for f in c.body}
     defs: Dict[str, ast.AST] = {}
     for node in ast.walk(tree):
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) \
+                and id(node) not in methods:
             defs.setdefault(node.name, node)
     traced: List[ast.AST] = []
     for node in ast.walk(tree):

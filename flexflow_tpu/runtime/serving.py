@@ -48,6 +48,12 @@ superstep discipline the training runtime already has (PRs 1/3/5):
   paged alike — out-of-reservation paged writes land in scratch
   block 0).
 
+Three layers in this module: :class:`ServingExecutor` builds the
+programs, :class:`ServingEngine` holds the caches and is the only code
+that dispatches or fences one, and :class:`Server` (like the
+scheduler's ``ScheduledServer``) is admission policy and bookkeeping
+over the engine.
+
 The KV-cache protocol lives on the op layer (``ops/attention.py``):
 ``MultiHeadAttention.forward`` takes a cached path when ``state``
 carries ``cache_k``/``cache_v``/``pos``, with a Pallas flash *decode*
@@ -331,10 +337,10 @@ class KVBlockLedger:
     """Host-side free-list accounting for the paged KV pool.
 
     PURE integer arithmetic, deliberately device-free: the SAME ledger
-    gates admission in the real :class:`Server` / ``_RealEngine`` loop
-    and in the scheduler's compute-free ``simulated`` mode, so the
-    simulation stays dispatch-for-dispatch exact on the paged path by
-    construction.
+    gates admission in both loops over the real :class:`ServingEngine`
+    (:class:`Server`, ``ScheduledServer``) and in the scheduler's
+    compute-free ``simulated`` mode, so the simulation stays
+    dispatch-for-dispatch exact on the paged path by construction.
 
     Block 0 is the SCRATCH block — never allocated.  Inactive slots'
     table rows point at it, and decode writes past a slot's
@@ -1746,6 +1752,198 @@ class ServingExecutor:
         return out
 
 
+class ServingEngine:
+    """The device side of a serving loop: the caches and every dispatch
+    and fence on them.  :class:`Server` and the scheduler's
+    ``ScheduledServer`` are both policy over this one class — who is
+    admitted, the ledger, token consumption, journal, events — and the
+    scheduler's ``_SimEngine`` is its compute-free twin, with the same
+    signatures.
+
+    Three decisions live here and nowhere else: which
+    ``SPAN_CATALOG`` span wraps which call, what rides the prefill's
+    and the superstep's fence (tokens, finiteness, and the expert
+    layers' routing counters, handed back to the caller), and how a
+    prefill's rows reach a slot (:meth:`install`).  Prefill and install
+    are separate calls because the two loops allocate the ledger row on
+    different sides of the prefill's fence.  A wall runs from the
+    instant the host starts on the program's arguments (a prefill's:
+    once the prompt is padded) to its fence's return.  ``caches`` is
+    public: the fault injector's ``before_superstep`` takes it and
+    hands back what the engine then holds."""
+
+    simulated = False
+
+    def __init__(self, ex: ServingExecutor, params, op_state,
+                 sample: Optional[Tuple[float, int, int]] = None,
+                 speculate: int = 0, draft_params=None):
+        self.ex = ex
+        self.params = params
+        self.op_state = op_state
+        self.sample = sample
+        self.speculate = speculate
+        self.caches = ex.init_cache()
+        if speculate:
+            self.draft_params = (draft_params if draft_params is not None
+                                 else params)
+            self.dcaches = ex.init_draft_cache()
+
+    @staticmethod
+    def _pad(prompt, bucket: int) -> np.ndarray:
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :len(prompt)] = np.asarray(prompt, np.int32)
+        return padded
+
+    def prefill(self, prompt, bucket: int, plen: Optional[int] = None,
+                rid: int = 0, offset: int = 0, shared_ids=None):
+        """Pad-to-bucket prefill: ``(cache_rows, first_token, finite,
+        counters, wall_s)`` after one fence; the rows are for
+        :meth:`install`.  ``prompt`` is the full (prompt ‖ carried)
+        sequence — re-prefill over it is the loss-free resume primitive
+        of journal recovery and preemption.  Sampled engines prefill
+        through the sampled variant, keyed by ``plen``/``rid``, so a
+        RESUMED position replays the decode head's exact draw (greedy
+        when ``len(prompt) == plen``, a fresh admission).  ``offset >
+        0`` runs the prefix-sharing offset prefill instead
+        (``build_prefill_from``): the shared span's KV is gathered from
+        the pool blocks ``shared_ids`` and only the tail is computed.
+        ``counters`` are the expert layers' routing counters that rode
+        the fence ({} for a graph without them)."""
+        tel = _telemetry.current()
+        with _telemetry.span("ff/serve/prefill_dispatch", id=rid,
+                             bucket=bucket):
+            padded = self._pad(prompt, bucket)
+            t0 = time.perf_counter()
+            if offset:
+                pf = self.ex.build_prefill_from(bucket, offset,
+                                                sample=self.sample)
+                args = (self.params, self.op_state, self.caches,
+                        np.asarray(shared_ids, np.int32), padded,
+                        np.int32(len(prompt)))
+            else:
+                pf = self.ex.build_prefill(bucket, sample=self.sample)
+                args = (self.params, self.op_state, padded,
+                        np.int32(len(prompt)))
+            if self.sample is not None:
+                args += (np.int32(len(prompt) if plen is None else plen),
+                         np.int32(rid))
+            tel.program_cost("prefill", pf, args, bucket=bucket)
+            rows, *fetch = pf(*args)
+        with _telemetry.span("ff/serve/prefill_fence", id=rid):
+            tok0, ok, *counters = tel.fence(tuple(fetch), "prefill")
+        return (rows, int(tok0), bool(ok), counters[0] if counters else {},
+                time.perf_counter() - t0)
+
+    def install(self, rows, slot_i: int, row: Optional[np.ndarray] = None,
+                shared: int = 0, rid: int = 0) -> None:
+        """Install prefilled ``rows`` into ``slot_i``: the padded cache
+        row, or on the paged layout the ledger-assigned table ``row`` of
+        pool blocks.  Under a shared prefix the first ``shared`` entries
+        are masked to scratch block 0, where their (all-zero) chunks
+        land — the donor's blocks are never written; the caller's table
+        row keeps the real shared ids for decode."""
+        with _telemetry.span("ff/serve/install", id=rid):
+            if row is None:
+                self.caches = self.ex.install(self.caches, rows, slot_i)
+                return
+            if shared:
+                row = row.copy()
+                row[:shared] = 0
+            self.caches = self.ex.install_paged(self.caches, rows, row)
+
+    def draft_prefill(self, prompt, bucket: int, slot_i: int,
+                      rid: int = 0) -> float:
+        """Populate the DRAFT model's own cache rows for ``slot_i`` —
+        one extra dispatch per admission when speculating, priced by
+        the latency model's ``draft_prefill_ms``.  No fence: nothing to
+        read back, and the next spec round synchronizes."""
+        t0 = time.perf_counter()
+        with _telemetry.span("ff/serve/prefill_dispatch", id=rid,
+                             bucket=bucket):
+            dpf = self.ex.build_draft_prefill(bucket)
+            dargs = (self.draft_params, self.op_state,
+                     self._pad(prompt, bucket))
+            _telemetry.current().program_cost("draft_prefill", dpf, dargs,
+                                              bucket=bucket)
+            drows = dpf(*dargs)
+        with _telemetry.span("ff/serve/install", id=rid):
+            self.dcaches = self.ex.install(self.dcaches, drows, slot_i)
+        return time.perf_counter() - t0
+
+    def prepare(self, k: int) -> None:
+        """Build the program a closed loop's every superstep will call
+        (the speculative round of the engine's depth, else the decode
+        superstep of ``k`` steps) before the first admission, so that
+        its ``serving_program`` event leads the run's stream as it
+        always has.  A loop that chooses ``k`` a superstep skips this:
+        the programs are built as they are first called."""
+        if self.speculate:
+            self.ex.build_spec_step(self.speculate, sample=self.sample)
+        else:
+            self.ex.build_decode_superstep(k, sample=self.sample)
+
+    def _batch_args(self, pos_vec, tok_vec, block_table, req_ids) -> Tuple:
+        """The tail every superstep program takes after its caches."""
+        args = (pos_vec, tok_vec)
+        if block_table is not None:
+            # The caller rewrites its table row by row while the
+            # dispatch may still be reading this one.
+            args = (block_table.copy(),) + args
+        if self.sample is not None:
+            args += (np.asarray(req_ids, np.int32),)
+        return args
+
+    # In decode and spec the donated caches, the argument tuple, the
+    # program's pos/tok carry (the loops keep theirs on the host) and
+    # the fetched device arrays are all dropped inside a span: what is
+    # freed between two spans a traced run counts as no span's.
+
+    def decode(self, pos_vec: np.ndarray, tok_vec: np.ndarray, k: int,
+               block_table: Optional[np.ndarray] = None,
+               req_ids: Optional[np.ndarray] = None, superstep: int = 0):
+        """One fused k-token superstep over the whole slot batch:
+        ``(tokens (k, B), finite (k, B), counters, wall_s)`` after one
+        fence — ``counters`` the routing counters a step, (k,) each."""
+        tel = _telemetry.current()
+        with _telemetry.span("ff/serve/decode_dispatch",
+                             superstep=superstep):
+            t0 = time.perf_counter()
+            fn = self.ex.build_decode_superstep(k, sample=self.sample)
+            args = (self.params, self.op_state, self.caches) + \
+                self._batch_args(pos_vec, tok_vec, block_table, req_ids)
+            tel.program_cost("decode_superstep", fn, args, k=k)
+            self.caches, _pos, _tok, fetch = fn(*args)
+            del args, _pos, _tok
+        with _telemetry.span("ff/serve/decode_fence", superstep=superstep):
+            toks, oks, *counters = tel.fence(fetch, "decode_superstep")
+            wall = time.perf_counter() - t0
+            del fetch
+        return toks, oks, counters[0] if counters else {}, wall
+
+    def spec(self, pos_vec: np.ndarray, tok_vec: np.ndarray, d: int,
+             block_table: Optional[np.ndarray] = None,
+             req_ids: Optional[np.ndarray] = None, superstep: int = 0):
+        """One fused speculative round (d+1 draft steps, d+1 verify
+        steps) over the whole slot batch: ``(tokens (d+1, B), finite
+        (d+1, B), accepted (B,), wall_s)`` after one fence."""
+        tel = _telemetry.current()
+        with _telemetry.span("ff/serve/decode_dispatch",
+                             superstep=superstep):
+            t0 = time.perf_counter()
+            fn = self.ex.build_spec_step(d, sample=self.sample)
+            args = (self.params, self.draft_params, self.op_state,
+                    self.caches, self.dcaches) + \
+                self._batch_args(pos_vec, tok_vec, block_table, req_ids)
+            tel.program_cost("spec_verify", fn, args, d=d)
+            self.caches, self.dcaches, _pos, _tok, fetch = fn(*args)
+            del args, _pos, _tok
+        with _telemetry.span("ff/serve/decode_fence", superstep=superstep):
+            toks, oks, acc = tel.fence(fetch, "spec_verify")
+            wall = time.perf_counter() - t0
+            del fetch
+        return toks, oks, acc, wall
+
+
 class Server:
     """Continuous-batching serving loop over a :class:`ServingExecutor`.
 
@@ -1818,15 +2016,11 @@ class Server:
         ex = self.ex
         B, k = ex.max_batch, self.decode_steps
         spec_d = self.speculate
-        if spec_d:
-            decode_fn = None
-            spec_fn = ex.build_spec_step(spec_d, sample=self.sample)
-            dcaches = ex.init_draft_cache()
-        else:
-            decode_fn = ex.build_decode_superstep(k, sample=self.sample)
-            spec_fn = None
-            dcaches = None
-        caches = ex.init_cache()
+        # Made anew each run: every run starts on fresh caches.
+        engine = ServingEngine(ex, self.params, self.op_state,
+                               sample=self.sample, speculate=spec_d,
+                               draft_params=self.draft_params)
+        engine.prepare(k)
         ledger = ex.make_ledger() if ex.paged else None
         block_table = (
             np.zeros((B, ledger.blocks_per_slot), np.int32)
@@ -1840,16 +2034,8 @@ class Server:
         results: Dict[int, RequestResult] = {}
         superstep_idx = 0
         total_tokens = 0
-        supersteps = 0
-        prefills = 0
-        prefix_hits = 0
-        full_hits = 0
-        prefill_tokens_saved = 0
-        kv_cows = 0
-        draft_prefills = 0
-        decode_tokens = 0
-        spec_accept_total = 0
-        spec_draft_total = 0
+        #: The run's counts: supersteps, prefills, prefix hits, ...
+        n = collections.Counter()
         decode_s = 0.0
         t_run0 = time.perf_counter()
         # -- journal replay: completed requests are NOT re-run,
@@ -1903,6 +2089,13 @@ class Server:
                 ledger.free(slot_i)
                 block_table[slot_i] = 0
             slots[slot_i] = None
+
+        def rounded(counters) -> Dict[str, float]:
+            # Routing counters (expert layers only) as an event carries
+            # them: the mean over a superstep's K steps of the layers'
+            # mean (a prefill's are one step's).
+            return {key: round(float(np.mean(v)), 4)
+                    for key, v in counters.items()}
 
         def slot_done(sl: _Slot) -> bool:
             toks = sl.all_tokens
@@ -2020,78 +2213,42 @@ class Server:
                         slot_i = slots.index(None)
                         tel.emit("request_start", id=r.id, prompt_len=plen,
                                  bucket=bucket, slot=slot_i)
-                        # Re-prefill over (prompt ‖ carried) — the
-                        # loss-free resume primitive, shared with the
-                        # scheduler's preemption path.
-                        with _telemetry.span("ff/serve/prefill_dispatch",
-                                             id=r.id, bucket=bucket):
-                            padded = np.zeros((1, bucket), np.int32)
-                            padded[0, :plen] = np.asarray(r.prompt, np.int32)
-                            if prior:
-                                padded[0, plen:flen] = np.asarray(
-                                    prior, np.int32
-                                )
-                            digests = (
-                                prefix_digests(r.prompt, ledger.block)
-                                if ledger is not None and ledger.prefix_cache
-                                else []
-                            )
-                            t0 = time.perf_counter()
-                            pf = rows = okf = None
-                            if plan is not None and plan.full_hit:
-                                # -- ZERO-dispatch admission: the whole prompt
-                                # is resident full blocks and the greedy first
-                                # token is memoized — no prefill program runs
-                                # at all (the prefix-sharing headline).
-                                tok0 = plan.tok0
-                            elif plan is not None and plan.use > 0:
-                                # -- partial hit: gather the shared span from
-                                # the pool, compute only the tail through the
-                                # offset prefill (same fence discipline).
-                                pf = ex.build_prefill_from(
-                                    bucket, plan.offset, sample=self.sample
-                                )
-                                shared_ids = np.asarray(plan.shared, np.int32)
-                                pf_args = (self.params, self.op_state, caches,
-                                           shared_ids, padded, np.int32(flen))
-                            else:
-                                # Sampled runs prefill through the sampled
-                                # variant so a RESUMED position replays the
-                                # decode head's exact draw (greedy when
-                                # flen == plen, i.e. a fresh admission).
-                                pf = ex.build_prefill(bucket,
-                                                      sample=self.sample)
-                                pf_args = (self.params, self.op_state, padded,
-                                           np.int32(flen))
-                            if pf is not None:
-                                if self.sample is not None:
-                                    pf_args += (np.int32(plen), np.int32(r.id))
-                                tel.program_cost("prefill", pf, pf_args,
-                                                 bucket=bucket)
-                                rows, tok0, okf, *pstats = pf(*pf_args)
-                        if okf is None:
-                            ok, pf_s = True, 0.0
-                            prefix_hits += 1
-                            full_hits += 1
-                            prefill_tokens_saved += plan.offset
+                        full = np.concatenate([
+                            np.asarray(r.prompt, np.int32),
+                            np.asarray(prior, np.int32),
+                        ]) if prior else r.prompt
+                        digests = (
+                            prefix_digests(r.prompt, ledger.block)
+                            if ledger is not None and ledger.prefix_cache
+                            else []
+                        )
+                        use = plan.use if plan is not None else 0
+                        rows = None
+                        if plan is not None and plan.full_hit:
+                            # -- ZERO-dispatch admission: the whole prompt
+                            # is resident full blocks and the greedy first
+                            # token is memoized — no prefill program runs
+                            # at all (the prefix-sharing headline).
+                            tok0, ok, pf_s = plan.tok0, True, 0.0
+                            n["prefix_hits"] += 1
+                            n["full_hits"] += 1
+                            n["prefill_tokens_saved"] += plan.offset
                             tel.emit("prefix_hit", id=r.id,
                                      blocks=plan.use, full=True,
                                      tokens_saved=plan.offset)
                         else:
-                            with _telemetry.span("ff/serve/prefill_fence",
-                                                 id=r.id):
-                                tok0, ok, *pstats = tel.fence(
-                                    (tok0, okf, *pstats), "prefill")
-                            pf_s = time.perf_counter() - t0
-                            # The expert layers' routing counters ride
-                            # the fence the prefill already has.
-                            routed = {k: round(float(v), 4)
-                                      for k, v in pstats[0].items()} \
-                                if pstats else {}
-                            prefills += 1
-                            if plan is not None and plan.use > 0:
-                                prefix_hits += 1
-                                prefill_tokens_saved += plan.offset
+                            # A partial hit (use > 0) gathers the shared
+                            # span from the pool and computes only the
+                            # tail (same fence discipline).
+                            rows, tok0, ok, routed, pf_s = engine.prefill(
+                                full, bucket, plen=plen, rid=r.id,
+                                offset=plan.offset if use else 0,
+                                shared_ids=plan.shared if use else None,
+                            )
+                            n["prefills"] += 1
+                            if use:
+                                n["prefix_hits"] += 1
+                                n["prefill_tokens_saved"] += plan.offset
                                 tel.emit("prefill", id=r.id, bucket=bucket,
                                          offset=plan.offset,
                                          wall_s=round(pf_s, 6))
@@ -2099,70 +2256,50 @@ class Server:
                                          blocks=plan.use, full=False,
                                          tokens_saved=plan.offset)
                                 if plan.cow:
-                                    kv_cows += plan.cow
+                                    n["kv_cows"] += plan.cow
                                     tel.emit("kv_cow", id=r.id,
                                              blocks=plan.cow)
                             else:
                                 tel.emit("prefill", id=r.id, bucket=bucket,
-                                         wall_s=round(pf_s, 6), **routed)
+                                         wall_s=round(pf_s, 6),
+                                         **rounded(routed))
                         if jr is not None:
-                            jr.admit(r.id, plen,
-                                     int(tok0) if bool(ok) else None,
+                            jr.admit(r.id, plen, tok0 if ok else None,
                                      resumed=len(prior))
-                        if not bool(ok):
+                        if not ok:
                             sl = _Slot(r, flen, 0, [], t_run0, pf_s,
                                        carried=list(prior))
                             slots[slot_i] = sl
                             finish(slot_i,
                                    error="non-finite logits in prefill")
                             continue
-                        with _telemetry.span("ff/serve/install", id=r.id):
-                            if ledger is not None:
-                                row = ledger.alloc(slot_i, need,
-                                                   shared=plan.shared)
-                                block_table[slot_i] = row
-                                if rows is not None:
-                                    # Masked install: shared entries write
-                                    # their (all-zero) chunks into scratch
-                                    # block 0 — the donor's blocks are never
-                                    # touched; the table row keeps the real
-                                    # shared ids for decode.
-                                    masked = row.copy()
-                                    masked[: plan.use] = 0
-                                    caches = ex.install_paged(caches, rows,
-                                                              masked)
-                                if digests:
-                                    # Index only AFTER the fence validated the
-                                    # install (never make never-written blocks
-                                    # shareable); memoize the first token when
-                                    # the prompt is exactly block-aligned and
-                                    # fresh — the future full-hit upgrade.
-                                    ledger.register_prefix(slot_i, digests,
-                                                           start=plan.use)
-                                    if flen == plen and \
-                                            plen % ledger.block == 0 and \
-                                            not plan.full_hit:
-                                        ledger.record_next(digests[-1],
-                                                           int(tok0))
-                            else:
-                                caches = ex.install(caches, rows, slot_i)
-                            if spec_d:
-                                # Populate the DRAFT model's own cache rows —
-                                # one extra dispatch per admission, priced by
-                                # the latency model's draft_prefill_ms.  No
-                                # fence: nothing to read back, and the next
-                                # spec round synchronizes.
-                                dpf = ex.build_draft_prefill(bucket)
-                                dargs = (self.draft_params, self.op_state,
-                                         padded)
-                                tel.program_cost("draft_prefill", dpf, dargs,
-                                                 bucket=bucket)
-                                drows = dpf(*dargs)
-                                dcaches = ex.install(dcaches, drows, slot_i)
-                                draft_prefills += 1
+                        row = None
+                        if ledger is not None:
+                            row = ledger.alloc(slot_i, need,
+                                               shared=plan.shared)
+                            block_table[slot_i] = row
+                        if rows is not None:
+                            engine.install(rows, slot_i, row=row,
+                                           shared=use, rid=r.id)
+                        if digests:
+                            # Index only AFTER the fence validated the
+                            # install (never make never-written blocks
+                            # shareable); memoize the first token when
+                            # the prompt is exactly block-aligned and
+                            # fresh — the future full-hit upgrade.
+                            ledger.register_prefix(slot_i, digests,
+                                                   start=use)
+                            if flen == plen and \
+                                    plen % ledger.block == 0 and \
+                                    not plan.full_hit:
+                                ledger.record_next(digests[-1], tok0)
+                        if spec_d:
+                            engine.draft_prefill(full, bucket, slot_i,
+                                                 rid=r.id)
+                            n["draft_prefills"] += 1
                         sl = _Slot(
-                            request=r, pos=flen, last_tok=int(tok0),
-                            tokens=[int(tok0)], t_eligible=t_run0,
+                            request=r, pos=flen, last_tok=tok0,
+                            tokens=[tok0], t_eligible=t_run0,
                             prefill_s=pf_s, carried=list(prior),
                         )
                         total_tokens += 1
@@ -2181,9 +2318,9 @@ class Server:
                                      active=len(active)):
                     if self.injector is not None:
                         try:
-                            caches, _nan = self.injector.before_superstep(
-                                superstep_idx, caches, block_table
-                            )
+                            engine.caches = self.injector.before_superstep(
+                                superstep_idx, engine.caches, block_table
+                            )[0]
                         except ServingFault as f:
                             superstep_idx += 1
                             if slots[f.slot] is not None:
@@ -2195,49 +2332,30 @@ class Server:
                     tok_vec = np.array(
                         [sl.last_tok if sl else 0 for sl in slots], np.int32
                     )
-                    t_call = time.perf_counter()
-                    args = (self.params, self.draft_params, self.op_state,
-                            caches, dcaches) if spec_d else \
-                        (self.params, self.op_state, caches)
-                    if block_table is not None:
-                        args += (block_table.copy(),)
-                    args += (pos_vec, tok_vec)
-                    if self.sample is not None:
-                        args += (np.array(
-                            [sl.request.id if sl else 0 for sl in slots],
-                            np.int32
-                        ),)
-                with _telemetry.span("ff/serve/decode_dispatch",
-                                     superstep=superstep_idx):
-                    if spec_d:
-                        # -- one fused speculative round: d+1 draft steps
-                        # + d+1 verify steps, one dispatch, one fence
-                        # reading (tokens, finite, accepted).
-                        tel.program_cost("spec_verify", spec_fn, args,
-                                         d=spec_d)
-                        caches, dcaches, _pos, _tok, fetch = spec_fn(*args)
-                    else:
-                        tel.program_cost("decode_superstep", decode_fn,
-                                         args, k=k)
-                        caches, _pos, _tok, fetch = decode_fn(*args)
-                with _telemetry.span("ff/serve/decode_fence",
-                                     superstep=superstep_idx):
-                    if spec_d:
-                        host_toks, host_oks, host_acc = tel.fence(
-                            fetch, "spec_verify"
-                        )
-                        k_eff = spec_d + 1
-                    else:
-                        host_toks, host_oks, *dstats = tel.fence(
-                            fetch, "decode_superstep"
-                        )
-                        host_acc = None
-                        k_eff = k
+                    req_vec = np.array(
+                        [sl.request.id if sl else 0 for sl in slots],
+                        np.int32
+                    ) if self.sample is not None else None
+                if spec_d:
+                    # -- one fused speculative round: d+1 draft steps
+                    # + d+1 verify steps, one dispatch, one fence
+                    # reading (tokens, finite, accepted).
+                    host_toks, host_oks, host_acc, wall = engine.spec(
+                        pos_vec, tok_vec, spec_d, block_table=block_table,
+                        req_ids=req_vec, superstep=superstep_idx,
+                    )
+                    k_eff = spec_d + 1
+                else:
+                    host_toks, host_oks, routed, wall = engine.decode(
+                        pos_vec, tok_vec, k, block_table=block_table,
+                        req_ids=req_vec, superstep=superstep_idx,
+                    )
+                    host_acc = None
+                    k_eff = k
                 with _telemetry.span("ff/serve/bookkeep",
                                      superstep=superstep_idx):
-                    wall = time.perf_counter() - t_call
                     decode_s += wall
-                    supersteps += 1
+                    n["supersteps"] += 1
                     superstep_idx += 1
                     # Training-superstep accounting: ONE host program and
                     # one fence covered k_eff decode steps (programs/step
@@ -2249,16 +2367,11 @@ class Server:
                     # each dispatch).  Captured before finish() frees slots.
                     occ = [slots[i].request.id for i in active]
                     if not spec_d:
-                        # Routing counters (expert layers only): the
-                        # mean over the K steps of the layers' mean.
-                        routed = {key: round(float(np.mean(v)), 4)
-                                  for key, v in dstats[0].items()} \
-                            if dstats else {}
                         tel.emit("decode_superstep", k=k, active=len(active),
                                  capacity=B, slots=occ,
-                                 wall_s=round(wall, 6), **routed)
+                                 wall_s=round(wall, 6), **rounded(routed))
                     for j in range(k_eff):
-                        tel.record_step((supersteps - 1) * k_eff + j,
+                        tel.record_step((n["supersteps"] - 1) * k_eff + j,
                                         wall_s=wall / k_eff)
                     n_active = len(active)
                     emitted_round = 0
@@ -2268,7 +2381,7 @@ class Server:
                         appended: List[int] = []
                         if spec_d:
                             n_take = int(host_acc[i]) + 1
-                            spec_accept_total += int(host_acc[i])
+                            n["spec_accept_total"] += int(host_acc[i])
                         else:
                             n_take = k
                         for j in range(n_take):
@@ -2283,7 +2396,7 @@ class Server:
                             if slot_done(sl):
                                 break
                         sl.last_tok = sl.tokens[-1] if sl.tokens else 0
-                        decode_tokens += len(appended)
+                        n["decode_tokens"] += len(appended)
                         emitted_round += len(appended)
                         # Journal the fence-validated delta BEFORE any done
                         # record so replay accumulation sees tokens first —
@@ -2300,12 +2413,16 @@ class Server:
                         acc_round = int(sum(
                             int(host_acc[i]) for i in active
                         ))
-                        spec_draft_total += spec_d * n_active
+                        n["spec_draft_total"] += spec_d * n_active
                         tel.emit("spec_verify", d=spec_d, active=n_active,
                                  accepted=acc_round,
                                  draft=spec_d * n_active,
                                  emitted=emitted_round, slots=occ,
                                  wall_s=round(wall, 6))
+                    # Under the span: where device_get hands back a
+                    # view of the device's buffer (the CPU), this is
+                    # what frees it.
+                    del host_toks, host_oks, host_acc
         finally:
             preempt.__exit__(None, None, None)
             if jr is not None:
@@ -2328,10 +2445,10 @@ class Server:
             "tokens": total_tokens,
             "elapsed_s": elapsed,
             "tokens_per_s": total_tokens / max(elapsed, 1e-9),
-            "decode_supersteps": supersteps,
+            "decode_supersteps": n["supersteps"],
             "decode_steps_per_call": k,
             "decode_s": decode_s,
-            "prefills": prefills,
+            "prefills": n["prefills"],
             "request_latency_ms_p50": round(pct(0.50) * 1e3, 3),
             "request_latency_ms_p95": round(pct(0.95) * 1e3, 3),
             # One host program per decode superstep, by construction
@@ -2346,29 +2463,29 @@ class Server:
             stats["kv_blocks"] = ex.kv_blocks
         if getattr(ex, "prefix_cache", False):
             stats["prefix_cache"] = True
-            stats["prefix_hits"] = prefix_hits
+            stats["prefix_hits"] = n["prefix_hits"]
             stats["prefix_hit_rate"] = round(
-                prefix_hits / max(prefills + full_hits, 1), 4
+                n["prefix_hits"] / max(n["prefills"] + n["full_hits"], 1), 4
             )
-            stats["prefill_tokens_saved"] = prefill_tokens_saved
-            stats["kv_cows"] = kv_cows
-            if prefix_hits:
+            stats["prefill_tokens_saved"] = n["prefill_tokens_saved"]
+            stats["kv_cows"] = n["kv_cows"]
+            if n["prefix_hits"]:
                 # Final-rounded into the run_end summary block;
                 # reconstruct_summary recomputes both from the raw
                 # prefill/prefix_hit events and must match bit-for-bit.
                 tel.note_summary(
                     prefix_hit_rate=stats["prefix_hit_rate"],
-                    prefill_tokens_saved=prefill_tokens_saved,
+                    prefill_tokens_saved=n["prefill_tokens_saved"],
                 )
         if self.speculate:
             stats["speculate"] = self.speculate
             stats["draft_layers"] = ex.draft_layers
-            stats["draft_prefills"] = draft_prefills
+            stats["draft_prefills"] = n["draft_prefills"]
             stats["spec_acceptance_rate"] = round(
-                spec_accept_total / max(spec_draft_total, 1), 4
+                n["spec_accept_total"] / max(n["spec_draft_total"], 1), 4
             )
             stats["spec_tokens_per_dispatch"] = round(
-                decode_tokens / max(supersteps, 1), 3
+                n["decode_tokens"] / max(n["supersteps"], 1), 3
             )
             # Final-rounded into the run_end summary block;
             # reconstruct_summary recomputes both from the raw

@@ -150,16 +150,18 @@ def current():
     return _current if _current is not None else NULL
 
 
-def span(name: str, **kw):
-    """Open a host span named from ``obs/events.py::SPAN_CATALOG``: a
-    ``jax.profiler.TraceAnnotation``, so it lands in the profiler's
-    ``.xplane.pb`` beside the device operations, on their clock, and
-    costs a check of the profiler's flag when no profile is running.
-    ``kw`` (``id``, ``bucket``, ``superstep``, ``active``) go on as the
-    annotation's keywords and join the trace to the JSONL stream.  A
-    module function, not a :class:`Telemetry` method: a span exists in
-    a traced run whether or not a stream is open."""
-    return jax.profiler.TraceAnnotation(name, **kw)
+#: ``span(name, **kw)`` opens a host span named from
+#: ``obs/events.py::SPAN_CATALOG``: a ``jax.profiler.TraceAnnotation``,
+#: so it lands in the profiler's ``.xplane.pb`` beside the device
+#: operations, on their clock, and costs a check of the profiler's flag
+#: when no profile is running.  ``kw`` (``id``, ``bucket``,
+#: ``superstep``, ``active``) go on as the annotation's keywords and
+#: join the trace to the JSONL stream.  The class itself, not a
+#: function around it: the call sits between one span's end and the
+#: next one's start, where a traced run counts it as no span's.  Not a
+#: :class:`Telemetry` method: a span exists in a traced run whether or
+#: not a stream is open.
+span = jax.profiler.TraceAnnotation
 
 
 def process_tag() -> str:

@@ -73,6 +73,7 @@ from flexflow_tpu.runtime.serving import (
     Request,
     RequestResult,
     ServingCrashLoop,
+    ServingEngine,
     ServingEngineFault,
     ServingExecutor,
     ServingFault,
@@ -245,170 +246,37 @@ class SlotShape:
         )
 
 
-class _RealEngine:
-    """Device-backed engine: the ServingExecutor program families,
-    with the legacy loop's telemetry discipline (program_cost at call
-    sites, labeled fences, and ``Server.run``'s dispatch and fence
-    span names at the same calls)."""
-
-    simulated = False
-
-    def __init__(self, ex: ServingExecutor, params, op_state,
-                 sample=None, speculate: int = 0, draft_params=None):
-        self.ex = ex
-        self.params = params
-        self.op_state = op_state
-        self.sample = sample
-        self.speculate = speculate
-        self.caches = ex.init_cache()
-        if speculate:
-            self.draft_params = (draft_params if draft_params is not None
-                                 else params)
-            self.dcaches = ex.init_draft_cache()
-
-    def prefill(self, prompt: np.ndarray, bucket: int, slot_i: int,
-                row: Optional[np.ndarray] = None,
-                plen: Optional[int] = None, rid: int = 0,
-                offset: int = 0, shared_ids=None):
-        """Pad-to-bucket prefill + cache install into ``slot_i``
-        (padded rows, or the ledger-assigned block ``row`` on the
-        paged layout): ``(first_token, finite, wall_s)`` after one
-        fence.  ``prompt`` is the full (prompt ‖ carried) sequence;
-        ``plen``/``rid`` key the sampled variant so a RESUMED
-        position replays the decode head's draw.  ``offset > 0``
-        runs the prefix-sharing offset prefill instead
-        (``build_prefill_from``): the shared span's KV is gathered
-        from the pool blocks ``shared_ids`` and ``row`` is the
-        MASKED table row (shared entries -> scratch block 0) so the
-        donor's blocks are never written."""
-        tel = _telemetry.current()
-        ex = self.ex
-        flen = len(prompt)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :flen] = np.asarray(prompt, np.int32)
-        t0 = time.perf_counter()
-        if offset:
-            pf = ex.build_prefill_from(bucket, offset,
-                                       sample=self.sample)
-            pf_args = (self.params, self.op_state, self.caches,
-                       np.asarray(shared_ids, np.int32), padded,
-                       np.int32(flen))
-        else:
-            pf = ex.build_prefill(bucket, sample=self.sample)
-            pf_args = (self.params, self.op_state, padded,
-                       np.int32(flen))
-        if self.sample is not None:
-            pf_args += (np.int32(flen if plen is None else plen),
-                        np.int32(rid))
-        with _telemetry.span("ff/serve/prefill_dispatch", id=rid,
-                             bucket=bucket):
-            tel.program_cost("prefill", pf, pf_args, bucket=bucket)
-            # (a graph with expert layers appends its routing counters,
-            # which only Server.run's events carry)
-            rows, tok0, okf = pf(*pf_args)[:3]
-        with _telemetry.span("ff/serve/prefill_fence", id=rid):
-            tok0, ok = tel.fence((tok0, okf), "prefill")
-        wall = time.perf_counter() - t0
-        if bool(ok):
-            if row is not None:
-                self.caches = ex.install_paged(self.caches, rows, row)
-            else:
-                self.caches = ex.install(self.caches, rows, slot_i)
-        return int(tok0), bool(ok), wall
-
-    def decode(self, pos_vec: np.ndarray, tok_vec: np.ndarray, k: int,
-               block_table: Optional[np.ndarray] = None,
-               req_ids: Optional[np.ndarray] = None):
-        """One fused k-token superstep over the whole slot batch:
-        ``(tokens (k, B), finite (k, B), wall_s)`` after one fence."""
-        tel = _telemetry.current()
-        fn = self.ex.build_decode_superstep(k, sample=self.sample)
-        args = (self.params, self.op_state, self.caches)
-        if block_table is not None:
-            args += (block_table,)
-        args += (pos_vec, tok_vec)
-        if self.sample is not None:
-            args += (np.asarray(req_ids, np.int32),)
-        t0 = time.perf_counter()
-        with _telemetry.span("ff/serve/decode_dispatch"):
-            tel.program_cost("decode_superstep", fn, args, k=k)
-            self.caches, _pos, _tok, (toks, oks, *_routed) = fn(*args)
-        with _telemetry.span("ff/serve/decode_fence"):
-            host_toks, host_oks = tel.fence((toks, oks),
-                                            "decode_superstep")
-        return host_toks, host_oks, time.perf_counter() - t0
-
-    def draft_prefill(self, prompt: np.ndarray, bucket: int,
-                      slot_i: int):
-        """Populate the draft model's own cache rows for ``slot_i`` —
-        the spec-mode admission's second dispatch.  No fence (nothing
-        to read back; the next spec round synchronizes)."""
-        tel = _telemetry.current()
-        ex = self.ex
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(prompt)] = np.asarray(prompt, np.int32)
-        t0 = time.perf_counter()
-        dpf = ex.build_draft_prefill(bucket)
-        dargs = (self.draft_params, self.op_state, padded)
-        with _telemetry.span("ff/serve/prefill_dispatch", bucket=bucket):
-            tel.program_cost("draft_prefill", dpf, dargs, bucket=bucket)
-            drows = dpf(*dargs)
-        self.dcaches = ex.install(self.dcaches, drows, slot_i)
-        return time.perf_counter() - t0
-
-    def spec(self, pos_vec: np.ndarray, tok_vec: np.ndarray, d: int,
-             block_table: Optional[np.ndarray] = None,
-             req_ids: Optional[np.ndarray] = None):
-        """One fused speculative round (draft d + verify d+1) over the
-        whole slot batch: ``(tokens (d+1, B), finite (d+1, B),
-        accepted (B,), wall_s)`` after one fence."""
-        tel = _telemetry.current()
-        fn = self.ex.build_spec_step(d, sample=self.sample)
-        args = (self.params, self.draft_params, self.op_state,
-                self.caches, self.dcaches)
-        if block_table is not None:
-            args += (block_table,)
-        args += (pos_vec, tok_vec)
-        if self.sample is not None:
-            args += (np.asarray(req_ids, np.int32),)
-        t0 = time.perf_counter()
-        with _telemetry.span("ff/serve/decode_dispatch"):
-            tel.program_cost("spec_verify", fn, args, d=d)
-            self.caches, self.dcaches, _pos, _tok, (toks, oks, acc) = \
-                fn(*args)
-        with _telemetry.span("ff/serve/decode_fence"):
-            host_toks, host_oks, host_acc = tel.fence(
-                (toks, oks, acc), "spec_verify"
-            )
-        return host_toks, host_oks, host_acc, time.perf_counter() - t0
-
-
 class _SimEngine:
-    """Compute-free engine: fabricated (finite) tokens, zero wall.
-    Token values are synthetic; decision-relevant quantities (counts,
-    positions, budgets, KV-block reservations) are exact — see the
-    module docstring."""
+    """Compute-free twin of :class:`ServingEngine`, with its
+    signatures: fabricated (finite) tokens, zero wall, no span, no
+    counters.  Token values are synthetic; decision-relevant
+    quantities (counts, positions, budgets, KV-block reservations) are
+    exact — see the module docstring."""
 
     simulated = True
 
     def __init__(self, shape: SlotShape):
         self.shape = shape
 
-    def prefill(self, prompt, bucket, slot_i, row=None, plen=None,
-                rid=0, offset=0, shared_ids=None):
-        return 1, True, 0.0
+    def prefill(self, prompt, bucket, plen=None, rid=0, offset=0,
+                shared_ids=None):
+        return None, 1, True, {}, 0.0
+
+    def install(self, rows, slot_i, row=None, shared=0, rid=0):
+        pass
 
     def decode(self, pos_vec, tok_vec, k, block_table=None,
-               req_ids=None):
+               req_ids=None, superstep=0):
         B = len(pos_vec)
         toks = np.ones((k, B), np.int32)
         oks = np.ones((k, B), bool)
-        return toks, oks, 0.0
+        return toks, oks, {}, 0.0
 
-    def draft_prefill(self, prompt, bucket, slot_i):
+    def draft_prefill(self, prompt, bucket, slot_i, rid=0):
         return 0.0
 
-    def spec(self, pos_vec, tok_vec, d, block_table=None, req_ids=None):
+    def spec(self, pos_vec, tok_vec, d, block_table=None, req_ids=None,
+             superstep=0):
         # Fabricated FULL acceptance: token values (and hence the
         # accept/reject pattern) are what simulation cannot know, so
         # the exactness contract is stated against a fully-accepting
@@ -561,10 +429,10 @@ class ScheduledServer:
             ex._decode_fns.clear()
         while True:
             try:
-                return _RealEngine(ex, self._params, self._op_state,
-                                   sample=self.sample,
-                                   speculate=self.speculate,
-                                   draft_params=self._draft_params)
+                return ServingEngine(ex, self._params, self._op_state,
+                                     sample=self.sample,
+                                     speculate=self.speculate,
+                                     draft_params=self._draft_params)
             except DeviceMemoryError:
                 if ex.paged:
                     nb = ex.kv_blocks // 2
@@ -975,7 +843,7 @@ class ScheduledServer:
                     vclock += model.draft_prefill_ms(bucket)
                     try:
                         pf_s += self.engine.draft_prefill(
-                            full, bucket, slot_i
+                            full, bucket, slot_i, rid=r.id
                         )
                     except (RuntimeError, OSError) as e:
                         if res is None or isinstance(e, ServingFault):
@@ -988,34 +856,30 @@ class ScheduledServer:
                 )
                 if self.speculate:
                     vclock += model.draft_prefill_ms(bucket)
-                row = masked = None
+                row = None
                 if ledger is not None:
                     row = ledger.alloc(slot_i, ledger.blocks_for(
                         len(r.prompt), r.max_new_tokens),
                         shared=(plan.shared if plan is not None
                                 else ()))
                     block_table[slot_i] = row
-                    # Masked install: shared entries write their
-                    # (all-zero) chunks into scratch block 0 — the
-                    # donor's blocks are never touched; the table row
-                    # keeps the real shared ids for decode.
-                    masked = row
-                    if use:
-                        masked = row.copy()
-                        masked[:use] = 0
                 try:
-                    tok0, ok, pf_s = self.engine.prefill(
-                        full, bucket, slot_i, row=masked,
-                        plen=len(r.prompt), rid=r.id,
+                    # (the routing counters an expert graph's fence
+                    # carries stay out of this loop's events)
+                    rows, tok0, ok, _routed, pf_s = self.engine.prefill(
+                        full, bucket, plen=len(r.prompt), rid=r.id,
                         offset=(plan.offset if use else 0),
                         shared_ids=(plan.shared if use else None),
                     )
-                    if self.speculate and ok:
-                        # The draft cache's own prefill — spec mode's
-                        # second admission dispatch (no fence).
-                        pf_s += self.engine.draft_prefill(
-                            full, bucket, slot_i
-                        )
+                    if ok:
+                        self.engine.install(rows, slot_i, row=row,
+                                            shared=use, rid=r.id)
+                        if self.speculate:
+                            # The draft cache's own prefill — spec
+                            # mode's second admission dispatch.
+                            pf_s += self.engine.draft_prefill(
+                                full, bucket, slot_i, rid=r.id
+                            )
                 except (RuntimeError, OSError) as e:
                     if res is None or isinstance(e, ServingFault):
                         raise
@@ -1309,9 +1173,7 @@ class ScheduledServer:
                         caches = getattr(self.engine, "caches", None)
                         new_caches, sim_nan = \
                             self.injector.before_superstep(
-                                superstep_idx, caches,
-                                block_table if ledger is not None
-                                else None,
+                                superstep_idx, caches, block_table,
                             )
                         if new_caches is not None:
                             self.engine.caches = new_caches
@@ -1369,18 +1231,14 @@ class ScheduledServer:
                     if spec_d:
                         toks, oks, accs, wall = self.engine.spec(
                             pos_vec, tok_vec, spec_d,
-                            block_table=(block_table.copy()
-                                         if ledger is not None
-                                         else None),
-                            req_ids=req_vec,
+                            block_table=block_table, req_ids=req_vec,
+                            superstep=superstep_idx,
                         )
                     else:
-                        toks, oks, wall = self.engine.decode(
+                        toks, oks, _routed, wall = self.engine.decode(
                             pos_vec, tok_vec, k,
-                            block_table=(block_table.copy()
-                                         if ledger is not None
-                                         else None),
-                            req_ids=req_vec,
+                            block_table=block_table, req_ids=req_vec,
+                            superstep=superstep_idx,
                         )
                         accs = None
                 except (RuntimeError, OSError) as e:

@@ -96,6 +96,18 @@ class TestLintRules:
         # Host time OUTSIDE jit is fine (the trainer does it).
         src = "import time\ndef f():\n    return time.time()\n"
         assert "FF003" not in _ids(lint.lint_source(src, "planted.py"))
+        # jax.jit(g) names the def in scope, never a method called g.
+        src = (
+            "import time, jax\n"
+            "class E:\n"
+            "    def g(self):\n"
+            "        return time.time()\n"
+            "def build():\n"
+            "    def g(x):\n"
+            "        return x + 1\n"
+            "    return jax.jit(g)\n"
+        )
+        assert "FF003" not in _ids(lint.lint_source(src, "planted.py"))
 
     def test_ff004_bench_stdout_contract(self):
         bad = 'print("progress: 5/10")\n'

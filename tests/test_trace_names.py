@@ -7,7 +7,8 @@ kernels, scopes"; ``obs/events.py``).
   carries ``ff_loss`` and ``ff_opt`` in its ``op_name`` locations;
 - a tiny ``Server.run`` under ``jax.profiler`` on the CPU leaves an
   ``.xplane.pb`` that holds every ``ff/serve/*`` span, nested as the
-  table says, tiling the loop.
+  table says, tiling the loop; the scheduled loop leaves the engine's
+  five, in the same order.
 """
 
 import glob
@@ -29,6 +30,7 @@ from flexflow_tpu.optim import SGDOptimizer
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import Server, ServingExecutor
 from flexflow_tpu.serving import uniform_workload
+from flexflow_tpu.serving.scheduler import ScheduledServer, SchedulerPolicy
 
 
 # -- kernels ---------------------------------------------------------------
@@ -173,20 +175,29 @@ def _union(spans):
     return total + ((cur[1] - cur[0]) if cur else 0.0)
 
 
+#: The spans the engine opens (``ServingEngine``), on either loop.
+ENGINE_SPANS = {f"ff/serve/{n}" for n in (
+    "prefill_dispatch", "prefill_fence", "install", "decode_dispatch", "decode_fence")}
+
+
 @pytest.fixture(scope="module")
-def tiny_server():
+def tiny_servers():
     lm = build_transformer_lm(batch_size=2, seq_len=16, vocab_size=64, d_model=32, num_heads=2,
                               num_layers=2, config=FFConfig(batch_size=2))
     sex = ServingExecutor(lm, max_batch=2, max_seq=16, buckets=(8, 16), decode_kernel=False)
     params, state = sex.init(seed=0)
-    srv = Server(sex, params, state, decode_steps=4)
+    srvs = {"server": Server(sex, params, state, decode_steps=4),
+            "scheduled": ScheduledServer(sex, params, state, decode_steps=4,
+                                         policy=SchedulerPolicy.fifo())}
     reqs = uniform_workload(40, 64, prompt_len=(3, 6), max_new_tokens=6, seed=5)
-    srv.run(reqs)  # every program built
-    return srv, reqs
+    srvs["server"].run(reqs)  # every program built
+    return srvs, reqs
 
 
-def test_server_run_spans_nest_and_tile_the_loop(tiny_server, tmp_path):
-    srv, reqs = tiny_server
+@pytest.mark.parametrize("loop", ["server", "scheduled"])
+def test_server_run_spans_nest_and_tile_the_loop(tiny_servers, tmp_path, loop):
+    srvs, reqs = tiny_servers
+    srv = srvs[loop]
     best = 0.0
     for attempt in range(3):  # the host is shared: a pre-empted gap is not the loop's
         d = str(tmp_path / f"t{attempt}")
@@ -197,13 +208,34 @@ def test_server_run_spans_nest_and_tile_the_loop(tiny_server, tmp_path):
         finally:
             jax.profiler.stop_trace()
         spans, run = _serve_spans(d)
-        assert {s[0] for s in spans} == SPAN_CATALOG
+        assert {s[0] for s in spans} == (SPAN_CATALOG if loop == "server" else ENGINE_SPANS)
+        assert ENGINE_SPANS <= SPAN_CATALOG
         by = {n: [s for s in spans if s[0] == n] for n in SPAN_CATALOG}
+        # The engine's five, under either loop.  A request: dispatch, fence
+        # and install under its id, each over before the next starts.
+        assert len(reqs) == stats["prefills"]
+        admission = {n: {s[3]["id"]: s for s in by[f"ff/serve/{n}"]}
+                     for n in ("prefill_dispatch", "prefill_fence", "install")}
+        for r in reqs:
+            disp, fence, inst = (admission[n][r.id] for n in admission)
+            assert disp[2] <= fence[1] and fence[2] <= inst[1]
+        assert all(len(by[f"ff/serve/{n}"]) == len(reqs) for n in admission)
+        assert by["ff/serve/prefill_dispatch"][0][3]["bucket"] == 8
+        # A superstep: its dispatch, then its fence, numbered as the loop counts.
+        for n in ("decode_dispatch", "decode_fence"):
+            assert [s[3]["superstep"] for s in by[f"ff/serve/{n}"]] \
+                == list(range(stats["decode_supersteps"]))
+        steps = sorted(by["ff/serve/decode_dispatch"] + by["ff/serve/decode_fence"],
+                       key=lambda s: s[1])
+        assert [s[0] for s in steps] == ["ff/serve/decode_dispatch", "ff/serve/decode_fence"] \
+            * stats["decode_supersteps"]
+        assert all(x[2] <= y[1] for x, y in zip(steps, steps[1:]))
+        assert run[0] <= spans[0][1] and max(s[2] for s in spans) <= run[1]
+        if loop == "scheduled":  # policy only: the loop opens no span of its own
+            return
         # One admit per request, the three inside it; four spans a superstep.
-        assert len(by["ff/serve/admit"]) == len(reqs) == stats["prefills"]
-        for n in ("prefill_dispatch", "prefill_fence", "install"):
-            assert len(by[f"ff/serve/{n}"]) == len(reqs)
-        for n in ("decode_pack", "decode_dispatch", "decode_fence", "bookkeep"):
+        assert len(by["ff/serve/admit"]) == len(reqs)
+        for n in ("decode_pack", "bookkeep"):
             assert len(by[f"ff/serve/{n}"]) == stats["decode_supersteps"]
         admits = by["ff/serve/admit"]
         for n in ("prefill_dispatch", "prefill_fence", "install"):
@@ -216,13 +248,11 @@ def test_server_run_spans_nest_and_tile_the_loop(tiny_server, tmp_path):
         assert all(x[2] <= y[1] for x, y in zip(flat, flat[1:]))
         # The keywords that join the trace to the stream.
         assert sorted(s[3]["id"] for s in admits) == sorted(r.id for r in reqs)
-        assert by["ff/serve/prefill_dispatch"][0][3]["bucket"] == 8
         assert [s[3]["superstep"] for s in by["ff/serve/bookkeep"]] == list(range(stats["decode_supersteps"]))
         assert all(1 <= s[3]["active"] <= 2 for s in by["ff/serve/decode_pack"])
         # All of it inside Server.run, and tiling its loop.
-        assert run[0] <= spans[0][1] and max(s[2] for s in spans) <= run[1]
-        loop = max(s[2] for s in spans) - spans[0][1]
-        best = max(best, _union(spans) / loop)
+        loop_ns = max(s[2] for s in spans) - spans[0][1]
+        best = max(best, _union(spans) / loop_ns)
         if best >= 0.98:
             break
     assert best >= 0.98, best
