@@ -148,6 +148,12 @@ def has_mosaic_call(compiled_text: str) -> bool:
 
 
 _RELAYOUT_OPS = ("copy", "copy-start", "reshape", "transpose", "fusion")
+#: ... and of a KV cache: the step's column written by an XLA scatter
+#: is one more (the compiler lays it out row-major and copies the cache
+#: for it; PERF.md §6 PR 32).  No ``copy-start``: a cache of a few MB
+#: is moved between memory spaces in the same order, which is no
+#: relayout (the sharded smoke's 2 MB shards are).
+CACHE_RELAYOUT_OPS = ("copy", "reshape", "transpose", "fusion", "scatter")
 _HLO_SHAPE = r"\w+\[[\d,]*\](?:\{[^}]*\})?"
 _HLO_INSTRUCTION = re.compile(
     rf"^\s*(?:ROOT\s+)?%\S+ = (\(?{_HLO_SHAPE}(?:, {_HLO_SHAPE})*\)?) ([\w-]+)\(")
@@ -407,19 +413,28 @@ def serve_run(phase: str, argv: Sequence[str]) -> ServeRun:
 
 def check_decode_kernel(phase: str, run: ServeRun) -> None:
     """The decode superstep the run dispatched, as compiled, holds the
-    flash_decode kernel."""
+    flash_decode kernel and moves no cache: the kernel reads and writes
+    the caches in the order the chip stores them, so a copy, transpose,
+    scatter or fusion of a cache's size (on a mesh, of a device's
+    shard) is a relayout that came back (PERF.md §6 PR 32)."""
     sex = run.srv.ex
     params, state = sex.init(sex.config.seed)
     zeros = np.zeros((sex.max_batch,), np.int32)
     # k is what the server dispatched, clamped there already.
     k = int(run.stats["decode_steps_per_call"])
     fn = sex.build_decode_superstep(k)  # fflint: disable=FF006
-    text = fn.lower(
-        params, state, sex.init_cache(), zeros, zeros
-    ).compile().as_text()
-    check(has_mosaic_call(text),
+    caches = sex.init_cache()
+    text = fn.lower(params, state, caches, zeros, zeros).compile().as_text()
+    check(has_mosaic_call(text) and has_kernel(text, "ff_flash_decode"),
           f"{phase}: no flash_decode kernel in the compiled decode "
           f"superstep (einsum oracle or interpret mode)")
+    elements = {math.prod(c.sharding.shard_shape(c.shape))
+                for c in jax.tree.leaves(caches)}
+    moved = [line for n in sorted(elements)
+             for line in table_sized_relayouts(text, n, CACHE_RELAYOUT_OPS)]
+    check(not moved,
+          f"{phase}: the compiled decode superstep moves a whole cache: "
+          f"{moved[:3]}")
 
 
 def next_logits(sex, params, state, prefix: Sequence[int]) -> np.ndarray:

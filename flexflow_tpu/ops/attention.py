@@ -213,6 +213,19 @@ class MultiHeadAttention(Op):
         compiles (the ``serving_program`` event's ``attention``)."""
         return "kv_decode" if decode else "kv_dense"
 
+    def _kernel_block(self, slots: int, max_seq: int, c: int = 1) -> int:
+        """``flash_decode``'s block over a device's cache of ``slots``
+        slots and a ``c``-th of the heads; 0 where its gate refuses."""
+        d, h = self.inputs[0].shape[-1], self.attrs["num_heads"]
+        local, dtype = (slots, max_seq, h // c, d // h), self.outputs[0].dtype
+        if h % c or not pallas_kernels.flash_decode_supported(local, dtype):
+            return 0
+        return pallas_kernels.flash_decode_block(*local[1:], dtype)
+
+    def decode_fetch_block(self, slots, max_seq, kernel, c=1):
+        block = 0 if kernel is False else self._kernel_block(slots, max_seq, c)
+        return block or max_seq
+
     # -- helpers -----------------------------------------------------------
 
     def _project(self, params, x):
@@ -293,10 +306,11 @@ class MultiHeadAttention(Op):
     #   decode overwrites before its causal mask can reach them).
     # - **decode** (t == 1): the token at position ``pos`` writes its
     #   K/V at ``cache[b, pos[b]]`` and attends key positions
-    #   ``<= pos`` via the Pallas ``flash_decode`` kernel (q_len=1
-    #   streaming softmax over cache blocks; shard_map-wrapped when a
-    #   multi-device serving plan is bound) or the pure-jnp
-    #   ``_einsum_decode`` oracle.  When ``state`` additionally
+    #   ``<= pos``: both inside the Pallas ``flash_decode`` kernel
+    #   (q_len=1 streaming softmax over the live blocks of a cache read
+    #   in the order the chip stores it; shard_map-wrapped when a
+    #   multi-device serving plan is bound), or a scatter and the
+    #   pure-jnp ``_einsum_decode`` oracle.  When ``state`` additionally
     #   carries ``block_table``, the caches are PAGED global block
     #   pools and decode scatters/gathers through the table
     #   (runtime/serving.py KVBlockLedger).
@@ -364,11 +378,8 @@ class MultiHeadAttention(Op):
             out = _einsum_decode(qh[:, :, 0], view_k, view_v, pos)
             y = self._merge_heads(out[:, :, None], x.dtype)
         elif t == 1:
-            pos = state["pos"]
-            rows = jnp.arange(b)
-            ck = ck.at[rows, pos].set(kh[:, :, 0].astype(ck.dtype))
-            cv = cv.at[rows, pos].set(vh[:, :, 0].astype(cv.dtype))
-            out = self._decode_attend(qh[:, :, 0], ck, cv, pos)
+            out, ck, cv = self._decode_attend(
+                qh[:, :, 0], kh[:, :, 0], vh[:, :, 0], ck, cv, state["pos"])
             y = self._merge_heads(out[:, :, None], x.dtype)
         elif "chunk" in state:
             # Offset-prefill chunk sub-mode (SERVING.md "Prefix
@@ -425,34 +436,35 @@ class MultiHeadAttention(Op):
         out = jnp.einsum("bhqk,bhkd->bhqd", attn, v)
         return self._merge_heads(out, dtype)
 
-    def _decode_attend(self, q1, ck, cv, pos):
-        """Padded-layout decode attention dispatch: the Pallas
-        ``flash_decode`` kernel — shard_map-wrapped per local shard
-        when a multi-device plan is bound (batch on 'n', heads on 'c',
-        the ``_flash_dense`` discipline: a pallas_call has no GSPMD
-        partitioning rule) — or the pure-jnp ``_einsum_decode``
-        oracle, which under a mesh partitions via plain GSPMD (decode
-        softmax is local per (batch, head): zero collectives either
-        way).  ``q1``: (B, h, hd)."""
+    def _decode_attend(self, q1, k1, v1, ck, cv, pos):
+        """Padded-layout decode step: this token's K/V (``k1``/``v1``,
+        (B, h, hd) like ``q1``) go into the caches at ``pos`` and the
+        query attends positions ``<= pos``.  Returns ``(out, ck, cv)``.
+
+        The Pallas ``flash_decode`` kernel does both, on the caches in
+        the order the chip stores them (an XLA scatter in front of it
+        would be laid out row-major and bring a cache-sized copy a
+        cache into every step: SERVING.md "Cache layout") --
+        shard_map-wrapped per local shard when a multi-device plan is
+        bound (batch on 'n', heads on 'c', the ``_flash_dense``
+        discipline: a pallas_call has no GSPMD partitioning rule).
+        Shapes its gate refuses, and ``decode_kernel=False``, scatter
+        the column and run the pure-jnp ``_einsum_decode`` oracle,
+        which under a mesh partitions via plain GSPMD (decode softmax
+        is local per (batch, head): zero collectives either way)."""
         plan = getattr(self, "_plan", None)
-        if plan is None or plan.num_devices == 1:
-            use = self.decode_kernel
-            if use is None:
-                use = pallas_kernels.flash_decode_supported(
-                    ck.shape, ck.dtype
-                )
-            if use:
-                return pallas_kernels.flash_decode(q1, ck, cv, pos + 1)
-            return _einsum_decode(q1, ck, cv, pos)
-        (n_entry, n_deg), (c_entry, c_deg) = plan.local_degrees(
-            self._pc, "n", "c"
-        )
+        sharded = plan is not None and plan.num_devices > 1
         b, s, h, hd = ck.shape
-        local = (b // max(n_deg, 1), s, h // max(c_deg, 1), hd)
-        supported = (
-            b % max(n_deg, 1) == 0 and h % max(c_deg, 1) == 0
-            and pallas_kernels.flash_decode_supported(local, ck.dtype)
-        )
+        n_entry = c_entry = None
+        n_deg = c_deg = 1
+        if sharded:
+            (n_entry, n_deg), (c_entry, c_deg) = plan.local_degrees(
+                self._pc, "n", "c"
+            )
+            n_deg, c_deg = max(n_deg, 1), max(c_deg, 1)
+        local = (b // n_deg, s, h // c_deg, hd)
+        supported = (b % n_deg == 0
+                     and self._kernel_block(local[0], s, c_deg) > 0)
         use = self.decode_kernel
         if use is None:
             use = supported
@@ -460,25 +472,30 @@ class MultiHeadAttention(Op):
             import logging
 
             logging.getLogger("ff.attention").warning(
-                "%s: sharded flash_decode unsupported for local cache "
-                "shape %s — falling back to the einsum decode oracle "
-                "(single-mesh numerics, GSPMD-partitioned)",
+                "%s: flash_decode unsupported for local cache shape %s "
+                "— falling back to the einsum decode oracle",
                 self.name, local,
             )
             use = False
         if not use:
-            return _einsum_decode(q1, ck, cv, pos)
+            rows = jnp.arange(b)
+            ck = ck.at[rows, pos].set(k1.astype(ck.dtype))
+            cv = cv.at[rows, pos].set(v1.astype(cv.dtype))
+            return _einsum_decode(q1, ck, cv, pos), ck, cv
+        if not sharded:
+            return pallas_kernels.flash_decode(q1, k1, v1, ck, cv, pos + 1)
         q_spec = PartitionSpec(n_entry, c_entry, None)
         kv_spec = PartitionSpec(n_entry, None, c_entry, None)
         return jax.shard_map(
-            lambda ql, kl, vl, pl: pallas_kernels.flash_decode(
-                ql, kl, vl, pl + 1
+            lambda ql, k1l, v1l, kl, vl, pl: pallas_kernels.flash_decode(
+                ql, k1l, v1l, kl, vl, pl + 1
             ),
             mesh=plan.mesh,
-            in_specs=(q_spec, kv_spec, kv_spec, PartitionSpec(n_entry)),
-            out_specs=q_spec,
+            in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec,
+                      PartitionSpec(n_entry)),
+            out_specs=(q_spec, kv_spec, kv_spec),
             check_vma=False,
-        )(q1, ck, cv, pos)
+        )(q1, k1, v1, ck, cv, pos)
 
     def _attend_dense(self, q, k, v, dtype):
         q, k, v = map(self._split_heads, (q, k, v))
@@ -730,6 +747,13 @@ class LatentAttention(Op):
 
     def serving_path(self, decode: bool) -> str:
         return "latent_absorbed" if decode else "latent_expanded"
+
+    def decode_fetch_block(self, slots, max_seq, kernel, c=1):
+        shape = (slots, self.row_width, max_seq)
+        if kernel is not False and pallas_kernels.mla_decode_supported(
+                shape, self.attrs["kv_rank"]):
+            return pallas_kernels.mla_decode_block(max_seq)
+        return max_seq
 
     # -- shared pieces -------------------------------------------------------
 

@@ -126,6 +126,16 @@ class Op:
         by name."""
         return {}
 
+    def decode_fetch_block(self, slots: int, max_seq: int,
+                           kernel: Optional[bool], c: int = 1) -> int:
+        """Positions of a slot's padded cache one decode step fetches
+        at a time on a device holding ``slots`` slots and a ``c``-th of
+        the heads: a kernel's block where the op decodes through one
+        that fetches live blocks only (``kernel`` as ``decode_kernel``:
+        None = where supported), else the whole cache.  What
+        ``decode_superstep.kv_rows_fetched`` rounds lengths up to."""
+        return max_seq
+
     # -- mesh binding -----------------------------------------------------
 
     def bind_mesh(self, plan, pc) -> None:

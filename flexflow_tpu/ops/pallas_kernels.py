@@ -1065,77 +1065,118 @@ def flash_attention_lse_chunked(q, k, v, causal: bool = True,
 # t == t_kv; serving's decode step is a different shape class entirely:
 # ONE query per (batch, head) against a preallocated (B, max_seq, h, hd)
 # cache whose valid prefix length varies PER SLOT (continuous batching).
-# The kernel streams the cache in k-blocks through pipelined BlockSpecs
-# (no resident full cache in VMEM), masks key positions >= the slot's
-# length, and keeps the streaming-softmax state (m, l, acc) in scratch
-# across the sequential k dimension.
 #
-# Blocks span the WHOLE (h, hd) tile of the cache's own layout: Mosaic
-# takes a block whose last two dimensions equal the array's, and
-# refuses a block of 1 on the heads axis (the second-minor, sublane
-# dimension).  With the heads on sublanes and hd on lanes, a q_len=1
-# score is a lane reduction of q*k and the value sum a reduction over
-# the leading key axis — VPU work, no per-head transpose and no M=1
-# matmul; the step is bound by streaming K/V from HBM either way.
+# The kernel works on the cache in the order the chip stores it.  A
+# last dim narrower than a lane tile (hd 64) makes the chip hold
+# ``(B, max_seq, h, hd)`` as ``{1,3,2,0}``: POSITIONS ALONG THE LANES,
+# hd on the sublanes, dense.  ``transpose(cache, (0, 2, 3, 1))`` is a
+# bitcast of that, and it is what the kernel takes: a row-major
+# ``(B, max_seq, h, hd)`` operand made the compiler copy both caches
+# into a lane-padded layout in front of every superstep and back
+# behind it (PERF.md §6 PR 32).  Blocks are ``(1, h, hd, block_k)``
+# with ``block_k`` a few 128-position lane tiles; the lengths are a
+# scalar prefetch and the index map clamps to each slot's last live
+# block, so blocks past a slot's length are neither fetched nor
+# computed.  A head's score over 128 positions is a sum over the hd
+# sublanes of ``q * k`` and the value sum stays a ``(hd, 128)`` partial
+# a lane: VPU work.  The softmax statistics are PER LANE too (each lane
+# its own running max, merged when the slot ends), so a tile costs no
+# cross-lane operation: on the chip those (lane reductions, lane
+# gathers) cost about ten vector operations each (PERF.md §6 PR 32).
+# For the same reason q and the step's K/V enter already broadcast
+# along the lanes (``(B, h, hd, 128)``, an XLA broadcast: 38 MB a call
+# at 48 slots of 16 x 64, a quarter of what the call moves, against
+# 2.5 us a slot of lane gathers, a quarter of what the call then
+# took).  Several heads a loop iteration give the scheduler independent
+# chains to interleave; (m, l, acc) stay f32 in scratch.
+#
+# The step's own K/V column is WRITTEN here too: the lane tile that
+# holds ``pos`` is in VMEM already, the new column enters as an
+# operand, and the tile goes back through an aliased output.  An XLA
+# scatter (or ``dynamic_update_slice`` of one column a slot) in front
+# of the kernel is laid out row-major by the compiler, which brings the
+# cache-sized copies back, inside the decode scan.
 # Inference-only: no VJP (the decode path is reachable only from the
 # ServingExecutor, never from a differentiated train step; the pure-jnp
 # ``_einsum_decode`` in ops/attention.py stays the numerics oracle and
 # the fallback).
 
-#: VMEM the pipelined K and V blocks may hold (two arrays, double
-#: buffered), out of the 16 MB a kernel gets on v5e; the rest is left
-#: to the f32 working tiles.
-_DECODE_KV_VMEM_BYTES = 8 << 20
-#: f32 working tile of one inner-loop chunk, in (8, 128) vregs.
-_DECODE_CHUNK_VREGS = 32
+_LANES = 128
+#: VMEM the kernel plans for and the limit it asks for (v5e has
+#: 128 MiB; a kernel gets 16 MB unless it asks): the pipelined K and V
+#: blocks (two arrays, double buffered) take what the per-slot
+#: operands, the written tiles and the f32 state leave of the plan.
+_DECODE_VMEM_BYTES = 48 << 20
+_DECODE_VMEM_LIMIT = 64 << 20
 
 
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def _padded_row_bytes(h: int, hd: int, dtype) -> int:
-    """VMEM bytes of one cache position's (h, hd) tile: heads pad to
-    the dtype's sublane count, hd to 128 lanes."""
-    itemsize = jnp.dtype(dtype).itemsize
-    sublanes = 8 * max(1, 4 // itemsize)
-    return _round_up(h, sublanes) * _round_up(hd, 128) * itemsize
-
-
-def _decode_block(s: int, h: int, hd: int, dtype) -> int:
-    """K-block edge for the decode kernel: largest divisor of the cache
-    length <= the flash target whose pipelined K/V blocks fit
-    ``_DECODE_KV_VMEM_BYTES``; 0 if none satisfies the block rule."""
-    cap = _DECODE_KV_VMEM_BYTES // (4 * _padded_row_bytes(h, hd, dtype))
-    return _pick_block(s, min(_BLOCK_TARGET, cap - cap % 8))
-
-
-def _decode_chunk(block_k: int, h: int, hd: int) -> int:
-    """Rows of a K/V block one inner-loop iteration works on in f32."""
-    rows = max(1, _DECODE_CHUNK_VREGS * 4096
-               // _padded_row_bytes(h, hd, jnp.float32))
-    while block_k % rows:
-        rows -= 1
-    return rows
+def flash_decode_block(s: int, h: int, hd: int, dtype) -> int:
+    """K-block edge of the decode kernel, in positions: whole 128-lane
+    tiles that divide the cache length, at most 512 and at most a
+    quarter of the cache (what a slot fetches follows its length), and
+    K/V blocks that fit ``_DECODE_VMEM_BYTES`` beside the rest; 0 if
+    there is none."""
+    if s % _LANES:
+        return 0
+    item = jnp.dtype(dtype).itemsize
+    # q, k_new, v_new and the two written tiles, double buffered, and
+    # the f32 accumulator: all (h, hd, 128).
+    room = _DECODE_VMEM_BYTES - h * hd * _LANES * (10 * item + 4)
+    cap = max(room, 0) // (4 * h * hd * item)
+    b = min(512, max(_LANES, s // 4), cap - cap % _LANES)
+    while b >= _LANES and s % b:
+        b -= _LANES
+    return max(b, 0)
 
 
 def flash_decode_supported(cache_shape: Tuple[int, ...],
                            dtype=jnp.float32) -> bool:
     """Whether ``flash_decode`` applies to a (B, max_seq, h, hd) cache
-    of ``dtype``: true only for shapes the TPU compiler accepts
-    (tests/test_chip_compile.py holds the gate to that)."""
+    of ``dtype``: whole lane tiles of positions, hd whole sublane tiles
+    (tests/test_chip_compile.py holds the gate to what the TPU compiler
+    accepts)."""
     if len(cache_shape) != 4:
         return False
     _, s, h, hd = cache_shape
-    if s < 8 or hd < 8:
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    if hd % sublanes or h > _LANES:
         return False
-    return _decode_block(s, h, hd, dtype) >= 8
+    return flash_decode_block(s, h, hd, dtype) >= _LANES
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, block_k, chunk, scale, num_kb):
+def _decode_tile(s, v, m, l, acc, valid=None):
+    """One streaming-softmax step over a lane tile: scores ``s``
+    (1, 128), values ``v`` (hd, 128); ``m``, ``l`` (1, 128) and ``acc``
+    (hd, 128) are partials a lane, merged when the slot ends.  Lanes
+    outside ``valid`` add nothing."""
+    m_new = jnp.maximum(m, s)
+    p = jnp.exp(s - m_new)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
+    corr = jnp.exp(m - m_new)
+    return m_new, l * corr + p, acc * corr + p * v
+
+
+def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+                   o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr,
+                   *, block_k, scale, num_kb, heads):
     b = pl.program_id(0)
     kb = pl.program_id(1)
+    _, h, hd, _ = k_ref.shape
+    tiles = block_k // _LANES
+    pos = len_ref[b] - 1
+    last = pos // _LANES            # the lane tile that holds ``pos``
+    first = kb * tiles              # this block's first lane tile
+
+    def by_heads(body, carry=0):
+        """``body(i, carry)`` over the heads, ``heads`` of them unrolled
+        a loop iteration: independent chains the scheduler interleaves."""
+        def group(g, c):
+            for j in range(heads):
+                c = body(g * heads + j, c)
+            return c
+
+        return lax.fori_loop(0, h // heads, group, carry)
 
     @pl.when(kb == 0)
     def _init():
@@ -1143,92 +1184,159 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[b]
+    def load(ref, i, t):
+        start = pl.multiple_of(t * _LANES, _LANES)
+        return ref[0, i, :, pl.ds(start, _LANES)].astype(jnp.float32)
 
-    # Blocks wholly past the slot's length contribute exact zeros.
-    @pl.when(kb * block_k < length)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale        # (h, hd)
-        h = q.shape[0]
+    def score(i, k):
+        q = q_ref[0, i].astype(jnp.float32)                     # (hd, 128)
+        return jnp.sum(q * k, axis=0, keepdims=True) * scale
 
-        def body(i, carry):
-            m, l, acc = carry
-            start = i * chunk
-            k = k_ref[0, pl.ds(start, chunk)].astype(jnp.float32)
-            v = v_ref[0, pl.ds(start, chunk)].astype(jnp.float32)
-            s = jnp.sum(q[None] * k, axis=-1, keepdims=True)  # (c, h, 1)
-            k_pos = kb * block_k + start + lax.broadcasted_iota(
-                jnp.int32, (chunk, h, 1), 0
-            )
-            s = jnp.where(k_pos < length, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))  # (h, 1)
-            p = jnp.exp(s - m_new[None])
-            corr = jnp.exp(m - m_new)
-            acc = acc * corr + jnp.sum(p * v, axis=0)   # (h, hd)
-            l = l * corr + jnp.sum(p, axis=0)
-            return m_new, l, acc
+    # The block's whole tiles below ``pos``: nothing to mask.  Blocks
+    # past the slot's last live one do no work (and were not fetched).
+    @pl.when(first < last)
+    def _whole_tiles():
+        def group(g, c):
+            ids = [g * heads + j for j in range(heads)]
 
-        m, l, acc = lax.fori_loop(
-            0, block_k // chunk, body,
-            (m_scr[...], l_scr[...], acc_scr[...]),
-        )
-        m_scr[...] = m
-        l_scr[...] = l
-        acc_scr[...] = acc
+            def tile(t, states):
+                return tuple(
+                    _decode_tile(score(i, load(k_ref, i, t)),
+                                 load(v_ref, i, t), *state)
+                    for i, state in zip(ids, states))
+
+            states = lax.fori_loop(
+                0, jnp.minimum(last - first, tiles), tile,
+                tuple((m_scr[i], l_scr[i], acc_scr[i]) for i in ids))
+            for i, state in zip(ids, states):
+                m_scr[i], l_scr[i], acc_scr[i] = state
+            return c
+
+        lax.fori_loop(0, h // heads, group, 0)
+
+    # The tile that holds ``pos``: this step's column goes in, the tile
+    # goes back to the cache, and lanes past ``pos`` are masked.
+    @pl.when((first <= last) & (last < first + tiles))
+    def _last_tile():
+        at = pos - last * _LANES
+        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
+
+        def head(i, c):
+            k = jnp.where(lane == at, kn_ref[0, i].astype(jnp.float32),
+                          load(k_ref, i, last - first))
+            v = jnp.where(lane == at, vn_ref[0, i].astype(jnp.float32),
+                          load(v_ref, i, last - first))
+            ko_ref[0, i] = k.astype(ko_ref.dtype)
+            vo_ref[0, i] = v.astype(vo_ref.dtype)
+            m_scr[i], l_scr[i], acc_scr[i] = _decode_tile(
+                score(i, k), v, m_scr[i], l_scr[i], acc_scr[i],
+                valid=lane[:1] <= at)
+            return c
+
+        by_heads(head)
 
     @pl.when(kb == num_kb - 1)
     def _emit():
-        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
+
+        def head(i, out):
+            m = m_scr[i]
+            w = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))
+            o = (jnp.sum(acc_scr[i] * w, axis=1, keepdims=True)
+                 / jnp.sum(l_scr[i] * w, axis=1, keepdims=True))   # (hd, 1)
+            return jnp.where(lane == i, o, out)
+
+        out = by_heads(head, jnp.zeros((hd, _LANES), jnp.float32))
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def flash_decode(q, cache_k, cache_v, lengths,
+def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
                  interpret: Optional[bool] = None):
-    """Single-token decode attention against a KV cache.
+    """One decode step of attention against a KV cache, the step's own
+    column written on the way.
 
-    ``q``: (B, h, hd) — this step's query (the token at position
-    ``lengths - 1``, whose K/V the caller has already written into the
-    cache).  ``cache_k``/``cache_v``: (B, max_seq, h, hd) preallocated
-    caches.  ``lengths``: (B,) int32 — valid keys per slot (the query
-    attends key positions ``< lengths[b]``, and every slot has at
-    least one).  Returns (B, h, hd) in ``q.dtype``.  Callers gate on
+    ``q``, ``k_new``, ``v_new``: (B, h, hd) -- the query, key and value
+    of the token at position ``lengths - 1``.  ``cache_k``/``cache_v``:
+    (B, max_seq, h, hd) preallocated caches.  ``lengths``: (B,) int32
+    in ``1..max_seq``.  ``k_new``/``v_new`` are stored at
+    ``cache[b, lengths[b] - 1]`` (in the cache's dtype) and the query
+    attends key positions ``< lengths[b]``, its own among them.
+    Returns ``(out (B, h, hd) in q.dtype, cache_k, cache_v)``; donate
+    the caches and the write is in place.  Callers gate on
     :func:`flash_decode_supported`.
     """
     if interpret is None:
         interpret = _interpret_default()
-    b, s, h, hd = cache_k.shape
-    block_k = _decode_block(s, h, hd, cache_k.dtype)
-    if block_k < 8:
+    if not flash_decode_supported(cache_k.shape, cache_k.dtype):
         raise ValueError(
-            f"flash_decode needs a cache length with a block divisor "
-            f"that is a multiple of 8 and fits VMEM; got cache shape "
+            f"flash_decode needs a cache of whole 128-position lane "
+            f"tiles and whole sublane tiles of d_head; got cache shape "
             f"{cache_k.shape} {cache_k.dtype}.  Gate callers on "
             f"flash_decode_supported()."
         )
+    return _decode_call(q, k_new, v_new, cache_k, cache_v, lengths,
+                        interpret=interpret)
+
+
+# A jit of its own: a model's layers call this at one signature, so the
+# kernel is traced once and lowered once a program (as one function the
+# layers call) instead of once a layer -- 24 Mosaic lowerings were 1.1 s
+# of the GPT-2 superstep's 1.8 s of lowering, which is set-up.
+@functools.partial(jax.jit, static_argnames="interpret")
+def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret):
+    b, s, h, hd = cache_k.shape
+    block_k = flash_decode_block(s, h, hd, cache_k.dtype)
     num_kb = s // block_k
     kernel = functools.partial(
-        _decode_kernel, block_k=block_k,
-        chunk=_decode_chunk(block_k, h, hd),
-        scale=1.0 / math.sqrt(hd), num_kb=num_kb,
+        _decode_kernel, block_k=block_k, scale=1.0 / math.sqrt(hd),
+        num_kb=num_kb, heads=next(n for n in (4, 2, 1) if h % n == 0),
     )
-    return pl.pallas_call(
-        kernel,
+
+    def slot(bi, ki, lens):
+        return (bi, 0, 0, 0)
+
+    def block(bi, ki, lens):
+        return (bi, 0, 0, jnp.minimum(ki, lax.div(lens[bi] - 1, block_k)))
+
+    def written(bi, ki, lens):
+        return (bi, 0, 0, lax.div(lens[bi] - 1, _LANES))
+
+    def along_lanes(x, dtype):
+        return jnp.broadcast_to(x.astype(dtype)[..., None],
+                                (b, h, hd, _LANES))
+
+    cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
+    tile = pl.BlockSpec((1, h, hd, _LANES), slot)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, num_kb),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, h, hd), lambda bi, ki: (bi, 0, 0)),
-            pl.BlockSpec((1, block_k, h, hd), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, block_k, h, hd), lambda bi, ki: (bi, ki, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, hd), lambda bi, ki: (bi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        in_specs=[tile] * 3 + [pl.BlockSpec((1, h, hd, block_k), block)] * 2,
+        # The output has the heads along the lanes, a whole tile of them.
+        out_specs=[pl.BlockSpec((1, hd, _LANES), lambda bi, ki, lens:
+                                (bi, 0, 0))]
+        + [pl.BlockSpec((1, h, hd, _LANES), written)] * 2,
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, hd), jnp.float32),
+            pltpu.VMEM((h, 1, _LANES), jnp.float32),
+            pltpu.VMEM((h, 1, _LANES), jnp.float32),
+            pltpu.VMEM((h, hd, _LANES), jnp.float32),
         ],
+    )
+    out, cache_k, cache_v = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, hd, _LANES), q.dtype),
+                   cache, cache],
+        # Operands count the scalar prefetch: 4 and 5 are the caches.
+        input_output_aliases={4: 1, 5: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT),
         name="ff_flash_decode",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, cache_k, cache_v)
+    )(lengths.astype(jnp.int32), along_lanes(q, q.dtype),
+      along_lanes(k_new, cache_k.dtype), along_lanes(v_new, cache_v.dtype),
+      cache_k.transpose(0, 2, 3, 1), cache_v.transpose(0, 2, 3, 1))
+    return (jnp.swapaxes(out[:, :, :h], 1, 2),
+            cache_k.transpose(0, 3, 1, 2), cache_v.transpose(0, 3, 1, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -1412,7 +1520,10 @@ softmax_xent.defvjp(_xent_fwd, _xent_bwd)
 #   the unit is the ``(D, 128)`` block of 128 neighbouring rows.
 # ---------------------------------------------------------------------------
 
-_LANES = 128
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 _ROWS_SMEM_BYTES = 512 * 1024        # scalar-prefetched ids
 _ROWS_VMEM_BYTES = 8 * 1024 * 1024   # the update matrix held in VMEM
 
@@ -2064,7 +2175,7 @@ _MLA_DECODE_BLOCK = 2048
 _MLA_DECODE_CHUNK = 256
 
 
-def _mla_decode_block(s: int) -> int:
+def mla_decode_block(s: int) -> int:
     """Largest block of whole 128-position lane tiles dividing ``s``."""
     b = min(s, _MLA_DECODE_BLOCK)
     b -= b % 128
@@ -2079,7 +2190,7 @@ def mla_decode_supported(cache_shape: Tuple[int, ...], v_width: int) -> bool:
     if len(cache_shape) != 3:
         return False
     _, row, s = cache_shape
-    return (_mla_decode_block(s) >= 128 and 0 < v_width <= row
+    return (mla_decode_block(s) >= 128 and 0 < v_width <= row
             and v_width % 8 == 0 and row % 8 == 0)
 
 
@@ -2150,7 +2261,7 @@ def mla_decode(q, cache, lengths, v_width: int, scale: float,
         interpret = _interpret_default()
     b, row, s = cache.shape
     h = q.shape[1]
-    block_k = _mla_decode_block(s)
+    block_k = mla_decode_block(s)
     chunk = _MLA_DECODE_CHUNK if block_k % _MLA_DECODE_CHUNK == 0 else 128
     num_kb = s // block_k
     kernel = functools.partial(

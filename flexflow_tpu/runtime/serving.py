@@ -1204,6 +1204,25 @@ class ServingExecutor:
             op.serving_path(decode) for op in self.attn_ops
         }))
 
+    def kv_rows(self, pos, k: int) -> Dict[str, int]:
+        """What a decode superstep dispatched at positions ``pos``
+        (every slot's, idle ones too: they run) fetches of the caches,
+        as ``decode_superstep`` carries it: ``kv_rows_fetched`` sums
+        over the slots and the ``k`` steps the live length rounded up
+        to the block the ops' decode step reads in
+        (``Op.decode_fetch_block``; the paged view and the einsum
+        oracle read every row), ``kv_rows_cache`` is slots x max_seq x
+        k.  Host arithmetic, one layer's rows."""
+        S = self.max_seq
+        n, c = self.shard or (1, 1)
+        block = S if self.paged else max(
+            op.decode_fetch_block(self.max_batch // n, S,
+                                  self.decode_kernel, c)
+            for op in self.attn_ops)
+        live = np.minimum(np.asarray(pos)[:, None] + np.arange(k), S - 1) + 1
+        return {"kv_rows_fetched": int((-(-live // block) * block).sum()),
+                "kv_rows_cache": int(live.size * S)}
+
     @staticmethod
     def _mean_stats(stats):
         """The routing counters of one forward, mean over the layers
@@ -2369,7 +2388,8 @@ class Server:
                     if not spec_d:
                         tel.emit("decode_superstep", k=k, active=len(active),
                                  capacity=B, slots=occ,
-                                 wall_s=round(wall, 6), **rounded(routed))
+                                 wall_s=round(wall, 6), **rounded(routed),
+                                 **self.ex.kv_rows(pos_vec, k))
                     for j in range(k_eff):
                         tel.record_step((n["supersteps"] - 1) * k_eff + j,
                                         wall_s=wall / k_eff)

@@ -88,9 +88,9 @@ def _xent(n, v, dtype, grad):
 
 def _decode(b, s, h, hd, dtype):
     fn = functools.partial(pk.flash_decode, interpret=False)
-    cache = _sds((b, s, h, hd), dtype)
+    small, cache = _sds((b, h, hd), dtype), _sds((b, s, h, hd), dtype)
     return (lambda: pk.flash_decode_supported((b, s, h, hd), dtype)), fn, (
-        _sds((b, h, hd), dtype), cache, cache, _sds((b,), jnp.int32))
+        small, small, small, cache, cache, _sds((b,), jnp.int32))
 
 
 def _flash_uneven(t, h, qk, dv):
@@ -140,7 +140,8 @@ def _rows(kind, shape, n_ids, addressing):
 
 #: name -> () -> (gate, fn, abstract args): the kernels of the smoke's
 #: phases at their real shapes (transformer b8 x 8 heads x seq 512 x
-#: hd 64 and its 4096 x 32768 logits; serve's 8 x 512 x 8 x 64 cache;
+#: hd 64 and its 4096 x 32768 logits; serve's 8 x 512 x 8 x 64 cache and
+#: the ``gpt2m.serve.closed48`` cell's 48 x 1024 x 16 x 64;
 #: DLRM's 4 stacked 1M-row d=64 tables, whose last 128-row block is
 #: partial), plus the long-context flash shape, the largest decode
 #: shape ISSUE 21 names, and the row kernels in both addressings: the
@@ -155,6 +156,7 @@ CASES = {
     "xent_grad-4096x32768-bf16": lambda: _xent(4096, 32768, BF16, True),
     "decode-8x512x8x64-f32": lambda: _decode(8, 512, 8, 64, F32),
     "decode-8x512x8x64-bf16": lambda: _decode(8, 512, 8, 64, BF16),
+    "decode-48x1024x16x64-bf16": lambda: _decode(48, 1024, 16, 64, BF16),
     "decode-16x4096x16x128-bf16": lambda: _decode(16, 4096, 16, 128, BF16),
     # The kanana2.serve.closed16.p4k-15k cell's kernels at its widths
     # (32 heads, q.k 192 against v 128, a 576-value column, 128 experts
@@ -211,8 +213,10 @@ CASES = {
 @functools.lru_cache(maxsize=None)
 def _compiled_text(name: str) -> str:
     _gate, fn, args = CASES[name]()
-    # The table donated, as the train step donates its parameters.
-    donate = (0,) if name.startswith("scatter_add_rows") else ()
+    # The table donated, as the train step donates its parameters; the
+    # caches, as the decode superstep donates them.
+    donate = {"scatter_add_rows": (0,), "decode": (3, 4)}.get(
+        name.split("-")[0], ())
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
@@ -326,9 +330,8 @@ def test_supported_gates_match_the_compiler():
     """No ``*_supported`` gate says True for a shape the compiler
     refuses: every case above is admitted by its gate AND compiles,
     and so does every decode shape of the issue's (h, hd, dtype) grid
-    that the gate admits — the hole ``flash_decode_supported`` had
-    (True at every shape, a block of 1 on the heads axis at all of
-    them)."""
+    that the gate admits (``flash_decode_supported`` once said True at
+    every shape and Mosaic refused them all)."""
     for name, case in CASES.items():
         gate, _fn, _args = case()
         assert gate(), f"{name}: gate refuses a shape the smoke runs"
@@ -339,9 +342,10 @@ def test_supported_gates_match_the_compiler():
                 gate, fn, args = _decode(4, 512, h, hd, dtype)
                 assert gate(), (h, hd, dtype)
                 jax.jit(fn).lower(*args).compile()
-    # Past one block, a cache length with no 8-aligned divisor has no
-    # legal k-block.
+    # The decode kernel blocks over whole 128-position lane tiles with
+    # d_head on whole sublane tiles; what it refuses takes the einsum.
     assert not pk.flash_decode_supported((4, 1030, 8, 64), F32)
+    assert not pk.flash_decode_supported((4, 512, 8, 8), BF16)
     # The latent kernels work on whole 128-position lane tiles and
     # whole 128-lane expert widths, and say so.
     assert not pk.mla_decode_supported((4, 160, 200), 128)
@@ -359,6 +363,91 @@ def test_latent_decode_reads_the_cache_where_it_lies():
     assert chip_smoke.table_sized_relayouts(text, 16 * 576 * 16384) == []
 
 
+_CACHE = (48, 1024, 16, 64)
+#: A cache as the chip stores it (positions along the lanes), and as a
+#: row-major Mosaic operand wants it (hd 64 padded to a 128-lane tile).
+_CHIP_ORDER = "bf16[48,1024,16,64]{1,3,2,0:T(8,128)(2,1)}"
+_ROW_MAJOR = "bf16[48,1024,16,64]{3,2,1,0:T(8,128)(2,1)}"
+
+
+def _gpt2_superstep(monkeypatch):
+    """The decode superstep of ``gpt2m.serve.closed48`` at its widths
+    (48 slots x 1024 positions x 16 heads of 64, bf16, K 8) over two
+    blocks, caches donated, compiled for the described v5e."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_transformer_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    cfg = FFConfig(batch_size=48, compute_dtype="bfloat16")
+    lm = build_transformer_lm(
+        batch_size=48, seq_len=1024, vocab_size=1024, d_model=1024,
+        num_heads=16, num_layers=2, config=cfg)
+    sex = ServingExecutor(lm, max_batch=48, max_seq=1024, buckets=(1024,),
+                          device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    caches = sex._cache_tree(
+        sex._cache_specs, lambda ce: _sds((48,) + tuple(ce.shape), ce.dtype))
+    assert {c.shape for c in jax.tree.leaves(caches)} == {_CACHE}
+    vec = _sds((48,), jnp.int32)
+    return sex.build_decode_superstep(8).lower(
+        jax.tree.map(placed, params), jax.tree.map(placed, state), caches,
+        vec, vec).compile()
+
+
+def test_gpt2_decode_superstep_holds_no_cache_sized_relayout(monkeypatch):
+    """The scanned decode step reads and writes the caches where they
+    lie (PERF.md §6, PR 32: the parent's superstep copied every cache
+    to row-major before the scan and back after it, 19.7% of the
+    cell's device time, and held them lane-padded in between): no
+    ``copy``, ``transpose``, ``scatter`` or fusion of a cache's size, no
+    temporary, every cache ``{1,3,2,0}`` from parameter to result, and
+    the kernel's cache operands are bitcasts of the loop's carry."""
+    compiled = _gpt2_superstep(monkeypatch)
+    text = compiled.as_text()
+    assert chip_smoke.has_kernel(text, "ff_flash_decode")
+    assert chip_smoke.table_sized_relayouts(
+        text, math.prod(_CACHE), ops=chip_smoke.CACHE_RELAYOUT_OPS) == []
+    assert _ROW_MAJOR not in text
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
+    params, result = layout.split(")->(")
+    assert params.count(_CHIP_ORDER) == result.count(_CHIP_ORDER) == 4
+    calls = re.findall(r"= \((.*?)\) custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 2  # one a layer, inside the loop's body
+    for outs, operands in calls:
+        assert outs.count("bf16[48,16,64,1024]{3,2,1,0:T(8,128)(2,1)}") == 2
+        views = re.sub(r"/\*.*?\*/", "", operands).split(", ")[-2:]
+        assert all(v.startswith("%bitcast") for v in views), views
+    assert compiled.memory_analysis().temp_size_in_bytes < math.prod(_CACHE)
+
+
+def test_cache_sized_relayouts_names_what_pr32_removed():
+    """The detector on what the parent's superstep held around and
+    inside its scan (PERF.md §6, PR 32: the entry copies to row-major,
+    the scatter that wrote the step's column, the exit copies back) and
+    what must NOT count: the loop's carry, the bitcast views and the
+    aliased kernel call."""
+    text = """
+  %copy.8 = bf16[48,1024,16,64]{3,2,1,0:T(8,128)(2,1)} copy(bf16[48,1024,16,64]{1,3,2,0:T(8,128)(2,1)} %caches__blk0_attn____k__.1)
+  ROOT %scatter.33 = bf16[48,1024,16,64]{3,2,1,0:T(8,128)(2,1)} scatter(%param_0.531, %custom-call.23, %transpose.94), update_window_dims={1,2}
+  %fusion.7 = bf16[48,1024,16,64]{3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.3, %p.2, %p.3), kind=kLoop, calls=%fused_computation.2
+  %copy.14 = bf16[48,1024,16,64]{1,3,2,0:T(8,128)(2,1)} copy(%get-tuple-element.41)
+  %while.2 = (s32[]{:T(128)}, bf16[48,1024,16,64]{1,3,2,0:T(8,128)(2,1)}) while(%tuple.59), condition=%cond, body=%body
+  %bitcast.12 = bf16[48,16,64,1024]{3,2,1,0:T(8,128)(2,1)} bitcast(%get-tuple-element.5)
+  %ff_flash_decode.1 = (bf16[48,64,16]{2,1,0:T(8,128)(2,1)S(1)}, bf16[48,16,64,1024]{3,2,1,0:T(8,128)(2,1)}, bf16[48,16,64,1024]{3,2,1,0:T(8,128)(2,1)}) custom-call(%lengths, %bitcast.12)
+  %bitcast.126 = bf16[48,1024,16,64]{1,3,2,0:T(8,128)(2,1)} bitcast(%pallas_call.34)
+"""
+    found = chip_smoke.table_sized_relayouts(
+        text, math.prod(_CACHE), ops=chip_smoke.CACHE_RELAYOUT_OPS)
+    assert [re.search(r"%(\S+) =", line).group(1) for line in found] == [
+        "copy.8", "scatter.33", "fusion.7", "copy.14"]
+
+
 # -- chip_smoke.py, rehearsed on the CPU --------------------------------------
 
 _TINY_LM = ("--vocab", "256", "--d-model", "32", "--heads", "2",
@@ -373,7 +462,8 @@ _TINY = chip_smoke.Sizes(
           "--arch-sparse-feature-size", "8",
           "--arch-embedding-size", "100-100-100-100",
           "--arch-mlp-bot", "8-16-8", "--arch-mlp-top", "40-16-1"),
-    serve=("--max-seq", "32", "--max-batch", "2", "--requests", "3",
+    # 128 positions: the smallest the decode kernel's gate takes.
+    serve=("--max-seq", "128", "--max-batch", "2", "--requests", "3",
            "--max-new", "6", *_TINY_LM),
     # 128 positions: the smallest the latent kernels' gates take.
     serve_latent=("--model-config", "deepseek-v3-tiny", "--max-seq", "128",
@@ -400,7 +490,7 @@ def on_a_pretend_chip(monkeypatch):
     monkeypatch.setattr(chip_smoke, "has_kernel", lambda text, name: True)
     # ... and the CPU's XLA scatter is a fusion of the table's size.
     monkeypatch.setattr(chip_smoke, "table_sized_relayouts",
-                        lambda text, elements: [])
+                        lambda text, elements, ops=(): [])
 
 
 def _phases(which):

@@ -132,30 +132,6 @@ def test_decode_cache_matches_full_forward(sex, weights, full_forward):
     assert err <= DECODE_TOL, f"decode/full-forward drift {err}"
 
 
-def test_decode_kernel_matches_oracle_direct():
-    """flash_decode (interpret mode = the chip's code path) pinned
-    against the jnp oracle across per-slot lengths incl. boundaries."""
-    r = np.random.default_rng(1)
-    B, SS, h, hd = 4, 32, 2, 16
-    q = jnp.asarray(r.standard_normal((B, h, hd)), jnp.float32)
-    ck = jnp.asarray(r.standard_normal((B, SS, h, hd)), jnp.float32)
-    cv = jnp.asarray(r.standard_normal((B, SS, h, hd)), jnp.float32)
-    lens = jnp.array([1, 7, 32, 17], jnp.int32)
-    assert pallas_kernels.flash_decode_supported(ck.shape, q.dtype)
-    out_k = pallas_kernels.flash_decode(q, ck, cv, lens)
-    out_o = _einsum_decode(q, ck, cv, lens - 1)
-    assert float(jnp.max(jnp.abs(out_k - out_o))) < 1e-5
-
-
-def test_decode_kernel_end_to_end(lm, sex, weights, full_forward):
-    """The kernel-decode executor reproduces the oracle executor's
-    greedy decode AND stays within the full-forward tolerance."""
-    kex = ServingExecutor(lm, max_batch=2, max_seq=S, buckets=(8, S),
-                          decode_kernel=True)
-    err = _decode_logits_vs_full(kex, weights, full_forward, prefix=6)
-    assert err <= DECODE_TOL, f"kernel decode/full-forward drift {err}"
-
-
 def _serve(executor, weights, requests, **kw):
     params, state = weights
     srv = Server(executor, params, state, **kw)
@@ -166,6 +142,197 @@ def _serve(executor, weights, requests, **kw):
 def _req(rid, prompt, max_new=5):
     return Request(id=rid, prompt=np.asarray(prompt, np.int32),
                    max_new_tokens=max_new)
+
+
+# The decode kernel reads the cache positions-along-lanes in blocks of
+# whole 128-position lane tiles and writes the step's own K/V column
+# (ops/pallas_kernels.flash_decode).  Interpret mode = the chip's code
+# path.  One compile a (shape, dtype): lengths are data.
+
+KS, KH, KHD = 1024, 2, 16   # 256-position blocks, four a cache
+
+
+@pytest.fixture(scope="module")
+def decode_case():
+    """``run(lengths, dtype) -> (kernel out/caches, oracle out/caches)``
+    on seeded caches of ``len(lengths)`` slots: the oracle scatters the
+    new column and runs ``_einsum_decode``."""
+    fn = jax.jit(pallas_kernels.flash_decode)
+
+    def run(lengths, dtype=jnp.float32):
+        r = np.random.default_rng(1)
+        B = len(lengths)
+        q, kn, vn = (jnp.asarray(r.standard_normal((B, KH, KHD)), dtype)
+                     for _ in range(3))
+        ck, cv = (jnp.asarray(r.standard_normal((B, KS, KH, KHD)), dtype)
+                  for _ in range(2))
+        lens = jnp.asarray(lengths, jnp.int32)
+        assert pallas_kernels.flash_decode_supported(ck.shape, dtype)
+        rows = jnp.arange(B)
+        ek = ck.at[rows, lens - 1].set(kn)
+        ev = cv.at[rows, lens - 1].set(vn)
+        want = _einsum_decode(q, ek, ev, lens - 1), ek, ev
+        return fn(q, kn, vn, ck, cv, lens), want, (ck, cv, kn, vn)
+
+    return run
+
+
+def test_decode_block_comes_from_the_shape():
+    """Whole lane tiles, 128 to 512, at most a quarter of the cache,
+    inside the VMEM the K/V blocks may hold beside the slot's operands
+    and state; anything else takes the einsum oracle."""
+    blk = pallas_kernels.flash_decode_block
+    assert blk(KS, KH, KHD, jnp.float32) == 256
+    assert blk(1024, 16, 64, jnp.bfloat16) == 256
+    assert blk(128, 2, 16, jnp.float32) == 128
+    assert blk(512, 8, 64, jnp.bfloat16) == 128
+    assert blk(4096, 16, 128, jnp.bfloat16) == 512
+    assert blk(4096, 32, 128, jnp.float32) == 256  # the VMEM plan
+    assert blk(8192, 64, 128, jnp.float32) == 0    # no block fits
+    assert blk(1000, 2, 16, jnp.float32) == 0
+    sup = pallas_kernels.flash_decode_supported
+    assert not sup((4, 32, 2, 16), jnp.float32)      # not whole lane tiles
+    assert not sup((4, 128, 2, 8), jnp.bfloat16)     # hd under a bf16 tile
+    assert sup((4, 128, 2, 8), jnp.float32)
+    assert not sup((4, 128, 2), jnp.float32)
+
+
+# 1; a lane tile's last position, its edge and the first of the next;
+# a block's edge (256) and the first of the next; the last block; the
+# whole cache.
+@pytest.mark.parametrize(
+    "length", [1, 127, 128, 129, 256, 257, 769, 1023, 1024])
+def test_decode_kernel_matches_oracle_at_length(decode_case, length):
+    """Attention over ``length`` positions, the step's own among them,
+    pinned against the jnp oracle -- with an idle slot (length 1) and a
+    full one beside it."""
+    (out, ck, cv), (want, ek, ev), _ = decode_case([length, 1, KS])
+    assert float(jnp.max(jnp.abs(out - want))) < 1e-5
+    assert bool(jnp.array_equal(ck, ek)) and bool(jnp.array_equal(cv, ev))
+
+
+@pytest.mark.parametrize("pos", [0, 127, 128, 255, 256, 1023])
+def test_decode_kernel_writes_the_column_and_nothing_else(decode_case, pos):
+    """The new K/V column reads back at ``pos``; its neighbours, the
+    rest of the slot and the other slots are untouched, bit for bit."""
+    (_, ck, cv), _, (ck0, cv0, kn, vn) = decode_case([pos + 1, 400])
+    for got, before, new in ((ck, ck0, kn), (cv, cv0, vn)):
+        got, before = np.asarray(got), np.array(before)
+        np.testing.assert_array_equal(got[0, pos], np.asarray(new)[0])
+        np.testing.assert_array_equal(got[1, 399], np.asarray(new)[1])
+        before[0, pos], before[1, 399] = got[0, pos], got[1, 399]
+        np.testing.assert_array_equal(got, before)
+
+
+def test_decode_kernel_bf16_cache(decode_case):
+    """bf16 caches (a (16, 128) tile): the column is stored in the
+    cache's dtype and scores stay f32, so the kernel equals the oracle
+    on the rounded cache to bf16's last place."""
+    (out, ck, cv), (want, ek, ev), _ = decode_case(
+        [300, 1, 1024, 513], jnp.bfloat16)
+    err = jnp.max(jnp.abs(out.astype(jnp.float32) - want.astype(jnp.float32)))
+    assert float(err) <= 2 ** -7
+    assert bool(jnp.array_equal(ck, ek)) and bool(jnp.array_equal(cv, ev))
+
+
+KSEQ = 128  # the smallest cache the kernel's gate takes
+
+
+@pytest.fixture(scope="module")
+def lm128():
+    return build_transformer_lm(
+        batch_size=2, seq_len=KSEQ, vocab_size=V, d_model=D, num_heads=H,
+        num_layers=L, config=FFConfig(batch_size=2),
+    )
+
+
+def _kernel_pair(lm128, **kw):
+    """(kernel executor, oracle executor, weights) at ``max_seq`` 128."""
+    kex = ServingExecutor(lm128, max_batch=2, max_seq=KSEQ, buckets=(8,),
+                          decode_kernel=True, **kw)
+    oex = ServingExecutor(lm128, max_batch=2, max_seq=KSEQ, buckets=(8,),
+                          decode_kernel=False, **kw)
+    return kex, oex, oex.init(seed=0)
+
+
+def _kernel_reqs():
+    return [_req(0, [5, 9, 2], max_new=6), _req(1, [3, 1, 4, 1, 5], max_new=5)]
+
+
+def test_decode_kernel_end_to_end(lm128):
+    """The kernel-decode executor's logits equal the oracle
+    executor's through prefill, install and six single decode steps
+    (the kernel writes the cache the next step reads), and its served
+    tokens are the oracle's."""
+    kex, oex, (params, state) = _kernel_pair(lm128)
+    assert kex.kv_rows(np.zeros(2, np.int32), 1)["kv_rows_fetched"] == 256
+    toks = np.random.default_rng(0).integers(0, V, size=(12,)).astype(np.int32)
+    logits = []
+    for ex in (kex, oex):
+        padded = np.zeros((1, 8), np.int32)
+        padded[0, :6] = toks[:6]
+        rows, _t, ok = ex.build_prefill(8)(params, state, padded, np.int32(6))
+        caches = ex.install(ex.init_cache(), rows, 0)
+        dec = ex.build_decode_superstep(1, return_logits=True)
+        pos, got = np.array([6, 0], np.int32), []
+        for t in range(6, 12):
+            caches, pos, _n, (_nxt, _ok, lg) = dec(
+                params, state, caches, pos, np.array([toks[t], 0], np.int32))
+            got.append(np.asarray(lg)[0, 0])
+        logits.append(np.stack(got))
+    assert float(np.max(np.abs(logits[0] - logits[1]))) <= DECODE_TOL
+    base, _ = _serve(oex, (params, state), _kernel_reqs(), decode_steps=4)
+    got, _ = _serve(kex, (params, state), _kernel_reqs(), decode_steps=4)
+    assert [got[i].tokens for i in (0, 1)] == [base[i].tokens for i in (0, 1)]
+
+
+@pytest.mark.parametrize("shard", [(2, 1), (1, 2), (2, 2)])
+def test_decode_kernel_under_shard_map(lm128, shard):
+    """Batch on 'n', heads on 'c': each device's kernel call reads and
+    writes its own shard of the caches, and the served tokens are the
+    single-device oracle's."""
+    kex, _, _ = _kernel_pair(lm128, shard=shard)
+    _, oex, weights = _kernel_pair(lm128)
+    assert kex.shard == shard
+    w2 = (kex._place(weights[0]), kex._place(weights[1]))
+    base, _ = _serve(oex, weights, _kernel_reqs(), decode_steps=4)
+    got, _ = _serve(kex, w2, _kernel_reqs(), decode_steps=4)
+    for rid in (0, 1):
+        assert got[rid].error is None
+        assert got[rid].tokens == base[rid].tokens
+
+
+def test_decode_kernel_in_the_speculative_scan(lm128):
+    """The verify scan drives the same kernel step once a draft
+    position: columns written past the accepted position are never
+    attended, so speculation stays byte-identical to plain decode."""
+    kex, oex, weights = _kernel_pair(lm128, draft_layers=1)
+    base, _ = _serve(oex, weights, _kernel_reqs(), decode_steps=4)
+    sp, stats = _serve(kex, weights, _kernel_reqs(), decode_steps=4,
+                       speculate=3)
+    assert 0.0 <= stats["spec_acceptance_rate"] <= 1.0
+    for rid in (0, 1):
+        assert sp[rid].error is None
+        assert sp[rid].tokens == base[rid].tokens
+
+
+def test_unsupported_cache_falls_back_to_the_oracle(lm, weights, caplog):
+    """A cache that is not whole lane tiles (``max_seq`` 16) takes the
+    einsum oracle even when the kernel is asked for -- loudly, with the
+    oracle's tokens."""
+    import logging
+
+    kex = ServingExecutor(lm, max_batch=2, max_seq=S, buckets=(8, S),
+                          decode_kernel=True)
+    oex = ServingExecutor(lm, max_batch=2, max_seq=S, buckets=(8, S),
+                          decode_kernel=False)
+    with caplog.at_level(logging.WARNING, logger="ff.attention"):
+        got, _ = _serve(kex, weights, _kernel_reqs(), decode_steps=4)
+    assert any("flash_decode unsupported" in r.message for r in caplog.records)
+    base, _ = _serve(oex, weights, _kernel_reqs(), decode_steps=4)
+    assert [got[i].tokens for i in (0, 1)] == [base[i].tokens for i in (0, 1)]
+    assert kex.kv_rows(np.array([3, 0]), 2) == {
+        "kv_rows_fetched": 4 * S, "kv_rows_cache": 4 * S}
 
 
 def test_prefill_bucket_invariance(sex, weights):
@@ -338,6 +505,34 @@ def test_serve_telemetry_stream(lm, weights, tmp_path):
     assert tele["programs_per_step"] == pytest.approx(0.25)
     assert stats["request_latency_ms_p95"] >= stats[
         "request_latency_ms_p50"]
+    # What the superstep fetches of the caches: the einsum oracle
+    # reads every row of both slots in each of the 4 steps.
+    for e in events:
+        if e["ev"] == "decode_superstep":
+            assert e["kv_rows_fetched"] == e["kv_rows_cache"] == 2 * S * 4
+
+
+def test_kv_rows_round_lengths_up_to_the_kernels_block(lm128):
+    """``decode_superstep.kv_rows_fetched``: over every slot and the k
+    steps, the live length rounded up to the block ``flash_decode``
+    fetches in (128 at ``max_seq`` 128; 256 at the benchmark's 1024),
+    positions clamped at the cache's end as the superstep clamps
+    them."""
+    kex, oex, _ = _kernel_pair(lm128)
+    assert kex.kv_rows(np.array([0, 126]), 3) == {
+        "kv_rows_fetched": 6 * 128, "kv_rows_cache": 6 * 128}
+    big = ServingExecutor(
+        build_transformer_lm(batch_size=2, seq_len=1024, vocab_size=V,
+                             d_model=D, num_heads=H, num_layers=1,
+                             config=FFConfig(batch_size=2)),
+        max_batch=2, max_seq=1024, buckets=(8,))
+    # lengths 1, 2 | 255, 256, 257 -> 256, 256 | 256, 256, 512
+    assert big.kv_rows(np.array([0, 254]), 1)["kv_rows_fetched"] == 512
+    assert big.kv_rows(np.array([0, 254]), 3) == {
+        "kv_rows_fetched": 3 * 256 + 256 + 256 + 512,
+        "kv_rows_cache": 6 * 1024}
+    assert big.kv_rows(np.array([1022, 1023]), 2)["kv_rows_fetched"] == 4096
+    assert oex.kv_rows(np.array([0, 5]), 2)["kv_rows_fetched"] == 4 * 128
 
 
 # -- retired closed-loop arrival knob (loud-error contract) --------------

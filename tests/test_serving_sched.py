@@ -955,19 +955,25 @@ def test_sim_matches_real_through_retry_and_restart(sex, weights):
 
 
 @pytest.mark.slow  # >= 6 s in the tier-1 timing run (CHANGES.md PR 21)
-def test_degraded_decode_oracle_rung(lm, weights):
+def test_degraded_decode_oracle_rung():
     """Degraded-mode ladder rung 1: after ``kernel_fault_rung``
     decode-phase engine faults the flash_decode kernel is disabled and
     serving falls back to the ``_einsum_decode`` oracle — loudly,
     recorded in ``degraded_rungs`` — with tokens byte-identical to an
-    unfaulted run (the kernel-vs-oracle numerics pin)."""
-    params, state = weights
+    unfaulted run (the kernel-vs-oracle numerics pin).  128 positions:
+    the smallest cache the kernel's gate takes."""
+    S = 128
+    lm = build_transformer_lm(
+        batch_size=2, seq_len=S, vocab_size=V, d_model=D, num_heads=H,
+        num_layers=L, config=FFConfig(batch_size=2),
+    )
 
     def reqs():
         return [_req(0, 4, 6), _req(1, 5, 6)]
 
     base_ex = ServingExecutor(lm, max_batch=2, max_seq=S,
                               buckets=(8, S), decode_kernel=True)
+    params, state = base_ex.init(seed=0)
     base = ScheduledServer(base_ex, params, state, decode_steps=4,
                            policy=SchedulerPolicy(name="slo"))
     base_res, _ = base.run(reqs())

@@ -63,8 +63,8 @@ _ENTRY_POINTS = {
             lambda x: pk.flash_attention_lse_streamed(x, x, x, True, None, 64, 64)[0].sum())(q),
         _qkv, {"ff_flash_fwd_stream", "ff_flash_dq_stream", "ff_flash_dkv_stream"}),
     "flash_decode": (
-        lambda q: pk.flash_decode(q[:, :, 0], jnp.ones((1, 16, 2, 64)), jnp.ones((1, 16, 2, 64)),
-                                  jnp.array([5], jnp.int32)),
+        lambda q: pk.flash_decode(q[:, :, 0], q[:, :, 1], q[:, :, 2], jnp.ones((1, 128, 2, 64)),
+                                  jnp.ones((1, 128, 2, 64)), jnp.array([5], jnp.int32)),
         _qkv, {"ff_flash_decode"}),
     "softmax_xent": (
         lambda x: jax.grad(lambda l: pk.softmax_xent(l, jnp.zeros((128,), jnp.int32))[0].sum())(x),
