@@ -33,7 +33,9 @@ import re
 import sys
 import time
 import traceback
-from typing import Any, Callable, Dict, List, NamedTuple, Sequence, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import numpy as np
@@ -77,6 +79,7 @@ class Sizes:
     serve_latent: Tuple[str, ...]
     #: --chips 4: the cross-chip argv of each app; the comparison run
     #: is the same argv on one device (strategy / mesh flags dropped).
+    serve_solar: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -107,6 +110,13 @@ FULL = Sizes(
                   "--max-batch", "4", "--requests", "6", "--max-new", "12",
                   "--prompt-len", "100:200", "--buckets", "256",
                   "--dtype", "bfloat16"),
+    # 256 positions: two blocks of the streamed forward under grouped
+    # queries, four chunks of the delta rule's scan (prompts end inside
+    # a chunk), two lane tiles of the KV cache.
+    serve_solar=("--model-config", "solar-open2-smoke", "--max-seq", "256",
+                 "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                 "--prompt-len", "100:200", "--buckets", "256",
+                 "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
     dlrm4=("-b", "1024", "-i", "3", "--momentum", "0", "--wd", "0",
@@ -459,13 +469,15 @@ def next_logits(sex, params, state, prefix: Sequence[int]) -> np.ndarray:
     return np.asarray(fetched[2], np.float32)[0, 0]
 
 
-def compare_tokens(phase: str, got: ServeRun, want: ServeRun) -> None:
+def compare_tokens(phase: str, got: ServeRun, want: ServeRun,
+                   tol: Optional[float] = None) -> None:
     """Generated tokens equal the reference run's on the same seed.
     Where one argmax flips, it must be a near-tie and not an error: at
     the first divergence the two engines' logits, recomputed at
     ``highest`` matmul precision (the chip's default f32 matmul rounds
     operands to bf16, which the CPU tolerance never saw), agree within
-    ``DECODE_TOL``."""
+    ``tol`` (default ``DECODE_TOL``)."""
+    tol = DECODE_TOL if tol is None else tol
     flips = []
     engines = None  # [(executor, params, state)] x2, built at the first flip
     for r in got.requests:
@@ -483,9 +495,9 @@ def compare_tokens(phase: str, got: ServeRun, want: ServeRun) -> None:
         err = float(np.max(np.abs(la - lb)))
         top = np.sort(la)[-2:]
         flips.append((r.id, j, err, float(top[1] - top[0])))
-        check(err <= DECODE_TOL,
+        check(err <= tol,
               f"{phase}: request {r.id} diverges at token {j} and the "
-              f"logits there differ by {err} > {DECODE_TOL}")
+              f"logits there differ by {err} > {tol}")
     info(phase, token_parity="exact" if not flips else
          "near-tie flips (id, at, |dlogits|, top-2 gap): "
          + str([(i, j, f"{e:.2e}", f"{g:.2e}") for i, j, e, g in flips]))
@@ -503,6 +515,30 @@ def serve_phase(argv: Sequence[str]) -> None:
     compare_tokens("serve/plain", plain, oracle)
     compare_tokens("serve/sched", sched, oracle)
     serve_run("serve/paged", [*argv, "--kv-block", "16"])
+
+
+def check_program_kernels(phase: str, run: ServeRun, caches,
+                          decode: Sequence[str],
+                          prefill: Sequence[str]) -> str:
+    """The decode superstep the run dispatched and its largest bucket's
+    prefill, as compiled, hold the kernels named; returns the
+    superstep's text."""
+    sex = run.srv.ex
+    params, state = sex.init(sex.config.seed)
+    zeros = np.zeros((sex.max_batch,), np.int32)
+    k = int(run.stats["decode_steps_per_call"])
+    step = sex.build_decode_superstep(k).lower(  # fflint: disable=FF006
+        params, state, caches, zeros, zeros).compile().as_text()
+    bucket = sex.buckets[-1]
+    first = sex.build_prefill(bucket).lower(  # fflint: disable=FF006
+        params, state, np.zeros((1, bucket), np.int32), np.int32(bucket)
+    ).compile().as_text()
+    for text, kernels, what in ((step, decode, "decode superstep"),
+                                (first, prefill, "prefill")):
+        check(has_mosaic_call(text) and all(
+            has_kernel(text, name) for name in kernels),
+            f"{phase}: the compiled {what} lacks one of {kernels}")
+    return step
 
 
 def latent_phase(argv: Sequence[str]) -> None:
@@ -527,23 +563,74 @@ def latent_phase(argv: Sequence[str]) -> None:
     check(sex._attention_paths(False) == "latent_expanded"
           and sex._attention_paths(True) == "latent_absorbed",
           "serve/latent: prefill is not expanded or decode not absorbed")
-    params, state = sex.init(sex.config.seed)
-    zeros = np.zeros((sex.max_batch,), np.int32)
-    k = int(run.stats["decode_steps_per_call"])
-    decode = sex.build_decode_superstep(k).lower(  # fflint: disable=FF006
-        params, state, caches, zeros, zeros).compile().as_text()
-    bucket = sex.buckets[-1]
-    prefill = sex.build_prefill(bucket).lower(  # fflint: disable=FF006
-        params, state, np.zeros((1, bucket), np.int32), np.int32(bucket)
-    ).compile().as_text()
-    for text, kernels, what in (
-            (decode, ("ff_mla_decode", "ff_grouped_matmul"), "decode superstep"),
-            (prefill, ("ff_flash_fwd_uneven", "ff_grouped_matmul"), "prefill")):
-        check(has_mosaic_call(text) and all(
-            has_kernel(text, name) for name in kernels),
-            f"serve/latent: the compiled {what} lacks one of {kernels}")
+    check_program_kernels(
+        "serve/latent", run, caches,
+        decode=("ff_mla_decode", "ff_grouped_matmul"),
+        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
     oracle = serve_run("serve/latent-oracle", [*argv, "--no-decode-kernel"])
     compare_tokens("serve/latent", run, oracle)
+
+
+#: How far a bfloat16 kernel path's logits may lie from the ``jnp``
+#: path's at a flipped token: the attention kernels hand the value
+#: product their probabilities rounded to bfloat16 (2^-9 of each), which
+#: no matmul precision undoes; read on the chip at the smoke preset's
+#: logits of magnitude ~2: 0.0195 (PR 33).  A wrong kernel is off by 1.
+BF16_KERNEL_TOL = 0.06
+
+
+def cache_or_state_relayouts(compiled_text: str, caches) -> List[str]:
+    """``table_sized_relayouts`` over every KV cache and recurrent state
+    of ``caches`` (the 4-d entries), by element count AND dtype: a
+    float32 state of (slots, heads, 128, 128) has as many elements as
+    some bf16 weight of the same model."""
+    tag = {"float32": "f32", "bfloat16": "bf16"}
+    return [line for c in jax.tree.leaves(caches) if c.ndim == 4
+            for line in table_sized_relayouts(
+                compiled_text, math.prod(c.shape), CACHE_RELAYOUT_OPS)
+            if re.search(rf" = \(?{tag[c.dtype.name]}\[", line)]
+
+
+def solar_phase(argv: Sequence[str]) -> None:
+    """The Solar-Open2 family's preset through ``apps.serve``: two kinds
+    of slot state in one executor (a grouped-query KV cache held
+    positions-last, and a recurrent state with its convolution window),
+    the expert op in the served graph, every layer kind through its
+    kernels, no cache- or state-sized relayout in the superstep, and the
+    tokens of the plain ``jnp`` paths."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+    from flexflow_tpu.ops.delta_attention import KimiDeltaAttention
+
+    run = serve_run("serve/solar", argv)
+    sex = run.srv.ex
+    kinds = {type(op) for op in sex.attn_ops}
+    check(kinds == {MultiHeadAttention, KimiDeltaAttention}
+          and any(op.name.endswith("_moe") for op in sex._layers),
+          f"serve/solar: the served graph holds {sorted(map(str, kinds))}")
+    caches = sex.init_cache()
+    gqa = next(op for op in sex.attn_ops if isinstance(op, MultiHeadAttention))
+    a = gqa.attrs
+    # A head of whole lane tiles (the smoke preset's, the model's) is
+    # cached positions-last; the unit-test preset's narrow head is not.
+    want = (sex.max_batch, a["num_kv_heads"], a["head_dim"], sex.max_seq) \
+        if a["head_dim"] % 128 == 0 else \
+        (sex.max_batch, sex.max_seq, a["num_kv_heads"], a["head_dim"])
+    check(caches[gqa.name]["k"].shape == want,
+          f"serve/solar: KV cache {caches[gqa.name]['k'].shape}, expected "
+          f"{want} over the key/value heads")
+    check(sex._attention_paths(False) == "delta_chunked+gqa_dense"
+          and sex._attention_paths(True) == "delta_recurrent+gqa_decode",
+          "serve/solar: the programs announce other paths")
+    decode = check_program_kernels(
+        "serve/solar", run, caches,
+        decode=("ff_flash_decode", "ff_kda_decode", "ff_grouped_matmul"),
+        prefill=("ff_flash_fwd_uneven", "ff_kda_intra", "ff_kda_chunk",
+                 "ff_grouped_matmul"))
+    moved = cache_or_state_relayouts(decode, caches)
+    check(not moved, f"serve/solar: the compiled decode superstep moves a "
+                     f"whole cache or state: {moved[:3]}")
+    oracle = serve_run("serve/solar-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/solar", run, oracle, tol=BF16_KERNEL_TOL)
 
 
 # -- four chips ---------------------------------------------------------------
@@ -662,6 +749,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
         ("train/dlrm", lambda: dlrm_phase(sz.dlrm)),
         ("serve", lambda: serve_phase(sz.serve)),
         ("serve/latent", lambda: latent_phase(sz.serve_latent)),
+        ("serve/solar", lambda: solar_phase(sz.serve_solar)),
     ]
 
 

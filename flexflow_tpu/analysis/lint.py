@@ -336,7 +336,9 @@ FF008_KERNEL_NAMES = frozenset({
     "ff_flash_fwd", "ff_flash_fwd_stream", "ff_flash_dq",
     "ff_flash_dq_stream", "ff_flash_dkv", "ff_flash_dkv_stream",
     "ff_flash_decode", "ff_flash_fwd_uneven", "ff_mla_decode",
-    "ff_grouped_matmul", "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
+    "ff_grouped_matmul", "ff_kda_intra", "ff_kda_chunk",
+    "ff_kda_decode",
+    "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
     "ff_gather_rows", "ff_scatter_add_rows",
 })
 FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt"})

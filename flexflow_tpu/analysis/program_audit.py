@@ -305,6 +305,15 @@ def _latent_moe_graph():
                     _tiny_config(batch_size=8))
 
 
+def _delta_graph():
+    """KimiDeltaAttention beside grouped-query MultiHeadAttention (one
+    period's first two layers of the Solar-Open2 family)."""
+    from flexflow_tpu.models.transformer import SOLAR_OPEN2_TINY, build_lm
+
+    return build_lm({**SOLAR_OPEN2_TINY, "num_hidden_layers": 2}, 8, 8,
+                    _tiny_config(batch_size=8))
+
+
 def _serving_graph():
     """The graph ServingExecutor is audited on (no MoE: serving drives
     the plain transformer LM, apps/serve.py)."""
@@ -361,6 +370,7 @@ def catalog_models():
         ("transformer_moe", _transformer_graph()),
         ("nmt", _rnn_graph()),
         ("deepseek_v3", _latent_moe_graph()),
+        ("solar_open2", _delta_graph()),
     ]
 
 

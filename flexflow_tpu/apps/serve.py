@@ -29,7 +29,8 @@ Flags beyond the common set:
   --model-config PATH|PRESET   build the graph from a model's own
                                configuration keys instead (a JSON file,
                                or a preset of models/transformer.py:
-                               deepseek-v3-tiny, deepseek-v3-smoke)
+                               deepseek-v3-tiny, deepseek-v3-smoke,
+                               solar-open2-tiny, solar-open2-smoke)
 
 Capacity flags (SERVING.md "Cache layout"):
   --kv-block N       paged KV caches: N-token blocks + per-slot block

@@ -28,6 +28,7 @@ from flexflow_tpu.ops import (
     Embedding,
     Flat,
     HeteroEmbedding,
+    KimiDeltaAttention,
     LatentAttention,
     LayerNorm,
     Linear,
@@ -314,6 +315,16 @@ class FFModel:
         return self._add(
             LatentAttention(self._unique("latent_attention", name), x,
                             num_heads, **kw)
+        )
+
+    def delta_attention(self, x: TensorSpec, num_heads: int, head_dim: int,
+                        name: Optional[str] = None, **kw) -> TensorSpec:
+        """Gated delta-rule linear attention (``ops/delta_attention.py``
+        ``KimiDeltaAttention``: ``conv_size``, ``gate_rank``,
+        ``norm_eps``, ``neg_eigval``)."""
+        return self._add(
+            KimiDeltaAttention(self._unique("delta_attention", name), x,
+                               num_heads, head_dim, **kw)
         )
 
     def rms_norm(self, x: TensorSpec, name: Optional[str] = None, **kw) -> TensorSpec:

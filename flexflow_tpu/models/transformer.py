@@ -145,7 +145,70 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
 
-_BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm}
+def _solar_open2_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """The Solar-Open2 block family: RMSNorm pre-norm blocks whose mixer
+    is grouped-query softmax attention with an elementwise sigmoid output
+    gate on the layers ``gqa_layers`` names and Kimi Delta Attention (the
+    gated delta rule, ``linear_attn_config``) on the others, every layer
+    an expert layer under a sigmoid top-k router with a selection bias
+    and shared experts; no positional signal anywhere (``use_rope``
+    false), no bias, an untied head.  ``held_experts`` (the
+    deployment's) names the routed experts this chip holds; the
+    router's width is then ``published.n_routed_experts`` and
+    ``n_routed_experts`` the number held."""
+    lin = m["linear_attn_config"]
+    for key, got, built in (
+            ("use_rope", m.get("use_rope", False), (False,)),
+            ("first_k_dense_replace", m.get("first_k_dense_replace", 0), (0,)),
+            ("kda_use_full_proj", m.get("kda_use_full_proj", False), (False,)),
+            ("tie_word_embeddings", m.get("tie_word_embeddings", False),
+             (False,)),
+            ("linear_attn_config.num_kv_heads", lin.get("num_kv_heads"),
+             (None, lin["num_heads"]))):
+        if got not in built:
+            raise ValueError(
+                f"solar_open2 builder: {key}={got!r} is not built yet "
+                f"(only {built[0]!r})")
+    d, eps = m["hidden_size"], m["rms_norm_eps"]
+    held = m.get("held_experts")
+    routed = m["n_routed_experts"]
+    if held is not None:
+        routed = m.get("published", {}).get("n_routed_experts", routed)
+        if len(held) != m["n_routed_experts"]:
+            raise ValueError(
+                f"solar_open2 builder: held_experts names {len(held)} experts, "
+                f"n_routed_experts (the number held) is {m['n_routed_experts']}")
+    x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
+                          dtype=jnp.dtype(ff.config.compute_dtype))
+    for i in range(m["num_hidden_layers"]):
+        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
+        if i in m["gqa_layers"]:
+            a = ff.multihead_attention(
+                a, m["num_attention_heads"], causal=True, use_bias=False,
+                num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
+                gate=m["use_gqa_gate"], name=f"blk{i}_attn")
+        else:
+            a = ff.delta_attention(
+                a, lin["num_heads"], lin["head_dim"],
+                conv_size=lin["short_conv_kernel_size"], norm_eps=eps,
+                neg_eigval=m["kda_allow_neg_eigval"], name=f"blk{i}_kda")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
+        h = ff.moe(
+            h, routed, m["moe_intermediate_size"],
+            top_k=m["num_experts_per_tok"], dispatch="sorted",
+            router="sigmoid", gated=True, activation="silu",
+            shared_experts=m["n_shared_experts"], selection_bias=True,
+            norm_topk_prob=m["norm_topk_prob"],
+            routed_scale=m["routed_scaling_factor"],
+            held_experts=held, name=f"blk{i}_moe")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
+
+
+_BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
+           "solar_open2": _solar_open2_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
 #: audit catalog): every mechanism of the block, no published width.
@@ -171,8 +234,36 @@ DEEPSEEK_V3_SMOKE: Dict[str, Any] = {
     "v_head_dim": 64,
 }
 
+#: The Solar-Open2 family at unit-test size: five layers, so that a
+#: whole period (grouped-query, delta, delta, delta) and a second
+#: grouped-query layer occur; widths no kernel takes.
+SOLAR_OPEN2_TINY: Dict[str, Any] = {
+    "model_type": "solar_open2", "vocab_size": 512, "hidden_size": 64,
+    "num_hidden_layers": 5, "gqa_layers": [0, 4], "gqa_interval": 3,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "use_gqa_gate": True, "use_rope": False,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16,
+                           "num_heads": 4, "num_kv_heads": None},
+    "kda_use_full_proj": False, "kda_allow_neg_eigval": True,
+    "first_k_dense_replace": 0, "moe_intermediate_size": 32,
+    "n_routed_experts": 16, "num_experts_per_tok": 2, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip (heads of one whole lane tile): chip_smoke.py.
+SOLAR_OPEN2_SMOKE: Dict[str, Any] = {
+    **SOLAR_OPEN2_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "head_dim": 128, "moe_intermediate_size": 128,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                           "num_heads": 2, "num_kv_heads": None},
+}
+
 PRESETS = {"deepseek-v3-tiny": DEEPSEEK_V3_TINY,
-           "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE}
+           "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE,
+           "solar-open2-tiny": SOLAR_OPEN2_TINY,
+           "solar-open2-smoke": SOLAR_OPEN2_SMOKE}
 
 
 def load_model_config(name_or_path: str) -> Dict[str, Any]:

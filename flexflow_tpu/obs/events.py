@@ -109,6 +109,9 @@ KERNEL_CATALOG = frozenset({
     "ff_flash_fwd_uneven",
     "ff_mla_decode",
     "ff_grouped_matmul",
+    "ff_kda_intra",
+    "ff_kda_chunk",
+    "ff_kda_decode",
     "ff_softmax_xent_fwd",
     "ff_softmax_xent_bwd",
     "ff_gather_rows",

@@ -84,7 +84,10 @@ def box_fingerprint() -> Dict[str, Any]:
         # the backend if nothing has yet — callers (Telemetry, bench)
         # run on a backend they already hold, so this never adds a
         # first touch of the device the run itself would not make.
-        fp["platform"] = jax.default_backend()
+        # The device's own word, not ``jax.default_backend()``: tests
+        # that steer backend-sniffing code replace that function, and
+        # this cache would keep their answer for the process's life.
+        fp["platform"] = jax.devices()[0].platform
         fp["devices"] = jax.device_count()
         # World identity: which process of how many (1/1 single-host).
         # ``obs compare`` surfaces any delta via fingerprint_diff —

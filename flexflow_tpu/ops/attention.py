@@ -45,13 +45,18 @@ def _einsum_decode(q, cache_k, cache_v, pos):
     against a (B, max_seq, h, hd) KV cache, f32 scores, masked to key
     positions ``<= pos`` (the query's own position — its K/V are
     already written into the cache).  ``q``: (B, h, hd); ``pos``: (B,)
-    int32.  The numerics oracle the Pallas ``flash_decode`` kernel is
+    int32.  A cache of fewer heads is read a group of query heads a
+    cached head (grouped-query attention).  The numerics oracle the
+    Pallas ``flash_decode`` kernel is
     pinned against (tests/test_serving.py), and the fallback when the
     kernel does not support the cache shape."""
     dtype = q.dtype
     qf = q.astype(jnp.float32)
     kf = cache_k.astype(jnp.float32)
     vf = cache_v.astype(jnp.float32)
+    group = q.shape[1] // cache_k.shape[2]
+    if group > 1:
+        kf, vf = (jnp.repeat(c, group, axis=2) for c in (kf, vf))
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bhd,bshd->bhs", qf, kf) * scale
     mask = jnp.arange(cache_k.shape[1])[None, :] <= pos[:, None]  # (B, S)
@@ -163,6 +168,14 @@ class MultiHeadAttention(Op):
     ``s``-degree strategies run the ring-attention path; otherwise a
     plain fused attention that GSPMD shards over batch (and heads,
     via the 'c'-tagged projection weights).
+
+    ``num_kv_heads`` (a divisor of ``num_heads``; default all) makes it
+    grouped-query attention: query head j reads key/value head ``j //
+    (num_heads // num_kv_heads)``, and the cache holds the key/value
+    heads alone.  ``head_dim`` (default ``dim // num_heads``) frees the
+    heads' width from the model's.  ``gate`` multiplies the attended
+    values, before the output projection, by ``sigmoid(x W_gate)``
+    elementwise.  No positional signal is this op's business.
     """
 
     def __init__(
@@ -173,52 +186,86 @@ class MultiHeadAttention(Op):
         causal: bool = True,
         use_bias: bool = True,
         kernel_initializer=None,
+        num_kv_heads: Optional[int] = None,
+        head_dim: Optional[int] = None,
+        gate: bool = False,
     ):
         super().__init__(name, [x])
         assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
         d = x.shape[-1]
-        assert d % num_heads == 0, (d, num_heads)
-        self.attrs = dict(num_heads=num_heads, causal=causal, use_bias=use_bias)
+        if head_dim is None:
+            assert d % num_heads == 0, (d, num_heads)
+            head_dim = d // num_heads
+        kv = num_heads if num_kv_heads is None else int(num_kv_heads)
+        assert kv >= 1 and num_heads % kv == 0, (num_heads, num_kv_heads)
+        self.attrs = dict(num_heads=num_heads, causal=causal, use_bias=use_bias,
+                          num_kv_heads=kv, head_dim=int(head_dim),
+                          gate=bool(gate))
+        #: A head that fills whole lane tiles is cached positions-last,
+        #: the order ``flash_decode`` reads: the chip stores ``(max_seq,
+        #: h, hd)`` row-major there, and the decode superstep would pay
+        #: two cache-sized relayouts (SERVING.md "Cache layout").  The
+        #: paged pool, the offset prefill and the sharded specs read the
+        #: other order: an executor in one of those regimes clears this
+        #: (static, bound like ``decode_kernel``).
+        self.positions_last = self.lane_tile_heads
         self.kernel_initializer = kernel_initializer or GlorotUniform()
         self._make_output(x.shape, x.dtype, x.dim_axes)
 
+    cache_paged = True
+
+    @property
+    def group(self) -> int:
+        return self.attrs["num_heads"] // self.attrs["num_kv_heads"]
+
+    @property
+    def lane_tile_heads(self) -> bool:
+        return self.attrs["head_dim"] % 128 == 0
+
     def param_specs(self) -> Dict[str, ParamSpec]:
+        a = self.attrs
         d = self.inputs[0].shape[-1]
+        dq, dkv = a["num_heads"] * a["head_dim"], a["num_kv_heads"] * a["head_dim"]
         dt = self.outputs[0].dtype
         ki = self.kernel_initializer
         specs = {
-            "wq": ParamSpec((d, d), dt, ki, (None, "c")),
-            "wk": ParamSpec((d, d), dt, ki, (None, "c")),
-            "wv": ParamSpec((d, d), dt, ki, (None, "c")),
-            "wo": ParamSpec((d, d), dt, ki, ("c", None)),
+            "wq": ParamSpec((d, dq), dt, ki, (None, "c")),
+            "wk": ParamSpec((d, dkv), dt, ki, (None, "c")),
+            "wv": ParamSpec((d, dkv), dt, ki, (None, "c")),
+            "wo": ParamSpec((dq, d), dt, ki, ("c", None)),
         }
-        if self.attrs["use_bias"]:
-            specs["bq"] = ParamSpec((d,), dt, ZeroInitializer(), ("c",))
-            specs["bk"] = ParamSpec((d,), dt, ZeroInitializer(), ("c",))
-            specs["bv"] = ParamSpec((d,), dt, ZeroInitializer(), ("c",))
+        if a["gate"]:
+            specs["wg"] = ParamSpec((d, dq), dt, ki, (None, "c"))
+        if a["use_bias"]:
+            specs["bq"] = ParamSpec((dq,), dt, ZeroInitializer(), ("c",))
+            specs["bk"] = ParamSpec((dkv,), dt, ZeroInitializer(), ("c",))
+            specs["bv"] = ParamSpec((dkv,), dt, ZeroInitializer(), ("c",))
             specs["bo"] = ParamSpec((d,), dt, ZeroInitializer())
         return specs
 
-    cache_paged = True
-
     def cache_entries(self, max_seq: int) -> Dict[str, CacheEntry]:
-        d = self.inputs[0].shape[-1]
-        h = self.attrs["num_heads"]
-        row = CacheEntry((max_seq, h, d // h), self.outputs[0].dtype,
-                         (None, "c", None))
+        h, hd = self.attrs["num_kv_heads"], self.attrs["head_dim"]
+        if self.positions_last:
+            row = CacheEntry((h, hd, max_seq), self.outputs[0].dtype,
+                             ("c", None, None))
+        else:
+            row = CacheEntry((max_seq, h, hd), self.outputs[0].dtype,
+                             (None, "c", None))
         return {"k": row, "v": row}
 
     def serving_path(self, decode: bool) -> str:
         """Which attention formulation a serving program of this op
         compiles (the ``serving_program`` event's ``attention``)."""
-        return "kv_decode" if decode else "kv_dense"
+        kind = "gqa" if self.group > 1 else "kv"
+        return f"{kind}_decode" if decode else f"{kind}_dense"
 
     def _kernel_block(self, slots: int, max_seq: int, c: int = 1) -> int:
         """``flash_decode``'s block over a device's cache of ``slots``
         slots and a ``c``-th of the heads; 0 where its gate refuses."""
-        d, h = self.inputs[0].shape[-1], self.attrs["num_heads"]
-        local, dtype = (slots, max_seq, h // c, d // h), self.outputs[0].dtype
-        if h % c or not pallas_kernels.flash_decode_supported(local, dtype):
+        h, hd = self.attrs["num_kv_heads"], self.attrs["head_dim"]
+        local, dtype = (slots, max_seq, h // c, hd), self.outputs[0].dtype
+        if h % c or not pallas_kernels.flash_decode_supported(
+                local, dtype, self.group):
             return 0
         return pallas_kernels.flash_decode_block(*local[1:], dtype)
 
@@ -230,7 +277,7 @@ class MultiHeadAttention(Op):
 
     def _project(self, params, x):
         pc = getattr(self, "_pc", None)
-        if pc is None or pc.c == 1:
+        if (pc is None or pc.c == 1) and self.group == 1:
             # One fused (d, 3d) QKV matmul: XLA does not merge the
             # three separate gemms itself, and one (tokens, d) x
             # (d, 3d) dot tiles the MXU better than three (tokens, d)
@@ -251,7 +298,9 @@ class MultiHeadAttention(Op):
         # separate: the fused concat's column interleaving does not
         # align with the 'c' shard boundaries, so GSPMD would have to
         # regather the weights every step — exactly the comm the
-        # Megatron-style split exists to avoid.
+        # Megatron-style split exists to avoid.  So do grouped queries:
+        # their wide W_q is the traffic, and a decode step's concat of
+        # it would cost as much again.
         q = x @ params["wq"]
         k = x @ params["wk"]
         v = x @ params["wv"]
@@ -265,8 +314,8 @@ class MultiHeadAttention(Op):
         bf16 rate) with f32 accumulation; the einsum fallbacks cast to
         f32 themselves."""
         b, t, d = x.shape
-        h = self.attrs["num_heads"]
-        return x.reshape(b, t, h, d // h).transpose(0, 2, 1, 3)
+        hd = self.attrs["head_dim"]
+        return x.reshape(b, t, d // hd, hd).transpose(0, 2, 1, 3)
 
     def _merge_heads(self, x, dtype):
         b, h, t, hd = x.shape
@@ -282,11 +331,21 @@ class MultiHeadAttention(Op):
         if S <= 1:
             out = self._attend_dense(q, k, v, x.dtype)
         else:
+            assert self.group == 1, f"{self.name}: no grouped-query ring path"
             out = self._attend_ring(q, k, v, x.dtype)
+        return [self._output(params, x, out)], state
+
+    def _output(self, params, x, out):
+        """The output gate (where the op has one) and projection."""
+        if self.attrs["gate"]:
+            # The sigmoid in f32; the product in the compute dtype (an
+            # f32 copy of a 32k-token prefill's values is 1 GiB).
+            gate = jax.nn.sigmoid((x @ params["wg"]).astype(jnp.float32))
+            out = out * gate.astype(out.dtype)
         y = out @ params["wo"]
         if self.attrs["use_bias"]:
             y = y + params["bo"]
-        return [y], state
+        return y
 
     # -- KV-cache inference protocol (runtime/serving.py) -------------------
     #
@@ -354,6 +413,26 @@ class MultiHeadAttention(Op):
         q, k, v = self._project(params, x)
         qh, kh, vh = map(self._split_heads, (q, k, v))   # (B, h, t, hd)
         b, h, t, hd = qh.shape
+        if self.positions_last:
+            # Caches (B, h_kv, hd, S): the padded layout's prefill and
+            # decode only (a paged or sharded executor binds the other
+            # order).
+            if "block_table" in state or "chunk" in state:
+                raise NotImplementedError(
+                    f"{self.name}: a positions-last cache has no paged "
+                    f"pool or offset prefill yet (ROADMAP Queue B)")
+            if t == 1:
+                out, ck, cv = self._decode_attend(
+                    qh[:, :, 0], kh[:, :, 0], vh[:, :, 0], ck, cv,
+                    state["pos"])
+                y = self._merge_heads(out[:, :, None], x.dtype)
+            else:
+                ck = ck.at[..., :t].set(kh.transpose(0, 1, 3, 2).astype(ck.dtype))
+                cv = cv.at[..., :t].set(vh.transpose(0, 1, 3, 2).astype(cv.dtype))
+                y = self._attend_prefill(qh, kh, vh, x.dtype)
+            new_state = dict(state)
+            new_state["cache_k"], new_state["cache_v"] = ck, cv
+            return [self._output(params, x, y)], new_state
         if t == 1 and "block_table" in state:
             # Paged decode (SERVING.md "Cache layout"): ck/cv are the
             # GLOBAL block pools (kv_blocks, kv_block, h, hd); the
@@ -373,8 +452,8 @@ class MultiHeadAttention(Op):
             dest = bt[rows, pos // bs]
             ck = ck.at[dest, pos % bs].set(kh[:, :, 0].astype(ck.dtype))
             cv = cv.at[dest, pos % bs].set(vh[:, :, 0].astype(cv.dtype))
-            view_k = ck[bt].reshape(b, -1, h, hd)
-            view_v = cv[bt].reshape(b, -1, h, hd)
+            view_k = ck[bt].reshape(b, -1, ck.shape[-2], hd)
+            view_v = cv[bt].reshape(b, -1, cv.shape[-2], hd)
             out = _einsum_decode(qh[:, :, 0], view_k, view_v, pos)
             y = self._merge_heads(out[:, :, None], x.dtype)
         elif t == 1:
@@ -404,14 +483,26 @@ class MultiHeadAttention(Op):
         else:
             ck = ck.at[:, :t].set(kh.transpose(0, 2, 1, 3).astype(ck.dtype))
             cv = cv.at[:, :t].set(vh.transpose(0, 2, 1, 3).astype(cv.dtype))
-            y = self._attend_dense(q, k, v, x.dtype)
-        out_y = y @ params["wo"]
-        if self.attrs["use_bias"]:
-            out_y = out_y + params["bo"]
+            y = self._attend_prefill(qh, kh, vh, x.dtype) \
+                if self.group > 1 else self._attend_dense(q, k, v, x.dtype)
         new_state = dict(state)
         new_state["cache_k"] = ck
         new_state["cache_v"] = cv
-        return [out_y], new_state
+        return [self._output(params, x, y)], new_state
+
+    def _attend_prefill(self, qh, kh, vh, dtype):
+        """A serving prefill's causal attention on heads (B, h, t, hd)
+        against (B, h_kv, t, hd) keys and values: the streamed forward
+        kernel, which reaches a group's K and V through its index map
+        (no repeated copy), where its gate takes the shape; else the
+        dense path over repeated heads."""
+        plan = getattr(self, "_plan", None)
+        if self.attrs["causal"] and (plan is None or plan.num_devices == 1) \
+                and pallas_kernels.flash_uneven_supported(qh.shape, qh.shape[-1]):
+            out = pallas_kernels.flash_fwd_uneven(
+                qh, kh, vh, 1.0 / math.sqrt(qh.shape[-1]))
+            return self._merge_heads(out, dtype)
+        return self._attend_heads(qh, kh, vh, dtype)
 
     def _attend_chunk(self, qh, ck, cv, offset, t, dtype):
         """Offset-prefill attention: ``t`` queries at absolute
@@ -423,6 +514,8 @@ class MultiHeadAttention(Op):
         span = offset + t
         kh = ck[:, :span].transpose(0, 2, 1, 3)      # (B, h, span, hd)
         vh = cv[:, :span].transpose(0, 2, 1, 3)
+        if self.group > 1:
+            kh, vh = (jnp.repeat(c, self.group, axis=1) for c in (kh, vh))
         q, k, v = (x.astype(jnp.float32) for x in (qh, kh, vh))
         scale = 1.0 / math.sqrt(q.shape[-1])
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
@@ -454,7 +547,9 @@ class MultiHeadAttention(Op):
         is local per (batch, head): zero collectives either way)."""
         plan = getattr(self, "_plan", None)
         sharded = plan is not None and plan.num_devices > 1
-        b, s, h, hd = ck.shape
+        last = self.positions_last
+        b, h, hd, s = ck.shape if last else \
+            tuple(ck.shape[i] for i in (0, 2, 3, 1))
         n_entry = c_entry = None
         n_deg = c_deg = 1
         if sharded:
@@ -463,7 +558,8 @@ class MultiHeadAttention(Op):
             )
             n_deg, c_deg = max(n_deg, 1), max(c_deg, 1)
         local = (b // n_deg, s, h // c_deg, hd)
-        supported = (b % n_deg == 0
+        # Under a mesh only the plain layout has a shard_map'd kernel.
+        supported = (b % n_deg == 0 and not (sharded and (last or self.group > 1))
                      and self._kernel_block(local[0], s, c_deg) > 0)
         use = self.decode_kernel
         if use is None:
@@ -477,13 +573,21 @@ class MultiHeadAttention(Op):
                 self.name, local,
             )
             use = False
+        if not use and last:
+            rows = jnp.arange(b)
+            ck = ck.at[rows, :, :, pos].set(k1.astype(ck.dtype))
+            cv = cv.at[rows, :, :, pos].set(v1.astype(cv.dtype))
+            out = _einsum_decode(q1, ck.transpose(0, 3, 1, 2),
+                                 cv.transpose(0, 3, 1, 2), pos)
+            return out, ck, cv
         if not use:
             rows = jnp.arange(b)
             ck = ck.at[rows, pos].set(k1.astype(ck.dtype))
             cv = cv.at[rows, pos].set(v1.astype(cv.dtype))
             return _einsum_decode(q1, ck, cv, pos), ck, cv
         if not sharded:
-            return pallas_kernels.flash_decode(q1, k1, v1, ck, cv, pos + 1)
+            return pallas_kernels.flash_decode(q1, k1, v1, ck, cv, pos + 1,
+                                               positions_last=last)
         q_spec = PartitionSpec(n_entry, c_entry, None)
         kv_spec = PartitionSpec(n_entry, None, c_entry, None)
         return jax.shard_map(
@@ -498,7 +602,13 @@ class MultiHeadAttention(Op):
         )(q1, k1, v1, ck, cv, pos)
 
     def _attend_dense(self, q, k, v, dtype):
-        q, k, v = map(self._split_heads, (q, k, v))
+        return self._attend_heads(*map(self._split_heads, (q, k, v)), dtype)
+
+    def _attend_heads(self, q, k, v, dtype):
+        """Dense attention on split heads; grouped keys and values are
+        repeated a query head (the differentiable path)."""
+        if self.group > 1:
+            k, v = (jnp.repeat(x, self.group, axis=1) for x in (k, v))
         out = self._flash_dense(q, k, v)
         if out is None:
             out = _einsum_attention(q, k, v, self.attrs["causal"])

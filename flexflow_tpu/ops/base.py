@@ -48,11 +48,15 @@ class CacheEntry:
     axis lies in ``shape`` is the op's business (keys and values are
     ``(max_seq, heads, d_head)``; the latent cache puts the sequence
     last, the order the chip stores a 576-wide row in anyway).  ``axes``
-    tags the dims for sharded decode ('c' = heads)."""
+    tags the dims for sharded decode ('c' = heads).  ``sequence`` is
+    false for an entry with no sequence axis at all (a recurrent state,
+    a convolution window): its bytes do not grow with ``max_seq``, and
+    the op that declares it is told a prefill's true ``length``."""
 
     shape: Tuple[int, ...]
     dtype: Any
     axes: Tuple[Optional[str], ...] = ()
+    sequence: bool = True
 
     def __post_init__(self):
         if not self.axes:
@@ -132,7 +136,8 @@ class Op:
         at a time on a device holding ``slots`` slots and a ``c``-th of
         the heads: a kernel's block where the op decodes through one
         that fetches live blocks only (``kernel`` as ``decode_kernel``:
-        None = where supported), else the whole cache.  What
+        None = where supported), else the whole cache; 0 for an op whose
+        entries have no sequence axis.  What
         ``decode_superstep.kv_rows_fetched`` rounds lengths up to."""
         return max_seq
 

@@ -327,12 +327,46 @@ class MixtureOfExperts(Op):
         return product(apply_activation(product(x, w_gate), act)
                        * product(x, w_up), w_down)
 
+    #: Assignments' outputs one pass of the sorted formulation may hold
+    #: in f32 (``tokens x top_k x d``): a longer forward walks its
+    #: tokens in equal segments under this, one after another (experts
+    #: see a token alone, so segments change nothing but the order).
+    SEGMENT_BYTES = 1 << 29
+
     def _forward_sorted(self, params, x, state):
-        a = self.attrs
         b, t, d = x.shape
-        T, k, e, eh = b * t, a["top_k"], a["num_experts"], len(self.held)
+        T = b * t
+        serving = bool(state.get("serving"))
+        per_token = self.attrs["top_k"] * d * 4
+        n = -(-T * per_token // self.SEGMENT_BYTES)
+        while T % n:
+            n += 1
+        if n == 1:
+            y, counts = self._sorted_tokens(params, x.reshape(T, d), serving)
+        else:
+            y, counts = jax.lax.map(
+                lambda xs: self._sorted_tokens(params, xs, serving),
+                x.reshape(n, T // n, d))
+            counts = jnp.sum(counts, axis=0)
+        out = [y.reshape(b, t, d)]
+        if not serving:
+            return out, state
+        # Routing counters, fetched at the fence the step already has.
+        eh = len(self.held)
+        new_state = dict(state)
+        new_state["stats"] = {
+            "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
+            "expert_load_max": jnp.max(counts).astype(jnp.float32)
+            * eh / jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32),
+        }
+        return out, new_state
+
+    def _sorted_tokens(self, params, xf, serving: bool):
+        """``xf`` (T, d) -> ``(y (T, d), assignments a held expert)``."""
+        a = self.attrs
+        T, d = xf.shape
+        k, e, eh = a["top_k"], a["num_experts"], len(self.held)
         A = T * k
-        xf = x.reshape(T, d)
         idx, w = self.route(params, xf)
         # Global expert id -> row of this chip's expert arrays, or eh
         # for an expert held elsewhere (its assignments sort last and
@@ -362,10 +396,9 @@ class MixtureOfExperts(Op):
             jnp.minimum(dest, rows - 1))
         xs = xf[src_tok]                                         # (rows, d)
 
-        serving = bool(state.get("serving"))
         f = a["ffn_dim"]
-        if serving and pallas_kernels.grouped_matmul_supported(d, f, x.dtype) \
-                and pallas_kernels.grouped_matmul_supported(f, d, x.dtype):
+        if serving and pallas_kernels.grouped_matmul_supported(d, f, xf.dtype) \
+                and pallas_kernels.grouped_matmul_supported(f, d, xf.dtype):
             n_tiles = rows // tm
             used = p_end[-1] // tm
             tile_e = jnp.sum(
@@ -397,14 +430,4 @@ class MixtureOfExperts(Op):
                 ("s_up", "s_down")
             y = y + self._mlp(xf, params, shared,
                               lambda x, w: x @ w).astype(jnp.float32)
-        out = [y.astype(x.dtype).reshape(b, t, d)]
-        if not serving:
-            return out, state
-        # Routing counters, fetched at the fence the step already has.
-        new_state = dict(state)
-        new_state["stats"] = {
-            "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
-            "expert_load_max": jnp.max(counts).astype(jnp.float32)
-            * eh / jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32),
-        }
-        return out, new_state
+        return y.astype(xf.dtype), counts

@@ -43,6 +43,16 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _mxu_precision(dtype):
+    """The ``precision`` of a kernel's matrix product on operands of
+    ``dtype``: below float32 the unit's own (named, so that an ambient
+    ``jax.default_matmul_precision("highest")`` -- chip_smoke's recount
+    of a flipped token -- does not ask Mosaic for a float32 contraction
+    of bfloat16 operands, which it refuses); float32 operands take what
+    the caller's context asks."""
+    return None if jnp.dtype(dtype).itemsize >= 4 else lax.Precision.DEFAULT
+
+
 def _block_target_from_env() -> int:
     """FF_FLASH_BLOCK tuning knob, sanitized: non-numeric falls back to
     512, anything else clamps to a multiple of 8 >= 8 (the block rule
@@ -1130,16 +1140,20 @@ def flash_decode_block(s: int, h: int, hd: int, dtype) -> int:
 
 
 def flash_decode_supported(cache_shape: Tuple[int, ...],
-                           dtype=jnp.float32) -> bool:
+                           dtype=jnp.float32, group: int = 1) -> bool:
     """Whether ``flash_decode`` applies to a (B, max_seq, h, hd) cache
     of ``dtype``: whole lane tiles of positions, hd whole sublane tiles
     (tests/test_chip_compile.py holds the gate to what the TPU compiler
-    accepts)."""
+    accepts).  ``group`` query heads a cached head (grouped-query
+    attention) take the matrix-unit body, which wants ``hd`` in whole
+    lane tiles."""
     if len(cache_shape) != 4:
         return False
     _, s, h, hd = cache_shape
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
     if hd % sublanes or h > _LANES:
+        return False
+    if group > 1 and hd % _LANES:
         return False
     return flash_decode_block(s, h, hd, dtype) >= _LANES
 
@@ -1250,47 +1264,160 @@ def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
+def _decode_grouped_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+                           o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr,
+                           *, block_k, scale, num_kb):
+    """The grouped-query body: ``q_ref`` (1, h_kv, g, hd) holds the g
+    query heads of every cached head as rows, so a live K tile (hd, 128)
+    is fetched once for its group and scores and values are two small
+    matrix products a tile; softmax statistics a row.  Blocks, index
+    maps, the written tile and the aliasing are ``_decode_kernel``'s
+    (one launch, ``_decode_call``)."""
+    b = pl.program_id(0)
+    kb = pl.program_id(1)
+    _, h, hd, _ = k_ref.shape
+    tiles = block_k // _LANES
+    pos = len_ref[b] - 1
+    last = pos // _LANES
+    first = kb * tiles
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(ref, i, t):
+        start = pl.multiple_of(t * _LANES, _LANES)
+        return ref[0, i, :, pl.ds(start, _LANES)]                 # (hd, 128)
+
+    def step(i, k, v, state, valid=None):
+        """``k``, ``v`` (hd, w): one lane tile, or the whole block."""
+        m, l, acc = state                       # (g, 128) x2, (g, hd)
+        s = jnp.dot(q_ref[0, i], k, precision=_mxu_precision(k.dtype),
+                    preferred_element_type=jnp.float32) * scale    # (g, w)
+        if valid is not None:
+            s = jnp.where(valid, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)
+        corr = jnp.exp(m - m_new)
+        pv = lax.dot_general(p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                             precision=_mxu_precision(v.dtype),
+                             preferred_element_type=jnp.float32)   # (g, hd)
+        return (m_new, l * corr + jnp.sum(p, axis=1, keepdims=True),
+                acc * corr[:, :1] + pv)
+
+    # A block wholly below ``pos`` (all but a slot's last live one) goes
+    # through in one step: the statistics' lane reductions and the loop's
+    # turns, not the products, are what a tile costs (5.6 ms a call at 32
+    # slots of 8192 positions a tile at a time, PERF.md §6 PR 33).
+    @pl.when(last - first >= tiles)
+    def _whole_block():
+        def head(i, c):
+            m_scr[i], l_scr[i], acc_scr[i] = step(
+                i, k_ref[0, i], v_ref[0, i],
+                (m_scr[i], l_scr[i], acc_scr[i]))
+            return c
+
+        lax.fori_loop(0, h, head, 0)
+
+    @pl.when((first < last) & (last - first < tiles))
+    def _whole_tiles():
+        def head(i, c):
+            state = lax.fori_loop(
+                0, last - first,
+                lambda t, st: step(i, tile(k_ref, i, t), tile(v_ref, i, t), st),
+                (m_scr[i], l_scr[i], acc_scr[i]))
+            m_scr[i], l_scr[i], acc_scr[i] = state
+            return c
+
+        lax.fori_loop(0, h, head, 0)
+
+    @pl.when((first <= last) & (last < first + tiles))
+    def _last_tile():
+        at = pos - last * _LANES
+        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
+
+        def head(i, c):
+            k = jnp.where(lane == at, kn_ref[0, i].astype(jnp.float32),
+                          tile(k_ref, i, last - first).astype(jnp.float32))
+            v = jnp.where(lane == at, vn_ref[0, i].astype(jnp.float32),
+                          tile(v_ref, i, last - first).astype(jnp.float32))
+            k, v = k.astype(ko_ref.dtype), v.astype(vo_ref.dtype)
+            ko_ref[0, i] = k
+            vo_ref[0, i] = v
+            m_scr[i], l_scr[i], acc_scr[i] = step(
+                i, k, v, (m_scr[i], l_scr[i], acc_scr[i]),
+                valid=lane[:1] <= at)
+            return c
+
+        lax.fori_loop(0, h, head, 0)
+
+    @pl.when(kb == num_kb - 1)
+    def _emit():
+        def head(i, c):
+            o_ref[0, i] = (acc_scr[i] / l_scr[i][:, :1]).astype(o_ref.dtype)
+            return c
+
+        lax.fori_loop(0, h, head, 0)
+
+
 def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 positions_last: bool = False):
     """One decode step of attention against a KV cache, the step's own
     column written on the way.
 
-    ``q``, ``k_new``, ``v_new``: (B, h, hd) -- the query, key and value
-    of the token at position ``lengths - 1``.  ``cache_k``/``cache_v``:
-    (B, max_seq, h, hd) preallocated caches.  ``lengths``: (B,) int32
-    in ``1..max_seq``.  ``k_new``/``v_new`` are stored at
-    ``cache[b, lengths[b] - 1]`` (in the cache's dtype) and the query
+    ``q``: (B, h_q, hd); ``k_new``, ``v_new``: (B, h, hd) -- the query,
+    key and value of the token at position ``lengths - 1``, ``h_q`` a
+    multiple of ``h`` (query head j reads cached head ``j // (h_q //
+    h)``: grouped-query attention).  ``cache_k``/``cache_v``:
+    (B, max_seq, h, hd) preallocated caches, or with ``positions_last``
+    (B, h, hd, max_seq): the order the kernel reads, which an op whose
+    ``hd`` fills whole lane tiles declares itself, since the chip would
+    store the first form row-major there.  ``lengths``: (B,) int32
+    in ``1..max_seq``.  ``k_new``/``v_new`` are stored at position
+    ``lengths[b] - 1`` (in the cache's dtype) and the query
     attends key positions ``< lengths[b]``, its own among them.
-    Returns ``(out (B, h, hd) in q.dtype, cache_k, cache_v)``; donate
+    Returns ``(out (B, h_q, hd) in q.dtype, cache_k, cache_v)``; donate
     the caches and the write is in place.  Callers gate on
     :func:`flash_decode_supported`.
     """
     if interpret is None:
         interpret = _interpret_default()
-    if not flash_decode_supported(cache_k.shape, cache_k.dtype):
+    b, h, hd, s = cache_k.shape if positions_last else \
+        tuple(cache_k.shape[i] for i in (0, 2, 3, 1))
+    group = q.shape[1] // h
+    if q.shape[1] != group * h or not flash_decode_supported(
+            (b, s, h, hd), cache_k.dtype, group):
         raise ValueError(
             f"flash_decode needs a cache of whole 128-position lane "
-            f"tiles and whole sublane tiles of d_head; got cache shape "
+            f"tiles and whole sublane tiles of d_head (whole lane tiles "
+            f"under grouped queries); got q {q.shape}, cache shape "
             f"{cache_k.shape} {cache_k.dtype}.  Gate callers on "
             f"flash_decode_supported()."
         )
     return _decode_call(q, k_new, v_new, cache_k, cache_v, lengths,
-                        interpret=interpret)
+                        interpret=interpret, positions_last=positions_last)
 
 
 # A jit of its own: a model's layers call this at one signature, so the
 # kernel is traced once and lowered once a program (as one function the
 # layers call) instead of once a layer -- 24 Mosaic lowerings were 1.1 s
 # of the GPT-2 superstep's 1.8 s of lowering, which is set-up.
-@functools.partial(jax.jit, static_argnames="interpret")
-def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret):
-    b, s, h, hd = cache_k.shape
+@functools.partial(jax.jit, static_argnames=("interpret", "positions_last"))
+def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
+                 positions_last=False):
+    if not positions_last:
+        cache_k = cache_k.transpose(0, 2, 3, 1)
+        cache_v = cache_v.transpose(0, 2, 3, 1)
+    b, h, hd, s = cache_k.shape
+    group = q.shape[1] // h
     block_k = flash_decode_block(s, h, hd, cache_k.dtype)
     num_kb = s // block_k
-    kernel = functools.partial(
-        _decode_kernel, block_k=block_k, scale=1.0 / math.sqrt(hd),
-        num_kb=num_kb, heads=next(n for n in (4, 2, 1) if h % n == 0),
-    )
+    common = dict(block_k=block_k, scale=1.0 / math.sqrt(hd), num_kb=num_kb)
 
     def slot(bi, ki, lens):
         return (bi, 0, 0, 0)
@@ -1305,38 +1432,56 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret):
         return jnp.broadcast_to(x.astype(dtype)[..., None],
                                 (b, h, hd, _LANES))
 
-    cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
     tile = pl.BlockSpec((1, h, hd, _LANES), slot)
+    if group == 1:
+        kernel = functools.partial(
+            _decode_kernel, heads=next(n for n in (4, 2, 1) if h % n == 0),
+            **common)
+        q_in, q_spec = along_lanes(q, q.dtype), tile
+        # The output has the heads along the lanes, a whole tile of them.
+        o_shape = (b, hd, _LANES)
+        o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, ki, lens: (bi, 0, 0))
+        state = [(h, 1, _LANES), (h, 1, _LANES), (h, hd, _LANES)]
+    else:
+        # Queries and outputs (B, h, g, hd): a group's heads as rows,
+        # padded to a whole packed sublane tile of the query's dtype.
+        kernel = functools.partial(_decode_grouped_kernel, **common)
+        rows = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
+        gp = _round_up(group, rows)
+        q_in = jnp.pad(q.reshape(b, h, group, hd),
+                       ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+        o_shape = (b, h, gp, hd)
+        q_spec = o_spec = pl.BlockSpec((1, h, gp, hd), slot)
+        state = [(h, gp, _LANES), (h, gp, _LANES), (h, gp, hd)]
+    cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, num_kb),
-        in_specs=[tile] * 3 + [pl.BlockSpec((1, h, hd, block_k), block)] * 2,
-        # The output has the heads along the lanes, a whole tile of them.
-        out_specs=[pl.BlockSpec((1, hd, _LANES), lambda bi, ki, lens:
-                                (bi, 0, 0))]
-        + [pl.BlockSpec((1, h, hd, _LANES), written)] * 2,
-        scratch_shapes=[
-            pltpu.VMEM((h, 1, _LANES), jnp.float32),
-            pltpu.VMEM((h, 1, _LANES), jnp.float32),
-            pltpu.VMEM((h, hd, _LANES), jnp.float32),
-        ],
+        in_specs=[q_spec, tile, tile]
+        + [pl.BlockSpec((1, h, hd, block_k), block)] * 2,
+        out_specs=[o_spec] + [pl.BlockSpec((1, h, hd, _LANES), written)] * 2,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in state],
     )
     out, cache_k, cache_v = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, hd, _LANES), q.dtype),
-                   cache, cache],
+        out_shape=[jax.ShapeDtypeStruct(o_shape, q.dtype), cache, cache],
         # Operands count the scalar prefetch: 4 and 5 are the caches.
         input_output_aliases={4: 1, 5: 2},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_DECODE_VMEM_LIMIT),
         name="ff_flash_decode",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), along_lanes(q, q.dtype),
+    )(lengths.astype(jnp.int32), q_in,
       along_lanes(k_new, cache_k.dtype), along_lanes(v_new, cache_v.dtype),
-      cache_k.transpose(0, 2, 3, 1), cache_v.transpose(0, 2, 3, 1))
-    return (jnp.swapaxes(out[:, :, :h], 1, 2),
-            cache_k.transpose(0, 3, 1, 2), cache_v.transpose(0, 3, 1, 2))
+      cache_k, cache_v)
+    if group == 1:
+        out = jnp.swapaxes(out[:, :, :h], 1, 2)
+    else:
+        out = out[:, :, :group].reshape(b, h * group, hd)
+    if positions_last:
+        return out, cache_k, cache_v
+    return out, cache_k.transpose(0, 3, 1, 2), cache_v.transpose(0, 3, 1, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -2097,6 +2242,7 @@ def _fwd_uneven_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
+            precision=_mxu_precision(q.dtype),
             preferred_element_type=jnp.float32,
         ) * scale                                       # (bq, bk) f32
         if masked:
@@ -2111,6 +2257,7 @@ def _fwd_uneven_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         corr = jnp.exp(m - m_new)
         acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=_mxu_precision(v.dtype),
             preferred_element_type=jnp.float32,
         )
         l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
@@ -2131,12 +2278,18 @@ def flash_fwd_uneven(q, k, v, scale: float,
                      interpret: Optional[bool] = None):
     """Causal attention ``softmax(q k^T * scale) v`` on (b, h, t, qk)
     queries and keys and (b, h, t, dv) values, ``qk != dv`` allowed
-    (latent attention's expanded path: 192 against 128).  Forward only.
+    (latent attention's expanded path: 192 against 128).  Keys and
+    values may have fewer heads, ``h_kv`` dividing ``h`` (grouped-query
+    attention): query head j reads head ``j // (h // h_kv)`` through the
+    index map, so no repeated copy of K or V exists.  Forward only.
     Callers gate on :func:`flash_uneven_supported`."""
     if interpret is None:
         interpret = _interpret_default()
     b, h, t, qk = q.shape
     dv = v.shape[-1]
+    h_kv = k.shape[1]
+    group = h // h_kv
+    assert h == group * h_kv and v.shape[1] == h_kv, (q.shape, k.shape)
     block = _UNEVEN_BLOCK
     while t % block:
         block //= 2
@@ -2146,8 +2299,8 @@ def flash_fwd_uneven(q, k, v, scale: float,
         num_kb=num_kb,
     )
 
-    def kv_map(bi, i, j):
-        return (bi, jnp.minimum(j, i), 0)   # block_q == block_k
+    def kv_map(bi, i, j):                   # block_q == block_k
+        return (bi if group == 1 else bi // group, jnp.minimum(j, i), 0)
 
     out = pl.pallas_call(
         kernel,
@@ -2166,8 +2319,8 @@ def flash_fwd_uneven(q, k, v, scale: float,
         ],
         name="ff_flash_fwd_uneven",
         interpret=interpret,
-    )(q.reshape(b * h, t, qk), k.reshape(b * h, t, qk),
-      v.reshape(b * h, t, dv))
+    )(q.reshape(b * h, t, qk), k.reshape(b * h_kv, t, qk),
+      v.reshape(b * h_kv, t, dv))
     return out.reshape(b, h, t, dv)
 
 
@@ -2216,7 +2369,8 @@ def _mla_decode_kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr,
             m, l, acc = carry
             start = pl.multiple_of(i * chunk, chunk)
             rows = c_ref[0, :, pl.ds(start, chunk)]     # (row, chunk)
-            s = jnp.dot(q, rows, preferred_element_type=jnp.float32) * scale
+            s = jnp.dot(q, rows, precision=_mxu_precision(rows.dtype),
+                        preferred_element_type=jnp.float32) * scale
             k_pos = kb * block_k + start + lax.broadcasted_iota(
                 jnp.int32, (h, chunk), 1)
             s = jnp.where(k_pos < length, s, _NEG_INF)  # (h, chunk) f32
@@ -2225,6 +2379,7 @@ def _mla_decode_kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr,
             corr = jnp.exp(m - m_new)
             acc = acc * corr + lax.dot_general(
                 p.astype(rows.dtype), rows[:dv], (((1,), (1,)), ((), ())),
+                precision=_mxu_precision(rows.dtype),
                 preferred_element_type=jnp.float32,
             )                                           # (h, dv)
             l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
@@ -2322,9 +2477,12 @@ def _gmm_kernel(te_ref, nu_ref, x_ref, *refs, gated):
     @pl.when(pl.program_id(0) < nu_ref[0])
     def _tile():
         x = x_ref[...]
-        y = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
+        exact = _mxu_precision(x.dtype)
+        y = jnp.dot(x, refs[0][0], precision=exact,
+                    preferred_element_type=jnp.float32)
         if gated:
-            up = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
+            up = jnp.dot(x, refs[1][0], precision=exact,
+                         preferred_element_type=jnp.float32)
             y = y * jax.nn.sigmoid(y) * up              # silu(gate) * up
         o_ref[...] = y.astype(o_ref.dtype)
 
@@ -2374,3 +2532,276 @@ def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int,
         interpret=interpret,
     )(tile_expert.astype(jnp.int32),
       jnp.reshape(tiles_used, (1,)).astype(jnp.int32), x, *weights)
+
+
+# ---------------------------------------------------------------------------
+# gated delta-rule linear attention (Kimi Delta Attention, arXiv:2510.26692):
+# a recurrent state a head, decayed a key channel.  Forward only, reachable
+# from ops/delta_attention.py::KimiDeltaAttention when the serving executor
+# drives it; that module's plain recurrence is the oracle.
+# ---------------------------------------------------------------------------
+
+#: Tokens a chunk of the prefill scan, and rows of the sub-blocks its
+#: intra-chunk decays are taken inside.
+KDA_CHUNK = 64
+_KDA_SUB = 16
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def kda_supported(d_k: int, d_v: int) -> bool:
+    """Whether ``kda_chunk`` and ``kda_decode`` take heads of these
+    widths: whole lane tiles."""
+    return d_k % _LANES == 0 and d_v % _LANES == 0
+
+
+def _kda_intra_kernel(q_ref, k_ref, g_ref, a_ref, b_ref):
+    """One chunk of one head: the two decayed Gram matrices, a block row
+    of ``_KDA_SUB`` rows at a time.  Against the earlier rows of the
+    chunk through the block's first row ``r`` (``exp(G_i - r) exp(r -
+    G_j)``, both factors <= 1: one product a matrix on the matrix unit);
+    inside the block one column at a time on the vector unit."""
+    q, k, G = q_ref[0, 0], k_ref[0, 0], g_ref[0, 0]              # (C, d) f32
+    c, s = k.shape[0], _KDA_SUB
+    lane = lax.broadcasted_iota(jnp.int32, (s, c), 1)
+    sub = lax.broadcasted_iota(jnp.int32, (s, c), 0)
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+
+    def gram(x, y):
+        return lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+    for lo in range(0, c, s):
+        Gi, ki, qi = G[lo:lo + s], k[lo:lo + s], q[lo:lo + s]
+        ref = Gi[0:1]
+        left = jnp.exp(Gi - ref)
+        right = jnp.where(row < lo,
+                          k * jnp.exp(jnp.minimum(ref - G, 0.0)), 0.0)
+        a, b = gram(ki * left, right), gram(qi * left, right)     # (s, C)
+        for j in range(s):
+            kj = ki[j:j + 1] * jnp.exp(jnp.minimum(Gi - Gi[j:j + 1], 0.0))
+            at = lane == lo + j
+            a = jnp.where(at & (sub > j),
+                          jnp.sum(ki * kj, axis=1, keepdims=True), a)
+            b = jnp.where(at & (sub >= j),
+                          jnp.sum(qi * kj, axis=1, keepdims=True), b)
+        a_ref[0, 0, lo:lo + s, :] = a
+        b_ref[0, 0, lo:lo + s, :] = b
+
+
+def _kda_intra(q, k, G, interpret: bool):
+    """The two decayed Gram matrices of every chunk: ``A[i, j] = sum_c
+    k_i k_j exp(G_i - G_j)`` for ``j < i`` and ``B[i, j] = sum_c q_i k_j
+    exp(G_i - G_j)`` for ``j <= i``, zero elsewhere; ``q``, ``k``, ``G``
+    (N, nc, C, d) f32, ``G`` the chunk's running sum of log decays
+    (non-increasing).  ``exp(G_i) exp(-G_j)`` overflows under a strong
+    decay, so every exponent the kernel takes is a difference that is
+    ``<= 0``.  (Sixteen XLA passes over the operands did the columns
+    before, 20 ms a 2048-token segment of 64 heads: PERF.md §6 PR 33.)"""
+    n, nc, c, d = k.shape
+    rows = pl.BlockSpec((1, 1, c, d), lambda i, j: (i, j, 0, 0))
+    gram = pl.BlockSpec((1, 1, c, c), lambda i, j: (i, j, 0, 0))
+    out = jax.ShapeDtypeStruct((n, nc, c, c), jnp.float32)
+    return pl.pallas_call(
+        _kda_intra_kernel, grid=(n, nc), in_specs=[rows] * 3,
+        out_specs=[gram, gram], out_shape=[out, out],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        name="ff_kda_intra", interpret=interpret)(q, k, G)
+
+
+def _unit_lower_solve(m, rhs):
+    """``(I + m)^-1 rhs`` for strictly lower-triangular ``m`` (..., C, C)
+    by forward substitution (never a Neumann product: its powers cancel
+    catastrophically where keys repeat and beta nears 2): the diagonal
+    sub-blocks are inverted a row at a time, every chunk at once, then
+    the block rows are solved in order."""
+    *lead, c, _ = m.shape
+    s, nb = _KDA_SUB, c // _KDA_SUB
+    mb = m.reshape(*lead, nb, s, nb, s)
+    low = jnp.stack([mb[..., i, :, i, :] for i in range(nb)], axis=-3)
+    eye = jnp.eye(s, dtype=m.dtype)
+    # Row i of (I + low)^-1 is e_i - sum_{j<i} low[i, j] row_j.
+    inv = []
+    for i in range(s):
+        row = jnp.broadcast_to(eye[i], low.shape[:-2] + (s,))
+        for j in range(i):
+            row = row - low[..., i, j:j + 1] * inv[j]
+        inv.append(row)
+    inv = jnp.stack(inv, axis=-2)
+    rb = rhs.reshape(*lead, nb, s, rhs.shape[-1])
+    out = []
+    for i in range(nb):
+        r = rb[..., i, :, :]
+        for j in range(i):
+            r = r - jnp.einsum("...ij,...jk->...ik", mb[..., i, :, j, :],
+                               out[j], precision=_HIGHEST)
+        out.append(jnp.einsum("...ij,...jk->...ik", inv[..., i, :, :], r,
+                              precision=_HIGHEST))
+    return jnp.concatenate(out, axis=-2)
+
+
+def _kda_chunk_kernel(qt_ref, w_ref, u0_ref, kh_ref, b_ref, egl_ref, s0_ref,
+                      o_ref, s_ref, st_scr, *, num_chunks):
+    """One chunk of one head: the state ``st`` (d_v, d_k) arrives from
+    the chunk before.  ``u = u0 - w st^T`` are the chunk's corrected
+    values, ``o = qt st^T + b u`` its outputs, ``st' = st * egl + u^T
+    kh`` the state it hands on."""
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _first():
+        st_scr[...] = s0_ref[0]
+
+    def mm(x, y, dims):
+        return lax.dot_general(x, y, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+    st = st_scr[...]
+    u = u0_ref[0] - mm(w_ref[0], st, ((1,), (1,)))            # (C, d_v)
+    o_ref[0] = mm(qt_ref[0], st, ((1,), (1,))) + mm(b_ref[0], u, ((1,), (0,)))
+    st = st * egl_ref[0, 0] + mm(u, kh_ref[0], ((0,), (0,)))  # (d_v, d_k)
+    st_scr[...] = st
+
+    @pl.when(c == num_chunks - 1)
+    def _last():
+        s_ref[0] = st
+
+
+def kda_chunk(q, k, v, g, beta, state, interpret: Optional[bool] = None):
+    """The gated delta rule over ``T`` tokens of ``N`` heads, in chunks
+    of ``KDA_CHUNK``: for ``t = 0 .. T-1``
+
+        S' = diag(exp(g_t)) S;  S = S' + beta_t k_t (v_t - S'^T k_t)^T;
+        o_t = S^T q_t
+
+    ``q``, ``k`` (T, N, d_k) (normalised and scaled by the caller), ``v``
+    (T, N, d_v), ``g`` (T, N, d_k) log decays ``<= 0``, ``beta`` (T, N);
+    ``state`` (N, d_v, d_k) f32, ``S`` transposed.  A token with ``g = 0``
+    and ``beta = 0`` leaves the state as it is (how a caller ends a
+    prompt inside a padded bucket).  Returns ``(o (T, N, d_v) f32,
+    state)``.
+
+    Inside a chunk nothing but the last step needs the incoming state:
+    the decayed Gram matrices are the kernel ``ff_kda_intra``'s, every
+    chunk a grid step of its own; the unit-triangular system ``(I + beta
+    A) [u0 | w] = beta [v | k exp(G)]`` is XLA's, batched over all chunks
+    in f32 (``_unit_lower_solve``); the kernel ``ff_kda_chunk`` walks the
+    chunks of a head in order with the state in VMEM.  ``T`` a multiple of ``KDA_CHUNK``; callers gate on
+    :func:`kda_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    t, n, dk = q.shape
+    dv = v.shape[-1]
+    c = KDA_CHUNK
+    nc = t // c
+    assert t == nc * c and kda_supported(dk, dv), (q.shape, v.shape)
+    f32 = jnp.float32
+
+    def chunks(x):
+        return jnp.moveaxis(x.astype(f32).reshape(nc, c, n, x.shape[-1]), 2, 0)
+
+    q, k, v, g, b = map(chunks, (q, k, v, g, beta[..., None]))
+    G = jnp.cumsum(g, axis=2)
+    gl = G[:, :, -1:]
+    eg = jnp.exp(G)
+    kt = k * eg
+    a, bm = _kda_intra(q, k, G, interpret)
+    sol = _unit_lower_solve(b * a, jnp.concatenate([b * v, b * kt], axis=-1))
+
+    def flat(x):
+        return x.reshape(n, t, x.shape[-1])
+
+    def rows(width):
+        return pl.BlockSpec((1, c, width), lambda i, j: (i, j, 0))
+
+    whole = pl.BlockSpec((1, dv, dk), lambda i, j: (i, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, num_chunks=nc),
+        grid=(n, nc),
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(c),
+                  pl.BlockSpec((1, 1, 1, dk), lambda i, j: (i, j, 0, 0)),
+                  whole],
+        out_specs=[rows(dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((n, t, dv), f32),
+                   jax.ShapeDtypeStruct((n, dv, dk), f32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), f32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="ff_kda_chunk",
+        interpret=interpret,
+    )(flat(q * eg), flat(sol[..., dv:]), flat(sol[..., :dv]),
+      flat(k * jnp.exp(gl - G)), flat(bm), jnp.exp(gl), state.astype(f32))
+    return jnp.swapaxes(o, 0, 1), state
+
+
+_KDA_DECODE_HEADS = 16
+
+
+def _kda_decode_kernel(q_ref, k_ref, kb_ref, a_ref, vb_ref, s_ref,
+                       o_ref, so_ref, *, heads):
+    """``heads`` heads of one slot: each state tile (d_v, d_k) is
+    decayed along its lanes, corrected by one outer product and read
+    once by the query, all on the vector unit in f32.  Values and
+    outputs ride with the heads along the lanes (a column a head)."""
+    grp = pl.program_id(1)
+    dv, hl = vb_ref.shape[1:]
+    lane = lax.broadcasted_iota(jnp.int32, (dv, hl), 1)
+
+    @pl.when(grp == 0)
+    def _first():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def head(j, out):
+        i = grp * heads + j
+
+        def row(ref):
+            return ref[0, pl.ds(i, 1), :]                          # (1, d_k)
+
+        m = s_ref[0, j] * row(a_ref)
+        v = jnp.sum(jnp.where(lane == i, vb_ref[0], 0.0), axis=1, keepdims=True)
+        err = v - jnp.sum(m * row(kb_ref), axis=1, keepdims=True)  # (d_v, 1)
+        m = m + err * row(k_ref)
+        so_ref[0, j] = m
+        o = jnp.sum(m * row(q_ref), axis=1, keepdims=True)
+        return jnp.where(lane == i, o, out)
+
+    o_ref[0] = lax.fori_loop(0, heads, head, o_ref[0])
+
+
+def kda_decode(q, k, v, g, beta, state, interpret: Optional[bool] = None):
+    """One token of the gated delta rule for every slot (``kda_chunk``'s
+    recurrence at ``T = 1``): ``q``, ``k``, ``g`` (B, H, d_k), ``v``
+    (B, H, d_v), ``beta`` (B, H), ``state`` (B, H, d_v, d_k) f32.
+    Returns ``(o (B, H, d_v) f32, state)``; donate the state and it is
+    read and written in place.  Callers gate on :func:`kda_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    assert kda_supported(dk, dv), (q.shape, v.shape)
+    f32 = jnp.float32
+    heads = next(n for n in (_KDA_DECODE_HEADS, 8, 4, 2, 1) if h % n == 0)
+    hl = _round_up(h, _LANES)
+    beta = beta.astype(f32)[..., None]
+    k = k.astype(f32)
+    vb = jnp.pad(jnp.swapaxes(beta * v.astype(f32), 1, 2),
+                 ((0, 0), (0, 0), (0, hl - h)))                    # (B, d_v, hl)
+    rows = pl.BlockSpec((1, h, dk), lambda i, j: (i, 0, 0))
+    cols = pl.BlockSpec((1, dv, hl), lambda i, j: (i, 0, 0))
+    tiles = pl.BlockSpec((1, heads, dv, dk), lambda i, j: (i, j, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_kda_decode_kernel, heads=heads),
+        grid=(b, h // heads),
+        in_specs=[rows, rows, rows, rows, cols, tiles],
+        out_specs=[cols, tiles],
+        out_shape=[jax.ShapeDtypeStruct((b, dv, hl), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="ff_kda_decode",
+        interpret=interpret,
+    )(q.astype(f32), k, beta * k, jnp.exp(g.astype(f32)), vb, state)
+    return jnp.swapaxes(o[:, :, :h], 1, 2), state

@@ -643,6 +643,11 @@ class ServingExecutor:
         self._tokens_name = feed[0].name
         self.max_batch = int(max_batch)
         self.max_seq = int(max_seq or feed[0].shape[1])
+        # The positions-last cache order is the padded single-mesh
+        # layout's (SERVING.md "Cache layout").
+        self._positions_last = not (kv_block or shard)
+        for op in self._layers:
+            self._bind_layout(op)
         #: What each attention-like op declares it keeps for a slot
         #: (``Op.cache_entries``): name -> {entry: CacheEntry}.  The
         #: executor allocates, installs and carries exactly this.
@@ -659,6 +664,14 @@ class ServingExecutor:
                 "serving needs at least one op that declares a cache "
                 "(Op.cache_entries: the decode protocol lives there)"
             )
+        #: The cache-holding ops some entry of which has no sequence
+        #: axis (a recurrent state, a convolution window): what the block
+        #: pool, a shared prefix and a speculative step cannot hold.
+        self.stateful_ops = [
+            op for op in self.attn_ops
+            if not all(ce.sequence
+                       for ce in self._cache_specs[op.name].values())
+        ]
         #: Whether any op reports counters when serving (``Op.serving_stats``).
         self.has_stats = any(op.serving_stats for op in self._layers)
         # Pad buckets for prefill (ascending); every bucket compiles
@@ -673,12 +686,12 @@ class ServingExecutor:
         self.kv_block = int(kv_block or 0)
         self.paged = self.kv_block > 0
         if self.paged:
-            unpaged = [op.name for op in self.attn_ops if not op.cache_paged]
+            unpaged = [op for op in self.attn_ops if not op.cache_paged]
             if unpaged:
                 raise ValueError(
                     f"the paged KV layout (kv_block > 0) holds keys and "
-                    f"values a head; {unpaged} "
-                    f"({type(self.attn_ops[0]).__name__}) declare another "
+                    f"values a head; {[op.name for op in unpaged]} "
+                    f"({type(unpaged[0]).__name__}) declare another "
                     f"cache and have no paged pool yet (ROADMAP Queue B): "
                     f"serve them padded (kv_block=0)"
                 )
@@ -747,11 +760,13 @@ class ServingExecutor:
                 bad = [
                     op.name for op in self.attn_ops
                     if op.attrs["num_heads"] % c
+                    or op.attrs["num_kv_heads"] % c
                 ]
                 if bad:
                     raise ValueError(
                         f"shard head degree c={c} must divide num_heads "
-                        f"of every attention op; offenders: {bad}"
+                        f"(and num_kv_heads) of every attention op; "
+                        f"offenders: {bad}"
                     )
                 from flexflow_tpu.parallel.mesh import build_mesh_plan
                 from flexflow_tpu.parallel.strategy import ParallelConfig
@@ -841,14 +856,33 @@ class ServingExecutor:
 
     # -- caches -------------------------------------------------------------
 
+    def _entries(self, sequence: bool):
+        return [ce for ents in self._cache_specs.values()
+                for ce in ents.values() if ce.sequence == sequence]
+
     @property
     def _bytes_per_token(self) -> int:
-        """Bytes one cached token position costs across ALL layers
-        (every declared entry: K and V, or the latent column)."""
+        """Bytes one cached token position costs across ALL layers, over
+        the declared entries that have a sequence axis (K and V, the
+        latent column)."""
         return sum(
             math.prod(ce.shape) // self.max_seq * jnp.dtype(ce.dtype).itemsize
-            for ents in self._cache_specs.values() for ce in ents.values()
+            for ce in self._entries(True)
         )
+
+    @property
+    def _bytes_fixed(self) -> int:
+        """Bytes a slot's entries WITHOUT a sequence axis cost across
+        all layers, whatever the length (recurrent states, convolution
+        windows): 0 for a graph of attention layers alone."""
+        return sum(math.prod(ce.shape) * jnp.dtype(ce.dtype).itemsize
+                   for ce in self._entries(False))
+
+    @property
+    def _bytes_per_slot(self) -> int:
+        """A padded slot: ``max_seq`` positions of every per-token entry
+        and the fixed entries once."""
+        return self.max_seq * self._bytes_per_token + self._bytes_fixed
 
     def cache_total_bytes(self) -> int:
         """Per-device bytes :meth:`init_cache` will allocate (the
@@ -859,7 +893,7 @@ class ServingExecutor:
                 # The pool shards heads on 'c' only; 'n' replicates it.
                 total //= self._pc.c
         else:
-            total = self.max_batch * self.max_seq * self._bytes_per_token
+            total = self.max_batch * self._bytes_per_slot
             if self._plan is not None:
                 total //= self._plan.num_devices
         return total
@@ -868,13 +902,14 @@ class ServingExecutor:
         self, prompt_len: Optional[int] = None,
         max_new_tokens: Optional[int] = None,
     ) -> int:
-        """KV-cache HBM one decode slot costs.  Padded: the full
-        worst-case ``max_seq`` row, regardless of request length.
+        """Cache HBM one decode slot costs.  Padded: the full
+        worst-case ``max_seq`` row of every per-token entry, regardless
+        of request length, and the entries without a sequence axis once.
         Paged: the blocks :class:`KVBlockLedger` would reserve for a
         ``(prompt_len, max_new_tokens)`` request (defaults: the
         worst case, where the two layouts coincide up to rounding)."""
         if not self.paged:
-            return self.max_seq * self._bytes_per_token
+            return self._bytes_per_slot
         if prompt_len is None:
             blocks = self.blocks_per_slot
         else:
@@ -894,7 +929,7 @@ class ServingExecutor:
         bounded by worst-case ``max_seq`` rows; paged by the block
         pool the budget can hold."""
         if not self.paged:
-            return budget_bytes // (self.max_seq * self._bytes_per_token)
+            return budget_bytes // self._bytes_per_slot
         block_bytes = self.kv_block * self._bytes_per_token
         pool_blocks = budget_bytes // block_bytes - 1  # scratch
         led = KVBlockLedger(self.kv_blocks, self.kv_block, self.max_seq)
@@ -943,13 +978,17 @@ class ServingExecutor:
             )
 
     def init_cache(self):
-        """Preallocated per-layer KV caches on the serving device(s).
+        """Preallocated per-layer caches on the serving device(s).
 
         Padded: ``{op: {entry: (max_batch,) + declared shape}}`` — for
         ``MultiHeadAttention`` ``"k"``/``"v"`` of ``(max_batch,
-        max_seq, heads, d_head)`` (``NamedSharding``-placed
-        batch-on-'n'/heads-on-'c' when sharded), for ``LatentAttention``
-        one ``"ckr"`` of ``(max_batch, kv_rank + rope, max_seq)``.
+        max_seq, kv_heads, d_head)`` (``NamedSharding``-placed
+        batch-on-'n'/heads-on-'c' when sharded; ``(max_batch, kv_heads,
+        d_head, max_seq)`` where ``d_head`` fills whole lane tiles), for
+        ``LatentAttention`` one ``"ckr"`` of ``(max_batch, kv_rank +
+        rope, max_seq)``, for ``KimiDeltaAttention`` a ``"state"`` of
+        ``(max_batch, heads, d_head, d_head)`` float32 and a ``"conv"``
+        window, neither with a sequence axis.
         Paged: ``{op: {"k"/"v": (kv_blocks, kv_block, heads,
         d_head)}}`` — the global block pool; slot structure lives in
         the block table."""
@@ -1000,6 +1039,24 @@ class ServingExecutor:
         return self._cache_tree(self._draft_cache_specs, self._make_cache(
             paged=False, batch_axis=not self.paged))
 
+    def _bind_layout(self, op) -> None:
+        """Which order an attention op with heads of whole lane tiles
+        declares and reads its cache in under this executor (the op
+        objects are shared between executors, so this is bound before
+        every declaration and trace, like ``decode_kernel``)."""
+        if isinstance(op, MultiHeadAttention):
+            op.positions_last = op.lane_tile_heads and self._positions_last
+
+    def _refuse_stateful(self, what: str) -> None:
+        """A recurrent state has no rows: a rejected draft token cannot
+        be masked out of it and no prefix of it can be shared."""
+        if self.stateful_ops:
+            names = [op.name for op in self.stateful_ops]
+            raise ValueError(
+                f"{what} needs caches whose every entry has a sequence "
+                f"axis; {names} ({type(self.stateful_ops[0]).__name__}) "
+                f"keep a recurrent state (ROADMAP Queue B)")
+
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.buckets:
             if b >= prompt_len:
@@ -1013,7 +1070,7 @@ class ServingExecutor:
 
     def _forward(self, params, op_state, tokens, caches, pos,
                  block_table=None, skip=None, chunk=0, last=None,
-                 stats=None):
+                 stats=None, length=None):
         """Forward-only walk over the non-loss op graph in inference
         mode: attention ops get their caches + the per-slot position
         vector through the existing ``state`` mechanism
@@ -1034,6 +1091,11 @@ class ServingExecutor:
         row alone.  ``stats`` (a dict the caller hands in) collects
         what serving-aware ops report beside their outputs
         (``state["stats"]``: the expert layers' routing counters).
+        ``length`` (a traced scalar, a prefill's) tells the
+        cache-holding ops how many of ``tokens`` are the prompt's and
+        not the bucket's padding (``state["length"]``): an attention
+        layer may ignore it (its pad rows are overwritten before a mask
+        admits them), a recurrent layer must stop its state there.
         Returns ``(logits, new_caches)``."""
         env: Dict[str, Any] = {self._tokens_name: tokens}
         new_caches: Dict[str, Any] = {}
@@ -1056,6 +1118,7 @@ class ServingExecutor:
                 op.bind_mesh(None, None)
             if op.name in self._cache_specs:
                 op.decode_kernel = self.decode_kernel
+                self._bind_layout(op)
             xs = [env[t.name] for t in op.inputs]
             if last is not None and op.outputs[0].name == self._logits_name:
                 xs = [jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
@@ -1067,6 +1130,8 @@ class ServingExecutor:
                 for entry, c in caches[op.name].items():
                     s[f"cache_{entry}"] = c
                 s["pos"] = pos
+                if length is not None:
+                    s["length"] = length
                 if block_table is not None:
                     s["block_table"] = block_table
                 if chunk:
@@ -1124,9 +1189,11 @@ class ServingExecutor:
                       sample: Optional[Tuple[float, int, int]] = None):
         """One jitted prefill program per pad bucket: ``(params,
         op_state, tokens (1, bucket), length ()) -> (cache_rows,
-        first_token, finite)``.  ``cache_rows`` are (max_seq, h, hd)
-        per layer (rows beyond ``bucket`` zero), ready for
-        :meth:`install` into a slot.
+        first_token, finite)``.  ``cache_rows`` are one slot's worth of
+        every declared entry, ``{op: {entry: declared shape}}`` (K and V
+        with positions beyond ``bucket`` zero, a latent column, a
+        recurrent state and its convolution window as they stand after
+        ``length`` tokens), ready for :meth:`install` into a slot.
 
         ``sample=(temperature, top_k, seed)`` builds the SAMPLED
         variant — ``(params, op_state, tokens, length, prompt_len,
@@ -1157,6 +1224,7 @@ class ServingExecutor:
             logits, caches = self._forward(
                 params, op_state, tokens, caches, pos,
                 last=length - 1 if slim else None, stats=stats,
+                length=length,
             )
             last = logits[0, 0] if slim else jax.lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
@@ -1212,16 +1280,25 @@ class ServingExecutor:
         to the block the ops' decode step reads in
         (``Op.decode_fetch_block``; the paged view and the einsum
         oracle read every row), ``kv_rows_cache`` is slots x max_seq x
-        k.  Host arithmetic, one layer's rows."""
+        k.  Host arithmetic, one layer's rows, over the ops whose cache
+        has a sequence axis; a graph that also keeps recurrent state
+        adds ``state_bytes``, the bytes of state the superstep reads
+        and writes over all its layers."""
         S = self.max_seq
         n, c = self.shard or (1, 1)
         block = S if self.paged else max(
-            op.decode_fetch_block(self.max_batch // n, S,
-                                  self.decode_kernel, c)
-            for op in self.attn_ops)
+            (op.decode_fetch_block(self.max_batch // n, S,
+                                   self.decode_kernel, c)
+             for op in self.attn_ops if op not in self.stateful_ops),
+            default=S)
         live = np.minimum(np.asarray(pos)[:, None] + np.arange(k), S - 1) + 1
-        return {"kv_rows_fetched": int((-(-live // block) * block).sum()),
+        rows = {"kv_rows_fetched": int((-(-live // block) * block).sum()),
                 "kv_rows_cache": int(live.size * S)}
+        if self._bytes_fixed:
+            # Every slot's recurrent state and window, read and written
+            # once a step.
+            rows["state_bytes"] = 2 * k * self.max_batch * self._bytes_fixed
+        return rows
 
     @staticmethod
     def _mean_stats(stats):
@@ -1264,6 +1341,7 @@ class ServingExecutor:
                 "build_prefill_from needs paged + prefix_cache "
                 "(SERVING.md 'Prefix sharing')"
             )
+        self._refuse_stateful("the offset prefill")
         offset = int(offset)
         if offset < self.kv_block or offset % self.kv_block or \
                 offset >= bucket:
@@ -1324,9 +1402,10 @@ class ServingExecutor:
 
     @functools.cached_property
     def install(self):
-        """One jitted program installing a prefilled cache row into a
-        slot across every layer's K and V (donated caches: the install
-        is in-place on device)."""
+        """One jitted program installing a prefill's rows into a slot
+        across every declared entry of every layer (K and V, a latent
+        column, a recurrent state and its window; donated caches: the
+        install is in-place on device)."""
 
         def install(caches, rows, slot):
             return jax.tree.map(
@@ -1500,6 +1579,7 @@ class ServingExecutor:
         admission when speculating (priced by the latency model's
         ``draft_prefill_ms``).  No token/finiteness output: the draft
         never emits — a garbage draft row only costs acceptance."""
+        self._refuse_stateful("the speculative draft")
         key = ("draft", bucket)
         fn = self._prefill_fns.get(key)
         if fn is not None:
@@ -1566,6 +1646,7 @@ class ServingExecutor:
                 f"speculate depth must be >= 1, got {d} "
                 f"(plain fused decode is build_decode_superstep)"
             )
+        self._refuse_stateful("the speculative step")
         d = clamp_fused_steps(d, what="speculate", log=_log)
         if sample is not None:
             temperature, top_k, sample_seed = sample
@@ -2269,7 +2350,7 @@ class Server:
                                 n["prefix_hits"] += 1
                                 n["prefill_tokens_saved"] += plan.offset
                                 tel.emit("prefill", id=r.id, bucket=bucket,
-                                         offset=plan.offset,
+                                         length=flen, offset=plan.offset,
                                          wall_s=round(pf_s, 6))
                                 tel.emit("prefix_hit", id=r.id,
                                          blocks=plan.use, full=False,
@@ -2280,6 +2361,7 @@ class Server:
                                              blocks=plan.cow)
                             else:
                                 tel.emit("prefill", id=r.id, bucket=bucket,
+                                         length=flen,
                                          wall_s=round(pf_s, 6),
                                          **rounded(routed))
                         if jr is not None:

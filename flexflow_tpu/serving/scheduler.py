@@ -891,6 +891,7 @@ class ScheduledServer:
                     prefix_hits += 1
                     prefill_tokens_saved += plan.offset
                     sev("prefill", id=r.id, bucket=bucket,
+                        length=len(full),
                         offset=plan.offset, wall_s=round(pf_s, 6),
                         vclock_ms=round(vclock, 3))
                     sev("prefix_hit", id=r.id, blocks=plan.use,
@@ -902,7 +903,7 @@ class ScheduledServer:
                             vclock_ms=round(vclock, 3))
                 else:
                     sev("prefill", id=r.id, bucket=bucket,
-                        wall_s=round(pf_s, 6),
+                        length=len(full), wall_s=round(pf_s, 6),
                         vclock_ms=round(vclock, 3))
             if ok and digests:
                 # Index only AFTER the fence validated the install

@@ -93,12 +93,35 @@ def _decode(b, s, h, hd, dtype):
         small, small, small, cache, cache, _sds((b,), jnp.int32))
 
 
-def _flash_uneven(t, h, qk, dv):
-    q = _sds((1, h, t, qk), BF16)
+def _flash_uneven(t, h, qk, dv, h_kv=None):
+    q, k = _sds((1, h, t, qk), BF16), _sds((1, h_kv or h, t, qk), BF16)
     fn = functools.partial(pk.flash_fwd_uneven, scale=qk ** -0.5,
                            interpret=False)
     return (lambda: pk.flash_uneven_supported((1, h, t, qk), dv)), fn, (
-        q, q, _sds((1, h, t, dv), BF16))
+        q, k, _sds((1, h_kv or h, t, dv), BF16))
+
+
+def _decode_grouped(b, s, h, h_kv, hd, dtype):
+    """Grouped queries over a positions-last cache, as the op declares it."""
+    fn = functools.partial(pk.flash_decode, interpret=False, positions_last=True)
+    new, cache = _sds((b, h_kv, hd), dtype), _sds((b, h_kv, hd, s), dtype)
+    gate = lambda: pk.flash_decode_supported((b, s, h_kv, hd), dtype, h // h_kv)
+    return gate, fn, (_sds((b, h, hd), dtype), new, new, cache, cache,
+                      _sds((b,), jnp.int32))
+
+
+def _kda_chunk(t, n, d):
+    x = _sds((t, n, d), F32)
+    fn = functools.partial(pk.kda_chunk, interpret=False)
+    return (lambda: pk.kda_supported(d, d)), fn, (
+        x, x, x, x, _sds((t, n), F32), _sds((n, d, d), F32))
+
+
+def _kda_decode(b, h, d):
+    x = _sds((b, h, d), F32)
+    fn = functools.partial(pk.kda_decode, interpret=False)
+    return (lambda: pk.kda_supported(d, d)), fn, (
+        x, x, x, x, _sds((b, h), F32), _sds((b, h, d, d), F32))
 
 
 def _mla_decode(b, h, row, dv, s):
@@ -183,6 +206,34 @@ CASES = {
         lambda: _grouped(128, 8, 256, 128, 16, True),
     "grouped_matmul-down-smoke-128x128x256":
         lambda: _grouped(128, 8, 128, 256, 16, False),
+    # The solar2.serve.closed32.p4k-31k cell's kernels at its widths (64
+    # query heads over 8 key/value heads of 128, 64 delta-rule heads of
+    # 128, 40 held experts of 4096 x 1280): the 4608 and 32768 prefill
+    # buckets, a 2048-token segment of the chunked scan, 32 slots of
+    # 32768 positions, 256 assignments a decode step (16-row tiles) and
+    # a 4096-token segment's 32768 (128-row tiles); and the smoke preset's.
+    "flash_uneven-gqa64x8-4608x128-bf16":
+        lambda: _flash_uneven(4608, 64, 128, 128, h_kv=8),
+    "flash_uneven-gqa64x8-32768x128-bf16":
+        lambda: _flash_uneven(32768, 64, 128, 128, h_kv=8),
+    "flash_uneven-gqa4x2-256x128-bf16":
+        lambda: _flash_uneven(256, 4, 128, 128, h_kv=2),
+    "decode_grouped-32x32768x64x8x128-bf16":
+        lambda: _decode_grouped(32, 32768, 64, 8, 128, BF16),
+    "decode_grouped-4x256x4x2x128-bf16":
+        lambda: _decode_grouped(4, 256, 4, 2, 128, BF16),
+    "kda_chunk-2048x64x128": lambda: _kda_chunk(2048, 64, 128),
+    "kda_chunk-256x2x128": lambda: _kda_chunk(256, 2, 128),
+    "kda_decode-32x64x128": lambda: _kda_decode(32, 64, 128),
+    "kda_decode-4x2x128": lambda: _kda_decode(4, 2, 128),
+    "grouped_matmul-gated-decode-864x4096x1280":
+        lambda: _grouped(864, 40, 4096, 1280, 16, True),
+    "grouped_matmul-down-decode-864x1280x4096":
+        lambda: _grouped(864, 40, 1280, 4096, 16, False),
+    "grouped_matmul-gated-prefill-37888x4096x1280":
+        lambda: _grouped(37888, 40, 4096, 1280, 128, True),
+    "grouped_matmul-down-prefill-37888x1280x4096":
+        lambda: _grouped(37888, 40, 1280, 4096, 128, False),
     "gather_rows-1Mx64-1024ids":
         lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
@@ -215,8 +266,9 @@ def _compiled_text(name: str) -> str:
     _gate, fn, args = CASES[name]()
     # The table donated, as the train step donates its parameters; the
     # caches, as the decode superstep donates them.
-    donate = {"scatter_add_rows": (0,), "decode": (3, 4)}.get(
-        name.split("-")[0], ())
+    donate = {"scatter_add_rows": (0,), "decode": (3, 4),
+              "decode_grouped": (3, 4), "kda_chunk": (5,),
+              "kda_decode": (5,)}.get(name.split("-")[0], ())
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
@@ -346,6 +398,10 @@ def test_supported_gates_match_the_compiler():
     # d_head on whole sublane tiles; what it refuses takes the einsum.
     assert not pk.flash_decode_supported((4, 1030, 8, 64), F32)
     assert not pk.flash_decode_supported((4, 512, 8, 8), BF16)
+    # Grouped queries and the delta rule's kernels want whole lane tiles
+    # of d_head.
+    assert not pk.flash_decode_supported((4, 512, 2, 64), BF16, group=4)
+    assert not pk.kda_supported(64, 64)
     # The latent kernels work on whole 128-position lane tiles and
     # whole 128-lane expert widths, and say so.
     assert not pk.mla_decode_supported((4, 160, 200), 128)
@@ -361,6 +417,58 @@ def test_latent_decode_reads_the_cache_where_it_lies():
     cell's size.)"""
     text = _compiled_text("mla_decode-16x32x576x16384-bf16")
     assert chip_smoke.table_sized_relayouts(text, 16 * 576 * 16384) == []
+
+
+def test_solar_decode_superstep_holds_no_cache_or_state_sized_relayout(
+        monkeypatch):
+    """The scanned decode step of ``solar2.serve.closed32.p4k-31k`` over
+    one period (grouped-query, delta) at the cell's widths, 8 slots:
+    the KV cache is declared positions-last, since the chip would hold
+    ``(max_seq, 8, 128)`` row-major and pay PR 32's two relayouts, and
+    both it and the float32 recurrent state go from parameter to kernel
+    to result where they lie; and no weight is concatenated a step."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    from benchmark import common
+
+    model = common.load_json(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "solar-open2-250b-l4e40.json")
+    model.update(num_hidden_layers=2, vocab_size=1024)
+    slots, seq = 8, 2048   # sizes no weight of the model shares
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(model, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    assert caches["blk0_attn"]["k"].shape == (slots, 8, 128, seq)
+    assert caches["blk1_kda"]["state"].shape == (slots, 64, 128, 128)
+    vec = _sds((slots,), jnp.int32)
+    compiled = sex.build_decode_superstep(8).lower(
+        jax.tree.map(placed, params), jax.tree.map(placed, state), caches,
+        vec, vec).compile()
+    text = compiled.as_text()
+    for name in ("ff_flash_decode", "ff_kda_decode", "ff_grouped_matmul"):
+        assert chip_smoke.has_kernel(text, name), name
+    assert chip_smoke.cache_or_state_relayouts(text, caches) == []
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
+    ins, outs = layout.split(")->(")
+    kv = f"bf16[{slots},8,128,{seq}]{{3,2,1,0:T(8,128)(2,1)}}"
+    st = f"f32[{slots},64,128,128]{{3,2,1,0:T(8,128)}}"
+    assert ins.count(kv) == outs.count(kv) == 2
+    assert ins.count(st) == outs.count(st) == 1
+    assert "concatenate" not in "".join(
+        l for l in text.splitlines() if "bf16[4096,24576]" in l)
 
 
 _CACHE = (48, 1024, 16, 64)
@@ -469,6 +577,9 @@ _TINY = chip_smoke.Sizes(
     serve_latent=("--model-config", "deepseek-v3-tiny", "--max-seq", "128",
                   "--max-batch", "2", "--requests", "3", "--max-new", "6",
                   "--prompt-len", "20:60", "--buckets", "128"),
+    serve_solar=("--model-config", "solar-open2-tiny", "--max-seq", "128",
+                 "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                 "--prompt-len", "20:60", "--buckets", "128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -499,7 +610,7 @@ def _phases(which):
 
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
-              "serve", "serve/latent"])
+              "serve", "serve/latent", "serve/solar"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

@@ -83,6 +83,19 @@ _ENTRY_POINTS = {
                                     jnp.array([0, 1], jnp.int32), jnp.int32(2), 16,
                                     w_up=jnp.ones((2, 128, 128))),
         _qkv, {"ff_grouped_matmul"}),
+    "flash_decode_grouped": (
+        lambda q: pk.flash_decode(jnp.ones((1, 4, 128)), jnp.ones((1, 2, 128)), jnp.ones((1, 2, 128)),
+                                  jnp.ones((1, 2, 128, 128)), jnp.ones((1, 2, 128, 128)),
+                                  jnp.array([5], jnp.int32), positions_last=True),
+        _qkv, {"ff_flash_decode"}),
+    "kda_chunk": (
+        lambda q: pk.kda_chunk(*(jnp.ones((64, 1, 128)),) * 3, -jnp.ones((64, 1, 128)),
+                               jnp.ones((64, 1)), jnp.zeros((1, 128, 128))),
+        _qkv, {"ff_kda_intra", "ff_kda_chunk"}),
+    "kda_decode": (
+        lambda q: pk.kda_decode(*(jnp.ones((1, 2, 128)),) * 3, -jnp.ones((1, 2, 128)),
+                                jnp.ones((1, 2)), jnp.zeros((1, 2, 128, 128))),
+        _qkv, {"ff_kda_decode"}),
     "gather_rows": (lambda t: pk.gather_rows(*t), _rows, {"ff_gather_rows"}),
     "scatter_add_rows": (lambda t: pk.scatter_add_rows(t[0], t[1], jnp.ones((8, 128))), _rows,
                          {"ff_scatter_add_rows"}),
