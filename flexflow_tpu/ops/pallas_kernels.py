@@ -37,6 +37,8 @@ _NEG_INF = -1e30
 # Per-query scalars (lse, delta) carry this many broadcast lanes so
 # their pallas blocks meet the TPU tiling constraints.
 LSE_LANES = 8
+#: Lanes of a vector register, the minor edge of the chip's (8, 128) tile.
+_LANES = 128
 
 
 def _interpret_default() -> bool:
@@ -54,22 +56,22 @@ def _mxu_precision(dtype):
 
 
 def _block_target_from_env() -> int:
-    """FF_FLASH_BLOCK tuning knob, sanitized: non-numeric falls back to
-    512, anything else clamps to a multiple of 8 >= 8 (the block rule
-    _pick_block enforces — an unaligned target would silently disable
-    the kernel for every t > target)."""
-    raw = os.environ.get("FF_FLASH_BLOCK", "512")
+    """FF_FLASH_BLOCK tuning knob, sanitized: a ceiling on the block
+    ``_vmem_block_cap``'s table gives (unset: the table's own largest);
+    non-numeric falls back to that, anything else clamps to a multiple
+    of 8 >= 8 (the block rule _pick_block enforces — an unaligned
+    target would silently disable the kernel for every t > target)."""
+    raw = os.environ.get("FF_FLASH_BLOCK", "1024")
     try:
         t = int(raw)
     except ValueError:
-        return 512
+        return 1024
     return max(8, t - t % 8)
 
 
-#: Flash block-size target (q and k block edge).  Round-4 v5e sweep at
-#: (b16, h8, t2048, hd64): fwd 10.08/10.73/5.59 ms and fwd+bwd
-#: 29.51/19.44/13.30 ms for blocks 128/256/512 — bigger blocks amortize
-#: the streaming-softmax corrections; 1024 exceeds scoped VMEM.
+#: Ceiling on the flash block (q and k block edge, always equal); the
+#: edge itself, and the chip readings it rests on, are
+#: ``_vmem_block_cap``'s.
 _BLOCK_TARGET = _block_target_from_env()
 
 
@@ -87,37 +89,48 @@ def _pick_block(t: int, target: int = _BLOCK_TARGET) -> int:
 
 
 def _vmem_block_cap(t: int, hd: int, itemsize: int) -> int:
-    """Largest block edge whose kernels fit the 16 MB scoped-VMEM
-    limit, from a v5e compile matrix (round 4) keyed on the size of
-    one resident (t, hd) operand, ``u = t*hd*itemsize``:
+    """Largest block edge for ``(t, hd, itemsize)``, keyed on ``t``, the
+    bytes of an operand's row and the size of one resident (t, hd)
+    operand, ``u = t*hd*itemsize``:
 
-        u <= 512K (bf16 t<=4096 / f32 t<=2048 at hd=64): block 512 ok;
-          1024 OOMs (15.7M+ scoped) and is 2.2x slower per the sweep.
-        u <= 1M (bf16 t=8192 / f32 t=4096): 512 OOMs (16.2-21M),
-          256 compiles.
-        u = 2M (bf16 t=16384, f32 t=8192): every block OOMs (16.5-24M;
-          scoped use GROWS as blocks shrink — the pipeline's resident
-          copies dominate, not block scratch) -> unsupported; such
-          shapes belong on ring attention (sequence-sharded chunks),
-          not a single kernel launch.
+        t <= 2048 in whole lane tiles, at most 256 bytes a row of the
+          operand (bf16 at hd <= 128, f32 at hd 64): block 1024.  Up to
+          t 1024 the whole sequence is one block: no streaming loop, no
+          rescaling, one static walk a head.
+        u <= 1M (bf16 t <= 8192 / f32 t <= 4096 at hd 64): block 512.
+        u = 2M (bf16 t=16384, f32 t=8192): unsupported; such shapes
+          belong on the chunked launches or ring attention.
 
-    Analytic models (resident operands x double-buffering + block
-    scratch) under-predicted the measured scoped sizes by 2-3x, so
-    this is deliberately a measured table, not a formula.  The matrix
-    was measured at hd=64; per-block scratch scales with hd, so the
-    caps shrink proportionally for larger head dims (conservative —
-    unmeasured territory must fail toward smaller blocks, not Mosaic
-    compile errors)."""
+    A bigger block streams more rows past each set of matrix-unit
+    weights and pays a grid step's prologue and epilogue less often.
+    v5e, bf16 causal, ms a call forward / dq / dkv by block (my chip
+    runs, PR 34; PERF.md section 6 has every reading):
+    (bh 128, t 1024, hd 64) 1024: 0.352 / 0.374 / 0.494, 512: 0.429 /
+    0.440 / 0.507, 256: 1.015 / 0.724 / 0.713;
+    (64, 2048, 64) 1024: 0.585 / 0.634 / 0.824, 512: 0.731 / 0.710 /
+    0.901; (64, 2048, 128) 1024: 0.586 / 0.626 / 0.819, 512: 0.723 /
+    0.702 / 0.892, 256: 1.834 / 1.172 / 1.159;
+    (16, 8192, 64) 512: 2.509 / 2.469 / 3.216, 256: 6.731 / 4.148 /
+    4.073, 1024 runs out of scoped VMEM (16.5M of 16M);
+    (16, 4096, 128) 512: 0.672 / 0.681 / 0.833, 256: 1.736 / 1.114 /
+    1.071; f32 (64, 1024, 64) 1024: 0.178 / 0.202 / 0.241, 512: 0.211
+    / 0.231 / 0.294; f32 (16, 4096, 64) 512: 0.632 / 0.650 / 0.870,
+    256: 1.867 / 1.058 / 1.088.  f32 at t 2048 takes 1024 by the rule
+    and compiles for the described chip; it was not timed.
+
+    Read at hd 64 and 128; the caps shrink proportionally for larger
+    head dims (unmeasured territory must fail toward smaller blocks,
+    not Mosaic compile errors)."""
     u = t * hd * itemsize
 
     def scaled(cap: int) -> int:
-        b = max(8, (cap * 64 // max(hd, 64)) // 8 * 8)
+        b = max(8, (cap * _LANES // max(hd, _LANES)) // 8 * 8)
         return min(_BLOCK_TARGET, b)
 
-    if u <= 512 * 1024:
-        return scaled(512)  # 512 = measured ceiling at hd=64
+    if t <= 2048 and t % _LANES == 0 and hd * itemsize <= 256:
+        return scaled(1024)
     if u <= 1024 * 1024:
-        return scaled(256)
+        return scaled(512)
     return 0
 
 
@@ -155,67 +168,219 @@ def flash_supported(shape: Tuple[int, ...], dtype=jnp.float32) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# the walk: which scores a call computes
+# ---------------------------------------------------------------------------
+
+
+def _diag_walk(block: int):
+    """How a block that straddles the causal diagonal is visited: the
+    edge of its square sub-blocks and the sub-blocks ``(row0, col0,
+    masked)`` themselves, in block-local positions.  With ``block_q ==
+    block_k`` such a block is always the same lower triangle, so the
+    walk is static: sub-blocks of the lane tile (a block that is not
+    whole lane tiles is its own sub-block), the ones below the diagonal
+    unmasked, the ones on it under the one triangular mask, the ones
+    above it not visited at all."""
+    sub = _LANES if block % _LANES == 0 else block
+    return sub, tuple(
+        (r, c, r == c)
+        for r in range(0, block, sub)
+        for c in range(0, r + sub, sub)
+    )
+
+
+def _row_tile(rows: int) -> int:
+    """Rows of scores normalised at a time: 32 rows of a 512-wide block
+    are 16 of the 64 vector registers."""
+    for r in (32, 16, 8):
+        if rows % r == 0:
+            return r
+    return rows
+
+
+def _tri_mask(x, first_row, lower):
+    """``x``, rows ``first_row...`` of a square sub-block on the
+    diagonal, with the scores the causal mask drops at ``_NEG_INF``:
+    column <= row kept in a lower triangle, column >= row in ``dkv``'s
+    transposed one."""
+    row = first_row + lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    col = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((col <= row) if lower else (col >= row), x, _NEG_INF)
+
+
+def _nt(a, b):
+    """``a · bᵀ`` into float32 (contract the minor dimension of both)."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """``a · b`` into float32."""
+    return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _cat(xs, axis):
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=axis)
+
+
+def _visit(shape, lhs, rhs_refs, r0, tile_math, outs, first):
+    """One block of scores, in one basic block of the kernel: the
+    products that make them, the rows' arithmetic, the products that
+    consume them.
+
+    ``shape``: ``"full"`` (every score live), ``"lower"`` (the block
+    straddles the diagonal; row >= column live) or ``"upper"`` (the
+    same block seen by ``dkv``, whose rows are K positions: column >=
+    row live).  ``lhs``: the resident operands ``(block, hd)``, one a
+    score matrix; ``rhs_refs``: where their partners' rows ``r0...``
+    lie; the first pair makes the scores the mask applies to.
+    ``tile_math(at, c0, c1, *scores)`` turns a tile of rows
+    ``at`` of every score matrix, columns ``[c0, c1)`` of the block,
+    into the operand tiles of the consuming products; ``outs`` pairs
+    each of those with ``(accumulator, rhs_ref)``.
+
+    A straddling block goes by sub-block COLUMNS on the way in (a
+    column is one set of matrix-unit weights, streamed by the rows that
+    see it: rows above a sub-block's first live row take no part) and
+    by sub-block ROWS from there on: a row's live columns are
+    normalised at once and consumed in one product.  Values, not
+    scratch: emitted a row tile at a time, a tile's chain runs from the
+    matrix unit's result registers back into its operand registers, and
+    what does not fit the 64 vector registers the compiler spills (an
+    array-wide op at a time, the old order, kept 256 registers of
+    scores alive through six passes: a store a bundle was the pace,
+    PERF.md §6 PR 34)."""
+    block = lhs[0].shape[0]
+    if shape == "full":
+        sub, cells = block, {(0, 0): False}
+    else:
+        # (row strip, column) -> masked, in sub-blocks; dkv sees the
+        # walk transposed
+        sub, walk = _diag_walk(block)
+        cells = {((r // sub, c // sub) if shape == "lower"
+                  else (c // sub, r // sub)): masked
+                 for r, c, masked in walk}
+    n = block // sub
+    lower = shape != "upper"
+    # scores by sub-block column: one product of the rows that see it
+    first_seen = [min(i for i, jj in cells if jj == j) for j in range(n)]
+    last_seen = [max(i for i, jj in cells if jj == j) for j in range(n)]
+    cols = [[_nt(x[first_seen[j] * sub:(last_seen[j] + 1) * sub],
+                 ref[0, pl.ds(r0 + j * sub, sub), :])
+             for j in range(n)]
+            for x, ref in zip(lhs, rhs_refs)]
+    tile = _row_tile(sub)
+    for i in range(n):
+        live = [j for j in range(n) if (i, j) in cells]
+        c0, c1 = live[0] * sub, (live[-1] + 1) * sub
+        operands = []
+        for first_row in range(0, sub, tile):
+            row = i * sub + first_row
+            scores = []
+            for which, by_col in enumerate(cols):
+                parts = []
+                for j in live:
+                    at = row - first_seen[j] * sub
+                    x = by_col[j][at:at + tile]
+                    if which == 0 and cells[i, j]:
+                        x = _tri_mask(x, first_row, lower)
+                    parts.append(x)
+                scores.append(_cat(parts, 1))
+            operands.append(
+                tile_math(slice(row, row + tile), c0, c1, *scores))
+        strip = slice(i * sub, (i + 1) * sub)
+        for k, (acc, ref) in enumerate(outs):
+            got = _nn(_cat([o[k] for o in operands], 0),
+                      ref[0, pl.ds(r0 + c0, c1 - c0), :])
+            if first:
+                acc[strip, :] = got
+            else:
+                acc[strip, :] += got
+
+
+def _block_order(causal, diag, i, n):
+    """The blocks grid step ``i`` of ``n`` visits (``i`` a host integer
+    or the kernel's program id): ``(shape, first, lo, hi)``, the shape
+    and index of the block visited first, then the whole, unmasked
+    blocks ``[lo, hi)``.  Causal: the block on the diagonal first, by
+    its static walk (``diag``: ``"lower"``, or ``"upper"`` for
+    ``dkv``), then the live ones off it: below it for a query block,
+    after it for a K block; the dead ones not at all."""
+    if not causal:
+        return "full", 0, 1, n
+    lo, hi = (0, i) if diag == "lower" else (i + 1, n)
+    return diag, i, lo, hi
+
+
+def _each_block(causal, diag, i, n, visit):
+    """``_block_order``'s blocks through ``visit(shape, block, first)``.
+    The first visit starts the accumulators, so nothing is zeroed; a
+    sequence of one block has no loop."""
+    shape, b0, lo, hi = _block_order(causal, diag, i, n)
+    visit(shape, b0, True)
+    if n > 1:
+        lax.fori_loop(
+            lo, hi, lambda b, c: (visit("full", b, False), c)[1], 0)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, causal, scale):
     qi = pl.program_id(1)
-    # Dots run in the INPUT dtype with f32 accumulation (bf16 inputs
-    # hit the MXU at bf16 rate; scale applies post-dot, in f32).
-    q = q_ref[0]                                        # (bq, hd)
-    block_q, hd = q.shape
-    seq_k = k_ref.shape[1]
-    num_kb = seq_k // block_k
+    block = q_ref.shape[1]
+    num_kb = k_ref.shape[1] // block
+    # Products run in the INPUT dtype with f32 accumulation; the scale
+    # is applied to the f32 scores, behind the product.
+    q = q_ref[0]
+    lanes = l_scr.shape[1]
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, hd), jnp.float32)
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    def row_sum(p):
+        if lanes == 1:
+            return jnp.sum(p, axis=-1, keepdims=True)
+        # The row sum stays a lane-wide partial through the call
+        # (elementwise adds); lanes merge once, at the end: one lane
+        # reduction a tile (the max), not two.
+        return functools.reduce(
+            jnp.add, [p[:, c:c + lanes] for c in range(0, p.shape[1], lanes)])
 
-    def make_body(masked):
-        def body(kb, carry):
-            m, l, acc = carry
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                   # (bq, bk) f32
-            if masked:
-                k_pos = kb * block_k + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1
-                )
-                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            acc = acc * corr + lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            return m_new, l, acc
+    def softmax(first):
+        def tile_math(at, c0, c1, s):
+            s = s * scale
+            m_new = jnp.max(s, axis=-1, keepdims=True)
+            if first:
+                # The first block a grid step visits starts the
+                # streaming softmax: nothing to rescale.
+                p = jnp.exp(s - m_new)
+                l_scr[at, :] = row_sum(p)
+            else:
+                m = m_scr[at, :]
+                m_new = jnp.maximum(m, m_new)
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l_scr[at, :] = l_scr[at, :] * corr + row_sum(p)
+                acc_scr[at, :] = acc_scr[at, :] * corr
+            m_scr[at, :] = m_new
+            return (p.astype(q.dtype),)
 
-        return body
+        return tile_math
 
-    if causal:
-        # The streaming loop splits at the diagonal: blocks fully
-        # below it need no mask (skipping the per-block iota/compare/
-        # select — pure VPU overhead on every interior block), the
-        # 1-2 diagonal-straddling blocks run masked, and blocks
-        # strictly above contribute nothing.
-        full_upper = lax.div(qi * block_q, block_k)
-        upper = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        upper = jnp.minimum(upper, num_kb)
-        carry = lax.fori_loop(0, full_upper, make_body(False), (m0, l0, acc0))
-        m, l, acc = lax.fori_loop(full_upper, upper, make_body(True), carry)
-    else:
-        m, l, acc = lax.fori_loop(0, num_kb, make_body(False), (m0, l0, acc0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    def visit(shape, kb, first):
+        _visit(shape, (q,), (k_ref,), pl.multiple_of(kb * block, block),
+               softmax(first), ((acc_scr, v_ref),), first)
+
+    _each_block(causal, "lower", qi, num_kb, visit)
+    l = l_scr[...]
+    if lanes > 1:
+        l = jnp.sum(l, axis=-1, keepdims=True)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
     # lse is stored with a trailing lane dim of LSE_LANES (broadcast
     # copies) so its blocks satisfy the TPU (8, 128)-or-full tile rule.
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, LSE_LANES))
+    lse_ref[0] = jnp.broadcast_to(m_scr[...] + jnp.log(l), (block, LSE_LANES))
 
 
 # ---------------------------------------------------------------------------
@@ -224,122 +389,55 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *, block_k, causal, scale):
+               dq_scr, *, causal, scale):
     qi = pl.program_id(1)
+    block = q_ref.shape[1]
+    num_kb = k_ref.shape[1] // block
     q = q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0, :, 0:1]                            # (bq, 1)
-    delta = delta_ref[0, :, 0:1]                        # (bq, 1)
-    block_q, hd = q.shape
-    seq_k = k_ref.shape[1]
-    num_kb = seq_k // block_k
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
-    def make_body(masked):
-        def body(kb, dq):
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if masked:
-                k_pos = kb * block_k + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1
-                )
-                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-            p = jnp.exp(s - lse)                        # (bq, bk) f32
-            dp = lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = p * (dp - delta)
-            return dq + lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+    def tile_math(at, c0, c1, s, dp):
+        p = jnp.exp(s * scale - lse_ref[0, at, 0:1])
+        ds = p * (dp - delta_ref[0, at, 0:1])
+        return (ds.astype(q.dtype),)
 
-        return body
+    def visit(shape, kb, first):
+        _visit(shape, (q, do), (k_ref, v_ref),
+               pl.multiple_of(kb * block, block),
+               tile_math, ((dq_scr, k_ref),), first)
 
-    dq0 = jnp.zeros((block_q, hd), jnp.float32)
-    if causal:
-        # Unmasked below-diagonal blocks, masked diagonal straddlers
-        # (same split as the forward kernel).
-        full_upper = lax.div(qi * block_q, block_k)
-        upper = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        upper = jnp.minimum(upper, num_kb)
-        dq = lax.fori_loop(0, full_upper, make_body(False), dq0)
-        dq = lax.fori_loop(full_upper, upper, make_body(True), dq)
-    else:
-        dq = lax.fori_loop(0, num_kb, make_body(False), dq0)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    _each_block(causal, "lower", qi, num_kb, visit)
+    dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_q, causal, scale):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, causal, scale):
+    """The K block's rows against every query block at or below it, on
+    the TRANSPOSED scores ``k · qᵀ`` (``lse`` and ``delta`` come
+    lane-major, a row of a query block's positions): ``pᵀ · do`` and
+    ``dsᵀ · q`` are then plain products.  Contracting dimension 0 of a
+    score tile made Mosaic transpose it on the cross-lane unit (128
+    ``vxpose`` a block in the lowered kernel, PERF.md §6 PR 34)."""
     ki = pl.program_id(1)
-    k = k_ref[0]                                        # (bk, hd)
+    block = k_ref.shape[1]
+    num_qb = q_ref.shape[1] // block
+    k = k_ref[0]
     v = v_ref[0]
-    block_k, hd = k.shape
-    seq_q = q_ref.shape[1]
-    num_qb = seq_q // block_q
-    k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
-    def make_body(masked):
-        def body(qb, carry):
-            dk, dv = carry
-            q = q_ref[0, pl.ds(qb * block_q, block_q), :]
-            do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-            lse = lse_ref[0, pl.ds(qb * block_q, block_q), 0:1]
-            delta = delta_ref[0, pl.ds(qb * block_q, block_q), 0:1]
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                   # (bq, bk)
-            if masked:
-                q_pos = qb * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
-                )
-                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-            p = jnp.exp(s - lse)
-            dv = dv + lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dp = lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = p * (dp - delta)                       # (bq, bk)
-            dk = dk + lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return dk, dv
+    def visit(shape, qb, first):
+        def tile_math(at, c0, c1, s, dp):
+            p = jnp.exp(s * scale - lse_ref[0, qb, :, c0:c1])
+            ds = p * (dp - delta_ref[0, qb, :, c0:c1])
+            return p.astype(k.dtype), ds.astype(k.dtype)
 
-        return body
+        _visit(shape, (k, v), (q_ref, do_ref),
+               pl.multiple_of(qb * block, block),
+               tile_math, ((dv_scr, do_ref), (dk_scr, q_ref)), first)
 
-    zeros = (
-        jnp.zeros((block_k, hd), jnp.float32),
-        jnp.zeros((block_k, hd), jnp.float32),
-    )
-    if causal:
-        # Query blocks entirely above this K block see none of it;
-        # blocks straddling the diagonal run masked; blocks fully
-        # below the diagonal need no mask.
-        lower = lax.div(ki * block_k, block_q)
-        first_full = lax.div(
-            (ki + 1) * block_k + block_q - 2, block_q
-        )
-        first_full = jnp.clip(first_full, lower, num_qb)
-        carry = lax.fori_loop(lower, first_full, make_body(True), zeros)
-        dk, dv = lax.fori_loop(first_full, num_qb, make_body(False), carry)
-    else:
-        dk, dv = lax.fori_loop(0, num_qb, make_body(False), zeros)
-    # ds·q still needs the ∂s/∂k = scale·q factor (q is no longer
-    # pre-scaled; s scales post-dot).
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    _each_block(causal, "upper", ki, num_qb, visit)
+    # ds . q still needs the ds/dk = scale . q factor.
+    dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -347,27 +445,46 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
+def _score_lanes(block: int) -> int:
+    """Lanes the forward's running row sum is carried on."""
+    return _LANES if block % _LANES == 0 else 1
+
+
 def _fwd_call(q, k, v, causal, interpret):
+    _, t, hd = q.shape
+    return _fwd_launch(q, k, v, causal, interpret,
+                       _require_block(t, hd, q.dtype.itemsize))
+
+
+# The launches are jitted: one trace and one lowering a shape, however
+# many layers call it (a kernel's walk is unrolled at trace time, a
+# thousand operations at the training cell's shape, and an enclosing
+# program re-lowers every un-jitted call site: gpt2-medium's step has
+# 72 of them, twice).  The block is a static argument, so the cache's
+# key carries what the table chose.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _fwd_launch(q, k, v, causal, interpret, block):
     bh, t, hd = q.shape
-    block_q = _require_block(t, hd, q.dtype.itemsize)
-    block_k = block_q
     scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(
-        _fwd_kernel, block_k=block_k, causal=causal, scale=scale
-    )
+    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale)
     full = pl.BlockSpec((1, t, hd), lambda b, i: (b, 0, 0))
-    blocked = pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0))
+    blocked = pl.BlockSpec((1, block, hd), lambda b, i: (b, i, 0))
     return pl.pallas_call(
         kernel,
-        grid=(bh, t // block_q),
+        grid=(bh, t // block),
         in_specs=[blocked, full, full],
         out_specs=[
             blocked,
-            pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block, LSE_LANES), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
             jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block, 1), jnp.float32),            # m
+            pltpu.VMEM((block, _score_lanes(block)), jnp.float32),  # l
+            pltpu.VMEM((block, hd), jnp.float32),           # acc
         ],
         name="ff_flash_fwd",
         interpret=interpret,
@@ -736,38 +853,53 @@ def _bwd_stream_call(q, k, v, do, lse, delta, causal, interpret,
 
 
 def _bwd_call(q, k, v, do, lse, delta, causal, interpret):
+    _, t, hd = q.shape
+    return _bwd_launch(q, k, v, do, lse, delta, causal, interpret,
+                       _require_block(t, hd, q.dtype.itemsize))
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _bwd_launch(q, k, v, do, lse, delta, causal, interpret, block):
     bh, t, hd = q.shape
-    block_q = _require_block(t, hd, q.dtype.itemsize)
-    block_k = block_q
+    nb = t // block
     scale = 1.0 / math.sqrt(hd)
+    static = dict(causal=causal, scale=scale)
     full = pl.BlockSpec((1, t, hd), lambda b, i: (b, 0, 0))
-    full_r = pl.BlockSpec((1, t, LSE_LANES), lambda b, i: (b, 0, 0))
-    q_blocked = pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0))
-    q_blocked_r = pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i: (b, i, 0))
-    k_blocked = pl.BlockSpec((1, block_k, hd), lambda b, i: (b, i, 0))
+    blocked = pl.BlockSpec((1, block, hd), lambda b, i: (b, i, 0))
+    blocked_r = pl.BlockSpec((1, block, LSE_LANES), lambda b, i: (b, i, 0))
+    grad = pltpu.VMEM((block, hd), jnp.float32)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, causal=causal, scale=scale),
-        grid=(bh, t // block_q),
-        in_specs=[q_blocked, full, full, q_blocked, q_blocked_r, q_blocked_r],
-        out_specs=q_blocked,
+        functools.partial(_dq_kernel, **static),
+        grid=(bh, nb),
+        in_specs=[blocked, full, full, blocked, blocked_r, blocked_r],
+        out_specs=blocked,
         out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
+        scratch_shapes=[grad],
         name="ff_flash_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    # dkv walks the transposed scores: its per-query scalars lie along
+    # the lanes, a row a query block (a slice and a reshape here: two
+    # small relayouts a call, 1.3 ms of a gpt2-medium step's `copy`).
+    def lane_major(x):
+        return x[:, :, 0].reshape(bh, nb, 1, block)
+
+    full_r = pl.BlockSpec((1, nb, 1, block), lambda b, i: (b, 0, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, causal=causal, scale=scale),
-        grid=(bh, t // block_k),
-        in_specs=[full, k_blocked, k_blocked, full, full_r, full_r],
-        out_specs=[k_blocked, k_blocked],
+        functools.partial(_dkv_kernel, **static),
+        grid=(bh, nb),
+        in_specs=[full, blocked, blocked, full, full_r, full_r],
+        out_specs=[blocked, blocked],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, hd), k.dtype),
             jax.ShapeDtypeStruct((bh, t, hd), v.dtype),
         ],
+        scratch_shapes=[grad, grad],
         name="ff_flash_dkv",
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lane_major(lse), lane_major(delta))
     return dq, dk, dv
 
 
@@ -1111,7 +1243,6 @@ def flash_attention_lse_chunked(q, k, v, causal: bool = True,
 # ``_einsum_decode`` in ops/attention.py stays the numerics oracle and
 # the fallback).
 
-_LANES = 128
 #: VMEM the kernel plans for and the limit it asks for (v5e has
 #: 128 MiB; a kernel gets 16 MB unless it asks): the pipelined K and V
 #: blocks (two arrays, double buffered) take what the per-slot

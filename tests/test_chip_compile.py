@@ -166,7 +166,8 @@ def _rows(kind, shape, n_ids, addressing):
 #: hd 64 and its 4096 x 32768 logits; serve's 8 x 512 x 8 x 64 cache and
 #: the ``gpt2m.serve.closed48`` cell's 48 x 1024 x 16 x 64;
 #: DLRM's 4 stacked 1M-row d=64 tables, whose last 128-row block is
-#: partial), plus the long-context flash shape, the largest decode
+#: partial), plus the long-context flash shape, the ``gpt2m.train.b8s1024``
+#: cell's flash shape, the largest decode
 #: shape ISSUE 21 names, and the row kernels in both addressings: the
 #: ``dlrm.random.b1024`` cell's 8 x 2M x 64 at 8192 ids, 2-D narrow
 #: tables, and the row-major widths (128, and GPT-2's 1024).
@@ -175,6 +176,9 @@ CASES = {
     "flash_grad-8x8x512x64-bf16": lambda: _flash((8, 8, 512, 64), BF16, True),
     "flash_fwd-2x8x8192x64-bf16": lambda: _flash((2, 8, 8192, 64), BF16, False),
     "flash_grad-2x8x8192x64-bf16": lambda: _flash((2, 8, 8192, 64), BF16, True),
+    # The gpt2m.train.b8s1024 cell's own shape: batch 8, 16 heads of 64.
+    "flash_fwd-8x16x1024x64-bf16": lambda: _flash((8, 16, 1024, 64), BF16, False),
+    "flash_grad-8x16x1024x64-bf16": lambda: _flash((8, 16, 1024, 64), BF16, True),
     "xent_fwd-4096x32768-bf16": lambda: _xent(4096, 32768, BF16, False),
     "xent_grad-4096x32768-bf16": lambda: _xent(4096, 32768, BF16, True),
     "decode-8x512x8x64-f32": lambda: _decode(8, 512, 8, 64, F32),

@@ -139,16 +139,164 @@ def test_flash_supported_gating():
 
 
 def test_flash_block_vmem_cap():
-    # Long-context bf16 stays supported but with a reduced block
-    # (v5e compile matrix: 512 OOMs scoped VMEM at t=8192, 256
-    # compiles); f32 at the same u=2M operand size fails every block
-    # and must be gated off entirely (ring attention covers it).
+    # Long-context bf16 stays supported at the streaming block (512:
+    # PR 34's kernels keep a row tile of a block alive, not the block,
+    # and fit scoped VMEM there; the kernels before it needed 256 at
+    # t=8192); f32 at the u=2M operand size stays gated off entirely
+    # (ring attention covers it).
     assert pk.flash_supported((1, 1, 8192, 64), jnp.bfloat16)
-    assert pk._flash_block(8192, 64, 2) == 256
+    assert pk._flash_block(8192, 64, 2) == 512
+    # Block 1024 up to 2048 positions in whole lane tiles (one block a
+    # sequence up to 1024), at a head width of 128 as at 64; wider
+    # heads, unread on the chip, shrink the block.
+    assert pk._flash_block(1024, 64, 2) == 1024
+    assert pk._flash_block(1024, 128, 2) == 1024
+    assert pk._flash_block(2048, 128, 2) == 1024
+    assert pk._flash_block(4096, 128, 2) == 512
+    assert pk._flash_block(2048, 256, 2) == 256
     assert not pk.flash_supported((1, 1, 16384, 64), jnp.bfloat16)
     assert not pk.flash_supported((1, 1, 8192, 64), jnp.float32)
     # Unaligned short sequences keep their whole-dim single block.
     assert pk._flash_block(100, 64, 4) == 100
+
+
+# -- the walk, and the cell's geometry ----------------------------------------
+
+
+def _walk(t, block, causal, diag):
+    """Every sub-block ``(q0, k0, rows, cols, masked)`` one head's call
+    computes scores for, from the two host functions the kernels walk
+    by: ``_block_order`` (the blocks a grid step visits) and
+    ``_diag_walk`` (a straddling block's sub-blocks).  ``diag``
+    ``"lower"``: the forward's and ``dq``'s grid steps, a query block
+    each; ``"upper"``: ``dkv``'s, a K block each."""
+    n = t // block
+    sub, cells = pk._diag_walk(block)
+    out = []
+    for i in range(n):
+        shape, first, lo, hi = pk._block_order(causal, diag, i, n)
+        whole = list(range(lo, hi))
+        if shape == "full":
+            whole.insert(0, first)
+        else:
+            assert (shape, first) == (diag, i)
+            out += [(i * block + r, i * block + c, sub, sub, m)
+                    for r, c, m in cells]
+        for b in whole:
+            qb, kb = (i, b) if diag == "lower" else (b, i)
+            out.append((qb * block, kb * block, block, block, False))
+    return out
+
+
+@pytest.mark.parametrize("diag", ["lower", "upper"])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("t", [16, 96, 256, 512, 1024, 2048])
+def test_flash_walk_covers_the_mask_once(t, causal, diag):
+    """The walk as host integers, at the block the gate picks for bf16
+    heads of 64, by query blocks (forward, dq) and by K blocks (dkv):
+    the visited sub-blocks cover every live score exactly once, none
+    lies wholly above the diagonal, only the ones the diagonal crosses
+    are masked, and at t 1024 at least 85% of the computed scores are
+    live (two 512-blocks a side, visited whole, gave 66.7%)."""
+    block = pk._flash_block(t, 64, 2)
+    assert block >= 8 and t % block == 0
+    walk = _walk(t, block, causal, diag)
+    seen = np.zeros((t, t), np.int32)
+    for q0, k0, rows, cols, masked in walk:
+        assert rows > 0 and cols > 0
+        assert q0 + rows <= t and k0 + cols <= t
+        crosses = causal and k0 + cols - 1 > q0      # holds a dead score
+        assert masked == crosses, (q0, k0, rows, cols, masked)
+        if causal:
+            assert k0 <= q0 + rows - 1, "a sub-block wholly above the diagonal"
+        seen[q0:q0 + rows, k0:k0 + cols] += 1
+    live = np.tril(np.ones((t, t), bool)) if causal else np.ones((t, t), bool)
+    assert (seen[live] == 1).all()
+    assert seen.max() == 1
+    if causal and t == 1024:
+        assert live.sum() / seen.sum() >= 0.85
+    # A straddling block's own walk: lane-tile sub-blocks where the
+    # block is whole lane tiles, else the block itself.
+    sub, cells = pk._diag_walk(block)
+    assert sub == (128 if block % 128 == 0 else block)
+    assert len(cells) == (block // sub) * (block // sub + 1) // 2
+
+
+def _grads_and_lse(q, k, v, cot, cot_lse, causal, flash):
+    def loss(q, k, v):
+        if flash:
+            o, lse = pk.flash_attention_lse(q, k, v, causal)
+        else:
+            qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+            scale = 1.0 / np.sqrt(q.shape[-1])
+            s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf,
+                           precision="highest") * scale
+            if causal:
+                t = s.shape[-1]
+                s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+            lse = jax.scipy.special.logsumexp(s, axis=-1)
+            o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vf,
+                           precision="highest")
+        return (jnp.sum(o.astype(jnp.float32) * cot)
+                + jnp.sum(lse * cot_lse)), (o, lse)
+
+    (_, (o, lse)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return o, lse, grads
+
+
+@pytest.mark.parametrize("t", [256, 1024])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_cell_geometry_matches_naive(rng, t, dtype):
+    """Forward, lse and the three gradients at the ``gpt2m.train``
+    cell's geometry in small (2 heads of 64 at t 1024: one block of
+    eight lane-tile sub-blocks a side) and at the 256 serving bucket,
+    against the naive reference, at this file's tolerances."""
+    qf, kf, vf = make_qkv(rng, b=1, h=2, t=t, hd=64)
+    cot = jnp.asarray(rng.standard_normal(qf.shape), jnp.float32)
+    cot_lse = jnp.asarray(rng.standard_normal(qf.shape[:3]), jnp.float32)
+    q, k, v = (x.astype(dtype) for x in (qf, kf, vf))
+    o, lse, grads = _grads_and_lse(q, k, v, cot, cot_lse, True, True)
+    # the reference sees the operands the kernel saw (bf16-rounded)
+    ro, rlse, rgrads = _grads_and_lse(q, k, v, cot, cot_lse, True, False)
+    assert o.dtype == q.dtype and all(g.dtype == q.dtype for g in grads)
+    f32 = dtype == "float32"
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(ro),
+                               atol=2e-5 if f32 else 3e-2)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse),
+                               atol=2e-5 if f32 else 3e-2)
+    for g, rg in zip(grads, rgrads):
+        if f32:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(rg),
+                                       atol=5e-5)
+        else:
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(rg, np.float32),
+                                       atol=0.04, rtol=0.05)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("block", [64, 128, 256])
+def test_flash_launch_at_every_block_matches_the_tables(rng, block, causal):
+    """The jitted launches take the block as a static argument (the
+    cache's key carries what the table chose): at t 512 a 64 block (its
+    own sub-block, eight a side), a 128 block (one lane tile) and a 256
+    block (two sub-blocks a side, one interior block) all give what the
+    table's single 512 block gives, forward and backward."""
+    bh, t, hd = 2, 512, 64
+    q, k, v, do = (jnp.asarray(rng.standard_normal((bh, t, hd)),
+                               jnp.float32) for _ in range(4))
+    assert pk._flash_block(t, hd, 4) == t
+    o, lse_l = pk._fwd_call(q, k, v, causal, True)
+    delta_l = jnp.broadcast_to(jnp.sum(o * do, axis=-1)[:, :, None],
+                               (bh, t, pk.LSE_LANES))
+    ref = (o, lse_l) + tuple(
+        pk._bwd_call(q, k, v, do, lse_l, delta_l, causal, True))
+    got = tuple(pk._fwd_launch(q, k, v, causal, True, block)) + tuple(
+        pk._bwd_launch(q, k, v, do, lse_l, delta_l, causal, True, block))
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)
 
 
 # -- fused softmax cross-entropy -------------------------------------------
