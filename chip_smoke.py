@@ -80,6 +80,7 @@ class Sizes:
     #: --chips 4: the cross-chip argv of each app; the comparison run
     #: is the same argv on one device (strategy / mesh flags dropped).
     serve_solar: Tuple[str, ...]
+    serve_xing: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -117,6 +118,11 @@ FULL = Sizes(
                  "--max-batch", "4", "--requests", "6", "--max-new", "12",
                  "--prompt-len", "100:200", "--buckets", "256",
                  "--dtype", "bfloat16"),
+    # The latent phase's sizes round four hyper-connected streams.
+    serve_xing=("--model-config", "xing4-smoke", "--max-seq", "256",
+                "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                "--prompt-len", "100:200", "--buckets", "256",
+                "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
     dlrm4=("-b", "1024", "-i", "3", "--momentum", "0", "--wd", "0",
@@ -541,34 +547,47 @@ def check_program_kernels(phase: str, run: ServeRun, caches,
     return step
 
 
-def latent_phase(argv: Sequence[str]) -> None:
-    """The DeepSeek-V3 family's preset through ``apps.serve``: the
-    expert op is in the served graph, the cache is one column of
-    ``kv_rank + rope`` values a token a layer, prefill compiles the
-    expanded path and decode the absorbed one, each with its kernel."""
+def latent_phase(argv: Sequence[str], phase: str = "serve/latent",
+                 streams: int = 0) -> None:
+    """A DeepSeek-V3 family preset through ``apps.serve``: the expert op
+    is in the served graph, the cache is one column of ``kv_rank + rope``
+    values a token a layer, prefill compiles the expanded path and
+    decode the absorbed one, each with its kernel.  With ``streams``
+    (the Xing4.0 preset) the residual between the blocks is that many
+    hyper-connected streams, which keep nothing for a slot, and every
+    superstep reports the Sinkhorn rounds' defect."""
+    from flexflow_tpu.ops import HyperConnectionPost
     from flexflow_tpu.ops.attention import LatentAttention
 
-    run = serve_run("serve/latent", argv)
+    run = serve_run(phase, argv)
     sex = run.srv.ex
     names = [op.name for op in sex._layers]
     check(any(n.endswith("_moe") for n in names),
-          "serve/latent: the served graph dropped the expert op")
+          f"{phase}: the served graph dropped the expert op")
     attn = sex.attn_ops[0]
-    check(isinstance(attn, LatentAttention), "serve/latent: no latent op")
+    check(isinstance(attn, LatentAttention), f"{phase}: no latent op")
+    posts = [op for op in sex._layers if isinstance(op, HyperConnectionPost)]
+    check(len(posts) == (2 * len(sex.attn_ops) if streams else 0)
+          and all(op.attrs["streams"] == streams for op in posts)
+          and bool(attn.attrs["q_rank"]) == bool(streams),
+          f"{phase}: {len(posts)} hyper-connections round "
+          f"{len(sex.attn_ops)} blocks, q_rank {attn.attrs['q_rank']}")
     caches = sex.init_cache()
     want = (sex.max_batch, attn.row_width, sex.max_seq)
     shapes = {tuple(c.shape) for ents in caches.values() for c in ents.values()}
-    check(shapes == {want} and all(list(e) == ["ckr"] for e in caches.values()),
-          f"serve/latent: cache {shapes}, expected one 'ckr' of {want} a layer")
+    check(shapes == {want} and all(list(e) == ["ckr"] for e in caches.values())
+          and len(caches) == len(sex.attn_ops),
+          f"{phase}: cache {shapes}, expected one 'ckr' of {want} a layer")
     check(sex._attention_paths(False) == "latent_expanded"
           and sex._attention_paths(True) == "latent_absorbed",
-          "serve/latent: prefill is not expanded or decode not absorbed")
+          f"{phase}: prefill is not expanded or decode not absorbed")
     check_program_kernels(
-        "serve/latent", run, caches,
+        phase, run, caches,
         decode=("ff_mla_decode", "ff_grouped_matmul"),
         prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
-    oracle = serve_run("serve/latent-oracle", [*argv, "--no-decode-kernel"])
-    compare_tokens("serve/latent", run, oracle)
+    oracle = serve_run(f"{phase}-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens(phase, run, oracle,
+                   tol=BF16_KERNEL_TOL if streams else None)
 
 
 #: How far a bfloat16 kernel path's logits may lie from the ``jnp``
@@ -750,6 +769,8 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
         ("serve", lambda: serve_phase(sz.serve)),
         ("serve/latent", lambda: latent_phase(sz.serve_latent)),
         ("serve/solar", lambda: solar_phase(sz.serve_solar)),
+        ("serve/xing", lambda: latent_phase(sz.serve_xing, "serve/xing",
+                                            streams=4)),
     ]
 
 

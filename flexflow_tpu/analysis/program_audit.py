@@ -314,6 +314,15 @@ def _delta_graph():
                     _tiny_config(batch_size=8))
 
 
+def _stream_graph():
+    """HyperConnectionPre and HyperConnectionPost round a dense and an
+    expert layer of the Xing4.0 family (a compressed query, YaRN)."""
+    from flexflow_tpu.models.transformer import XING4_TINY, build_lm
+
+    return build_lm({**XING4_TINY, "num_hidden_layers": 2}, 8, 8,
+                    _tiny_config(batch_size=8))
+
+
 def _serving_graph():
     """The graph ServingExecutor is audited on (no MoE: serving drives
     the plain transformer LM, apps/serve.py)."""
@@ -371,6 +380,7 @@ def catalog_models():
         ("nmt", _rnn_graph()),
         ("deepseek_v3", _latent_moe_graph()),
         ("solar_open2", _delta_graph()),
+        ("xing4_0", _stream_graph()),
     ]
 
 

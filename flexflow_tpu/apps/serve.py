@@ -30,6 +30,7 @@ Flags beyond the common set:
                                configuration keys instead (a JSON file,
                                or a preset of models/transformer.py:
                                deepseek-v3-tiny, deepseek-v3-smoke,
+                               xing4-tiny, xing4-smoke,
                                solar-open2-tiny, solar-open2-smoke)
 
 Capacity flags (SERVING.md "Cache layout"):

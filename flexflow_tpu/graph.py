@@ -28,6 +28,8 @@ from flexflow_tpu.ops import (
     Embedding,
     Flat,
     HeteroEmbedding,
+    HyperConnectionPost,
+    HyperConnectionPre,
     KimiDeltaAttention,
     LatentAttention,
     LayerNorm,
@@ -311,7 +313,8 @@ class FFModel:
                          name: Optional[str] = None, **kw) -> TensorSpec:
         """Causal multi-head latent attention (``ops/attention.py``
         ``LatentAttention``: ``kv_rank``, ``nope_dim``, ``rope_dim``,
-        ``v_dim``, ``rope_theta``)."""
+        ``v_dim``, ``rope_theta``; ``q_rank`` for a compressed query,
+        ``rope_scaling`` for YaRN's frequencies)."""
         return self._add(
             LatentAttention(self._unique("latent_attention", name), x,
                             num_heads, **kw)
@@ -325,6 +328,25 @@ class FFModel:
         return self._add(
             KimiDeltaAttention(self._unique("delta_attention", name), x,
                                num_heads, head_dim, **kw)
+        )
+
+    def hyper_connection_pre(self, x: TensorSpec, streams: int,
+                             name: Optional[str] = None, **kw) -> TensorSpec:
+        """What a sublayer reads of an ``streams``-stream residual
+        (``ops/hyper_connection.py`` ``HyperConnectionPre``: ``iters``,
+        ``eps``, ``clamp``); a (batch, seq, dim) ``x`` opens the stream."""
+        return self._add(
+            HyperConnectionPre(self._unique("hc_pre", name), x, streams, **kw)
+        )
+
+    def hyper_connection_post(self, x: TensorSpec, y: TensorSpec, streams: int,
+                              name: Optional[str] = None, **kw) -> TensorSpec:
+        """The stream ``x`` after a sublayer's output ``y`` is written
+        back (``HyperConnectionPost``); ``close=True`` sums the streams
+        into (batch, seq, dim)."""
+        return self._add(
+            HyperConnectionPost(self._unique("hc_post", name), x, y, streams,
+                                **kw)
         )
 
     def rms_norm(self, x: TensorSpec, name: Optional[str] = None, **kw) -> TensorSpec:

@@ -90,16 +90,38 @@ def _gpt2_lm(ff: FFModel, tok, m: Dict[str, Any]):
     return ff.dense(x, m["vocab_size"], name="lm_head")
 
 
+def _residual(ff: FFModel, m: Dict[str, Any], x, i: int, k: int, sublayer,
+              close: bool = False):
+    """Sublayer ``k`` (1 attention, 2 feed-forward) of block ``i`` round
+    the residual path: ``x + sublayer(x)``, or where the configuration
+    carries ``hc_mult`` streams the hyper-connection pair
+    (``ops/hyper_connection.py``): the sublayer reads a mixture of the
+    streams and is written back into all of them.  The first pair opens
+    the stream from the table's row; ``close`` sums it for the last norm."""
+    n = m.get("hc_mult")
+    if not n:
+        return ff.add(x, sublayer(x), name=f"blk{i}_res{k}")
+    hc = dict(iters=m["hc_sinkhorn_iters"], eps=m["hc_eps"],
+              clamp=(m["mhc_h_res_clamp_min"], m["mhc_h_res_clamp_max"]))
+    u = ff.hyper_connection_pre(x, n, name=f"blk{i}_hc{k}_pre", **hc)
+    return ff.hyper_connection_post(x, sublayer(u), n, close=close,
+                                    name=f"blk{i}_hc{k}_post", **hc)
+
+
 def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
     """The DeepSeek-V3 block family (``transformers``' ``DeepseekV3``):
     RMSNorm, latent attention with rotary positions on a sub-width of
-    the head, ``first_k_dense_replace`` leading gated-SiLU dense layers,
-    then expert layers under a sigmoid top-k router with a selection
-    bias and shared experts; no bias anywhere, an untied head.
+    the head (the query compressed too under ``q_lora_rank``, the
+    positions YaRN's under ``rope_scaling``), ``first_k_dense_replace``
+    leading gated-SiLU dense layers, then expert layers under a sigmoid
+    top-k router with a selection bias and shared experts; no bias
+    anywhere, an untied head.  ``model_type`` ``xing4_0`` is the same
+    block round a residual of ``hc_mult`` streams (``_residual``); its
+    multi-token-prediction module (``num_nextn_predict_layers``) belongs
+    to training and to self-drafting and is not built.
     ``held_experts`` (not a key of the source: the deployment's) names
     the routed experts this chip holds, default all."""
-    for key, want in (("q_lora_rank", None), ("rope_scaling", None),
-                      ("n_group", 1), ("topk_group", 1),
+    for key, want in (("n_group", 1), ("topk_group", 1),
                       ("moe_layer_freq", 1), ("attention_bias", False),
                       ("hidden_act", "silu"), ("rope_interleave", True),
                       ("tie_word_embeddings", False)):
@@ -110,28 +132,31 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
     if m.get("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
         raise ValueError(f"scoring_func {m['scoring_func']!r}")
     d, eps = m["hidden_size"], m["rms_norm_eps"]
+    layers = m["num_hidden_layers"]
     # A table in the compute dtype (the family policy keeps it f32 for
     # the sparse-update kernels, which this family does not train with).
     x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
                           dtype=jnp.dtype(ff.config.compute_dtype))
-    for i in range(m["num_hidden_layers"]):
-        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
-        a = ff.latent_attention(
-            a, m["num_attention_heads"], kv_rank=m["kv_lora_rank"],
-            nope_dim=m["qk_nope_head_dim"], rope_dim=m["qk_rope_head_dim"],
-            v_dim=m["v_head_dim"], rope_theta=m["rope_theta"], norm_eps=eps,
-            name=f"blk{i}_attn")
-        x = ff.add(x, a, name=f"blk{i}_res1")
-        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
-        if i < m["first_k_dense_replace"]:
-            g = ff.dense(h, m["intermediate_size"], activation="silu",
-                         use_bias=False, name=f"blk{i}_mlp_gate")
-            u = ff.dense(h, m["intermediate_size"], use_bias=False,
-                         name=f"blk{i}_mlp_up")
-            h = ff.dense(ff.multiply(g, u, name=f"blk{i}_mlp_act"), d,
-                         use_bias=False, name=f"blk{i}_mlp_down")
-        else:
-            h = ff.moe(
+    for i in range(layers):
+        def attention(u, i=i):
+            a = ff.rms_norm(u, eps=eps, name=f"blk{i}_ln1")
+            return ff.latent_attention(
+                a, m["num_attention_heads"], kv_rank=m["kv_lora_rank"],
+                nope_dim=m["qk_nope_head_dim"], rope_dim=m["qk_rope_head_dim"],
+                v_dim=m["v_head_dim"], rope_theta=m["rope_theta"], norm_eps=eps,
+                q_rank=m.get("q_lora_rank"), rope_scaling=m.get("rope_scaling"),
+                name=f"blk{i}_attn")
+
+        def feed_forward(u, i=i):
+            h = ff.rms_norm(u, eps=eps, name=f"blk{i}_ln2")
+            if i < m["first_k_dense_replace"]:
+                g = ff.dense(h, m["intermediate_size"], activation="silu",
+                             use_bias=False, name=f"blk{i}_mlp_gate")
+                up = ff.dense(h, m["intermediate_size"], use_bias=False,
+                              name=f"blk{i}_mlp_up")
+                return ff.dense(ff.multiply(g, up, name=f"blk{i}_mlp_act"), d,
+                                use_bias=False, name=f"blk{i}_mlp_down")
+            return ff.moe(
                 h, m["n_routed_experts"], m["moe_intermediate_size"],
                 top_k=m["num_experts_per_tok"], dispatch="sorted",
                 router=m.get("scoring_func", "sigmoid"), gated=True,
@@ -140,7 +165,9 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
                 norm_topk_prob=m["norm_topk_prob"],
                 routed_scale=m["routed_scaling_factor"],
                 held_experts=m.get("held_experts"), name=f"blk{i}_moe")
-        x = ff.add(x, h, name=f"blk{i}_res2")
+
+        x = _residual(ff, m, x, i, 1, attention)
+        x = _residual(ff, m, x, i, 2, feed_forward, close=i == layers - 1)
     x = ff.rms_norm(x, eps=eps, name="ln_f")
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
@@ -208,7 +235,7 @@ def _solar_open2_lm(ff: FFModel, tok, m: Dict[str, Any]):
 
 
 _BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
-           "solar_open2": _solar_open2_lm}
+           "xing4_0": _deepseek_v3_lm, "solar_open2": _solar_open2_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
 #: audit catalog): every mechanism of the block, no published width.
@@ -260,8 +287,33 @@ SOLAR_OPEN2_SMOKE: Dict[str, Any] = {
                            "num_heads": 2, "num_kv_heads": None},
 }
 
+#: The Xing4.0 family at unit-test size: the DeepSeek-V3 block with a
+#: compressed query and YaRN positions round a residual of four
+#: streams, one dense and two expert layers.
+XING4_TINY: Dict[str, Any] = {
+    **DEEPSEEK_V3_TINY, "model_type": "xing4_0", "q_lora_rank": 24,
+    "rope_theta": 10000.0, "routed_scaling_factor": 2.0,
+    "rope_scaling": {"type": "yarn", "factor": 8, "beta_fast": 32,
+                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 32},
+    "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+    "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+    "num_nextn_predict_layers": 1,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip: chip_smoke.py.
+XING4_SMOKE: Dict[str, Any] = {
+    **XING4_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "intermediate_size": 512, "moe_intermediate_size": 128,
+    "q_lora_rank": 128, "kv_lora_rank": 128, "qk_nope_head_dim": 64,
+    "qk_rope_head_dim": 32, "v_head_dim": 64,
+}
+
 PRESETS = {"deepseek-v3-tiny": DEEPSEEK_V3_TINY,
            "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE,
+           "xing4-tiny": XING4_TINY,
+           "xing4-smoke": XING4_SMOKE,
            "solar-open2-tiny": SOLAR_OPEN2_TINY,
            "solar-open2-smoke": SOLAR_OPEN2_SMOKE}
 

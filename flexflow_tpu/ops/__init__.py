@@ -7,6 +7,7 @@ from flexflow_tpu.ops.attention import (
 from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
 from flexflow_tpu.ops.conv import Conv2D, Flat, Pool2D
 from flexflow_tpu.ops.delta_attention import KimiDeltaAttention
+from flexflow_tpu.ops.hyper_connection import HyperConnectionPost, HyperConnectionPre
 from flexflow_tpu.ops.embedding import Embedding, HeteroEmbedding, MultiEmbedding, WordEmbedding
 from flexflow_tpu.ops.linear import Linear
 from flexflow_tpu.ops.losses import MSELoss, SoftmaxCrossEntropy
@@ -41,6 +42,8 @@ __all__ = [
     "Concat",
     "DotInteraction",
     "Dropout",
+    "HyperConnectionPost",
+    "HyperConnectionPre",
     "KimiDeltaAttention",
     "LatentAttention",
     "LayerNorm",

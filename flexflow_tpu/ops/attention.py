@@ -24,6 +24,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec
 
@@ -65,14 +66,16 @@ def _einsum_decode(q, cache_k, cache_v, pos):
     return jnp.einsum("bhs,bshd->bhd", attn, vf).astype(dtype)
 
 
-def _einsum_attention(q, k, v, causal: bool):
-    """Dense reference attention on (b, h, t, hd) heads, f32 scores;
-    returns the input dtype.  The fallback when no flash formulation
-    applies — including inside a ``shard_map``ped local shard, where it
-    is numerically identical to the flash kernel it replaces."""
+def _einsum_attention(q, k, v, causal: bool, scale: Optional[float] = None):
+    """Dense reference attention on (b, h, t, hd) heads, f32 scores
+    times ``scale`` (default ``hd ** -0.5``); returns the input dtype.
+    The fallback when no flash formulation applies — including inside a
+    ``shard_map``ped local shard, where it is numerically identical to
+    the flash kernel it replaces."""
     dtype = q.dtype
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         t = scores.shape[-1]
@@ -771,15 +774,61 @@ class MultiHeadAttention(Op):
         return self._merge_heads(o, dtype)
 
 
-def rope_interleaved(x, pos, theta: float):
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_frequencies(d: int, theta: float, scaling: Optional[dict] = None):
+    """``(frequencies (d/2,) f32, scale of cos and sin, scale of the
+    softmax)``.  Plain: pair i turns ``theta^(-2i/d)`` a position, both
+    scales 1.  Under YaRN (``scaling``: a configuration's
+    ``rope_scaling`` of ``type`` ``yarn``) a pair that turns more than
+    ``beta_fast`` times in the ``original_max_position_embeddings``
+    keeps its frequency, one that turns fewer than ``beta_slow`` times
+    has it divided by ``factor``, and the pairs between (the range's
+    ends rounded outward to whole pairs) are blended along a linear
+    ramp; cos and sin are scaled by ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)`` and the softmax by
+    ``mscale(factor, mscale_all_dim)^2`` (DeepSeek-V3's reading)."""
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
+    if scaling is None:
+        return inv, 1.0, 1.0
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling type {kind!r} is not built yet (only 'yarn')")
+    factor = float(scaling["factor"])
+    span = scaling["original_max_position_embeddings"]
+
+    def pair_turning(turns: float) -> float:
+        return d * math.log(span / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(pair_turning(scaling.get("beta_fast", 32))), 0)
+    hi = min(math.ceil(pair_turning(scaling.get("beta_slow", 1))), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - lo)
+                   / max(hi - lo, 0.001), 0.0, 1.0)
+    slowed = inv / factor
+    # slowed where the ramp is 1, inv where it is 0 (and inv itself,
+    # bit for bit, under a factor of 1).
+    inv = slowed + (inv - slowed) * (1.0 - ramp)
+    all_dim = scaling.get("mscale_all_dim", 0)
+    if all_dim:
+        wave = _yarn_mscale(factor, scaling.get("mscale", 1)) \
+            / _yarn_mscale(factor, all_dim)
+        return inv, wave, _yarn_mscale(factor, all_dim) ** 2
+    return inv, _yarn_mscale(factor, 1), 1.0
+
+
+def rope_interleaved(x, pos, inv, wave: float = 1.0):
     """Rotary embedding over adjacent pairs ``(2i, 2i+1)`` of the last
     dim (DeepSeek's ``rope_interleave``), in f32: pair i turns by
-    ``pos * theta^(-2i/d)``.  ``x``: (..., t, d) with ``pos`` (..., t)
-    broadcasting against its leading dims."""
+    ``pos * inv[i]`` (``rope_frequencies``), cos and sin scaled by
+    ``wave``.  ``x``: (..., t, d) with ``pos`` (..., t) broadcasting
+    against its leading dims."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
     ang = pos.astype(jnp.float32)[..., None] * inv                 # (..., t, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if wave != 1.0:
+        cos, sin = cos * wave, sin * wave
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -816,6 +865,8 @@ class LatentAttention(Op):
     def __init__(self, name: str, x: TensorSpec, num_heads: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
                  rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                 q_rank: Optional[int] = None,
+                 rope_scaling: Optional[dict] = None,
                  kernel_initializer=None):
         super().__init__(name, [x])
         assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
@@ -823,7 +874,12 @@ class LatentAttention(Op):
         self.attrs = dict(num_heads=num_heads, kv_rank=kv_rank,
                           nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
                           rope_theta=float(rope_theta), norm_eps=norm_eps,
+                          q_rank=q_rank, rope_scaling=rope_scaling,
                           causal=True)
+        #: What the scores are multiplied by before the softmax.
+        self.scale = 1.0 / math.sqrt(nope_dim + rope_dim)
+        if rope_scaling is not None:
+            self.scale *= rope_frequencies(rope_dim, rope_theta, rope_scaling)[2]
         self.kernel_initializer = kernel_initializer or GlorotUniform()
         self._make_output(x.shape, x.dtype, x.dim_axes)
 
@@ -836,9 +892,14 @@ class LatentAttention(Op):
         h, r = a["num_heads"], a["kv_rank"]
         dt = self.outputs[0].dtype
         ki = self.kernel_initializer
+        qr, qw = a["q_rank"], h * (a["nope_dim"] + a["rope_dim"])
+        query = {"wq": ParamSpec((d, qw), dt, ki, (None, "c"))} if not qr else {
+            "wq_a": ParamSpec((d, qr), dt, ki),
+            "q_norm": ParamSpec((qr,), dt, OnesInitializer()),
+            "wq_b": ParamSpec((qr, qw), dt, ki, (None, "c")),
+        }
         return {
-            "wq": ParamSpec((d, h * (a["nope_dim"] + a["rope_dim"])), dt, ki,
-                            (None, "c")),
+            **query,
             "wkv_a": ParamSpec((d, r + a["rope_dim"]), dt, ki),
             "kv_norm": ParamSpec((r,), dt, OnesInitializer()),
             "wkv_b": ParamSpec((r, h * (a["nope_dim"] + a["v_dim"])), dt, ki,
@@ -875,15 +936,26 @@ class LatentAttention(Op):
         a = self.attrs
         b, t, _ = x.shape
         h, r = a["num_heads"], a["kv_rank"]
-        q = (x @ params["wq"]).reshape(b, t, h, a["nope_dim"] + a["rope_dim"])
+        if a["q_rank"]:
+            q = rms_norm(x @ params["wq_a"], params["q_norm"],
+                         a["norm_eps"]) @ params["wq_b"]
+        else:
+            q = x @ params["wq"]
+        q = q.reshape(b, t, h, a["nope_dim"] + a["rope_dim"])
         q_nope, q_rope = q[..., :a["nope_dim"]], q[..., a["nope_dim"]:]
         ckr = x @ params["wkv_a"]
         c = rms_norm(ckr[..., :r], params["kv_norm"], a["norm_eps"])
-        k_r = rope_interleaved(ckr[..., r:], pos, a["rope_theta"])
-        q_rope = rope_interleaved(
-            q_rope.transpose(0, 2, 1, 3), pos[:, None, :], a["rope_theta"]
+        k_r = self._rope(ckr[..., r:], pos)
+        q_rope = self._rope(
+            q_rope.transpose(0, 2, 1, 3), pos[:, None, :]
         ).transpose(0, 2, 1, 3)
         return q_nope, q_rope, c, k_r
+
+    def _rope(self, x, pos):
+        a = self.attrs
+        inv, wave, _ = rope_frequencies(x.shape[-1], a["rope_theta"],
+                                        a["rope_scaling"])
+        return rope_interleaved(x, pos, inv, wave)
 
     def _expanded(self, params, q_nope, q_rope, c, k_r, serving: bool):
         """Causal attention with K and V expanded a head; (b, t, h*v)."""
@@ -900,10 +972,9 @@ class LatentAttention(Op):
         plan = getattr(self, "_plan", None)
         if serving and (plan is None or plan.num_devices == 1) and \
                 pallas_kernels.flash_uneven_supported(q.shape, a["v_dim"]):
-            out = pallas_kernels.flash_fwd_uneven(
-                q, k, v, 1.0 / math.sqrt(q.shape[-1]))
+            out = pallas_kernels.flash_fwd_uneven(q, k, v, self.scale)
         else:
-            out = _einsum_attention(q, k, v, True)
+            out = _einsum_attention(q, k, v, True, self.scale)
         return out.transpose(0, 2, 1, 3).reshape(b, t, h * a["v_dim"])
 
     def forward(self, params, xs, state, training):
@@ -954,15 +1025,15 @@ class LatentAttention(Op):
         w_k, w_v = wkv_b[..., :a["nope_dim"]], wkv_b[..., a["nope_dim"]:]
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k)
         q_cat = jnp.concatenate([q_lat.astype(x.dtype), q_rope[:, 0]], axis=-1)
-        scale = 1.0 / math.sqrt(a["nope_dim"] + a["rope_dim"])
         use = self.decode_kernel
         supported = pallas_kernels.mla_decode_supported(cache.shape, r)
         if use is None or (use and not supported):
             use = supported
         if use:
-            o_lat = pallas_kernels.mla_decode(q_cat, cache, pos + 1, r, scale)
+            o_lat = pallas_kernels.mla_decode(q_cat, cache, pos + 1, r,
+                                              self.scale)
         else:
-            o_lat = _latent_decode(q_cat, cache, pos, r, scale)
+            o_lat = _latent_decode(q_cat, cache, pos, r, self.scale)
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
         return [o.reshape(b, 1, h * a["v_dim"]) @ params["wo"]], new_state
 

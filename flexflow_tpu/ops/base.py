@@ -63,6 +63,12 @@ class CacheEntry:
             object.__setattr__(self, "axes", tuple(None for _ in self.shape))
 
 
+#: Serving counters (``Op.serving_stats``) that say the worst of what was
+#: seen: folded over layers and steps by their largest, where every other
+#: counter is folded by its mean.
+SERVING_STATS_LARGEST = frozenset({"hc_defect"})
+
+
 @dataclasses.dataclass
 class TensorSpec:
     """Symbolic tensor in the op graph (the reference's ``Tensor`` /

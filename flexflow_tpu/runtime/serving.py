@@ -117,6 +117,7 @@ import numpy as np
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel
 from flexflow_tpu.ops.attention import MultiHeadAttention, PositionEmbedding
+from flexflow_tpu.ops.base import SERVING_STATS_LARGEST
 from flexflow_tpu.ops.linear import Linear
 from flexflow_tpu.runtime import telemetry as _telemetry
 
@@ -1233,7 +1234,7 @@ class ServingExecutor:
             ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             rows = jax.tree.map(lambda c: c[0], caches)
             if stats:
-                return rows, tok, ok, self._mean_stats(stats)
+                return rows, tok, ok, self._fold_stats(stats)
             return rows, tok, ok
 
         if sample is not None:
@@ -1301,14 +1302,16 @@ class ServingExecutor:
         return rows
 
     @staticmethod
-    def _mean_stats(stats):
-        """The routing counters of one forward, mean over the layers
-        that report them."""
-        keys = sorted(next(iter(stats.values())))
-        return {
-            k: jnp.mean(jnp.stack([s[k] for s in stats.values()]))
-            for k in keys
-        }
+    def _fold_stats(stats):
+        """The counters of one forward, each over the layers that report
+        it: their mean, or for a counter of ``SERVING_STATS_LARGEST``
+        their largest."""
+        out = {}
+        for k in sorted({k for s in stats.values() for k in s}):
+            vals = jnp.stack([s[k] for s in stats.values() if k in s])
+            out[k] = jnp.max(vals) if k in SERVING_STATS_LARGEST \
+                else jnp.mean(vals)
+        return out
 
     def build_prefill_from(
         self, bucket: int, offset: int,
@@ -1528,7 +1531,7 @@ class ServingExecutor:
                 pos = jnp.minimum(pos + 1, S - 1)
                 out = (nxt, ok, logits) if return_logits else (nxt, ok)
                 if stats:
-                    out += (self._mean_stats(stats),)
+                    out += (self._fold_stats(stats),)
                 return (caches, pos, nxt), out
 
             (caches, pos, tok), outs = jax.lax.scan(
@@ -2191,10 +2194,13 @@ class Server:
             slots[slot_i] = None
 
         def rounded(counters) -> Dict[str, float]:
-            # Routing counters (expert layers only) as an event carries
-            # them: the mean over a superstep's K steps of the layers'
-            # mean (a prefill's are one step's).
-            return {key: round(float(np.mean(v)), 4)
+            # The ops' counters as an event carries them: the mean over
+            # a superstep's K steps of the layers' mean (a prefill's are
+            # one step's), or for a counter that says the worst seen the
+            # largest of both, to four significant digits.
+            return {key: float(f"{np.max(v):.4g}")
+                    if key in SERVING_STATS_LARGEST
+                    else round(float(np.mean(v)), 4)
                     for key, v in counters.items()}
 
         def slot_done(sl: _Slot) -> bool:

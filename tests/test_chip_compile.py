@@ -475,6 +475,50 @@ def test_solar_decode_superstep_holds_no_cache_or_state_sized_relayout(
         l for l in text.splitlines() if "bf16[4096,24576]" in l)
 
 
+def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
+    """``chip_smoke.py``'s ``serve/xing`` programs at the smoke preset's
+    widths, compiled for the described chip: the decode superstep and
+    the prefill hold the three latent kernels round four hyper-connected
+    streams, the latent cache goes from parameter to kernel to result
+    where it lies, and the stream between the blocks is an activation
+    (no entry parameter or result carries it)."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import XING4_SMOKE, build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    slots, seq = 4, 256
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(XING4_SMOKE, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    params, state = jax.tree.map(placed, params), jax.tree.map(placed, state)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    assert sorted(caches) == ["blk0_attn", "blk1_attn", "blk2_attn"]
+    vec = _sds((slots,), jnp.int32)
+    step = sex.build_decode_superstep(8).lower(
+        params, state, caches, vec, vec).compile().as_text()
+    first = sex.build_prefill(seq).lower(
+        params, state, _sds((1, seq), jnp.int32), _sds((), jnp.int32)
+    ).compile().as_text()
+    for text, kernels in ((step, ("ff_mla_decode", "ff_grouped_matmul")),
+                          (first, ("ff_flash_fwd_uneven", "ff_grouped_matmul"))):
+        for name in kernels:
+            assert chip_smoke.has_kernel(text, name), name
+    assert chip_smoke.table_sized_relayouts(
+        step, slots * 160 * seq, chip_smoke.CACHE_RELAYOUT_OPS) == []
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
+    assert f"[{slots},1,4,256]" not in layout and "hc_defect" not in layout
+    assert layout.count(f"bf16[{slots},160,{seq}]") == 6
+
+
 _CACHE = (48, 1024, 16, 64)
 #: A cache as the chip stores it (positions along the lanes), and as a
 #: row-major Mosaic operand wants it (hd 64 padded to a 128-lane tile).
@@ -584,6 +628,9 @@ _TINY = chip_smoke.Sizes(
     serve_solar=("--model-config", "solar-open2-tiny", "--max-seq", "128",
                  "--max-batch", "2", "--requests", "3", "--max-new", "6",
                  "--prompt-len", "20:60", "--buckets", "128"),
+    serve_xing=("--model-config", "xing4-tiny", "--max-seq", "128",
+                "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                "--prompt-len", "20:60", "--buckets", "128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -614,7 +661,7 @@ def _phases(which):
 
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
-              "serve", "serve/latent", "serve/solar"])
+              "serve", "serve/latent", "serve/solar", "serve/xing"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM
