@@ -923,7 +923,7 @@ class LatentAttention(Op):
         shape = (slots, self.row_width, max_seq)
         if kernel is not False and pallas_kernels.mla_decode_supported(
                 shape, self.attrs["kv_rank"]):
-            return pallas_kernels.mla_decode_block(max_seq)
+            return pallas_kernels.mla_decode_chunk(max_seq)
         return max_seq
 
     # -- shared pieces -------------------------------------------------------
@@ -1014,13 +1014,6 @@ class LatentAttention(Op):
         pos = state["pos"]                              # (B,)
         q_nope, q_rope, c, k_r = self._latent(params, x, pos[:, None])
         col = jnp.concatenate([c, k_r], axis=-1)[:, 0].astype(cache.dtype)
-        # One in-place column write a slot.  A scatter along the last
-        # axis makes the compiler hold the whole cache positions-major
-        # and copy it back for the kernel, every layer of every step.
-        for i in range(b):
-            cache = lax.dynamic_update_slice(
-                cache, col[i][None, :, None], (i, 0, pos[i]))
-        new_state["cache_ckr"] = cache
         wkv_b = params["wkv_b"].reshape(r, h, a["nope_dim"] + a["v_dim"])
         w_k, w_v = wkv_b[..., :a["nope_dim"]], wkv_b[..., a["nope_dim"]:]
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k)
@@ -1030,10 +1023,19 @@ class LatentAttention(Op):
         if use is None or (use and not supported):
             use = supported
         if use:
-            o_lat = pallas_kernels.mla_decode(q_cat, cache, pos + 1, r,
-                                              self.scale)
+            # The kernel writes the column itself, into the lane tile
+            # that holds ``pos``.
+            o_lat, cache = pallas_kernels.mla_decode(
+                q_cat, col, cache, pos + 1, r, self.scale)
         else:
+            # One in-place column write a slot.  A scatter along the
+            # last axis makes the compiler hold the whole cache
+            # positions-major and copy it back, every layer of every step.
+            for i in range(b):
+                cache = lax.dynamic_update_slice(
+                    cache, col[i][None, :, None], (i, 0, pos[i]))
             o_lat = _latent_decode(q_cat, cache, pos, r, self.scale)
+        new_state["cache_ckr"] = cache
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
         return [o.reshape(b, 1, h * a["v_dim"]) @ params["wo"]], new_state
 

@@ -2455,17 +2455,22 @@ def flash_fwd_uneven(q, k, v, scale: float,
     return out.reshape(b, h, t, dv)
 
 
-_MLA_DECODE_BLOCK = 2048
-_MLA_DECODE_CHUNK = 256
+#: Positions the latent decode kernel fetches and scores at a time.
+#: v5e-measured at 96 slots of 4096 positions, 58 of them 300..1500
+#: long and 38 empty, and at 16 slots of 16384 (PERF.md §6 PR 38): 512
+#: and 256 read the same on short rows, 512 a quarter less on long ones
+#: (one loop turn and one DMA for twice the positions).
+_MLA_DECODE_CHUNKS = (512, 256, 128)
+#: Chunks of the cache in VMEM: one being scored, the others in flight
+#: (0.266 / 0.238 / 0.237 ms a call at 2 / 3 / 4 on the short rows).
+_MLA_DECODE_RING = 3
 
 
-def mla_decode_block(s: int) -> int:
-    """Largest block of whole 128-position lane tiles dividing ``s``."""
-    b = min(s, _MLA_DECODE_BLOCK)
-    b -= b % 128
-    while b >= 128 and s % b:
-        b -= 128
-    return b
+def mla_decode_chunk(s: int) -> int:
+    """The granule the latent decode kernel fetches a slot's cache in:
+    the largest chunk of whole 128-position lane tiles that divides
+    ``s``; 0 if there is none."""
+    return next((c for c in _MLA_DECODE_CHUNKS if s % c == 0), 0)
 
 
 def mla_decode_supported(cache_shape: Tuple[int, ...], v_width: int) -> bool:
@@ -2474,111 +2479,179 @@ def mla_decode_supported(cache_shape: Tuple[int, ...], v_width: int) -> bool:
     if len(cache_shape) != 3:
         return False
     _, row, s = cache_shape
-    return (mla_decode_block(s) >= 128 and 0 < v_width <= row
+    return (mla_decode_chunk(s) > 0 and 0 < v_width <= row
             and v_width % 8 == 0 and row % 8 == 0)
 
 
-def _mla_decode_kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr,
-                       *, block_k, chunk, scale, num_kb, dv):
+def _mla_decode_kernel(len_ref, q_ref, col_ref, cache_in, o_ref, cache_ref,
+                       ring, sem, wsem, cur, *, chunk, scale, dv):
+    # One slot a grid step.  cache_ref is cache_in's buffer (aliased),
+    # in HBM.  The live chunks of all slots, in order, are one stream of
+    # (row, chunk) DMAs through ``ring``: chunk i of the stream lands in
+    # ring slot ``i % depth``, and a slot once scored takes the next
+    # chunk of the stream, a later slot's too.  ``cur`` carries the
+    # stream over the grid: chunks scored so far, and the slot and chunk
+    # to fetch next.
+    del cache_in
     b = pl.program_id(0)
-    kb = pl.program_id(1)
+    slots = pl.num_programs(0)
+    depth = ring.shape[0]
+    q = q_ref[0]                                        # (h, row)
+    h = q.shape[0]
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def live(i):
+        return lax.div(len_ref[i] + (chunk - 1), chunk)
 
-    length = len_ref[b]
+    def fetch(i, j, k):
+        start = pl.multiple_of(j * chunk, chunk)
+        return pltpu.make_async_copy(
+            cache_ref.at[i, :, pl.ds(start, chunk)], ring.at[k], sem.at[k])
 
-    @pl.when(kb * block_k < length)
-    def _accumulate():
-        q = q_ref[0]                                    # (h, row)
-        h = q.shape[0]
+    def fetch_next(k, fb, fj):
+        @pl.when(fb < slots)
+        def _():
+            fetch(fb, fj, k).start()
 
-        def body(i, carry):
-            m, l, acc = carry
-            start = pl.multiple_of(i * chunk, chunk)
-            rows = c_ref[0, :, pl.ds(start, chunk)]     # (row, chunk)
-            s = jnp.dot(q, rows, precision=_mxu_precision(rows.dtype),
-                        preferred_element_type=jnp.float32) * scale
-            k_pos = kb * block_k + start + lax.broadcasted_iota(
-                jnp.int32, (h, chunk), 1)
-            s = jnp.where(k_pos < length, s, _NEG_INF)  # (h, chunk) f32
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            acc = acc * corr + lax.dot_general(
-                p.astype(rows.dtype), rows[:dv], (((1,), (1,)), ((), ())),
-                precision=_mxu_precision(rows.dtype),
-                preferred_element_type=jnp.float32,
-            )                                           # (h, dv)
-            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            return m_new, l, acc
+        done = fj + 1 >= live(jnp.minimum(fb, slots - 1))
+        return jnp.where(done, fb + 1, fb), jnp.where(done, 0, fj + 1)
 
-        m, l, acc = lax.fori_loop(
-            0, block_k // chunk, body,
-            (m_scr[...], l_scr[...], acc_scr[...]),
-        )
-        m_scr[...] = m
-        l_scr[...] = l
-        acc_scr[...] = acc
+    @pl.when(b == 0)
+    def _prime():
+        fb = fj = jnp.int32(0)
+        for k in range(depth):
+            fb, fj = fetch_next(k, fb, fj)
+        cur[0], cur[1], cur[2] = jnp.int32(0), fb, fj
 
-    @pl.when(kb == num_kb - 1)
-    def _emit():
-        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+    def step(rows, state, valid=None):
+        m, l, acc = state
+        s = jnp.dot(q, rows, precision=_mxu_precision(rows.dtype),
+                    preferred_element_type=jnp.float32) * scale
+        if valid is not None:
+            s = jnp.where(valid, s, _NEG_INF)           # (h, chunk) f32
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr + lax.dot_general(
+            p.astype(rows.dtype), rows[:dv], (((1,), (1,)), ((), ())),
+            precision=_mxu_precision(rows.dtype),
+            preferred_element_type=jnp.float32,
+        )                                               # (h, dv)
+        return m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    n = live(b)
+    pos = len_ref[b] - 1
+
+    # Whole chunks below the one that holds ``pos``: nothing to mask.
+    def whole(j, c):
+        *state, i, fb, fj = c
+        k = lax.rem(i, depth)
+        fetch(b, j, k).wait()
+        state = step(ring[k], state)
+        return (*state, i + 1, *fetch_next(k, fb, fj))
+
+    *state, i, fb, fj = lax.fori_loop(0, n - 1, whole, (
+        jnp.full((h, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32),
+        jnp.zeros((h, dv), jnp.float32), cur[0], cur[1], cur[2]))
+
+    # The chunk that holds ``pos``: this step's column goes into its
+    # lane tile, the tile goes back to the cache, and positions past
+    # ``pos`` are masked.
+    k = lax.rem(i, depth)
+    fetch(b, n - 1, k).wait()
+
+    def lanes(t):
+        return pl.ds(pl.multiple_of(t * _LANES, _LANES), _LANES)
+
+    at = pos - (n - 1) * chunk
+    tile, lane = lanes(at // _LANES), lax.rem(at, _LANES)
+    col = _move_lane(col_ref[:, lanes(b // _LANES)], lax.rem(b, _LANES), lane)
+    ring[k, :, tile] = jnp.where(
+        _lane_iota(col.shape[0]) == lane, col,
+        ring[k, :, tile].astype(col.dtype)).astype(ring.dtype)
+    write = pltpu.make_async_copy(
+        ring.at[k, :, tile], cache_ref.at[b, :, lanes(pos // _LANES)], wsem)
+    write.start()
+    _, l, acc = step(ring[k], state, valid=lax.broadcasted_iota(
+        jnp.int32, (h, chunk), 1) <= at)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    write.wait()
+    cur[0] = i + 1
+    cur[1], cur[2] = fetch_next(k, fb, fj)
 
 
-def mla_decode(q, cache, lengths, v_width: int, scale: float,
+def mla_decode(q, col, cache, lengths, v_width: int, scale: float,
                interpret: Optional[bool] = None):
-    """Absorbed latent-attention decode: one query ``q`` (B, h, row) a
-    head against the latent cache ``cache`` (B, row, max_seq): one
-    ``row``-value column a token, shared by every head, positions along
-    the lanes (the order the chip stores a row narrower than a whole
-    number of lane tiles in: a (B, max_seq, 576) array would be copied
-    into this order in front of every call).  The score of position j
-    is ``q . cache[:, j] * scale`` over the whole column (latent part
-    and rotary key), the value its first ``v_width`` entries.
-    ``lengths`` (B,) int32: positions ``< lengths[b]`` are attended (at
-    least one).  Returns (B, h, v_width) in ``q.dtype``; blocks past a
-    slot's length are neither fetched nor computed.  Callers gate on
+    """One absorbed latent-attention decode step, the step's own column
+    written on the way: one query ``q`` (B, h, row) a head against the
+    latent cache ``cache`` (B, row, max_seq): one ``row``-value column a
+    token, shared by every head, positions along the lanes (the order
+    the chip stores a row narrower than a whole number of lane tiles in:
+    a (B, max_seq, 576) array would be copied into this order in front
+    of every call).  ``col`` (B, row), the column of the token at
+    position ``lengths - 1``, is stored there (in the cache's dtype);
+    the query attends positions ``< lengths[b]``, its own among them.
+    The score of position j is ``q . cache[:, j] * scale`` over the whole
+    column (latent part and rotary key), the value its first ``v_width``
+    entries.  ``lengths`` (B,) int32 in ``1..max_seq``.  What a slot
+    costs follows its length: its live chunks
+    (:func:`mla_decode_chunk`) are what is fetched and scored, and the
+    lane tile that holds the new column is what is written.  Returns
+    ``(out (B, h, v_width) in q.dtype, cache)``; donate the cache and
+    the write is in place.  Callers gate on
     :func:`mla_decode_supported`."""
     if interpret is None:
         interpret = _interpret_default()
+    return _mla_decode_call(q, col, cache, lengths, v_width=v_width,
+                            scale=scale, interpret=interpret)
+
+
+# A jit of its own, as ``_decode_call``: traced and lowered once a
+# program, not once a layer.
+@functools.partial(jax.jit,
+                   static_argnames=("v_width", "scale", "interpret"))
+def _mla_decode_call(q, col, cache, lengths, v_width, scale, interpret):
     b, row, s = cache.shape
     h = q.shape[1]
-    block_k = mla_decode_block(s)
-    chunk = _MLA_DECODE_CHUNK if block_k % _MLA_DECODE_CHUNK == 0 else 128
-    num_kb = s // block_k
-    kernel = functools.partial(
-        _mla_decode_kernel, block_k=block_k, chunk=chunk, scale=scale,
-        num_kb=num_kb, dv=v_width,
-    )
-
-    def cache_map(bi, ki, lens):
-        return (bi, 0, jnp.minimum(ki, lax.div(lens[bi] - 1, block_k)))
-
+    chunk = mla_decode_chunk(s)
+    kernel = functools.partial(_mla_decode_kernel, chunk=chunk, scale=scale,
+                               dv=v_width)
+    # The columns with the slots along the lanes: a slot's is moved to
+    # its position's lane by one rotate.
+    cols = jnp.pad(col.astype(jnp.float32).T,
+                   ((0, 0), (0, _round_up(b, _LANES) - b)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, num_kb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, h, row), lambda bi, ki, lens: (bi, 0, 0)),
-            pl.BlockSpec((1, row, block_k), cache_map),
+            pl.BlockSpec((1, h, row), lambda i, lens: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),          # cols
+            pl.BlockSpec(memory_space=pl.ANY),              # cache (HBM)
         ],
-        out_specs=pl.BlockSpec((1, h, v_width), lambda bi, ki, lens: (bi, 0, 0)),
+        out_specs=[
+            pl.BlockSpec((1, h, v_width), lambda i, lens: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, v_width), jnp.float32),
+            pltpu.VMEM((_MLA_DECODE_RING, row, chunk), cache.dtype),
+            pltpu.SemaphoreType.DMA((_MLA_DECODE_RING,)),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SMEM((3,), jnp.int32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+                   jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
+        # Operands count the scalar prefetch: 3 is the cache.
+        input_output_aliases={3: 1},
+        # The grid's steps share the ring and follow one another.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="ff_mla_decode",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, cache)
+    )(jnp.clip(lengths.astype(jnp.int32), 1, s), q, cols, cache)
 
 
 #: VMEM the grouped product may use: two (K, N) expert blocks, double

@@ -125,10 +125,11 @@ def _kda_decode(b, h, d):
 
 
 def _mla_decode(b, h, row, dv, s):
-    fn = lambda q, c, n: pk.mla_decode(q, c, n, dv, 0.07, interpret=False)
+    fn = lambda q, col, c, n: pk.mla_decode(q, col, c, n, dv, 0.07,
+                                            interpret=False)
     return (lambda: pk.mla_decode_supported((b, row, s), dv)), fn, (
-        _sds((b, h, row), BF16), _sds((b, row, s), BF16),
-        _sds((b,), jnp.int32))
+        _sds((b, h, row), BF16), _sds((b, row), BF16),
+        _sds((b, row, s), BF16), _sds((b,), jnp.int32))
 
 
 def _grouped(rows, e, k, n, tm, gated):
@@ -189,7 +190,8 @@ CASES = {
     # (32 heads, q.k 192 against v 128, a 576-value column, 128 experts
     # of 2048 x 768): the 4608 and 16384 prefill buckets, 16 slots of
     # 16384 positions, 96 assignments a decode step (16-row tiles) and
-    # a 16k prefill's 98304 (128-row tiles); and the smoke preset's.
+    # a 16k prefill's 98304 (128-row tiles); the smoke preset's; and the
+    # xing4.serve.closed96.p256-2k cell's 96 slots of 4096 positions.
     "flash_uneven-32x4608x192v128-bf16":
         lambda: _flash_uneven(4608, 32, 192, 128),
     "flash_uneven-32x16384x192v128-bf16":
@@ -197,6 +199,8 @@ CASES = {
     "flash_uneven-4x256x96v64-bf16": lambda: _flash_uneven(256, 4, 96, 64),
     "mla_decode-16x32x576x16384-bf16":
         lambda: _mla_decode(16, 32, 576, 512, 16384),
+    "mla_decode-96x32x576x4096-bf16":
+        lambda: _mla_decode(96, 32, 576, 512, 4096),
     "mla_decode-4x4x160x256-bf16": lambda: _mla_decode(4, 4, 160, 128, 256),
     "grouped_matmul-gated-decode-1536x2048x768":
         lambda: _grouped(1536, 128, 2048, 768, 16, True),
@@ -272,7 +276,8 @@ def _compiled_text(name: str) -> str:
     # caches, as the decode superstep donates them.
     donate = {"scatter_add_rows": (0,), "decode": (3, 4),
               "decode_grouped": (3, 4), "kda_chunk": (5,),
-              "kda_decode": (5,)}.get(name.split("-")[0], ())
+              "kda_decode": (5,), "mla_decode": (2,)}.get(
+                  name.split("-")[0], ())
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
@@ -413,14 +418,24 @@ def test_supported_gates_match_the_compiler():
     assert not pk.grouped_matmul_supported(64, 32, BF16)
 
 
-def test_latent_decode_reads_the_cache_where_it_lies():
-    """No copy of the latent cache stands in front of the decode kernel:
-    ``(slots, 576, max_seq)`` is the order the chip holds it in.  (A
-    ``(slots, max_seq, 576)`` cache is held positions-major and copied
-    into the kernel's order every call: 302 MB a layer a step at the
-    cell's size.)"""
-    text = _compiled_text("mla_decode-16x32x576x16384-bf16")
-    assert chip_smoke.table_sized_relayouts(text, 16 * 576 * 16384) == []
+@pytest.mark.parametrize("slots,seq", [(16, 16384), (96, 4096)])
+def test_latent_decode_reads_the_cache_where_it_lies(slots, seq):
+    """No copy of the latent cache stands in front of the decode kernel
+    or behind it: ``(slots, 576, max_seq)`` is the order the chip holds
+    it in, and the kernel, which writes the step's column into it,
+    hands back the buffer it was given.  (A ``(slots, max_seq, 576)``
+    cache is held positions-major and copied into the kernel's order
+    every call: 302 MB a layer a step at the cell's size.)"""
+    text = _compiled_text(f"mla_decode-{slots}x32x576x{seq}-bf16")
+    assert chip_smoke.table_sized_relayouts(
+        text, slots * 576 * seq, chip_smoke.CACHE_RELAYOUT_OPS) == []
+    cache = f"bf16[{slots},576,{seq}]{{2,1,0:T(8,128)(2,1)}}"
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
+    ins, outs = layout.split(")->(")
+    assert ins.count(cache) == outs.count(cache) == 1
+    # The cache is argument 2 and result 1, one buffer.
+    assert re.search(r"input_output_alias=\{[^\n]*\{1\}: \(2, \{\}", text)
+    assert "dynamic-update-slice" not in text
 
 
 def test_solar_decode_superstep_holds_no_cache_or_state_sized_relayout(
@@ -514,6 +529,10 @@ def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
             assert chip_smoke.has_kernel(text, name), name
     assert chip_smoke.table_sized_relayouts(
         step, slots * 160 * seq, chip_smoke.CACHE_RELAYOUT_OPS) == []
+    # The step's column is the kernel's to write: no update of XLA's on
+    # a cache (the parent made one a slot a layer).
+    assert [l for l in step.splitlines() if "dynamic-update-slice(" in l
+            and f"bf16[{slots},160,{seq}]" in l] == []
     layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
     assert f"[{slots},1,4,256]" not in layout and "hc_defect" not in layout
     assert layout.count(f"bf16[{slots},160,{seq}]") == 6

@@ -206,17 +206,72 @@ def test_grouped_matmul_kernel_against_ragged_dot(rows_per_expert, tm):
                                rtol=2e-3, atol=2e-3)
 
 
-def test_mla_decode_kernel_against_the_dense_oracle():
+def _column_written(cache, col, pos):
+    """``cache`` (B, row, S) with ``col[b]`` at position ``pos[b]``."""
+    out = np.array(cache)
+    out[np.arange(len(pos)), :, np.asarray(pos)] = np.asarray(col)
+    return jnp.asarray(out)
+
+
+#: A length under test by the kernel's granule ``c`` and the cache's
+#: ``s`` positions: every edge of the walk over a slot's live chunks.
+_MLA_EDGES = {
+    "one": lambda c, s: 1, "chunk-1": lambda c, s: c - 1,
+    "chunk": lambda c, s: c, "chunk+1": lambda c, s: c + 1,
+    "granule_end": lambda c, s: 2 * c, "max_seq": lambda c, s: s,
+}
+
+
+@pytest.mark.parametrize("edge", sorted(_MLA_EDGES))
+@pytest.mark.parametrize("s", [1536, 768, 384])     # granules of 512, 256, 128
+def test_mla_decode_kernel_against_the_dense_oracle(s, edge):
+    """Three granules a slot; the length under test in one batch with
+    an empty slot (length 1), a slot inside its second granule and a
+    full one, in an order that makes the ring of chunk fetches wrap
+    between slots."""
     rng = np.random.default_rng(4)
-    b, h, row, dv, s = 3, 4, 40, 32, 256
+    h, row, dv = 4, 40, 32
+    chunk = pallas_kernels.mla_decode_chunk(s)
+    assert s == 3 * chunk
+    lengths = [chunk + 7, _MLA_EDGES[edge](chunk, s), 1, s, 2 * chunk - 1]
+    b = len(lengths)
     q = jnp.asarray(rng.standard_normal((b, h, row)), jnp.float32)
     cache = jnp.asarray(rng.standard_normal((b, row, s)), jnp.float32)
-    pos = jnp.asarray([0, 130, 255], jnp.int32)
+    col = jnp.asarray(rng.standard_normal((b, row)), jnp.float32)
+    pos = jnp.asarray(lengths, jnp.int32) - 1
     assert pallas_kernels.mla_decode_supported(cache.shape, dv)
     assert not pallas_kernels.mla_decode_supported((b, row, 100), dv)
-    got = pallas_kernels.mla_decode(q, cache, pos + 1, dv, 0.2)
-    want = _latent_decode(q, cache, pos, dv, 0.2)
+    got, _ = pallas_kernels.mla_decode(q, col, cache, pos + 1, dv, 0.2)
+    want = _latent_decode(q, _column_written(cache, col, pos), pos, dv, 0.2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,b", [(jnp.float32, 6), (jnp.bfloat16, 6),
+                                     (jnp.float32, 130)])
+def test_mla_decode_kernel_writes_the_steps_column(dtype, b):
+    """The kernel puts the step's column at ``pos`` and nowhere else:
+    every other column of every slot comes back bit for bit, and the
+    output attends the new column (the oracle on the updated cache, not
+    on the one handed in).  130 slots: the columns come slots-along-
+    lanes, more than one lane tile of them."""
+    rng = np.random.default_rng(5)
+    h, row, dv, s = 4, 48, 32, 1024
+    q = jnp.asarray(rng.standard_normal((b, h, row)), dtype)
+    cache = jnp.asarray(rng.standard_normal((b, row, s)), dtype)
+    col = jnp.asarray(3.0 * rng.standard_normal((b, row)), dtype)
+    pos = jnp.asarray(([0, 127, 128, 511, 512, 1023] * b)[:b], jnp.int32)
+    got, new = pallas_kernels.mla_decode(q, col, cache, pos + 1, dv, 0.2)
+    want_cache = _column_written(cache, col, pos)
+    assert new.dtype == cache.dtype
+    assert np.array_equal(np.asarray(new, np.float32),
+                          np.asarray(want_cache, np.float32))
+    tol = dict(rtol=1e-4, atol=1e-5) if dtype == jnp.float32 else \
+        dict(rtol=3e-2, atol=3e-2)
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(
+        f32(got), f32(_latent_decode(q, want_cache, pos, dv, 0.2)), **tol)
+    stale = f32(_latent_decode(q, cache, pos, dv, 0.2))
+    assert np.abs(f32(got) - stale).max() > 0.3
 
 
 def test_flash_fwd_uneven_kernel_against_the_einsum_oracle():

@@ -535,6 +535,31 @@ def test_kv_rows_round_lengths_up_to_the_kernels_block(lm128):
     assert oex.kv_rows(np.array([0, 5]), 2)["kv_rows_fetched"] == 4 * 128
 
 
+def test_kv_rows_of_a_latent_graph_round_to_the_kernels_chunk():
+    """A latent-attention graph: ``kv_rows_fetched`` rounds a slot's
+    live length up to the chunk ``mla_decode`` fetches and scores in
+    (512 positions at ``max_seq`` 1024, 128 at 384), not to the cache;
+    the dense fallback reads every row."""
+    from flexflow_tpu.models.transformer import DEEPSEEK_V3_TINY, build_lm
+
+    def sex(seq, **kw):
+        cfg = FFConfig(batch_size=2)
+        return ServingExecutor(build_lm(DEEPSEEK_V3_TINY, 2, seq, cfg), cfg,
+                               max_batch=2, max_seq=seq, buckets=(8,), **kw)
+
+    big = sex(1024)
+    assert pallas_kernels.mla_decode_chunk(1024) == 512
+    # lengths 1, 2, 3 | 511, 512, 513 -> 512 x 3 | 512, 512, 1024
+    assert big.kv_rows(np.array([0, 510]), 3) == {
+        "kv_rows_fetched": 3 * 512 + 512 + 512 + 1024,
+        "kv_rows_cache": 6 * 1024}
+    assert big.kv_rows(np.array([1022, 1023]), 2)["kv_rows_fetched"] == 4096
+    # lengths 1 | 129 -> 128 | 256
+    assert sex(384).kv_rows(np.array([0, 128]), 1)["kv_rows_fetched"] == 384
+    assert sex(1024, decode_kernel=False).kv_rows(
+        np.array([0, 510]), 1)["kv_rows_fetched"] == 2048
+
+
 # -- retired closed-loop arrival knob (loud-error contract) --------------
 
 

@@ -75,7 +75,8 @@ _ENTRY_POINTS = {
                                       jnp.ones((1, 2, 128, 16)), 0.2),
         _qkv, {"ff_flash_fwd_uneven"}),
     "mla_decode": (
-        lambda q: pk.mla_decode(jnp.ones((1, 2, 40)), jnp.ones((1, 40, 128)),
+        lambda q: pk.mla_decode(jnp.ones((1, 2, 40)), jnp.ones((1, 40)),
+                                jnp.ones((1, 40, 128)),
                                 jnp.array([5], jnp.int32), 32, 0.2),
         _qkv, {"ff_mla_decode"}),
     "grouped_matmul": (
