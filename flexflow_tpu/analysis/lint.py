@@ -315,6 +315,7 @@ FF008_EVENT_NAMES = frozenset({
     "fault", "rollback", "replay", "preempt",
     "stall", "stall_recovered",
     "analysis", "search",
+    "serve_run",
     "request_start", "kv_wait", "prefill", "prefix_hit", "kv_cow",
     "decode_superstep", "spec_verify",
     "request_end", "serving_program",

@@ -156,7 +156,13 @@ def cmd_request(args) -> int:
         print(f"request: cannot read {path}: {log.read_error}",
               file=sys.stderr)
         return 2
-    tls = _spans.timelines_from_run(log)
+    n_runs = _spans.count_runs(log.iter_raw())
+    tls = _spans.timelines_from_run(log, args.loop_run)
+    if n_runs > 1:
+        # A benchmark cell's stream: the warm-up and the window, each a
+        # run of Server.run numbering its requests from 0.
+        print(f"stream holds {n_runs} runs of the serving loop; showing "
+              f"run {args.loop_run % n_runs} (--loop-run N picks another)")
     if args.journal:
         outcomes = _spans.journal_outcomes(
             _spans.fleet_journal_paths(args.journal))
@@ -198,8 +204,10 @@ def cmd_request(args) -> int:
         for tl in sorted(tls.values(), key=lambda t: t.id):
             slo = ("miss" if tl.slo_ok is False
                    else "ok" if tl.slo_ok else "-")
-            qw = "-" if tl.queue_wait_ms is None \
-                else f"{tl.queue_wait_ms:.3f}"
+            # (the measured loop's request_end carries no queue_wait_ms:
+            # its wait for a slot is the timeline's `queued` phase)
+            qw = f"{tl.phase_us['queued'] / 1000.0:.3f}" \
+                if tl.queue_wait_ms is None else f"{tl.queue_wait_ms:.3f}"
             mark = "  [transplanted]" if tl.transplanted else ""
             print(f"{tl.id:>5} {tl.tier if tl.tier is not None else '-':>4} "
                   f"{tl.e2e_ms:>10.3f} {qw:>9} {tl.tokens:>6} {slo:>4}"
@@ -249,6 +257,10 @@ def main(argv=None) -> int:
                     help="waterfalls for the N slowest requests")
     pq.add_argument("--stream", action="append", metavar="PATH",
                     help="extra per-process telemetry stream(s) to merge")
+    pq.add_argument("--loop-run", type=int, default=-1, metavar="N",
+                    help="which run of the serving loop in the stream "
+                         "(a benchmark cell's holds warm-up and window; "
+                         "default: the last)")
     pq.add_argument("--journal", metavar="PREFIX",
                     help="request journal (fleet .r{i} fan-out globbed) "
                          "to cross-check ids against")

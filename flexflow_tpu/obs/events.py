@@ -53,6 +53,7 @@ EVENT_CATALOG = frozenset({
     "analysis",
     "search",
     # serving (SERVING.md)
+    "serve_run",
     "request_start",
     "kv_wait",
     "prefill",

@@ -25,7 +25,8 @@ Contracts the reader owns:
   ``tests/test_telemetry.py::test_chaos_log_reconstructs_run``).
 - **Summary reconstruction**: :meth:`RunLog.reconstruct_summary`
   replicates ``Telemetry.step_summary`` field for field from raw
-  events, and :meth:`RunLog.summary` prefers the authoritative
+  events (a serving round's steps from its one event,
+  :func:`round_steps`), and :meth:`RunLog.summary` prefers the authoritative
   ``run_end`` block when the log is complete.
 """
 
@@ -76,6 +77,21 @@ def _fence_exclude() -> frozenset:
     from flexflow_tpu.runtime.telemetry import CALIBRATION_FENCE_EXCLUDE
 
     return CALIBRATION_FENCE_EXCLUDE
+
+
+def round_steps(ev: Any) -> List[float]:
+    """The step walls one fused serving round stands for: a
+    ``decode_superstep`` (``k`` steps) or ``spec_verify`` (``d + 1``)
+    event that carries ``superstep`` is the stream's only record of
+    its steps, ``wall_s / k`` each — the division
+    ``Telemetry.record_steps`` is fed with, so the two sides agree to
+    the bit.  A round without the key (a stream from before it) wrote
+    ``k`` ``step`` lines beside itself and gives nothing here."""
+    if ev.get("ev") not in ("decode_superstep", "spec_verify") \
+            or ev.get("superstep") is None or ev.get("wall_s") is None:
+        return []
+    k = int(ev.get("k") or int(ev.get("d") or 0) + 1)
+    return [float(ev["wall_s"]) / k] * k
 
 
 def _pct(sorted_vals: Sequence[float], p: float) -> float:
@@ -272,6 +288,9 @@ class RunLog:
         spec_rounds = spec_accepted = spec_draft = spec_emitted = 0
         prefill_evs = prefix_hits = full_hits = tokens_saved = 0
         for e in self.events:
+            walls = round_steps(e.data)
+            steps += len(walls)
+            step_walls.extend(walls)
             if e.ev == "step":
                 steps += 1
                 w = e.get("wall_s")

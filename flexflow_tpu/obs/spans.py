@@ -1,16 +1,23 @@
 """Per-request span timelines from a serving run's event stream.
 
-The serving scheduler stamps every request-visible phase transition
-with the deterministic virtual clock (``vclock_ms``, rounded to 3
-decimals = integer microseconds).  This module folds those events into
-one span timeline per request — queued → kv_wait → prefill → decode →
-slot_wait → preempted → retry_backoff → transplanted — whose phase
-totals reconcile EXACTLY (integer-microsecond equality, not a
-tolerance) with the ``e2e_ms`` the ``request_end`` event carries:
-``e2e_ms`` is computed from the same rounded stamps the phase edges
-are, so the telescoped sum and the recorded end-to-end are the same
-integer.  Any gap is a scheduler instrumentation bug, and the tests
-pin it (OBSERVABILITY.md "Reading a request").
+Both serving loops stamp every request-visible phase transition,
+rounded to 3 decimals of a millisecond = integer microseconds: the
+scheduler (``serving/scheduler.py::ScheduledServer``) with its
+deterministic virtual clock (``vclock_ms``), the plain loop
+(``runtime/serving.py::Server.run``, the one a benchmark cell
+measures) with the run's own real clock (``t_ms``:
+``time.perf_counter`` since the run's start; a prefill and a decode
+round carry both edges of the engine's call, ``t0_ms`` and ``t_ms``).
+This module folds either into one span timeline per request — queued
+→ kv_wait → prefill → decode → slot_wait → preempted → retry_backoff
+→ transplanted — whose phase totals reconcile EXACTLY
+(integer-microsecond equality, not a tolerance) with the ``e2e_ms``
+the ``request_end`` event carries: ``e2e_ms`` is computed from the
+same rounded stamps the phase edges are, so the telescoped sum and the
+recorded end-to-end are the same integer.  Any gap is an
+instrumentation bug of the loop, and the tests pin it
+(OBSERVABILITY.md "Reading a request").  One fold: :func:`stamp_of` is
+the only place that knows there are two clocks.
 
 Stdlib-only (no jax): loadable by the obs CLI, the measure tools and
 the lint sync pin anywhere.  Input is either a ``RunLog`` or any
@@ -68,7 +75,7 @@ def us(ms: Any) -> int:
 
 @dataclasses.dataclass
 class Span:
-    """One contiguous phase interval on the virtual clock."""
+    """One contiguous phase interval on the stream's clock."""
 
     phase: str
     start_ms: float
@@ -99,6 +106,9 @@ class RequestTimeline:
     transplanted: bool
     #: phase -> integer microseconds (the reconciliation currency).
     phase_us: Dict[str, int]
+    #: Which run of the loop in the stream this request belongs to
+    #: (``serve_run`` marks; 0 in a stream that holds one run).
+    run: int = 0
 
     @property
     def total_us(self) -> int:
@@ -107,7 +117,7 @@ class RequestTimeline:
     @property
     def reconciled(self) -> bool:
         """Phase totals telescope to exactly ``e2e_ms`` — the
-        virtual-clock equality the span layer is pinned on."""
+        rounded-stamp equality the span layer is pinned on."""
         return self.total_us == us(self.e2e_ms)
 
     @property
@@ -126,12 +136,54 @@ def _get(rec: Any, key: str, default: Any = None) -> Any:
     return rec.get(key, default)
 
 
-def build_timelines(records: Iterable[Any]) -> Dict[int, RequestTimeline]:
+def stamp_of(rec: Any) -> Any:
+    """An event's instant on its stream's clock, in rounded ms: the
+    real clock of ``Server.run`` (``t_ms``) where the event has it,
+    else the scheduler's virtual clock (``vclock_ms``), else None (an
+    event from before either; the fold skips it)."""
+    v = _get(rec, "t_ms")
+    return v if v is not None else _get(rec, "vclock_ms")
+
+
+def split_runs(records: Iterable[Any]) -> List[List[Any]]:
+    """The stream cut at its ``serve_run`` lines (one opens every
+    ``Server.run``): one list of records a run of a serving loop, in
+    order.  What stands before the first mark is a run of its own only
+    if a request ended there (a scheduler's stream has no mark and is
+    one run)."""
+    runs: List[List[Any]] = [[]]
+    for r in records:
+        if _get(r, "ev") == "serve_run":
+            runs.append([])
+        runs[-1].append(r)
+    if len(runs) > 1 and \
+            not any(_get(r, "ev") == "request_end" for r in runs[0]):
+        del runs[0]
+    return runs
+
+
+def count_runs(records: Iterable[Any]) -> int:
+    """How many runs of a serving loop the stream holds."""
+    return len(split_runs(records))
+
+
+def build_timelines(records: Iterable[Any],
+                    run: int = -1) -> Dict[int, RequestTimeline]:
     """Fold an event stream (raw dicts or ``RunLog`` events, in stream
     order) into per-request timelines.  Only requests whose
     ``request_end`` carries the stamped split (``arrival_ms`` /
-    ``vclock_ms`` / ``e2e_ms`` — the scheduler era) yield a timeline;
-    legacy events are skipped, never raised on."""
+    ``e2e_ms`` and a :func:`stamp_of`) yield a timeline; unstamped events
+    are skipped, never raised on.
+
+    A stream may hold several runs of ``Server.run`` (a benchmark
+    cell's holds the warm-up and the window), each opened by one
+    ``serve_run`` line, each numbering its requests from 0 on a clock
+    that restarts.  :func:`split_runs` keeps them apart and ``run``
+    picks one (a Python index; the last by default: the window).  A
+    scheduler's or a fleet's stream is one run."""
+    runs = split_runs(records)
+    if not -len(runs) <= run < len(runs):
+        return {}
     # -- pass 1: per-id ordered record lists ------------------------------
     recs: Dict[int, List[tuple]] = {}
     ends: Dict[int, Dict[str, Any]] = {}
@@ -140,9 +192,9 @@ def build_timelines(records: Iterable[Any]) -> Dict[int, RequestTimeline]:
         recs.setdefault(int(rid), []).append((kind, stamp, extra))
 
     pending = None  # (v0_us, [slot ids]) from the last sched_decision
-    for r in records:
+    for r in runs[run]:
         ev = _get(r, "ev")
-        v = _get(r, "vclock_ms")
+        v = stamp_of(r)
         rid = _get(r, "id")
         if ev == "sched_decision":
             ids = _get(r, "slots")
@@ -150,10 +202,16 @@ def build_timelines(records: Iterable[Any]) -> Dict[int, RequestTimeline]:
                        if v is not None and ids is not None else None)
         elif ev in ("decode_superstep", "spec_verify"):
             ids = _get(r, "slots")
-            if v is not None and ids is not None and pending is not None:
-                v1 = us(v)
-                for sid in ids:
-                    push(sid, "decode", pending[0], v1)
+            v0 = _get(r, "t0_ms")
+            if v is not None and ids is not None:
+                # A round is bounded by its own two stamps where it
+                # has both (the measured loop), else by the preceding
+                # decision's and its own (the scheduler).
+                v0 = us(v0) if v0 is not None else \
+                    pending[0] if pending is not None else None
+                if v0 is not None:
+                    for sid in ids:
+                        push(sid, "decode", v0, us(v))
             pending = None
         elif v is None and ev != "replica_route":
             continue  # legacy (unstamped) serving event
@@ -275,7 +333,7 @@ def build_timelines(records: Iterable[Any]) -> Dict[int, RequestTimeline]:
         out[rid] = RequestTimeline(
             id=rid,
             arrival_ms=float(end["arrival_ms"]),
-            end_ms=float(end["vclock_ms"]),
+            end_ms=float(stamp_of(end)),
             e2e_ms=float(end["e2e_ms"]),
             queue_wait_ms=end.get("queue_wait_ms"),
             tier=end.get("tier"),
@@ -286,14 +344,16 @@ def build_timelines(records: Iterable[Any]) -> Dict[int, RequestTimeline]:
             donor_spans=donor,
             transplanted=transplanted,
             phase_us=phase_us,
+            run=run % len(runs),
         )
     return out
 
 
-def timelines_from_run(run) -> Dict[int, RequestTimeline]:
+def timelines_from_run(log, run: int = -1) -> Dict[int, RequestTimeline]:
     """Timelines from a loaded :class:`~flexflow_tpu.obs.reader.RunLog`
-    (or anything with ``iter_raw``)."""
-    return build_timelines(run.iter_raw())
+    (or anything with ``iter_raw``); ``run`` as :func:`build_timelines`
+    takes it."""
+    return build_timelines(log.iter_raw(), run)
 
 
 def slo_autopsy(timelines: Dict[int, RequestTimeline]) -> Dict[str, Any]:

@@ -1871,7 +1871,10 @@ class ServingEngine:
     are separate calls because the two loops allocate the ledger row on
     different sides of the prefill's fence.  A wall runs from the
     instant the host starts on the program's arguments (a prefill's:
-    once the prompt is padded) to its fence's return.  ``caches`` is
+    once the prompt is padded) to its fence's return; that instant,
+    ``t0`` on ``time.perf_counter``'s clock, is handed back beside it,
+    so a loop can stamp both edges of the call on its one event.
+    ``caches`` is
     public: the fault injector's ``before_superstep`` takes it and
     hands back what the engine then holds."""
 
@@ -1900,7 +1903,7 @@ class ServingEngine:
     def prefill(self, prompt, bucket: int, plen: Optional[int] = None,
                 rid: int = 0, offset: int = 0, shared_ids=None):
         """Pad-to-bucket prefill: ``(cache_rows, first_token, finite,
-        counters, wall_s)`` after one fence; the rows are for
+        counters, wall_s, t0)`` after one fence; the rows are for
         :meth:`install`.  ``prompt`` is the full (prompt ‖ carried)
         sequence — re-prefill over it is the loss-free resume primitive
         of journal recovery and preemption.  Sampled engines prefill
@@ -1935,7 +1938,7 @@ class ServingEngine:
         with _telemetry.span("ff/serve/prefill_fence", id=rid):
             tok0, ok, *counters = tel.fence(tuple(fetch), "prefill")
         return (rows, int(tok0), bool(ok), counters[0] if counters else {},
-                time.perf_counter() - t0)
+                time.perf_counter() - t0, t0)
 
     def install(self, rows, slot_i: int, row: Optional[np.ndarray] = None,
                 shared: int = 0, rid: int = 0) -> None:
@@ -2005,8 +2008,9 @@ class ServingEngine:
                block_table: Optional[np.ndarray] = None,
                req_ids: Optional[np.ndarray] = None, superstep: int = 0):
         """One fused k-token superstep over the whole slot batch:
-        ``(tokens (k, B), finite (k, B), counters, wall_s)`` after one
-        fence — ``counters`` the routing counters a step, (k,) each."""
+        ``(tokens (k, B), finite (k, B), counters, wall_s, t0)`` after
+        one fence — ``counters`` the routing counters a step, (k,)
+        each."""
         tel = _telemetry.current()
         with _telemetry.span("ff/serve/decode_dispatch",
                              superstep=superstep):
@@ -2021,14 +2025,14 @@ class ServingEngine:
             toks, oks, *counters = tel.fence(fetch, "decode_superstep")
             wall = time.perf_counter() - t0
             del fetch
-        return toks, oks, counters[0] if counters else {}, wall
+        return toks, oks, counters[0] if counters else {}, wall, t0
 
     def spec(self, pos_vec: np.ndarray, tok_vec: np.ndarray, d: int,
              block_table: Optional[np.ndarray] = None,
              req_ids: Optional[np.ndarray] = None, superstep: int = 0):
         """One fused speculative round (d+1 draft steps, d+1 verify
         steps) over the whole slot batch: ``(tokens (d+1, B), finite
-        (d+1, B), accepted (B,), wall_s)`` after one fence."""
+        (d+1, B), accepted (B,), wall_s, t0)`` after one fence."""
         tel = _telemetry.current()
         with _telemetry.span("ff/serve/decode_dispatch",
                              superstep=superstep):
@@ -2044,7 +2048,7 @@ class ServingEngine:
             toks, oks, acc = tel.fence(fetch, "spec_verify")
             wall = time.perf_counter() - t0
             del fetch
-        return toks, oks, acc, wall
+        return toks, oks, acc, wall, t0
 
 
 class Server:
@@ -2141,6 +2145,19 @@ class Server:
         n = collections.Counter()
         decode_s = 0.0
         t_run0 = time.perf_counter()
+
+        def stamp(t: Optional[float] = None) -> float:
+            # The run's own clock: ms since its start, to three
+            # decimals, so every stamp is a whole number of
+            # microseconds and the request fold (``obs/spans.py``)
+            # reconciles exactly.  ``t`` is an instant the engine took.
+            return round(((time.perf_counter() if t is None else t)
+                          - t_run0) * 1e3, 3)
+
+        # One line a run: ids restart at 0 in every run of a stream
+        # (a benchmark cell's holds the warm-up and the window), and
+        # so does this clock; the fold keeps the runs apart by it.
+        tel.emit("serve_run", requests=len(queue), capacity=B, k=k)
         # -- journal replay: completed requests are NOT re-run,
         # in-flight requests resume with their fence-validated tokens
         # carried (re-prefill over prompt ‖ carried at admission).
@@ -2170,6 +2187,15 @@ class Server:
         drained = False
         preempt = PreemptionHandler(install=self.drain_on_preempt)
 
+        def end_request(rid: int, n_tokens: int, error: Optional[str],
+                        lat: float, t_eligible: float):
+            # ``e2e_ms`` from the ROUNDED stamps, as the scheduler's
+            # ``finish_result``: the fold's phases sum to it exactly.
+            arr, end = stamp(t_eligible), stamp()
+            tel.emit("request_end", id=rid, tokens=n_tokens, error=error,
+                     latency_s=round(lat, 6), arrival_ms=arr,
+                     e2e_ms=round(end - arr, 3), t_ms=end)
+
         def finish(slot_i: int, error: Optional[str] = None):
             sl = slots[slot_i]
             toks = sl.all_tokens
@@ -2182,9 +2208,8 @@ class Server:
                 latency_s=lat,
                 prefill_s=sl.prefill_s,
             )
-            tel.emit("request_end", id=sl.request.id,
-                     tokens=len(toks), error=error,
-                     latency_s=round(lat, 6))
+            end_request(sl.request.id, len(toks), error, lat,
+                        sl.t_eligible)
             if jr is not None:
                 jr.done(sl.request.id, len(sl.request.prompt),
                         len(toks), error, latency_s=round(lat, 6))
@@ -2217,14 +2242,13 @@ class Server:
             # and an honest latency.
             plen = len(r.prompt)
             tel.emit("request_start", id=r.id, prompt_len=plen,
-                     bucket=None, slot=None)
+                     bucket=None, slot=None, t_ms=stamp())
             lat = time.perf_counter() - t_run0
             results[r.id] = RequestResult(
                 id=r.id, prompt_len=plen, tokens=[],
                 error=err, latency_s=lat,
             )
-            tel.emit("request_end", id=r.id, tokens=0,
-                     error=err, latency_s=round(lat, 6))
+            end_request(r.id, 0, err, lat, t_run0)
             if jr is not None:
                 jr.done(r.id, plen, 0, err, latency_s=round(lat, 6))
 
@@ -2239,14 +2263,13 @@ class Server:
                          prior[-1] == self.eos_id):
                 return False
             tel.emit("request_start", id=r.id, prompt_len=plen,
-                     bucket=None, slot=None)
+                     bucket=None, slot=None, t_ms=stamp())
             lat = time.perf_counter() - t_run0
             results[r.id] = RequestResult(
                 id=r.id, prompt_len=plen, tokens=list(prior),
                 error=None, latency_s=lat,
             )
-            tel.emit("request_end", id=r.id, tokens=len(prior),
-                     error=None, latency_s=round(lat, 6))
+            end_request(r.id, len(prior), None, lat, t_run0)
             if jr is not None:
                 jr.done(r.id, plen, len(prior), None,
                         latency_s=round(lat, 6))
@@ -2318,7 +2341,7 @@ class Server:
                         carried_map.pop(r.id, None)
                         slot_i = slots.index(None)
                         tel.emit("request_start", id=r.id, prompt_len=plen,
-                                 bucket=bucket, slot=slot_i)
+                                 bucket=bucket, slot=slot_i, t_ms=stamp())
                         full = np.concatenate([
                             np.asarray(r.prompt, np.int32),
                             np.asarray(prior, np.int32),
@@ -2341,34 +2364,41 @@ class Server:
                             n["prefill_tokens_saved"] += plan.offset
                             tel.emit("prefix_hit", id=r.id,
                                      blocks=plan.use, full=True,
-                                     tokens_saved=plan.offset)
+                                     tokens_saved=plan.offset,
+                                     t_ms=stamp())
                         else:
                             # A partial hit (use > 0) gathers the shared
                             # span from the pool and computes only the
                             # tail (same fence discipline).
-                            rows, tok0, ok, routed, pf_s = engine.prefill(
-                                full, bucket, plen=plen, rid=r.id,
-                                offset=plan.offset if use else 0,
-                                shared_ids=plan.shared if use else None,
-                            )
+                            rows, tok0, ok, routed, pf_s, t0 = \
+                                engine.prefill(
+                                    full, bucket, plen=plen, rid=r.id,
+                                    offset=plan.offset if use else 0,
+                                    shared_ids=plan.shared if use else None,
+                                )
                             n["prefills"] += 1
+                            # Both edges of the engine's call on the one
+                            # event: its ``t0`` and its fence's return.
+                            edges = dict(wall_s=round(pf_s, 6),
+                                         t0_ms=stamp(t0),
+                                         t_ms=stamp(t0 + pf_s))
                             if use:
                                 n["prefix_hits"] += 1
                                 n["prefill_tokens_saved"] += plan.offset
                                 tel.emit("prefill", id=r.id, bucket=bucket,
                                          length=flen, offset=plan.offset,
-                                         wall_s=round(pf_s, 6))
+                                         **edges)
                                 tel.emit("prefix_hit", id=r.id,
                                          blocks=plan.use, full=False,
-                                         tokens_saved=plan.offset)
+                                         tokens_saved=plan.offset,
+                                         t_ms=stamp())
                                 if plan.cow:
                                     n["kv_cows"] += plan.cow
                                     tel.emit("kv_cow", id=r.id,
                                              blocks=plan.cow)
                             else:
                                 tel.emit("prefill", id=r.id, bucket=bucket,
-                                         length=flen,
-                                         wall_s=round(pf_s, 6),
+                                         length=flen, **edges,
                                          **rounded(routed))
                         if jr is not None:
                             jr.admit(r.id, plen, tok0 if ok else None,
@@ -2447,13 +2477,13 @@ class Server:
                     # -- one fused speculative round: d+1 draft steps
                     # + d+1 verify steps, one dispatch, one fence
                     # reading (tokens, finite, accepted).
-                    host_toks, host_oks, host_acc, wall = engine.spec(
+                    host_toks, host_oks, host_acc, wall, t0 = engine.spec(
                         pos_vec, tok_vec, spec_d, block_table=block_table,
                         req_ids=req_vec, superstep=superstep_idx,
                     )
                     k_eff = spec_d + 1
                 else:
-                    host_toks, host_oks, routed, wall = engine.decode(
+                    host_toks, host_oks, routed, wall, t0 = engine.decode(
                         pos_vec, tok_vec, k, block_table=block_table,
                         req_ids=req_vec, superstep=superstep_idx,
                     )
@@ -2463,24 +2493,29 @@ class Server:
                                      superstep=superstep_idx):
                     decode_s += wall
                     n["supersteps"] += 1
-                    superstep_idx += 1
                     # Training-superstep accounting: ONE host program and
                     # one fence covered k_eff decode steps (programs/step
                     # == 1/k_eff).
                     tel.add_programs(1, steps=k_eff)
-                    # `slots`: per-superstep occupancy by request id — the
-                    # span layer's decode attribution (this loop carries no
-                    # vclock stamps; ids still tell WHO was in the batch
-                    # each dispatch).  Captured before finish() frees slots.
+                    # The round's one event: `slots`, its occupancy by
+                    # request id (captured before finish() frees slots),
+                    # both edges of the engine's call on the run's clock
+                    # (the request fold gives the round to each occupant
+                    # as `decode`) and `superstep`, the key its
+                    # dispatch and fence spans carry.  Its k_eff steps
+                    # are counted, not written: the event holds `wall_s`
+                    # and `k` (or `d`), and the reader divides.
                     occ = [slots[i].request.id for i in active]
+                    edges = dict(superstep=superstep_idx,
+                                 wall_s=round(wall, 6), t0_ms=stamp(t0),
+                                 t_ms=stamp(t0 + wall))
+                    superstep_idx += 1
                     if not spec_d:
                         tel.emit("decode_superstep", k=k, active=len(active),
-                                 capacity=B, slots=occ,
-                                 wall_s=round(wall, 6), **rounded(routed),
+                                 capacity=B, slots=occ, **edges,
+                                 **rounded(routed),
                                  **self.ex.kv_rows(pos_vec, k))
-                    for j in range(k_eff):
-                        tel.record_step((n["supersteps"] - 1) * k_eff + j,
-                                        wall_s=wall / k_eff)
+                    tel.record_steps(k_eff, edges["wall_s"] / k_eff)
                     n_active = len(active)
                     emitted_round = 0
                     for i in active:
@@ -2525,8 +2560,7 @@ class Server:
                         tel.emit("spec_verify", d=spec_d, active=n_active,
                                  accepted=acc_round,
                                  draft=spec_d * n_active,
-                                 emitted=emitted_round, slots=occ,
-                                 wall_s=round(wall, 6))
+                                 emitted=emitted_round, slots=occ, **edges)
                     # Under the span: where device_get hands back a
                     # view of the device's buffer (the CPU), this is
                     # what frees it.

@@ -107,6 +107,9 @@ class _NullTelemetry:
     def record_step(self, step, loss=None, wall_s=None, **fields) -> None:
         pass
 
+    def record_steps(self, n: int, wall_s: float) -> None:
+        pass
+
     def record_input_wait(self, step, wall_s, **depths) -> None:
         pass
 
@@ -411,6 +414,17 @@ class Telemetry:
                     self._last_flush = now
             self._last_label = "step"
         self.heartbeat(f"step:{step}")
+
+    def record_steps(self, n: int, wall_s: float) -> None:
+        """``n`` steps of ``wall_s`` each that one fused serving round
+        covered: the counters and the percentile feed of ``n``
+        :meth:`record_step` calls, and no line.  The round's own event
+        (``decode_superstep`` / ``spec_verify``: ``wall_s``, ``k`` or
+        ``d``, ``superstep``) is the stream's record of them, and
+        ``RunLog.reconstruct_summary`` divides it the same way."""
+        self.counts["steps"] += int(n)
+        self.step_times.extend([float(wall_s)] * int(n))
+        self.heartbeat("steps")
 
     def record_input_wait(self, step, wall_s, **depths) -> None:
         """Input starvation: the wall time one steady-state

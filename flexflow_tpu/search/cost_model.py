@@ -354,7 +354,10 @@ class Calibration:
         """Fit from raw JSONL events (robust to truncated logs with no
         ``run_end``): step wall p50, min non-warmup fence wall, and the
         programs/fences-per-step counters re-derived from ``step`` /
-        ``fence`` / ``superstep`` events."""
+        ``fence`` / ``superstep`` events (a serving round's steps from
+        its one event, ``obs.reader.round_steps``)."""
+        from flexflow_tpu.obs.reader import round_steps
+
         run_end_cal: Optional[Dict[str, Any]] = None
         step_walls: List[float] = []
         fence_walls: List[float] = []
@@ -364,6 +367,9 @@ class Calibration:
         exclude = _fence_exclude()
         for ev in events:
             kind = ev.get("ev")
+            walls = round_steps(ev)
+            steps += len(walls)
+            step_walls.extend(walls)
             if kind == "step":
                 steps += 1
                 if ev.get("wall_s") is not None:

@@ -260,7 +260,7 @@ class _SimEngine:
 
     def prefill(self, prompt, bucket, plen=None, rid=0, offset=0,
                 shared_ids=None):
-        return None, 1, True, {}, 0.0
+        return None, 1, True, {}, 0.0, 0.0
 
     def install(self, rows, slot_i, row=None, shared=0, rid=0):
         pass
@@ -270,7 +270,7 @@ class _SimEngine:
         B = len(pos_vec)
         toks = np.ones((k, B), np.int32)
         oks = np.ones((k, B), bool)
-        return toks, oks, {}, 0.0
+        return toks, oks, {}, 0.0, 0.0
 
     def draft_prefill(self, prompt, bucket, slot_i, rid=0):
         return 0.0
@@ -285,7 +285,7 @@ class _SimEngine:
         toks = np.ones((d + 1, B), np.int32)
         oks = np.ones((d + 1, B), bool)
         acc = np.full(B, d, np.int64)
-        return toks, oks, acc, 0.0
+        return toks, oks, acc, 0.0, 0.0
 
 
 @dataclasses.dataclass
@@ -866,7 +866,7 @@ class ScheduledServer:
                 try:
                     # (the routing counters an expert graph's fence
                     # carries stay out of this loop's events)
-                    rows, tok0, ok, _routed, pf_s = self.engine.prefill(
+                    rows, tok0, ok, _routed, pf_s, _ = self.engine.prefill(
                         full, bucket, plen=len(r.prompt), rid=r.id,
                         offset=(plan.offset if use else 0),
                         shared_ids=(plan.shared if use else None),
@@ -1230,13 +1230,13 @@ class ScheduledServer:
                            else model.decode_ms(k))
                 try:
                     if spec_d:
-                        toks, oks, accs, wall = self.engine.spec(
+                        toks, oks, accs, wall, _ = self.engine.spec(
                             pos_vec, tok_vec, spec_d,
                             block_table=block_table, req_ids=req_vec,
                             superstep=superstep_idx,
                         )
                     else:
-                        toks, oks, _routed, wall = self.engine.decode(
+                        toks, oks, _routed, wall, _ = self.engine.decode(
                             pos_vec, tok_vec, k,
                             block_table=block_table, req_ids=req_vec,
                             superstep=superstep_idx,
@@ -1262,13 +1262,17 @@ class ScheduledServer:
                 # one fence covered k_eff decode steps
                 # (programs/step == 1/k_eff).
                 tel.add_programs(1, steps=k_eff)
+                # The round's k_eff steps are counted, not written:
+                # its one event holds ``wall_s``, ``k`` (or ``d``) and
+                # ``superstep``, the key of its dispatch and fence
+                # spans, and the reader divides (``Server.run`` alike).
+                wall = round(wall, 6)
                 if not spec_d:
                     sev("decode_superstep", k=k,
-                        active=len(active), wall_s=round(wall, 6),
-                        slots=occ, vclock_ms=round(vclock, 3))
-                for j in range(k_eff):
-                    tel.record_step((supersteps - 1) * k_eff + j,
-                                    wall_s=wall / k_eff)
+                        active=len(active), wall_s=wall,
+                        slots=occ, superstep=superstep_idx - 1,
+                        vclock_ms=round(vclock, 3))
+                tel.record_steps(k_eff, wall / k_eff)
                 emitted_round = 0
                 for i in active:
                     sl = slots[i]
@@ -1312,7 +1316,8 @@ class ScheduledServer:
                         active=len(active), accepted=acc_round,
                         draft=spec_d * len(active),
                         emitted=emitted_round,
-                        wall_s=round(wall, 6), slots=occ,
+                        wall_s=wall, slots=occ,
+                        superstep=superstep_idx - 1,
                         vclock_ms=round(vclock, 3))
         finally:
             preempt.__exit__(None, None, None)

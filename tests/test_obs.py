@@ -485,10 +485,46 @@ def test_superstep_program_cost(tmp_path):
     assert costs[0]["k"] == 4 and costs[0]["flops"] > 0
 
 
+@pytest.mark.parametrize("kind,width", [("decode_superstep", {"k": 8}),
+                                        ("spec_verify", {"d": 3})])
+def test_a_serving_rounds_steps_from_its_one_event(kind, width):
+    """A fused serving round no longer writes its ``k`` ``step`` lines
+    (PR 40): the event that carries ``superstep`` stands for them, and
+    the summary and the calibration come out as from the lines a stream
+    from before wrote beside a round WITHOUT the key, to the bit."""
+    from flexflow_tpu.obs.reader import round_steps
+    from flexflow_tpu.search.cost_model import Calibration
+
+    k = width.get("k") or width["d"] + 1
+    walls = [0.080001, 0.064, 0.096003]
+    fences = [{"ev": "fence", "label": kind, "wall_s": w / 2} for w in walls]
+    new = [e for i, w in enumerate(walls) for e in (
+        fences[i], {"ev": kind, **width, "superstep": i, "wall_s": w})]
+    old = [e for i, w in enumerate(walls) for e in (
+        [fences[i], {"ev": kind, **width, "wall_s": w}]
+        + [{"ev": "step", "step": i * k + j, "wall_s": w / k} for j in range(k)])]
+    assert round_steps(new[1]) == [walls[0] / k] * k and round_steps(old[1]) == []
+    assert round_steps({"ev": "superstep", "k": 8, "wall_s": 1.0, "superstep": 0}) == []
+    a, b = RunLog.from_events(new), RunLog.from_events(old)
+    assert a.reconstruct_summary() == b.reconstruct_summary()
+    assert a.reconstruct_summary()["steps"] == 3 * k
+    assert vars(Calibration.from_events(new)) == vars(Calibration.from_events(old))
+    # the write side keeps the same k values in memory, and no line
+    with Telemetry() as tel:
+        for w in walls:
+            tel.fence((), kind)
+            tel.record_steps(k, w / k)
+        live = tel.step_summary()
+    for key in ("steps", "fences", "fences_per_step", "step_ms_p50", "step_ms_p95",
+                "step_ms_max"):
+        assert live[key] == a.reconstruct_summary()[key], key
+
+
 def test_telemetry_off_hooks_are_noops():
     from flexflow_tpu.runtime.telemetry import NULL
 
     assert NULL.program_cost("train_step", lambda x: x, (1,)) is None
+    assert NULL.record_steps(8, 0.01) is None
     assert NULL.attach_trace_summary("/nowhere") is None
 
 

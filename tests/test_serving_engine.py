@@ -2,9 +2,12 @@
 ``runtime/serving.py``): ``Server.run`` and ``ScheduledServer.run``
 under ``fifo`` serve the same requests through the same class, with the
 same number of prefills, installs and decode dispatches, and give every
-request the same tokens."""
+request the same tokens.  Every call that ends in a fence hands back its
+wall and the instant it started at, and the scheduler's compute-free
+twin keeps the same returns."""
 
 import collections
+import time
 
 import numpy as np
 import pytest
@@ -17,10 +20,17 @@ from flexflow_tpu.runtime.serving import (
     ServingEngine,
     ServingExecutor,
 )
-from flexflow_tpu.serving.scheduler import ScheduledServer, SchedulerPolicy
+from flexflow_tpu.serving.scheduler import (
+    ScheduledServer,
+    SchedulerPolicy,
+    SlotShape,
+    _SimEngine,
+)
 
 V, S = 64, 32
 OPS = ("prefill", "install", "draft_prefill", "decode", "spec")
+#: The calls that end in a fence: ``(..., wall_s, t0)``.
+FENCED = ("prefill", "decode", "spec")
 
 
 def _gpt(**kw):
@@ -65,10 +75,19 @@ def test_both_loops_serve_through_the_one_engine(case, monkeypatch):
     build, ex_kw, srv_kw, shared = CASES[case]
     sex, vocab, params, state = build(**ex_kw)
     calls = collections.Counter()
+    returned = {}
     for op in OPS:
         def counted(self, *a, _op=op, _inner=getattr(ServingEngine, op), **kw):
             calls[_op] += 1
-            return _inner(self, *a, **kw)
+            before = time.perf_counter()
+            out = _inner(self, *a, **kw)
+            if _op in FENCED:
+                # its wall and the instant it started, on perf_counter's
+                # clock: both edges of the call, for the loop's one event
+                wall, t0 = out[-2:]
+                assert before <= t0 <= t0 + wall <= time.perf_counter()
+                returned[_op] = len(out)
+            return out
         monkeypatch.setattr(ServingEngine, op, counted)
     reqs = _requests(vocab, shared)
 
@@ -81,6 +100,13 @@ def test_both_loops_serve_through_the_one_engine(case, monkeypatch):
     queued, sstats = sched.run(reqs)
 
     assert dict(calls) == under_plain
+    # The compute-free twin keeps the engine's returns, element for element.
+    sim = _SimEngine(SlotShape(max_batch=2, max_seq=S, buckets=(S,)))
+    pos = np.zeros(2, np.int32)
+    twin = {"prefill": sim.prefill(reqs[0].prompt, S),
+            "decode": sim.decode(pos, pos, 4), "spec": sim.spec(pos, pos, 3)}
+    assert returned and {op: len(twin[op]) for op in returned} == returned
+    assert all(twin[op][-2:] == (0.0, 0.0) for op in returned)
     assert under_plain["prefill"] == under_plain["install"] == pstats["prefills"] \
         == sstats["prefills"]
     assert under_plain.get("decode", 0) + under_plain.get("spec", 0) \

@@ -19,14 +19,23 @@ Pinned invariants:
   still reconcile; a 1-replica fleet's merged stream equals the
   single-server fold; a torn tail in one stream of a multi-stream
   load never poisons the merged timeline.
+- **The measured loop** (``runtime/serving.py::Server.run``, the loop a
+  benchmark cell times): its events carry the run's own real clock
+  (``t_ms``; both edges of a prefill and of a decode round) and fold
+  through the SAME ``build_timelines``, reconciling exactly like the
+  scheduler's — padded, paged and speculative, with a rejected request
+  and a non-finite finish, over a stream that holds two runs of the
+  loop with colliding ids; a stream without stamps is skipped.
 - **Latency-model prefix pricing** (satellite): ``expected_prefill_ms``
   defaults to ``prefill_ms`` exactly; fitting from ``prefix_hit``
   events discounts it; serve-auto still ranks prefix-cache-on first
   on the shared-prefix workload.
 
-All cases run the compute-free simulated loop (no jax programs); the
-real-engine reconciliation lives in ``test_serving_sched.py``'s
-telemetered run + ``tools/measure_serving.py``'s reconciliation leg.
+The scheduler's cases run the compute-free simulated loop (no jax
+programs); its real-engine reconciliation lives in
+``test_serving_sched.py``'s telemetered run +
+``tools/measure_serving.py``'s reconciliation leg.  The measured loop's
+cases run a tiny model on the CPU.
 """
 
 import numpy as np
@@ -36,6 +45,8 @@ from flexflow_tpu.obs import spans
 from flexflow_tpu.obs.reader import RunLog
 from flexflow_tpu.runtime.serving import (
     Request,
+    Server,
+    ServingExecutor,
     ServingFaultInjector,
 )
 from flexflow_tpu.runtime.telemetry import Telemetry
@@ -443,3 +454,235 @@ def test_serve_auto_ranks_prefix_cache_on_shared_prefix_workload():
     flags = {s.config.prefix_cache for s in res.candidates}
     assert flags == {True, False}
     assert res.chosen.config.prefix_cache is True
+
+
+# -- the measured loop: Server.run on its own real clock ----------------------
+
+#: ``ServingExecutor`` keywords, ``Server`` keywords.
+MEASURED = {
+    "padded": ({}, {}),
+    "paged": (dict(kv_block=4), {}),
+    "paged_prefix": (dict(kv_block=4, prefix_cache=True), {}),
+    "speculate": (dict(draft_layers=1), dict(speculate=3)),
+}
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_transformer_lm
+
+    return build_transformer_lm(batch_size=2, seq_len=S, vocab_size=V,
+                                d_model=32, num_heads=2, num_layers=2,
+                                config=FFConfig(batch_size=2))
+
+
+@pytest.fixture(scope="module")
+def measured_streams(tiny_lm, tmp_path_factory):
+    """``case -> (path, results of the second run)``: one stream a case
+    holding TWO runs of ``Server.run``, as a benchmark cell's does (the
+    warm-up, then the window), both numbering their requests from 0.
+    The second run serves five requests over two slots, rejects a sixth
+    (no bucket holds it) and loses one to a NaN'd cache row (not under
+    the prefix cache, where the row's blocks are other requests' too)."""
+    out = {}
+
+    def get(case):
+        if case in out:
+            return out[case]
+        ex_kw, srv_kw = MEASURED[case]
+        sex = ServingExecutor(tiny_lm, max_batch=2, max_seq=S,
+                              buckets=(8, 16), decode_kernel=False, **ex_kw)
+        params, state = sex.init(seed=0)
+        rng = np.random.default_rng(1)
+        # A shared 8-token span in front under the prefix cache, so that
+        # the later admissions are hits (a full one among them).
+        span = rng.integers(0, V, size=8 if "prefix" in case else 0)
+
+        def reqs(n):
+            return [Request(id=i, max_new_tokens=5 + i % 3,
+                            prompt=np.concatenate(
+                                [span, rng.integers(0, V, size=(
+                                    0 if "prefix" in case and i % 2 else 3 + i))]
+                            ).astype(np.int32)) for i in range(n)]
+
+        tel = Telemetry(str(tmp_path_factory.mktemp(case)))
+        with tel:
+            Server(sex, params, state, decode_steps=4, **srv_kw).run(reqs(2))
+            window = reqs(5) + [Request(
+                id=5, max_new_tokens=4,
+                prompt=rng.integers(0, V, size=20).astype(np.int32))]
+            results, stats = Server(
+                sex, params, state, decode_steps=4,
+                fault_injector=None if "prefix" in case else
+                ServingFaultInjector(nan_cache_at={1: 1}),
+                **srv_kw).run(window)
+        out[case] = (tel.path, results, stats)
+        return out[case]
+
+    return get
+
+
+@pytest.mark.parametrize("case", list(MEASURED))
+def test_measured_loop_reconciles(measured_streams, case):
+    """Every request of both runs of the stream folds to a timeline
+    whose phases sum to its ``e2e_ms`` in integer microseconds; the two
+    runs' colliding ids stay apart; the rejected request is all
+    ``queued`` and the faulted one ends where its error was seen."""
+    path, results, stats = measured_streams(case)
+    run = RunLog.load(path)
+    assert not run.unknown_events
+    assert spans.count_runs(run.iter_raw()) == 2
+    warm = spans.timelines_from_run(run, 0)
+    tls = spans.timelines_from_run(run)          # the last run: the window
+    assert sorted(warm) == [0, 1] and sorted(tls) == list(range(6))
+    assert {t.run for t in warm.values()} == {0}
+    assert {t.run for t in tls.values()} == {1}
+    _assert_all_reconciled(warm)
+    _assert_all_reconciled(tls)
+    assert spans.timelines_from_run(run, 1)[0].phase_us == tls[0].phase_us
+    assert spans.timelines_from_run(run, 2) == {}
+    # ids 0 and 1 of the warm-up are not ids 0 and 1 of the window
+    assert warm[0].e2e_ms != tls[0].e2e_ms
+    for i, tl in tls.items():
+        assert tl.error == results[i].error and tl.tokens == len(results[i].tokens)
+        assert tl.arrival_ms == 0.0 and tl.e2e_ms == tl.end_ms
+        # a span never reaches past the request's end, and they abut
+        assert [s.start_ms for s in tl.spans[1:]] == [s.end_ms for s in tl.spans[:-1]]
+    assert stats["failed"] == (1 if case == "paged_prefix" else 2)
+    rejected = tls[5]
+    assert "exceeds the largest pad bucket" in rejected.error
+    assert rejected.phase_us["queued"] == rejected.total_us > 0
+    faulted = [t for t in tls.values() if t.error and "non-finite" in t.error]
+    assert len(faulted) == stats["failed"] - 1
+    assert all(t.phase_us["decode"] > 0 for t in faulted)
+    served = [t for t in tls.values() if t.error is None]
+    assert all(t.phase_us["prefill"] > 0 or case == "paged_prefix" for t in served)
+    assert all(t.phase_us["decode"] > 0 for t in served)
+    # two slots, five requests: the later ones queued for a slot
+    assert tls[4].phase_us["queued"] > tls[0].phase_us["queued"]
+    if case == "paged_prefix":
+        # a FULL hit closes its prefill at length 0 on this clock too
+        hits = [e for e in run.iter_raw() if e["ev"] == "prefix_hit"]
+        assert all(e.get("t_ms") is not None for e in hits)
+        assert any(e["full"] for e in hits)
+
+
+@pytest.mark.parametrize("case", list(MEASURED))
+def test_measured_loop_round_is_one_line(measured_streams, case):
+    """What replaced the ``k`` ``step`` lines a superstep: the round's
+    one event carries ``superstep``, ``wall_s`` and ``k`` (``d``), the
+    summary's step counters are kept in memory, and the reader rebuilds
+    them from the event to the bit.  A run adds one ``serve_run`` line
+    and nothing a superstep, a request or an admission."""
+    path, _, stats = measured_streams(case)
+    run = RunLog.load(path)
+    kinds = [e["ev"] for e in run.iter_raw()]
+    assert "step" not in kinds and kinds.count("serve_run") == 2
+    rounds = [e for e in run.iter_raw()
+              if e["ev"] in ("decode_superstep", "spec_verify")]
+    k_eff = 4                       # decode_steps, and d + 1 of speculate=3
+    assert sum(len(r["slots"]) > 0 for r in rounds) == len(rounds)
+    window = rounds[-stats["decode_supersteps"]:]
+    # numbered as the loop counts them (its spans carry the same key);
+    # a raised fault skips an index, a NaN'd row does not
+    assert [r["superstep"] for r in window] == list(range(len(window)))
+    assert all(r["t0_ms"] < r["t_ms"] for r in rounds)
+    assert all(abs((r["t_ms"] - r["t0_ms"]) - r["wall_s"] * 1e3) < 2e-3 for r in rounds)
+    # one fence line and one round line a superstep
+    assert kinds.count("fence") == len(rounds) + kinds.count("prefill")
+    rec, summ = run.reconstruct_summary(), run.summary()
+    assert rec["steps"] == summ["steps"] == len(rounds) * k_eff
+    for key in ("fences", "fences_per_step", "step_ms_p50", "step_ms_p95",
+                "step_ms_max"):
+        assert rec[key] == summ[key], key
+    assert summ["programs_per_step"] == pytest.approx(1 / k_eff)
+
+
+def test_slot_wait_of_the_first_admitted_is_the_other_admissions():
+    """To the microsecond, on a hand-written stream: request 0 is
+    admitted first and holds slot 0 through request 1's admission
+    (1.250 ms) and request 2's (0.875 ms, between two rounds); each
+    round is ``decode`` for its occupants, and nothing else is left."""
+    ev = [
+        {"ev": "serve_run", "requests": 3, "capacity": 2, "k": 4},
+        {"ev": "request_start", "id": 0, "bucket": 8, "t_ms": 0.100},
+        {"ev": "prefill", "id": 0, "t0_ms": 0.150, "t_ms": 1.100},
+        {"ev": "request_start", "id": 1, "bucket": 8, "t_ms": 1.100},
+        {"ev": "prefill", "id": 1, "t0_ms": 1.200, "t_ms": 2.350},
+        {"ev": "decode_superstep", "k": 4, "slots": [0, 1], "superstep": 0,
+         "t0_ms": 2.350, "t_ms": 4.350},
+        {"ev": "request_end", "id": 1, "tokens": 5, "error": None,
+         "arrival_ms": 0.0, "e2e_ms": 4.350, "t_ms": 4.350},
+        {"ev": "request_start", "id": 2, "bucket": 8, "t_ms": 4.350},
+        {"ev": "prefill", "id": 2, "t0_ms": 4.400, "t_ms": 5.225},
+        {"ev": "decode_superstep", "k": 4, "slots": [0, 2], "superstep": 1,
+         "t0_ms": 5.225, "t_ms": 7.000},
+        {"ev": "request_end", "id": 0, "tokens": 9, "error": None,
+         "arrival_ms": 0.0, "e2e_ms": 7.000, "t_ms": 7.000},
+        {"ev": "request_end", "id": 2, "tokens": 5, "error": None,
+         "arrival_ms": 0.0, "e2e_ms": 7.125, "t_ms": 7.125},
+    ]
+    tls = spans.build_timelines(ev)
+    _assert_all_reconciled(tls)
+    assert tls[0].phase_us == {**{p: 0 for p in spans.PHASES}, "queued": 100,
+                               "prefill": 1000, "decode": 2000 + 1775,
+                               "slot_wait": 1250 + 875}
+    assert tls[1].phase_us["queued"] == 1100 and tls[1].phase_us["slot_wait"] == 0
+    assert tls[2].phase_us["slot_wait"] == 125   # bookkeeping after its last round
+    assert [s.phase for s in tls[0].spans] == [
+        "queued", "prefill", "slot_wait", "decode", "slot_wait", "decode"]
+
+
+def test_slot_wait_holds_the_other_admissions_on_a_real_run(measured_streams):
+    """On the real loop the first admitted waits at least through every
+    admission made while it held its slot (they run one after another on
+    the one host thread), plus installs, pack and bookkeeping."""
+    path, _, _ = measured_streams("padded")
+    evs = [e for e in RunLog.load(path).iter_raw()]
+    evs = evs[max(i for i, e in enumerate(evs) if e["ev"] == "serve_run"):]
+    tl = spans.build_timelines(evs)[0]
+    start = {e["id"]: e["t_ms"] for e in evs if e["ev"] == "request_start"}
+    others = sum(spans.us(e["t_ms"]) - spans.us(start[e["id"]])
+                 for e in evs if e["ev"] == "prefill" and e["id"] != 0
+                 and start[e["id"]] < tl.end_ms)
+    assert others > 0
+    assert tl.phase_us["slot_wait"] >= others
+
+
+def test_unstamped_stream_is_skipped_not_raised_on(measured_streams):
+    """A stream from before the stamps (or a parent's): no timeline, no
+    error; one whose rounds alone lack them still reconciles, the
+    rounds' time read as ``slot_wait``."""
+    path, _, _ = measured_streams("padded")
+    evs = [dict(e) for e in RunLog.load(path).iter_raw()]
+    bare = [{k: v for k, v in e.items()
+             if k not in ("t_ms", "t0_ms", "arrival_ms", "e2e_ms")}
+            for e in evs if e["ev"] != "serve_run"]
+    assert spans.build_timelines(bare) == {}
+    assert spans.count_runs(bare) == 1
+    no_rounds = [{k: v for k, v in e.items() if k not in ("t_ms", "t0_ms")}
+                 if e["ev"] == "decode_superstep" else e for e in evs]
+    tls = spans.build_timelines(no_rounds)
+    _assert_all_reconciled(tls)
+    assert all(t.phase_us["decode"] == 0 for t in tls.values())
+
+
+def test_obs_request_cli_on_a_measured_stream(measured_streams, capsys):
+    """``obs request`` on the stream a benchmark cell leaves: says that
+    it holds two runs, shows the window by default, the warm-up on
+    ``--loop-run 0``."""
+    from flexflow_tpu.obs.__main__ import main
+
+    path, _, _ = measured_streams("padded")
+    assert main(["request", path]) == 0
+    table = capsys.readouterr().out
+    assert "holds 2 runs" in table and "showing run 1" in table
+    assert "dominant" in table and "WARNING" not in table
+    assert main(["request", path, "4"]) == 0
+    out = capsys.readouterr().out
+    assert "request 4" in out and "reconciled=yes" in out and "slot_wait" in out
+    assert main(["request", path, "4", "--loop-run", "0"]) == 2   # two warm-up requests
+    capsys.readouterr()
+    assert main(["request", path, "1", "--loop-run", "0"]) == 0
+    assert "showing run 0" in capsys.readouterr().out

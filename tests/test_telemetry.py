@@ -347,7 +347,11 @@ def test_watchdog_warns_once_per_stall(caplog):
 def test_watchdog_notifies_external_supervisor(tmp_path):
     """Stall escalation (--stall-notify-pid): the watchdog SIGUSR1s an
     EXTERNAL supervisor process on stall — and still kills nothing
-    (the child observes the signal and exits cleanly on its own)."""
+    (the child observes the signal and exits cleanly on its own).  The
+    watchdog is armed only once the child says its handler is in: a
+    SIGUSR1 that finds the default disposition kills it (seen once
+    under six workers, where starting an interpreter outlasted the
+    0.1 s deadline)."""
     import subprocess
     import sys
 
@@ -356,6 +360,7 @@ def test_watchdog_notifies_external_supervisor(tmp_path):
             "import signal, sys, time\n"
             "got = []\n"
             "signal.signal(signal.SIGUSR1, lambda s, f: got.append(s))\n"
+            "print('READY', flush=True)\n"
             "deadline = time.monotonic() + 15\n"
             "while not got and time.monotonic() < deadline:\n"
             "    time.sleep(0.02)\n"
@@ -364,6 +369,7 @@ def test_watchdog_notifies_external_supervisor(tmp_path):
         stdout=subprocess.PIPE, text=True,
     )
     try:
+        assert child.stdout.readline().strip() == "READY"
         with Telemetry(str(tmp_path), stall_deadline_s=0.1,
                        notify_pid=child.pid) as tel:
             time.sleep(0.5)
