@@ -263,14 +263,14 @@ class MultiHeadAttention(Op):
         return f"{kind}_decode" if decode else f"{kind}_dense"
 
     def _kernel_block(self, slots: int, max_seq: int, c: int = 1) -> int:
-        """``flash_decode``'s block over a device's cache of ``slots``
+        """``flash_decode``'s chunk over a device's cache of ``slots``
         slots and a ``c``-th of the heads; 0 where its gate refuses."""
         h, hd = self.attrs["num_kv_heads"], self.attrs["head_dim"]
         local, dtype = (slots, max_seq, h // c, hd), self.outputs[0].dtype
         if h % c or not pallas_kernels.flash_decode_supported(
                 local, dtype, self.group):
             return 0
-        return pallas_kernels.flash_decode_block(*local[1:], dtype)
+        return pallas_kernels.flash_decode_chunk(*local[1:], dtype, self.group)
 
     def decode_fetch_block(self, slots, max_seq, kernel, c=1):
         block = 0 if kernel is False else self._kernel_block(slots, max_seq, c)

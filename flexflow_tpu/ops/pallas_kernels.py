@@ -1215,59 +1215,77 @@ def flash_attention_lse_chunked(q, k, v, causal: bool = True,
 # bitcast of that, and it is what the kernel takes: a row-major
 # ``(B, max_seq, h, hd)`` operand made the compiler copy both caches
 # into a lane-padded layout in front of every superstep and back
-# behind it (PERF.md §6 PR 32).  Blocks are ``(1, h, hd, block_k)``
-# with ``block_k`` a few 128-position lane tiles; the lengths are a
-# scalar prefetch and the index map clamps to each slot's last live
-# block, so blocks past a slot's length are neither fetched nor
-# computed.  A head's score over 128 positions is a sum over the hd
-# sublanes of ``q * k`` and the value sum stays a ``(hd, 128)`` partial
-# a lane: VPU work.  The softmax statistics are PER LANE too (each lane
-# its own running max, merged when the slot ends), so a tile costs no
+# behind it (PERF.md §6 PR 32).
+#
+# Both caches stay in HBM (``pl.ANY``, aliased to the results) and the
+# kernel moves what it needs itself: one slot a grid step, a slot's
+# live ``(h, hd, chunk)`` pieces of K and of V, and the live chunks of
+# ALL slots, in slot order, as one stream of DMAs through a ring of
+# VMEM buffers whose cursor is carried over the grid in SMEM
+# (``_kv_stream``, the shape of ``_mla_decode_kernel``).  A slot costs
+# its live chunks and nothing else: no grid step without a body, and
+# the next slots' first chunks are in flight while this one's last is
+# scored (a grid of ``slots x max_seq / block`` clamped to the last
+# live block spent 113 of 192 steps dead at the serving cell's mix and
+# fetched every slot's first block behind one of them, PERF.md §6 PR
+# 41).  ``flash_decode_chunk`` picks the chunk from the shape.
+#
+# A head's score over 128 positions is a sum over the hd sublanes of
+# ``q * k`` and the value sum stays a ``(hd, 128)`` partial a lane: VPU
+# work.  The softmax statistics are PER LANE too (each lane its own
+# running max, merged when the slot ends), so a tile costs no
 # cross-lane operation: on the chip those (lane reductions, lane
 # gathers) cost about ten vector operations each (PERF.md §6 PR 32).
-# For the same reason q and the step's K/V enter already broadcast
-# along the lanes (``(B, h, hd, 128)``, an XLA broadcast: 38 MB a call
-# at 48 slots of 16 x 64, a quarter of what the call moves, against
-# 2.5 us a slot of lane gathers, a quarter of what the call then
-# took).  Several heads a loop iteration give the scheduler independent
-# chains to interleave; (m, l, acc) stay f32 in scratch.
+# For the same reason a slot's query is laid along the lanes once, by
+# one product with a one-row matrix on the otherwise idle matrix unit.
+# Several heads a loop iteration give the scheduler independent chains
+# to interleave; (m, l, acc) stay f32 in scratch.  Grouped queries
+# (``_decode_grouped_kernel``) take a whole chunk in two small matrix
+# products instead.
 #
-# The step's own K/V column is WRITTEN here too: the lane tile that
-# holds ``pos`` is in VMEM already, the new column enters as an
-# operand, and the tile goes back through an aliased output.  An XLA
-# scatter (or ``dynamic_update_slice`` of one column a slot) in front
-# of the kernel is laid out row-major by the compiler, which brings the
-# cache-sized copies back, inside the decode scan.
+# The step's own K/V column is WRITTEN here too.  ``k_new``/``v_new``
+# enter as one ``(2, h, hd, slots -> lanes)`` operand resident in VMEM
+# (in the cache's dtype, as the 32-bit words a vector register packs it
+# in); a slot's column is moved to the lane of ``pos`` by one rotate
+# (``_move_lane``), selected into the lane tile of the ring buffer
+# that holds ``pos``, and that one ``(h, hd, 128)`` tile goes back to
+# the cache by a DMA of its own.  An XLA scatter (or
+# ``dynamic_update_slice`` of one column a slot) in front of the kernel
+# is laid out row-major by the compiler, which brings the cache-sized
+# copies back, inside the decode scan.
 # Inference-only: no VJP (the decode path is reachable only from the
 # ServingExecutor, never from a differentiated train step; the pure-jnp
 # ``_einsum_decode`` in ops/attention.py stays the numerics oracle and
 # the fallback).
 
-#: VMEM the kernel plans for and the limit it asks for (v5e has
-#: 128 MiB; a kernel gets 16 MB unless it asks): the pipelined K and V
-#: blocks (two arrays, double buffered) take what the per-slot
-#: operands, the written tiles and the f32 state leave of the plan.
-_DECODE_VMEM_BYTES = 48 << 20
+#: Chunks of K and of V in VMEM each: one being scored, the others in
+#: flight (v5e, 48 slots of 16 x 64 at the serving cell's mix: 0.151 /
+#: 0.140 / 0.138 ms a call at 2 / 3 / 4; PERF.md §6 PR 41).
+_DECODE_RING = 4
+#: What the two rings may take of VMEM, and the limit the kernel asks
+#: for (v5e has 128 MiB; a kernel gets 16 MB unless it asks).
+_DECODE_RING_BYTES = 24 << 20
 _DECODE_VMEM_LIMIT = 64 << 20
 
 
-def flash_decode_block(s: int, h: int, hd: int, dtype) -> int:
-    """K-block edge of the decode kernel, in positions: whole 128-lane
-    tiles that divide the cache length, at most 512 and at most a
-    quarter of the cache (what a slot fetches follows its length), and
-    K/V blocks that fit ``_DECODE_VMEM_BYTES`` beside the rest; 0 if
-    there is none."""
-    if s % _LANES:
-        return 0
+def flash_decode_chunk(s: int, h: int, hd: int, dtype, group: int = 1) -> int:
+    """The granule the decode kernel fetches a slot's cache in, in
+    positions: whole 128-lane tiles that divide the cache length and
+    whose two rings fit ``_DECODE_RING_BYTES``; 0 if there is none.
+    One query head a cached head is vector work a lane tile at a time,
+    so one tile is the granule: what a slot fetches past its length is
+    wasted (v5e, the serving cell's mix at 48 x 1024 x 16 x 64 bf16:
+    0.138 / 0.159 / 0.210 ms a call at 128 / 256 / 512).  Grouped
+    queries score a whole chunk in two matrix products, and what a
+    chunk costs there is the loop's turn and the statistics, not its
+    width (32 x 32768 x 8 x 128, 8 query heads a cached one: 2.37 /
+    1.29 / 0.73 ms at 128 / 256 / 512; PERF.md §6 PR 41)."""
     item = jnp.dtype(dtype).itemsize
-    # q, k_new, v_new and the two written tiles, double buffered, and
-    # the f32 accumulator: all (h, hd, 128).
-    room = _DECODE_VMEM_BYTES - h * hd * _LANES * (10 * item + 4)
-    cap = max(room, 0) // (4 * h * hd * item)
-    b = min(512, max(_LANES, s // 4), cap - cap % _LANES)
-    while b >= _LANES and s % b:
-        b -= _LANES
-    return max(b, 0)
+    fits = _DECODE_RING_BYTES // (2 * _DECODE_RING * h * hd * item)
+    for chunk in ((512, 256, 128) if group > 1 else (128,)):
+        if s % chunk == 0 and chunk <= fits:
+            return chunk
+    return 0
 
 
 def flash_decode_supported(cache_shape: Tuple[int, ...],
@@ -1286,7 +1304,107 @@ def flash_decode_supported(cache_shape: Tuple[int, ...],
         return False
     if group > 1 and hd % _LANES:
         return False
-    return flash_decode_block(s, h, hd, dtype) >= _LANES
+    return flash_decode_chunk(s, h, hd, dtype, group) >= _LANES
+
+
+def _lane_tile(t):
+    """The ``t``-th 128-lane tile of a ref's last axis."""
+    return pl.ds(pl.multiple_of(t * _LANES, _LANES), _LANES)
+
+
+def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
+               sem, wsem, cur, *, whole, last, emit):
+    """One slot (grid step) of the decode kernels' cache traffic.
+
+    ``k_hbm``/``v_hbm`` (B, h, hd, S) stay in HBM.  The live chunks of
+    all slots, in slot order, are one stream of (h, hd, chunk) DMA
+    pairs through ``ring_k``/``ring_v``: pair i of the stream lands in
+    ring slot ``i % depth``, and a ring slot once scored takes the next
+    pair of the stream, a later slot's too.  ``cur`` carries the stream
+    over the grid: pairs scored so far, and the slot and chunk to fetch
+    next.
+
+    ``whole(k, v)`` scores a chunk wholly below ``pos`` from the ring
+    refs ``k``, ``v`` (h, hd, chunk).  ``last(k, v, at, place)``
+    gets the chunk that holds ``pos`` (at offset ``at``): for every
+    head ``i`` it calls ``place(0, k, i)`` and ``place(1, v, i)``, which
+    store this step's column into the lane tile that holds ``at`` and
+    return that tile (hd, 128), and scores the chunk up to ``at``; the
+    tile then goes back to the cache while ``emit()`` writes the slot's
+    output.  The columns come in ``new_ref`` (2, h, hd / p, slots ->
+    lanes) as 32-bit words of p values each, the packing a vector
+    register holds the cache's dtype in, so that a column moves to its
+    lane by one rotate a register and is selected into the tile word
+    for word."""
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    depth, _, _, chunk = ring_k.shape
+    pairs = ((k_hbm, ring_k), (v_hbm, ring_v))
+
+    def live(i):
+        return lax.div(len_ref[i] + (chunk - 1), chunk)
+
+    def fetch(i, j, k):
+        at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        return [pltpu.make_async_copy(hbm.at[i, :, :, at], ring.at[k],
+                                      sem.at[n, k])
+                for n, (hbm, ring) in enumerate(pairs)]
+
+    def fetch_next(k, fb, fj):
+        @pl.when(fb < slots)
+        def _():
+            for copy in fetch(fb, fj, k):
+                copy.start()
+
+        done = fj + 1 >= live(jnp.minimum(fb, slots - 1))
+        return jnp.where(done, fb + 1, fb), jnp.where(done, 0, fj + 1)
+
+    @pl.when(b == 0)
+    def _prime():
+        fb = fj = jnp.int32(0)
+        for k in range(depth):
+            fb, fj = fetch_next(k, fb, fj)
+        cur[0], cur[1], cur[2] = jnp.int32(0), fb, fj
+
+    n = live(b)
+    pos = len_ref[b] - 1
+
+    def below(j, c):
+        i, fb, fj = c
+        k = lax.rem(i, depth)
+        for copy in fetch(b, j, k):
+            copy.wait()
+        whole(ring_k.at[k], ring_v.at[k])
+        return (i + 1, *fetch_next(k, fb, fj))
+
+    i, fb, fj = lax.fori_loop(0, n - 1, below, (cur[0], cur[1], cur[2]))
+    k = lax.rem(i, depth)
+    for copy in fetch(b, n - 1, k):
+        copy.wait()
+    at = pos - (n - 1) * chunk
+    tile, lane = _lane_tile(at // _LANES), lax.rem(at, _LANES)
+    here = _lane_iota(new_ref.shape[2]) == lane
+
+    def place(which, ref, head):
+        column = _move_lane(new_ref[which, head, :, _lane_tile(b // _LANES)],
+                            lax.rem(b, _LANES), lane)
+        words = pltpu.bitcast(ref[head, :, tile], jnp.uint32)
+        ref[head, :, tile] = new = pltpu.bitcast(
+            jnp.where(here, column, words), ref.dtype)
+        return new
+
+    last(ring_k.at[k], ring_v.at[k], at, place)
+    writes = [pltpu.make_async_copy(ring.at[k, :, :, tile],
+                                    hbm.at[b, :, :, _lane_tile(pos // _LANES)],
+                                    wsem.at[n])
+              for n, (hbm, ring) in enumerate(pairs)]
+    for write in writes:
+        write.start()
+    emit()
+    for write in writes:
+        write.wait()
+    cur[0] = i + 1
+    cur[1], cur[2] = fetch_next(k, fb, fj)
 
 
 def _decode_tile(s, v, m, l, acc, valid=None):
@@ -1302,16 +1420,15 @@ def _decode_tile(s, v, m, l, acc, valid=None):
     return m_new, l * corr + p, acc * corr + p * v
 
 
-def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                   o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr,
-                   *, block_k, scale, num_kb, heads):
+def _decode_kernel(len_ref, q_ref, new_ref, k_in, v_in,
+                   o_ref, k_hbm, v_hbm, ring_k, ring_v, sem, wsem, cur,
+                   m_scr, l_scr, acc_scr, q_scr, *, scale, heads):
+    # k_hbm, v_hbm are k_in's and v_in's buffers (aliased), in HBM.
+    del k_in, v_in
+    _, h, hd, chunk = ring_k.shape
+    tiles = chunk // _LANES
+    lanes = _lane_iota(hd)
     b = pl.program_id(0)
-    kb = pl.program_id(1)
-    _, h, hd, _ = k_ref.shape
-    tiles = block_k // _LANES
-    pos = len_ref[b] - 1
-    last = pos // _LANES            # the lane tile that holds ``pos``
-    first = kb * tiles              # this block's first lane tile
 
     def by_heads(body, carry=0):
         """``body(i, carry)`` over the heads, ``heads`` of them unrolled
@@ -1323,24 +1440,39 @@ def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
         return lax.fori_loop(0, h // heads, group, carry)
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    # A slot restarts its statistics with ``m`` alone: against a fresh
+    # ``m`` a lane's first ``exp(m - m_new)`` is 0, so what the slot
+    # before left in ``l`` and ``acc`` (finite) counts for nothing.
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+
+    @pl.when(b == 0)
+    def _clear():
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def load(ref, i, t):
-        start = pl.multiple_of(t * _LANES, _LANES)
-        return ref[0, i, :, pl.ds(start, _LANES)].astype(jnp.float32)
+        return ref[i, :, _lane_tile(t)].astype(jnp.float32)
+
+    # This slot's query, a column of the resident operand (the slots
+    # along its lanes), laid along the lanes once for all its tiles:
+    # one product with a matrix whose row ``b % 128`` is ones puts the
+    # column on every lane, for all heads, on the otherwise idle matrix
+    # unit and exactly (a lane rotate and broadcast a head cost the
+    # vector unit 0.39 us a slot, XLA's broadcast 12.3 us a call:
+    # PERF.md §6 PR 41).
+    pick = (lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+            == lax.rem(b, _LANES)).astype(q_ref.dtype)
+    q_scr[...] = jnp.dot(
+        q_ref[:, :, _lane_tile(b // _LANES)].reshape(h * hd, _LANES), pick,
+        preferred_element_type=jnp.float32,
+        precision=(lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
+                   else lax.Precision.DEFAULT)).reshape(h, hd, _LANES)
 
     def score(i, k):
-        q = q_ref[0, i].astype(jnp.float32)                     # (hd, 128)
-        return jnp.sum(q * k, axis=0, keepdims=True) * scale
+        return jnp.sum(q_scr[i] * k, axis=0, keepdims=True) * scale
 
-    # The block's whole tiles below ``pos``: nothing to mask.  Blocks
-    # past the slot's last live one do no work (and were not fetched).
-    @pl.when(first < last)
-    def _whole_tiles():
+    def whole_tiles(k_ref, v_ref, count):
+        """The chunk's first ``count`` lane tiles: nothing to mask."""
         def group(g, c):
             ids = [g * heads + j for j in range(heads)]
 
@@ -1351,7 +1483,7 @@ def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                     for i, state in zip(ids, states))
 
             states = lax.fori_loop(
-                0, jnp.minimum(last - first, tiles), tile,
+                0, count, tile,
                 tuple((m_scr[i], l_scr[i], acc_scr[i]) for i in ids))
             for i, state in zip(ids, states):
                 m_scr[i], l_scr[i], acc_scr[i] = state
@@ -1359,72 +1491,62 @@ def _decode_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
         lax.fori_loop(0, h // heads, group, 0)
 
-    # The tile that holds ``pos``: this step's column goes in, the tile
-    # goes back to the cache, and lanes past ``pos`` are masked.
-    @pl.when((first <= last) & (last < first + tiles))
-    def _last_tile():
-        at = pos - last * _LANES
-        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
+    def last(k_ref, v_ref, at, place):
+        t = at // _LANES
+        if tiles > 1:
+            pl.when(t > 0)(lambda: whole_tiles(k_ref, v_ref, t))
 
+        # The tile that holds ``pos``: this step's column goes in and
+        # lanes past ``pos`` are masked.
         def head(i, c):
-            k = jnp.where(lane == at, kn_ref[0, i].astype(jnp.float32),
-                          load(k_ref, i, last - first))
-            v = jnp.where(lane == at, vn_ref[0, i].astype(jnp.float32),
-                          load(v_ref, i, last - first))
-            ko_ref[0, i] = k.astype(ko_ref.dtype)
-            vo_ref[0, i] = v.astype(vo_ref.dtype)
+            k = place(0, k_ref, i).astype(jnp.float32)
+            v = place(1, v_ref, i).astype(jnp.float32)
             m_scr[i], l_scr[i], acc_scr[i] = _decode_tile(
                 score(i, k), v, m_scr[i], l_scr[i], acc_scr[i],
-                valid=lane[:1] <= at)
+                valid=lanes[:1] <= lax.rem(at, _LANES))
             return c
 
         by_heads(head)
 
-    @pl.when(kb == num_kb - 1)
-    def _emit():
-        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
-
+    def emit():
         def head(i, out):
             m = m_scr[i]
             w = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))
             o = (jnp.sum(acc_scr[i] * w, axis=1, keepdims=True)
                  / jnp.sum(l_scr[i] * w, axis=1, keepdims=True))   # (hd, 1)
-            return jnp.where(lane == i, o, out)
+            return jnp.where(lanes == i, o, out)
 
         out = by_heads(head, jnp.zeros((hd, _LANES), jnp.float32))
         o_ref[0] = out.astype(o_ref.dtype)
 
+    _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
+               sem, wsem, cur, last=last, emit=emit,
+               whole=lambda k_ref, v_ref: whole_tiles(k_ref, v_ref, tiles))
 
-def _decode_grouped_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                           o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr,
-                           *, block_k, scale, num_kb):
+
+def _decode_grouped_kernel(len_ref, q_ref, new_ref, k_in, v_in,
+                           o_ref, k_hbm, v_hbm, ring_k, ring_v, sem, wsem,
+                           cur, m_scr, l_scr, acc_scr, *, scale):
     """The grouped-query body: ``q_ref`` (1, h_kv, g, hd) holds the g
-    query heads of every cached head as rows, so a live K tile (hd, 128)
-    is fetched once for its group and scores and values are two small
-    matrix products a tile; softmax statistics a row.  Blocks, index
-    maps, the written tile and the aliasing are ``_decode_kernel``'s
+    query heads of every cached head as rows, so a live K chunk
+    (hd, chunk) is fetched once for its group and scores and values are
+    two small matrix products a chunk; softmax statistics a row.  The
+    stream, the written tile and the aliasing are ``_decode_kernel``'s
     (one launch, ``_decode_call``)."""
-    b = pl.program_id(0)
-    kb = pl.program_id(1)
-    _, h, hd, _ = k_ref.shape
-    tiles = block_k // _LANES
-    pos = len_ref[b] - 1
-    last = pos // _LANES
-    first = kb * tiles
+    del k_in, v_in
+    _, h, _, chunk = ring_k.shape
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def tile(ref, i, t):
-        start = pl.multiple_of(t * _LANES, _LANES)
-        return ref[0, i, :, pl.ds(start, _LANES)]                 # (hd, 128)
-
-    def step(i, k, v, state, valid=None):
-        """``k``, ``v`` (hd, w): one lane tile, or the whole block."""
-        m, l, acc = state                       # (g, 128) x2, (g, hd)
+    # A chunk goes through in one step: the statistics' lane reductions
+    # and the loop's turns, not the products, are what a lane tile at a
+    # time costs (5.6 ms a call at 32 slots of 8192 positions, PERF.md
+    # §6 PR 33).
+    def step(i, k, v, valid=None):
+        """``k``, ``v`` (hd, chunk) of head ``i``."""
+        m, l, acc = m_scr[i], l_scr[i], acc_scr[i]   # (g, 128) x2, (g, hd)
         s = jnp.dot(q_ref[0, i], k, precision=_mxu_precision(k.dtype),
                     preferred_element_type=jnp.float32) * scale    # (g, w)
         if valid is not None:
@@ -1437,62 +1559,37 @@ def _decode_grouped_kernel(len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
         pv = lax.dot_general(p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
                              precision=_mxu_precision(v.dtype),
                              preferred_element_type=jnp.float32)   # (g, hd)
-        return (m_new, l * corr + jnp.sum(p, axis=1, keepdims=True),
-                acc * corr[:, :1] + pv)
+        m_scr[i] = m_new
+        l_scr[i] = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[i] = acc * corr[:, :1] + pv
 
-    # A block wholly below ``pos`` (all but a slot's last live one) goes
-    # through in one step: the statistics' lane reductions and the loop's
-    # turns, not the products, are what a tile costs (5.6 ms a call at 32
-    # slots of 8192 positions a tile at a time, PERF.md §6 PR 33).
-    @pl.when(last - first >= tiles)
-    def _whole_block():
+    def over_heads(body):
         def head(i, c):
-            m_scr[i], l_scr[i], acc_scr[i] = step(
-                i, k_ref[0, i], v_ref[0, i],
-                (m_scr[i], l_scr[i], acc_scr[i]))
+            body(i)
             return c
 
         lax.fori_loop(0, h, head, 0)
 
-    @pl.when((first < last) & (last - first < tiles))
-    def _whole_tiles():
-        def head(i, c):
-            state = lax.fori_loop(
-                0, last - first,
-                lambda t, st: step(i, tile(k_ref, i, t), tile(v_ref, i, t), st),
-                (m_scr[i], l_scr[i], acc_scr[i]))
-            m_scr[i], l_scr[i], acc_scr[i] = state
-            return c
+    def last(k_ref, v_ref, at, place):
+        valid = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) <= at
 
-        lax.fori_loop(0, h, head, 0)
+        def head(i):
+            place(0, k_ref, i)
+            place(1, v_ref, i)
+            step(i, k_ref[i], v_ref[i], valid)
 
-    @pl.when((first <= last) & (last < first + tiles))
-    def _last_tile():
-        at = pos - last * _LANES
-        lane = lax.broadcasted_iota(jnp.int32, (hd, _LANES), 1)
+        over_heads(head)
 
-        def head(i, c):
-            k = jnp.where(lane == at, kn_ref[0, i].astype(jnp.float32),
-                          tile(k_ref, i, last - first).astype(jnp.float32))
-            v = jnp.where(lane == at, vn_ref[0, i].astype(jnp.float32),
-                          tile(v_ref, i, last - first).astype(jnp.float32))
-            k, v = k.astype(ko_ref.dtype), v.astype(vo_ref.dtype)
-            ko_ref[0, i] = k
-            vo_ref[0, i] = v
-            m_scr[i], l_scr[i], acc_scr[i] = step(
-                i, k, v, (m_scr[i], l_scr[i], acc_scr[i]),
-                valid=lane[:1] <= at)
-            return c
-
-        lax.fori_loop(0, h, head, 0)
-
-    @pl.when(kb == num_kb - 1)
-    def _emit():
-        def head(i, c):
+    def emit():
+        def head(i):
             o_ref[0, i] = (acc_scr[i] / l_scr[i][:, :1]).astype(o_ref.dtype)
-            return c
 
-        lax.fori_loop(0, h, head, 0)
+        over_heads(head)
+
+    _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
+               sem, wsem, cur, last=last, emit=emit,
+               whole=lambda k_ref, v_ref: over_heads(
+                   lambda i: step(i, k_ref[i], v_ref[i])))
 
 
 def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
@@ -1511,7 +1608,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
     store the first form row-major there.  ``lengths``: (B,) int32
     in ``1..max_seq``.  ``k_new``/``v_new`` are stored at position
     ``lengths[b] - 1`` (in the cache's dtype) and the query
-    attends key positions ``< lengths[b]``, its own among them.
+    attends key positions ``< lengths[b]``, its own among them.  What a
+    slot costs follows its length: its live chunks
+    (:func:`flash_decode_chunk`) are what is fetched and scored, and
+    the lane tile that holds the new column is what is written.
     Returns ``(out (B, h_q, hd) in q.dtype, cache_k, cache_v)``; donate
     the caches and the write is in place.  Callers gate on
     :func:`flash_decode_supported`.
@@ -1546,37 +1646,43 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
         cache_v = cache_v.transpose(0, 2, 3, 1)
     b, h, hd, s = cache_k.shape
     group = q.shape[1] // h
-    block_k = flash_decode_block(s, h, hd, cache_k.dtype)
-    num_kb = s // block_k
-    common = dict(block_k=block_k, scale=1.0 / math.sqrt(hd), num_kb=num_kb)
+    chunk = flash_decode_chunk(s, h, hd, cache_k.dtype, group)
+    scale = 1.0 / math.sqrt(hd)
 
-    def slot(bi, ki, lens):
+    def slot(bi, lens):
         return (bi, 0, 0, 0)
 
-    def block(bi, ki, lens):
-        return (bi, 0, 0, jnp.minimum(ki, lax.div(lens[bi] - 1, block_k)))
+    def columns(*xs):
+        """(B, h, hd) arrays stacked, in the cache's dtype, with the
+        slots along the lanes and ``pack`` neighbours along ``hd`` to a
+        32-bit word, as a vector register packs them
+        (``pltpu.bitcast``'s order): (len(xs), h, hd / pack, B -> 128s)."""
+        pack = 4 // jnp.dtype(cache_k.dtype).itemsize
+        x = jnp.stack(xs).astype(cache_k.dtype)
+        x = lax.bitcast_convert_type(
+            x.reshape(len(xs), b, h, hd // pack, pack), jnp.uint32)
+        return jnp.pad(x.reshape(len(xs), b, h, hd // pack).transpose(0, 2, 3, 1),
+                       ((0, 0),) * 3 + ((0, _round_up(b, _LANES) - b),))
 
-    def written(bi, ki, lens):
-        return (bi, 0, 0, lax.div(lens[bi] - 1, _LANES))
-
-    def along_lanes(x, dtype):
-        return jnp.broadcast_to(x.astype(dtype)[..., None],
-                                (b, h, hd, _LANES))
-
-    tile = pl.BlockSpec((1, h, hd, _LANES), slot)
+    resident = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     if group == 1:
         kernel = functools.partial(
-            _decode_kernel, heads=next(n for n in (4, 2, 1) if h % n == 0),
-            **common)
-        q_in, q_spec = along_lanes(q, q.dtype), tile
+            _decode_kernel, scale=scale,
+            heads=next(n for n in (4, 2, 1) if h % n == 0))
+        # The queries too with the slots along the lanes, resident.
+        q_in = jnp.pad(q.transpose(1, 2, 0),
+                       ((0, 0), (0, 0), (0, _round_up(b, _LANES) - b)))
+        q_spec = resident
         # The output has the heads along the lanes, a whole tile of them.
         o_shape = (b, hd, _LANES)
-        o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, ki, lens: (bi, 0, 0))
-        state = [(h, 1, _LANES), (h, 1, _LANES), (h, hd, _LANES)]
+        o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, lens: (bi, 0, 0))
+        state = [(h, 1, _LANES), (h, 1, _LANES), (h, hd, _LANES),
+                 (h, hd, _LANES)]
     else:
         # Queries and outputs (B, h, g, hd): a group's heads as rows,
         # padded to a whole packed sublane tile of the query's dtype.
-        kernel = functools.partial(_decode_grouped_kernel, **common)
+        kernel = functools.partial(_decode_grouped_kernel, scale=scale)
         rows = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
         gp = _round_up(group, rows)
         q_in = jnp.pad(q.reshape(b, h, group, hd),
@@ -1587,25 +1693,31 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
     cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, num_kb),
-        in_specs=[q_spec, tile, tile]
-        + [pl.BlockSpec((1, h, hd, block_k), block)] * 2,
-        out_specs=[o_spec] + [pl.BlockSpec((1, h, hd, _LANES), written)] * 2,
-        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in state],
+        grid=(b,),
+        in_specs=[q_spec, resident, in_hbm, in_hbm],
+        out_specs=[o_spec, in_hbm, in_hbm],
+        scratch_shapes=[
+            pltpu.VMEM((_DECODE_RING, h, hd, chunk), cache_k.dtype),
+            pltpu.VMEM((_DECODE_RING, h, hd, chunk), cache_v.dtype),
+            pltpu.SemaphoreType.DMA((2, _DECODE_RING)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((3,), jnp.int32),
+        ] + [pltpu.VMEM(shape, jnp.float32) for shape in state],
     )
     out, cache_k, cache_v = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(o_shape, q.dtype), cache, cache],
-        # Operands count the scalar prefetch: 4 and 5 are the caches.
-        input_output_aliases={4: 1, 5: 2},
+        # Operands count the scalar prefetch: 3 and 4 are the caches.
+        input_output_aliases={3: 1, 4: 2},
+        # The grid's steps share the rings and follow one another.
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_VMEM_LIMIT),
         name="ff_flash_decode",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q_in,
-      along_lanes(k_new, cache_k.dtype), along_lanes(v_new, cache_v.dtype),
-      cache_k, cache_v)
+    )(jnp.clip(lengths.astype(jnp.int32), 1, s), q_in,
+      columns(k_new, v_new), cache_k, cache_v)
     if group == 1:
         out = jnp.swapaxes(out[:, :, :h], 1, 2)
     else:
@@ -2560,17 +2672,16 @@ def _mla_decode_kernel(len_ref, q_ref, col_ref, cache_in, o_ref, cache_ref,
     k = lax.rem(i, depth)
     fetch(b, n - 1, k).wait()
 
-    def lanes(t):
-        return pl.ds(pl.multiple_of(t * _LANES, _LANES), _LANES)
-
     at = pos - (n - 1) * chunk
-    tile, lane = lanes(at // _LANES), lax.rem(at, _LANES)
-    col = _move_lane(col_ref[:, lanes(b // _LANES)], lax.rem(b, _LANES), lane)
+    tile, lane = _lane_tile(at // _LANES), lax.rem(at, _LANES)
+    col = _move_lane(col_ref[:, _lane_tile(b // _LANES)], lax.rem(b, _LANES),
+                     lane)
     ring[k, :, tile] = jnp.where(
         _lane_iota(col.shape[0]) == lane, col,
         ring[k, :, tile].astype(col.dtype)).astype(ring.dtype)
     write = pltpu.make_async_copy(
-        ring.at[k, :, tile], cache_ref.at[b, :, lanes(pos // _LANES)], wsem)
+        ring.at[k, :, tile], cache_ref.at[b, :, _lane_tile(pos // _LANES)],
+        wsem)
     write.start()
     _, l, acc = step(ring[k], state, valid=lax.broadcasted_iota(
         jnp.int32, (h, chunk), 1) <= at)

@@ -418,6 +418,80 @@ def test_supported_gates_match_the_compiler():
     assert not pk.grouped_matmul_supported(64, 32, BF16)
 
 
+#: The padded caches of the two cells whose decode step is
+#: ``ff_flash_decode``: (slots, max_seq, cached heads, d_head), query
+#: heads, the model width that gives them.
+_DECODE_CELLS = {
+    "gpt2m.serve.closed48": ((48, 1024, 16, 64), 16, 1024),
+    "solar2.serve.closed32.p4k-31k": ((32, 32768, 8, 128), 64, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_decode_gate_and_granule_hold_for_the_cells(cell):
+    """Both cells' caches pass ``flash_decode_supported`` and get a
+    chunk of whole lane tiles that divides the cache and whose two
+    rings leave the scoped VMEM the kernel asks for half empty."""
+    (slots, seq, h, hd), heads, _ = _DECODE_CELLS[cell]
+    group = heads // h
+    assert pk.flash_decode_supported((slots, seq, h, hd), BF16, group)
+    chunk = pk.flash_decode_chunk(seq, h, hd, BF16, group)
+    assert chunk in ((512,) if group > 1 else (128, 256))
+    assert seq % chunk == 0 and chunk % 128 == 0
+    assert 2 * pk._DECODE_RING * h * hd * chunk * 2 <= pk._DECODE_VMEM_LIMIT // 2
+
+
+@pytest.mark.parametrize("cell,c", [("gpt2m.serve.closed48", 1),
+                                    ("gpt2m.serve.closed48", 2),
+                                    ("solar2.serve.closed32.p4k-31k", 1)])
+def test_decode_fetch_block_is_the_kernels_granule(cell, c):
+    """``serve_kv_fetch_pct`` rounds a slot's length up to
+    ``Op.decode_fetch_block``: for ``MultiHeadAttention``, with and
+    without grouped queries and with the heads split over ``c``, that
+    is the chunk the kernel moves (``flash_decode_chunk`` at the local
+    shape), and the whole cache where the kernel is off."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+    from flexflow_tpu.ops.base import TensorSpec
+
+    (slots, seq, h, hd), heads, d = _DECODE_CELLS[cell]
+    x = TensorSpec("x", (slots, seq, d), BF16, ("n", "s", None))
+    op = MultiHeadAttention("attn", x, heads, num_kv_heads=h, head_dim=hd)
+    want = pk.flash_decode_chunk(seq, h // c, hd, BF16, heads // h)
+    assert want >= 128
+    assert op.decode_fetch_block(slots, seq, None, c) == want
+    assert op.decode_fetch_block(slots, seq, True, c) == want
+    assert op.decode_fetch_block(slots, seq, False, c) == seq
+
+
+def test_sharded_decode_kernel_compiles_for_four_chips():
+    """``chip_smoke.py --chips 4``'s ``serve4`` phase: ``flash_decode``
+    under ``shard_map``, the batch on ``n`` and the cached heads on
+    ``c``, its caches in HBM and aliased, lowers through Mosaic for the
+    four described devices."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(_four_chips()).reshape(2, 2), ("n", "c"))
+    b, s, h, hd = 8, 512, 8, 64
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    q_spec, kv_spec = P("n", "c", None), P("n", None, "c", None)
+    fn = jax.shard_map(
+        lambda q, k1, v1, ck, cv, pos: pk.flash_decode(
+            q, k1, v1, ck, cv, pos + 1, interpret=False),
+        mesh=mesh,
+        in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec, P("n")),
+        out_specs=(q_spec, kv_spec, kv_spec), check_vma=False)
+    small, cache = sds((b, h, hd), F32, q_spec), sds((b, s, h, hd), F32, kv_spec)
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        small, small, small, cache, cache,
+        sds((b,), jnp.int32, P("n"))).compile().as_text()
+    assert chip_smoke.has_kernel(text, "ff_flash_decode")
+
+
 @pytest.mark.parametrize("slots,seq", [(16, 16384), (96, 4096)])
 def test_latent_decode_reads_the_cache_where_it_lies(slots, seq):
     """No copy of the latent cache stands in front of the decode kernel

@@ -144,52 +144,78 @@ def _req(rid, prompt, max_new=5):
                    max_new_tokens=max_new)
 
 
-# The decode kernel reads the cache positions-along-lanes in blocks of
-# whole 128-position lane tiles and writes the step's own K/V column
+# The decode kernel reads the cache positions-along-lanes, a slot's
+# live chunks of whole 128-position lane tiles through one ring of DMAs
+# shared by all slots, and writes the step's own K/V column
 # (ops/pallas_kernels.flash_decode).  Interpret mode = the chip's code
 # path.  One compile a (shape, dtype): lengths are data.
 
-KS, KH, KHD = 1024, 2, 16   # 256-position blocks, four a cache
+KS, KH, KHD = 1024, 2, 16
+#: The granule of the plain body at that shape, and of the grouped body
+#: (4 query heads a cached head of 128) at the same cache length.
+KC = pallas_kernels.flash_decode_chunk(KS, KH, KHD, jnp.float32)
+GHD, GROUP = 128, 4
+GC = pallas_kernels.flash_decode_chunk(KS, KH, GHD, jnp.float32, GROUP)
 
 
 @pytest.fixture(scope="module")
 def decode_case():
-    """``run(lengths, dtype) -> (kernel out/caches, oracle out/caches)``
-    on seeded caches of ``len(lengths)`` slots: the oracle scatters the
-    new column and runs ``_einsum_decode``."""
-    fn = jax.jit(pallas_kernels.flash_decode)
+    """``run(lengths, dtype, grouped, positions_last) -> (kernel
+    out/caches, oracle out/caches, inputs)`` on seeded caches of
+    ``len(lengths)`` slots: the oracle writes the new column with one
+    ``dynamic_update_slice`` a slot and runs ``_einsum_decode``.
+    ``grouped`` takes the matrix-unit body (GROUP query heads a cached
+    head of GHD); ``positions_last`` hands the caches over as
+    (B, h, hd, S), the order an op with wide heads declares."""
+    fn = jax.jit(pallas_kernels.flash_decode,
+                 static_argnames=("positions_last",))
 
-    def run(lengths, dtype=jnp.float32):
+    def run(lengths, dtype=jnp.float32, grouped=False, positions_last=False):
         r = np.random.default_rng(1)
         B = len(lengths)
-        q, kn, vn = (jnp.asarray(r.standard_normal((B, KH, KHD)), dtype)
-                     for _ in range(3))
-        ck, cv = (jnp.asarray(r.standard_normal((B, KS, KH, KHD)), dtype)
+        hd, group = (GHD, GROUP) if grouped else (KHD, 1)
+        q = jnp.asarray(r.standard_normal((B, KH * group, hd)), dtype)
+        kn, vn = (jnp.asarray(r.standard_normal((B, KH, hd)), dtype)
+                  for _ in range(2))
+        ck, cv = (jnp.asarray(r.standard_normal((B, KS, KH, hd)), dtype)
                   for _ in range(2))
         lens = jnp.asarray(lengths, jnp.int32)
-        assert pallas_kernels.flash_decode_supported(ck.shape, dtype)
-        rows = jnp.arange(B)
-        ek = ck.at[rows, lens - 1].set(kn)
-        ev = cv.at[rows, lens - 1].set(vn)
+        assert pallas_kernels.flash_decode_supported(ck.shape, dtype, group)
+
+        def put(cache, new):
+            for b, n in enumerate(lengths):
+                cache = jax.lax.dynamic_update_slice(
+                    cache, new[b][None, None], (b, n - 1, 0, 0))
+            return cache
+
+        ek, ev = put(ck, kn), put(cv, vn)
         want = _einsum_decode(q, ek, ev, lens - 1), ek, ev
-        return fn(q, kn, vn, ck, cv, lens), want, (ck, cv, kn, vn)
+        if positions_last:
+            out, gk, gv = fn(q, kn, vn, ck.transpose(0, 2, 3, 1),
+                             cv.transpose(0, 2, 3, 1), lens,
+                             positions_last=True)
+            got = out, gk.transpose(0, 3, 1, 2), gv.transpose(0, 3, 1, 2)
+        else:
+            got = fn(q, kn, vn, ck, cv, lens)
+        return got, want, (ck, cv, kn, vn)
 
     return run
 
 
-def test_decode_block_comes_from_the_shape():
-    """Whole lane tiles, 128 to 512, at most a quarter of the cache,
-    inside the VMEM the K/V blocks may hold beside the slot's operands
-    and state; anything else takes the einsum oracle."""
-    blk = pallas_kernels.flash_decode_block
-    assert blk(KS, KH, KHD, jnp.float32) == 256
-    assert blk(1024, 16, 64, jnp.bfloat16) == 256
-    assert blk(128, 2, 16, jnp.float32) == 128
-    assert blk(512, 8, 64, jnp.bfloat16) == 128
-    assert blk(4096, 16, 128, jnp.bfloat16) == 512
-    assert blk(4096, 32, 128, jnp.float32) == 256  # the VMEM plan
-    assert blk(8192, 64, 128, jnp.float32) == 0    # no block fits
-    assert blk(1000, 2, 16, jnp.float32) == 0
+def test_decode_chunk_comes_from_the_shape():
+    """Whole lane tiles that divide the cache, by the body that reads
+    them (a lane tile at a time on the vector unit, a whole chunk in
+    two matrix products under grouped queries) and inside the VMEM the
+    two rings may take; anything else takes the einsum oracle."""
+    chunk = pallas_kernels.flash_decode_chunk
+    assert chunk(1024, 16, 64, jnp.bfloat16) == KC == 128
+    assert chunk(128, 2, 16, jnp.float32) == 128
+    assert chunk(32768, 8, 128, jnp.bfloat16, 8) == 512
+    assert chunk(2048, 2, 128, jnp.float32, 4) == GC == 512
+    assert chunk(256, 2, 128, jnp.float32, 4) == 256
+    assert chunk(4096, 32, 128, jnp.float32, 2) == 128   # the rings' VMEM
+    assert chunk(8192, 128, 512, jnp.float32) == 0       # no chunk fits
+    assert chunk(1000, 2, 16, jnp.float32) == 0
     sup = pallas_kernels.flash_decode_supported
     assert not sup((4, 32, 2, 16), jnp.float32)      # not whole lane tiles
     assert not sup((4, 128, 2, 8), jnp.bfloat16)     # hd under a bf16 tile
@@ -197,25 +223,92 @@ def test_decode_block_comes_from_the_shape():
     assert not sup((4, 128, 2), jnp.float32)
 
 
+def _same(got, want, tol=1e-5):
+    (out, ck, cv), (ref, ek, ev) = got, want
+    assert float(jnp.max(jnp.abs(out - ref))) < tol
+    assert bool(jnp.array_equal(ck, ek)) and bool(jnp.array_equal(cv, ev))
+
+
 # 1; a lane tile's last position, its edge and the first of the next;
-# a block's edge (256) and the first of the next; the last block; the
-# whole cache.
+# a chunk's last position, its edge and the first of the next, for the
+# granule of either body; the last chunk; the whole cache.
 @pytest.mark.parametrize(
-    "length", [1, 127, 128, 129, 256, 257, 769, 1023, 1024])
+    "length", [1, 127, 128, 129, 255, 256, 257, 511, 512, 513, 769, 1023,
+               1024])
 def test_decode_kernel_matches_oracle_at_length(decode_case, length):
     """Attention over ``length`` positions, the step's own among them,
     pinned against the jnp oracle -- with an idle slot (length 1) and a
     full one beside it."""
-    (out, ck, cv), (want, ek, ev), _ = decode_case([length, 1, KS])
-    assert float(jnp.max(jnp.abs(out - want))) < 1e-5
-    assert bool(jnp.array_equal(ck, ek)) and bool(jnp.array_equal(cv, ev))
+    assert {KC - 1, KC, KC + 1, GC - 1, GC, GC + 1} <= {
+        127, 128, 129, 255, 256, 257, 511, 512, 513}
+    _same(*decode_case([length, 1, KS])[:2])
+
+
+@pytest.mark.parametrize("length", [1, 511, 512, 513, 1024])
+@pytest.mark.parametrize("positions_last", [False, True])
+def test_grouped_decode_kernel_matches_oracle_at_length(
+        decode_case, length, positions_last):
+    """The grouped body (two matrix products a chunk) at its chunk's
+    edges, in both cache orders."""
+    _same(*decode_case([length, 1, KS], grouped=True,
+                       positions_last=positions_last)[:2], tol=2e-5)
+
+
+#: Empty slots (dispatched at length 1) between full and half-full
+#: ones: 1, 8, 1, 1, 5, 1, 8, 1, 3, 1, 1 chunks of 128 (1, 2, 1, 1, 2,
+#: 1, 2, 1, 1, 1, 1 of 512), so that slots start at every phase of the
+#: ring of four, and chunks of later slots are in flight while an
+#: earlier one is scored.
+_STREAM = [1, 1024, 1, 1, 600, 1, 1024, 1, 300, 1, 1]
+
+
+@pytest.mark.parametrize("shift", [0, 1, 2])
+@pytest.mark.parametrize("grouped,positions_last",
+                         [(False, False), (False, True), (True, True)])
+def test_decode_stream_crosses_slots_at_every_ring_phase(
+        decode_case, shift, grouped, positions_last):
+    """One stream of chunk DMAs over all slots: every slot's output and
+    written column equal the oracle's whatever ring slot its first
+    chunk lands in, for both bodies and both cache orders."""
+    lengths = _STREAM[shift:] + _STREAM[:shift]
+    _same(*decode_case(lengths, grouped=grouped,
+                       positions_last=positions_last)[:2], tol=2e-5)
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+@pytest.mark.parametrize("length", [1, 100, 128])
+def test_decode_ring_deeper_than_the_stream(decode_case, grouped, length):
+    """One slot of one chunk: the ring is primed with a single pair of
+    DMAs and nothing follows it."""
+    _same(*decode_case([length], grouped=grouped, positions_last=grouped)[:2],
+          tol=2e-5)
+
+
+def test_decode_columns_span_more_than_one_lane_tile_of_slots():
+    """130 slots: the step's columns enter with the slots along the
+    lanes, two lane tiles of them, and slot 129's comes from the
+    second."""
+    r = np.random.default_rng(3)
+    B, S = 130, 128
+    q, kn, vn = (jnp.asarray(r.standard_normal((B, 1, 8)), jnp.float32)
+                 for _ in range(3))
+    ck, cv = (jnp.asarray(r.standard_normal((B, S, 1, 8)), jnp.float32)
+              for _ in range(2))
+    lens = jnp.asarray(r.integers(1, S + 1, size=B), jnp.int32)
+    rows = jnp.arange(B)
+    ek, ev = ck.at[rows, lens - 1].set(kn), cv.at[rows, lens - 1].set(vn)
+    got = jax.jit(pallas_kernels.flash_decode)(q, kn, vn, ck, cv, lens)
+    _same(got, (_einsum_decode(q, ek, ev, lens - 1), ek, ev))
 
 
 @pytest.mark.parametrize("pos", [0, 127, 128, 255, 256, 1023])
-def test_decode_kernel_writes_the_column_and_nothing_else(decode_case, pos):
+@pytest.mark.parametrize("grouped", [False, True])
+def test_decode_kernel_writes_the_column_and_nothing_else(
+        decode_case, pos, grouped):
     """The new K/V column reads back at ``pos``; its neighbours, the
     rest of the slot and the other slots are untouched, bit for bit."""
-    (_, ck, cv), _, (ck0, cv0, kn, vn) = decode_case([pos + 1, 400])
+    (_, ck, cv), _, (ck0, cv0, kn, vn) = decode_case(
+        [pos + 1, 400], grouped=grouped, positions_last=grouped)
     for got, before, new in ((ck, ck0, kn), (cv, cv0, vn)):
         got, before = np.asarray(got), np.array(before)
         np.testing.assert_array_equal(got[0, pos], np.asarray(new)[0])
@@ -512,12 +605,12 @@ def test_serve_telemetry_stream(lm, weights, tmp_path):
             assert e["kv_rows_fetched"] == e["kv_rows_cache"] == 2 * S * 4
 
 
-def test_kv_rows_round_lengths_up_to_the_kernels_block(lm128):
+def test_kv_rows_round_lengths_up_to_the_kernels_chunk(lm128):
     """``decode_superstep.kv_rows_fetched``: over every slot and the k
-    steps, the live length rounded up to the block ``flash_decode``
-    fetches in (128 at ``max_seq`` 128; 256 at the benchmark's 1024),
-    positions clamped at the cache's end as the superstep clamps
-    them."""
+    steps, the live length rounded up to the chunk ``flash_decode``
+    fetches in (128 at ``max_seq`` 128; ``flash_decode_chunk`` at the
+    benchmark's 1024), positions clamped at the cache's end as the
+    superstep clamps them."""
     kex, oex, _ = _kernel_pair(lm128)
     assert kex.kv_rows(np.array([0, 126]), 3) == {
         "kv_rows_fetched": 6 * 128, "kv_rows_cache": 6 * 128}
@@ -526,10 +619,12 @@ def test_kv_rows_round_lengths_up_to_the_kernels_block(lm128):
                              d_model=D, num_heads=H, num_layers=1,
                              config=FFConfig(batch_size=2)),
         max_batch=2, max_seq=1024, buckets=(8,))
-    # lengths 1, 2 | 255, 256, 257 -> 256, 256 | 256, 256, 512
-    assert big.kv_rows(np.array([0, 254]), 1)["kv_rows_fetched"] == 512
-    assert big.kv_rows(np.array([0, 254]), 3) == {
-        "kv_rows_fetched": 3 * 256 + 256 + 256 + 512,
+    c = pallas_kernels.flash_decode_chunk(1024, H, D // H, jnp.float32)
+    assert c in (128, 256)
+    # lengths 1, 2, 3 | c - 1, c, c + 1 -> c x 3 | c, c, 2 c
+    assert big.kv_rows(np.array([0, c - 2]), 1)["kv_rows_fetched"] == 2 * c
+    assert big.kv_rows(np.array([0, c - 2]), 3) == {
+        "kv_rows_fetched": 3 * c + c + c + 2 * c,
         "kv_rows_cache": 6 * 1024}
     assert big.kv_rows(np.array([1022, 1023]), 2)["kv_rows_fetched"] == 4096
     assert oex.kv_rows(np.array([0, 5]), 2)["kv_rows_fetched"] == 4 * 128

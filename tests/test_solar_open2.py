@@ -275,8 +275,8 @@ def test_the_op_ends_its_scan_at_the_length_on_both_paths(length):
 
 
 @pytest.mark.parametrize("s,lens", [
-    (256, [1, 130, 256]),           # blocks of one lane tile
-    (2048, [700, 2048, 513]),       # blocks of four: whole, partial, last
+    (256, [1, 130, 256]),           # one chunk of two lane tiles
+    (2048, [700, 2048, 513]),       # chunks of four: whole, partial, last
 ])
 def test_grouped_query_kernels_equal_the_einsum_oracle(s, lens):
     """Decode on a positions-last cache (the step's column written on
@@ -288,7 +288,7 @@ def test_grouped_query_kernels_equal_the_einsum_oracle(s, lens):
     k1, v1 = (jnp.asarray(r.normal(size=(b, hkv, hd)), jnp.float32) for _ in range(2))
     ck, cv = (jnp.asarray(r.normal(size=(b, hkv, hd, s)), jnp.float32) for _ in range(2))
     lengths = jnp.asarray(lens, jnp.int32)
-    assert pk.flash_decode_block(s, hkv, hd, jnp.float32) == min(512, s // 2)
+    assert pk.flash_decode_chunk(s, hkv, hd, jnp.float32, 4) == min(512, s)
     assert pk.flash_decode_supported((b, s, hkv, hd), jnp.float32, group=4)
     assert not pk.flash_decode_supported((b, s, hkv, 64), jnp.float32, group=4)
     out, nk, nv = pk.flash_decode(q, k1, v1, ck, cv, lengths, positions_last=True)
