@@ -81,6 +81,8 @@ class Sizes:
     #: is the same argv on one device (strategy / mesh flags dropped).
     serve_solar: Tuple[str, ...]
     serve_xing: Tuple[str, ...]
+    #: The Keye-VL-2.0 preset: prompts longer than its ``topk``.
+    serve_keye: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -122,6 +124,13 @@ FULL = Sizes(
     serve_xing=("--model-config", "xing4-smoke", "--max-seq", "256",
                 "--max-batch", "4", "--requests", "6", "--max-new", "12",
                 "--prompt-len", "100:200", "--buckets", "256",
+                "--dtype", "bfloat16"),
+    # 512 positions under a ``topk`` of 256 and chunks of 128: prompts
+    # of 300-450 select in the prefill's last chunks (its first 256 rows
+    # go through the streamed forward kernel) and in every decode step.
+    serve_keye=("--model-config", "keye-vl2-smoke", "--max-seq", "512",
+                "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                "--prompt-len", "300:450", "--buckets", "512",
                 "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
@@ -652,6 +661,61 @@ def solar_phase(argv: Sequence[str]) -> None:
     compare_tokens("serve/solar", run, oracle, tol=BF16_KERNEL_TOL)
 
 
+def cache_shaped_relayouts(compiled_text: str, caches) -> List[str]:
+    """``table_sized_relayouts`` over caches of any rank, by the cache's
+    own shape (a weight of this preset has as many elements as a cache):
+    a line that names the shape and is no plain bitcast."""
+    return sorted({
+        line for c in jax.tree.leaves(caches)
+        for line in table_sized_relayouts(
+            compiled_text, math.prod(c.shape), CACHE_RELAYOUT_OPS)
+        if "[" + ",".join(map(str, c.shape)) + "]" in line
+        and "calls=%bitcast_fusion" not in line})
+
+
+def keye_phase(argv: Sequence[str]) -> None:
+    """The Keye-VL-2.0 preset through ``apps.serve``: grouped-query
+    attention ops that compose a token selector, three cache entries a
+    layer held positions-major (a position's heads one row), the expert
+    op under its softmax router, the kernels the two programs still hold
+    (the streamed forward over a prefill's leading ``topk`` rows, the
+    grouped product), a decode superstep that moves no cache, and a
+    superstep's event counting ``topk`` fetched rows a slot a step."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+
+    run = serve_run("serve/keye", argv)
+    sex = run.srv.ex
+    check(all(isinstance(op, MultiHeadAttention) and op.select is not None
+              for op in sex.attn_ops)
+          and any(op.name.endswith("_moe") and op.attrs["router"] == "softmax"
+                  for op in sex._layers),
+          "serve/keye: the served graph lacks the selector or the router")
+    caches = sex.init_cache()
+    op = sex.attn_ops[0]
+    row = op.attrs["num_kv_heads"] * op.attrs["head_dim"]
+    want = {"k": (sex.max_batch, sex.max_seq, row),
+            "v": (sex.max_batch, sex.max_seq, row),
+            "idx": (sex.max_batch, sex.max_seq, op.select.head_dim)}
+    got = {e: tuple(c.shape) for e, c in caches[op.name].items()}
+    check(got == want, f"serve/keye: caches {got}, expected {want}")
+    check(sex._attention_paths(False) == "gqa_select_dense"
+          and sex._attention_paths(True) == "gqa_select_decode",
+          "serve/keye: the programs announce other paths")
+    decode = check_program_kernels(
+        "serve/keye", run, caches, decode=("ff_grouped_matmul",),
+        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
+    moved = cache_shaped_relayouts(decode, caches)
+    check(not moved, f"serve/keye: the compiled decode superstep moves a "
+                     f"whole cache: {moved[:3]}")
+    k = int(run.stats["decode_steps_per_call"])
+    rows = sex.kv_rows(np.full((sex.max_batch,), 400, np.int32), k)
+    check(rows["kv_rows_fetched"] == sex.max_batch * k * op.select.topk
+          and rows["idx_rows_fetched"] == rows["kv_rows_cache"],
+          f"serve/keye: a superstep at 400 live positions reports {rows}")
+    check(all(len(r.prompt) > op.select.topk for r in run.requests),
+          "serve/keye: a prompt under topk: nothing was selected")
+
+
 # -- four chips ---------------------------------------------------------------
 
 
@@ -771,6 +835,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
         ("serve/solar", lambda: solar_phase(sz.serve_solar)),
         ("serve/xing", lambda: latent_phase(sz.serve_xing, "serve/xing",
                                             streams=4)),
+        ("serve/keye", lambda: keye_phase(sz.serve_keye)),
     ]
 
 

@@ -342,7 +342,7 @@ FF008_KERNEL_NAMES = frozenset({
     "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
     "ff_gather_rows", "ff_scatter_add_rows",
 })
-FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt"})
+FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt", "ff_index", "ff_select"})
 
 #: Receiver names that mark an ``.emit(...)`` call as a telemetry
 #: emission (vs some unrelated emit API).

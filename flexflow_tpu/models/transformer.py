@@ -234,8 +234,61 @@ def _solar_open2_lm(ff: FFModel, tok, m: Dict[str, Any]):
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
 
+def _keye_vl2_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """The language model of the Keye-VL-2.0 family (``model_type``
+    ``KeyeVL2``): the Qwen3-MoE block (RMSNorm pre-norm, grouped-query
+    attention with an RMSNorm over each head of q and k and rotary
+    positions on the whole head under ``mrope_section``, every layer an
+    expert layer under a softmax top-k router, no shared expert, no bias,
+    an untied head) with a learned token selector inside the attention
+    (``sa_config``: ``ops/token_select.py``).  The vision tower is not
+    built: the graph takes token ids, for which the three position
+    components are the token's index."""
+    rope = m.get("rope_scaling") or {}
+    for key, got, want in (
+            ("attention_bias", m.get("attention_bias", False), False),
+            ("use_sliding_window", m.get("use_sliding_window", False), False),
+            ("mlp_only_layers", list(m.get("mlp_only_layers", [])), []),
+            ("decoder_sparse_step", m.get("decoder_sparse_step", 1), 1),
+            ("tie_word_embeddings", m.get("tie_word_embeddings", False), False),
+            ("hidden_act", m.get("hidden_act", "silu"), "silu"),
+            ("rope_scaling.rope_type",
+             rope.get("rope_type", rope.get("type", "default")), "default")):
+        if got != want:
+            raise ValueError(
+                f"KeyeVL2 builder: {key}={got!r} is not built yet "
+                f"(only {want!r})")
+    experts = m["num_experts"]
+    if m.get("num_local_experts", experts) != experts:
+        raise ValueError(
+            f"KeyeVL2 builder: num_local_experts={m['num_local_experts']!r} "
+            f"of num_experts={experts!r}: every expert is held")
+    d, eps = m["hidden_size"], m["rms_norm_eps"]
+    x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
+                          dtype=jnp.dtype(ff.config.compute_dtype))
+    for i in range(m["num_hidden_layers"]):
+        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
+        a = ff.multihead_attention(
+            a, m["num_attention_heads"], causal=True, use_bias=False,
+            num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
+            qk_norm=eps,
+            rope={"theta": float(m["rope_theta"]),
+                  "sections": rope.get("mrope_section")},
+            select=m.get("sa_config"), name=f"blk{i}_attn")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
+        h = ff.moe(h, experts, m["moe_intermediate_size"],
+                   top_k=m["num_experts_per_tok"], dispatch="sorted",
+                   router="softmax", gated=True, activation="silu",
+                   norm_topk_prob=m["norm_topk_prob"], name=f"blk{i}_moe")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
+
+
 _BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
-           "xing4_0": _deepseek_v3_lm, "solar_open2": _solar_open2_lm}
+           "xing4_0": _deepseek_v3_lm, "solar_open2": _solar_open2_lm,
+           "KeyeVL2": _keye_vl2_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
 #: audit catalog): every mechanism of the block, no published width.
@@ -310,7 +363,42 @@ XING4_SMOKE: Dict[str, Any] = {
     "qk_rope_head_dim": 32, "v_head_dim": 64,
 }
 
-PRESETS = {"deepseek-v3-tiny": DEEPSEEK_V3_TINY,
+#: The Keye-VL-2.0 language model at unit-test size: widths no kernel
+#: takes, a ``topk`` smaller than the tests' sequences (so that the
+#: selector selects) and a chunk smaller still (so that the chunked
+#: prefill runs its dense head, two key widths and several chunks).
+KEYE_VL2_TINY: Dict[str, Any] = {
+    "model_type": "KeyeVL2", "vocab_size": 512, "hidden_size": 64,
+    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "attention_bias": False,
+    "hidden_act": "silu", "rms_norm_eps": 1e-6, "rope_theta": 10000000,
+    "rope_scaling": {"mrope_section": [2, 3, 3], "rope_type": "default",
+                     "type": "default"},
+    "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 4,
+                  "indexer_num_kv_heads": 1, "kv_chunk_size": 8,
+                  "q_chunk_size": 8, "topk": 16},
+    "num_experts": 8, "num_local_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "norm_topk_prob": True,
+    "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "use_sliding_window": False, "tie_word_embeddings": False,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip (heads of one whole lane tile, expert products of whole
+#: tiles): chip_smoke.py.
+KEYE_VL2_SMOKE: Dict[str, Any] = {
+    **KEYE_VL2_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "head_dim": 128, "moe_intermediate_size": 128,
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default",
+                     "type": "default"},
+    "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 4,
+                  "indexer_num_kv_heads": 1, "kv_chunk_size": 128,
+                  "q_chunk_size": 128, "topk": 256},
+}
+
+PRESETS = {"keye-vl2-tiny": KEYE_VL2_TINY,
+           "keye-vl2-smoke": KEYE_VL2_SMOKE,
+           "deepseek-v3-tiny": DEEPSEEK_V3_TINY,
            "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE,
            "xing4-tiny": XING4_TINY,
            "xing4-smoke": XING4_SMOKE,

@@ -119,11 +119,16 @@ KERNEL_CATALOG = frozenset({
     "ff_scatter_add_rows",
 })
 
-#: ``jax.named_scope`` phases of a train step beside the per-op scopes
-#: (``op.name``): the loss ops, and every optimizer update.
+#: ``jax.named_scope`` phases beside the per-op scopes (``op.name``):
+#: of a train step the loss ops and every optimizer update; of a serving
+#: program the two parts of a token selector.
 SCOPE_CATALOG = frozenset({
     "ff_loss",
     "ff_opt",
+    # inside an attention op that selects (ops/token_select.py): the
+    # selector's projections and scores; its top-k and the row gather
+    "ff_index",
+    "ff_select",
 })
 
 #: ``run_end.exit`` classifications (the reader adds ``truncated`` for

@@ -32,6 +32,7 @@ from flexflow_tpu.initializers import GlorotUniform, OnesInitializer, ZeroInitia
 from flexflow_tpu.ops import pallas_kernels
 from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
 from flexflow_tpu.ops.norm import rms_norm
+from flexflow_tpu.ops.token_select import TokenSelector, rope_half
 
 _NEG_INF = -1e30
 
@@ -178,7 +179,25 @@ class MultiHeadAttention(Op):
     heads alone.  ``head_dim`` (default ``dim // num_heads``) frees the
     heads' width from the model's.  ``gate`` multiplies the attended
     values, before the output projection, by ``sigmoid(x W_gate)``
-    elementwise.  No positional signal is this op's business.
+    elementwise.
+
+    Three more arguments, each absent by default (the op then lowers to
+    the program it always did).  ``qk_norm`` (an epsilon): an RMSNorm
+    with a learned weight over each head of q and k, before positions.
+    ``rope`` (``{"theta", "sections"}``): rotary positions over the whole
+    head, half-split pairs (``token_select.rope_half``); ``sections``
+    are a model's ``mrope_section`` (pairs turned by each of the
+    position's components: ``state["positions"]`` (b, t, 3) where a
+    caller has them, else the token's index for all three, which is
+    plain rotary).  ``select`` (a model's ``sa_config``): a learned
+    token selector (``ops/token_select.py``) chooses the ``topk`` past
+    positions each query attends; the op then keeps a third cache entry
+    (the selector's keys), declares K and V positions-major with a
+    position's heads in one row (a decode step gathers the chosen rows
+    and no others), and serves on one device from the padded layout
+    alone: the paged pool, the offset prefill and ``shard=`` raise
+    ``NotImplementedError`` (ROADMAP B-M1), and so does the ring path
+    for ``rope`` or ``select``.
     """
 
     def __init__(
@@ -192,6 +211,9 @@ class MultiHeadAttention(Op):
         num_kv_heads: Optional[int] = None,
         head_dim: Optional[int] = None,
         gate: bool = False,
+        qk_norm: Optional[float] = None,
+        rope: Optional[dict] = None,
+        select: Optional[dict] = None,
     ):
         super().__init__(name, [x])
         assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
@@ -203,7 +225,14 @@ class MultiHeadAttention(Op):
         assert kv >= 1 and num_heads % kv == 0, (num_heads, num_kv_heads)
         self.attrs = dict(num_heads=num_heads, causal=causal, use_bias=use_bias,
                           num_kv_heads=kv, head_dim=int(head_dim),
-                          gate=bool(gate))
+                          gate=bool(gate), qk_norm=qk_norm, rope=rope,
+                          select=select)
+        if select is not None and not causal:
+            raise ValueError(f"{name}: a token selector reads the causal past")
+        #: The token selector composed into this op, if any.
+        self.select = None if select is None else TokenSelector(
+            select, theta=(rope or {}).get("theta", 10000.0),
+            eps=qk_norm if qk_norm is not None else 1e-6)
         #: A head that fills whole lane tiles is cached positions-last,
         #: the order ``flash_decode`` reads: the chip stores ``(max_seq,
         #: h, hd)`` row-major there, and the decode superstep would pay
@@ -215,7 +244,9 @@ class MultiHeadAttention(Op):
         self.kernel_initializer = kernel_initializer or GlorotUniform()
         self._make_output(x.shape, x.dtype, x.dim_axes)
 
-    cache_paged = True
+    @property
+    def cache_paged(self) -> bool:
+        return self.select is None
 
     @property
     def group(self) -> int:
@@ -223,7 +254,10 @@ class MultiHeadAttention(Op):
 
     @property
     def lane_tile_heads(self) -> bool:
-        return self.attrs["head_dim"] % 128 == 0
+        """Whether the cache may lie positions-last.  Never under a
+        selector: a chosen position's K (or V) has to be one contiguous
+        row to be fetched alone."""
+        return self.attrs["head_dim"] % 128 == 0 and self.select is None
 
     def param_specs(self) -> Dict[str, ParamSpec]:
         a = self.attrs
@@ -244,10 +278,21 @@ class MultiHeadAttention(Op):
             specs["bk"] = ParamSpec((dkv,), dt, ZeroInitializer(), ("c",))
             specs["bv"] = ParamSpec((dkv,), dt, ZeroInitializer(), ("c",))
             specs["bo"] = ParamSpec((d,), dt, ZeroInitializer())
+        if a["qk_norm"] is not None:
+            specs["q_norm"] = ParamSpec((a["head_dim"],), dt, OnesInitializer())
+            specs["k_norm"] = ParamSpec((a["head_dim"],), dt, OnesInitializer())
+        if self.select is not None:
+            specs.update(self.select.param_specs(d, dt, ki))
         return specs
 
     def cache_entries(self, max_seq: int) -> Dict[str, CacheEntry]:
         h, hd = self.attrs["num_kv_heads"], self.attrs["head_dim"]
+        if self.select is not None:
+            # A position's heads side by side: the row a decode step
+            # gathers.  The selector's keys beside them.
+            row = CacheEntry((max_seq, h * hd), self.outputs[0].dtype)
+            return {"k": row, "v": row, TokenSelector.ENTRY:
+                    self.select.cache_entry(max_seq, self.outputs[0].dtype)}
         if self.positions_last:
             row = CacheEntry((h, hd, max_seq), self.outputs[0].dtype,
                              ("c", None, None))
@@ -260,6 +305,8 @@ class MultiHeadAttention(Op):
         """Which attention formulation a serving program of this op
         compiles (the ``serving_program`` event's ``attention``)."""
         kind = "gqa" if self.group > 1 else "kv"
+        if self.select is not None:
+            kind += "_select"
         return f"{kind}_decode" if decode else f"{kind}_dense"
 
     def _kernel_block(self, slots: int, max_seq: int, c: int = 1) -> int:
@@ -273,6 +320,9 @@ class MultiHeadAttention(Op):
         return pallas_kernels.flash_decode_chunk(*local[1:], dtype, self.group)
 
     def decode_fetch_block(self, slots, max_seq, kernel, c=1):
+        if self.select is not None:
+            # Rows, not blocks: the gather fetches the chosen positions.
+            return 1
         block = 0 if kernel is False else self._kernel_block(slots, max_seq, c)
         return block or max_seq
 
@@ -331,12 +381,211 @@ class MultiHeadAttention(Op):
         pc = getattr(self, "_pc", None)
         S = pc.s if pc is not None else 1
         q, k, v = self._project(params, x)
-        if S <= 1:
+        if self.positional:
+            if S > 1:
+                raise NotImplementedError(
+                    f"{self.name}: no ring path under rope or select "
+                    f"(ROADMAP B-M1)")
+            index, pos = self._positions(state, *x.shape[:2])
+            kh, vh = map(self._split_heads, (k, v))
+            kh = self._place_heads(kh, params.get("k_norm"), pos)
+            if self.select is None:
+                qh = self._place_heads(self._split_heads(q),
+                                       params.get("q_norm"), pos)
+                out = self._attend_heads(qh, kh, vh, x.dtype)
+            else:
+                out = self._attend_selected(
+                    params, q, kh, vh, pos, self._index(params, x, index),
+                    x.dtype, self._attend_heads)
+        elif S <= 1:
             out = self._attend_dense(q, k, v, x.dtype)
         else:
             assert self.group == 1, f"{self.name}: no grouped-query ring path"
             out = self._attend_ring(q, k, v, x.dtype)
         return [self._output(params, x, out)], state
+
+    # -- positions and selection ---------------------------------------------
+
+    @property
+    def positional(self) -> bool:
+        """Whether q and k pass ``_place`` (none of the three arguments
+        present: the op's programs are what they were without them)."""
+        a = self.attrs
+        return a["qk_norm"] is not None or a["rope"] is not None \
+            or self.select is not None
+
+    def _positions(self, state, b: int, t: int):
+        """``(index (b, t), rotary positions)`` of the call's tokens: a
+        decode step's index from the slots' ``pos``, else ``chunk ..``
+        (0 where there is none); the rotary positions are the index, or
+        the caller's ``positions`` (b, t, components)."""
+        if t == 1 and "pos" in state:
+            index = state["pos"][:, None]
+        else:
+            start = int(state.get("chunk", 0))
+            index = jnp.broadcast_to(start + jnp.arange(t)[None], (b, t))
+        return index, state.get("positions", index)
+
+    def _place(self, params, qh, kh, pos):
+        """Heads (b, h, t, hd) of q and k through the head norm and
+        the rotary positions ``pos`` (b, t) or (b, t, components), as far
+        as the op has them."""
+        return (self._place_heads(qh, params.get("q_norm"), pos),
+                self._place_heads(kh, params.get("k_norm"), pos))
+
+    def _place_heads(self, heads, scale, pos):
+        a = self.attrs
+        if a["qk_norm"] is not None:
+            heads = rms_norm(heads, scale, a["qk_norm"])
+        if a["rope"] is not None:
+            heads = rope_half(heads, pos[:, None], a["rope"]["theta"],
+                              a["rope"].get("sections"))
+        return heads
+
+    def _index(self, params, x, index):
+        """The selector's ``(q, k, w)`` of this call's tokens at their
+        indices (b, t)."""
+        with jax.named_scope("ff_index"):
+            return self.select.project(params, x, index)
+
+    def _attend_selected(self, params, q, kh, vh, pos, index, dtype, dense):
+        """Causal attention of queries ``q`` (b, t, h * hd) over (b, h_kv,
+        t, hd) keys and values starting at position 0, each query row
+        over the positions the selector keeps for it.  The keys come
+        placed; the queries as projected, and are split into heads and
+        placed (``_place_heads`` at ``pos``) a chunk at a time (all 32
+        heads of a 32k prefill are 256 MB transposed and 512 MB in
+        f32).  Rows under ``topk`` keep
+        their whole past: whole chunks of them go to ``dense`` (the
+        op's own causal path).  The rest run a chunk of
+        ``select.q_chunk`` query rows at a time: the chunk's selector
+        scores against the keys up to its end, its rows' ``topk``-th
+        largest as the threshold, and masked attention a query head at
+        a time (a head's scores of one chunk against 32k keys are 64 MB
+        in f32; all heads' at once would be 2 GB).  Chunks are grouped
+        by where they end into doubling key widths so that each width
+        is one compiled loop and a chunk pays for at most twice the
+        keys it can see.  Masked pairs are computed and thrown away
+        (ROADMAP B-M1: a prefill that does not pay for them)."""
+        sel = self.select
+        iq, ik, iw = index
+        b, t, _ = q.shape
+        h, hd = self.attrs["num_heads"], self.attrs["head_dim"]
+
+        def placed_q(start, n):
+            return self._place_heads(
+                self._split_heads(lax.dynamic_slice_in_dim(q, start, n, axis=1)),
+                params.get("q_norm"),
+                lax.dynamic_slice_in_dim(pos, start, n, axis=1))
+
+        if t <= sel.topk:
+            return dense(placed_q(0, t), kh, vh, dtype)
+        c = t if t % sel.q_chunk else sel.q_chunk   # one chunk: odd sizes
+        head = (sel.topk // c) * c
+        outs = []
+        if head:
+            outs.append(dense(placed_q(0, head), kh[:, :, :head],
+                              vh[:, :, :head], dtype))
+        scale = 1.0 / math.sqrt(hd)
+        g = self.group
+
+        def chunk_out(start, width):
+            rows = start + jnp.arange(c)
+            with jax.named_scope("ff_index"):
+                scores = sel.scores(
+                    lax.dynamic_slice_in_dim(iq, start, c, axis=1),
+                    lax.dynamic_slice_in_dim(iw, start, c, axis=1),
+                    ik[:, :width])                               # (b, c, width)
+            with jax.named_scope("ff_select"):
+                keep = sel.keep(scores, rows)
+            qc = placed_q(start, c)                               # (b, h, c, hd)
+
+            def one_head(j):
+                q = qc[:, j]
+                k, v = kh[:, j // g, :width], vh[:, j // g, :width]
+                s = jnp.einsum("bqd,bsd->bqs", q, k,
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1)
+                return jnp.einsum("bqs,bsd->bqd", p.astype(v.dtype), v,
+                                  preferred_element_type=jnp.float32)
+
+            o = lax.map(one_head, jnp.arange(h))                  # (h, b, c, hd)
+            return o.transpose(1, 2, 0, 3).reshape(b, c, h * hd).astype(dtype)
+
+        lo = head
+        while lo < t:
+            hi = t if lo == 0 else min(2 * lo, t)
+            o = lax.map(lambda s, hi=hi: chunk_out(s, hi),
+                        jnp.arange(lo, hi, c))                    # (n, b, c, h*hd)
+            outs.append(o.transpose(1, 0, 2, 3).reshape(b, hi - lo, h * hd))
+            lo = hi
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+    def _forward_selected(self, params, x, state):
+        """The cached forward of an op with a selector: caches ``k`` and
+        ``v`` (B, S, h_kv * hd), a position a row, and ``idx`` (B, S,
+        selector head), the selector's keys.  Prefill (t > 1): the three
+        written at rows ``0..t-1`` and ``_attend_selected``.  Decode
+        (t == 1): the token at ``pos`` writes its three rows (a slice
+        update a slot, in place), the selector scores every row of its
+        small cache, keeps ``topk`` among the live ones, and K and V of
+        those rows alone are gathered and attended."""
+        sel, plan = self.select, getattr(self, "_plan", None)
+        if "block_table" in state or "chunk" in state or \
+                (plan is not None and plan.num_devices > 1):
+            raise NotImplementedError(
+                f"{self.name}: selection over a paged pool, an offset "
+                f"prefill or a sharded cache is not built (ROADMAP B-M1)")
+        ck, cv, ci = (state[f"cache_{e}"] for e in ("k", "v", sel.ENTRY))
+        b, t, _ = x.shape
+        index, pos = self._positions(state, b, t)
+        q, k, v = self._project(params, x)
+        kh, vh = map(self._split_heads, (k, v))                 # (B, h_kv, t, hd)
+        iq, ik, iw = self._index(params, x, index)
+        kh = self._place_heads(kh, params.get("k_norm"), pos)
+        rows_k = kh.transpose(0, 2, 1, 3).reshape(b, t, -1).astype(ck.dtype)
+        new_state = dict(state)
+        if t > 1:
+            new = (rows_k, v.astype(cv.dtype), ik.astype(ci.dtype))
+            ck, cv, ci = (c.at[:, :t].set(r) for c, r in zip((ck, cv, ci), new))
+            y = self._attend_selected(params, q, kh, vh, pos, (iq, ik, iw),
+                                      x.dtype, self._attend_prefill)
+        else:
+            qh = self._place_heads(self._split_heads(q), params.get("q_norm"),
+                                   pos)
+            at = state["pos"]
+            for i in range(b):
+                ck, cv, ci = (
+                    lax.dynamic_update_slice(c, r[i][None].astype(c.dtype),
+                                             (i, at[i], 0))
+                    for c, r in ((ck, rows_k), (cv, v), (ci, ik)))
+            with jax.named_scope("ff_index"):
+                scores = sel.scores(iq, iw, ci)[:, 0]            # (B, S)
+            with jax.named_scope("ff_select"):
+                idx, valid = sel.pick(scores, at)
+                kg, vg = (jnp.take_along_axis(c, idx[:, :, None], axis=1)
+                          for c in (ck, cv))                     # (B, k, h_kv*hd)
+            y = self._decode_selected(qh[:, :, 0], kg, vg, valid, x.dtype)
+        new_state["cache_k"], new_state["cache_v"] = ck, cv
+        new_state[f"cache_{sel.ENTRY}"] = ci
+        return [self._output(params, x, y)], new_state
+
+    def _decode_selected(self, q1, kg, vg, valid, dtype):
+        """One query a head ``q1`` (B, h, hd) over a slot's gathered rows
+        ``kg``/``vg`` (B, k, h_kv * hd), the ``valid`` (B, k) ones: f32
+        scores and softmax, the products in the operands' dtype.
+        Returns (B, 1, h * hd)."""
+        b, h, hd = q1.shape
+        hkv = h // self.group
+        kg, vg = (c.reshape(b, -1, hkv, hd) for c in (kg, vg))
+        qg = q1.reshape(b, hkv, self.group, hd)
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, kg,
+                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+        p = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, _NEG_INF),
+                           axis=-1)
+        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(vg.dtype), vg,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, 1, h * hd).astype(dtype)
 
     def _output(self, params, x, out):
         """The output gate (where the op has one) and projection."""
@@ -355,7 +604,8 @@ class MultiHeadAttention(Op):
     # The serving executor threads an inference mode through the
     # existing ``state`` mechanism: when ``state`` carries
     # ``cache_k``/``cache_v`` — preallocated (B, max_seq, heads,
-    # d_head) caches — plus the per-slot position vector ``pos`` (B,)
+    # d_head) caches, (B, heads, d_head, max_seq) under
+    # ``positions_last`` — plus the per-slot position vector ``pos`` (B,)
     # int32, ``forward`` takes this path instead.  Two sub-modes by
     # query length:
     #
@@ -400,6 +650,20 @@ class MultiHeadAttention(Op):
     # numerics bit-identical, which is what pins the sharded paged
     # path to the single-mesh paged oracle (tests/test_serving.py).
     #
+    # **Positions and a third entry** (PR 42).  An op built with
+    # ``qk_norm`` or ``rope`` passes q and k through ``_place`` first
+    # (the head norm, then the rotary turn at the call's positions: a
+    # decode step's from ``pos``, a prefill's ``0..t-1``, an offset
+    # prefill's from ``chunk``; ``state["positions"]`` where a caller
+    # brings multimodal components); what reaches the caches and the
+    # decode kernel is rotated, and nothing below changes.  An op built
+    # with ``select`` leaves this path at its first line for
+    # ``_forward_selected``: three entries (``k`` and ``v`` positions-
+    # major with a position's heads one row, ``idx`` the selector's
+    # keys), a decode step that gathers the chosen rows, and no paged
+    # pool, offset prefill or mesh (``NotImplementedError`` naming
+    # ROADMAP B-M1).
+    #
     # Training never sets cache keys, so the differentiable pure-jnp
     # contract on the training path is untouched (the decode kernel
     # has no VJP — it is reachable only from the forward-only serving
@@ -412,10 +676,14 @@ class MultiHeadAttention(Op):
     decode_kernel: Optional[bool] = None
 
     def _forward_cached(self, params, x, state):
+        if self.select is not None:
+            return self._forward_selected(params, x, state)
         ck, cv = state["cache_k"], state["cache_v"]
         q, k, v = self._project(params, x)
         qh, kh, vh = map(self._split_heads, (q, k, v))   # (B, h, t, hd)
         b, h, t, hd = qh.shape
+        if self.positional:
+            qh, kh = self._place(params, qh, kh, self._positions(state, b, t)[1])
         if self.positions_last:
             # Caches (B, h_kv, hd, S): the padded layout's prefill and
             # decode only (a paged or sharded executor binds the other
@@ -487,7 +755,8 @@ class MultiHeadAttention(Op):
             ck = ck.at[:, :t].set(kh.transpose(0, 2, 1, 3).astype(ck.dtype))
             cv = cv.at[:, :t].set(vh.transpose(0, 2, 1, 3).astype(cv.dtype))
             y = self._attend_prefill(qh, kh, vh, x.dtype) \
-                if self.group > 1 else self._attend_dense(q, k, v, x.dtype)
+                if self.group > 1 or self.positional \
+                else self._attend_dense(q, k, v, x.dtype)
         new_state = dict(state)
         new_state["cache_k"] = ck
         new_state["cache_v"] = cv

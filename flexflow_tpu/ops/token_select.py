@@ -1,0 +1,183 @@
+"""A learned token selector over a slot's cache (DeepSeek Sparse
+Attention's "lightning indexer"): which past positions an attention
+layer reads.
+
+Not an attention op: it owns a few small projections, ONE more cache
+entry a slot (its keys, one head of ``head_dim`` a position: 128 B
+where K and V of a grouped-query layer are 2 KB), a score a (query,
+position) pair and a top-k over the scores.  An attention op composes
+it (``MultiHeadAttention(select=...)`` today; the same selector sits
+over latent attention in other models) and attends the selected
+positions alone.  With ``a`` the attention layer's (normed) input,
+
+    q^I_j = R'_p((a W_qI)_j)   j < heads         k^I = R'_p(LayerNorm(a W_kI))
+    w     = (a W_w) * heads^-1/2 * head_dim^-1/2                    (float32)
+    I(t, s) = sum_j w_j(t) ReLU(q^I_j(t) . k^I(s))                  s <= t
+    S_t   = the ``topk`` positions s <= t of largest I(t, s)  (all while t < topk)
+
+``R'`` turns half-split pairs ``(i, i + head_dim/2)`` of the whole head
+by the token's index (``rope_half`` with one position component).
+Queries, keys and the key cache are the compute dtype; ``w``, the
+scores and the top-k are float32.  Ties go to the lower position
+(``lax.top_k``'s order), in a decode step and in a prefill alike.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from flexflow_tpu.initializers import OnesInitializer, ZeroInitializer
+from flexflow_tpu.ops.base import CacheEntry, ParamSpec
+
+_NEG_INF = -jnp.inf
+
+
+def rope_half(x, pos, theta: float, sections: Optional[Sequence[int]] = None):
+    """Rotary embedding over half-split pairs ``(i, i + d/2)`` of the
+    last dim, in f32: pair ``i`` turns by ``p_c(i) * theta^(-2i/d)``.
+    ``x``: (..., t, d).  ``pos``: (..., t) token indices, or with
+    ``sections`` (multimodal rotary positions: how many pairs each
+    position component turns, in order) optionally (..., t,
+    len(sections)); one index stands for every component, which is plain
+    rotary."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
+    pos = jnp.asarray(pos).astype(jnp.float32)
+    if sections is not None and pos.ndim == x.ndim:
+        assert sum(sections) == d // 2, (sections, d)
+        comp = np.repeat(np.arange(len(sections)), sections)
+        pos = jnp.take(pos, comp, axis=-1)                          # (..., t, d/2)
+    else:
+        pos = pos[..., None]
+    ang = pos * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class TokenSelector:
+    """The selector's parameters, cache entry, scores and top-k, for an
+    attention op to compose.  ``config`` is a model's ``sa_config``
+    (``indexer_num_heads``, ``indexer_head_dim``, ``topk``; one key head;
+    ``q_chunk_size`` the query rows a prefill scores at a time, which
+    changes no result)."""
+
+    #: The cache entry's name inside the composing op (the parameters
+    #: there carry the prefix ``idx_``).
+    ENTRY = "idx"
+
+    def __init__(self, config: Dict[str, Any], theta: float, eps: float = 1e-6):
+        if config.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError(
+                f"token selector: indexer_num_kv_heads="
+                f"{config['indexer_num_kv_heads']!r} is not built (one key head)")
+        self.heads = int(config["indexer_num_heads"])
+        self.head_dim = int(config["indexer_head_dim"])
+        self.topk = int(config["topk"])
+        self.q_chunk = int(config.get("q_chunk_size", 512))
+        assert self.head_dim % 2 == 0, self.head_dim
+        self.theta, self.eps = float(theta), float(eps)
+        self.scale = 1.0 / math.sqrt(self.heads * self.head_dim)
+
+    def param_specs(self, d: int, dtype, initializer) -> Dict[str, ParamSpec]:
+        h, hd = self.heads, self.head_dim
+        return {
+            "idx_wq": ParamSpec((d, h * hd), dtype, initializer),
+            "idx_wk": ParamSpec((d, hd), dtype, initializer),
+            # Held and multiplied in f32, like a router.
+            "idx_ww": ParamSpec((d, h), jnp.float32, initializer),
+            "idx_k_scale": ParamSpec((hd,), dtype, OnesInitializer()),
+            "idx_k_bias": ParamSpec((hd,), dtype, ZeroInitializer()),
+        }
+
+    def cache_entry(self, max_seq: int, dtype) -> CacheEntry:
+        """The keys of every position, positions-major (a row a key)."""
+        return CacheEntry((max_seq, self.head_dim), dtype)
+
+    def project(self, params, a, pos):
+        """``(q (b, t, heads, hd), k (b, t, hd), w (b, t, heads) f32)``
+        of the tokens ``a`` (b, t, d) at indices ``pos`` (b, t)."""
+        b, t, _ = a.shape
+        q = (a @ params["idx_wq"]).reshape(b, t, self.heads, self.head_dim)
+        q = rope_half(q.transpose(0, 2, 1, 3), pos[:, None],
+                      self.theta).transpose(0, 2, 1, 3)
+        kf = (a @ params["idx_wk"]).astype(jnp.float32)
+        mean = jnp.mean(kf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(kf - mean), axis=-1, keepdims=True)
+        kf = (kf - mean) * lax.rsqrt(var + self.eps) \
+            * params["idx_k_scale"].astype(jnp.float32) \
+            + params["idx_k_bias"].astype(jnp.float32)
+        k = rope_half(kf.astype(a.dtype), pos, self.theta)
+        w = jnp.dot(a.astype(jnp.float32), params["idx_ww"],
+                    precision=lax.Precision.HIGHEST) * self.scale
+        return q, k, w
+
+    #: The f32 products of every head at once (``t x heads x s``) one
+    #: call of ``scores`` may hold; past it the heads run one after
+    #: another (a prefill chunk of 512 rows against 32k keys is 1 GiB
+    #: for 16 heads at once, 64 MiB a head).
+    DOTS_BYTES = 1 << 27
+
+    def scores(self, q, w, keys):
+        """``I`` (b, t, s) f32 of queries ``q`` (b, t, heads, hd) under
+        weights ``w`` (b, t, heads) against ``keys`` (b, s, hd); no
+        mask."""
+        b, t, h, _ = q.shape
+        if b * t * h * keys.shape[1] * 4 <= self.DOTS_BYTES:
+            dots = jnp.einsum("bthd,bsd->bths", q, keys,
+                              preferred_element_type=jnp.float32)
+            return jnp.einsum("bths,bth->bts", jax.nn.relu(dots), w)
+
+        def add_head(acc, qw):
+            qj, wj = qw                                   # (b, t, hd), (b, t)
+            dots = jnp.einsum("btd,bsd->bts", qj, keys,
+                              preferred_element_type=jnp.float32)
+            return acc + jax.nn.relu(dots) * wj[..., None], None
+
+        acc = jnp.zeros((b, t, keys.shape[1]), jnp.float32)
+        return lax.scan(add_head, acc, (q.transpose(2, 0, 1, 3),
+                                        w.transpose(2, 0, 1)))[0]
+
+    def pick(self, scores, pos):
+        """A decode step's selection: ``(idx (B, k) int32, valid (B, k))``
+        from ``scores`` (B, S) over each slot's whole cache and the
+        query's own position ``pos`` (B,): the ``k = min(topk, S)``
+        largest among positions ``<= pos``.  Where fewer are live,
+        ``valid`` is false on the rest (their indices point anywhere)."""
+        live = jnp.arange(scores.shape[-1])[None, :] <= pos[:, None]
+        top, idx = lax.top_k(jnp.where(live, scores, _NEG_INF),
+                             min(self.topk, scores.shape[-1]))
+        return idx, top > _NEG_INF
+
+    def keep(self, scores, q_pos):
+        """A prefill's selection as a mask (b, t, s): of ``scores``
+        (b, t, s) against key positions ``0..s-1``, query row ``i`` (at
+        position ``q_pos[i]``) keeps the causal positions whose score
+        reaches its ``topk``-th largest causal score (all of them while
+        there are no more than ``topk``)."""
+        s = scores.shape[-1]
+        causal = jnp.arange(s)[None, :] <= q_pos[:, None]
+        if s <= self.topk:
+            return jnp.broadcast_to(causal[None], scores.shape)
+        masked = jnp.where(causal[None], scores, _NEG_INF)
+        kth = lax.top_k(masked, self.topk)[0][..., -1:]
+        keep = causal[None] & (masked >= kth)
+
+        def lowest_of_equals(keep):
+            # Scores that tie with the topk-th (exact zeros, where every
+            # head's ReLU is shut): the lowest positions, as top_k has it.
+            above = masked > kth
+            ties = keep & ~above
+            room = self.topk - jnp.sum(above, axis=-1, keepdims=True)
+            return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+
+        return lax.cond(jnp.any(jnp.sum(keep, axis=-1) > self.topk),
+                        lowest_of_equals, lambda keep: keep, keep)

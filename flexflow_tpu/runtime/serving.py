@@ -748,6 +748,16 @@ class ServingExecutor:
                         f"shard batch degree n={n} must divide "
                         f"max_batch={self.max_batch}"
                     )
+                selecting = [
+                    op.name for op in self.attn_ops
+                    if getattr(op, "select", None) is not None
+                ]
+                if selecting:
+                    raise NotImplementedError(
+                        f"sharded decode (shard=): {selecting} keep a "
+                        f"token selector, whose cache and gather are "
+                        f"built for one device (ROADMAP B-M1)"
+                    )
                 unsharded = [
                     op.name for op in self.attn_ops
                     if not isinstance(op, MultiHeadAttention)
@@ -1281,20 +1291,34 @@ class ServingExecutor:
         to the block the ops' decode step reads in
         (``Op.decode_fetch_block``; the paged view and the einsum
         oracle read every row), ``kv_rows_cache`` is slots x max_seq x
-        k.  Host arithmetic, one layer's rows, over the ops whose cache
-        has a sequence axis; a graph that also keeps recurrent state
-        adds ``state_bytes``, the bytes of state the superstep reads
-        and writes over all its layers."""
+        k.  An op under a token selector (``ops/token_select.py``)
+        gathers its ``topk`` rows whatever the live length, and scores
+        every row of its selector's keys, the padded cache's (a plain
+        product): ``idx_rows_fetched``, present when some op selects.
+        Host arithmetic, one layer's rows: the mean over the ops whose
+        cache has a sequence axis, each counted by what it reads, so a
+        graph that mixes kinds of layer sums to its layers' own; a
+        graph that also keeps recurrent state adds ``state_bytes``,
+        the bytes of state the superstep reads and writes over all its
+        layers."""
         S = self.max_seq
         n, c = self.shard or (1, 1)
+        ops = [op for op in self.attn_ops if op not in self.stateful_ops]
+        picks = [getattr(op, "select", None) for op in ops]
         block = S if self.paged else max(
             (op.decode_fetch_block(self.max_batch // n, S,
                                    self.decode_kernel, c)
-             for op in self.attn_ops if op not in self.stateful_ops),
+             for op, pick in zip(ops, picks) if pick is None),
             default=S)
         live = np.minimum(np.asarray(pos)[:, None] + np.arange(k), S - 1) + 1
-        rows = {"kv_rows_fetched": int((-(-live // block) * block).sum()),
+        dense = int((-(-live // block) * block).sum())
+        fetched = [dense if pick is None else live.size * min(pick.topk, S)
+                   for pick in picks] or [dense]
+        rows = {"kv_rows_fetched": int(round(sum(fetched) / len(fetched))),
                 "kv_rows_cache": int(live.size * S)}
+        if any(pick is not None for pick in picks):
+            scored = sum(live.size * S for pick in picks if pick is not None)
+            rows["idx_rows_fetched"] = int(round(scored / len(picks)))
         if self._bytes_fixed:
             # Every slot's recurrent state and window, read and written
             # once a step.

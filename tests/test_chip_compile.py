@@ -612,6 +612,57 @@ def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
     assert layout.count(f"bf16[{slots},160,{seq}]") == 6
 
 
+def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
+    """``chip_smoke.py``'s ``serve/keye`` programs at the smoke preset's
+    widths, compiled for the described chip: the prefill holds the
+    streamed forward kernel (its leading ``topk`` rows) and the grouped
+    product beside the masked chunks, the decode superstep the grouped
+    product, a sort a layer (the top-k) and a row gather of K and of V;
+    the three caches go from parameter to result where they lie, K and V
+    a position a row, updated a slot at a time in place."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import KEYE_VL2_SMOKE, build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    slots, seq = 4, 512
+    topk = KEYE_VL2_SMOKE["sa_config"]["topk"]
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(KEYE_VL2_SMOKE, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    params, state = jax.tree.map(placed, params), jax.tree.map(placed, state)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    assert {e: c.shape for e, c in caches["blk1_attn"].items()} == {
+        "k": (slots, seq, 256), "v": (slots, seq, 256), "idx": (slots, seq, 64)}
+    vec = _sds((slots,), jnp.int32)
+    step = sex.build_decode_superstep(8).lower(
+        params, state, caches, vec, vec).compile().as_text()
+    first = sex.build_prefill(seq).lower(
+        params, state, _sds((1, seq), jnp.int32), _sds((), jnp.int32)
+    ).compile().as_text()
+    assert chip_smoke.has_kernel(step, "ff_grouped_matmul")
+    assert not chip_smoke.has_kernel(step, "ff_flash_decode")
+    for name in ("ff_flash_fwd_uneven", "ff_grouped_matmul"):
+        assert chip_smoke.has_kernel(first, name), name
+    assert chip_smoke.cache_shaped_relayouts(step, caches) == []
+    # The selection: K and V of topk rows a slot, gathered a layer.
+    gathers = [l for l in step.splitlines() if " gather(" in l
+               and f"bf16[{slots},{topk},256]" in l]
+    assert len(gathers) == 2 * KEYE_VL2_SMOKE["num_hidden_layers"]
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
+    ins, outs = layout.split(")->(")
+    kv = f"bf16[{slots},{seq},256]{{2,1,0:T(8,128)(2,1)}}"
+    assert ins.count(kv) == outs.count(kv) == 2 * KEYE_VL2_SMOKE["num_hidden_layers"]
+
+
 _CACHE = (48, 1024, 16, 64)
 #: A cache as the chip stores it (positions along the lanes), and as a
 #: row-major Mosaic operand wants it (hd 64 padded to a 128-lane tile).
@@ -724,6 +775,10 @@ _TINY = chip_smoke.Sizes(
     serve_xing=("--model-config", "xing4-tiny", "--max-seq", "128",
                 "--max-batch", "2", "--requests", "3", "--max-new", "6",
                 "--prompt-len", "20:60", "--buckets", "128"),
+    # topk 16 under prompts of 40-100: the selector selects.
+    serve_keye=("--model-config", "keye-vl2-tiny", "--max-seq", "128",
+                "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                "--prompt-len", "40:100", "--buckets", "128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -754,7 +809,8 @@ def _phases(which):
 
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
-              "serve", "serve/latent", "serve/solar", "serve/xing"])
+              "serve", "serve/latent", "serve/solar", "serve/xing",
+              "serve/keye"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

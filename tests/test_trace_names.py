@@ -150,8 +150,8 @@ def test_lowered_step_carries_the_loss_and_optimizer_phases(make):
     # round it: ``jit(train_step)/transpose(jvp(ff_loss))/softmax/mul``.
     paths = [[c for c in re.split(r"[/()]", n) if c] for n in names]
     loss_op = next(op.name for op in ex.model.layers if op.is_loss)
-    for scope in SCOPE_CATALOG:
-        assert any(scope in p for p in paths), scope
+    for scope in ("ff_loss", "ff_opt"):     # the catalog's train-step phases
+        assert scope in SCOPE_CATALOG and any(scope in p for p in paths), scope
     # The loss: forward and transpose, with the op's own scope kept inside.
     assert any("ff_loss" in p and loss_op in p and "transpose" not in p for p in paths)
     assert any("ff_loss" in p and loss_op in p and "transpose" in p for p in paths)
@@ -160,6 +160,39 @@ def test_lowered_step_carries_the_loss_and_optimizer_phases(make):
         # The row gather and the row step sit under the embedding's own scope.
         assert any(sparse_op in p and "ff_opt" not in p for p in paths)
         assert any(sparse_op in p and "ff_opt" in p for p in paths)
+
+
+def test_lowered_serving_programs_carry_the_selectors_two_phases():
+    """A graph whose attention composes a token selector: ``ff_index``
+    (projections and scores) and ``ff_select`` (top-k and gather) sit
+    inside each ``blk<i>_attn`` of the prefill and of the decode
+    superstep, and are the catalog's other two scopes."""
+    from flexflow_tpu.models.transformer import KEYE_VL2_TINY, build_lm
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    lm = build_lm(KEYE_VL2_TINY, 2, 64, FFConfig(batch_size=2))
+    sex = ServingExecutor(lm, lm.config, max_batch=2, max_seq=64, buckets=(64,))
+    params, _opt, state = jax.eval_shape(Executor(lm, config=lm.config).init)
+    caches = sex._cache_tree(sex._cache_specs, lambda ce: jax.ShapeDtypeStruct(
+        (2,) + tuple(ce.shape), ce.dtype))
+    vec = jax.ShapeDtypeStruct((2,), np.int32)
+    programs = {
+        "decode": sex.build_decode_superstep(2).lower(params, state, caches, vec, vec),
+        "prefill": sex.build_prefill(64).lower(
+            params, state, jax.ShapeDtypeStruct((1, 64), np.int32),
+            jax.ShapeDtypeStruct((), np.int32)),
+    }
+    assert SCOPE_CATALOG == {"ff_loss", "ff_opt", "ff_index", "ff_select"}
+    for kind, lowered in programs.items():
+        # The compiled text's ``op_name``: what a trace's ``tf_op`` holds
+        # (the lowering's ``loc`` forgets the op's scope inside a loop body).
+        names = set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+        paths = [[c for c in re.split(r"[/()]", n) if c] for n in names]
+        for scope in ("ff_index", "ff_select"):
+            for blk in ("blk0_attn", "blk1_attn"):
+                assert any(scope in p and blk in p for p in paths), (kind, scope, blk)
+        assert not any("ff_index" in p and "ff_select" in p for p in paths)
+        assert not any("ff_select" in p and "blk0_moe" in p for p in paths)
 
 
 # -- host spans --------------------------------------------------------------
