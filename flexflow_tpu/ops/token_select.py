@@ -20,6 +20,18 @@ by the token's index (``rope_half`` with one position component).
 Queries, keys and the key cache are the compute dtype; ``w``, the
 scores and the top-k are float32.  Ties go to the lower position
 (``lax.top_k``'s order), in a decode step and in a prefill alike.
+
+A decode step needs the ``topk`` indices and takes ``lax.top_k`` (a full
+sort of the row).  A prefill needs each row's ``topk``-th largest score
+alone, never the order of the rest, and sorts nothing: ``order_key``
+turns a float32 into the int32 whose integer order is the float's total
+order (``lax.top_k``'s own: ``-0.0`` below ``+0.0``, ``-inf`` below
+every finite score), and ``kth_largest_key`` builds the ``topk``-th
+largest key from its top bit down, a compare and a count of the row a
+bit.  What is kept is compared in keys too, so the selected set is
+``lax.top_k``'s position for position, zeros of both signs included.
+NaN scores are outside the contract (their keys lie beyond both
+infinities).
 """
 
 from __future__ import annotations
@@ -36,6 +48,38 @@ from flexflow_tpu.initializers import OnesInitializer, ZeroInitializer
 from flexflow_tpu.ops.base import CacheEntry, ParamSpec
 
 _NEG_INF = -jnp.inf
+
+
+def order_key(x):
+    """The int32 key of a float32: ``key(a) < key(b)`` exactly where
+    ``a`` lies below ``b`` in the float's total order (a negative's
+    magnitude bits are flipped, so ``-0.0`` is -1 and ``+0.0`` is 0)."""
+    b = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+
+
+def kth_largest_key(keys, k: int):
+    """The ``k``-th largest of each row of int32 ``keys`` (..., s), as
+    (..., 1): the largest threshold that ``k`` of the row's keys still
+    reach, exact and without a sort.  Built from the sign down: a
+    candidate keeps its bit iff ``k`` keys are still ``>=`` it, 32
+    counts of the row in all (on the chip one fused compare-and-reduce
+    a round, the keys held in VMEM across the loop: PERF.md §6 PR 43).
+    ``1 <= k <= s``."""
+    assert 1 <= k <= keys.shape[-1], (k, keys.shape)
+
+    def reached(cand):
+        return jnp.sum(keys >= cand, axis=-1, keepdims=True,
+                       dtype=jnp.int32) >= k
+
+    zero = jnp.zeros(keys.shape[:-1] + (1,), jnp.int32)
+    base = jnp.where(reached(zero), zero, jnp.iinfo(jnp.int32).min)
+
+    def set_bit(i, t):
+        cand = t | (jnp.int32(1) << (30 - i))
+        return jnp.where(reached(cand), cand, t)
+
+    return lax.fori_loop(0, 31, set_bit, base)
 
 
 def rope_half(x, pos, theta: float, sections: Optional[Sequence[int]] = None):
@@ -162,19 +206,26 @@ class TokenSelector:
         (b, t, s) against key positions ``0..s-1``, query row ``i`` (at
         position ``q_pos[i]``) keeps the causal positions whose score
         reaches its ``topk``-th largest causal score (all of them while
-        there are no more than ``topk``)."""
+        there are no more than ``topk``).  The ``topk``-th is found by
+        ``kth_largest_key``'s threshold search over the scores' order
+        keys, not by a sort, and every comparison after it is of keys:
+        the total order is ``lax.top_k``'s (``+0.0`` above ``-0.0``), so
+        with the lower position among equal keys the mask is the one
+        scattered from ``lax.top_k``'s indices.  A row with fewer than
+        ``topk`` causal positions finds the key of ``-inf`` and keeps
+        its whole past."""
         s = scores.shape[-1]
         causal = jnp.arange(s)[None, :] <= q_pos[:, None]
         if s <= self.topk:
             return jnp.broadcast_to(causal[None], scores.shape)
-        masked = jnp.where(causal[None], scores, _NEG_INF)
-        kth = lax.top_k(masked, self.topk)[0][..., -1:]
-        keep = causal[None] & (masked >= kth)
+        keys = order_key(jnp.where(causal[None], scores, _NEG_INF))
+        kth = kth_largest_key(keys, self.topk)
+        keep = causal[None] & (keys >= kth)
 
         def lowest_of_equals(keep):
             # Scores that tie with the topk-th (exact zeros, where every
             # head's ReLU is shut): the lowest positions, as top_k has it.
-            above = masked > kth
+            above = keys > kth
             ties = keep & ~above
             room = self.topk - jnp.sum(above, axis=-1, keepdims=True)
             return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))

@@ -616,7 +616,8 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     """``chip_smoke.py``'s ``serve/keye`` programs at the smoke preset's
     widths, compiled for the described chip: the prefill holds the
     streamed forward kernel (its leading ``topk`` rows) and the grouped
-    product beside the masked chunks, the decode superstep the grouped
+    product beside the masked chunks and no sort of a row of scores (its
+    threshold is searched), the decode superstep the grouped
     product, a sort a layer (the top-k) and a row gather of K and of V;
     the three caches go from parameter to result where they lie, K and V
     a position a row, updated a slot at a time in place."""
@@ -652,6 +653,13 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     assert not chip_smoke.has_kernel(step, "ff_flash_decode")
     for name in ("ff_flash_fwd_uneven", "ff_grouped_matmul"):
         assert chip_smoke.has_kernel(first, name), name
+    # The prefill's threshold is searched, not sorted (PR 43): its only
+    # sorts are the routers' (tokens, top-8).  The step still sorts a
+    # slot's whole row for its indices.
+    row_sorts = lambda text: [l for l in text.splitlines() if " sort(" in l
+                              and re.search(rf"f32\[[0-9,]*\b{seq}\]", l)]
+    assert row_sorts(first) == []
+    assert len(row_sorts(step)) == KEYE_VL2_SMOKE["num_hidden_layers"]
     assert chip_smoke.cache_shaped_relayouts(step, caches) == []
     # The selection: K and V of topk rows a slot, gathered a layer.
     gathers = [l for l in step.splitlines() if " gather(" in l

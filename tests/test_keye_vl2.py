@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark import common, weights
+from flexflow_tpu.analysis.program_audit import iter_eqns
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.models.transformer import (
     KEYE_VL2_SMOKE,
@@ -23,6 +24,7 @@ from flexflow_tpu.models.transformer import (
 from flexflow_tpu.ops.attention import MultiHeadAttention
 from flexflow_tpu.ops.base import TensorSpec
 from flexflow_tpu.ops.moe import MixtureOfExperts
+from flexflow_tpu.ops import token_select
 from flexflow_tpu.ops.token_select import TokenSelector, rope_half
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import ServingExecutor
@@ -166,6 +168,97 @@ def test_equal_scores_go_to_the_lower_position_in_both_paths():
     assert keep.tolist() == [True, True, False, False, True, False, False, True]
     idx, valid = sel.pick(scores[0], jnp.asarray([7]))
     assert sorted(np.asarray(idx[0]).tolist()) == [0, 1, 4, 7] and bool(valid.all())
+
+
+def _adversarial_rows(case):
+    """``(scores (rows, width) float32, k)`` of one case: row 0 is the
+    case's own row, the rest seeded noise with exact zeros of both
+    signs, which ride along at every width and ``k``."""
+    width, k = 256, 24
+    if case == "width_17x128":
+        width, k = 17 * 128, 300
+    elif case == "width_no_multiple_of_128":
+        width, k = 300, 41
+    elif case == "k_1":
+        k = 1
+    elif case == "k_width_less_1":
+        k = width - 1
+    r = np.random.default_rng(len(case))
+    x = r.standard_normal((12, width)).astype(np.float32)
+    x = np.where(r.random(x.shape) < 0.25,
+                 np.where(r.random(x.shape) < 0.5, 0.0, -0.0), x).astype(np.float32)
+    row = x[0]
+    if case == "all_equal":
+        row[:] = 0.75
+    elif case == "zeros_of_both_signs_at_the_threshold":
+        row[:] = -1.0
+        row[:8] = 1.0                     # k - 8 = 16 of the 40 zeros are kept
+        row[100:140] = np.where(np.arange(40) % 3 == 0, 0.0, -0.0)
+    elif case == "run_of_neg_inf":
+        row[40:200] = -np.inf
+    elif case == "fewer_than_k_finite":
+        row[k // 2:] = -np.inf
+    elif case == "negatives_only":
+        row[:] = -np.abs(row) - 1e-3
+    elif case == "denormals_beside_1e30":
+        row[0::3], row[1::3], row[2::3] = 1e-45, 1e30, -1e-45
+    return x, k
+
+
+@pytest.mark.parametrize("case", [
+    "all_equal", "zeros_of_both_signs_at_the_threshold", "run_of_neg_inf",
+    "fewer_than_k_finite", "negatives_only", "denormals_beside_1e30",
+    "width_17x128", "width_no_multiple_of_128", "k_1", "k_width_less_1"])
+def test_prefill_threshold_and_mask_are_top_ks_bit_for_bit(case):
+    """``keep`` sorts nothing, and still: its threshold is
+    ``lax.top_k``'s ``k``-th value bit for bit (``-0.0`` is not
+    ``+0.0``; an order key is its float's bits, folded), and its mask
+    the one scattered from ``lax.top_k``'s indices."""
+    x, k = _adversarial_rows(case)
+    rows, width = x.shape
+    # Rows that see from fewer than k positions up to the whole width.
+    q_pos = np.linspace(k - 3, width - 1, rows).astype(np.int32)
+    q_pos[0] = width - 1
+    causal = np.arange(width)[None, :] <= q_pos[:, None]
+    masked = jnp.where(causal, x, -jnp.inf)
+    top, idx = jax.lax.top_k(masked, k)
+    kth = token_select.kth_largest_key(token_select.order_key(masked), k)
+    assert kth.shape == (rows, 1)
+    assert np.array_equal(np.asarray(kth),
+                          np.asarray(token_select.order_key(top[:, -1:])))
+    sel = TokenSelector(dict(KEYE_VL2_TINY["sa_config"], topk=k), 1e4)
+    want = np.zeros((rows, width), bool)
+    np.put_along_axis(want, np.asarray(idx), True, axis=1)
+    keep = np.asarray(sel.keep(jnp.asarray(x)[None], jnp.asarray(q_pos)))[0]
+    assert np.array_equal(keep, want & causal)
+    assert np.array_equal(keep.sum(axis=1), np.minimum(q_pos + 1, k))
+
+
+def test_cached_prefill_sorts_nothing_and_the_decode_step_once():
+    """What PR 43 took and what it left, on the attention op alone (the
+    routers' top-8 of 128 is another matter): a prefill past ``topk``
+    holds no ``top_k`` and no ``sort``; a decode step still takes one
+    ``lax.top_k`` for its ``topk`` indices (ROADMAP B-M1 (f): a PR that
+    takes it changes this count)."""
+    cfg = _cfg()
+    t = 96
+    assert t > TOPK
+    op, _get, params, a = _attn_op(cfg, 1, t)
+    caches = {f"cache_{e}": jnp.zeros((1,) + ce.shape, ce.dtype)
+              for e, ce in op.cache_entries(S).items()}
+
+    def forward(x, pos):
+        return op.forward(params, [x], dict(caches, pos=pos), training=False)
+
+    def primitives(x, pos):              # the sub-programs' too
+        return [e.primitive.name for e in iter_eqns(
+            jax.make_jaxpr(forward)(x, pos).jaxpr, descend_custom_ad=True)]
+
+    prefill = primitives(a, jnp.zeros((1,), jnp.int32))
+    assert "cond" in prefill                 # keep's tie break: it is reached
+    assert not {"top_k", "sort"} & set(prefill)
+    step = primitives(a[:, :1], jnp.full((1,), t - 1, jnp.int32))
+    assert step.count("top_k") == 1 and "sort" not in step
 
 
 # -- the attention op -------------------------------------------------------
