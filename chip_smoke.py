@@ -83,6 +83,8 @@ class Sizes:
     serve_xing: Tuple[str, ...]
     #: The Keye-VL-2.0 preset: prompts longer than its ``topk``.
     serve_keye: Tuple[str, ...]
+    #: The Laguna preset: prompts several windows long.
+    serve_laguna: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -132,6 +134,15 @@ FULL = Sizes(
                 "--max-batch", "4", "--requests", "6", "--max-new", "12",
                 "--prompt-len", "300:450", "--buckets", "512",
                 "--dtype", "bfloat16"),
+    # 2048 positions under a window of 512 (one ``flash_decode`` chunk):
+    # prompts of 1100-1900 fill every ring before the first decode step,
+    # the banded forward walks two or three key blocks a query block
+    # (blocks of 256 at the 1280 bucket, of 512 at 2048), and groups of
+    # 9 and 6 query heads decode through the kernel.
+    serve_laguna=("--model-config", "laguna-smoke", "--max-seq", "2048",
+                  "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                  "--prompt-len", "1100:1900", "--buckets", "1280,2048",
+                  "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
     dlrm4=("-b", "1024", "-i", "3", "--momentum", "0", "--wd", "0",
@@ -716,6 +727,72 @@ def keye_phase(argv: Sequence[str]) -> None:
           "serve/keye: a prompt under topk: nothing was selected")
 
 
+def laguna_phase(argv: Sequence[str]) -> None:
+    """The Laguna preset through ``apps.serve``: window and full
+    grouped-query layers with different head counts in one served graph,
+    a ring of ``window`` positions beside a full cache, the kernels of
+    both (the banded and the causal forward, one decode kernel over a
+    ring and over a full cache), a decode superstep that moves no cache,
+    a superstep's event counting two full layers and three rings, and
+    the tokens of the plain ``jnp`` paths."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+
+    run = serve_run("serve/laguna", argv)
+    sex = run.srv.ex
+    ops = sex.attn_ops
+    windows = [op.attrs["window"] for op in ops]
+    heads = [op.attrs["num_heads"] for op in ops]
+    w = next(x for x in windows if x)
+    check(all(isinstance(op, MultiHeadAttention)
+              and op.attrs["gate"] == "per_head" for op in ops)
+          and windows == [None, w, w, w, None]
+          and heads[1] == heads[2] == heads[3] != heads[0] == heads[4]
+          and any(op.name.endswith("_moe") and op.attrs["router"] == "sigmoid"
+                  for op in sex._layers),
+          f"serve/laguna: the served graph has windows {windows}, query "
+          f"heads {heads}")
+    caches = sex.init_cache()
+    a = ops[0].attrs
+    last = a["head_dim"] % 128 == 0
+
+    def shape(positions):
+        if last:
+            return (sex.max_batch, a["num_kv_heads"], a["head_dim"], positions)
+        return (sex.max_batch, positions, a["num_kv_heads"], a["head_dim"])
+
+    got = [tuple(caches[op.name]["k"].shape) for op in ops]
+    want = [shape(x or sex.max_seq) for x in windows]
+    check(got == want, f"serve/laguna: caches {got}, expected {want}")
+    check(sex._attention_paths(False) == "gqa_dense+gqa_window_dense"
+          and sex._attention_paths(True) == "gqa_decode+gqa_window_decode",
+          "serve/laguna: the programs announce other paths")
+    decode = check_program_kernels(
+        "serve/laguna", run, caches,
+        decode=("ff_flash_decode", "ff_grouped_matmul"),
+        prefill=("ff_flash_fwd_window", "ff_flash_fwd_uneven",
+                 "ff_grouped_matmul"))
+    moved = cache_shaped_relayouts(decode, caches)
+    check(not moved, f"serve/laguna: the compiled decode superstep moves a "
+                     f"whole cache: {moved[:3]}")
+    k = int(run.stats["decode_steps_per_call"])
+    at = min(2000, sex.max_seq - k - 1)
+    rows = sex.kv_rows(np.full((sex.max_batch,), at, np.int32), k)
+    blocks = [op.decode_fetch_block(sex.max_batch, sex.max_seq,
+                                    sex.decode_kernel) for op in ops]
+    live = at + 1 + np.arange(k)
+    each = [int((-(-(np.minimum(live, x) if x else live) // b) * b).sum())
+            for x, b in zip(windows, blocks)]
+    check(rows["kv_rows_fetched"] == round(sex.max_batch * sum(each) / 5)
+          and each[1] == k * (-(-w // blocks[1]) * blocks[1]) < each[0]
+          and "state_bytes" not in rows,
+          f"serve/laguna: a superstep at {at} live positions reports {rows}, "
+          f"a slot's layers {each}")
+    check(all(len(r.prompt) > 2 * w for r in run.requests),
+          "serve/laguna: a prompt under two windows: no ring wrapped twice")
+    oracle = serve_run("serve/laguna-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/laguna", run, oracle, tol=BF16_KERNEL_TOL)
+
+
 # -- four chips ---------------------------------------------------------------
 
 
@@ -836,6 +913,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
         ("serve/xing", lambda: latent_phase(sz.serve_xing, "serve/xing",
                                             streams=4)),
         ("serve/keye", lambda: keye_phase(sz.serve_keye)),
+        ("serve/laguna", lambda: laguna_phase(sz.serve_laguna)),
     ]
 
 

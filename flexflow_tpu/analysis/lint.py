@@ -336,7 +336,8 @@ FF008_SPAN_NAMES = frozenset({
 FF008_KERNEL_NAMES = frozenset({
     "ff_flash_fwd", "ff_flash_fwd_stream", "ff_flash_dq",
     "ff_flash_dq_stream", "ff_flash_dkv", "ff_flash_dkv_stream",
-    "ff_flash_decode", "ff_flash_fwd_uneven", "ff_mla_decode",
+    "ff_flash_decode", "ff_flash_fwd_uneven", "ff_flash_fwd_window",
+    "ff_mla_decode",
     "ff_grouped_matmul", "ff_kda_intra", "ff_kda_chunk",
     "ff_kda_decode",
     "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",

@@ -31,7 +31,9 @@ Flags beyond the common set:
                                or a preset of models/transformer.py:
                                deepseek-v3-tiny, deepseek-v3-smoke,
                                xing4-tiny, xing4-smoke,
-                               solar-open2-tiny, solar-open2-smoke)
+                               solar-open2-tiny, solar-open2-smoke,
+                               keye-vl2-tiny, keye-vl2-smoke,
+                               laguna-tiny, laguna-smoke)
 
 Capacity flags (SERVING.md "Cache layout"):
   --kv-block N       paged KV caches: N-token blocks + per-slot block

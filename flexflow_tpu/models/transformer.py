@@ -286,9 +286,112 @@ def _keye_vl2_lm(ff: FFModel, tok, m: Dict[str, Any]):
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
 
+def _laguna_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """The Laguna block family (``model_type`` ``laguna``): RMSNorm
+    pre-norm blocks of grouped-query attention whose kind and head count
+    go by layer (``layer_types``: ``full_attention`` over the whole
+    causal past, ``sliding_attention`` over the last ``sliding_window``
+    positions; ``num_attention_heads_per_layer`` query heads over
+    ``num_key_value_heads``), rotary positions by kind
+    (``rope_parameters``: a sub-width of the head under
+    ``partial_rotary_factor``, YaRN's frequencies under ``rope_type``
+    ``yarn``), a sigmoid gate a head on the attended values
+    (``gating_types``: ``per_head``), and a feed-forward that is a gated
+    SiLU MLP on the ``dense`` layers of ``mlp_layer_types`` and an expert
+    layer under a sigmoid top-k router with one shared expert on the
+    others; no bias, an untied head.  ``held_experts`` (the
+    deployment's) names the routed experts this chip holds; the router's
+    width is then ``published.num_experts`` and ``num_experts`` the
+    number held."""
+    layers = m["num_hidden_layers"]
+    kinds, mlps = m["layer_types"][:layers], m["mlp_layer_types"][:layers]
+    gates = m.get("gating_types", ["per_head"] * layers)[:layers]
+    heads = m.get("num_attention_heads_per_layer",
+                  [m["num_attention_heads"]] * layers)[:layers]
+    shared = m.get("shared_expert_intermediate_size", 0)
+    dense = [i for i, k in enumerate(m["mlp_layer_types"]) if k == "dense"]
+    for key, got, built in (
+            ("attention_bias", m.get("attention_bias", False), (False,)),
+            ("tie_word_embeddings", m.get("tie_word_embeddings", False),
+             (False,)),
+            ("moe_apply_router_weight_on_input",
+             m.get("moe_apply_router_weight_on_input", False), (False,)),
+            ("moe_router_logit_softcapping",
+             m.get("moe_router_logit_softcapping", 0), (0, None)),
+            ("decoder_sparse_step", m.get("decoder_sparse_step", 1), (1,)),
+            ("gating", m.get("gating", "per-head"), ("per-head",)),
+            ("gating_types", sorted(set(gates)), (["per_head"],)),
+            ("layer_types", sorted(set(kinds) - {"full_attention",
+                                                 "sliding_attention"}), ([],)),
+            ("mlp_layer_types", sorted(set(mlps) - {"dense", "sparse"}),
+             ([],)),
+            ("mlp_only_layers", sorted(m.get("mlp_only_layers", dense)),
+             (dense,)),
+            ("shared_expert_intermediate_size",
+             shared % m["moe_intermediate_size"], (0,))):
+        if got not in built:
+            raise ValueError(
+                f"laguna builder: {key}={got!r} is not built yet "
+                f"(only {built[0]!r})")
+    if len(kinds) < layers or len(mlps) < layers or len(heads) < layers:
+        raise ValueError(
+            f"laguna builder: layer_types, mlp_layer_types and "
+            f"num_attention_heads_per_layer must name all {layers} layers")
+    d, eps, hd = m["hidden_size"], m["rms_norm_eps"], m["head_dim"]
+    held = m.get("held_experts")
+    routed = m["num_experts"]
+    if held is not None:
+        routed = m.get("published", {}).get("num_experts", routed)
+        if len(held) != m["num_experts"]:
+            raise ValueError(
+                f"laguna builder: held_experts names {len(held)} experts, "
+                f"num_experts (the number held) is {m['num_experts']}")
+
+    def rope(kind):
+        r = m["rope_parameters"][kind]
+        turn = {"theta": float(r["rope_theta"])}
+        if r.get("partial_rotary_factor", 1) != 1:
+            turn["rotary_dim"] = int(round(hd * r["partial_rotary_factor"]))
+        if r.get("rope_type", "default") != "default":
+            turn["scaling"] = r        # the type's own keys (YaRN's)
+        return turn
+
+    x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
+                          dtype=jnp.dtype(ff.config.compute_dtype))
+    for i in range(layers):
+        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
+        a = ff.multihead_attention(
+            a, heads[i], causal=True, use_bias=False,
+            num_kv_heads=m["num_key_value_heads"], head_dim=hd,
+            gate="per_head", rope=rope(kinds[i]),
+            window=m["sliding_window"] if kinds[i] == "sliding_attention"
+            else None, name=f"blk{i}_attn")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
+        if mlps[i] == "dense":
+            g = ff.dense(h, m["intermediate_size"], activation="silu",
+                         use_bias=False, name=f"blk{i}_mlp_gate")
+            up = ff.dense(h, m["intermediate_size"], use_bias=False,
+                          name=f"blk{i}_mlp_up")
+            h = ff.dense(ff.multiply(g, up, name=f"blk{i}_mlp_act"), d,
+                         use_bias=False, name=f"blk{i}_mlp_down")
+        else:
+            h = ff.moe(
+                h, routed, m["moe_intermediate_size"],
+                top_k=m["num_experts_per_tok"], dispatch="sorted",
+                router="sigmoid", gated=True, activation="silu",
+                shared_experts=shared // m["moe_intermediate_size"],
+                norm_topk_prob=m["norm_topk_prob"],
+                routed_scale=m["moe_routed_scaling_factor"],
+                held_experts=held, name=f"blk{i}_moe")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
+
+
 _BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
            "xing4_0": _deepseek_v3_lm, "solar_open2": _solar_open2_lm,
-           "KeyeVL2": _keye_vl2_lm}
+           "KeyeVL2": _keye_vl2_lm, "laguna": _laguna_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
 #: audit catalog): every mechanism of the block, no published width.
@@ -396,7 +499,53 @@ KEYE_VL2_SMOKE: Dict[str, Any] = {
                   "q_chunk_size": 128, "topk": 256},
 }
 
-PRESETS = {"keye-vl2-tiny": KEYE_VL2_TINY,
+#: The Laguna family at unit-test size: five layers (dense then sparse
+#: feed-forwards; full, window, window, window, full attention with 4
+#: and 6 query heads over 2), a window far smaller than the tests'
+#: sequences (so that a ring wraps more than twice), both rotary
+#: settings; widths no kernel takes.
+LAGUNA_TINY: Dict[str, Any] = {
+    "model_type": "laguna", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 5,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "attention_bias": False, "rms_norm_eps": 1e-6, "num_experts": 16,
+    "num_experts_per_tok": 3, "moe_intermediate_size": 32,
+    "shared_expert_intermediate_size": 32, "norm_topk_prob": True,
+    "decoder_sparse_step": 1, "mlp_only_layers": [0],
+    "tie_word_embeddings": False, "gating": "per-head", "sliding_window": 16,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 8,
+            "original_max_position_embeddings": 32, "beta_slow": 1,
+            "beta_fast": 32, "attention_factor": 1.2079441541679836,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1}},
+    "layer_types": ["full_attention", "sliding_attention",
+                    "sliding_attention", "sliding_attention",
+                    "full_attention"],
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"],
+    "gating_types": ["per_head"] * 5,
+    "num_attention_heads_per_layer": [4, 6, 6, 6, 4],
+    "moe_apply_router_weight_on_input": False,
+    "moe_routed_scaling_factor": 2.5, "moe_router_logit_softcapping": 0,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip (heads of one whole lane tile, groups of 9 and 6 query heads
+#: a cached head as published, a window of one ``flash_decode`` chunk,
+#: expert products of whole tiles): chip_smoke.py.
+LAGUNA_SMOKE: Dict[str, Any] = {
+    **LAGUNA_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "intermediate_size": 512, "head_dim": 128, "num_attention_heads": 12,
+    "num_attention_heads_per_layer": [12, 18, 18, 18, 12],
+    "moe_intermediate_size": 128, "shared_expert_intermediate_size": 128,
+    "sliding_window": 512,
+}
+
+PRESETS = {"laguna-tiny": LAGUNA_TINY,
+           "laguna-smoke": LAGUNA_SMOKE,
+           "keye-vl2-tiny": KEYE_VL2_TINY,
            "keye-vl2-smoke": KEYE_VL2_SMOKE,
            "deepseek-v3-tiny": DEEPSEEK_V3_TINY,
            "deepseek-v3-smoke": DEEPSEEK_V3_SMOKE,

@@ -108,6 +108,7 @@ KERNEL_CATALOG = frozenset({
     "ff_flash_dkv_stream",
     "ff_flash_decode",
     "ff_flash_fwd_uneven",
+    "ff_flash_fwd_window",
     "ff_mla_decode",
     "ff_grouped_matmul",
     "ff_kda_intra",

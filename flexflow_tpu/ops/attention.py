@@ -86,6 +86,39 @@ def _einsum_attention(q, k, v, causal: bool, scale: Optional[float] = None):
     return jnp.einsum("bhqk,bhkd->bhqd", attn, v).astype(dtype)
 
 
+def _band_attention(q, k, v, window: int):
+    """Dense attention on (b, h, t, hd) heads under the causal band
+    ``t - window < s <= t``, f32 scores; returns the input dtype."""
+    dtype = q.dtype
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    rows = jnp.arange(q.shape[2])[:, None]
+    cols = jnp.arange(k.shape[2])[None, :]
+    mask = (cols <= rows) & (cols > rows - window)
+    attn = jax.nn.softmax(jnp.where(mask[None, None], scores, _NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", attn, v).astype(dtype)
+
+
+def _ring_rows(length, t: int, window: int):
+    """Which of a prefill's ``t`` positions each of the ring's
+    ``window`` rows takes when ``length`` of them are the prompt's: row
+    ``r`` the newest ``s < length`` with ``s mod window == r`` (rows no
+    position reaches yet point at some row of the call; a decode step's
+    live count keeps them out)."""
+    r = jnp.arange(window)
+    turns = jnp.maximum(length - 1 - r, 0) // window
+    return jnp.clip(r + window * turns, 0, t - 1)
+
+
+def _gate_heads(out, logits, head_dim: int):
+    """``out`` (b, t, heads * head_dim) times ``sigmoid(logits)`` (b, t,
+    heads), one value a head: the sigmoid in f32, the product in the
+    compute dtype."""
+    b, t, _ = out.shape
+    gate = jax.nn.sigmoid(logits.astype(jnp.float32)).astype(out.dtype)
+    return (out.reshape(b, t, -1, head_dim) * gate[..., None]).reshape(out.shape)
+
+
 class LayerNorm(Op):
     """Layer normalization over the last (feature) dim."""
 
@@ -198,6 +231,24 @@ class MultiHeadAttention(Op):
     alone: the paged pool, the offset prefill and ``shard=`` raise
     ``NotImplementedError`` (ROADMAP B-M1), and so does the ring path
     for ``rope`` or ``select``.
+
+    Three more again (PR 44), each absent by default.  ``gate="per_head"``:
+    one gate value a query head (``wg`` is ``(d, heads)``) where
+    ``gate=True`` has one a value.  ``rope`` may carry ``rotary_dim`` (the
+    leading sub-width of the head the pairs are taken from; the rest
+    passes) and ``scaling`` (a ``rope_scaling`` dict for
+    ``rope_frequencies``: YaRN's blended frequencies, cos and sin times
+    its ``attention_factor``).  ``window`` (W): query ``t`` attends keys
+    ``s`` with ``t - W < s <= t``, and the op keeps for a slot a RING of W
+    positions a head, not ``max_seq`` (``sequence=False`` entries: the
+    executor tells a prefill its true ``length``): a prefill installs its
+    last ``min(length, W)`` positions at their residues ``s mod W``, a
+    decode step writes position ``p`` at ``p mod W`` and attends ``min(p +
+    1, W)`` rows.  Keys are rotated before they are cached, so the order
+    of the ring's rows does not matter to the softmax.  The padded layout
+    on one device alone: the paged pool, the offset prefill, ``shard=``
+    and the ring-attention path raise ``NotImplementedError`` (ROADMAP
+    B-M4).
     """
 
     def __init__(
@@ -214,6 +265,7 @@ class MultiHeadAttention(Op):
         qk_norm: Optional[float] = None,
         rope: Optional[dict] = None,
         select: Optional[dict] = None,
+        window: Optional[int] = None,
     ):
         super().__init__(name, [x])
         assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
@@ -225,10 +277,16 @@ class MultiHeadAttention(Op):
         assert kv >= 1 and num_heads % kv == 0, (num_heads, num_kv_heads)
         self.attrs = dict(num_heads=num_heads, causal=causal, use_bias=use_bias,
                           num_kv_heads=kv, head_dim=int(head_dim),
-                          gate=bool(gate), qk_norm=qk_norm, rope=rope,
-                          select=select)
+                          gate=gate if gate == "per_head" else bool(gate),
+                          qk_norm=qk_norm, rope=rope, select=select,
+                          window=None if window is None else int(window))
         if select is not None and not causal:
             raise ValueError(f"{name}: a token selector reads the causal past")
+        if window is not None and (not causal or select is not None
+                                   or int(window) < 1):
+            raise ValueError(
+                f"{name}: window={window!r} is a causal band of at least one "
+                f"position, and is not built under a token selector")
         #: The token selector composed into this op, if any.
         self.select = None if select is None else TokenSelector(
             select, theta=(rope or {}).get("theta", 10000.0),
@@ -246,7 +304,11 @@ class MultiHeadAttention(Op):
 
     @property
     def cache_paged(self) -> bool:
-        return self.select is None
+        return self.select is None and self.attrs["window"] is None
+
+    @property
+    def decode_window(self) -> Optional[int]:
+        return self.attrs["window"]
 
     @property
     def group(self) -> int:
@@ -272,7 +334,8 @@ class MultiHeadAttention(Op):
             "wo": ParamSpec((dq, d), dt, ki, ("c", None)),
         }
         if a["gate"]:
-            specs["wg"] = ParamSpec((d, dq), dt, ki, (None, "c"))
+            wide = a["num_heads"] if a["gate"] == "per_head" else dq
+            specs["wg"] = ParamSpec((d, wide), dt, ki, (None, "c"))
         if a["use_bias"]:
             specs["bq"] = ParamSpec((dq,), dt, ZeroInitializer(), ("c",))
             specs["bk"] = ParamSpec((dkv,), dt, ZeroInitializer(), ("c",))
@@ -293,7 +356,12 @@ class MultiHeadAttention(Op):
             row = CacheEntry((max_seq, h * hd), self.outputs[0].dtype)
             return {"k": row, "v": row, TokenSelector.ENTRY:
                     self.select.cache_entry(max_seq, self.outputs[0].dtype)}
-        if self.positions_last:
+        if self.attrs["window"] is not None:
+            # A ring of ``window`` positions whatever ``max_seq``.
+            w = self.attrs["window"]
+            row = CacheEntry((h, hd, w) if self.positions_last else (w, h, hd),
+                             self.outputs[0].dtype, sequence=False)
+        elif self.positions_last:
             row = CacheEntry((h, hd, max_seq), self.outputs[0].dtype,
                              ("c", None, None))
         else:
@@ -307,6 +375,8 @@ class MultiHeadAttention(Op):
         kind = "gqa" if self.group > 1 else "kv"
         if self.select is not None:
             kind += "_select"
+        if self.attrs["window"] is not None:
+            kind += "_window"
         return f"{kind}_decode" if decode else f"{kind}_dense"
 
     def _kernel_block(self, slots: int, max_seq: int, c: int = 1) -> int:
@@ -323,6 +393,8 @@ class MultiHeadAttention(Op):
         if self.select is not None:
             # Rows, not blocks: the gather fetches the chosen positions.
             return 1
+        if self.attrs["window"] is not None:
+            return self._ring_block(slots, kernel) or self.attrs["window"]
         block = 0 if kernel is False else self._kernel_block(slots, max_seq, c)
         return block or max_seq
 
@@ -380,6 +452,10 @@ class MultiHeadAttention(Op):
             return self._forward_cached(params, x, state)
         pc = getattr(self, "_pc", None)
         S = pc.s if pc is not None else 1
+        if S > 1 and self.attrs["window"] is not None:
+            raise NotImplementedError(
+                f"{self.name}: no ring-attention path under a window "
+                f"(ROADMAP B-M4)")
         q, k, v = self._project(params, x)
         if self.positional:
             if S > 1:
@@ -438,9 +514,30 @@ class MultiHeadAttention(Op):
         if a["qk_norm"] is not None:
             heads = rms_norm(heads, scale, a["qk_norm"])
         if a["rope"] is not None:
-            heads = rope_half(heads, pos[:, None], a["rope"]["theta"],
-                              a["rope"].get("sections"))
+            rope = a["rope"]
+            more = {}
+            if rope.get("rotary_dim") or rope.get("scaling"):
+                more = self._rope_turn(rope)
+            heads = rope_half(heads, pos[:, None], rope["theta"],
+                              rope.get("sections"), **more)
         return heads
+
+    def _rope_turn(self, rope):
+        """``rope_half``'s further arguments under a rotary sub-width or
+        a ``scaling``: the sub-width, its pairs' frequencies and the
+        scale of cos and sin (``rope_frequencies``; a ``scaling`` that
+        states its own ``attention_factor`` has to agree with it)."""
+        r = int(rope.get("rotary_dim") or self.attrs["head_dim"])
+        scaling = rope.get("scaling")
+        inv, wave, soft = rope_frequencies(r, rope["theta"], scaling)
+        stated = (scaling or {}).get("attention_factor")
+        if soft != 1.0 or (stated is not None
+                           and abs(float(stated) - wave) > 1e-6 * wave):
+            raise ValueError(
+                f"{self.name}: rope scaling {scaling!r}: cos and sin scale by "
+                f"{wave!r}, the softmax by {soft!r}; only a scale of cos and "
+                f"sin that agrees with attention_factor is built")
+        return dict(rotary_dim=r, inv=inv, wave=wave)
 
     def _index(self, params, x, index):
         """The selector's ``(q, k, w)`` of this call's tokens at their
@@ -589,7 +686,9 @@ class MultiHeadAttention(Op):
 
     def _output(self, params, x, out):
         """The output gate (where the op has one) and projection."""
-        if self.attrs["gate"]:
+        if self.attrs["gate"] == "per_head":
+            out = _gate_heads(out, x @ params["wg"], self.attrs["head_dim"])
+        elif self.attrs["gate"]:
             # The sigmoid in f32; the product in the compute dtype (an
             # f32 copy of a 32k-token prefill's values is 1 GiB).
             gate = jax.nn.sigmoid((x @ params["wg"]).astype(jnp.float32))
@@ -678,6 +777,8 @@ class MultiHeadAttention(Op):
     def _forward_cached(self, params, x, state):
         if self.select is not None:
             return self._forward_selected(params, x, state)
+        if self.attrs["window"] is not None:
+            return self._forward_window(params, x, state)
         ck, cv = state["cache_k"], state["cache_v"]
         q, k, v = self._project(params, x)
         qh, kh, vh = map(self._split_heads, (q, k, v))   # (B, h, t, hd)
@@ -881,10 +982,111 @@ class MultiHeadAttention(Op):
         repeated a query head (the differentiable path)."""
         if self.group > 1:
             k, v = (jnp.repeat(x, self.group, axis=1) for x in (k, v))
+        if self.attrs["window"] is not None:
+            # No window in the training flash kernels (ROADMAP B-M4).
+            return self._merge_heads(
+                _band_attention(q, k, v, self.attrs["window"]), dtype)
         out = self._flash_dense(q, k, v)
         if out is None:
             out = _einsum_attention(q, k, v, self.attrs["causal"])
         return self._merge_heads(out, dtype)
+
+    # -- a window: the band and the ring (PR 44) ------------------------------
+
+    def _ring_block(self, slots: int, kernel: Optional[bool]) -> int:
+        """``flash_decode``'s chunk over a ring of ``window`` positions;
+        0 where the ring is decoded by the einsum oracle (``kernel``
+        false, a cache not positions-last, one query head a cached head:
+        the kernel's ring is its grouped body's)."""
+        h, hd = self.attrs["num_kv_heads"], self.attrs["head_dim"]
+        w, dtype = self.attrs["window"], self.outputs[0].dtype
+        if kernel is False or not self.positions_last or self.group == 1 or \
+                not pallas_kernels.flash_decode_supported(
+                    (slots, w, h, hd), dtype, self.group):
+            return 0
+        return pallas_kernels.flash_decode_chunk(w, h, hd, dtype, self.group)
+
+    def _forward_window(self, params, x, state):
+        """The cached forward of an op with a ``window`` W: caches ``k``
+        and ``v`` are rings (B, h_kv, hd, W) (or (B, W, h_kv, hd) where
+        the head does not fill lane tiles), row ``r`` holding the newest
+        position ``s`` with ``s mod W == r``.  Prefill (t > 1): banded
+        attention over the call's own keys, and the ring filled from the
+        last ``min(length, W)`` of the ``length`` true positions
+        (``state["length"]``; the bucket's padding never enters the
+        ring).  Decode (t == 1): position ``p`` written at ``p mod W``,
+        ``min(p + 1, W)`` rows attended, in ``flash_decode`` where its
+        gate takes the ring."""
+        w, plan = self.attrs["window"], getattr(self, "_plan", None)
+        if "block_table" in state or "chunk" in state or \
+                (plan is not None and plan.num_devices > 1):
+            raise NotImplementedError(
+                f"{self.name}: a window's ring over a paged pool, an offset "
+                f"prefill or a sharded cache is not built (ROADMAP B-M4)")
+        ck, cv = state["cache_k"], state["cache_v"]
+        q, k, v = self._project(params, x)
+        qh, kh, vh = map(self._split_heads, (q, k, v))   # (B, h, t, hd)
+        b, _, t, _ = qh.shape
+        if self.positional:
+            qh, kh = self._place(params, qh, kh,
+                                 self._positions(state, b, t)[1])
+        if t == 1:
+            out, ck, cv = self._decode_ring(qh[:, :, 0], kh[:, :, 0],
+                                            vh[:, :, 0], ck, cv, state["pos"])
+            y = self._merge_heads(out[:, :, None], x.dtype)
+        else:
+            rows = _ring_rows(state.get("length", t), t, w)
+            order = (0, 1, 3, 2) if self.positions_last else (0, 2, 1, 3)
+            ck, cv = (jnp.take(c, rows, axis=2).transpose(order).astype(ring.dtype)
+                      for c, ring in ((kh, ck), (vh, cv)))
+            y = self._attend_band(qh, kh, vh, x.dtype)
+        new_state = dict(state)
+        new_state["cache_k"], new_state["cache_v"] = ck, cv
+        return [self._output(params, x, y)], new_state
+
+    def _attend_band(self, qh, kh, vh, dtype):
+        """A serving prefill's banded attention on heads (B, h, t, hd)
+        against (B, h_kv, t, hd): the banded forward kernel, which
+        visits a query block's band alone, where its gate takes the
+        shape; else the dense band over repeated heads."""
+        w, plan = self.attrs["window"], getattr(self, "_plan", None)
+        if (plan is None or plan.num_devices == 1) and \
+                pallas_kernels.flash_window_supported(qh.shape, w):
+            out = pallas_kernels.flash_fwd_window(
+                qh, kh, vh, 1.0 / math.sqrt(qh.shape[-1]), w)
+            return self._merge_heads(out, dtype)
+        return self._attend_heads(qh, kh, vh, dtype)
+
+    def _ring_index(self, pos):
+        """``(the row position ``pos`` is written at, the rows live once
+        it is)`` of the ring."""
+        w = self.attrs["window"]
+        return lax.rem(pos, w), jnp.minimum(pos + 1, w)
+
+    def _decode_ring(self, q1, k1, v1, ck, cv, pos):
+        """One decode step over the ring: ``k1``/``v1`` (B, h_kv, hd)
+        written at ``pos mod W``, the query ``q1`` (B, h, hd) attending
+        the ``min(pos + 1, W)`` live rows.  ``(out, ck, cv)``."""
+        last = self.positions_last
+        at, live = self._ring_index(pos)
+        if self._ring_block(ck.shape[0], self.decode_kernel):
+            return pallas_kernels.flash_decode(
+                q1, k1, v1, ck, cv, live, positions_last=True, write_at=at)
+        if self.decode_kernel:
+            import logging
+
+            logging.getLogger("ff.attention").warning(
+                "%s: flash_decode does not take the ring %s -- falling back "
+                "to the einsum decode oracle", self.name, ck.shape)
+        rows = jnp.arange(ck.shape[0])
+        if last:
+            ck = ck.at[rows, :, :, at].set(k1.astype(ck.dtype))
+            cv = cv.at[rows, :, :, at].set(v1.astype(cv.dtype))
+            return _einsum_decode(q1, ck.transpose(0, 3, 1, 2),
+                                  cv.transpose(0, 3, 1, 2), live - 1), ck, cv
+        ck = ck.at[rows, at].set(k1.astype(ck.dtype))
+        cv = cv.at[rows, at].set(v1.astype(cv.dtype))
+        return _einsum_decode(q1, ck, cv, live - 1), ck, cv
 
     def _flash_dense(self, q, k, v):
         """Run the Pallas flash kernel on the dense path, or None to

@@ -136,6 +136,10 @@ class Op:
         by name."""
         return {}
 
+    #: Positions of a slot's past a decode step reads at most (an
+    #: attention window), None = every live one.
+    decode_window: Optional[int] = None
+
     def decode_fetch_block(self, slots: int, max_seq: int,
                            kernel: Optional[bool], c: int = 1) -> int:
         """Positions of a slot's padded cache one decode step fetches

@@ -1313,7 +1313,7 @@ def _lane_tile(t):
 
 
 def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
-               sem, wsem, cur, *, whole, last, emit):
+               sem, wsem, cur, *, whole, last, emit, at_ref=None):
     """One slot (grid step) of the decode kernels' cache traffic.
 
     ``k_hbm``/``v_hbm`` (B, h, hd, S) stay in HBM.  The live chunks of
@@ -1335,7 +1335,16 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
     lanes) as 32-bit words of p values each, the packing a vector
     register holds the cache's dtype in, so that a column moves to its
     lane by one rotate a register and is selected into the tile word
-    for word."""
+    for word.
+
+    ``at_ref`` (a second scalar operand, absent by default): where each
+    slot's column is WRITTEN, apart from how many positions are live.
+    The cache is then a ring (``ops/attention.py``, ``window``): the
+    slot's live chunks stream in the order that ends with the chunk
+    that holds the written position (once the ring is full every chunk
+    is live and whole, and the order of a softmax's terms is free), and
+    ``last`` gets as ``at`` the last live offset of that chunk, which
+    may lie past the chunk's end."""
     b = pl.program_id(0)
     slots = pl.num_programs(0)
     depth, _, _, chunk = ring_k.shape
@@ -1345,6 +1354,8 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
         return lax.div(len_ref[i] + (chunk - 1), chunk)
 
     def fetch(i, j, k):
+        if at_ref is not None:
+            j = lax.rem(at_ref[i] // chunk + 1 + j, live(i))
         at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
         return [pltpu.make_async_copy(hbm.at[i, :, :, at], ring.at[k],
                                       sem.at[n, k])
@@ -1367,7 +1378,7 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
         cur[0], cur[1], cur[2] = jnp.int32(0), fb, fj
 
     n = live(b)
-    pos = len_ref[b] - 1
+    pos = len_ref[b] - 1 if at_ref is None else at_ref[b]
 
     def below(j, c):
         i, fb, fj = c
@@ -1381,7 +1392,11 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
     k = lax.rem(i, depth)
     for copy in fetch(b, n - 1, k):
         copy.wait()
-    at = pos - (n - 1) * chunk
+    if at_ref is None:
+        at = limit = pos - (n - 1) * chunk
+    else:
+        at = lax.rem(pos, chunk)
+        limit = len_ref[b] - 1 - (pos - at)
     tile, lane = _lane_tile(at // _LANES), lax.rem(at, _LANES)
     here = _lane_iota(new_ref.shape[2]) == lane
 
@@ -1393,7 +1408,7 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
             jnp.where(here, column, words), ref.dtype)
         return new
 
-    last(ring_k.at[k], ring_v.at[k], at, place)
+    last(ring_k.at[k], ring_v.at[k], limit, place)
     writes = [pltpu.make_async_copy(ring.at[k, :, :, tile],
                                     hbm.at[b, :, :, _lane_tile(pos // _LANES)],
                                     wsem.at[n])
@@ -1526,7 +1541,7 @@ def _decode_kernel(len_ref, q_ref, new_ref, k_in, v_in,
 
 def _decode_grouped_kernel(len_ref, q_ref, new_ref, k_in, v_in,
                            o_ref, k_hbm, v_hbm, ring_k, ring_v, sem, wsem,
-                           cur, m_scr, l_scr, acc_scr, *, scale):
+                           cur, m_scr, l_scr, acc_scr, *, scale, at_ref=None):
     """The grouped-query body: ``q_ref`` (1, h_kv, g, hd) holds the g
     query heads of every cached head as rows, so a live K chunk
     (hd, chunk) is fetched once for its group and scores and values are
@@ -1587,14 +1602,20 @@ def _decode_grouped_kernel(len_ref, q_ref, new_ref, k_in, v_in,
         over_heads(head)
 
     _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
-               sem, wsem, cur, last=last, emit=emit,
+               sem, wsem, cur, last=last, emit=emit, at_ref=at_ref,
                whole=lambda k_ref, v_ref: over_heads(
                    lambda i: step(i, k_ref[i], v_ref[i])))
 
 
+def _decode_ring_kernel(len_ref, at_ref, *refs, scale):
+    """``_decode_grouped_kernel`` over a ring: a second scalar operand
+    says where each slot's column is written."""
+    _decode_grouped_kernel(len_ref, *refs, scale=scale, at_ref=at_ref)
+
+
 def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
                  interpret: Optional[bool] = None,
-                 positions_last: bool = False):
+                 positions_last: bool = False, write_at=None):
     """One decode step of attention against a KV cache, the step's own
     column written on the way.
 
@@ -1615,6 +1636,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
     Returns ``(out (B, h_q, hd) in q.dtype, cache_k, cache_v)``; donate
     the caches and the write is in place.  Callers gate on
     :func:`flash_decode_supported`.
+
+    ``write_at`` (B,) int32, absent by default: the cache is a ring.
+    ``k_new``/``v_new`` are stored at position ``write_at[b]`` and the
+    query attends the first ``lengths[b]`` positions of the cache, the
+    written one among them (``write_at < lengths``: a ring of W rows at
+    position p takes ``write_at = p mod W`` and ``lengths = min(p + 1,
+    W)``).  The grouped body alone (``h_q > h``).
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -1630,8 +1658,12 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
             f"{cache_k.shape} {cache_k.dtype}.  Gate callers on "
             f"flash_decode_supported()."
         )
+    if write_at is not None and group == 1:
+        raise ValueError("flash_decode: a ring (write_at) takes the grouped "
+                         "body alone: several query heads a cached head")
     return _decode_call(q, k_new, v_new, cache_k, cache_v, lengths,
-                        interpret=interpret, positions_last=positions_last)
+                        interpret=interpret, positions_last=positions_last,
+                        write_at=write_at)
 
 
 # A jit of its own: a model's layers call this at one signature, so the
@@ -1640,7 +1672,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
 # of the GPT-2 superstep's 1.8 s of lowering, which is set-up.
 @functools.partial(jax.jit, static_argnames=("interpret", "positions_last"))
 def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
-                 positions_last=False):
+                 positions_last=False, write_at=None):
     if not positions_last:
         cache_k = cache_k.transpose(0, 2, 3, 1)
         cache_v = cache_v.transpose(0, 2, 3, 1)
@@ -1649,7 +1681,7 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
     chunk = flash_decode_chunk(s, h, hd, cache_k.dtype, group)
     scale = 1.0 / math.sqrt(hd)
 
-    def slot(bi, lens):
+    def slot(bi, *_):
         return (bi, 0, 0, 0)
 
     def columns(*xs):
@@ -1676,7 +1708,7 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
         q_spec = resident
         # The output has the heads along the lanes, a whole tile of them.
         o_shape = (b, hd, _LANES)
-        o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, lens: (bi, 0, 0))
+        o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, *_: (bi, 0, 0))
         state = [(h, 1, _LANES), (h, 1, _LANES), (h, hd, _LANES),
                  (h, hd, _LANES)]
     else:
@@ -1691,8 +1723,12 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
         q_spec = o_spec = pl.BlockSpec((1, h, gp, hd), slot)
         state = [(h, gp, _LANES), (h, gp, _LANES), (h, gp, hd)]
     cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
+    scalars = (jnp.clip(lengths.astype(jnp.int32), 1, s),)
+    if write_at is not None:
+        kernel = functools.partial(_decode_ring_kernel, scale=scale)
+        scalars += (jnp.clip(write_at.astype(jnp.int32), 0, scalars[0] - 1),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=[q_spec, resident, in_hbm, in_hbm],
         out_specs=[o_spec, in_hbm, in_hbm],
@@ -1708,16 +1744,16 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(o_shape, q.dtype), cache, cache],
-        # Operands count the scalar prefetch: 3 and 4 are the caches.
-        input_output_aliases={3: 1, 4: 2},
+        # Operands count the scalar prefetch: the caches follow it, the
+        # queries and the columns.
+        input_output_aliases={len(scalars) + 2: 1, len(scalars) + 3: 2},
         # The grid's steps share the rings and follow one another.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_VMEM_LIMIT),
         name="ff_flash_decode",
         interpret=interpret,
-    )(jnp.clip(lengths.astype(jnp.int32), 1, s), q_in,
-      columns(k_new, v_new), cache_k, cache_v)
+    )(*scalars, q_in, columns(k_new, v_new), cache_k, cache_v)
     if group == 1:
         out = jnp.swapaxes(out[:, :, :h], 1, 2)
     else:
@@ -2565,6 +2601,128 @@ def flash_fwd_uneven(q, k, v, scale: float,
     )(q.reshape(b * h, t, qk), k.reshape(b * h_kv, t, qk),
       v.reshape(b * h_kv, t, dv))
     return out.reshape(b, h, t, dv)
+
+
+def flash_window_supported(q_shape: Tuple[int, ...], window: int) -> bool:
+    """Whether ``flash_fwd_window`` applies to (b, h, t, hd) queries:
+    ``flash_fwd_uneven``'s shapes (whole 128-row blocks)."""
+    return window >= 1 and flash_uneven_supported(q_shape, q_shape[-1])
+
+
+def flash_window_walk(t: int, window: int) -> Tuple[int, int]:
+    """``(block, reach)`` of the banded forward over ``t`` positions:
+    the rows of a query (and key) block, and how many key blocks a query
+    block's band ``q - window < s <= q`` can intersect (the grid's last
+    axis; a query block near the start skips those before position 0)."""
+    block = _UNEVEN_BLOCK
+    while t % block:
+        block //= 2
+    return block, -(-(window - 1) // block) + 1
+
+
+def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                       *, block, scale, window, reach):
+    """``_fwd_uneven_kernel``'s streamed forward under another block
+    walk: 3D grid (bh, q-block, step), step ``j`` of query block ``i``
+    at key block ``i - (reach - 1) + j``, the ``reach`` blocks the band
+    can touch and no other.  A block before the sequence is neither
+    fetched (the index map clamps to block 0) nor computed."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    kb = qi - (reach - 1) + j
+    q_start = qi * block
+    k_start = kb * block
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            precision=_mxu_precision(q.dtype),
+            preferred_element_type=jnp.float32,
+        ) * scale                                       # (bq, bk) f32
+        if masked:
+            q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block, block), 0)
+            k_pos = k_start + lax.broadcasted_iota(jnp.int32, (block, block), 1)
+            s = jnp.where((k_pos <= q_pos) & (k_pos > q_pos - window),
+                          s, _NEG_INF)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:
+            # A query row may find no key of its band in this block (its
+            # running max is then still the floor): the row adds nothing.
+            p = jnp.where(s > _NEG_INF, p, 0.0)
+        corr = jnp.exp(m - m_new)
+        acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=_mxu_precision(v.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+
+    # A block every key of which lies inside every query's band needs
+    # no mask (none at block == window: both blocks cross an edge).
+    inside = jnp.logical_and(k_start + block - 1 <= q_start,
+                             k_start > q_start + block - 1 - window)
+    pl.when(jnp.logical_and(kb >= 0, inside))(lambda: step(False))
+    pl.when(jnp.logical_and(kb >= 0, jnp.logical_not(inside)))(
+        lambda: step(True))
+
+    @pl.when(j == reach - 1)
+    def _emit():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def flash_fwd_window(q, k, v, scale: float, window: int,
+                     interpret: Optional[bool] = None):
+    """Banded causal attention: query ``t`` over keys ``t - window < s
+    <= t``, on (b, h, t, hd) queries and (b, h_kv, t, hd) keys and
+    values, ``h_kv`` dividing ``h`` (a group's K and V reached through
+    the index map, as ``flash_fwd_uneven`` does).  A query block visits
+    the key blocks its band intersects (``flash_window_walk``) and no
+    other: the work follows ``t * window``, not ``t^2 / 2``.  Forward
+    only.  Callers gate on :func:`flash_window_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, t, hd = q.shape
+    h_kv = k.shape[1]
+    group = h // h_kv
+    assert h == group * h_kv and v.shape == k.shape, (q.shape, k.shape, v.shape)
+    block, reach = flash_window_walk(t, window)
+    kernel = functools.partial(_fwd_window_kernel, block=block, scale=scale,
+                               window=int(window), reach=reach)
+
+    def kv_map(bi, i, j):
+        return (bi if group == 1 else bi // group,
+                jnp.maximum(i - (reach - 1) + j, 0), 0)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(b * h, t // block, reach),
+        in_specs=[
+            pl.BlockSpec((1, block, hd), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, block, hd), kv_map),
+            pl.BlockSpec((1, block, hd), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, block, hd), lambda bi, i, j: (bi, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, hd), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, hd), jnp.float32),
+        ],
+        name="ff_flash_fwd_window",
+        interpret=interpret,
+    )(q.reshape(b * h, t, hd), k.reshape(b * h_kv, t, hd),
+      v.reshape(b * h_kv, t, hd))
+    return out.reshape(b, h, t, hd)
 
 
 #: Positions the latent decode kernel fetches and scores at a time.

@@ -82,16 +82,25 @@ def kth_largest_key(keys, k: int):
     return lax.fori_loop(0, 31, set_bit, base)
 
 
-def rope_half(x, pos, theta: float, sections: Optional[Sequence[int]] = None):
+def rope_half(x, pos, theta: float, sections: Optional[Sequence[int]] = None,
+              rotary_dim: Optional[int] = None, inv=None, wave: float = 1.0):
     """Rotary embedding over half-split pairs ``(i, i + d/2)`` of the
     last dim, in f32: pair ``i`` turns by ``p_c(i) * theta^(-2i/d)``.
     ``x``: (..., t, d).  ``pos``: (..., t) token indices, or with
     ``sections`` (multimodal rotary positions: how many pairs each
     position component turns, in order) optionally (..., t,
     len(sections)); one index stands for every component, which is plain
-    rotary."""
+    rotary.  ``rotary_dim`` (r < d): the pairs ``(i, i + r/2)`` of the
+    leading ``r`` turn and the rest passes through.  ``inv`` (r/2,) the
+    pairs' frequencies where they are not ``theta``'s own, and ``wave``
+    the scale of cos and sin (``ops/attention.py::rope_frequencies``)."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = rope_half(x[..., :rotary_dim], pos, theta, sections,
+                           inv=inv, wave=wave)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)   # (d/2,)
     pos = jnp.asarray(pos).astype(jnp.float32)
     if sections is not None and pos.ndim == x.ndim:
         assert sum(sections) == d // 2, (sections, d)
@@ -101,6 +110,8 @@ def rope_half(x, pos, theta: float, sections: Optional[Sequence[int]] = None):
         pos = pos[..., None]
     ang = pos * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if wave != 1.0:
+        cos, sin = cos * wave, sin * wave
     xf = x.astype(jnp.float32)
     a, b = xf[..., :d // 2], xf[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],

@@ -683,10 +683,18 @@ class ServingExecutor:
         self.buckets: Tuple[int, ...] = tuple(bks)
         self.decode_kernel = decode_kernel
         self.device = device if device is not None else jax.devices()[0]
+        # The ops that keep a window's ring: padded, on one device.
+        ringed = [op.name for op in self.attn_ops
+                  if op.decode_window is not None]
         # -- paged KV layout --
         self.kv_block = int(kv_block or 0)
         self.paged = self.kv_block > 0
         if self.paged:
+            if ringed:
+                raise NotImplementedError(
+                    f"the paged KV layout (kv_block > 0): {ringed} keep a "
+                    f"window's ring, which has no blocks in the pool "
+                    f"(ROADMAP B-M4): serve them padded (kv_block=0)")
             unpaged = [op for op in self.attn_ops if not op.cache_paged]
             if unpaged:
                 raise ValueError(
@@ -748,6 +756,11 @@ class ServingExecutor:
                         f"shard batch degree n={n} must divide "
                         f"max_batch={self.max_batch}"
                     )
+                if ringed:
+                    raise NotImplementedError(
+                        f"sharded decode (shard=): {ringed} keep a "
+                        f"window's ring, which is built for one device "
+                        f"(ROADMAP B-M4)")
                 selecting = [
                     op.name for op in self.attn_ops
                     if getattr(op, "select", None) is not None
@@ -1066,7 +1079,8 @@ class ServingExecutor:
             raise ValueError(
                 f"{what} needs caches whose every entry has a sequence "
                 f"axis; {names} ({type(self.stateful_ops[0]).__name__}) "
-                f"keep a recurrent state (ROADMAP Queue B)")
+                f"keep a recurrent state or a window's ring (ROADMAP "
+                f"Queue B)")
 
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.buckets:
@@ -1295,34 +1309,53 @@ class ServingExecutor:
         gathers its ``topk`` rows whatever the live length, and scores
         every row of its selector's keys, the padded cache's (a plain
         product): ``idx_rows_fetched``, present when some op selects.
-        Host arithmetic, one layer's rows: the mean over the ops whose
-        cache has a sequence axis, each counted by what it reads, so a
-        graph that mixes kinds of layer sums to its layers' own; a
-        graph that also keeps recurrent state adds ``state_bytes``,
-        the bytes of state the superstep reads and writes over all its
-        layers."""
+        Host arithmetic, one layer's rows: the mean over the ops that
+        read a cache by position, each counted by what it reads (an op
+        under a ``window`` the ``min(live, window)`` rows of its ring,
+        rounded to the ring's own block), so a graph that mixes kinds of
+        layer sums to its layers' own; ``kv_rows_cache`` stays what
+        layers without a window or a selector would hold.  A graph that
+        also keeps recurrent state adds ``state_bytes``, the bytes of
+        state the superstep reads and writes over all its layers (a
+        window's ring is rows, counted above, not state)."""
         S = self.max_seq
         n, c = self.shard or (1, 1)
-        ops = [op for op in self.attn_ops if op not in self.stateful_ops]
-        picks = [getattr(op, "select", None) for op in ops]
-        block = S if self.paged else max(
-            (op.decode_fetch_block(self.max_batch // n, S,
-                                   self.decode_kernel, c)
-             for op, pick in zip(ops, picks) if pick is None),
-            default=S)
         live = np.minimum(np.asarray(pos)[:, None] + np.arange(k), S - 1) + 1
-        dense = int((-(-live // block) * block).sum())
-        fetched = [dense if pick is None else live.size * min(pick.topk, S)
-                   for pick in picks] or [dense]
+        fetched, picks, state = [], [], 0
+        rounded = {}    # (block, window) -> rows: one sum a kind of layer
+        for op in self.attn_ops:
+            pick = getattr(op, "select", None)
+            block = op.decode_fetch_block(self.max_batch // n, S,
+                                          self.decode_kernel, c)
+            if not block:
+                # A recurrent state: no rows.
+                state += sum(
+                    math.prod(ce.shape) * jnp.dtype(ce.dtype).itemsize
+                    for ce in self._cache_specs[op.name].values()
+                    if not ce.sequence)
+                continue
+            picks.append(pick)
+            if pick is not None:
+                fetched.append(live.size * min(pick.topk, S))
+                continue
+            window = op.decode_window
+            if window is None and self.paged:
+                block = S
+            if (block, window) not in rounded:
+                rows = live if window is None else np.minimum(live, window)
+                rounded[block, window] = int((-(-rows // block) * block).sum())
+            fetched.append(rounded[block, window])
+        if not fetched:
+            fetched = [int(live.size * S)]
         rows = {"kv_rows_fetched": int(round(sum(fetched) / len(fetched))),
                 "kv_rows_cache": int(live.size * S)}
         if any(pick is not None for pick in picks):
             scored = sum(live.size * S for pick in picks if pick is not None)
             rows["idx_rows_fetched"] = int(round(scored / len(picks)))
-        if self._bytes_fixed:
-            # Every slot's recurrent state and window, read and written
-            # once a step.
-            rows["state_bytes"] = 2 * k * self.max_batch * self._bytes_fixed
+        if state:
+            # Every slot's recurrent state and convolution window, read
+            # and written once a step.
+            rows["state_bytes"] = 2 * k * self.max_batch * state
         return rows
 
     @staticmethod

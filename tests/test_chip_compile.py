@@ -110,6 +110,26 @@ def _decode_grouped(b, s, h, h_kv, hd, dtype):
                       _sds((b,), jnp.int32))
 
 
+def _flash_window(t, h, h_kv, hd, window):
+    q, k = _sds((1, h, t, hd), BF16), _sds((1, h_kv, t, hd), BF16)
+    fn = functools.partial(pk.flash_fwd_window, scale=hd ** -0.5,
+                           window=window, interpret=False)
+    return (lambda: pk.flash_window_supported((1, h, t, hd), window)), fn, (
+        q, k, k)
+
+
+def _decode_ring(b, w, h, h_kv, hd, dtype):
+    """Grouped queries over a ring of ``w`` positions: the write index a
+    second scalar operand."""
+    fn = functools.partial(pk.flash_decode, interpret=False, positions_last=True)
+    new, cache = _sds((b, h_kv, hd), dtype), _sds((b, h_kv, hd, w), dtype)
+    gate = lambda: pk.flash_decode_supported((b, w, h_kv, hd), dtype, h // h_kv)
+    vec = _sds((b,), jnp.int32)
+    return gate, lambda q, k1, v1, ck, cv, n, at: fn(
+        q, k1, v1, ck, cv, n, write_at=at), (
+            _sds((b, h, hd), dtype), new, new, cache, cache, vec, vec)
+
+
 def _kda_chunk(t, n, d):
     x = _sds((t, n, d), F32)
     fn = functools.partial(pk.kda_chunk, interpret=False)
@@ -230,6 +250,38 @@ CASES = {
         lambda: _decode_grouped(32, 32768, 64, 8, 128, BF16),
     "decode_grouped-4x256x4x2x128-bf16":
         lambda: _decode_grouped(4, 256, 4, 2, 128, BF16),
+    # The laguna.serve.closed16.p8k-31k cell's kernels at its widths (72
+    # and 48 query heads over 8 key/value heads of 128: groups of 9 and
+    # 6; a window of 512; 64 held experts of 3072 x 1024): the banded
+    # prefill at the 8704 and 32768 buckets, the causal one of the full
+    # layers, 16 slots of 32768 positions and of a 512-position ring,
+    # 160 assignments a decode step (1,120 rows of 16-row tiles) and a
+    # prefill segment's 40,960 (4,096 tokens; 128-row tiles); and the
+    # smoke preset's.
+    "flash_window-gqa72x8-8704x128w512-bf16":
+        lambda: _flash_window(8704, 72, 8, 128, 512),
+    "flash_window-gqa72x8-32768x128w512-bf16":
+        lambda: _flash_window(32768, 72, 8, 128, 512),
+    "flash_window-gqa18x2-1280x128w512-bf16":
+        lambda: _flash_window(1280, 18, 2, 128, 512),
+    "flash_uneven-gqa48x8-32768x128-bf16":
+        lambda: _flash_uneven(32768, 48, 128, 128, h_kv=8),
+    "decode_grouped-16x32768x48x8x128-bf16":
+        lambda: _decode_grouped(16, 32768, 48, 8, 128, BF16),
+    "decode_ring-16x512x72x8x128-bf16":
+        lambda: _decode_ring(16, 512, 72, 8, 128, BF16),
+    "decode_ring-4x512x18x2x128-bf16":
+        lambda: _decode_ring(4, 512, 18, 2, 128, BF16),
+    "decode_ring-4x1024x12x2x128-bf16":
+        lambda: _decode_ring(4, 1024, 12, 2, 128, BF16),
+    "grouped_matmul-gated-decode-1120x3072x1024":
+        lambda: _grouped(1120, 64, 3072, 1024, 16, True),
+    "grouped_matmul-down-decode-1120x1024x3072":
+        lambda: _grouped(1120, 64, 1024, 3072, 16, False),
+    "grouped_matmul-gated-prefill-49152x3072x1024":
+        lambda: _grouped(49152, 64, 3072, 1024, 128, True),
+    "grouped_matmul-down-prefill-49152x1024x3072":
+        lambda: _grouped(49152, 64, 1024, 3072, 128, False),
     "kda_chunk-2048x64x128": lambda: _kda_chunk(2048, 64, 128),
     "kda_chunk-256x2x128": lambda: _kda_chunk(256, 2, 128),
     "kda_decode-32x64x128": lambda: _kda_decode(32, 64, 128),
@@ -275,7 +327,8 @@ def _compiled_text(name: str) -> str:
     # The table donated, as the train step donates its parameters; the
     # caches, as the decode superstep donates them.
     donate = {"scatter_add_rows": (0,), "decode": (3, 4),
-              "decode_grouped": (3, 4), "kda_chunk": (5,),
+              "decode_grouped": (3, 4), "decode_ring": (3, 4),
+              "kda_chunk": (5,),
               "kda_decode": (5,), "mla_decode": (2,)}.get(
                   name.split("-")[0], ())
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
@@ -671,6 +724,60 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     assert ins.count(kv) == outs.count(kv) == 2 * KEYE_VL2_SMOKE["num_hidden_layers"]
 
 
+def test_laguna_smoke_programs_compile_for_the_chip(monkeypatch):
+    """``chip_smoke.py``'s ``serve/laguna`` programs at the smoke preset's
+    widths, compiled for the described chip: the prefill holds the banded
+    forward kernel (window layers) beside the causal one (full layers)
+    and the grouped product, the decode superstep ``ff_flash_decode``
+    over rings of 512 and full caches of 2048 positions at groups of 9
+    and 6; every cache goes from parameter to result where it lies, the
+    rings 512 positions long, and no XLA update or scatter touches one."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import LAGUNA_SMOKE, build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    slots, seq, w = 4, 2048, LAGUNA_SMOKE["sliding_window"]
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(LAGUNA_SMOKE, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(1280, seq), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    params, state = jax.tree.map(placed, params), jax.tree.map(placed, state)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    assert [caches[f"blk{i}_attn"]["k"].shape[-1] for i in range(5)] == [
+        seq, w, w, w, seq]
+    assert [op.decode_fetch_block(slots, seq, True) for op in sex.attn_ops] \
+        == [512] * 5
+    vec = _sds((slots,), jnp.int32)
+    step = sex.build_decode_superstep(8).lower(
+        params, state, caches, vec, vec).compile().as_text()
+    for name in ("ff_flash_decode", "ff_grouped_matmul"):
+        assert chip_smoke.has_kernel(step, name), name
+    for bucket in (1280, seq):
+        first = sex.build_prefill(bucket).lower(
+            params, state, _sds((1, bucket), jnp.int32), _sds((), jnp.int32)
+        ).compile().as_text()
+        for name in ("ff_flash_fwd_window", "ff_flash_fwd_uneven",
+                     "ff_grouped_matmul"):
+            assert chip_smoke.has_kernel(first, name), (bucket, name)
+    assert chip_smoke.cache_shaped_relayouts(step, caches) == []
+    assert [l for l in step.splitlines()
+            if ("dynamic-update-slice(" in l or " scatter(" in l)
+            and re.search(rf"bf16\[{slots},2,128,({w}|{seq})\]", l)] == []
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
+    ins, outs = layout.split(")->(")
+    for positions, layers in ((w, 3), (seq, 2)):
+        kv = f"bf16[{slots},2,128,{positions}]{{3,2,1,0:T(8,128)(2,1)}}"
+        assert ins.count(kv) == outs.count(kv) == 2 * layers, (positions, layout)
+
+
 _CACHE = (48, 1024, 16, 64)
 #: A cache as the chip stores it (positions along the lanes), and as a
 #: row-major Mosaic operand wants it (hd 64 padded to a 128-lane tile).
@@ -787,6 +894,10 @@ _TINY = chip_smoke.Sizes(
     serve_keye=("--model-config", "keye-vl2-tiny", "--max-seq", "128",
                 "--max-batch", "2", "--requests", "3", "--max-new", "6",
                 "--prompt-len", "40:100", "--buckets", "128"),
+    # A window of 16 under prompts of 40-100: every ring wraps twice.
+    serve_laguna=("--model-config", "laguna-tiny", "--max-seq", "128",
+                  "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                  "--prompt-len", "40:100", "--buckets", "64,128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -818,7 +929,7 @@ def _phases(which):
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
               "serve", "serve/latent", "serve/solar", "serve/xing",
-              "serve/keye"])
+              "serve/keye", "serve/laguna"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

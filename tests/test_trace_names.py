@@ -74,6 +74,16 @@ _ENTRY_POINTS = {
         lambda q: pk.flash_fwd_uneven(jnp.ones((1, 2, 128, 24)), jnp.ones((1, 2, 128, 24)),
                                       jnp.ones((1, 2, 128, 16)), 0.2),
         _qkv, {"ff_flash_fwd_uneven"}),
+    "flash_fwd_window": (
+        lambda q: pk.flash_fwd_window(jnp.ones((1, 4, 256, 32)), jnp.ones((1, 2, 256, 32)),
+                                      jnp.ones((1, 2, 256, 32)), 0.2, 100),
+        _qkv, {"ff_flash_fwd_window"}),
+    "flash_decode_ring": (
+        lambda q: pk.flash_decode(jnp.ones((1, 6, 128)), jnp.ones((1, 2, 128)), jnp.ones((1, 2, 128)),
+                                  jnp.ones((1, 2, 128, 128)), jnp.ones((1, 2, 128, 128)),
+                                  jnp.array([128], jnp.int32), positions_last=True,
+                                  write_at=jnp.array([5], jnp.int32)),
+        _qkv, {"ff_flash_decode"}),
     "mla_decode": (
         lambda q: pk.mla_decode(jnp.ones((1, 2, 40)), jnp.ones((1, 40)),
                                 jnp.ones((1, 40, 128)),
