@@ -41,10 +41,26 @@ experts' part of the result (plus the shared experts, which every chip
 computes alike) and leaves the rest out.  A router without an auxiliary
 loss makes the op an ordinary one (``is_loss`` false), which is what
 lets the serving executor keep it.
+
+How the rows are sized.  An op that holds every expert gives each of a
+segment's ``A = tokens x top_k`` assignments a row (``A`` plus the tile
+padding of the experts), and so does a decode step, whose rows are
+tile padding and not assignments.  A prefill-sized segment of an op
+that holds a share has rows for ``held_rows_bound(A)`` assignments:
+those a uniform router would put on its experts, with
+``HELD_ROWS_MARGIN`` to spare.  The gather in front of the two
+products, the products' results, the gather behind them and the f32 sum
+of a token's choices (a sorted scatter-add over the held assignments in
+token order) are that much smaller.  How many fall here is data: the
+sorted held assignments are walked in windows of that many, one window
+unless the router sends more here, so any routing is computed exactly
+and nothing is dropped; the serving counter ``held_rows_overflow`` says
+how often a segment took more than one.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -118,6 +134,8 @@ class MixtureOfExperts(Op):
         self.is_loss = dispatch == "capacity"
         if dispatch == "sorted":
             self.serving_stats = ("experts_touched", "expert_load_max")
+            if len(held) < num_experts:
+                self.serving_stats += ("held_rows_overflow",)
         self.held = held
         assert x.ndim == 3, f"moe input must be (batch, seq, d), got {x.shape}"
         check_activation(activation)
@@ -333,6 +351,27 @@ class MixtureOfExperts(Op):
     #: see a token alone, so segments change nothing but the order).
     SEGMENT_BYTES = 1 << 29
 
+    #: Room a prefill-sized segment's rows leave over the assignments a
+    #: uniform router would put on the held experts (``A * held /
+    #: routed``); a segment that puts more there walks them in more
+    #: than one window of rows, and ``held_rows_overflow`` counts it.
+    HELD_ROWS_MARGIN = 1.5
+
+    def held_rows_bound(self, assignments: int) -> Optional[int]:
+        """How many of a segment's ``assignments`` its rows are sized
+        for where that is fewer than all: the held share with its
+        margin, in a prefill-sized segment (whole 128-row tiles an
+        expert; a decode step's rows are tile padding, not assignments:
+        the rule ``grouped_tile_rows`` draws).  None where every
+        assignment gets a row."""
+        eh = len(self.held)
+        bound = math.ceil(self.HELD_ROWS_MARGIN * assignments * eh
+                          / self.attrs["num_experts"])
+        if bound < assignments and \
+                pallas_kernels.grouped_tile_rows(assignments, eh) == 128:
+            return bound
+        return None
+
     def _forward_sorted(self, params, x, state):
         b, t, d = x.shape
         T = b * t
@@ -343,11 +382,12 @@ class MixtureOfExperts(Op):
             n += 1
         if n == 1:
             y, counts = self._sorted_tokens(params, x.reshape(T, d), serving)
+            by_segment = counts
         else:
-            y, counts = jax.lax.map(
+            y, by_segment = jax.lax.map(
                 lambda xs: self._sorted_tokens(params, xs, serving),
                 x.reshape(n, T // n, d))
-            counts = jnp.sum(counts, axis=0)
+            counts = jnp.sum(by_segment, axis=0)
         out = [y.reshape(b, t, d)]
         if not serving:
             return out, state
@@ -359,6 +399,13 @@ class MixtureOfExperts(Op):
             "expert_load_max": jnp.max(counts).astype(jnp.float32)
             * eh / jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32),
         }
+        bound = self.held_rows_bound(T // n * self.attrs["top_k"])
+        if bound is not None:
+            # The share of the segments that put more on the held
+            # experts than one window of the held-sized rows takes.
+            new_state["stats"]["held_rows_overflow"] = jnp.mean(
+                jnp.sum(by_segment.reshape(n, eh), axis=1) > bound,
+                dtype=jnp.float32)
         return out, new_state
 
     def _sorted_tokens(self, params, xf, serving: bool):
@@ -378,6 +425,27 @@ class MixtureOfExperts(Op):
         counts = jnp.sum(local[:, None] == jnp.arange(eh)[None, :], axis=0,
                          dtype=jnp.int32)                        # (eh,)
         tm = pallas_kernels.grouped_tile_rows(A, eh)
+        bound = self.held_rows_bound(A)
+        if bound is None:
+            y = self._routed_terms(params, xf, w, local, here, counts, tm,
+                                   serving)
+        else:
+            y = self._held_terms(params, xf, w, local, counts, tm, serving,
+                                 bound)
+        if a["shared_experts"]:
+            shared = ("s_gate", "s_up", "s_down") if a["gated"] else \
+                ("s_up", "s_down")
+            y = y + self._mlp(xf, params, shared,
+                              lambda x, w: x @ w).astype(jnp.float32)
+        return y.astype(xf.dtype), counts
+
+    def _routed_terms(self, params, xf, w, local, here, counts, tm, serving):
+        """The held experts' weighted outputs summed a token, ``(T, d)``
+        f32, with a row for every one of the ``A`` assignments."""
+        a = self.attrs
+        T, d = xf.shape
+        k, eh = a["top_k"], len(self.held)
+        A = T * k
         rows = -(-(A + min(eh, A) * (tm - 1)) // tm) * tm
         padded = -(-counts // tm) * tm
         p_end = jnp.cumsum(padded)
@@ -394,11 +462,82 @@ class MixtureOfExperts(Op):
         src_tok = jnp.zeros((rows,), jnp.int32).at[dest].set(tok, mode="drop")
         row_of = jnp.zeros((A,), jnp.int32).at[slot].set(
             jnp.minimum(dest, rows - 1))
-        xs = xf[src_tok]                                         # (rows, d)
+        ys = self._expert_rows(params, xf[src_tok], padded, p_end, tm, serving)
+        # Back to (token, choice) order; an assignment held elsewhere
+        # reads some row and is masked (not multiplied: the row may be
+        # one the kernel never wrote).
+        y_tk = ys[row_of].reshape(T, k, d).astype(jnp.float32)
+        return jnp.sum(
+            jnp.where(here.reshape(T, k, 1), y_tk * w[..., None], 0.0), axis=1)
 
-        f = a["ffn_dim"]
-        if serving and pallas_kernels.grouped_matmul_supported(d, f, xf.dtype) \
-                and pallas_kernels.grouped_matmul_supported(f, d, xf.dtype):
+    def _held_terms(self, params, xf, w, local, counts, tm, serving,
+                    bound: int):
+        """The same sum through rows for ``bound`` assignments at a
+        time: the assignments sorted by expert (the held ones first)
+        are walked in windows of ``bound``, as many as hold a held one
+        (one, unless the router sends more here than the bound), each
+        through its own rows, and a token's terms are added up by a
+        ``segment_sum``-like scatter in token order."""
+        a = self.attrs
+        T, d = xf.shape
+        k, eh = a["top_k"], len(self.held)
+        A = T * k
+        rows = -(-(bound + min(eh, bound) * (tm - 1)) // tm) * tm
+        key, slot = jax.lax.sort(
+            (local, jnp.arange(A, dtype=jnp.int32)), num_keys=1)
+        # Whole windows: the last one is filled up with assignments
+        # held elsewhere, like the ones that sort there anyway.
+        fill = -A % bound
+        key = jnp.pad(key, (0, fill), constant_values=eh)
+        slot = jnp.pad(slot, (0, fill), constant_values=A)
+        first = jnp.cumsum(counts) - counts
+        weight = w.reshape(A)
+
+        def window(i, y):
+            lo = i * bound
+            # What of each expert's run of the sorted order lies inside.
+            inside = jnp.clip(first + counts, lo, lo + bound) \
+                - jnp.clip(first, lo, lo + bound)
+            padded = -(-inside // tm) * tm
+            p_end = jnp.cumsum(padded)
+            start = jnp.cumsum(inside) - inside
+            ky = jax.lax.dynamic_slice(key, (lo,), (bound,))
+            sl = jax.lax.dynamic_slice(slot, (lo,), (bound,))
+            kc = jnp.minimum(ky, eh - 1)
+            dest = jnp.where(
+                ky < eh,
+                (p_end - padded)[kc] + jnp.arange(bound) - start[kc], rows)
+            src_tok = jnp.zeros((rows,), jnp.int32).at[dest].set(
+                sl // k, mode="drop")
+            ys = self._expert_rows(params, xf[src_tok], padded, p_end, tm,
+                                   serving)
+            # The window's held assignments back in (token, choice)
+            # order, each with its row; the others sort last and are
+            # masked (not multiplied, as above).
+            sl, row = jax.lax.sort(
+                (jnp.where(ky < eh, sl, A), jnp.minimum(dest, rows - 1)),
+                num_keys=1)
+            held = sl < A
+            sl = jnp.minimum(sl, A - 1)
+            terms = jnp.where(
+                held[:, None],
+                ys[row].astype(jnp.float32) * weight[sl][:, None], 0.0)
+            return y.at[sl // k].add(terms, indices_are_sorted=True)
+
+        return jax.lax.fori_loop(
+            0, -(-jnp.sum(counts) // bound), window,
+            jnp.zeros((T, d), jnp.float32))
+
+    def _expert_rows(self, params, xs, padded, p_end, tm, serving):
+        """``xs`` (rows, d), sorted by expert and padded to whole tiles
+        of ``tm`` rows an expert (``padded`` rows each, ending at
+        ``p_end``), through the routed experts' MLPs: ``ys`` (rows, d);
+        rows past ``p_end[-1]`` may hold anything."""
+        a = self.attrs
+        rows, d = xs.shape
+        eh, f = len(self.held), a["ffn_dim"]
+        if serving and pallas_kernels.grouped_matmul_supported(d, f, xs.dtype) \
+                and pallas_kernels.grouped_matmul_supported(f, d, xs.dtype):
             n_tiles = rows // tm
             used = p_end[-1] // tm
             tile_e = jnp.sum(
@@ -418,16 +557,4 @@ class MixtureOfExperts(Op):
                 return jax.lax.ragged_dot(x, w, padded)
 
         routed = ("w_gate", "w_up", "w_down") if a["gated"] else ("w1", "w2")
-        ys = self._mlp(xs, params, routed, product, fused_gate)
-        # Back to (token, choice) order; an assignment held elsewhere
-        # reads some row and is masked (not multiplied: the row may be
-        # one the kernel never wrote).
-        y_tk = ys[row_of].reshape(T, k, d).astype(jnp.float32)
-        y = jnp.sum(jnp.where(here.reshape(T, k, 1), y_tk * w[..., None], 0.0),
-                    axis=1)
-        if a["shared_experts"]:
-            shared = ("s_gate", "s_up", "s_down") if a["gated"] else \
-                ("s_up", "s_down")
-            y = y + self._mlp(xf, params, shared,
-                              lambda x, w: x @ w).astype(jnp.float32)
-        return y.astype(xf.dtype), counts
+        return self._mlp(xs, params, routed, product, fused_gate)

@@ -778,6 +778,37 @@ def test_laguna_smoke_programs_compile_for_the_chip(monkeypatch):
         assert ins.count(kv) == outs.count(kv) == 2 * layers, (positions, layout)
 
 
+def test_held_quarter_segment_sizes_its_rows_by_the_held_share(monkeypatch):
+    """One prefill-sized segment of an expert layer that holds four of
+    sixteen experts, compiled for the described chip (PR 45): no result
+    is as long as the segment's assignments or as the rows that would
+    take them all (the gathered rows, both products, the ``(T, k, d)``
+    combine), the rows are ``held_rows_bound``'s, and the loop's body
+    holds ``ff_grouped_matmul`` as a Mosaic call."""
+    from flexflow_tpu.ops.base import TensorSpec
+    from flexflow_tpu.ops.moe import MixtureOfExperts
+
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    T, d, f, k, e, eh = 1024, 256, 128, 8, 16, 4
+    op = MixtureOfExperts(
+        "moe", TensorSpec("x", (1, T, d), BF16, ("n", "s", None)), e, f,
+        top_k=k, dispatch="sorted", router="sigmoid", gated=True,
+        activation="silu", shared_experts=1, held_experts=list(range(eh)))
+    params = {n: _sds(s.shape, s.dtype) for n, s in op.param_specs().items()}
+    text = jax.jit(lambda p, x: op._sorted_tokens(p, x, True)).lower(
+        params, _sds((T, d), BF16)).compile().as_text()
+    A = T * k
+    rows, held_rows = (-(-(n + eh * 127) // 128) * 128
+                       for n in (A, op.held_rows_bound(A)))
+    assert (A, rows, held_rows) == (8192, 8704, 3584)
+    wide = lambda n: [l for l in text.splitlines() if re.search(
+        rf" = \(?\w+\[{n},({d}|{f})\]", l)]
+    assert wide(A) == wide(rows) == [] and wide(held_rows)
+    assert not re.search(rf" = \w+\[{T},{k},{d}\]", text)
+    assert len(re.findall(r"%ff_grouped_matmul[.\d]* = ", text)) == 2
+    assert chip_smoke.has_mosaic_call(text)
+
+
 _CACHE = (48, 1024, 16, 64)
 #: A cache as the chip stores it (positions along the lanes), and as a
 #: row-major Mosaic operand wants it (hd 64 padded to a 128-lane tile).
