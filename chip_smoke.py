@@ -663,8 +663,7 @@ def solar_phase(argv: Sequence[str]) -> None:
     decode = check_program_kernels(
         "serve/solar", run, caches,
         decode=("ff_flash_decode", "ff_kda_decode", "ff_grouped_matmul"),
-        prefill=("ff_flash_fwd_uneven", "ff_kda_intra", "ff_kda_chunk",
-                 "ff_grouped_matmul"))
+        prefill=("ff_flash_fwd_uneven", "ff_kda_chunk", "ff_grouped_matmul"))
     moved = cache_or_state_relayouts(decode, caches)
     check(not moved, f"serve/solar: the compiled decode superstep moves a "
                      f"whole cache or state: {moved[:3]}")

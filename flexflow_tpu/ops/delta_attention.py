@@ -16,8 +16,11 @@ of its three short convolutions.  For a token with normed input ``u``:
 Three formulations of the same recurrence: :func:`kda_recurrence` (a
 ``lax.scan`` a token: the oracle, the differentiable path, and the path
 where a gate refuses the shape), ``pallas_kernels.kda_chunk`` (a prefill
-in chunks of 64) and ``pallas_kernels.kda_decode`` (one token a slot,
-the state read and written in place).
+in chunks of 64: one kernel that reads q, k, v, g and beta where
+``_qkv`` and ``_decay`` leave them and makes the chunk's decays, Gram
+matrices and triangular solve beside the state in VMEM) and
+``pallas_kernels.kda_decode`` (one token a slot, the state read and
+written in place).
 
 Strategy axes: ``c`` tags the head dimension of every parameter and of
 the state, so a later sharded placement needs no new declaration; no

@@ -3027,118 +3027,156 @@ def kda_supported(d_k: int, d_v: int) -> bool:
     return d_k % _LANES == 0 and d_v % _LANES == 0
 
 
-def _kda_intra_kernel(q_ref, k_ref, g_ref, a_ref, b_ref):
-    """One chunk of one head: the two decayed Gram matrices, a block row
-    of ``_KDA_SUB`` rows at a time.  Against the earlier rows of the
-    chunk through the block's first row ``r`` (``exp(G_i - r) exp(r -
-    G_j)``, both factors <= 1: one product a matrix on the matrix unit);
-    inside the block one column at a time on the vector unit."""
-    q, k, G = q_ref[0, 0], k_ref[0, 0], g_ref[0, 0]              # (C, d) f32
-    c, s = k.shape[0], _KDA_SUB
-    lane = lax.broadcasted_iota(jnp.int32, (s, c), 1)
-    sub = lax.broadcasted_iota(jnp.int32, (s, c), 0)
-    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-
-    def gram(x, y):
-        return lax.dot_general(x, y, (((1,), (1,)), ((), ())),
-                               precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
-
-    for lo in range(0, c, s):
-        Gi, ki, qi = G[lo:lo + s], k[lo:lo + s], q[lo:lo + s]
-        ref = Gi[0:1]
-        left = jnp.exp(Gi - ref)
-        right = jnp.where(row < lo,
-                          k * jnp.exp(jnp.minimum(ref - G, 0.0)), 0.0)
-        a, b = gram(ki * left, right), gram(qi * left, right)     # (s, C)
-        for j in range(s):
-            kj = ki[j:j + 1] * jnp.exp(jnp.minimum(Gi - Gi[j:j + 1], 0.0))
-            at = lane == lo + j
-            a = jnp.where(at & (sub > j),
-                          jnp.sum(ki * kj, axis=1, keepdims=True), a)
-            b = jnp.where(at & (sub >= j),
-                          jnp.sum(qi * kj, axis=1, keepdims=True), b)
-        a_ref[0, 0, lo:lo + s, :] = a
-        b_ref[0, 0, lo:lo + s, :] = b
+#: Heads a grid step of the prefill scan walks, and how many of them
+#: are written side by side in the loop's body (PERF.md §6 PR 46).
+_KDA_HEADS = 8
+_KDA_TOGETHER = 2
 
 
-def _kda_intra(q, k, G, interpret: bool):
-    """The two decayed Gram matrices of every chunk: ``A[i, j] = sum_c
-    k_i k_j exp(G_i - G_j)`` for ``j < i`` and ``B[i, j] = sum_c q_i k_j
-    exp(G_i - G_j)`` for ``j <= i``, zero elsewhere; ``q``, ``k``, ``G``
-    (N, nc, C, d) f32, ``G`` the chunk's running sum of log decays
-    (non-increasing).  ``exp(G_i) exp(-G_j)`` overflows under a strong
-    decay, so every exponent the kernel takes is a difference that is
-    ``<= 0``.  (Sixteen XLA passes over the operands did the columns
-    before, 20 ms a 2048-token segment of 64 heads: PERF.md §6 PR 33.)"""
-    n, nc, c, d = k.shape
-    rows = pl.BlockSpec((1, 1, c, d), lambda i, j: (i, j, 0, 0))
-    gram = pl.BlockSpec((1, 1, c, c), lambda i, j: (i, j, 0, 0))
-    out = jax.ShapeDtypeStruct((n, nc, c, c), jnp.float32)
-    return pl.pallas_call(
-        _kda_intra_kernel, grid=(n, nc), in_specs=[rows] * 3,
-        out_specs=[gram, gram], out_shape=[out, out],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        name="ff_kda_intra", interpret=interpret)(q, k, G)
-
-
-def _unit_lower_solve(m, rhs):
-    """``(I + m)^-1 rhs`` for strictly lower-triangular ``m`` (..., C, C)
-    by forward substitution (never a Neumann product: its powers cancel
-    catastrophically where keys repeat and beta nears 2): the diagonal
-    sub-blocks are inverted a row at a time, every chunk at once, then
-    the block rows are solved in order."""
-    *lead, c, _ = m.shape
-    s, nb = _KDA_SUB, c // _KDA_SUB
-    mb = m.reshape(*lead, nb, s, nb, s)
-    low = jnp.stack([mb[..., i, :, i, :] for i in range(nb)], axis=-3)
-    eye = jnp.eye(s, dtype=m.dtype)
-    # Row i of (I + low)^-1 is e_i - sum_{j<i} low[i, j] row_j.
-    inv = []
+# A jit of its own inside the kernel: the sixteen columns are traced once
+# a call of the kernel, not once a block row of every head written side
+# by side (what a serving program's set-up pays is the tracing).
+@jax.jit
+def _kda_columns(Gi, ki, qi, bi, at, x, b):
+    """The diagonal block of one block row of ``_KDA_SUB`` rows, a column
+    at a time: ``Gi``, ``ki``, ``qi`` (s, d) the rows' running decays,
+    keys and queries, ``bi`` (s, 1) their betas, ``at`` (s, C) the lane's
+    column counted from the block's first, ``x`` (s, d_v + d_k) the
+    block's right-hand sides, corrected by the rows above the block, and
+    ``b`` (s, C) its row of ``B``, filled left of the diagonal block.
+    Returns the solved ``x`` and the filled ``b``."""
+    s = Gi.shape[0]
+    sub = lax.broadcasted_iota(jnp.int32, at.shape, 0)
+    below = lax.broadcasted_iota(jnp.int32, (s, 1), 0)
+    kb = bi * ki
     for i in range(s):
-        row = jnp.broadcast_to(eye[i], low.shape[:-2] + (s,))
-        for j in range(i):
-            row = row - low[..., i, j:j + 1] * inv[j]
-        inv.append(row)
-    inv = jnp.stack(inv, axis=-2)
-    rb = rhs.reshape(*lead, nb, s, rhs.shape[-1])
-    out = []
-    for i in range(nb):
-        r = rb[..., i, :, :]
-        for j in range(i):
-            r = r - jnp.einsum("...ij,...jk->...ik", mb[..., i, :, j, :],
-                               out[j], precision=_HIGHEST)
-        out.append(jnp.einsum("...ij,...jk->...ik", inv[..., i, :, :], r,
-                              precision=_HIGHEST))
-    return jnp.concatenate(out, axis=-2)
+        ki_dec = ki[i:i + 1] * jnp.exp(jnp.minimum(Gi - Gi[i:i + 1], 0.0))
+        b = jnp.where((at == i) & (sub >= i),
+                      jnp.sum(qi * ki_dec, axis=1, keepdims=True), b)
+        if i < s - 1:
+            ba = jnp.sum(kb * ki_dec, axis=1, keepdims=True)
+            x = x - jnp.where(below > i, ba, 0.0) * x[i:i + 1]
+    return x, b
 
 
-def _kda_chunk_kernel(qt_ref, w_ref, u0_ref, kh_ref, b_ref, egl_ref, s0_ref,
-                      o_ref, s_ref, st_scr, *, num_chunks):
-    """One chunk of one head: the state ``st`` (d_v, d_k) arrives from
-    the chunk before.  ``u = u0 - w st^T`` are the chunk's corrected
-    values, ``o = qt st^T + b u`` its outputs, ``st' = st * egl + u^T
-    kh`` the state it hands on."""
-    c = pl.program_id(1)
+def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                      o_ref, s_ref, st_scr, sol_scr, bm_scr,
+                      *, heads, num_chunks):
+    """One chunk of ``heads`` neighbouring heads, ``sol_scr.shape[0]`` of
+    them at a time; the states ``st`` (d_v, d_k) arrive from the chunk
+    before in ``st_scr``.  For a head, all in f32 in VMEM:
 
-    @pl.when(c == 0)
+    - ``G``, the chunk's running sum of log decays (non-increasing), by
+      shifted adds;
+    - the decayed Gram matrices ``A[i, j] = sum_c k_i k_j exp(G_i - G_j)``
+      (``j < i``) and ``B[i, j] = sum_c q_i k_j exp(G_i - G_j)`` (``j <=
+      i``), a block row of ``_KDA_SUB`` rows at a time: against the
+      earlier rows through the block's first row ``r`` (``exp(G_i - r)
+      exp(r - G_j)``, both factors <= 1: one product on the matrix unit),
+      inside the block a column at a time on the vector unit.  ``exp(G_i)
+      exp(-G_j)`` overflows under a strong decay, so every exponent taken
+      is a difference that is ``<= 0``;
+    - ``[u0 | w] = (I + beta A)^-1 beta [v | k exp(G)]`` by forward
+      substitution (never a Neumann product: its powers cancel
+      catastrophically where keys repeat and beta nears 2): a block row
+      is corrected by the solved rows above it in one product, then a
+      solved row leaves the rows below it as soon as its column of ``A``
+      exists;
+    - the walk: ``u = u0 - w st^T`` the chunk's corrected values, ``o =
+      q exp(G) st^T + B u`` its outputs, ``st' = st exp(G_last) + u^T (k
+      exp(G_last - G))`` the state it hands on."""
+    grp, step = pl.program_id(0), pl.program_id(1)
+    c, s = KDA_CHUNK, _KDA_SUB
+    dk, dv = q_ref.shape[1] // heads, v_ref.shape[1] // heads
+
+    @pl.when(step == 0)
     def _first():
-        st_scr[...] = s0_ref[0]
+        st_scr[...] = s0_ref[...]
 
     def mm(x, y, dims):
         return lax.dot_general(x, y, (dims, ((), ())), precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
 
-    st = st_scr[...]
-    u = u0_ref[0] - mm(w_ref[0], st, ((1,), (1,)))            # (C, d_v)
-    o_ref[0] = mm(qt_ref[0], st, ((1,), (1,))) + mm(b_ref[0], u, ((1,), (0,)))
-    st = st * egl_ref[0, 0] + mm(u, kh_ref[0], ((0,), (0,)))  # (d_v, d_k)
-    st_scr[...] = st
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
 
-    @pl.when(c == num_chunks - 1)
+    def running_sum(x):
+        """Inclusive sum down the rows, by shifted adds."""
+        shift = 1
+        while shift < c:
+            if shift % 8:
+                moved = jnp.where(row >= shift, pltpu.roll(x, shift, 0), 0.0)
+            else:
+                moved = jnp.concatenate(
+                    [jnp.zeros((shift, x.shape[1]), x.dtype), x[:c - shift]], axis=0)
+            x = x + moved
+            shift *= 2
+        return x
+
+    lane = lax.broadcasted_iota(jnp.int32, (s, c), 1)
+    head_of = lax.broadcasted_iota(jnp.int32, beta_ref.shape, 1)
+
+    together = sol_scr.shape[0]
+    each = range(together)
+
+    def some(p, carry):
+        # ``together`` heads side by side, statement by statement: their
+        # chains (product, sixteen columns, product, ...) do not depend
+        # on one another, and the schedule is filled from both.
+        js = [p * together + r for r in each]
+        at_k = [pl.ds(pl.multiple_of(j * dk, _LANES), dk) for j in js]
+        at_v = [pl.ds(pl.multiple_of(j * dv, _LANES), dv) for j in js]
+        q = [q_ref[:, at] for at in at_k]                          # (C, d) f32
+        k = [k_ref[:, at] for at in at_k]
+        beta = [jnp.sum(jnp.where(head_of == grp * heads + j, beta_ref[...], 0.0),
+                        axis=1, keepdims=True) for j in js]        # (C, 1)
+        G = [running_sum(g_ref[:, at]) for at in at_k]
+        eg = [jnp.exp(G[r]) for r in each]
+        rhs = [jnp.concatenate([beta[r] * v_ref[:, at_v[r]],
+                                beta[r] * (k[r] * eg[r])], axis=1) for r in each]
+        # The Gram matrices' block rows left of the diagonal wait for
+        # nothing of the solve.
+        ab = [{} for r in each]
+        for lo in range(s, c, s):
+            rows = slice(lo, lo + s)
+            for r in each:
+                ref = G[r][lo:lo + 1]
+                left = jnp.exp(G[r][rows] - ref)
+                right = k[r][:lo] * jnp.exp(ref - G[r][:lo])
+                ab[r][lo] = mm(
+                    jnp.concatenate([k[r][rows] * left, q[r][rows] * left], axis=0),
+                    right, ((1,), (1,)))                           # (2 s, lo)
+        for lo in range(0, c, s):
+            rows = slice(lo, lo + s)
+            x = [rhs[r][rows] for r in each]
+            b = [jnp.zeros((s, c), jnp.float32) for r in each]
+            if lo:
+                for r in each:
+                    x[r] = x[r] - mm(beta[r][rows] * ab[r][lo][:s],
+                                     sol_scr[r, :lo], ((1,), (0,)))
+            cols = [_kda_columns(G[r][rows], k[r][rows], q[r][rows],
+                                 beta[r][rows], lane - lo, x[r], b[r])
+                    for r in each]
+            x, b = [xb[0] for xb in cols], [xb[1] for xb in cols]
+            for r in each:
+                sol_scr[r, rows] = x[r]
+                bm_scr[r, rows] = b[r]
+                if lo:
+                    bm_scr[r, rows, :lo] = ab[r][lo][s:]
+        for r in each:
+            st, gl = st_scr[js[r]], G[r][c - 1:c]
+            both = mm(jnp.concatenate([sol_scr[r, :, dv:], q[r] * eg[r]], axis=0),
+                      st, ((1,), (1,)))                            # (2 C, d_v)
+            u = sol_scr[r, :, :dv] - both[:c]
+            o_ref[:, at_v[r]] = both[c:] + mm(bm_scr[r], u, ((1,), (0,)))
+            st_scr[js[r]] = st * jnp.exp(gl) + mm(
+                u, k[r] * jnp.exp(gl - G[r]), ((0,), (0,)))        # (d_v, d_k)
+        return carry
+
+    lax.fori_loop(0, heads // together, some, 0)
+
+    @pl.when(step == num_chunks - 1)
     def _last():
-        s_ref[0] = st
+        s_ref[...] = st_scr[...]
 
 
 def kda_chunk(q, k, v, g, beta, state, interpret: Optional[bool] = None):
@@ -3155,58 +3193,57 @@ def kda_chunk(q, k, v, g, beta, state, interpret: Optional[bool] = None):
     prompt inside a padded bucket).  Returns ``(o (T, N, d_v) f32,
     state)``.
 
-    Inside a chunk nothing but the last step needs the incoming state:
-    the decayed Gram matrices are the kernel ``ff_kda_intra``'s, every
-    chunk a grid step of its own; the unit-triangular system ``(I + beta
-    A) [u0 | w] = beta [v | k exp(G)]`` is XLA's, batched over all chunks
-    in f32 (``_unit_lower_solve``); the kernel ``ff_kda_chunk`` walks the
-    chunks of a head in order with the state in VMEM.  ``T`` a multiple of ``KDA_CHUNK``; callers gate on
-    :func:`kda_supported`."""
+    One kernel, ``ff_kda_chunk``, reads the operands where they lie
+    (viewed ``(T, N d)``: a chunk of ``_KDA_HEADS`` neighbouring heads
+    is a block) and walks the chunks of those heads in order with their
+    states in VMEM; what a chunk needs besides the incoming state (the
+    running decay, the decayed Gram matrices, the unit-triangular
+    solve) it makes there too (``_kda_chunk_kernel``).  ``T`` a multiple
+    of ``KDA_CHUNK``; callers gate on :func:`kda_supported`."""
     if interpret is None:
         interpret = _interpret_default()
+    assert q.shape[0] % KDA_CHUNK == 0 and \
+        kda_supported(q.shape[-1], v.shape[-1]), (q.shape, v.shape)
+    return _kda_chunk_call(q, k, v, g, beta, state, interpret=interpret)
+
+
+# A jit of its own, as ``_decode_call``: traced and lowered once a
+# program, not once a delta layer.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kda_chunk_call(q, k, v, g, beta, state, interpret):
     t, n, dk = q.shape
     dv = v.shape[-1]
     c = KDA_CHUNK
     nc = t // c
-    assert t == nc * c and kda_supported(dk, dv), (q.shape, v.shape)
     f32 = jnp.float32
-
-    def chunks(x):
-        return jnp.moveaxis(x.astype(f32).reshape(nc, c, n, x.shape[-1]), 2, 0)
-
-    q, k, v, g, b = map(chunks, (q, k, v, g, beta[..., None]))
-    G = jnp.cumsum(g, axis=2)
-    gl = G[:, :, -1:]
-    eg = jnp.exp(G)
-    kt = k * eg
-    a, bm = _kda_intra(q, k, G, interpret)
-    sol = _unit_lower_solve(b * a, jnp.concatenate([b * v, b * kt], axis=-1))
-
-    def flat(x):
-        return x.reshape(n, t, x.shape[-1])
+    heads = next(h for h in range(min(_KDA_HEADS, n), 0, -1) if n % h == 0)
+    together = _KDA_TOGETHER if heads % _KDA_TOGETHER == 0 else 1
 
     def rows(width):
-        return pl.BlockSpec((1, c, width), lambda i, j: (i, j, 0))
+        return pl.BlockSpec((c, heads * width), lambda i, j: (j, i))
 
-    whole = pl.BlockSpec((1, dv, dk), lambda i, j: (i, 0, 0))
+    def flat(x):
+        return x.astype(f32).reshape(t, n * x.shape[-1])
+
+    states = pl.BlockSpec((heads, dv, dk), lambda i, j: (i, 0, 0))
     o, state = pl.pallas_call(
-        functools.partial(_kda_chunk_kernel, num_chunks=nc),
-        grid=(n, nc),
-        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(c),
-                  pl.BlockSpec((1, 1, 1, dk), lambda i, j: (i, j, 0, 0)),
-                  whole],
-        out_specs=[rows(dv), whole],
-        out_shape=[jax.ShapeDtypeStruct((n, t, dv), f32),
+        functools.partial(_kda_chunk_kernel, heads=heads, num_chunks=nc),
+        grid=(n // heads, nc),
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk),
+                  pl.BlockSpec((c, n), lambda i, j: (j, 0)), states],
+        out_specs=[rows(dv), states],
+        out_shape=[jax.ShapeDtypeStruct((t, n * dv), f32),
                    jax.ShapeDtypeStruct((n, dv, dk), f32)],
-        scratch_shapes=[pltpu.VMEM((dv, dk), f32)],
-        input_output_aliases={6: 1},
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), f32),
+                        pltpu.VMEM((together, c, dv + dk), f32),
+                        pltpu.VMEM((together, c, c), f32)],
+        input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         name="ff_kda_chunk",
         interpret=interpret,
-    )(flat(q * eg), flat(sol[..., dv:]), flat(sol[..., :dv]),
-      flat(k * jnp.exp(gl - G)), flat(bm), jnp.exp(gl), state.astype(f32))
-    return jnp.swapaxes(o, 0, 1), state
+    )(flat(q), flat(k), flat(v), flat(g), beta.astype(f32), state.astype(f32))
+    return o.reshape(t, n, dv), state
 
 
 _KDA_DECODE_HEADS = 16

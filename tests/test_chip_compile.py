@@ -283,6 +283,7 @@ CASES = {
     "grouped_matmul-down-prefill-49152x1024x3072":
         lambda: _grouped(49152, 64, 1024, 3072, 128, False),
     "kda_chunk-2048x64x128": lambda: _kda_chunk(2048, 64, 128),
+    "kda_chunk-1536x64x128": lambda: _kda_chunk(1536, 64, 128),
     "kda_chunk-256x2x128": lambda: _kda_chunk(256, 2, 128),
     "kda_decode-32x64x128": lambda: _kda_decode(32, 64, 128),
     "kda_decode-4x2x128": lambda: _kda_decode(4, 2, 128),
@@ -615,6 +616,54 @@ def test_solar_decode_superstep_holds_no_cache_or_state_sized_relayout(
     assert ins.count(st) == outs.count(st) == 1
     assert "concatenate" not in "".join(
         l for l in text.splitlines() if "bf16[4096,24576]" in l)
+
+
+def test_solar_smoke_prefill_prepares_the_scan_inside_its_kernel(monkeypatch):
+    """``chip_smoke.py``'s ``serve/solar`` prefill (the smoke preset: a
+    bucket of 256 tokens, three delta layers of 2 heads of 128)
+    compiled for the described chip.  Under every ``blk<i>_kda`` scope
+    the scan is one ``ff_kda_chunk`` call and nothing of XLA's round it
+    prepares its operands: no ``copy`` or ``transpose`` of a float32
+    array of (tokens, heads, head_dim) elements (the parent moved q, k,
+    v, g in and o out, five a layer), and no product with a dimension
+    of ``_KDA_SUB`` = 16 rows (its batched triangular solve: thirty
+    ``convolution``s)."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import SOLAR_OPEN2_SMOKE, build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    slots, seq = 4, 256
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(SOLAR_OPEN2_SMOKE, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    text = sex.build_prefill(seq).lower(
+        jax.tree.map(placed, params), jax.tree.map(placed, state),
+        _sds((1, seq), jnp.int32), _sds((), jnp.int32)).compile().as_text()
+    lin = SOLAR_OPEN2_SMOKE["linear_attn_config"]
+    delta = [f"blk{i}_kda" for i in range(SOLAR_OPEN2_SMOKE["num_hidden_layers"])
+             if i not in SOLAR_OPEN2_SMOKE["gqa_layers"]]
+    assert len(delta) == 3
+    scoped = [l for l in text.splitlines()
+              if re.search(r'op_name="[^"]*/blk\d+_kda/', l)]
+    for name in delta:
+        calls = [l for l in scoped if f"/{name}/" in l
+                 and "custom_call_target=\"tpu_custom_call\"" in l]
+        assert len(calls) == 1 and "%ff_kda_chunk" in calls[0], (name, calls)
+    operand = seq * lin["num_heads"] * lin["head_dim"]
+    moved = [l for l in scoped if re.search(r" (copy|transpose)\(", l)
+             and any(math.prod(map(int, d.split(","))) == operand
+                     for d in re.findall(r"f32\[([\d,]+)\]", l.split("(")[0]))]
+    assert moved == []
+    narrow = [l for l in scoped if re.search(r" (dot|convolution)\(", l)
+              and re.search(rf"\[(\d+,)*{pk._KDA_SUB}(,\d+)*\]", l.split("(")[0])]
+    assert narrow == []
 
 
 def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):

@@ -227,12 +227,33 @@ def _delta_inputs(t, n, d, seed, hard):
     return q, k, v, g, beta, st
 
 
-@pytest.mark.parametrize("hard", [False, True])
-def test_chunked_scan_equals_the_recurrence(hard):
-    args = _delta_inputs(192, 3, 128, 0, hard)
-    o0, s0 = kda_recurrence(*args)
-    o1, s1 = jax.jit(pk.kda_chunk)(*args)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0), atol=5e-6)
+#: (T, N, hard, length): chunks 1, 3 and 6; heads 1, 3 (one step), one
+#: more than ``_KDA_HEADS`` (steps of 3 heads) and 4 (an even step: its
+#: heads go through the body two side by side); a pad tail from a row
+#: inside a chunk and from a chunk's first row.
+_SCAN_CASES = [
+    (64, 1, False, None), (64, 1, True, None),
+    (192, 3, False, None), (192, 3, True, None),
+    (384, pk._KDA_HEADS + 1, False, None), (384, pk._KDA_HEADS + 1, True, None),
+    (128, 4, True, None), (128, 4, False, 70),
+    (192, 3, True, 100), (192, pk._KDA_HEADS + 1, False, 128),
+    (384, 1, True, 200), (64, 3, False, 1),
+]
+
+
+@pytest.mark.parametrize("t,n,hard,length", _SCAN_CASES)
+def test_chunked_scan_equals_the_recurrence(t, n, hard, length):
+    """``kda_chunk`` from a non-zero state against the recurrence in
+    float32.  With a ``length`` the rows from it on are pad (``g = 0,
+    beta = 0``): the state is the one the real rows leave."""
+    q, k, v, g, beta, st = _delta_inputs(t, n, 128, 0, hard)
+    if length is not None:
+        g[length:], beta[length:] = 0.0, 0.0
+    live = slice(0, length)
+    o0, s0 = kda_recurrence(q[live], k[live], v[live], g[live], beta[live], st)
+    o1, s1 = jax.jit(pk.kda_chunk)(q, k, v, g, beta, st)
+    assert o1.shape == (t, n, 128) and s1.shape == (n, 128, 128)
+    np.testing.assert_allclose(np.asarray(o1[live]), np.asarray(o0), atol=5e-6)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), atol=1e-4)
 
 

@@ -102,7 +102,7 @@ _ENTRY_POINTS = {
     "kda_chunk": (
         lambda q: pk.kda_chunk(*(jnp.ones((64, 1, 128)),) * 3, -jnp.ones((64, 1, 128)),
                                jnp.ones((64, 1)), jnp.zeros((1, 128, 128))),
-        _qkv, {"ff_kda_intra", "ff_kda_chunk"}),
+        _qkv, {"ff_kda_chunk"}),
     "kda_decode": (
         lambda q: pk.kda_decode(*(jnp.ones((1, 2, 128)),) * 3, -jnp.ones((1, 2, 128)),
                                 jnp.ones((1, 2)), jnp.zeros((1, 2, 128, 128))),
@@ -122,7 +122,10 @@ def test_every_pallas_call_is_named_from_the_catalog(entry):
 
 
 def test_the_entry_points_reach_every_name_of_the_catalog():
-    assert set().union(*(w for _, _, w in _ENTRY_POINTS.values())) == KERNEL_CATALOG
+    # ``ff_kda_intra``: no kernel since PR 46, kept in the catalog for the
+    # benchmark's metric file that still lists its pattern (obs/events.py).
+    assert set().union(*(w for _, _, w in _ENTRY_POINTS.values())) == \
+        KERNEL_CATALOG - {"ff_kda_intra"}
 
 
 # -- scopes ----------------------------------------------------------------
