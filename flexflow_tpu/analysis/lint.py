@@ -21,12 +21,9 @@ Rule catalog (ANALYSIS.md has the full rationale table):
 - FF003 host time / host RNG (``time.*``, ``np.random``, stdlib
   ``random``) inside a jit-traced function — traced once, frozen
   forever; breaks replay determinism.
-- FF004 bare stdout writes in ``bench.py`` — the driver parses
-  exactly ONE JSON line from stdout (``print(json.dumps(...))`` is
-  the sanctioned form; everything else goes to stderr).
-- FF005 ``pallas_call`` outside ``ops/pallas_kernels.py`` and its
-  sanctioned probe consumers — kernels without AD rules must stay
-  behind the audited reachability choke points.
+- FF005 ``pallas_call`` outside ``ops/pallas_kernels.py`` — kernels
+  without AD rules must stay behind the audited reachability choke
+  points.
 - FF006 ``build_superstep``/``build_decode_superstep`` in a module
   that never references the fused-step bound
   (``clamp_fused_steps``/``MAX_STEPS_PER_CALL``) — the bound has one
@@ -41,10 +38,12 @@ Rule catalog (ANALYSIS.md has the full rationale table):
   (``KERNEL_CATALOG``) and an ``ff_*`` ``named_scope`` literal
   (``SCOPE_CATALOG``).
 
-FF002 (named ``jax.devices("tpu")`` lookup) and FF007 (``timeout=`` in
-``tools/``) are retired with their code: both guarded a forwarding
-service that is gone, and on a sealed machine with a budget a time
-limit is right, not a hazard.  The ids are not reused.
+FF002 (named ``jax.devices("tpu")`` lookup), FF004 (bare stdout writes
+in a one-JSON-line benchmark script) and FF007 (``timeout=`` in
+``tools/``) are retired with their code: the first and the last guarded
+a forwarding service that is gone (on a sealed machine with a budget a
+time limit is right, not a hazard), the second a script that went with
+PR 47.  The ids are not reused.
 """
 
 from __future__ import annotations
@@ -68,13 +67,9 @@ _CAP_NAMES = frozenset({
     "clamp_fused_steps", "MAX_STEPS_PER_CALL", "MAX_DECODE_STEPS_PER_CALL",
 })
 
-#: Sanctioned homes of raw ``pallas_call`` (FF005): the kernel library
-#: and its two probe-tool consumers (kernel-variant A/B probes that by
-#: design bypass the library to compare raw pallas_call variants).
+#: Sanctioned homes of raw ``pallas_call`` (FF005): the kernel library.
 PALLAS_ALLOWLIST = (
     "flexflow_tpu/ops/pallas_kernels.py",
-    "tools/probe_flash_variants.py",
-    "tools/probe_flash_bwd_variants.py",
 )
 
 
@@ -203,37 +198,6 @@ def _check_host_impurity_in_jit(tree: ast.AST, path: str):
     return out
 
 
-# -- FF004 ------------------------------------------------------------------
-
-def _check_bench_stdout(tree: ast.AST, path: str):
-    out = []
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        name = _dotted(node.func)
-        if name == "print":
-            file_kw = next(
-                (k for k in node.keywords if k.arg == "file"), None
-            )
-            if file_kw is not None and \
-                    _dotted(file_kw.value) != "sys.stdout":
-                continue  # routed (bench always routes to stderr)
-            # The sanctioned form: print(json.dumps(...)) — THE one
-            # JSON line (including the structured-error epilogue).
-            if len(node.args) == 1 and isinstance(node.args[0], ast.Call) \
-                    and _dotted(node.args[0].func) == "json.dumps":
-                continue
-            out.append((node.lineno,
-                        "bare print to stdout in bench.py: the driver "
-                        "parses exactly ONE JSON line from stdout "
-                        "(print(json.dumps(...)) or file=sys.stderr)"))
-        elif name == "sys.stdout.write":
-            out.append((node.lineno,
-                        "sys.stdout.write in bench.py breaks the "
-                        "one-JSON-line stdout contract"))
-    return out
-
-
 # -- FF005 ------------------------------------------------------------------
 
 def _check_pallas_confinement(tree: ast.AST, path: str):
@@ -303,47 +267,39 @@ def _check_unclamped_superstep_k(tree: ast.AST, path: str):
 
 # -- FF008 ------------------------------------------------------------------
 
-#: The registered telemetry event names (kept in sync with
-#: ``flexflow_tpu/obs/events.py::EVENT_CATALOG`` by ``tests/test_obs.py``
-#: — lint must stay import-free, same precedent as FUSED_STEPS_CAP).
-FF008_EVENT_NAMES = frozenset({
-    "run_start", "run_end",
-    "step", "input_wait", "superstep", "fence", "compiled_step",
-    "program_cost", "embedding_gather", "embedding_combine",
-    "embedding_rows",
-    "ckpt_save", "ckpt_restore", "ckpt_torn",
-    "fault", "rollback", "replay", "preempt",
-    "stall", "stall_recovered",
-    "analysis", "search",
-    "serve_run",
-    "request_start", "kv_wait", "prefill", "prefix_hit", "kv_cow",
-    "decode_superstep", "spec_verify",
-    "request_end", "serving_program",
-    "sched_decision", "request_preempt", "request_shed",
-    "request_retry", "request_expire", "serving_drain",
-    "engine_restart", "degraded_mode",
-    "replica_route", "replica_loss", "fleet_state",
-    "distributed_init", "elastic_resize",
-})
+def _read_catalogs(*names: str) -> List[frozenset]:
+    """The name catalogs of ``flexflow_tpu/obs/events.py``, read out of
+    its text: lint imports nothing of the package, and each catalog
+    there is ``NAME = frozenset({...literals...})``.  One that is
+    missing, or no longer a literal, fails here, at import."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "obs", "events.py")
+    with open(path) as fh:
+        tree = ast.parse(fh.read(), path)
+    found: Dict[str, frozenset] = {}
+    for node in tree.body:
+        if not (isinstance(node, ast.Assign) and len(node.targets) == 1):
+            continue
+        name, call = _dotted(node.targets[0]), node.value
+        if name not in names:
+            continue
+        if not (isinstance(call, ast.Call) and len(call.args) == 1
+                and _dotted(call.func) == "frozenset"):
+            raise ValueError(
+                f"{path}:{node.lineno}: {name} is not frozenset({{...}}) "
+                f"of literals; FF008 reads it as text")
+        found[name] = frozenset(ast.literal_eval(call.args[0]))
+    missing = [n for n in names if n not in found]
+    if missing:
+        raise ValueError(f"{path}: no catalog named {missing}")
+    return [found[n] for n in names]
 
-#: The names a profiler trace is read by, under the same pin
-#: (``SPAN_CATALOG``, ``KERNEL_CATALOG``, ``SCOPE_CATALOG``).
-FF008_SPAN_NAMES = frozenset({
-    "ff/serve/admit", "ff/serve/prefill_dispatch", "ff/serve/prefill_fence",
-    "ff/serve/install", "ff/serve/decode_pack", "ff/serve/decode_dispatch",
-    "ff/serve/decode_fence", "ff/serve/bookkeep",
-})
-FF008_KERNEL_NAMES = frozenset({
-    "ff_flash_fwd", "ff_flash_fwd_stream", "ff_flash_dq",
-    "ff_flash_dq_stream", "ff_flash_dkv", "ff_flash_dkv_stream",
-    "ff_flash_decode", "ff_flash_fwd_uneven", "ff_flash_fwd_window",
-    "ff_mla_decode",
-    "ff_grouped_matmul", "ff_kda_intra", "ff_kda_chunk",
-    "ff_kda_decode",
-    "ff_softmax_xent_fwd", "ff_softmax_xent_bwd",
-    "ff_gather_rows", "ff_scatter_add_rows",
-})
-FF008_SCOPE_NAMES = frozenset({"ff_loss", "ff_opt", "ff_index", "ff_select"})
+
+#: The registered telemetry event names, and the names a profiler trace
+#: is read by: ``obs/events.py``'s own sets.
+(FF008_EVENT_NAMES, FF008_SPAN_NAMES, FF008_KERNEL_NAMES,
+ FF008_SCOPE_NAMES) = _read_catalogs(
+    "EVENT_CATALOG", "SPAN_CATALOG", "KERNEL_CATALOG", "SCOPE_CATALOG")
 
 #: Receiver names that mark an ``.emit(...)`` call as a telemetry
 #: emission (vs some unrelated emit API).
@@ -439,13 +395,6 @@ RULES: List[Rule] = [
         "break deterministic replay (RESILIENCE.md)",
         lambda p: p.endswith(".py") and not _is_test(p),
         _check_host_impurity_in_jit,
-    ),
-    Rule(
-        "FF004", "bare stdout write in bench.py",
-        "bench.py prints exactly ONE JSON line on stdout (CLAUDE.md "
-        "design invariant); everything else goes to stderr",
-        lambda p: os.path.basename(p) == "bench.py",
-        _check_bench_stdout,
     ),
     Rule(
         "FF005", "pallas_call outside the kernel library",

@@ -96,8 +96,8 @@ def enable_compile_cache() -> str:
     """Point jax's persistent compilation cache at a stable place and
     return it: where ``JAX_COMPILATION_CACHE_DIR`` says when it is set
     (jax reads the variable itself — nothing is set in code), else
-    ``<checkout>/.ffcache``.  Called at ``main`` time by every app,
-    ``bench.py`` and ``chip_smoke.py`` — never at package import, so
+    ``<checkout>/.ffcache``.  Called at ``main`` time by every app
+    and ``chip_smoke.py`` — never at package import, so
     the test suite (tests/conftest.py) stays off the cache."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:

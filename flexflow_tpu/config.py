@@ -239,7 +239,7 @@ class FFConfig:
     # host-program counts, checkpoint I/O, faults/rollbacks/replays),
     # step-time percentiles folded into the fit stats under
     # "telemetry", a heartbeat file (DIR/heartbeat, or
-    # FF_HEARTBEAT_FILE, shared with tools/tpu_watcher.sh) and the
+    # FF_HEARTBEAT_FILE, for an external supervisor) and the
     # stall watchdog.  None = off: zero overhead, no extra fences,
     # stats/numerics bit-identical.  FF_TELEMETRY_DIR in the
     # environment enables it without touching flags.

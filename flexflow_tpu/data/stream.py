@@ -230,8 +230,8 @@ class ThrottledSource(StreamSource):
     """Wrap a source with per-read latency -- a disk-bound stand-in.
 
     ``delay_s`` is a fixed cost per read; ``per_row_s`` scales with the
-    range.  Used by the starvation tests and tools/measure_data.py to
-    make input-bound runs reproducible on the CPU box.
+    range.  Used by the starvation tests to make input-bound runs
+    reproducible on the CPU box.
     """
 
     def __init__(self, source: StreamSource, delay_s: float = 0.0,

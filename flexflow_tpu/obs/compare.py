@@ -11,13 +11,11 @@ locals:
   property: ``python -m flexflow_tpu.obs compare A B`` reads it as
   ``drift:step_ms_p50`` in one command, and the fingerprint diff says
   whether the box itself changed.
-- :func:`paired_measure` — the measure_telemetry.py paired-median +
-  A/A-control protocol (each rep runs both variants back to back with
-  order alternating between reps; the statistic is the median of
-  per-pair relative deltas, read against an A/A control run under the
-  same pairing), now the ONE implementation both
-  ``tools/measure_telemetry.py`` (delta-% form) and
-  ``tools/measure_data.py`` (ratio form) cite.
+- :func:`paired_measure` — the paired-median + A/A-control protocol
+  (each rep runs both variants back to back with order alternating
+  between reps; the statistic is the median of per-pair relative
+  deltas, in delta-% or ratio form, read against an A/A control run
+  under the same pairing).
 """
 
 from __future__ import annotations
@@ -32,8 +30,8 @@ from flexflow_tpu.obs.registry import fingerprint_diff
 #: Relative-drift thresholds per metric (|b-a|/|a| past which the
 #: verdict flips), in verdict priority order.  Counter metrics
 #: (fences/step, programs/step) are ACCOUNTING — any change is drift;
-#: wall-time metrics carry the box's run-to-run noise (the A/A control
-#: in measure_telemetry reads 1-15% on this box), so their thresholds
+#: wall-time metrics carry the box's run-to-run noise (an A/A control
+#: on the CPU box read 1-15%), so their thresholds
 #: sit well above noise and well below round-6's ~1.5x.
 DEFAULT_THRESHOLDS: Dict[str, float] = {
     "fences_per_step": 0.01,

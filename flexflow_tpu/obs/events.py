@@ -98,14 +98,10 @@ SPAN_CATALOG = frozenset({
 })
 
 #: ``name=`` of every ``pl.pallas_call`` (``ops/pallas_kernels.py``).
-#: The streamed variants (``FF_FLASH_*``) share their family's prefix.
 KERNEL_CATALOG = frozenset({
     "ff_flash_fwd",
-    "ff_flash_fwd_stream",
     "ff_flash_dq",
-    "ff_flash_dq_stream",
     "ff_flash_dkv",
-    "ff_flash_dkv_stream",
     "ff_flash_decode",
     "ff_flash_fwd_uneven",
     "ff_flash_fwd_window",

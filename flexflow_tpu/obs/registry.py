@@ -6,9 +6,8 @@ to the machine state that produced it.  Two fixes live here:
 
 - :func:`box_fingerprint` — git sha, jax/jaxlib versions, backend
   platform, device count, host — stamped onto every ``run_start``
-  (``Telemetry.__init__``) and into bench.py's JSON under
-  ``extra.fingerprint``, so any two numbers can be checked for
-  same-box before being compared.
+  (``Telemetry.__init__``), so any two runs can be checked for
+  same-box before their numbers are compared (``obs compare``).
 - An **append-only index** (``runs.jsonl`` next to the run logs, one
   line per completed run: id, path, exit, fingerprint, headline
   summary numbers) appended by ``Telemetry.close`` — ``python -m
@@ -81,8 +80,8 @@ def box_fingerprint() -> Dict[str, Any]:
         except Exception:
             pass
         # Backend identity: platform + device count.  This initializes
-        # the backend if nothing has yet — callers (Telemetry, bench)
-        # run on a backend they already hold, so this never adds a
+        # the backend if nothing has yet — its caller (Telemetry)
+        # runs on a backend it already holds, so this never adds a
         # first touch of the device the run itself would not make.
         # The device's own word, not ``jax.default_backend()``: tests
         # that steer backend-sniffing code replace that function, and

@@ -1103,8 +1103,7 @@ class MultiHeadAttention(Op):
         def kernel_for(shape, dtype):
             # Single launch when the shape fits the VMEM cap; the
             # chunked decomposition (per-chunk launches + lse merges)
-            # for longer sequences (or when FF_FLASH_FORCE_CHUNK pins
-            # it); None -> einsum fallback.
+            # for longer sequences; None -> einsum fallback.
             if not pallas_kernels.flash_any_supported(shape, dtype):
                 return None
 

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -55,27 +54,7 @@ def _mxu_precision(dtype):
     return None if jnp.dtype(dtype).itemsize >= 4 else lax.Precision.DEFAULT
 
 
-def _block_target_from_env() -> int:
-    """FF_FLASH_BLOCK tuning knob, sanitized: a ceiling on the block
-    ``_vmem_block_cap``'s table gives (unset: the table's own largest);
-    non-numeric falls back to that, anything else clamps to a multiple
-    of 8 >= 8 (the block rule _pick_block enforces — an unaligned
-    target would silently disable the kernel for every t > target)."""
-    raw = os.environ.get("FF_FLASH_BLOCK", "1024")
-    try:
-        t = int(raw)
-    except ValueError:
-        return 1024
-    return max(8, t - t % 8)
-
-
-#: Ceiling on the flash block (q and k block edge, always equal); the
-#: edge itself, and the chip readings it rests on, are
-#: ``_vmem_block_cap``'s.
-_BLOCK_TARGET = _block_target_from_env()
-
-
-def _pick_block(t: int, target: int = _BLOCK_TARGET) -> int:
+def _pick_block(t: int, target: int) -> int:
     """Largest divisor of ``t`` <= target that satisfies the TPU block
     rule (multiple of 8, or the whole dim).  0 if none exists."""
     if t <= target:
@@ -124,8 +103,7 @@ def _vmem_block_cap(t: int, hd: int, itemsize: int) -> int:
     u = t * hd * itemsize
 
     def scaled(cap: int) -> int:
-        b = max(8, (cap * _LANES // max(hd, _LANES)) // 8 * 8)
-        return min(_BLOCK_TARGET, b)
+        return max(8, (cap * _LANES // max(hd, _LANES)) // 8 * 8)
 
     if t <= 2048 and t % _LANES == 0 and hd * itemsize <= 256:
         return scaled(1024)
@@ -150,7 +128,7 @@ def _require_block(t: int, hd: int, itemsize: int) -> int:
     if block < 8 or t < 16:
         raise ValueError(
             f"flash attention needs seq >= 16 with a block divisor that "
-            f"is a multiple of 8, <= {_BLOCK_TARGET} and within the VMEM "
+            f"is a multiple of 8, <= 1024 and within the VMEM "
             f"budget; got t={t}, hd={hd}. Gate callers on "
             f"flash_supported()."
         )
@@ -491,367 +469,6 @@ def _fwd_launch(q, k, v, causal, interpret, block):
     )(q, k, v)
 
 
-def _fwd_stream_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr,
-                       *, block_q, block_k, causal, scale, num_kb):
-    """Streamed forward: 3D grid (bh, q-block, k-block).  K/V arrive
-    one block per grid step through pipelined BlockSpecs (Pallas
-    double-buffers the copies), so VMEM holds only the working blocks
-    — no resident full-K/V and therefore no ``_vmem_block_cap`` on t.
-    The softmax state (m, l, acc) persists in scratch across the
-    sequential k dimension; output writes at the last k step.  This is
-    the official TPU flash structure (cf. jax pallas ops
-    flash_attention) racing the resident-K/V production kernel
-    (``tools/probe_flash_variants.py`` v6_stream); it becomes the
-    default only after chip validation."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q_start = qi * block_q
-    k_start = kb * block_k
-
-    def _step():
-        q = q_ref[0]                                    # (bq, hd)
-        k = k_ref[0]                                    # (bk, hd)
-        v = v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # (bq, bk)
-        if causal:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m = m_scr[:]
-        l = l_scr[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        l_scr[:] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[:] = m_new
-
-    if causal:
-        # Blocks strictly above the diagonal contribute nothing —
-        # skip their MXU work (the fetch still happens; grid shapes
-        # are static).  Non-causal runs the body unconditionally
-        # (causal is a static Python bool; no runtime predicate).
-        pl.when(k_start <= q_start + block_q - 1)(_step)
-    else:
-        _step()
-
-    @pl.when(kb == num_kb - 1)
-    def _emit():
-        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            m_scr[:] + jnp.log(l_scr[:]), (block_q, LSE_LANES)
-        )
-
-
-def _fwd_stream_call(q, k, v, causal, interpret, block_q, block_k):
-    """Raw streamed forward on FOLDED (bh, t, hd) arrays; returns
-    (o, lse_lanes)."""
-    bh, t, hd = q.shape
-    num_kb = t // block_k
-    scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(
-        _fwd_stream_kernel, block_q=block_q, block_k=block_k,
-        causal=causal, scale=scale, num_kb=num_kb,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(bh, t // block_q, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
-        name="ff_flash_fwd_stream",
-        interpret=interpret,
-    )(q, k, v)
-
-
-def _stream_blocks(t: int, block_q: int, block_k: int):
-    """Clamp the streamed blocks to t; None if t doesn't tile."""
-    bq, bk = min(block_q, t), min(block_k, t)
-    if t % bq or t % bk:
-        return None
-    return bq, bk
-
-
-def _stream_default_block(hd: int) -> int:
-    """Dispatcher block size for the streamed path, scaled so the
-    working set (q/k/v blocks double-buffered + the f32 score block +
-    accumulator) stays inside scoped VMEM as hd grows — unmeasured
-    territory must fail toward smaller blocks, not Mosaic compile
-    errors (the _vmem_block_cap principle).  0 = don't dispatch."""
-    if hd <= 128:
-        return 512
-    if hd <= 256:
-        return 256
-    return 0
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_lse_streamed(q, k, v, causal: bool = True,
-                                 interpret: Optional[bool] = None,
-                                 block_q: int = 512, block_k: int = 512):
-    """Streamed flash on (b, h, t, hd): any t with ``t % block == 0``,
-    VMEM bounded by the working blocks alone (no resident K/V, so no
-    ``_vmem_block_cap`` on t).  Fully differentiable — the VJP runs the
-    streamed dq/dkv kernels.  Opt-in production path: the dispatcher
-    routes through it under ``FF_FLASH_STREAMED=1`` for fused-step
-    racing on chip (the FF_FLASH_FORCE_CHUNK pattern); also raced
-    per-kernel as v6_stream/b3_stream."""
-    (o, _lse), _ = _stream_fwd(q, k, v, causal, interpret, block_q, block_k)
-    return o, _lse
-
-
-def _stream_fwd(q, k, v, causal, interpret, block_q, block_k):
-    if interpret is None:
-        interpret = _interpret_default()
-    b, h, t, hd = q.shape
-    blocks = _stream_blocks(t, block_q, block_k)
-    assert blocks, (t, block_q, block_k)
-    bq, bk = blocks
-    fold = lambda x: x.reshape(b * h, t, hd)
-    o, lse_l = _fwd_stream_call(
-        fold(q), fold(k), fold(v), causal, interpret, bq, bk
-    )
-    out = (o.reshape(b, h, t, hd), lse_l[:, :, 0].reshape(b, h, t))
-    return out, (q, k, v, out[0], lse_l)
-
-
-def _cotangent_delta_lanes(o, g_o, g_lse, b, h, t):
-    """Shared VJP glue for both flash formulations: the per-row
-    ``delta = sum(o * do)`` with the lse cotangent folded in
-    (``d lse / d s = p``, so it enters ``ds = p * (dp - delta)`` as
-    ``delta -= g_lse``), broadcast to the LSE_LANES layout."""
-    delta = jnp.sum(o.astype(jnp.float32) * g_o.astype(jnp.float32), axis=-1)
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32).reshape(b, h, t)
-    return jnp.broadcast_to(
-        delta.reshape(b * h, t)[:, :, None], (b * h, t, LSE_LANES)
-    )
-
-
-def _stream_bwd(causal, interpret, block_q, block_k, res, g):
-    if interpret is None:
-        interpret = _interpret_default()
-    q, k, v, o, lse_l = res
-    g_o, g_lse = g
-    b, h, t, hd = q.shape
-    bq, bk = _stream_blocks(t, block_q, block_k)
-    fold = lambda x: x.reshape(b * h, t, hd)
-    delta_l = _cotangent_delta_lanes(o, g_o, g_lse, b, h, t)
-    dq, dk, dv = _bwd_stream_call(
-        fold(q), fold(k), fold(v), fold(g_o.astype(q.dtype)),
-        lse_l, delta_l, causal, interpret, block_q=bq, block_k=bk,
-    )
-    unfold = lambda x: x.reshape(b, h, t, hd)
-    return unfold(dq), unfold(dk), unfold(dv)
-
-
-flash_attention_lse_streamed.defvjp(_stream_fwd, _stream_bwd)
-
-
-def _dq_stream_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_scr,
-                      *, block_q, block_k, causal, scale, num_kb):
-    """Streamed dq: grid (bh, q-block, k-block); K/V blocks arrive via
-    pipelined BlockSpecs, dq accumulates in scratch across the
-    sequential k axis (same no-resident-K/V rationale as
-    ``_fwd_stream_kernel``)."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    q_start = qi * block_q
-    k_start = kb * block_k
-
-    def _step():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0:1]
-        delta = delta_ref[0, :, 0:1]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        dq_scr[:] = dq_scr[:] + lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_step)
-    else:
-        _step()
-
-    @pl.when(kb == num_kb - 1)
-    def _emit():
-        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
-
-
-def _dkv_stream_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr,
-                       *, block_q, block_k, causal, scale, num_qb):
-    """Streamed dk/dv: grid (bh, k-block, q-block); q/do/lse/delta
-    blocks stream through the sequential q axis, dk/dv accumulate in
-    scratch."""
-    ki = pl.program_id(1)
-    qb = pl.program_id(2)
-
-    @pl.when(qb == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    k_start = ki * block_k
-    q_start = qb * block_q
-
-    def _step():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0:1]
-        delta = delta_ref[0, :, 0:1]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # (bq, bk)
-        if causal:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_scr[:] = dv_scr[:] + lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        dk_scr[:] = dk_scr[:] + lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        # Query blocks entirely above this K block see none of it.
-        pl.when(q_start + block_q - 1 >= k_start)(_step)
-    else:
-        _step()
-
-    @pl.when(qb == num_qb - 1)
-    def _emit():
-        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _bwd_stream_call(q, k, v, do, lse, delta, causal, interpret,
-                     block_q=512, block_k=512):
-    """Streamed backward on folded (bh, t, hd): any t % block == 0,
-    VMEM bounded by working blocks.  Race/probe surface until chip
-    validation; the production VJP keeps the resident-K/V kernels."""
-    bh, t, hd = q.shape
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    assert t % block_q == 0 and t % block_k == 0, (t, block_q, block_k)
-    scale = 1.0 / math.sqrt(hd)
-    nq, nk = t // block_q, t // block_k
-    qb = lambda i_ax: pl.BlockSpec((1, block_q, hd),
-                                   (lambda b, i, j: (b, i, 0)) if i_ax
-                                   else (lambda b, i, j: (b, j, 0)))
-    qr = lambda i_ax: pl.BlockSpec((1, block_q, LSE_LANES),
-                                   (lambda b, i, j: (b, i, 0)) if i_ax
-                                   else (lambda b, i, j: (b, j, 0)))
-    kb_ = lambda i_ax: pl.BlockSpec((1, block_k, hd),
-                                    (lambda b, i, j: (b, i, 0)) if i_ax
-                                    else (lambda b, i, j: (b, j, 0)))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_stream_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          num_kb=nk),
-        grid=(bh, nq, nk),
-        in_specs=[qb(True), kb_(False), kb_(False), qb(True),
-                  qr(True), qr(True)],
-        out_specs=qb(True),
-        out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        name="ff_flash_dq_stream",
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_stream_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          num_qb=nq),
-        grid=(bh, nk, nq),
-        in_specs=[qb(False), kb_(True), kb_(True), qb(False),
-                  qr(False), qr(False)],
-        out_specs=[kb_(True), kb_(True)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, hd), k.dtype),
-            jax.ShapeDtypeStruct((bh, t, hd), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
-        ],
-        name="ff_flash_dkv_stream",
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
-
 def _bwd_call(q, k, v, do, lse, delta, causal, interpret):
     _, t, hd = q.shape
     return _bwd_launch(q, k, v, do, lse, delta, causal, interpret,
@@ -931,6 +548,18 @@ def _flash_fwd(q, k, v, causal, interpret):
     o = o.reshape(b, h, t, hd)
     lse = lse_l[:, :, 0].reshape(b, h, t)
     return (o, lse), (q, k, v, o, lse_l)
+
+
+def _cotangent_delta_lanes(o, g_o, g_lse, b, h, t):
+    """The backward's per-row ``delta = sum(o * do)``, the lse cotangent
+    folded in (``d lse / d s = p``: it enters ``ds = p * (dp - delta)``
+    as ``delta -= g_lse``), broadcast to the LSE_LANES layout."""
+    delta = jnp.sum(o.astype(jnp.float32) * g_o.astype(jnp.float32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32).reshape(b, h, t)
+    return jnp.broadcast_to(
+        delta.reshape(b * h, t)[:, :, None], (b * h, t, LSE_LANES)
+    )
 
 
 def _flash_bwd(causal, interpret, res, g):
@@ -1084,19 +713,6 @@ def attention_lse_blocked(q, k, v, causal: bool = True,
     return o, lse
 
 
-#: FF_FLASH_FORCE_CHUNK=<len>: route single-launch-capable shapes
-#: through the chunked decomposition at the given chunk length — the
-#: tuning knob for racing the two formulations at the fused-train-step
-#: level (tools/profile_lm_decomp.py).  0 = off (normal dispatch).
-_FORCE_CHUNK = int(os.environ.get("FF_FLASH_FORCE_CHUNK", "0") or 0)
-
-#: FF_FLASH_STREAMED=1: dispatch through the streamed 3D-grid
-#: formulation (no resident K/V; fwd + bwd custom VJP) wherever t
-#: tiles by the streamed blocks — the fused-step racing knob for
-#: promoting v6_stream/b3_stream to production after chip validation.
-_STREAMED = os.environ.get("FF_FLASH_STREAMED", "0") == "1"
-
-
 def flash_attention_lse_auto(q, k, v, causal: bool = True,
                              interpret: Optional[bool] = None):
     """``flash_attention_lse`` when the shape fits one launch, the
@@ -1105,20 +721,6 @@ def flash_attention_lse_auto(q, k, v, causal: bool = True,
     fall-back-to-dense signal instead of catching a trace-time raise
     (keeps the einsum path reachable if the support gates and this
     dispatcher ever diverge)."""
-    b, h, t, hd = q.shape
-    if _STREAMED and t >= 16 and hd >= 8:
-        blk = _stream_default_block(hd)
-        if blk and _stream_blocks(t, blk, blk) is not None:
-            return flash_attention_lse_streamed(
-                q, k, v, causal, interpret, blk, blk
-            )
-    if (_FORCE_CHUNK and t > _FORCE_CHUNK and t % _FORCE_CHUNK == 0
-            and flash_supported((b, h, _FORCE_CHUNK, hd), q.dtype)):
-        # A stale/oversized env value falls through to normal dispatch
-        # rather than raising from inside the jitted forward.
-        return flash_attention_lse_chunked(
-            q, k, v, causal, interpret, chunk=_FORCE_CHUNK
-        )
     if flash_supported(q.shape, q.dtype):
         return flash_attention_lse(q, k, v, causal, interpret)
     if flash_chunked_supported(q.shape, q.dtype):
@@ -1153,8 +755,7 @@ def flash_any_supported(shape: Tuple[int, ...], dtype=jnp.float32) -> bool:
 
 
 def flash_attention_lse_chunked(q, k, v, causal: bool = True,
-                                interpret: Optional[bool] = None,
-                                chunk: Optional[int] = None):
+                                interpret: Optional[bool] = None):
     """Flash attention for sequences past the single-launch VMEM cap
     (``_vmem_block_cap`` marks e.g. bf16 t=16384/hd=64 unsupported —
     the pipeline's resident copies alone exceed scoped VMEM).
@@ -1169,12 +770,11 @@ def flash_attention_lse_chunked(q, k, v, causal: bool = True,
     score matrix.
     """
     b, h, t, hd = q.shape
-    c = chunk or _chunk_len(t, hd, q.dtype.itemsize)
-    if c == 0 or c == t or t % c:
+    c = _chunk_len(t, hd, q.dtype.itemsize)
+    if c == 0 or c == t:
         raise ValueError(
             f"flash_attention_lse_chunked: no supported chunking for "
-            f"t={t}, hd={hd} (chunk={chunk}); an explicit chunk must "
-            f"divide t, and auto callers gate on flash_chunked_supported()."
+            f"t={t}, hd={hd}; callers gate on flash_chunked_supported()."
         )
     nq = t // c
     sl = lambda x, i: lax.slice_in_dim(x, i * c, (i + 1) * c, axis=2)
@@ -2485,7 +2085,45 @@ def _collapse_runs(flat_idx, updates):
 # ops/moe.py::MixtureOfExperts only when the serving executor drives them
 # ---------------------------------------------------------------------------
 
-_UNEVEN_BLOCK = 512
+def _prefill_block(t: int) -> int:
+    """Rows of a query (and key) block of the serving prefill forwards:
+    the largest of 512, 256, 128 that divides ``t`` (the gates hold
+    ``t`` to whole 128-row blocks)."""
+    block = 512
+    while t % block:
+        block //= 2
+    return block
+
+
+def _stream_softmax_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
+                         keep=None, drop_dead_rows=False):
+    """One ``(block, block)`` tile of scores folded into the running
+    softmax in scratch: ``m`` the rows' maxima, ``l`` their sums, ``acc``
+    the weighted values.  ``keep()`` gives the tile's mask (``None``:
+    every score is live).  ``drop_dead_rows``: a query row may find no
+    live key in this tile (its running max is then still the floor),
+    and such a row adds nothing."""
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    s = lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        precision=_mxu_precision(q.dtype),
+        preferred_element_type=jnp.float32,
+    ) * scale                                       # (bq, bk) f32
+    if keep is not None:
+        s = jnp.where(keep(), s, _NEG_INF)
+    m = m_scr[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if drop_dead_rows:
+        p = jnp.where(s > _NEG_INF, p, 0.0)
+    corr = jnp.exp(m - m_new)
+    acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        precision=_mxu_precision(v.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[...] = m_new
 
 
 def flash_uneven_supported(q_shape: Tuple[int, ...], v_width: int) -> bool:
@@ -2517,30 +2155,16 @@ def _fwd_uneven_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def causal():
+        q_pos = q_start + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = k_start + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return k_pos <= q_pos
+
     def step(masked):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            precision=_mxu_precision(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # (bq, bk) f32
-        if masked:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            precision=_mxu_precision(v.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
+        _stream_softmax_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                             scale, keep=causal if masked else None)
 
     # Blocks wholly below the diagonal need no mask.
     below = k_start + block_k - 1 <= q_start
@@ -2569,9 +2193,7 @@ def flash_fwd_uneven(q, k, v, scale: float,
     h_kv = k.shape[1]
     group = h // h_kv
     assert h == group * h_kv and v.shape[1] == h_kv, (q.shape, k.shape)
-    block = _UNEVEN_BLOCK
-    while t % block:
-        block //= 2
+    block = _prefill_block(t)
     num_kb = t // block
     kernel = functools.partial(
         _fwd_uneven_kernel, block_q=block, block_k=block, scale=scale,
@@ -2614,9 +2236,7 @@ def flash_window_walk(t: int, window: int) -> Tuple[int, int]:
     the rows of a query (and key) block, and how many key blocks a query
     block's band ``q - window < s <= q`` can intersect (the grid's last
     axis; a query block near the start skips those before position 0)."""
-    block = _UNEVEN_BLOCK
-    while t % block:
-        block //= 2
+    block = _prefill_block(t)
     return block, -(-(window - 1) // block) + 1
 
 
@@ -2639,33 +2259,15 @@ def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def band():
+        q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block, block), 0)
+        k_pos = k_start + lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        return (k_pos <= q_pos) & (k_pos > q_pos - window)
+
     def step(masked):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            precision=_mxu_precision(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # (bq, bk) f32
-        if masked:
-            q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block, block), 0)
-            k_pos = k_start + lax.broadcasted_iota(jnp.int32, (block, block), 1)
-            s = jnp.where((k_pos <= q_pos) & (k_pos > q_pos - window),
-                          s, _NEG_INF)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if masked:
-            # A query row may find no key of its band in this block (its
-            # running max is then still the floor): the row adds nothing.
-            p = jnp.where(s > _NEG_INF, p, 0.0)
-        corr = jnp.exp(m - m_new)
-        acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            precision=_mxu_precision(v.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
+        _stream_softmax_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                             scale, keep=band if masked else None,
+                             drop_dead_rows=masked)
 
     # A block every key of which lies inside every query's band needs
     # no mask (none at block == window: both blocks cross an edge).

@@ -229,8 +229,9 @@ class Telemetry:
     """Run-scoped telemetry collector.
 
     ``directory=None`` keeps everything in-process (counters +
-    percentiles + watchdog, no JSONL) — what bench.py uses to fold a
-    telemetry summary into its JSON without touching disk.
+    percentiles + watchdog, no JSONL) — what the program audit
+    (``analysis/program_audit.py``) counts a live pipeline step's host
+    programs with, without touching disk.
 
     As a context manager it installs itself as :func:`current` so every
     runtime component (trainer fences, pipeline program counters,
@@ -613,7 +614,8 @@ class Telemetry:
 
     def step_summary(self) -> Dict[str, Any]:
         """Counters + host-side step-time percentiles (p50/p95/max ms,
-        nearest-rank) — the block folded into fit stats and bench.py."""
+        nearest-rank) — the block folded into fit stats, ``run_end``
+        and the run index (``obs/registry.py``)."""
         out: Dict[str, Any] = {
             "steps": self.counts["steps"],
             "fences": self.counts["fences"],

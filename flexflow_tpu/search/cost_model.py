@@ -458,8 +458,7 @@ class Calibration:
 
     @staticmethod
     def from_telemetry(tel) -> "Calibration":
-        """Fit from a live in-memory Telemetry (bench.py's in-process
-        calibration leg)."""
+        """Fit from a live in-memory Telemetry."""
         return Calibration.from_summary(
             tel.calibration_summary(), source="in-memory telemetry"
         )

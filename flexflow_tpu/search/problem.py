@@ -180,7 +180,7 @@ def build_stage_partition(
     counts) over disjoint contiguous device blocks of ``num_devices //
     stages`` each, data-parallel within every stage — the same
     construction the reference's NMT app hand-writes per layer chunk
-    (``nmt.cc:269-308``) and bench.py's pipeline leg uses.  Returns
+    (``nmt.cc:269-308``).  Returns
     ``None`` when the partition is infeasible for this model (stage
     count vs ops/devices, or batch extents that don't divide across
     ``microbatches x intra-stage DP``) — the searcher simply skips the

@@ -11,8 +11,7 @@ scheduler (SERVING.md "Scheduler policy"):
   ``serving/workload.py``) become visible when the clock passes them.
   Queue-wait, e2e latency and SLO attainment are all virtual-clock
   quantities — bit-identical across replays and across boxes, which is
-  what makes the FIFO-vs-SLO A/B (tools/measure_serving.py) and the
-  chaos shed scenario exact.  Wall time is still measured for
+  what makes a FIFO-vs-SLO A/B and the chaos shed scenario exact.  Wall time is still measured for
   throughput stats, but no decision ever reads it.
 - **Policies.**  ``fifo`` reproduces the legacy discipline inside the
   new loop (arrival order, fixed decode k, no priorities/preemption/

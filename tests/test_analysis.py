@@ -109,21 +109,6 @@ class TestLintRules:
         )
         assert "FF003" not in _ids(lint.lint_source(src, "planted.py"))
 
-    def test_ff004_bench_stdout_contract(self):
-        bad = 'print("progress: 5/10")\n'
-        assert "FF004" in _ids(lint.lint_source(bad, "bench.py"))
-        ok = (
-            "import json, sys\n"
-            "print(json.dumps(result))\n"          # THE one JSON line
-            'print("note", file=sys.stderr)\n'     # routed
-        )
-        assert "FF004" not in _ids(lint.lint_source(ok, "bench.py"))
-        # Same bare print outside bench.py is out of scope.
-        assert "FF004" not in _ids(lint.lint_source(bad, "planted.py"))
-        # Review finding: an explicit file=sys.stdout must not pass.
-        sneaky = 'import sys\nprint("x", file=sys.stdout)\n'
-        assert "FF004" in _ids(lint.lint_source(sneaky, "bench.py"))
-
     def test_ff005_pallas_confinement(self):
         src = (
             "from jax.experimental import pallas as pl\n"
@@ -131,7 +116,7 @@ class TestLintRules:
         )
         vs = _ids(lint.lint_source(src, "flexflow_tpu/ops/linear.py"))
         assert "FF005" in vs
-        # The kernel library and the sanctioned probe tools are exempt.
+        # The kernel library is exempt.
         for exempt in lint.PALLAS_ALLOWLIST:
             assert "FF005" not in _ids(lint.lint_source(src, exempt))
         # Review finding: the repo's OWN wrapper library is the
@@ -190,8 +175,7 @@ class TestLintRules:
         assert "FF008" not in _ids(lint.lint_source(
             bad, "flexflow_tpu/runtime/telemetry.py"
         ))
-        # The catalog copy is dependency-free; tests/test_obs.py pins
-        # it equal to obs.events.EVENT_CATALOG.
+        # The catalog is obs/events.py's own, read as text (tests/test_obs.py).
         assert "run_start" in lint.FF008_EVENT_NAMES
 
     def test_planted_violation_in_temp_module(self, tmp_path):

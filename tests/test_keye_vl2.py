@@ -1,16 +1,16 @@
 """The Keye-VL-2.0 language model (grouped-query attention with a head
 norm, rotary positions and a learned token selector; a softmax router
 through the sorted expert layer) at a small size on the CPU, seeded
-weights, against the plain reference (``tests/references/keye_vl2.py``,
-a copy of the benchmark's that imports nothing of the program)."""
-
-import os
+weights, against the plain reference
+(``benchmark/references/keye_vl2.py``, the benchmark's own, which imports
+nothing of the program)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import benchmark.references.keye_vl2 as ref
 from benchmark import common, weights
 from flexflow_tpu.analysis.program_audit import iter_eqns
 from flexflow_tpu.config import FFConfig
@@ -28,9 +28,7 @@ from flexflow_tpu.ops import token_select
 from flexflow_tpu.ops.token_select import TokenSelector, rope_half
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import ServingExecutor
-from tests.references import keye_vl2 as ref
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 3300000029
 S = 128
 TOPK = KEYE_VL2_TINY["sa_config"]["topk"]           # 16: sequences are 4-8x
@@ -74,14 +72,6 @@ def _attn_op(cfg, b, t, **over):
     a = jnp.asarray(np.random.default_rng(2).standard_normal(
         (b, t, cfg["hidden_size"])).astype(np.float32))
     return op, get, params, a
-
-
-def test_the_two_reference_copies_are_one_text():
-    bench = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "keye_vl2.py")
-    mine = os.path.join(HERE, "references", "keye_vl2.py")
-    assert open(bench).read() == open(mine).read()
-    assert "flexflow_tpu" not in open(mine).read()
 
 
 def test_the_graph_and_what_the_builder_refuses():

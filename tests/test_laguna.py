@@ -2,8 +2,8 @@
 head counts in one model, a ring cache beside a full cache, a gate a
 head, rotary positions on a sub-width under YaRN, a share of the experts
 held) at a small size on the CPU, seeded weights, against the plain
-reference (``tests/references/laguna.py``, a copy of the benchmark's
-that imports nothing of the program)."""
+reference (``benchmark/references/laguna.py``, the benchmark's own,
+which imports nothing of the program)."""
 
 import math
 import os
@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import benchmark.references.laguna as ref
 from benchmark import common
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.models.transformer import (
@@ -35,9 +36,7 @@ from flexflow_tpu.ops.moe import MixtureOfExperts
 from flexflow_tpu.ops.token_select import rope_half
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import ServingExecutor
-from tests.references import laguna as ref
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 4400000077
 S = 64
 W = LAGUNA_TINY["sliding_window"]                    # 16: sequences reach 4 W
@@ -75,15 +74,6 @@ def _attn_op(b, t, heads=6, kv=2, hd=16, d=64, **kw):
               for k, s in op.param_specs().items()}
     a = jnp.asarray(rng.standard_normal((b, t, d)).astype(np.float32))
     return op, params, a
-
-
-def test_the_two_reference_copies_are_one_text():
-    bench = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "laguna.py")
-    mine = os.path.join(HERE, "references", "laguna.py")
-    assert open(bench).read() == open(mine).read()
-    assert "flexflow_tpu" not in open(mine).read()
-    assert 'precision="highest"' in open(mine).read()
 
 
 def test_the_graph_and_what_the_builder_refuses():

@@ -1,15 +1,14 @@
 """The DeepSeek-V3 block family (latent attention, the sorted expert
 layer, the cache protocol) at a small size on the CPU, seeded weights,
-against the plain reference (``tests/references/deepseek_v3.py``, a copy
-of the benchmark's that imports nothing of the program)."""
-
-import os
+against the plain reference (``benchmark/references/deepseek_v3.py``,
+the benchmark's own, which imports nothing of the program)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import benchmark.references.deepseek_v3 as ref
 from benchmark import common
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.models.transformer import (
@@ -26,9 +25,7 @@ from flexflow_tpu.ops.attention import (
 from flexflow_tpu.runtime import telemetry
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import Request, Server, ServingExecutor
-from tests.references import deepseek_v3 as ref
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 2900000017
 S = 128  # the kernels want whole 128-position tiles
 
@@ -53,14 +50,6 @@ def _model(cfg, batch, seq, dtype="float32"):
 def _tokens(n, t):
     return np.random.default_rng(5).integers(0, 512, size=(n, t),
                                              dtype=np.int32)
-
-
-def test_the_two_reference_copies_are_one_text():
-    bench = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "deepseek_v3.py")
-    mine = os.path.join(HERE, "references", "deepseek_v3.py")
-    assert open(bench).read() == open(mine).read()
-    assert "flexflow_tpu" not in open(mine).read()
 
 
 def test_full_forward_logits_match_the_reference():

@@ -110,11 +110,16 @@ def _synth_log(path, run_id="run-a", step_ms_p50=2.0, step_ms_p95=2.4,
 
 
 def test_ff008_catalog_matches_event_catalog():
-    # The lint rule keeps a dependency-free copy (it may not import
-    # flexflow_tpu.obs); this pin is what keeps the two sets one.
-    from flexflow_tpu.analysis.lint import FF008_EVENT_NAMES, lint_source
+    # The lint rule may not import flexflow_tpu.obs: it reads the
+    # catalogs out of obs/events.py's text, and a catalog that is gone
+    # or no longer a literal set fails loudly.
+    from flexflow_tpu.analysis.lint import (
+        FF008_EVENT_NAMES, _read_catalogs, lint_source)
 
     assert FF008_EVENT_NAMES == EVENT_CATALOG
+    for not_a_catalog in ("NO_SUCH_CATALOG", "EXIT_CLEAN"):
+        with pytest.raises(ValueError, match=not_a_catalog):
+            _read_catalogs(not_a_catalog)
     bad = 'tel.emit("not_a_registered_event", x=1)\n'
     vs = lint_source(bad, "flexflow_tpu/runtime/foo.py")
     assert [v.rule for v in vs] == ["FF008"]
@@ -141,7 +146,7 @@ def test_ff008_catalog_matches_event_catalog():
      'with jax.named_scope(op.name):\n    pass\n'),
 ])
 def test_ff008_trace_name_catalogs(copy, catalog, bad, ok, dynamic):
-    """The same pin and the same rule for the three kinds of name a
+    """The same reading and the same rule for the three kinds of name a
     profiler trace is read by; a kernel library or the telemetry
     module is no exemption, a dynamic name is."""
     from flexflow_tpu.analysis import lint

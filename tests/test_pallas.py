@@ -5,6 +5,11 @@ interpreter on the CPU test mesh (SURVEY.md §4: jax autodiff/naive
 math as the numeric oracle for every hand kernel).
 """
 
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -512,96 +517,27 @@ def test_auto_dispatch_long_ragged_uses_blocked():
 
 
 def test_chunked_gates_32k_and_beyond():
-    """VERDICT r4 item 7: bf16 t=32768+ decomposes into kernel chunks
-    (the transformer_32k bench leg's dispatch path)."""
+    """bf16 t=32768+ decomposes into kernel chunks."""
     for t in (32768, 65536):
         shape = (1, 8, t, 64)
         assert pk.flash_chunked_supported(shape, jnp.bfloat16), t
         assert pk._chunk_len(t, 64, 2) == 8192
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_streamed_flash_matches_production(rng, causal):
-    """The 3D-grid streamed forward (v6_stream race candidate, no
-    resident K/V) must match the production kernel exactly in
-    interpret mode, including its lse."""
-    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)),
-                           jnp.float32) for _ in range(3))
-    o_s, lse_s = pk.flash_attention_lse_streamed(
-        q, k, v, causal, block_q=64, block_k=64)
-    o_r, lse_r = pk.flash_attention_lse(q, k, v, causal)
-    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_r),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(lse_s),
-        np.asarray(lse_r if lse_r.ndim == 3 else lse_r[..., 0]),
-        rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_streamed_backward_matches_production(rng, causal):
-    """The streamed dq/dkv kernels (3D grid, no resident K/V) must
-    match the production backward exactly in interpret mode."""
-    bh, t, hd = 2, 256, 64
-    q, k, v, do = (jnp.asarray(rng.standard_normal((bh, t, hd)),
-                               jnp.float32) for _ in range(4))
-    o, lse_l = pk._fwd_call(q, k, v, causal, True)
-    delta = jnp.sum(o.astype(jnp.float32) * do, axis=-1)
-    delta_l = jnp.broadcast_to(delta[:, :, None], (bh, t, pk.LSE_LANES))
-    ref = pk._bwd_call(q, k, v, do, lse_l, delta_l, causal, True)
-    got = pk._bwd_stream_call(q, k, v, do, lse_l, delta_l, causal, True,
-                              block_q=64, block_k=64)
-    for a, b in zip(got, ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_streamed_vjp_matches_production_grads(rng):
-    """flash_attention_lse_streamed is a full custom-VJP path: grads
-    must match the production kernel's."""
-    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)),
-                           jnp.float32) for _ in range(3))
-    cot = jnp.asarray(rng.standard_normal((1, 2, 256, 64)), jnp.float32)
-
-    def loss_stream(q, k, v):
-        return jnp.sum(pk.flash_attention_lse_streamed(
-            q, k, v, True, None, 64, 64)[0] * cot)
-
-    def loss_prod(q, k, v):
-        return jnp.sum(pk.flash_attention_lse(q, k, v, True)[0] * cot)
-
-    gs = jax.grad(loss_stream, argnums=(0, 1, 2))(q, k, v)
-    gp = jax.grad(loss_prod, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gs, gp):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_streamed_env_dispatch(monkeypatch):
-    """FF_FLASH_STREAMED=1 routes auto through the streamed path for
-    tiling shapes (observed via a sentinel wrapper, not just output
-    shape) and falls through for ragged ones and oversized head dims."""
-    calls = []
-    real = pk.flash_attention_lse_streamed
-
-    def sentinel(q, k, v, *a, **kw):
-        calls.append(q.shape)
-        return real(q, k, v, *a, **kw)
-
-    monkeypatch.setattr(pk, "_STREAMED", True)
-    monkeypatch.setattr(pk, "flash_attention_lse_streamed", sentinel)
-    q = jnp.zeros((1, 1, 1024, 64), jnp.float32)
-    res = pk.flash_attention_lse_auto(q, q, q)
-    assert res is not None and res[0].shape == q.shape
-    assert calls == [q.shape], "streamed path not taken"
-    # Ragged t: streamed can't tile, normal dispatch takes over.
-    q2 = jnp.zeros((1, 1, 8200, 64), jnp.bfloat16)
-    res2 = pk.flash_attention_lse_auto(q2, q2, q2)
-    assert res2 is not None and res2[0].shape == q2.shape
-    assert len(calls) == 1, "ragged t must not route streamed"
-    # Oversized head dim: VMEM-unsafe at any streamed block — fall
-    # through (here: to None, nothing else supports it either).
-    assert pk._stream_default_block(512) == 0
+def test_the_kernel_library_reads_no_environment():
+    """What a cell's kernels are is decided by the shape, in code: the
+    module reads no environment variable, and a shell that exports the
+    block knob of old gets the table's block all the same."""
+    src = pathlib.Path(pk.__file__).read_text()
+    assert "os.environ" not in src and "getenv" not in src
+    code = ("from flexflow_tpu.ops import pallas_kernels as pk; "
+            "print(pk._flash_block(1024, 64, 2))")
+    env = dict(os.environ, FF_FLASH_BLOCK="256", JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=120, check=True,
+        cwd=pathlib.Path(pk.__file__).parents[2])
+    assert out.stdout.split()[-1] == "1024", out.stdout
 
 
 # -- row kernels: the two addressings ----------------------------------------

@@ -1,16 +1,15 @@
 """The Solar-Open2 block family (grouped-query attention with an output
 gate, Kimi Delta Attention with its recurrent state, the expert share)
 at a small size on the CPU, seeded weights, against the plain reference
-(``tests/references/solar_open2.py``, a copy of the benchmark's that
+(``benchmark/references/solar_open2.py``, the benchmark's own, which
 imports nothing of the program)."""
-
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import benchmark.references.solar_open2 as ref
 from benchmark import common
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.models.transformer import (
@@ -29,9 +28,7 @@ from flexflow_tpu.ops.delta_attention import KimiDeltaAttention, kda_recurrence
 from flexflow_tpu.runtime import telemetry
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import Request, Server, ServingExecutor
-from tests.references import solar_open2 as ref
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 3300000019
 S = 128  # the kernels want whole 128-position tiles
 
@@ -70,14 +67,6 @@ def _activations(b, t, d):
 def _tokens(n, t, vocab=512):
     return np.random.default_rng(5).integers(0, vocab, size=(n, t),
                                              dtype=np.int32)
-
-
-def test_the_two_reference_copies_are_one_text():
-    bench = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "solar_open2.py")
-    mine = os.path.join(HERE, "references", "solar_open2.py")
-    assert open(bench).read() == open(mine).read()
-    assert "flexflow_tpu" not in open(mine).read()
 
 
 def test_the_graph_keeps_the_three_op_names_and_refuses_what_it_does_not_build():

@@ -33,8 +33,7 @@ Pinned invariants:
 
 The scheduler's cases run the compute-free simulated loop (no jax
 programs); its real-engine reconciliation lives in
-``test_serving_sched.py``'s telemetered run +
-``tools/measure_serving.py``'s reconciliation leg.  The measured loop's
+``test_serving_sched.py``'s telemetered run.  The measured loop's
 cases run a tiny model on the CPU.
 """
 

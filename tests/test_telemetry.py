@@ -399,7 +399,7 @@ def test_heartbeat_file(tmp_path, monkeypatch):
         time.sleep(0.02)
         tel.heartbeat()
         assert hb.stat().st_mtime >= t0
-    # FF_HEARTBEAT_FILE relocates it (the tpu_watcher.sh wiring).
+    # FF_HEARTBEAT_FILE relocates it (an external supervisor's wiring).
     alt = tmp_path / "alt_beat"
     monkeypatch.setenv("FF_HEARTBEAT_FILE", str(alt))
     with Telemetry():

@@ -58,10 +58,6 @@ def _rows():
 _ENTRY_POINTS = {
     "flash": (lambda q: jax.grad(lambda x: pk.flash_attention(x, x, x).sum())(q),
               _qkv, {"ff_flash_fwd", "ff_flash_dq", "ff_flash_dkv"}),
-    "flash_streamed": (
-        lambda q: jax.grad(
-            lambda x: pk.flash_attention_lse_streamed(x, x, x, True, None, 64, 64)[0].sum())(q),
-        _qkv, {"ff_flash_fwd_stream", "ff_flash_dq_stream", "ff_flash_dkv_stream"}),
     "flash_decode": (
         lambda q: pk.flash_decode(q[:, :, 0], q[:, :, 1], q[:, :, 2], jnp.ones((1, 128, 2, 64)),
                                   jnp.ones((1, 128, 2, 64)), jnp.array([5], jnp.int32)),

@@ -1,8 +1,8 @@
 """The Xing4.0 block family (the hyper-connection's four-stream residual
 round latent attention with a compressed query under YaRN and the sorted
 expert layer) at a small size on the CPU, seeded weights, against the
-plain reference (``tests/references/xing4.py``, a copy of the
-benchmark's that imports nothing of the program)."""
+plain reference (``benchmark/references/xing4.py``, the benchmark's
+own, which imports nothing of the program)."""
 
 import json
 import os
@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import benchmark.references.xing4 as ref
 from benchmark import common
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel
@@ -27,10 +28,8 @@ from flexflow_tpu.ops.hyper_connection import sinkhorn, stochastic_defect
 from flexflow_tpu.runtime import telemetry
 from flexflow_tpu.runtime.executor import Executor
 from flexflow_tpu.runtime.serving import Request, Server, ServingExecutor
-from tests.references import xing4 as ref
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 2900000035
 S = 128  # the kernels want whole 128-position tiles
 
@@ -57,13 +56,6 @@ def _model(cfg, batch, seq, dtype="float32"):
 def _tokens(n, t):
     return np.random.default_rng(5).integers(0, 512, size=(n, t),
                                              dtype=np.int32)
-
-
-def test_the_two_reference_copies_are_one_text():
-    bench = os.path.join(REPO, "benchmark", "references", "xing4.py")
-    mine = os.path.join(HERE, "references", "xing4.py")
-    assert open(bench).read() == open(mine).read()
-    assert "flexflow_tpu" not in open(mine).read()
 
 
 # -- the hyper-connection -------------------------------------------------------

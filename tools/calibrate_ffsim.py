@@ -9,8 +9,8 @@ so the ``*_speedup_sim`` numbers were internally consistent yet
 externally unanchored.
 
 This tool closes the loop on the one device we can reach: for
-alexnet (bench.py's headline b=2048 config) / vgg16 (search shape,
-b=64 — it has no bench leg) / dlrm (run_random.sh shape) it
+alexnet (b=2048) / vgg16 (search shape, b=64) / dlrm
+(run_random.sh shape) it
   1. measures the per-(op, degree=1) fwd+bwd table live,
   2. predicts the single-chip step via ffsim in BOTH pricing modes
      (measured table / analytic roofline),
@@ -44,9 +44,8 @@ def _models(on_tpu: bool):
     )
 
     out = []
-    # bench.py's headline alexnet config (BENCH_BATCH default 2048) —
-    # the calibration must anchor the shape the bench reports; vgg16
-    # has no bench leg, so it runs at its search shape (b=64).
+    # alexnet at the batch OP_PARALLEL.md's table was read at; vgg16
+    # at its search shape (b=64).
     b = 2048 if on_tpu else 16
     cfg = FFConfig(batch_size=b, compute_dtype="bfloat16")
     out.append(("alexnet", build_alexnet(
@@ -70,7 +69,7 @@ def main():
 
     if (jax.default_backend() == "cpu"
             and os.environ.get("JAX_PLATFORMS") != "cpu"):
-        # bench.py's rule: the CPU only when it was asked for.
+        # The CPU only when it was asked for.
         sys.exit("calibrate_ffsim: jax found no accelerator and "
                  "JAX_PLATFORMS=cpu was not asked for")
     if jax.default_backend() == "cpu":
