@@ -85,6 +85,8 @@ class Sizes:
     serve_keye: Tuple[str, ...]
     #: The Laguna preset: prompts several windows long.
     serve_laguna: Tuple[str, ...]
+    #: The A.X-K2 preset: prompts longer than its ``index_topk``.
+    serve_axk2: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -143,6 +145,14 @@ FULL = Sizes(
                   "--max-batch", "4", "--requests", "6", "--max-new", "12",
                   "--prompt-len", "1100:1900", "--buckets", "1280,2048",
                   "--dtype", "bfloat16"),
+    # 1024 positions under an ``index_topk`` of 512 and chunks of 512:
+    # prompts of 600-900 select in the prefill's second chunk (its first
+    # 512 rows go through the streamed forward kernel) and in every
+    # decode step.
+    serve_axk2=("--model-config", "axk2-smoke", "--max-seq", "1024",
+                "--max-batch", "4", "--requests", "6", "--max-new", "12",
+                "--prompt-len", "600:900", "--buckets", "1024",
+                "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
     dlrm4=("-b", "1024", "-i", "3", "--momentum", "0", "--wd", "0",
@@ -726,6 +736,55 @@ def keye_phase(argv: Sequence[str]) -> None:
           "serve/keye: a prompt under topk: nothing was selected")
 
 
+def axk2_phase(argv: Sequence[str]) -> None:
+    """The A.X-K2 preset through ``apps.serve``: latent attention ops that
+    compose the token selector (its query from the compressed query), two
+    cache entries a layer held positions-major (a position's latent row
+    filled up to whole lane tiles, the selector's key), gated norms, a
+    router over expert groups, the grouped product in both programs, a
+    decode superstep that moves no cache, and a superstep's event
+    counting ``index_topk`` fetched rows a slot a step."""
+    from flexflow_tpu.ops.attention import LatentAttention
+    from flexflow_tpu.ops.norm import RMSNorm
+
+    run = serve_run("serve/axk2", argv)
+    sex = run.srv.ex
+    check(all(isinstance(op, LatentAttention) and op.select is not None
+              and op.select.query_dim == op.attrs["q_rank"]
+              and op.attrs["gate"] == "per_head" for op in sex.attn_ops)
+          and any(op.name.endswith("_moe") and op.attrs["n_group"] > 1
+                  for op in sex._layers)
+          and all(op.attrs["gate_rank"] for op in sex._layers
+                  if isinstance(op, RMSNorm)),
+          "serve/axk2: the served graph lacks the selector, the gate, the "
+          "groups or a gated norm")
+    caches = sex.init_cache()
+    op = sex.attn_ops[0]
+    want = {"ckr": (sex.max_batch, sex.max_seq, op.row_width + op.row_pad),
+            "idx": (sex.max_batch, sex.max_seq, op.select.head_dim)}
+    got = {e: tuple(c.shape) for e, c in caches[op.name].items()}
+    check(got == want and want["ckr"][2] % 128 == 0,
+          f"serve/axk2: caches {got}, expected {want}")
+    check(sex._attention_paths(False) == "latent_select_expanded"
+          and sex._attention_paths(True) == "latent_select_absorbed",
+          "serve/axk2: the programs announce other paths")
+    decode = check_program_kernels(
+        "serve/axk2", run, caches, decode=("ff_grouped_matmul",),
+        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
+    moved = cache_shaped_relayouts(decode, caches)
+    check(not moved, f"serve/axk2: the compiled decode superstep moves a "
+                     f"whole cache: {moved[:3]}")
+    k = int(run.stats["decode_steps_per_call"])
+    rows = sex.kv_rows(np.full((sex.max_batch,), 700, np.int32), k)
+    check(rows["kv_rows_fetched"] == sex.max_batch * k * op.select.topk
+          and rows["idx_rows_fetched"] == rows["kv_rows_cache"],
+          f"serve/axk2: a superstep at 700 live positions reports {rows}")
+    check(all(len(r.prompt) > op.select.topk for r in run.requests),
+          "serve/axk2: a prompt under index_topk: nothing was selected")
+    oracle = serve_run("serve/axk2-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/axk2", run, oracle, tol=BF16_KERNEL_TOL)
+
+
 def laguna_phase(argv: Sequence[str]) -> None:
     """The Laguna preset through ``apps.serve``: window and full
     grouped-query layers with different head counts in one served graph,
@@ -913,6 +972,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
                                             streams=4)),
         ("serve/keye", lambda: keye_phase(sz.serve_keye)),
         ("serve/laguna", lambda: laguna_phase(sz.serve_laguna)),
+        ("serve/axk2", lambda: axk2_phase(sz.serve_axk2)),
     ]
 
 

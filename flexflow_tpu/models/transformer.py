@@ -114,15 +114,24 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
     the head (the query compressed too under ``q_lora_rank``, the
     positions YaRN's under ``rope_scaling``), ``first_k_dense_replace``
     leading gated-SiLU dense layers, then expert layers under a sigmoid
-    top-k router with a selection bias and shared experts; no bias
-    anywhere, an untied head.  ``model_type`` ``xing4_0`` is the same
-    block round a residual of ``hc_mult`` streams (``_residual``); its
-    multi-token-prediction module (``num_nextn_predict_layers``) belongs
-    to training and to self-drafting and is not built.
-    ``held_experts`` (not a key of the source: the deployment's) names
-    the routed experts this chip holds, default all."""
-    for key, want in (("n_group", 1), ("topk_group", 1),
-                      ("moe_layer_freq", 1), ("attention_bias", False),
+    top-k router with a selection bias (among the ``topk_group`` best of
+    ``n_group`` groups of experts where there are groups) and shared
+    experts; no bias anywhere, an untied head.  ``model_type``
+    ``xing4_0`` is the same block round a residual of ``hc_mult``
+    streams (``_residual``); its multi-token-prediction module
+    (``num_nextn_predict_layers``) belongs to training and to
+    self-drafting and is not built.  ``model_type`` ``axk2`` is the same
+    block with DeepSeek-V3.2's learned token selector over the latent
+    cache (``index_n_heads``, ``index_head_dim``, ``index_topk``), a
+    sigmoid gate a head on the attended values
+    (``attention_output_gate``), a low-rank gate on the block's two norms
+    and the last one (``gated_norm``, ``gated_norm_rank``) and its
+    rotary keys under ``rope_parameters``.  ``held_experts`` (not a key
+    of the source: the deployment's) names the routed experts this chip
+    holds, default all; where ``published.n_routed_experts`` stands
+    beside it, that is the router's width and ``n_routed_experts`` the
+    number held."""
+    for key, want in (("moe_layer_freq", 1), ("attention_bias", False),
                       ("hidden_act", "silu"), ("rope_interleave", True),
                       ("tie_word_embeddings", False)):
         if m.get(key, want) != want:
@@ -133,22 +142,45 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
         raise ValueError(f"scoring_func {m['scoring_func']!r}")
     d, eps = m["hidden_size"], m["rms_norm_eps"]
     layers = m["num_hidden_layers"]
+    rope = m.get("rope_parameters") or {}
+    scaling = m.get("rope_scaling")
+    if rope.get("rope_type", "default") != "default":
+        scaling = rope                       # the type's own keys (YaRN's)
+    held, routed = m.get("held_experts"), m["n_routed_experts"]
+    if held is not None and "n_routed_experts" in m.get("published", {}):
+        routed = m["published"]["n_routed_experts"]
+        if len(held) != m["n_routed_experts"]:
+            raise ValueError(
+                f"deepseek_v3 builder: held_experts names {len(held)} "
+                f"experts, n_routed_experts (the number held) is "
+                f"{m['n_routed_experts']}")
+    norm = dict(eps=eps)
+    if m.get("gated_norm"):
+        norm["gate_rank"] = m["gated_norm_rank"]
+    more = {}
+    if m.get("attention_output_gate"):
+        more["gate"] = "per_head"
+    if "index_topk" in m:
+        more["select"] = {"indexer_num_heads": m["index_n_heads"],
+                          "indexer_head_dim": m["index_head_dim"],
+                          "topk": m["index_topk"]}
     # A table in the compute dtype (the family policy keeps it f32 for
     # the sparse-update kernels, which this family does not train with).
     x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
                           dtype=jnp.dtype(ff.config.compute_dtype))
     for i in range(layers):
         def attention(u, i=i):
-            a = ff.rms_norm(u, eps=eps, name=f"blk{i}_ln1")
+            a = ff.rms_norm(u, name=f"blk{i}_ln1", **norm)
             return ff.latent_attention(
                 a, m["num_attention_heads"], kv_rank=m["kv_lora_rank"],
                 nope_dim=m["qk_nope_head_dim"], rope_dim=m["qk_rope_head_dim"],
-                v_dim=m["v_head_dim"], rope_theta=m["rope_theta"], norm_eps=eps,
-                q_rank=m.get("q_lora_rank"), rope_scaling=m.get("rope_scaling"),
-                name=f"blk{i}_attn")
+                v_dim=m["v_head_dim"],
+                rope_theta=rope.get("rope_theta", m.get("rope_theta")),
+                norm_eps=eps, q_rank=m.get("q_lora_rank"),
+                rope_scaling=scaling, name=f"blk{i}_attn", **more)
 
         def feed_forward(u, i=i):
-            h = ff.rms_norm(u, eps=eps, name=f"blk{i}_ln2")
+            h = ff.rms_norm(u, name=f"blk{i}_ln2", **norm)
             if i < m["first_k_dense_replace"]:
                 g = ff.dense(h, m["intermediate_size"], activation="silu",
                              use_bias=False, name=f"blk{i}_mlp_gate")
@@ -157,18 +189,19 @@ def _deepseek_v3_lm(ff: FFModel, tok, m: Dict[str, Any]):
                 return ff.dense(ff.multiply(g, up, name=f"blk{i}_mlp_act"), d,
                                 use_bias=False, name=f"blk{i}_mlp_down")
             return ff.moe(
-                h, m["n_routed_experts"], m["moe_intermediate_size"],
+                h, routed, m["moe_intermediate_size"],
                 top_k=m["num_experts_per_tok"], dispatch="sorted",
                 router=m.get("scoring_func", "sigmoid"), gated=True,
                 activation="silu", shared_experts=m["n_shared_experts"],
                 selection_bias=m.get("topk_method") == "noaux_tc",
                 norm_topk_prob=m["norm_topk_prob"],
                 routed_scale=m["routed_scaling_factor"],
-                held_experts=m.get("held_experts"), name=f"blk{i}_moe")
+                n_group=m.get("n_group", 1), topk_group=m.get("topk_group", 1),
+                held_experts=held, name=f"blk{i}_moe")
 
         x = _residual(ff, m, x, i, 1, attention)
         x = _residual(ff, m, x, i, 2, feed_forward, close=i == layers - 1)
-    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    x = ff.rms_norm(x, name="ln_f", **norm)
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
 
@@ -390,7 +423,8 @@ def _laguna_lm(ff: FFModel, tok, m: Dict[str, Any]):
 
 
 _BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
-           "xing4_0": _deepseek_v3_lm, "solar_open2": _solar_open2_lm,
+           "xing4_0": _deepseek_v3_lm, "axk2": _deepseek_v3_lm,
+           "solar_open2": _solar_open2_lm,
            "KeyeVL2": _keye_vl2_lm, "laguna": _laguna_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
@@ -543,7 +577,39 @@ LAGUNA_SMOKE: Dict[str, Any] = {
     "sliding_window": 512,
 }
 
-PRESETS = {"laguna-tiny": LAGUNA_TINY,
+#: The A.X-K2 family at unit-test size: the DeepSeek-V3 block with a
+#: token selector over the latent cache (a ``topk`` smaller than the
+#: tests' sequences, its rotary part half its head), a gate a head,
+#: gated norms, four groups of four experts of which two stay, one
+#: dense and two expert layers.
+AXK2_TINY: Dict[str, Any] = {
+    **{k: v for k, v in DEEPSEEK_V3_TINY.items() if k != "rope_theta"},
+    "model_type": "axk2", "q_lora_rank": 24, "routed_scaling_factor": 2.5,
+    "n_routed_experts": 16, "n_group": 4, "topk_group": 2,
+    "num_experts_per_tok": 3,
+    "rope_parameters": {"rope_type": "yarn", "rope_theta": 10000.0,
+                        "factor": 2, "beta_fast": 32, "beta_slow": 1,
+                        "mscale": 1, "mscale_all_dim": 1,
+                        "original_max_position_embeddings": 32},
+    "attention_output_gate": True, "attn_gate_fused": True,
+    "gated_norm": True, "gated_norm_rank": 4,
+    "index_n_heads": 4, "index_head_dim": 16, "index_topk": 16,
+    "num_nextn_predict_layers": 0,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip: chip_smoke.py.
+AXK2_SMOKE: Dict[str, Any] = {
+    **AXK2_TINY, "vocab_size": 2048, "hidden_size": 256,
+    "intermediate_size": 512, "moe_intermediate_size": 128,
+    "q_lora_rank": 128, "kv_lora_rank": 128, "qk_nope_head_dim": 64,
+    "qk_rope_head_dim": 32, "v_head_dim": 64, "gated_norm_rank": 16,
+    "index_head_dim": 64, "index_topk": 512,
+}
+
+PRESETS = {"axk2-tiny": AXK2_TINY,
+           "axk2-smoke": AXK2_SMOKE,
+           "laguna-tiny": LAGUNA_TINY,
            "laguna-smoke": LAGUNA_SMOKE,
            "keye-vl2-tiny": KEYE_VL2_TINY,
            "keye-vl2-smoke": KEYE_VL2_SMOKE,

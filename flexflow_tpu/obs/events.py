@@ -122,7 +122,8 @@ KERNEL_CATALOG = frozenset({
 
 #: ``jax.named_scope`` phases beside the per-op scopes (``op.name``):
 #: of a train step the loss ops and every optimizer update; of a serving
-#: program the two parts of a token selector.
+#: program the two parts of a token selector, a gated norm's gate and the
+#: group step of a router.
 SCOPE_CATALOG = frozenset({
     "ff_loss",
     "ff_opt",
@@ -130,6 +131,12 @@ SCOPE_CATALOG = frozenset({
     # selector's projections and scores; its top-k and the row gather
     "ff_index",
     "ff_select",
+    # inside a gated RMSNorm (ops/norm.py): the low-rank gate's two maps,
+    # its sigmoid and the product
+    "ff_gnorm",
+    # inside an expert layer's router under expert groups (ops/moe.py):
+    # the groups' standing, the kept groups and the mask
+    "ff_route_group",
 })
 
 #: ``run_end.exit`` classifications (the reader adds ``truncated`` for

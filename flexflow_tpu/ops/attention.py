@@ -119,6 +119,87 @@ def _gate_heads(out, logits, head_dim: int):
     return (out.reshape(b, t, -1, head_dim) * gate[..., None]).reshape(out.shape)
 
 
+def _attend_selected(sel, t: int, placed_q, kh, vh, index, scale: float,
+                     dense, dtype, shared_k=None):
+    """Causal attention of ``t`` query rows over (b, h_kv, t, dk) keys
+    and (b, h_kv, t, dv) values starting at position 0, each query row
+    over the positions the selector ``sel`` keeps for it (``index``: its
+    ``(q, k, w)`` of the call's tokens).  The keys come placed; the
+    queries a chunk at a time, ``placed_q(start, n)`` (b, h, n, dk).
+    Rows under ``topk`` keep their whole past: whole chunks of them go
+    to ``dense(q, k, v, dtype)`` (the op's own causal path, (b, n, h *
+    dv)).  The rest run a chunk of ``sel.q_chunk`` query rows at a time:
+    the chunk's selector scores against the keys up to its end, its
+    rows' ``topk``-th largest as the threshold, and masked attention a
+    query head at a time (a head's scores of one chunk against 32k keys
+    are 64 MB in f32; all heads' at once would be 2 GB).  Chunks are
+    grouped by where they end into doubling key widths so that each
+    width is one compiled loop and a chunk pays for at most twice the
+    keys it can see.  Masked pairs are computed and thrown away
+    (ROADMAP B-M1: a prefill that does not pay for them).  Two forms for
+    an op whose whole-sequence arrays would not fit: the selector's
+    queries may be a function ``(start, n) -> (b, n, heads, hd)`` made a
+    chunk at a time, and ``shared_k`` (b, t, r) is a part of every head's
+    key held once: a query's trailing ``r`` values are scored against it
+    and ``kh`` holds the heads' own part alone (``dense`` is handed
+    ``kh`` as it is: the op puts the two together for those rows)."""
+    iq, ik, iw = index
+    index_q = iq if callable(iq) else \
+        lambda start, n: lax.dynamic_slice_in_dim(iq, start, n, axis=1)
+    b = kh.shape[0]
+    if t <= sel.topk:
+        return dense(placed_q(0, t), kh, vh, dtype)
+    c = t if t % sel.q_chunk else sel.q_chunk   # one chunk: odd sizes
+    head = (sel.topk // c) * c
+    outs = []
+    if head:
+        outs.append(dense(placed_q(0, head), kh[:, :, :head],
+                          vh[:, :, :head], dtype))
+
+    def chunk_out(start, width):
+        rows = start + jnp.arange(c)
+        with jax.named_scope("ff_index"):
+            scores = sel.scores(
+                index_q(start, c),
+                lax.dynamic_slice_in_dim(iw, start, c, axis=1),
+                ik[:, :width])                               # (b, c, width)
+        with jax.named_scope("ff_select"):
+            keep = sel.keep(scores, rows)
+        qc = placed_q(start, c)                               # (b, h, c, dk)
+        h = qc.shape[1]
+        g = h // kh.shape[1]
+
+        def one_head(j):
+            q = qc[:, j]
+            k, v = kh[:, j // g, :width], vh[:, j // g, :width]
+            if shared_k is not None:
+                own = k.shape[-1]
+                s = jnp.einsum("bqd,bsd->bqs", q[..., :own], k,
+                               preferred_element_type=jnp.float32) \
+                    + jnp.einsum("bqd,bsd->bqs", q[..., own:],
+                                 shared_k[:, :width],
+                                 preferred_element_type=jnp.float32)
+                s = s * scale
+            else:
+                s = jnp.einsum("bqd,bsd->bqs", q, k,
+                               preferred_element_type=jnp.float32) * scale
+            p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1)
+            return jnp.einsum("bqs,bsd->bqd", p.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32)
+
+        o = lax.map(one_head, jnp.arange(h))                  # (h, b, c, dv)
+        return o.transpose(1, 2, 0, 3).reshape(b, c, -1).astype(dtype)
+
+    lo = head
+    while lo < t:
+        hi = t if lo == 0 else min(2 * lo, t)
+        o = lax.map(lambda s, hi=hi: chunk_out(s, hi),
+                    jnp.arange(lo, hi, c))                    # (n, b, c, h*dv)
+        outs.append(o.transpose(1, 0, 2, 3).reshape(b, hi - lo, -1))
+        lo = hi
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 class LayerNorm(Op):
     """Layer normalization over the last (feature) dim."""
 
@@ -546,77 +627,19 @@ class MultiHeadAttention(Op):
             return self.select.project(params, x, index)
 
     def _attend_selected(self, params, q, kh, vh, pos, index, dtype, dense):
-        """Causal attention of queries ``q`` (b, t, h * hd) over (b, h_kv,
-        t, hd) keys and values starting at position 0, each query row
-        over the positions the selector keeps for it.  The keys come
-        placed; the queries as projected, and are split into heads and
-        placed (``_place_heads`` at ``pos``) a chunk at a time (all 32
-        heads of a 32k prefill are 256 MB transposed and 512 MB in
-        f32).  Rows under ``topk`` keep
-        their whole past: whole chunks of them go to ``dense`` (the
-        op's own causal path).  The rest run a chunk of
-        ``select.q_chunk`` query rows at a time: the chunk's selector
-        scores against the keys up to its end, its rows' ``topk``-th
-        largest as the threshold, and masked attention a query head at
-        a time (a head's scores of one chunk against 32k keys are 64 MB
-        in f32; all heads' at once would be 2 GB).  Chunks are grouped
-        by where they end into doubling key widths so that each width
-        is one compiled loop and a chunk pays for at most twice the
-        keys it can see.  Masked pairs are computed and thrown away
-        (ROADMAP B-M1: a prefill that does not pay for them)."""
-        sel = self.select
-        iq, ik, iw = index
-        b, t, _ = q.shape
-        h, hd = self.attrs["num_heads"], self.attrs["head_dim"]
-
+        """``_attend_selected`` over queries ``q`` (b, t, h * hd) as
+        projected: split into heads and placed (``_place_heads`` at
+        ``pos``) a chunk at a time (all 32 heads of a 32k prefill are
+        256 MB transposed and 512 MB in f32)."""
         def placed_q(start, n):
             return self._place_heads(
                 self._split_heads(lax.dynamic_slice_in_dim(q, start, n, axis=1)),
                 params.get("q_norm"),
                 lax.dynamic_slice_in_dim(pos, start, n, axis=1))
 
-        if t <= sel.topk:
-            return dense(placed_q(0, t), kh, vh, dtype)
-        c = t if t % sel.q_chunk else sel.q_chunk   # one chunk: odd sizes
-        head = (sel.topk // c) * c
-        outs = []
-        if head:
-            outs.append(dense(placed_q(0, head), kh[:, :, :head],
-                              vh[:, :, :head], dtype))
-        scale = 1.0 / math.sqrt(hd)
-        g = self.group
-
-        def chunk_out(start, width):
-            rows = start + jnp.arange(c)
-            with jax.named_scope("ff_index"):
-                scores = sel.scores(
-                    lax.dynamic_slice_in_dim(iq, start, c, axis=1),
-                    lax.dynamic_slice_in_dim(iw, start, c, axis=1),
-                    ik[:, :width])                               # (b, c, width)
-            with jax.named_scope("ff_select"):
-                keep = sel.keep(scores, rows)
-            qc = placed_q(start, c)                               # (b, h, c, hd)
-
-            def one_head(j):
-                q = qc[:, j]
-                k, v = kh[:, j // g, :width], vh[:, j // g, :width]
-                s = jnp.einsum("bqd,bsd->bqs", q, k,
-                               preferred_element_type=jnp.float32) * scale
-                p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1)
-                return jnp.einsum("bqs,bsd->bqd", p.astype(v.dtype), v,
-                                  preferred_element_type=jnp.float32)
-
-            o = lax.map(one_head, jnp.arange(h))                  # (h, b, c, hd)
-            return o.transpose(1, 2, 0, 3).reshape(b, c, h * hd).astype(dtype)
-
-        lo = head
-        while lo < t:
-            hi = t if lo == 0 else min(2 * lo, t)
-            o = lax.map(lambda s, hi=hi: chunk_out(s, hi),
-                        jnp.arange(lo, hi, c))                    # (n, b, c, h*hd)
-            outs.append(o.transpose(1, 0, 2, 3).reshape(b, hi - lo, h * hd))
-            lo = hi
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        return _attend_selected(
+            self.select, q.shape[1], placed_q, kh, vh, index,
+            1.0 / math.sqrt(self.attrs["head_dim"]), dense, dtype)
 
     def _forward_selected(self, params, x, state):
         """The cached forward of an op with a selector: caches ``k`` and
@@ -1326,6 +1349,28 @@ class LatentAttention(Op):
       cache holds ``[c_j | k_r,j]``: ``kv_rank + rope`` values a token,
       shared by every head.
 
+    Two more arguments, each absent by default (the op then declares the
+    cache and lowers to the programs it always did).  ``gate="per_head"``:
+    the attended values of a head times ``sigmoid(x W_g)``'s value for
+    it, before the output projection.  ``select`` (``indexer_num_heads``,
+    ``indexer_head_dim``, ``topk``): the learned token selector of
+    ``ops/token_select.py``, DeepSeek-V3.2's reading of it: its query
+    from the normed compressed query (``q_rank``), its rotary part the
+    leading ``rope_dim`` of the selector's head at the layer's own
+    frequencies.  The op then keeps a second cache entry (the selector's
+    keys) and declares the latent cache positions-major, a position's
+    ``kv_rank + rope`` values one row, filled up with zeros to whole
+    128-lane tiles (576 -> 640: the chip stores a row that is not whole
+    tiles positions-last whatever the declared order, and a decode
+    superstep would relayout every layer's cache on its way in and out):
+    a decode step scores the slot's
+    keys, picks ``topk`` positions, gathers those rows (one gather
+    serves every head) and runs the absorbed attention over them alone;
+    a prefill runs the expanded attention with row ``t`` over its
+    selected set (``_attend_selected``).  Padded, on one device: the
+    paged pool, the offset prefill and a mesh raise
+    ``NotImplementedError`` (ROADMAP B-M1).
+
     Strategy axes: ``c`` shards heads (the q, kv-expansion and output
     projections carry the tag on their head dim); ``n`` the batch.
     """
@@ -1337,19 +1382,35 @@ class LatentAttention(Op):
                  rope_theta: float = 10000.0, norm_eps: float = 1e-6,
                  q_rank: Optional[int] = None,
                  rope_scaling: Optional[dict] = None,
+                 gate: Optional[str] = None,
+                 select: Optional[dict] = None,
                  kernel_initializer=None):
         super().__init__(name, [x])
         assert x.ndim == 3, f"attention input must be (batch, seq, dim), got {x.shape}"
         assert rope_dim % 2 == 0, rope_dim
+        if gate not in (None, "per_head"):
+            raise ValueError(f"{name}: gate={gate!r} (only 'per_head')")
         self.attrs = dict(num_heads=num_heads, kv_rank=kv_rank,
                           nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
                           rope_theta=float(rope_theta), norm_eps=norm_eps,
                           q_rank=q_rank, rope_scaling=rope_scaling,
-                          causal=True)
+                          gate=gate, select=select, causal=True)
         #: What the scores are multiplied by before the softmax.
         self.scale = 1.0 / math.sqrt(nope_dim + rope_dim)
+        inv, wave, soft = rope_frequencies(rope_dim, rope_theta, rope_scaling)
         if rope_scaling is not None:
-            self.scale *= rope_frequencies(rope_dim, rope_theta, rope_scaling)[2]
+            self.scale *= soft
+        #: The token selector composed into this op, if any.
+        self.select = None
+        if select is not None:
+            if wave != 1.0 or int(select["indexer_head_dim"]) < rope_dim:
+                raise ValueError(
+                    f"{name}: a token selector whose head is narrower than "
+                    f"the rotary part ({rope_dim}), or under a rope scaling "
+                    f"whose cos and sin scale ({wave!r}), is not built")
+            self.select = TokenSelector(
+                select, theta=rope_theta, eps=norm_eps, query_dim=q_rank,
+                rotary_dim=rope_dim, inv=inv)
         self.kernel_initializer = kernel_initializer or GlorotUniform()
         self._make_output(x.shape, x.dtype, x.dim_axes)
 
@@ -1368,7 +1429,7 @@ class LatentAttention(Op):
             "q_norm": ParamSpec((qr,), dt, OnesInitializer()),
             "wq_b": ParamSpec((qr, qw), dt, ki, (None, "c")),
         }
-        return {
+        specs = {
             **query,
             "wkv_a": ParamSpec((d, r + a["rope_dim"]), dt, ki),
             "kv_norm": ParamSpec((r,), dt, OnesInitializer()),
@@ -1376,20 +1437,39 @@ class LatentAttention(Op):
                                (None, "c")),
             "wo": ParamSpec((h * a["v_dim"], d), dt, ki, ("c", None)),
         }
+        if a["gate"]:
+            specs["wg"] = ParamSpec((d, h), dt, ki, (None, "c"))
+        if self.select is not None:
+            specs.update(self.select.param_specs(d, dt, ki))
+        return specs
 
     @property
     def row_width(self) -> int:
         return self.attrs["kv_rank"] + self.attrs["rope_dim"]
 
+    @property
+    def row_pad(self) -> int:
+        """Zeros behind a position's row in the positions-major cache."""
+        return -self.row_width % 128 if self.select is not None else 0
+
     def cache_entries(self, max_seq: int) -> Dict[str, CacheEntry]:
+        dt = self.outputs[0].dtype
+        if self.select is not None:
+            # A position a row: the row a decode step gathers.  The
+            # selector's keys beside it.
+            return {"ckr": CacheEntry((max_seq, self.row_width + self.row_pad), dt),
+                    TokenSelector.ENTRY: self.select.cache_entry(max_seq, dt)}
         # One column a token, positions last: see pallas_kernels.mla_decode.
-        return {"ckr": CacheEntry((self.row_width, max_seq),
-                                  self.outputs[0].dtype)}
+        return {"ckr": CacheEntry((self.row_width, max_seq), dt)}
 
     def serving_path(self, decode: bool) -> str:
-        return "latent_absorbed" if decode else "latent_expanded"
+        kind = "latent" if self.select is None else "latent_select"
+        return f"{kind}_absorbed" if decode else f"{kind}_expanded"
 
     def decode_fetch_block(self, slots, max_seq, kernel, c=1):
+        if self.select is not None:
+            # Rows, not blocks: the gather fetches the chosen positions.
+            return 1
         shape = (slots, self.row_width, max_seq)
         if kernel is not False and pallas_kernels.mla_decode_supported(
                 shape, self.attrs["kv_rank"]):
@@ -1398,34 +1478,62 @@ class LatentAttention(Op):
 
     # -- shared pieces -------------------------------------------------------
 
+    def _query_source(self, params, x):
+        """What the queries are projected from: the normed compressed
+        query (b, t, q_rank), or ``x`` where the op has none."""
+        if self.attrs["q_rank"]:
+            return rms_norm(x @ params["wq_a"], params["q_norm"],
+                            self.attrs["norm_eps"])
+        return x
+
+    def _query_heads(self, params, src):
+        """``(q_nope, q_rope)`` (b, t, h, .) of ``_query_source``'s rows,
+        the rotary part not yet turned."""
+        a = self.attrs
+        b, t, _ = src.shape
+        q = src @ params["wq_b" if a["q_rank"] else "wq"]
+        q = q.reshape(b, t, a["num_heads"], a["nope_dim"] + a["rope_dim"])
+        return q[..., :a["nope_dim"]], q[..., a["nope_dim"]:]
+
+    def _turn_queries(self, q_rope, pos):
+        return self._rope(
+            q_rope.transpose(0, 2, 1, 3), pos[:, None, :]
+        ).transpose(0, 2, 1, 3)
+
+    def _key_latent(self, params, x, pos):
+        """``(c, k_r)``: the normalised latent (b, t, kv_rank) and the
+        shared rotary key (b, t, rope) turned to ``pos`` (b, t)."""
+        r = self.attrs["kv_rank"]
+        ckr = x @ params["wkv_a"]
+        c = rms_norm(ckr[..., :r], params["kv_norm"], self.attrs["norm_eps"])
+        return c, self._rope(ckr[..., r:], pos)
+
     def _latent(self, params, x, pos):
         """``(q_nope, q_rope, c, k_r)``: queries a head (b, t, h, .),
         rotary parts turned to their positions ``pos`` (b, t); the
         normalised latent (b, t, kv_rank) and the shared rotary key
         (b, t, rope)."""
-        a = self.attrs
-        b, t, _ = x.shape
-        h, r = a["num_heads"], a["kv_rank"]
-        if a["q_rank"]:
-            q = rms_norm(x @ params["wq_a"], params["q_norm"],
-                         a["norm_eps"]) @ params["wq_b"]
-        else:
-            q = x @ params["wq"]
-        q = q.reshape(b, t, h, a["nope_dim"] + a["rope_dim"])
-        q_nope, q_rope = q[..., :a["nope_dim"]], q[..., a["nope_dim"]:]
-        ckr = x @ params["wkv_a"]
-        c = rms_norm(ckr[..., :r], params["kv_norm"], a["norm_eps"])
-        k_r = self._rope(ckr[..., r:], pos)
-        q_rope = self._rope(
-            q_rope.transpose(0, 2, 1, 3), pos[:, None, :]
-        ).transpose(0, 2, 1, 3)
-        return q_nope, q_rope, c, k_r
+        q_nope, q_rope = self._query_heads(params,
+                                           self._query_source(params, x))
+        c, k_r = self._key_latent(params, x, pos)
+        return q_nope, self._turn_queries(q_rope, pos), c, k_r
 
     def _rope(self, x, pos):
         a = self.attrs
         inv, wave, _ = rope_frequencies(x.shape[-1], a["rope_theta"],
                                         a["rope_scaling"])
         return rope_interleaved(x, pos, inv, wave)
+
+    def _causal(self, q, k, v, serving: bool):
+        """Causal attention on heads (b, h, t, .); (b, t, h * v_dim)."""
+        plan = getattr(self, "_plan", None)
+        if serving and (plan is None or plan.num_devices == 1) and \
+                pallas_kernels.flash_uneven_supported(q.shape, self.attrs["v_dim"]):
+            out = pallas_kernels.flash_fwd_uneven(q, k, v, self.scale)
+        else:
+            out = _einsum_attention(q, k, v, True, self.scale)
+        b, _, t, _ = q.shape
+        return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
     def _expanded(self, params, q_nope, q_rope, c, k_r, serving: bool):
         """Causal attention with K and V expanded a head; (b, t, h*v)."""
@@ -1438,14 +1546,17 @@ class LatentAttention(Op):
             [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, a["rope_dim"]))],
             axis=-1,
         ).transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        plan = getattr(self, "_plan", None)
-        if serving and (plan is None or plan.num_devices == 1) and \
-                pallas_kernels.flash_uneven_supported(q.shape, a["v_dim"]):
-            out = pallas_kernels.flash_fwd_uneven(q, k, v, self.scale)
-        else:
-            out = _einsum_attention(q, k, v, True, self.scale)
-        return out.transpose(0, 2, 1, 3).reshape(b, t, h * a["v_dim"])
+        return self._causal(q, k, v.transpose(0, 2, 1, 3), serving)
+
+    def _gate_logits(self, params, x):
+        return x @ params["wg"] if self.attrs["gate"] else None
+
+    def _output(self, params, out, gate):
+        """The output gate (``_gate_logits``, where the op has one) and
+        projection."""
+        if gate is not None:
+            out = _gate_heads(out, gate, self.attrs["v_dim"])
+        return out @ params["wo"]
 
     def forward(self, params, xs, state, training):
         (x,) = xs
@@ -1453,9 +1564,11 @@ class LatentAttention(Op):
             return self._forward_cached(params, x, state)
         b, t, _ = x.shape
         pos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+        if self.select is not None:
+            return [self._prefill_selected(params, x, pos, serving=False)[0]], state
         out = self._expanded(params, *self._latent(params, x, pos),
                              serving=False)
-        return [out @ params["wo"]], state
+        return [self._output(params, out, self._gate_logits(params, x))], state
 
     # -- the latent cache (runtime/serving.py) -------------------------------
 
@@ -1464,6 +1577,8 @@ class LatentAttention(Op):
         tokens, writing post-norm ``c`` and post-RoPE ``k_r`` into cache
         columns ``0..t-1``.  Decode (t == 1): the token at ``pos``
         writes its column and attends columns ``<= pos`` absorbed."""
+        if self.select is not None:
+            return self._forward_selected(params, x, state)
         a = self.attrs
         cache = state["cache_ckr"]                      # (B, row, S)
         b, t, _ = x.shape
@@ -1480,14 +1595,12 @@ class LatentAttention(Op):
             new_state["cache_ckr"] = cache.at[:, :, :t].set(
                 col.transpose(0, 2, 1))
             out = self._expanded(params, q_nope, q_rope, c, k_r, serving=True)
-            return [out @ params["wo"]], new_state
+            return [self._output(params, out, self._gate_logits(params, x))], \
+                new_state
         pos = state["pos"]                              # (B,)
         q_nope, q_rope, c, k_r = self._latent(params, x, pos[:, None])
         col = jnp.concatenate([c, k_r], axis=-1)[:, 0].astype(cache.dtype)
-        wkv_b = params["wkv_b"].reshape(r, h, a["nope_dim"] + a["v_dim"])
-        w_k, w_v = wkv_b[..., :a["nope_dim"]], wkv_b[..., a["nope_dim"]:]
-        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k)
-        q_cat = jnp.concatenate([q_lat.astype(x.dtype), q_rope[:, 0]], axis=-1)
+        q_cat, w_v = self._absorb(params, q_nope, q_rope, x.dtype)
         use = self.decode_kernel
         supported = pallas_kernels.mla_decode_supported(cache.shape, r)
         if use is None or (use and not supported):
@@ -1507,7 +1620,147 @@ class LatentAttention(Op):
             o_lat = _latent_decode(q_cat, cache, pos, r, self.scale)
         new_state["cache_ckr"] = cache
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
-        return [o.reshape(b, 1, h * a["v_dim"]) @ params["wo"]], new_state
+        return [self._output(params, o.reshape(b, 1, h * a["v_dim"]),
+                             self._gate_logits(params, x))], new_state
+
+    def _absorb(self, params, q_nope, q_rope, dtype):
+        """A decode step's ``(q_cat (B, h, kv_rank + rope), w_v (kv_rank,
+        h, v_dim))``: ``W_kvb``'s key half folded into the query, its
+        value half for the output."""
+        a = self.attrs
+        wkv_b = params["wkv_b"].reshape(a["kv_rank"], a["num_heads"],
+                                        a["nope_dim"] + a["v_dim"])
+        w_k, w_v = wkv_b[..., :a["nope_dim"]], wkv_b[..., a["nope_dim"]:]
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k)
+        return jnp.concatenate([q_lat.astype(dtype), q_rope[:, 0]], axis=-1), w_v
+
+    # -- a token selector over the latent cache (PR 48) ------------------------
+
+    def _index(self, params, x, src, pos):
+        """The selector's ``(q, k, w)`` of this call's tokens at ``pos``
+        (b, t): the query from ``src`` (``_query_source``'s rows)."""
+        with jax.named_scope("ff_index"):
+            return self.select.project(params, x, pos, q_from=src)
+
+    def _prefill_selected(self, params, x, pos, serving: bool):
+        """The expanded attention of the tokens ``x`` at ``pos`` = ``0..
+        t-1``, each row over its selected set; ``(out (b, t, h * v_dim),
+        rows (b, t, .) for the latent cache, the selector's keys (b, t,
+        .))``.  Sized for a 32k prefill at 64 heads beside the weights:
+        the attention's and the selector's queries are made from the
+        compressed query a chunk at a time (805 and 512 MB whole), the
+        heads' keys hold their own ``nope`` part alone and the rotary key
+        is scored once for all of them (K whole is 1 GB, its product with
+        ``W_kvb`` in one piece another), and the gate's logits are taken
+        before the attention so that the layer's input can go."""
+        a, sel = self.attrs, self.select
+        h, r, nope = a["num_heads"], a["kv_rank"], a["nope_dim"]
+        src = self._query_source(params, x)
+        c, k_r = self._key_latent(params, x, pos)
+        with jax.named_scope("ff_index"):
+            ik, iw = sel.keys(params, x, pos)
+        gate = self._gate_logits(params, x)
+        wkv_b = params["wkv_b"].reshape(r, h, nope + a["v_dim"])
+        k_nope = jnp.einsum("btr,rhn->bhtn", c, wkv_b[..., :nope])
+        v = jnp.einsum("btr,rhv->bhtv", c, wkv_b[..., nope:])
+
+        def index_q(start, n):
+            with jax.named_scope("ff_index"):
+                return sel.queries(
+                    params, lax.dynamic_slice_in_dim(src, start, n, axis=1),
+                    lax.dynamic_slice_in_dim(pos, start, n, axis=1))
+
+        def placed_q(start, n):
+            q_nope, q_rope = self._query_heads(
+                params, lax.dynamic_slice_in_dim(src, start, n, axis=1))
+            q_rope = self._turn_queries(
+                q_rope, lax.dynamic_slice_in_dim(pos, start, n, axis=1))
+            return jnp.concatenate([q_nope, q_rope],
+                                   axis=-1).transpose(0, 2, 1, 3)
+
+        def dense(q, k, v, dtype):
+            n = k.shape[2]
+            whole = jnp.concatenate([k, jnp.broadcast_to(
+                k_r[:, None, :n], k.shape[:3] + (a["rope_dim"],))], axis=-1)
+            return self._causal(q, whole, v, serving).astype(dtype)
+
+        out = _attend_selected(sel, x.shape[1], placed_q, k_nope, v,
+                               (index_q, ik, iw), self.scale, dense, x.dtype,
+                               shared_k=k_r)
+        return self._output(params, out, gate), self._cache_rows(c, k_r), ik
+
+    def _cache_rows(self, c, k_r):
+        """``[c | k_r | zeros]`` (b, t, the cache's row)."""
+        pad = jnp.zeros(c.shape[:-1] + (self.row_pad,), c.dtype)
+        return jnp.concatenate([c, k_r, pad], axis=-1)
+
+    def _forward_selected(self, params, x, state):
+        """The cached forward of an op with a selector: caches ``ckr``
+        (B, S, kv_rank + rope + row_pad), a position a row, and ``idx`` (B, S,
+        selector head), the selector's keys.  Prefill (t > 1): both
+        written at rows ``0..t-1`` and ``_prefill_selected``.  Decode
+        (t == 1): the token at ``pos`` writes its two rows (a slice
+        update a slot, in place), the selector scores every row of its
+        small cache, keeps ``topk`` among the live ones, and the latent
+        rows of those positions alone are gathered and attended,
+        absorbed."""
+        a, sel, plan = self.attrs, self.select, getattr(self, "_plan", None)
+        if "block_table" in state or "chunk" in state or \
+                (plan is not None and plan.num_devices > 1):
+            raise NotImplementedError(
+                f"{self.name}: selection over a paged pool, an offset "
+                f"prefill or a sharded cache is not built (ROADMAP B-M1)")
+        cache, ci = state["cache_ckr"], state[f"cache_{sel.ENTRY}"]
+        b, t, _ = x.shape
+        new_state = dict(state)
+        if t > 1:
+            pos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+            y, rows, ik = self._prefill_selected(params, x, pos, serving=True)
+            cache = cache.at[:, :t].set(rows.astype(cache.dtype))
+            ci = ci.at[:, :t].set(ik.astype(ci.dtype))
+        else:
+            at = state["pos"]                           # (B,)
+            pos = at[:, None]
+            src = self._query_source(params, x)
+            q_nope, q_rope = self._query_heads(params, src)
+            c, k_r = self._key_latent(params, x, pos)
+            q_rope = self._turn_queries(q_rope, pos)
+            iq, ik, iw = self._index(params, x, src, pos)
+            row = self._cache_rows(c, k_r)
+            for i in range(b):
+                cache, ci = (
+                    lax.dynamic_update_slice(e, new[i][None].astype(e.dtype),
+                                             (i, at[i], 0))
+                    for e, new in ((cache, row), (ci, ik)))
+            with jax.named_scope("ff_index"):
+                scores = sel.scores(iq, iw, ci)[:, 0]             # (B, S)
+            with jax.named_scope("ff_select"):
+                idx, valid = sel.pick(scores, at)
+                rows = jnp.take_along_axis(cache, idx[:, :, None], axis=1)
+            q_cat, w_v = self._absorb(params, q_nope, q_rope, x.dtype)
+            # Zeros against the rows' zeros, so that the product runs
+            # over whole lane tiles and no row is cut.
+            q_cat = jnp.pad(q_cat, ((0, 0), (0, 0), (0, self.row_pad)))
+            o_lat = _latent_decode_rows(q_cat, rows, valid, a["kv_rank"],
+                                        self.scale)
+            out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v).reshape(b, 1, -1)
+            y = self._output(params, out, self._gate_logits(params, x))
+        new_state["cache_ckr"] = cache
+        new_state[f"cache_{sel.ENTRY}"] = ci
+        return [y], new_state
+
+
+def _latent_decode_rows(q_cat, rows, valid, v_width: int, scale: float):
+    """The absorbed attention of one query a head ``q_cat`` (B, h, row)
+    over a slot's gathered latent rows ``rows`` (B, k, row), the
+    ``valid`` (B, k) ones: f32 scores and softmax, the products in the
+    operands' dtype.  (B, h, v_width)."""
+    s = jnp.einsum("bhr,bkr->bhk", q_cat, rows,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, _NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkv->bhv", p.astype(rows.dtype),
+                      rows[..., :v_width],
+                      preferred_element_type=jnp.float32).astype(q_cat.dtype)
 
 
 def _latent_decode(q_cat, cache, pos, v_width: int, scale: float):

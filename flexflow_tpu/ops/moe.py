@@ -110,6 +110,8 @@ class MixtureOfExperts(Op):
         selection_bias: bool = False,
         norm_topk_prob: bool = True,
         routed_scale: float = 1.0,
+        n_group: int = 1,
+        topk_group: int = 1,
     ):
         super().__init__(name, [x])
         if dispatch not in ("capacity", "sorted"):
@@ -119,10 +121,19 @@ class MixtureOfExperts(Op):
         if dispatch == "capacity" and (
                 router != "softmax" or gated or shared_experts
                 or held_experts is not None or selection_bias
-                or routed_scale != 1.0):
+                or routed_scale != 1.0 or n_group != 1):
             raise ValueError(
                 f"moe {name}: sigmoid routing, gated or shared experts, a "
-                f"selection bias and held_experts need dispatch='sorted'")
+                f"selection bias, expert groups and held_experts need "
+                f"dispatch='sorted'")
+        if n_group < 1 or num_experts % n_group or \
+                not 1 <= topk_group <= n_group or \
+                (n_group > 1 and (num_experts // n_group < 2
+                                  or top_k > topk_group * (num_experts // n_group))):
+            raise ValueError(
+                f"moe {name}: n_group={n_group!r}, topk_group={topk_group!r}: "
+                f"groups of at least two experts that divide {num_experts}, "
+                f"and the kept ones hold the {top_k} a token chooses")
         held = tuple(range(num_experts)) if held_experts is None else \
             tuple(sorted(int(e) for e in held_experts))
         if not held or len(set(held)) != len(held) or \
@@ -169,6 +180,8 @@ class MixtureOfExperts(Op):
             selection_bias=bool(selection_bias),
             norm_topk_prob=bool(norm_topk_prob),
             routed_scale=float(routed_scale),
+            n_group=int(n_group),
+            topk_group=int(topk_group),
         )
         self.d_model = d
         self.kernel_initializer = kernel_initializer or GlorotUniform()
@@ -317,18 +330,35 @@ class MixtureOfExperts(Op):
         """``(idx (T, k) int32, w (T, k) f32)``: the experts a token
         chooses, over the router's full width, and the weights of
         their outputs.  All in f32, the product at full precision (a
-        bf16 product flips choices between near-equal scores)."""
+        bf16 product flips choices between near-equal scores).  Under
+        ``n_group`` groups (DeepSeek-V3's ``noaux_tc``) a group stands by
+        the sum of its two largest ``score + bias``, the ``topk_group``
+        best groups stay (the lower index among equals) and the top-k
+        is taken among their experts alone."""
         a = self.attrs
         logits = jnp.dot(xf.astype(jnp.float32), params["gate"],
                          precision=jax.lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits) if a["router"] == "sigmoid" \
             else jax.nn.softmax(logits, axis=-1)
         choice = scores + params["e_bias"] if a["selection_bias"] else scores
+        if a["n_group"] > 1:
+            with jax.named_scope("ff_route_group"):
+                choice = self._kept_groups(choice)
         _, idx = jax.lax.top_k(choice, a["top_k"])
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if a["top_k"] > 1 and a["norm_topk_prob"]:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx, w * a["routed_scale"]
+
+    def _kept_groups(self, choice):
+        """``choice`` (T, e) with the experts of every group but the
+        ``topk_group`` best at ``-inf``."""
+        g, keep = self.attrs["n_group"], self.attrs["topk_group"]
+        by_group = choice.reshape(choice.shape[0], g, -1)
+        standing = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)      # (T, g)
+        _, best = jax.lax.top_k(standing, keep)                         # (T, keep)
+        kept = jnp.any(best[:, :, None] == jnp.arange(g)[None, None, :], axis=1)
+        return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(choice.shape)
 
     def _mlp(self, x, p, names, product, fused_gate=None):
         """One (gated) MLP over the leaves ``p[names]`` (``(in, out)``,
@@ -363,7 +393,15 @@ class MixtureOfExperts(Op):
         margin, in a prefill-sized segment (whole 128-row tiles an
         expert; a decode step's rows are tile padding, not assignments:
         the rule ``grouped_tile_rows`` draws).  None where every
-        assignment gets a row."""
+        assignment gets a row.  Expert groups leave the share where it
+        is: a uniform router keeps a held expert's group ``topk_group /
+        n_group`` of the time and then puts ``n_group / topk_group``
+        times its uniform share of a token's choices there, ``top_k x
+        held / routed`` either way (8 x 16 / 256 = 0.5 a token at 16
+        held of 256 in 8 groups of which 4 stay).  What grows is a
+        token's spread, a whole group in or out (variance 0.66 a token
+        against 0.46 there): 37 assignments over a segment of 2048
+        tokens, against the margin's 512."""
         eh = len(self.held)
         bound = math.ceil(self.HELD_ROWS_MARGIN * assignments * eh
                           / self.attrs["num_experts"])

@@ -16,7 +16,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from flexflow_tpu.initializers import OnesInitializer, ZeroInitializer
+from flexflow_tpu.initializers import GlorotUniform, OnesInitializer, ZeroInitializer
 from flexflow_tpu.ops.activations import apply_activation
 from flexflow_tpu.ops.base import Op, ParamSpec, TensorSpec
 
@@ -80,20 +80,40 @@ class BatchNorm(Op):
 
 class RMSNorm(Op):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last dim, in f32
-    (no mean, no bias: the norm of the Llama/DeepSeek block family)."""
+    (no mean, no bias: the norm of the Llama/DeepSeek block family).
 
-    def __init__(self, name: str, x: TensorSpec, eps: float = 1e-6):
+    ``gate_rank`` (r > 0) makes it a gated norm: with ``n`` the norm's
+    output, ``n * sigmoid((n W_down) W_up)``, ``W_down`` (d, r) and
+    ``W_up`` (r, d) with nothing between them; the two maps in the
+    compute dtype with f32 accumulation, the sigmoid in f32, the product
+    in the compute dtype.  Under the scope ``ff_gnorm``."""
+
+    def __init__(self, name: str, x: TensorSpec, eps: float = 1e-6,
+                 gate_rank: int = 0, kernel_initializer=None):
         super().__init__(name, [x])
-        self.attrs = dict(eps=eps)
+        self.attrs = dict(eps=eps, gate_rank=int(gate_rank))
+        self.kernel_initializer = kernel_initializer or GlorotUniform()
         self._make_output(x.shape, x.dtype, x.dim_axes)
 
     def param_specs(self) -> Dict[str, ParamSpec]:
-        d = self.inputs[0].shape[-1]
-        return {"scale": ParamSpec((d,), self.outputs[0].dtype, OnesInitializer())}
+        d, dt = self.inputs[0].shape[-1], self.outputs[0].dtype
+        specs = {"scale": ParamSpec((d,), dt, OnesInitializer())}
+        r = self.attrs["gate_rank"]
+        if r:
+            specs["w_down"] = ParamSpec((d, r), dt, self.kernel_initializer)
+            specs["w_up"] = ParamSpec((r, d), dt, self.kernel_initializer)
+        return specs
 
     def forward(self, params, xs, state, training):
         (x,) = xs
-        return [rms_norm(x, params["scale"], self.attrs["eps"])], state
+        n = rms_norm(x, params["scale"], self.attrs["eps"])
+        if self.attrs["gate_rank"]:
+            with jax.named_scope("ff_gnorm"):
+                low = n @ params["w_down"]
+                logits = jnp.dot(low, params["w_up"],
+                                 preferred_element_type=jnp.float32)
+                n = n * jax.nn.sigmoid(logits).astype(n.dtype)
+        return [n], state
 
 
 def rms_norm(x, scale, eps: float):

@@ -2529,6 +2529,20 @@ def _mla_decode_call(q, col, cache, lengths, v_width, scale, interpret):
 #: buffered, are 12.6 MB at K=2048, N=768 in bf16 — past the 16 MB a
 #: kernel gets by default once the row tiles are added (v5e has 128 MiB).
 _GMM_VMEM_BYTES = 64 << 20
+#: Of it, what the double-buffered expert blocks may take (41.9 MB at
+#: K=4096, N=1280 gated): wider experts are walked in blocks of columns
+#: (117 MB whole at K=7168, N=2048 gated).
+_GMM_WEIGHT_BYTES = 48 << 20
+
+
+def grouped_block_cols(k: int, n: int, weights: int, itemsize: int) -> int:
+    """Columns of the expert blocks one grid step of ``grouped_matmul``
+    holds: all ``n`` where ``weights`` double-buffered (k, n) blocks fit
+    ``_GMM_WEIGHT_BYTES``, else the widest whole-lane-tile divisor of
+    ``n`` that does."""
+    fits = [c for c in range(_LANES, n + 1, _LANES) if n % c == 0
+            and 2 * weights * k * c * itemsize <= _GMM_WEIGHT_BYTES]
+    return max(fits) if fits else _LANES
 
 
 def grouped_matmul_supported(k: int, n: int, dtype) -> bool:
@@ -2545,11 +2559,11 @@ def grouped_tile_rows(assignments: int, experts: int) -> int:
     return 128 if assignments >= 128 * experts else 16
 
 
-def _gmm_kernel(te_ref, nu_ref, x_ref, *refs, gated):
+def _gmm_kernel(te_ref, nu_ref, x_ref, *refs, gated, tile_axis):
     del te_ref
     o_ref = refs[-1]
 
-    @pl.when(pl.program_id(0) < nu_ref[0])
+    @pl.when(pl.program_id(tile_axis) < nu_ref[0])
     def _tile():
         x = x_ref[...]
         exact = _mxu_precision(x.dtype)
@@ -2575,6 +2589,9 @@ def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int,
     ``tiles_used`` repeating the last used tile's expert, so that an
     unused tile moves nothing: an expert's block is fetched once for
     each run of its tiles, i.e. once a call for every touched expert.
+    Experts too wide for VMEM (``grouped_block_cols``) are walked a block
+    of columns at a time, the tiles inside: every touched expert's
+    columns still move once a call, the row tiles once a block.
     Forward only.  Callers gate on :func:`grouped_matmul_supported`."""
     if interpret is None:
         interpret = _interpret_default()
@@ -2582,23 +2599,32 @@ def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int,
     n = w.shape[-1]
     n_tiles = rows // tile_rows
     gated = w_up is not None
-
-    def row_map(i, te, nu):
-        return (jnp.minimum(i, jnp.maximum(nu[0] - 1, 0)), 0)
-
-    def w_map(i, te, nu):
-        return (te[i], 0, 0)
-
     weights = (w, w_up) if gated else (w,)
+    cols = grouped_block_cols(k, n, len(weights), w.dtype.itemsize)
+
+    def last_used(i, nu):
+        return jnp.minimum(i, jnp.maximum(nu[0] - 1, 0))
+
+    if cols == n:
+        grid = (n_tiles,)
+        row_map = lambda i, te, nu: (last_used(i, nu), 0)
+        w_map = lambda i, te, nu: (te[i], 0, 0)
+        out_map = row_map
+    else:
+        grid = (n // cols, n_tiles)
+        row_map = lambda j, i, te, nu: (last_used(i, nu), 0)
+        w_map = lambda j, i, te, nu: (te[i], 0, j)
+        out_map = lambda j, i, te, nu: (last_used(i, nu), j)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_tiles,),
+        grid=grid,
         in_specs=[pl.BlockSpec((tile_rows, k), row_map)]
-        + [pl.BlockSpec((1, k, n), w_map) for _ in weights],
-        out_specs=pl.BlockSpec((tile_rows, n), row_map),
+        + [pl.BlockSpec((1, k, cols), w_map) for _ in weights],
+        out_specs=pl.BlockSpec((tile_rows, cols), out_map),
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, gated=gated),
+        functools.partial(_gmm_kernel, gated=gated, tile_axis=len(grid) - 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=pltpu.CompilerParams(

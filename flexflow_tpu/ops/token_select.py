@@ -16,7 +16,13 @@ positions alone.  With ``a`` the attention layer's (normed) input,
     S_t   = the ``topk`` positions s <= t of largest I(t, s)  (all while t < topk)
 
 ``R'`` turns half-split pairs ``(i, i + head_dim/2)`` of the whole head
-by the token's index (``rope_half`` with one position component).
+by the token's index (``rope_half`` with one position component).  Two
+arguments move it to DeepSeek-V3.2's own reading over latent attention:
+``query_dim`` (the query is taken from another row than ``a``: the
+layer's normed compressed query, handed to ``project`` as ``q_from``)
+and ``rotary_dim`` with ``inv`` (the pairs ``(i, i + rotary_dim/2)`` of
+the leading ``rotary_dim`` turn, at the layer's own frequencies; the
+rest passes).
 Queries, keys and the key cache are the compute dtype; ``w``, the
 scores and the top-k are float32.  Ties go to the lower position
 (``lax.top_k``'s order), in a decode step and in a prefill alike.
@@ -123,13 +129,17 @@ class TokenSelector:
     attention op to compose.  ``config`` is a model's ``sa_config``
     (``indexer_num_heads``, ``indexer_head_dim``, ``topk``; one key head;
     ``q_chunk_size`` the query rows a prefill scores at a time, which
-    changes no result)."""
+    changes no result).  ``query_dim``: the width of the row the query
+    is projected from where that is not the layer's input;
+    ``rotary_dim`` / ``inv``: ``rope_half``'s, for q and k alike."""
 
     #: The cache entry's name inside the composing op (the parameters
     #: there carry the prefix ``idx_``).
     ENTRY = "idx"
 
-    def __init__(self, config: Dict[str, Any], theta: float, eps: float = 1e-6):
+    def __init__(self, config: Dict[str, Any], theta: float, eps: float = 1e-6,
+                 query_dim: Optional[int] = None,
+                 rotary_dim: Optional[int] = None, inv=None):
         if config.get("indexer_num_kv_heads", 1) != 1:
             raise ValueError(
                 f"token selector: indexer_num_kv_heads="
@@ -140,12 +150,15 @@ class TokenSelector:
         self.q_chunk = int(config.get("q_chunk_size", 512))
         assert self.head_dim % 2 == 0, self.head_dim
         self.theta, self.eps = float(theta), float(eps)
+        self.query_dim = query_dim
+        self.turn = dict(rotary_dim=rotary_dim, inv=inv)
         self.scale = 1.0 / math.sqrt(self.heads * self.head_dim)
 
     def param_specs(self, d: int, dtype, initializer) -> Dict[str, ParamSpec]:
         h, hd = self.heads, self.head_dim
         return {
-            "idx_wq": ParamSpec((d, h * hd), dtype, initializer),
+            "idx_wq": ParamSpec((self.query_dim or d, h * hd), dtype,
+                                initializer),
             "idx_wk": ParamSpec((d, hd), dtype, initializer),
             # Held and multiplied in f32, like a router.
             "idx_ww": ParamSpec((d, h), jnp.float32, initializer),
@@ -157,23 +170,33 @@ class TokenSelector:
         """The keys of every position, positions-major (a row a key)."""
         return CacheEntry((max_seq, self.head_dim), dtype)
 
-    def project(self, params, a, pos):
+    def project(self, params, a, pos, q_from=None):
         """``(q (b, t, heads, hd), k (b, t, hd), w (b, t, heads) f32)``
-        of the tokens ``a`` (b, t, d) at indices ``pos`` (b, t)."""
-        b, t, _ = a.shape
-        q = (a @ params["idx_wq"]).reshape(b, t, self.heads, self.head_dim)
-        q = rope_half(q.transpose(0, 2, 1, 3), pos[:, None],
-                      self.theta).transpose(0, 2, 1, 3)
+        of the tokens ``a`` (b, t, d) at indices ``pos`` (b, t); the
+        query from ``q_from`` (b, t, query_dim) where the selector has
+        one."""
+        q = self.queries(params, a if q_from is None else q_from, pos)
+        return (q, *self.keys(params, a, pos))
+
+    def queries(self, params, src, pos):
+        """``q`` (b, t, heads, hd) of the rows ``src`` at ``pos``."""
+        b, t, _ = src.shape
+        q = (src @ params["idx_wq"]).reshape(b, t, self.heads, self.head_dim)
+        return rope_half(q.transpose(0, 2, 1, 3), pos[:, None], self.theta,
+                         **self.turn).transpose(0, 2, 1, 3)
+
+    def keys(self, params, a, pos):
+        """``(k (b, t, hd), w (b, t, heads) f32)`` of the tokens ``a``."""
         kf = (a @ params["idx_wk"]).astype(jnp.float32)
         mean = jnp.mean(kf, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(kf - mean), axis=-1, keepdims=True)
         kf = (kf - mean) * lax.rsqrt(var + self.eps) \
             * params["idx_k_scale"].astype(jnp.float32) \
             + params["idx_k_bias"].astype(jnp.float32)
-        k = rope_half(kf.astype(a.dtype), pos, self.theta)
+        k = rope_half(kf.astype(a.dtype), pos, self.theta, **self.turn)
         w = jnp.dot(a.astype(jnp.float32), params["idx_ww"],
                     precision=lax.Precision.HIGHEST) * self.scale
-        return q, k, w
+        return k, w
 
     #: The f32 products of every head at once (``t x heads x s``) one
     #: call of ``scores`` may hold; past it the heads run one after

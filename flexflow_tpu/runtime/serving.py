@@ -119,6 +119,7 @@ from flexflow_tpu.graph import FFModel
 from flexflow_tpu.ops.attention import MultiHeadAttention, PositionEmbedding
 from flexflow_tpu.ops.base import SERVING_STATS_LARGEST
 from flexflow_tpu.ops.linear import Linear
+from flexflow_tpu.ops.tensor_ops import Add
 from flexflow_tpu.runtime import telemetry as _telemetry
 
 #: Bound on the fused decode superstep — THE training supersteps'
@@ -1093,6 +1094,16 @@ class ServingExecutor:
 
     # -- the forward walk ---------------------------------------------------
 
+    #: A residual stream wider than this is held where each add leaves
+    #: it.  XLA otherwise fuses the chain of adds into every consumer and
+    #: a block's norm then sums the table's rows and EVERY earlier
+    #: sublayer's output again, each kept alive to the end of the
+    #: program: eight 448 MiB arrays beside the weights at a 32k prefill
+    #: of hidden 7168 (PERF.md section 6 PR 48).  Under it (every other
+    #: served configuration's prefills, and every decode step) the
+    #: programs are what they were.
+    RESIDUAL_PIN_BYTES = 256 << 20
+
     def _forward(self, params, op_state, tokens, caches, pos,
                  block_table=None, skip=None, chunk=0, last=None,
                  stats=None, length=None):
@@ -1168,6 +1179,9 @@ class ServingExecutor:
             with jax.named_scope(op.name):
                 ys, s_new = op.forward(params.get(op.name, {}), xs, s,
                                        training=False)
+            if isinstance(op, Add) and \
+                    ys[0].size * ys[0].dtype.itemsize > self.RESIDUAL_PIN_BYTES:
+                ys = jax.lax.optimization_barrier(ys)
             if op.name in caches:
                 new_caches[op.name] = {
                     entry: s_new[f"cache_{entry}"]

@@ -295,6 +295,16 @@ CASES = {
         lambda: _grouped(37888, 40, 4096, 1280, 128, True),
     "grouped_matmul-down-prefill-37888x1280x4096":
         lambda: _grouped(37888, 40, 1280, 4096, 128, False),
+    # The axk2.serve.closed8.p8k-31k cell's expert products (16 held
+    # experts of 7168 x 2048: blocks of 512 and 3584 columns, PR 48).
+    "grouped_matmul-gated-decode-304x7168x2048":
+        lambda: _grouped(304, 16, 7168, 2048, 16, True),
+    "grouped_matmul-down-decode-304x2048x7168":
+        lambda: _grouped(304, 16, 2048, 7168, 16, False),
+    "grouped_matmul-gated-prefill-3584x7168x2048":
+        lambda: _grouped(3584, 16, 7168, 2048, 128, True),
+    "grouped_matmul-down-prefill-3584x2048x7168":
+        lambda: _grouped(3584, 16, 2048, 7168, 128, False),
     "gather_rows-1Mx64-1024ids":
         lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
@@ -773,6 +783,61 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     assert ins.count(kv) == outs.count(kv) == 2 * KEYE_VL2_SMOKE["num_hidden_layers"]
 
 
+def test_axk2_smoke_programs_compile_for_the_chip(monkeypatch):
+    """``chip_smoke.py``'s ``serve/axk2`` programs at the smoke preset's
+    widths, compiled for the described chip: the prefill holds the
+    streamed forward kernel (its leading ``index_topk`` rows) and the
+    grouped product beside the masked chunk, the decode superstep the
+    grouped product, a sort a layer (the top-k) and ONE row gather a layer
+    (the latent rows, for every head); both caches go from parameter to
+    result where they lie, a position a row of whole lane tiles."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import AXK2_SMOKE, build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    slots, seq = 4, 1024
+    topk, layers = AXK2_SMOKE["index_topk"], AXK2_SMOKE["num_hidden_layers"]
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(AXK2_SMOKE, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    params, state = jax.tree.map(placed, params), jax.tree.map(placed, state)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    # 128 + 32 values a position, filled up to two lane tiles.
+    assert {e: c.shape for e, c in caches["blk1_attn"].items()} == {
+        "ckr": (slots, seq, 256), "idx": (slots, seq, 64)}
+    vec = _sds((slots,), jnp.int32)
+    step = sex.build_decode_superstep(8).lower(
+        params, state, caches, vec, vec).compile().as_text()
+    first = sex.build_prefill(seq).lower(
+        params, state, _sds((1, seq), jnp.int32), _sds((), jnp.int32)
+    ).compile().as_text()
+    assert chip_smoke.has_kernel(step, "ff_grouped_matmul")
+    assert not chip_smoke.has_kernel(step, "ff_mla_decode")
+    for name in ("ff_flash_fwd_uneven", "ff_grouped_matmul"):
+        assert chip_smoke.has_kernel(first, name), name
+    row_sorts = lambda text: [l for l in text.splitlines() if " sort(" in l
+                              and re.search(rf"f32\[[0-9,]*\b{seq}\]", l)]
+    assert row_sorts(first) == []
+    assert len(row_sorts(step)) == layers
+    assert chip_smoke.cache_shaped_relayouts(step, caches) == []
+    gathers = [l for l in step.splitlines() if " gather(" in l
+               and f"bf16[{slots},{topk},256]" in l]
+    assert len(gathers) == layers
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
+    ins, outs = layout.split(")->(")
+    row = f"bf16[{slots},{seq},256]{{2,1,0:T(8,128)(2,1)}}"
+    assert ins.count(row) == outs.count(row) == layers
+
+
 def test_laguna_smoke_programs_compile_for_the_chip(monkeypatch):
     """``chip_smoke.py``'s ``serve/laguna`` programs at the smoke preset's
     widths, compiled for the described chip: the prefill holds the banded
@@ -978,6 +1043,10 @@ _TINY = chip_smoke.Sizes(
     serve_laguna=("--model-config", "laguna-tiny", "--max-seq", "128",
                   "--max-batch", "2", "--requests", "3", "--max-new", "6",
                   "--prompt-len", "40:100", "--buckets", "64,128"),
+    # index_topk 16 under prompts of 40-100: the selector selects.
+    serve_axk2=("--model-config", "axk2-tiny", "--max-seq", "128",
+                "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                "--prompt-len", "40:100", "--buckets", "128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -1009,7 +1078,7 @@ def _phases(which):
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
               "serve", "serve/latent", "serve/solar", "serve/xing",
-              "serve/keye", "serve/laguna"])
+              "serve/keye", "serve/laguna", "serve/axk2"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

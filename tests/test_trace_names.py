@@ -175,7 +175,8 @@ def test_lowered_serving_programs_carry_the_selectors_two_phases():
     """A graph whose attention composes a token selector: ``ff_index``
     (projections and scores) and ``ff_select`` (top-k and gather) sit
     inside each ``blk<i>_attn`` of the prefill and of the decode
-    superstep, and are the catalog's other two scopes."""
+    superstep; with the gated norm's gate and the router's group step
+    (tests/test_axk2.py) they are the catalog's other scopes."""
     from flexflow_tpu.models.transformer import KEYE_VL2_TINY, build_lm
     from flexflow_tpu.runtime.serving import ServingExecutor
 
@@ -191,7 +192,8 @@ def test_lowered_serving_programs_carry_the_selectors_two_phases():
             params, state, jax.ShapeDtypeStruct((1, 64), np.int32),
             jax.ShapeDtypeStruct((), np.int32)),
     }
-    assert SCOPE_CATALOG == {"ff_loss", "ff_opt", "ff_index", "ff_select"}
+    assert SCOPE_CATALOG == {"ff_loss", "ff_opt", "ff_index", "ff_select",
+                             "ff_gnorm", "ff_route_group"}
     for kind, lowered in programs.items():
         # The compiled text's ``op_name``: what a trace's ``tf_op`` holds
         # (the lowering's ``loc`` forgets the op's scope inside a loop body).

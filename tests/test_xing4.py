@@ -365,16 +365,18 @@ def test_one_block_function_builds_both_families():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("n_group", 2), ("topk_group", 2), ("moe_layer_freq", 2),
+    ("n_group", 3), ("topk_group", 2), ("moe_layer_freq", 2),
     ("attention_bias", True), ("hidden_act", "gelu"),
     ("tie_word_embeddings", True), ("rope_interleave", False),
 ])
 @pytest.mark.parametrize("base", ["deepseek_v3", "xing4_0"])
 def test_what_the_builder_does_not_build_still_raises_by_key(base, key, value):
-    """``q_lora_rank`` and ``rope_scaling`` are built now; the guards
-    that remain did not go with them."""
+    """``q_lora_rank``, ``rope_scaling`` and (PR 48) expert groups are
+    built now; the guards that remain did not go with them, and groups
+    the experts cannot be cut into (three of eight; two kept of one) are
+    refused by the expert layer."""
     preset = DEEPSEEK_V3_TINY if base == "deepseek_v3" else XING4_TINY
-    with pytest.raises(ValueError, match=f"{key}={value!r} is not built yet"):
+    with pytest.raises(ValueError, match=f"{key}={value!r}"):
         build_lm({**preset, key: value}, 2, 16)
 
 
