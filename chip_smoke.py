@@ -693,6 +693,36 @@ def cache_shaped_relayouts(compiled_text: str, caches) -> List[str]:
         and "calls=%bitcast_fusion" not in line})
 
 
+@contextlib.contextmanager
+def plain_masked_chunks():
+    """Inside the block a selected prefill's masked chunks take the
+    plain path (``ops/attention.py::_attend_kept_heads``): the gate of
+    ``ff_attend_kept`` refuses every shape.  The oracle of the kernel's
+    token parity, steered here and not by an option of the program; the
+    programs traced inside the block keep the path they were traced
+    with."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    gate = pallas_kernels.attend_kept_supported
+    pallas_kernels.attend_kept_supported = lambda *a, **k: False
+    try:
+        yield
+    finally:
+        pallas_kernels.attend_kept_supported = gate
+
+
+def check_kept_kernel(phase: str, run: ServeRun) -> None:
+    """The largest bucket's prefill sends its masked chunks through
+    ``ff_attend_kept`` (what its ``serving_program`` event announces,
+    asked of the ops by shape), visiting no more key blocks than the
+    runs' widths hold."""
+    sex = run.srv.ex
+    kept = sex.kept_blocks(sex.buckets[-1])
+    check(kept.get("kept_kernel") is True
+          and 0 < kept["kept_key_blocks"] <= kept["kept_key_blocks_square"],
+          f"{phase}: the prefill of {sex.buckets[-1]} rows reports {kept}")
+
+
 def keye_phase(argv: Sequence[str]) -> None:
     """The Keye-VL-2.0 preset through ``apps.serve``: grouped-query
     attention ops that compose a token selector, three cache entries a
@@ -721,9 +751,11 @@ def keye_phase(argv: Sequence[str]) -> None:
     check(sex._attention_paths(False) == "gqa_select_dense"
           and sex._attention_paths(True) == "gqa_select_decode",
           "serve/keye: the programs announce other paths")
+    check_kept_kernel("serve/keye", run)
     decode = check_program_kernels(
         "serve/keye", run, caches, decode=("ff_grouped_matmul",),
-        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
+        prefill=("ff_flash_fwd_uneven", "ff_attend_kept",
+                 "ff_grouped_matmul"))
     moved = cache_shaped_relayouts(decode, caches)
     check(not moved, f"serve/keye: the compiled decode superstep moves a "
                      f"whole cache: {moved[:3]}")
@@ -734,6 +766,9 @@ def keye_phase(argv: Sequence[str]) -> None:
           f"serve/keye: a superstep at 400 live positions reports {rows}")
     check(all(len(r.prompt) > op.select.topk for r in run.requests),
           "serve/keye: a prompt under topk: nothing was selected")
+    with plain_masked_chunks():
+        oracle = serve_run("serve/keye-oracle", argv)
+    compare_tokens("serve/keye", run, oracle, tol=BF16_KERNEL_TOL)
 
 
 def axk2_phase(argv: Sequence[str]) -> None:
@@ -768,9 +803,11 @@ def axk2_phase(argv: Sequence[str]) -> None:
     check(sex._attention_paths(False) == "latent_select_expanded"
           and sex._attention_paths(True) == "latent_select_absorbed",
           "serve/axk2: the programs announce other paths")
+    check_kept_kernel("serve/axk2", run)
     decode = check_program_kernels(
         "serve/axk2", run, caches, decode=("ff_grouped_matmul",),
-        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
+        prefill=("ff_flash_fwd_uneven", "ff_attend_kept",
+                 "ff_grouped_matmul"))
     moved = cache_shaped_relayouts(decode, caches)
     check(not moved, f"serve/axk2: the compiled decode superstep moves a "
                      f"whole cache: {moved[:3]}")
@@ -781,7 +818,8 @@ def axk2_phase(argv: Sequence[str]) -> None:
           f"serve/axk2: a superstep at 700 live positions reports {rows}")
     check(all(len(r.prompt) > op.select.topk for r in run.requests),
           "serve/axk2: a prompt under index_topk: nothing was selected")
-    oracle = serve_run("serve/axk2-oracle", [*argv, "--no-decode-kernel"])
+    with plain_masked_chunks():
+        oracle = serve_run("serve/axk2-oracle", [*argv, "--no-decode-kernel"])
     compare_tokens("serve/axk2", run, oracle, tol=BF16_KERNEL_TOL)
 
 

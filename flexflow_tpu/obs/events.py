@@ -105,6 +105,7 @@ KERNEL_CATALOG = frozenset({
     "ff_flash_decode",
     "ff_flash_fwd_uneven",
     "ff_flash_fwd_window",
+    "ff_attend_kept",
     "ff_mla_decode",
     "ff_grouped_matmul",
     # No kernel carries it since PR 46 (``ff_kda_chunk`` makes the Gram

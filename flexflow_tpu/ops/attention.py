@@ -20,7 +20,7 @@ collectives needed there.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,8 +119,79 @@ def _gate_heads(out, logits, head_dim: int):
     return (out.reshape(b, t, -1, head_dim) * gate[..., None]).reshape(out.shape)
 
 
+def selected_walk(sel, t: int):
+    """How ``_attend_selected`` walks ``t > sel.topk`` query rows:
+    ``(c, head, groups)``.  ``c`` rows a chunk (``sel.q_chunk``, or all
+    ``t`` where that does not divide them); the leading ``head`` rows,
+    whole chunks under ``topk``, keep their whole past; ``groups`` the
+    ``(lo, hi)`` runs of chunks that share a key width ``hi``, each run
+    ending at twice its start (one compiled loop a width, and a chunk's
+    selector scores at most twice the keys it can see)."""
+    c = t if t % sel.q_chunk else sel.q_chunk   # one chunk: odd sizes
+    head = (sel.topk // c) * c
+    groups, lo = [], head
+    while lo < t:
+        hi = t if lo == 0 else min(2 * lo, t)
+        groups.append((lo, hi))
+        lo = hi
+    return c, head, groups
+
+
+def kept_blocks(sel, t: int, q_heads: int, k_shape, dk: int, v_width: int,
+                shared: Optional[int]) -> Dict[str, Any]:
+    """What a serving prefill of ``t`` rows does with its masked chunks,
+    by shape (the ``serving_program`` event's fields): ``kept_kernel``,
+    whether they attend through ``pallas_kernels.attend_kept``;
+    ``kept_key_blocks``, the 512-key blocks they visit when they do (a
+    chunk stops at its own last row), and ``kept_key_blocks_square``,
+    what the runs' widths hold (what the selector scores, and what the
+    ``jnp`` path attends).  One op's; zeros under ``topk``."""
+    if t <= sel.topk:
+        return dict(kept_kernel=False, kept_key_blocks=0,
+                    kept_key_blocks_square=0)
+    c, _, groups = selected_walk(sel, t)
+    starts = [(s, hi) for lo, hi in groups for s in range(lo, hi, c)]
+    return dict(
+        kept_kernel=pallas_kernels.attend_kept_supported(
+            (1, q_heads, c, dk), k_shape, v_width, shared),
+        kept_key_blocks=sum(-(-(s + c) // 512) for s, _ in starts),
+        kept_key_blocks_square=sum(-(-hi // 512) for _, hi in starts))
+
+
+def _attend_kept_heads(qc, kh, vh, keep, scale: float, shared_k=None):
+    """The plain form of ``pallas_kernels.attend_kept`` (its oracle, and
+    the path of the shapes its gate refuses and of a differentiated
+    forward): a chunk's queries ``qc`` (b, h, c, dk) over the leading
+    ``width`` keys under ``keep`` (b, c, width), a query head at a time
+    (a head's scores of 512 rows against 32k keys are 64 MB in f32; all
+    heads' at once would be 2 GB).  Every pair of the width is computed
+    and the masked ones thrown away.  (b, h, c, dv) float32."""
+    width = keep.shape[-1]
+    g = qc.shape[1] // kh.shape[1]
+
+    def one_head(j):
+        q = qc[:, j]
+        k, v = kh[:, j // g, :width], vh[:, j // g, :width]
+        if shared_k is not None:
+            own = k.shape[-1]
+            s = jnp.einsum("bqd,bsd->bqs", q[..., :own], k,
+                           preferred_element_type=jnp.float32) \
+                + jnp.einsum("bqd,bsd->bqs", q[..., own:],
+                             shared_k[:, :width],
+                             preferred_element_type=jnp.float32)
+            s = s * scale
+        else:
+            s = jnp.einsum("bqd,bsd->bqs", q, k,
+                           preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1)
+        return jnp.einsum("bqs,bsd->bqd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    return lax.map(one_head, jnp.arange(qc.shape[1])).transpose(1, 0, 2, 3)
+
+
 def _attend_selected(sel, t: int, placed_q, kh, vh, index, scale: float,
-                     dense, dtype, shared_k=None):
+                     dense, dtype, shared_k=None, serving: bool = False):
     """Causal attention of ``t`` query rows over (b, h_kv, t, dk) keys
     and (b, h_kv, t, dv) values starting at position 0, each query row
     over the positions the selector ``sel`` keeps for it (``index``: its
@@ -128,29 +199,29 @@ def _attend_selected(sel, t: int, placed_q, kh, vh, index, scale: float,
     queries a chunk at a time, ``placed_q(start, n)`` (b, h, n, dk).
     Rows under ``topk`` keep their whole past: whole chunks of them go
     to ``dense(q, k, v, dtype)`` (the op's own causal path, (b, n, h *
-    dv)).  The rest run a chunk of ``sel.q_chunk`` query rows at a time:
-    the chunk's selector scores against the keys up to its end, its
-    rows' ``topk``-th largest as the threshold, and masked attention a
-    query head at a time (a head's scores of one chunk against 32k keys
-    are 64 MB in f32; all heads' at once would be 2 GB).  Chunks are
-    grouped by where they end into doubling key widths so that each
-    width is one compiled loop and a chunk pays for at most twice the
-    keys it can see.  Masked pairs are computed and thrown away
-    (ROADMAP B-M1: a prefill that does not pay for them).  Two forms for
-    an op whose whole-sequence arrays would not fit: the selector's
-    queries may be a function ``(start, n) -> (b, n, heads, hd)`` made a
-    chunk at a time, and ``shared_k`` (b, t, r) is a part of every head's
-    key held once: a query's trailing ``r`` values are scored against it
-    and ``kh`` holds the heads' own part alone (``dense`` is handed
-    ``kh`` as it is: the op puts the two together for those rows)."""
+    dv)).  The rest run a chunk of ``sel.q_chunk`` query rows at a time
+    (``selected_walk``): the chunk's selector scores against the keys of
+    its run's width, its rows' ``topk``-th largest as the threshold, and
+    attention under that mask.  In a serving program (``serving``: one
+    device, nothing differentiated) whose shapes
+    ``pallas_kernels.attend_kept_supported`` takes, the chunk attends
+    through ``attend_kept``: the scores stay in VMEM and the walk stops
+    at the chunk's own last row, so only the selector pays for the
+    width.  Elsewhere ``_attend_kept_heads`` computes every pair of the
+    width.  Two forms for an op whose whole-sequence arrays would not
+    fit: the selector's queries may be a function ``(start, n) -> (b, n,
+    heads, hd)`` made a chunk at a time, and ``shared_k`` (b, t, r) is a
+    part of every head's key held once: a query's trailing ``r`` values
+    are scored against it and ``kh`` holds the heads' own part alone
+    (``dense`` is handed ``kh`` as it is: the op puts the two together
+    for those rows)."""
     iq, ik, iw = index
     index_q = iq if callable(iq) else \
         lambda start, n: lax.dynamic_slice_in_dim(iq, start, n, axis=1)
     b = kh.shape[0]
     if t <= sel.topk:
         return dense(placed_q(0, t), kh, vh, dtype)
-    c = t if t % sel.q_chunk else sel.q_chunk   # one chunk: odd sizes
-    head = (sel.topk // c) * c
+    c, head, groups = selected_walk(sel, t)
     outs = []
     if head:
         outs.append(dense(placed_q(0, head), kh[:, :, :head],
@@ -166,37 +237,19 @@ def _attend_selected(sel, t: int, placed_q, kh, vh, index, scale: float,
         with jax.named_scope("ff_select"):
             keep = sel.keep(scores, rows)
         qc = placed_q(start, c)                               # (b, h, c, dk)
-        h = qc.shape[1]
-        g = h // kh.shape[1]
+        if serving and pallas_kernels.attend_kept_supported(
+                qc.shape, kh.shape, vh.shape[-1],
+                None if shared_k is None else shared_k.shape[-1]):
+            o = pallas_kernels.attend_kept(qc, kh, vh, keep, start, scale,
+                                           shared_k=shared_k)
+        else:
+            o = _attend_kept_heads(qc, kh, vh, keep, scale, shared_k)
+        return o.transpose(0, 2, 1, 3).reshape(b, c, -1).astype(dtype)
 
-        def one_head(j):
-            q = qc[:, j]
-            k, v = kh[:, j // g, :width], vh[:, j // g, :width]
-            if shared_k is not None:
-                own = k.shape[-1]
-                s = jnp.einsum("bqd,bsd->bqs", q[..., :own], k,
-                               preferred_element_type=jnp.float32) \
-                    + jnp.einsum("bqd,bsd->bqs", q[..., own:],
-                                 shared_k[:, :width],
-                                 preferred_element_type=jnp.float32)
-                s = s * scale
-            else:
-                s = jnp.einsum("bqd,bsd->bqs", q, k,
-                               preferred_element_type=jnp.float32) * scale
-            p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1)
-            return jnp.einsum("bqs,bsd->bqd", p.astype(v.dtype), v,
-                              preferred_element_type=jnp.float32)
-
-        o = lax.map(one_head, jnp.arange(h))                  # (h, b, c, dv)
-        return o.transpose(1, 2, 0, 3).reshape(b, c, -1).astype(dtype)
-
-    lo = head
-    while lo < t:
-        hi = t if lo == 0 else min(2 * lo, t)
+    for lo, hi in groups:
         o = lax.map(lambda s, hi=hi: chunk_out(s, hi),
                     jnp.arange(lo, hi, c))                    # (n, b, c, h*dv)
         outs.append(o.transpose(1, 0, 2, 3).reshape(b, hi - lo, -1))
-        lo = hi
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
@@ -626,7 +679,8 @@ class MultiHeadAttention(Op):
         with jax.named_scope("ff_index"):
             return self.select.project(params, x, index)
 
-    def _attend_selected(self, params, q, kh, vh, pos, index, dtype, dense):
+    def _attend_selected(self, params, q, kh, vh, pos, index, dtype, dense,
+                         serving: bool = False):
         """``_attend_selected`` over queries ``q`` (b, t, h * hd) as
         projected: split into heads and placed (``_place_heads`` at
         ``pos``) a chunk at a time (all 32 heads of a 32k prefill are
@@ -639,7 +693,15 @@ class MultiHeadAttention(Op):
 
         return _attend_selected(
             self.select, q.shape[1], placed_q, kh, vh, index,
-            1.0 / math.sqrt(self.attrs["head_dim"]), dense, dtype)
+            1.0 / math.sqrt(self.attrs["head_dim"]), dense, dtype,
+            serving=serving)
+
+    def kept_blocks(self, t: int) -> Dict[str, Any]:
+        """``kept_blocks`` of a serving prefill of ``t`` rows."""
+        a = self.attrs
+        h, hd = a["num_kv_heads"], a["head_dim"]
+        return kept_blocks(self.select, t, h * self.group, (1, h, t, hd),
+                           hd, hd, None)
 
     def _forward_selected(self, params, x, state):
         """The cached forward of an op with a selector: caches ``k`` and
@@ -669,7 +731,8 @@ class MultiHeadAttention(Op):
             new = (rows_k, v.astype(cv.dtype), ik.astype(ci.dtype))
             ck, cv, ci = (c.at[:, :t].set(r) for c, r in zip((ck, cv, ci), new))
             y = self._attend_selected(params, q, kh, vh, pos, (iq, ik, iw),
-                                      x.dtype, self._attend_prefill)
+                                      x.dtype, self._attend_prefill,
+                                      serving=True)
         else:
             qh = self._place_heads(self._split_heads(q), params.get("q_norm"),
                                    pos)
@@ -1652,7 +1715,10 @@ class LatentAttention(Op):
         heads' keys hold their own ``nope`` part alone and the rotary key
         is scored once for all of them (K whole is 1 GB, its product with
         ``W_kvb`` in one piece another), and the gate's logits are taken
-        before the attention so that the layer's input can go."""
+        before the attention so that the layer's input can go.  A
+        serving program's masked chunks score the two key parts inside
+        ``pallas_kernels.attend_kept`` (no head's 64 MB of float32 scores
+        is written out); elsewhere a head at a time."""
         a, sel = self.attrs, self.select
         h, r, nope = a["num_heads"], a["kv_rank"], a["nope_dim"]
         src = self._query_source(params, x)
@@ -1686,8 +1752,15 @@ class LatentAttention(Op):
 
         out = _attend_selected(sel, x.shape[1], placed_q, k_nope, v,
                                (index_q, ik, iw), self.scale, dense, x.dtype,
-                               shared_k=k_r)
+                               shared_k=k_r, serving=serving)
         return self._output(params, out, gate), self._cache_rows(c, k_r), ik
+
+    def kept_blocks(self, t: int) -> Dict[str, Any]:
+        """``kept_blocks`` of a serving prefill of ``t`` rows."""
+        a = self.attrs
+        h, nope, rope = a["num_heads"], a["nope_dim"], a["rope_dim"]
+        return kept_blocks(self.select, t, h, (1, h, t, nope), nope + rope,
+                           a["v_dim"], rope)
 
     def _cache_rows(self, c, k_r):
         """``[c | k_r | zeros]`` (b, t, the cache's row)."""

@@ -2104,11 +2104,24 @@ def _stream_softmax_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
     live key in this tile (its running max is then still the floor),
     and such a row adds nothing."""
     q, k, v = q_ref[0], k_ref[0], v_ref[0]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        precision=_mxu_precision(q.dtype),
+    _fold_scores(_nt_f32(q, k) * scale, v, m_scr, l_scr, acc_scr, keep,
+                 drop_dead_rows)
+
+
+def _nt_f32(a, b):
+    """``a @ b.T`` on the matrix unit into float32."""
+    return lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=_mxu_precision(a.dtype),
         preferred_element_type=jnp.float32,
-    ) * scale                                       # (bq, bk) f32
+    )
+
+
+def _fold_scores(s, v, m_scr, l_scr, acc_scr, keep=None,
+                 drop_dead_rows=False):
+    """``_stream_softmax_step`` from the tile's scaled float32 scores
+    ``s`` (bq, bk) on: the mask, the running maximum and sum, and the
+    product with ``v`` (bk, dv) into the accumulator."""
     if keep is not None:
         s = jnp.where(keep(), s, _NEG_INF)
     m = m_scr[...]
@@ -2325,6 +2338,183 @@ def flash_fwd_window(q, k, v, scale: float, window: int,
     )(q.reshape(b * h, t, hd), k.reshape(b * h_kv, t, hd),
       v.reshape(b * h_kv, t, hd))
     return out.reshape(b, h, t, hd)
+
+
+#: Query heads with K and V of their own that one grid step of
+#: ``attend_kept`` folds against one fetch of the mask tile (grouped
+#: queries take a group a step instead).
+_KEPT_HEADS = 4
+#: What a grid step's blocks, scratch and tile may take of VMEM.
+_KEPT_VMEM_LIMIT = 48 << 20
+
+
+def _kept_heads(h: int, h_kv: int) -> Tuple[int, int]:
+    """``(query heads, KV heads)`` a grid step of ``attend_kept`` takes:
+    a group of query heads over its one KV head, or ``_KEPT_HEADS``
+    heads with K and V of their own (as many as divide ``h``)."""
+    if h != h_kv:
+        return h // h_kv, 1
+    n = _KEPT_HEADS
+    while h % n:
+        n //= 2
+    return n, n
+
+
+def attend_kept_supported(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
+                          v_width: int, shared: Optional[int]) -> bool:
+    """Whether ``attend_kept`` applies to a chunk of (b, h, c, dk)
+    queries over (b, h_kv, T, dk_own) keys with ``v_width``-wide values
+    and a ``shared``-wide key part held once for all heads (``None``:
+    the keys are whole): whole 128-row blocks of the chunk and of the
+    keys, ``h_kv`` dividing ``h``, and a grid step's heads inside
+    ``_KEPT_VMEM_LIMIT`` (the test suite's AOT compile holds the gate to
+    what the TPU compiler accepts)."""
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        return False
+    _, h, c, dk = q_shape
+    _, h_kv, t, own = k_shape
+    if h_kv < 1 or h % h_kv or c < 128 or c % 128 or t % 128 or t < c:
+        return False
+    if dk != own + (shared or 0) or own < 8 or v_width < 8 or \
+            (shared is not None and shared < 8):
+        return False
+    heads, kv = _kept_heads(h, h_kv)
+    lanes = lambda n: _round_up(n, _LANES)
+    # Bytes of a grid step at 2 B a value: the heads' query and output
+    # blocks (two buffers each), their float32 accumulator and the two
+    # statistics (a lane tile a row), a 512-key block of K and V (two
+    # buffers), and a float32 tile's scores, mask and weights.  Half the
+    # limit: the compiler's own temporaries take the rest.
+    step = 2 * heads * c * 2 * (lanes(dk) + lanes(v_width)) \
+        + 4 * heads * c * (lanes(v_width) + 2 * _LANES) \
+        + 2 * kv * 512 * 2 * (lanes(own) + lanes(v_width)) + 16 * c * 512
+    return step <= _KEPT_VMEM_LIMIT // 2
+
+
+def _attend_kept_kernel(start_ref, q_ref, k_ref, v_ref, *refs, c, block_k,
+                        scale, group, own):
+    """Grid (batch x head block, key block).  A step folds one key
+    block into the running softmax of each of the block's query heads
+    under the chunk's mask tile, fetched once for all of them.  Key
+    blocks past the chunk's last row are neither fetched (the index
+    maps clamp to the last block it can see) nor computed; the output
+    is written at that block."""
+    ks_ref = refs[0] if own is not None else None
+    keep_ref, o_ref, m_scr, l_scr, acc_scr = refs[-5:]
+    kb = pl.program_id(1)
+    last = lax.div(start_ref[0] + c - 1, block_k)
+    heads = q_ref.shape[0]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kb <= last)
+    def _fold():
+        live = keep_ref[0].astype(jnp.int32) != 0               # (c, bk)
+
+        def head(j, _):
+            k, v = k_ref[j // group], v_ref[j // group]
+            if own is None:
+                s = _nt_f32(q_ref[j], k)
+            else:
+                s = _nt_f32(q_ref[j, :, :own], k) \
+                    + _nt_f32(q_ref[j, :, own:], ks_ref[0])
+            # A dropped score is -inf over a finite floor of the maxima:
+            # its weight is exp(-inf) = 0 whatever the row has seen, so a
+            # row that keeps nothing in this tile adds nothing.
+            _fold_scores(jnp.where(live, s * scale, -jnp.inf), v,
+                         m_scr.at[j], l_scr.at[j], acc_scr.at[j])
+
+        lax.fori_loop(0, heads, head, None)
+
+    @pl.when(kb == last)
+    def _emit():
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def attend_kept(q, k, v, keep, start, scale: float, shared_k=None,
+                interpret: Optional[bool] = None):
+    """A selected prefill's masked chunk: ``softmax(q k^T * scale) v``
+    of ``c`` query rows at positions ``start .. start + c - 1`` over the
+    keys ``keep`` (b, c, width) marks (one mask for every head: the
+    causal edge is in it), streamed a key block at a time and no further
+    than the chunk's own last row.  ``q`` (b, h, c, dk); ``k`` (b, h_kv,
+    T, dk_own) and ``v`` (b, h_kv, T, dv) with ``T >= width``, whole:
+    only the blocks the walk names are fetched, and query head ``j``
+    reads head ``j // (h // h_kv)``; ``shared_k`` (b, T, r): a key part
+    held once for all heads, scored against the queries' trailing ``r``
+    values into the same float32 tile.  Every row keeps at least one
+    key.  Float32 statistics, the weights cast to ``v``'s dtype for the
+    product.  (b, h, c, dv) in ``q``'s dtype.  Forward only.  Callers
+    gate on :func:`attend_kept_supported`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    return _attend_kept_call(q, k, v, shared_k, keep.astype(jnp.int8),
+                             jnp.asarray(start, jnp.int32).reshape(1),
+                             scale=float(scale), interpret=interpret)
+
+
+# A jit of its own, as ``_decode_call``: traced once a shape, not once a
+# chunk loop of every layer.
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _attend_kept_call(q, k, v, shared_k, keep, start, scale, interpret):
+    b, h, c, dk = q.shape
+    h_kv, own = k.shape[1], k.shape[-1]
+    dv, width = v.shape[-1], keep.shape[-1]
+    heads, kv = _kept_heads(h, h_kv)
+    block_k = _prefill_block(width)
+    per_batch = h // heads
+    assert dk == own + (0 if shared_k is None else shared_k.shape[-1]) \
+        and keep.shape == (b, c, width) and width <= k.shape[2], \
+        (q.shape, k.shape, keep.shape)
+
+    def at(j, start_ref):
+        return jnp.minimum(j, lax.div(start_ref[0] + c - 1, block_k))
+
+    head_map = lambda i, j, s: (i, 0, 0)
+    kv_map = lambda i, j, s: (i, at(j, s), 0)
+    in_specs = [
+        pl.BlockSpec((heads, c, dk), head_map),
+        pl.BlockSpec((kv, block_k, own), kv_map),
+        pl.BlockSpec((kv, block_k, dv), kv_map),
+    ]
+    operands = [q.reshape(b * h, c, dk), k.reshape(b * h_kv, -1, own),
+                v.reshape(b * h_kv, -1, dv)]
+    if shared_k is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, block_k, shared_k.shape[-1]),
+            lambda i, j, s: (i // per_batch, at(j, s), 0)))
+        operands.append(shared_k)
+    in_specs.append(pl.BlockSpec(
+        (1, c, block_k), lambda i, j, s: (i // per_batch, 0, at(j, s))))
+    operands.append(keep)
+    kernel = functools.partial(
+        _attend_kept_kernel, c=c, block_k=block_k, scale=scale,
+        group=h // h_kv, own=None if shared_k is None else own)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * per_batch, width // block_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((heads, c, dv), head_map),
+            scratch_shapes=[
+                pltpu.VMEM((heads, c, 1), jnp.float32),
+                pltpu.VMEM((heads, c, 1), jnp.float32),
+                pltpu.VMEM((heads, c, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * h, c, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_KEPT_VMEM_LIMIT),
+        name="ff_attend_kept",
+        interpret=interpret,
+    )(start, *operands)
+    return out.reshape(b, h, c, dv)
 
 
 #: Positions the latent decode kernel fetches and scores at a time.

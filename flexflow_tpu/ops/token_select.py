@@ -36,6 +36,10 @@ every finite score), and ``kth_largest_key`` builds the ``topk``-th
 largest key from its top bit down, a compare and a count of the row a
 bit.  What is kept is compared in keys too, so the selected set is
 ``lax.top_k``'s position for position, zeros of both signs included.
+The mask (``keep``: the causal edge is in it) is all the attention op
+takes from a prefill chunk's selection: ``ops/attention.py::
+_attend_selected`` hands it to ``pallas_kernels.attend_kept`` where
+that kernel's gate takes the shapes, one mask for every head.
 NaN scores are outside the contract (their keys lie beyond both
 infinities).
 """

@@ -1287,7 +1287,8 @@ class ServingExecutor:
                                   bucket=int(bucket),
                                   sampled=sample is not None,
                                   attention=self._attention_paths(False),
-                                  head_rows="last" if slim else "all")
+                                  head_rows="last" if slim else "all",
+                                  **self.kept_blocks(bucket))
         return fn
 
     #: A prefill whose whole-bucket logits would pass this many bytes
@@ -1303,6 +1304,23 @@ class ServingExecutor:
             bucket * out.shape[-1] * jnp.dtype(out.dtype).itemsize
             > self.SLIM_HEAD_BYTES
         )
+
+    def kept_blocks(self, bucket: int) -> Dict[str, Any]:
+        """What a prefill of ``bucket`` rows does with the masked chunks
+        of its ops under a token selector, as ``serving_program``
+        carries it (``ops/attention.py::kept_blocks``, asked of each op
+        by shape): ``kept_kernel`` true where every such op's chunks
+        attend through ``ff_attend_kept``, and one layer's key-block
+        counts (the mean over those ops).  Nothing where no op
+        selects."""
+        found = [op.kept_blocks(bucket) for op in self.attn_ops
+                 if getattr(op, "select", None) is not None]
+        if not found:
+            return {}
+        return dict(
+            kept_kernel=all(f["kept_kernel"] for f in found),
+            **{k: sum(f[k] for f in found) // len(found)
+               for k in ("kept_key_blocks", "kept_key_blocks_square")})
 
     def _attention_paths(self, decode: bool) -> str:
         """Which attention formulation this program's cache-holding ops

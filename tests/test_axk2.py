@@ -694,3 +694,44 @@ def test_smoke_preset_takes_the_kernels_widths():
     q = (1, m["num_attention_heads"], 512, m["qk_nope_head_dim"] + m["qk_rope_head_dim"])
     assert pk.flash_uneven_supported(q, m["v_head_dim"])
     assert m["index_topk"] == 512 and m["index_head_dim"] >= m["qk_rope_head_dim"]
+
+
+def test_prefill_through_the_kept_kernel_equals_the_plain_path(monkeypatch):
+    """The smoke preset's prefill (1024 rows under an ``index_topk`` of
+    512: the second chunk is masked, at whole 128-row blocks) through
+    ``pallas_kernels.attend_kept`` and, with its gate turned off, through
+    ``_attend_kept_heads``: the same first token and the same rows for
+    both cache entries of every layer."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    seq, plen = 1024, 900
+    ff, params = _model(_cfg(base=AXK2_SMOKE), 1, seq, chunk=512)
+    toks = _tokens(1, seq)
+    calls, real = [], pk.attend_kept
+    monkeypatch.setattr(pk, "attend_kept", lambda *a, **k: (
+        calls.append(a[0].shape), real(*a, **k))[1])
+
+    def prefill():
+        sex = ServingExecutor(ff, ff.config, max_batch=1, max_seq=seq,
+                              buckets=[seq])
+        rows, tok, ok, *_ = sex.build_prefill(seq)(params, {}, toks,
+                                                   np.int32(plen))
+        assert bool(ok)
+        return sex.kept_blocks(seq), rows, int(tok[0] if np.ndim(tok) else tok)
+
+    kept, rows, tok = prefill()
+    layers = AXK2_SMOKE["num_hidden_layers"]
+    heads = AXK2_SMOKE["num_attention_heads"]
+    assert kept == dict(kept_kernel=True, kept_key_blocks=2,
+                        kept_key_blocks_square=2)
+    assert calls == [(1, heads, 512, 96)] * layers
+    monkeypatch.setattr(pk, "attend_kept_supported", lambda *a: False)
+    plain_kept, plain_rows, plain_tok = prefill()
+    assert len(calls) == layers and plain_kept["kept_kernel"] is False
+    assert tok == plain_tok
+    assert set(rows["blk1_attn"]) == {"ckr", "idx"}
+    # Round-off of the streamed softmax apart, a later layer's rows move
+    # only where a near-tie of a router or a selector falls the other way.
+    for got, want in zip(jax.tree.leaves(rows), jax.tree.leaves(plain_rows)):
+        gap = np.abs(np.asarray(got[:plen]) - np.asarray(want[:plen]))
+        assert np.median(gap) < 1e-6 and np.mean(gap > 1e-4) < 0.005

@@ -101,6 +101,22 @@ def _flash_uneven(t, h, qk, dv, h_kv=None):
         q, k, _sds((1, h_kv or h, t, dv), BF16))
 
 
+def _attend_kept(t, h, h_kv, c, own, dv, shared=None):
+    """A selected prefill's last chunk of ``c`` rows against all ``t``
+    keys: the widest run of the walk."""
+    q = _sds((1, h, c, own + (shared or 0)), BF16)
+    k, v = _sds((1, h_kv, t, own), BF16), _sds((1, h_kv, t, dv), BF16)
+    keep, start = _sds((1, c, t), jnp.bool_), _sds((), jnp.int32)
+    scale = q.shape[-1] ** -0.5
+    gate = lambda: pk.attend_kept_supported(q.shape, k.shape, dv, shared)
+    if shared is None:
+        return gate, lambda q, k, v, keep, start: pk.attend_kept(
+            q, k, v, keep, start, scale, interpret=False), (q, k, v, keep, start)
+    return gate, lambda q, k, v, sk, keep, start: pk.attend_kept(
+        q, k, v, keep, start, scale, shared_k=sk, interpret=False), (
+            q, k, v, _sds((1, t, shared), BF16), keep, start)
+
+
 def _decode_grouped(b, s, h, h_kv, hd, dtype):
     """Grouped queries over a positions-last cache, as the op declares it."""
     fn = functools.partial(pk.flash_decode, interpret=False, positions_last=True)
@@ -305,6 +321,18 @@ CASES = {
         lambda: _grouped(3584, 16, 7168, 2048, 128, True),
     "grouped_matmul-down-prefill-3584x2048x7168":
         lambda: _grouped(3584, 16, 2048, 7168, 128, False),
+    # A selected prefill's masked chunks (PR 49) at the two cells' shapes,
+    # 512 rows against a 32768 bucket: axk2's 64 heads of 128 + 64 against
+    # 128 with the rotary key shared, keye2's 32 query heads over 4; and
+    # the two smoke presets' (a key part of 64 cut inside a lane tile).
+    "attend_kept-64x512x128s64v128-32768-bf16":
+        lambda: _attend_kept(32768, 64, 64, 512, 128, 128, shared=64),
+    "attend_kept-gqa32x4-512x128-32768-bf16":
+        lambda: _attend_kept(32768, 32, 4, 512, 128, 128),
+    "attend_kept-4x512x64s32v64-1024-bf16":
+        lambda: _attend_kept(1024, 4, 4, 512, 64, 64, shared=32),
+    "attend_kept-gqa4x2-128x128-512-bf16":
+        lambda: _attend_kept(512, 4, 2, 128, 128, 128),
     "gather_rows-1Mx64-1024ids":
         lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
@@ -480,6 +508,9 @@ def test_supported_gates_match_the_compiler():
     assert not pk.mla_decode_supported((4, 160, 200), 128)
     assert not pk.flash_uneven_supported((1, 4, 200, 96), 64)
     assert not pk.grouped_matmul_supported(64, 32, BF16)
+    # A masked chunk streams in whole 128-row blocks of queries and keys.
+    assert not pk.attend_kept_supported((1, 4, 96, 24), (1, 4, 96, 24), 16, None)
+    assert not pk.attend_kept_supported((1, 4, 128, 32), (1, 4, 200, 24), 16, 8)
 
 
 #: The padded caches of the two cells whose decode step is
@@ -724,7 +755,53 @@ def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
     assert layout.count(f"bf16[{slots},160,{seq}]") == 6
 
 
-def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
+def _head_score_products(jaxpr, rows: int, stack: str = ""):
+    """The matrix products of a traced program, outside any kernel and
+    outside the selector's own scopes, whose float32 result is ``rows``
+    query rows by at least ``2 * rows`` keys: a masked chunk's scores
+    written out a head (``_attend_kept_heads``' first einsum)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "dot_general":
+            out = eqn.outvars[0].aval
+            if out.dtype == F32 and out.ndim >= 2 and out.shape[-2] == rows \
+                    and out.shape[-1] >= 2 * rows \
+                    and "ff_index" not in here and "ff_select" not in here:
+                found.append((here, out.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _head_score_products(sub, rows, here)
+    return found
+
+
+def _kept_prefill(sex, seq, args, tmp_path, chunk):
+    """The prefill program of ``seq`` rows built under a telemetry
+    stream: ``(compiled text, its serving_program event)``, after the
+    checks every selected prefill passes: the masked chunks' kernel is
+    in the compiled text, no head's scores are written out, and the
+    event says so with the walk's block counts."""
+    import json
+
+    from flexflow_tpu.runtime import telemetry
+
+    with telemetry.Telemetry(directory=str(tmp_path)) as tel:
+        fn = sex.build_prefill(seq)
+    with open(tel.path) as f:
+        (event,) = [e for e in map(json.loads, f)
+                    if e["ev"] == "serving_program"]
+    text = fn.lower(*args).compile().as_text()
+    assert chip_smoke.has_kernel(text, "ff_attend_kept")
+    assert _head_score_products(jax.make_jaxpr(fn)(*args).jaxpr, chunk) == []
+    assert event["kept_kernel"] is True and event["bucket"] == seq
+    assert 0 < event["kept_key_blocks"] <= event["kept_key_blocks_square"]
+    assert {k: event[k] for k in event if k.startswith("kept_")} \
+        == sex.kept_blocks(seq)
+    return text, event
+
+
+def test_keye_smoke_programs_compile_for_the_chip(monkeypatch, tmp_path):
     """``chip_smoke.py``'s ``serve/keye`` programs at the smoke preset's
     widths, compiled for the described chip: the prefill holds the
     streamed forward kernel (its leading ``topk`` rows) and the grouped
@@ -758,9 +835,12 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     vec = _sds((slots,), jnp.int32)
     step = sex.build_decode_superstep(8).lower(
         params, state, caches, vec, vec).compile().as_text()
-    first = sex.build_prefill(seq).lower(
-        params, state, _sds((1, seq), jnp.int32), _sds((), jnp.int32)
-    ).compile().as_text()
+    # 512 rows under a topk of 256 in chunks of 128: two masked chunks,
+    # one run of width 512 = one key block each.
+    first, event = _kept_prefill(
+        sex, seq, (params, state, _sds((1, seq), jnp.int32),
+                   _sds((), jnp.int32)), tmp_path, 128)
+    assert (event["kept_key_blocks"], event["kept_key_blocks_square"]) == (2, 2)
     assert chip_smoke.has_kernel(step, "ff_grouped_matmul")
     assert not chip_smoke.has_kernel(step, "ff_flash_decode")
     for name in ("ff_flash_fwd_uneven", "ff_grouped_matmul"):
@@ -783,7 +863,7 @@ def test_keye_smoke_programs_compile_for_the_chip(monkeypatch):
     assert ins.count(kv) == outs.count(kv) == 2 * KEYE_VL2_SMOKE["num_hidden_layers"]
 
 
-def test_axk2_smoke_programs_compile_for_the_chip(monkeypatch):
+def test_axk2_smoke_programs_compile_for_the_chip(monkeypatch, tmp_path):
     """``chip_smoke.py``'s ``serve/axk2`` programs at the smoke preset's
     widths, compiled for the described chip: the prefill holds the
     streamed forward kernel (its leading ``index_topk`` rows) and the
@@ -817,9 +897,12 @@ def test_axk2_smoke_programs_compile_for_the_chip(monkeypatch):
     vec = _sds((slots,), jnp.int32)
     step = sex.build_decode_superstep(8).lower(
         params, state, caches, vec, vec).compile().as_text()
-    first = sex.build_prefill(seq).lower(
-        params, state, _sds((1, seq), jnp.int32), _sds((), jnp.int32)
-    ).compile().as_text()
+    # 1024 rows under an index_topk of 512 in chunks of 512: one masked
+    # chunk, whose run is the whole bucket.
+    first, event = _kept_prefill(
+        sex, seq, (params, state, _sds((1, seq), jnp.int32),
+                   _sds((), jnp.int32)), tmp_path, 512)
+    assert (event["kept_key_blocks"], event["kept_key_blocks_square"]) == (2, 2)
     assert chip_smoke.has_kernel(step, "ff_grouped_matmul")
     assert not chip_smoke.has_kernel(step, "ff_mla_decode")
     for name in ("ff_flash_fwd_uneven", "ff_grouped_matmul"):
@@ -1069,6 +1152,11 @@ def on_a_pretend_chip(monkeypatch):
     # ... and the CPU's XLA scatter is a fusion of the table's size.
     monkeypatch.setattr(chip_smoke, "table_sized_relayouts",
                         lambda text, elements, ops=(): [])
+    # ... and the tiny presets' chunks of 8 rows are under the gate of
+    # ``ff_attend_kept`` (whole 128-row blocks): the smoke's own sizes
+    # are held to it by the two ``*_smoke_programs_compile`` cases above.
+    monkeypatch.setattr(chip_smoke, "check_kept_kernel",
+                        lambda phase, run: None)
 
 
 def _phases(which):

@@ -617,3 +617,45 @@ def test_programs_without_the_three_arguments_take_none_of_the_new_paths(name, m
     jax.make_jaxpr(sex.build_prefill(32))(
         params, state, jax.ShapeDtypeStruct((1, 32), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32))
+
+
+def test_prefill_through_the_kept_kernel_equals_the_plain_path(monkeypatch):
+    """The smoke preset's prefill (512 rows under a ``topk`` of 256 in
+    chunks of 128: two masked chunks, groups of 2 query heads on a KV
+    head) through ``pallas_kernels.attend_kept`` and, with its gate
+    turned off, through ``_attend_kept_heads``: the same first token and
+    the same rows for the three cache entries of every layer."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    seq, plen = 512, 450
+    ff, params = _model(_cfg(base=KEYE_VL2_SMOKE), 1, seq)
+    toks = _tokens(1, seq)
+    calls, real = [], pk.attend_kept
+    monkeypatch.setattr(pk, "attend_kept", lambda *a, **k: (
+        calls.append(a[0].shape), real(*a, **k))[1])
+
+    def prefill():
+        sex = ServingExecutor(ff, ff.config, max_batch=1, max_seq=seq,
+                              buckets=[seq])
+        rows, tok, ok, *_ = sex.build_prefill(seq)(params, {}, toks,
+                                                   np.int32(plen))
+        assert bool(ok)
+        return sex.kept_blocks(seq), rows, int(tok[0] if np.ndim(tok) else tok)
+
+    kept, rows, tok = prefill()
+    layers = KEYE_VL2_SMOKE["num_hidden_layers"]
+    heads = KEYE_VL2_SMOKE["num_attention_heads"]
+    assert kept == dict(kept_kernel=True, kept_key_blocks=2,
+                        kept_key_blocks_square=2)
+    # One run (256..512), traced once a layer: its two chunks are a loop.
+    assert calls == [(1, heads, 128, 128)] * layers
+    monkeypatch.setattr(pk, "attend_kept_supported", lambda *a: False)
+    plain_kept, plain_rows, plain_tok = prefill()
+    assert len(calls) == layers and plain_kept["kept_kernel"] is False
+    assert tok == plain_tok
+    assert set(rows["blk1_attn"]) == {"k", "v", "idx"}
+    # Round-off of the streamed softmax apart, a later layer's rows move
+    # only where a near-tie of a router or a selector falls the other way.
+    for got, want in zip(jax.tree.leaves(rows), jax.tree.leaves(plain_rows)):
+        gap = np.abs(np.asarray(got[:plen]) - np.asarray(want[:plen]))
+        assert np.median(gap) < 1e-6 and np.mean(gap > 1e-4) < 0.005

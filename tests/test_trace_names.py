@@ -74,6 +74,11 @@ _ENTRY_POINTS = {
         lambda q: pk.flash_fwd_window(jnp.ones((1, 4, 256, 32)), jnp.ones((1, 2, 256, 32)),
                                       jnp.ones((1, 2, 256, 32)), 0.2, 100),
         _qkv, {"ff_flash_fwd_window"}),
+    "attend_kept": (
+        lambda q: pk.attend_kept(jnp.ones((1, 4, 128, 32)), jnp.ones((1, 2, 256, 24)),
+                                 jnp.ones((1, 2, 256, 16)), jnp.ones((1, 128, 256), bool),
+                                 128, 0.2, shared_k=jnp.ones((1, 256, 8))),
+        _qkv, {"ff_attend_kept"}),
     "flash_decode_ring": (
         lambda q: pk.flash_decode(jnp.ones((1, 6, 128)), jnp.ones((1, 2, 128)), jnp.ones((1, 2, 128)),
                                   jnp.ones((1, 2, 128, 128)), jnp.ones((1, 2, 128, 128)),
