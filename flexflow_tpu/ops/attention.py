@@ -158,6 +158,32 @@ def kept_blocks(sel, t: int, q_heads: int, k_shape, dk: int, v_width: int,
         kept_key_blocks_square=sum(-(-hi // 512) for _, hi in starts))
 
 
+def _on_one_device(op) -> bool:
+    """Whether ``op`` runs unsharded (the serving kernels' condition)."""
+    plan = getattr(op, "_plan", None)
+    return plan is None or plan.num_devices == 1
+
+
+def causal_blocks(sel, t: int, q_heads: int, h_kv: int, dk: int,
+                  v_width: int, dtype) -> Dict[str, int]:
+    """What a serving prefill of ``t`` rows costs in its call of
+    ``pallas_kernels.flash_fwd_uneven``, by shape (the
+    ``serving_program`` event's fields): ``causal_blocks``, the key
+    blocks a head visits (the ``n (n + 1) / 2`` a query can see), and
+    ``causal_steps``, the grid steps a head rides (equal where the walk
+    is the live one).  Under a selector ``sel`` the call covers the
+    leading rows that keep their whole past.  One op's; nothing where
+    the gate refuses the shape or no row goes that way."""
+    if sel is not None and t > sel.topk:
+        t = selected_walk(sel, t)[1]
+    shape = (1, q_heads, t, dk)
+    if not t or not pallas_kernels.flash_uneven_supported(shape, v_width):
+        return {}
+    n = t // pallas_kernels.flash_uneven_walk(shape, h_kv, v_width, dtype)[0]
+    return dict(causal_blocks=n * (n + 1) // 2,
+                causal_steps=len(pallas_kernels.flash_uneven_pairs(n)[0]))
+
+
 def _attend_kept_heads(qc, kh, vh, keep, scale: float, shared_k=None):
     """The plain form of ``pallas_kernels.attend_kept`` (its oracle, and
     the path of the shapes its gate refuses and of a differentiated
@@ -703,6 +729,20 @@ class MultiHeadAttention(Op):
         return kept_blocks(self.select, t, h * self.group, (1, h, t, hd),
                            hd, hd, None)
 
+    def causal_blocks(self, t: int) -> Dict[str, int]:
+        """``causal_blocks`` of a serving prefill of ``t`` rows: nothing
+        under a window (the banded forward) or where the prefill takes
+        another causal path (``_forward_cached``)."""
+        a = self.attrs
+        if a["window"] is not None or not (
+                self.select is not None or self.positions_last
+                or self.group > 1 or self.positional) \
+                or not (a["causal"] and _on_one_device(self)):
+            return {}
+        h, hd = a["num_kv_heads"], a["head_dim"]
+        return causal_blocks(self.select, t, h * self.group, h, hd, hd,
+                             self.outputs[0].dtype)
+
     def _forward_selected(self, params, x, state):
         """The cached forward of an op with a selector: caches ``k`` and
         ``v`` (B, S, h_kv * hd), a position a row, and ``idx`` (B, S,
@@ -952,12 +992,11 @@ class MultiHeadAttention(Op):
     def _attend_prefill(self, qh, kh, vh, dtype):
         """A serving prefill's causal attention on heads (B, h, t, hd)
         against (B, h_kv, t, hd) keys and values: the streamed forward
-        kernel, which reaches a group's K and V through its index map
-        (no repeated copy), where its gate takes the shape; else the
-        dense path over repeated heads."""
-        plan = getattr(self, "_plan", None)
-        if self.attrs["causal"] and (plan is None or plan.num_devices == 1) \
-                and pallas_kernels.flash_uneven_supported(qh.shape, qh.shape[-1]):
+        kernel, a group's query heads over one fetched K/V block (no
+        repeated copy), where its gate takes the shape; else the dense
+        path over repeated heads."""
+        if self.attrs["causal"] and _on_one_device(self) and \
+                pallas_kernels.flash_uneven_supported(qh.shape, qh.shape[-1]):
             out = pallas_kernels.flash_fwd_uneven(
                 qh, kh, vh, 1.0 / math.sqrt(qh.shape[-1]))
             return self._merge_heads(out, dtype)
@@ -1587,10 +1626,18 @@ class LatentAttention(Op):
                                         a["rope_scaling"])
         return rope_interleaved(x, pos, inv, wave)
 
+    def causal_blocks(self, t: int) -> Dict[str, int]:
+        """``causal_blocks`` of a serving prefill of ``t`` rows."""
+        a = self.attrs
+        if not _on_one_device(self):
+            return {}
+        return causal_blocks(self.select, t, a["num_heads"], a["num_heads"],
+                             a["nope_dim"] + a["rope_dim"], a["v_dim"],
+                             self.outputs[0].dtype)
+
     def _causal(self, q, k, v, serving: bool):
         """Causal attention on heads (b, h, t, .); (b, t, h * v_dim)."""
-        plan = getattr(self, "_plan", None)
-        if serving and (plan is None or plan.num_devices == 1) and \
+        if serving and _on_one_device(self) and \
                 pallas_kernels.flash_uneven_supported(q.shape, self.attrs["v_dim"]):
             out = pallas_kernels.flash_fwd_uneven(q, k, v, self.scale)
         else:

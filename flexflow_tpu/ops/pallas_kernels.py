@@ -28,6 +28,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -2150,44 +2151,129 @@ def flash_uneven_supported(q_shape: Tuple[int, ...], v_width: int) -> bool:
     return t >= 128 and t % 128 == 0 and qk >= 8 and v_width >= 8
 
 
-def _fwd_uneven_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                       *, block_q, block_k, scale, num_kb):
-    """Causal streamed forward, 3D grid (bh, q-block, k-block), with
-    q·k and v of different widths.  K/V blocks above the diagonal are
-    neither fetched (the index map clamps to the last block a query
-    block needs) nor computed; the output is written at that block."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    q_start = qi * block_q
-    k_start = kb * block_k
-    last = jnp.minimum(lax.div(q_start + block_q - 1, block_k), num_kb - 1)
+#: Rows of a query (and key) block of the causal prefill forward, the
+#: largest first.
+_CAUSAL_BLOCKS = (1024, 512, 256, 128)
+#: What a grid step's blocks, scratch and score tile may take of VMEM.
+_CAUSAL_VMEM_LIMIT = 64 << 20
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def causal():
-        q_pos = q_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        return k_pos <= q_pos
+def flash_uneven_walk(q_shape: Tuple[int, ...], h_kv: int, v_width: int,
+                      dtype) -> Tuple[int, int, int]:
+    """``(block, query heads, KV heads)`` of a grid step of
+    ``flash_fwd_uneven`` on (b, h, t, qk) queries over ``h_kv`` heads of
+    keys and ``v_width``-wide values: the heads as ``_kept_heads`` takes
+    them (a group over its one KV head, or four heads with K and V of
+    their own), and the largest block of ``_CAUSAL_BLOCKS`` that divides
+    ``t`` and holds the step inside half of ``_CAUSAL_VMEM_LIMIT`` (the
+    compiler's own temporaries take the rest); fewer heads of a group a
+    step where not even the smallest block holds them all.
 
-    def step(masked):
-        _stream_softmax_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                             scale, keep=causal if masked else None)
+    v5e, bf16, ms a call by the largest block allowed (my chip runs, PR 50,
+    ``tools/time_prefill_kernel.py``; the parent's square grid of 512 in
+    brackets; PERF.md section 6 has every reading): 48 heads over 8 at t
+    32,768 1024: 92.59 (72.3% of peak by the t (t + 1) / 2 count), 512:
+    127.05 (52.7%) [174.10]; at t 16,384 1024: 23.71 [42.59]; 32 heads of
+    192 | 128 at t 16,384 1024: 21.37 (65.3%) [35.67], at t 4,608 512:
+    2.277 (48.5%) [3.199], at t 512: 0.0428 [0.0686]; 32 over 4 at t
+    2,048 1024: 0.339 [0.538].  A bucket of 8,704 or 4,608 rows keeps
+    512."""
+    _, h, t, qk = q_shape
+    size = jnp.dtype(dtype).itemsize
+    lanes = lambda n: _round_up(n, _LANES)
 
-    # Blocks wholly below the diagonal need no mask.
-    below = k_start + block_k - 1 <= q_start
-    pl.when(jnp.logical_and(kb <= last, below))(lambda: step(False))
-    pl.when(jnp.logical_and(kb <= last, jnp.logical_not(below)))(
-        lambda: step(True))
+    def step(block, heads, kv):
+        # The heads' query and output blocks (two buffers each), their
+        # float32 accumulator and two statistics (a lane tile a row), a
+        # block of K and V a KV head (two buffers), and a block's float32
+        # scores beside their weights.
+        return 2 * heads * block * size * (lanes(qk) + lanes(v_width)) \
+            + 4 * heads * block * (lanes(v_width) + 2 * _LANES) \
+            + 2 * kv * block * size * (lanes(qk) + lanes(v_width)) \
+            + block * block * (4 + size)
 
-    @pl.when(kb == last)
+    heads, kv = _kept_heads(h, h_kv)
+    while True:
+        fits = [b for b in _CAUSAL_BLOCKS if t % b == 0
+                and step(b, heads, kv) <= _CAUSAL_VMEM_LIMIT // 2]
+        if fits or heads == 1:
+            return (fits[0] if fits else _CAUSAL_BLOCKS[-1]), heads, kv
+        # A group too wide for the smallest block: a divisor of it a step.
+        heads = max(d for d in range(1, heads) if heads % d == 0)
+        kv = min(kv, heads)
+
+
+def flash_uneven_pairs(n: int):
+    """The live ``(query block, key block)`` pairs of a causal sequence
+    of ``n`` blocks in the order the grid walks them: a query block's own
+    diagonal block first (it starts the running softmax), then the blocks
+    below it down to block 0, where the output is written."""
+    qi = np.repeat(np.arange(n), np.arange(1, n + 1))
+    ki = np.concatenate([np.r_[i, np.arange(i - 1, -1, -1)] for i in range(n)])
+    return qi.astype(np.int32), ki.astype(np.int32)
+
+
+def _fwd_uneven_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref,
+                       m_scr, l_scr, acc_scr, *, scale, per_kv):
+    """Causal streamed forward with q·k and v of different widths, grid
+    (head block, live pair): step ``s`` folds key block ``ki[s]`` into
+    the running softmax of query block ``qi[s]`` of each of the step's
+    heads, ``per_kv`` of them over one fetched K/V block.  A block above
+    the diagonal is no step at all; a query block's diagonal block comes
+    first and starts ``m``, ``l`` and ``acc`` (nothing zeroed, nothing
+    rescaled) and is the only one masked.  A head's block is ONE basic
+    block of a few dozen operations on whole ``(block, block)`` arrays:
+    the product that makes the scores, the rows' arithmetic with the row
+    sum lane-wide (elementwise adds; lanes merge once, at the emit: one
+    lane reduction a row, the max, not two), the product that consumes
+    the weights.  The compiler schedules such a block a vector register
+    at a time whatever the arrays' height (4,888 bundles a (1,024, 1,024)
+    block whole, 5,467 in tiles of 128-512 rows, 5,291 in ``_visit``'s
+    32-row tiles), and a serving program traces and lowers the body once
+    a bucket: ``_visit``'s thousand operations a block read +16 s of warm
+    set-up a cell on the chip's host (PERF.md §6 PR 50)."""
+    step = pl.program_id(1)
+    qi, ki = qi_ref[step], ki_ref[step]
+    heads, block, _ = q_ref.shape
+    lanes = l_scr.shape[-1]
+
+    def row_sum(p):
+        return functools.reduce(
+            jnp.add, [p[:, c:c + lanes] for c in range(0, block, lanes)])
+
+    def fold(first):
+        def head(j, _):
+            g = j // per_kv
+            s = _nt_f32(q_ref[j], k_ref[g]) * scale
+            if first:
+                row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(col <= row, s, _NEG_INF)
+                m_new = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp(s - m_new)
+                l_scr[j] = row_sum(p)
+            else:
+                m = m_scr[j]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l_scr[j] = l_scr[j] * corr + row_sum(p)
+            m_scr[j] = m_new
+            out = lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[g], (((1,), (0,)), ((), ())),
+                precision=_mxu_precision(v_ref.dtype),
+                preferred_element_type=jnp.float32)
+            acc_scr[j] = out if first else acc_scr[j] * corr + out
+
+        lax.fori_loop(0, heads, head, None)
+
+    pl.when(ki == qi)(lambda: fold(True))
+    pl.when(ki != qi)(lambda: fold(False))
+
+    @pl.when(ki == 0)
     def _emit():
-        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        l = jnp.sum(l_scr[...], axis=-1, keepdims=True)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_fwd_uneven(q, k, v, scale: float,
@@ -2196,45 +2282,58 @@ def flash_fwd_uneven(q, k, v, scale: float,
     queries and keys and (b, h, t, dv) values, ``qk != dv`` allowed
     (latent attention's expanded path: 192 against 128).  Keys and
     values may have fewer heads, ``h_kv`` dividing ``h`` (grouped-query
-    attention): query head j reads head ``j // (h // h_kv)`` through the
-    index map, so no repeated copy of K or V exists.  Forward only.
-    Callers gate on :func:`flash_uneven_supported`."""
+    attention): a group's query heads ride one grid step over one
+    fetched K/V block, so no repeated copy of K or V exists and none is
+    fetched twice.  Only the ``n (n + 1) / 2`` blocks a query can see
+    are grid steps (:func:`flash_uneven_walk`).  Forward only.  Callers
+    gate on :func:`flash_uneven_supported`."""
     if interpret is None:
         interpret = _interpret_default()
+    return _fwd_uneven_call(q, k, v, scale=float(scale), interpret=interpret)
+
+
+# A jit of its own, as ``_fwd_launch``: traced once a shape, and lowered
+# once a program however many layers call it.
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _fwd_uneven_call(q, k, v, scale, interpret):
     b, h, t, qk = q.shape
     dv = v.shape[-1]
     h_kv = k.shape[1]
-    group = h // h_kv
-    assert h == group * h_kv and v.shape[1] == h_kv, (q.shape, k.shape)
-    block = _prefill_block(t)
-    num_kb = t // block
-    kernel = functools.partial(
-        _fwd_uneven_kernel, block_q=block, block_k=block, scale=scale,
-        num_kb=num_kb,
-    )
-
-    def kv_map(bi, i, j):                   # block_q == block_k
-        return (bi if group == 1 else bi // group, jnp.minimum(j, i), 0)
-
-    out = pl.pallas_call(
+    assert h % h_kv == 0 and v.shape[1] == h_kv, (q.shape, k.shape, v.shape)
+    block, heads, kv = flash_uneven_walk(q.shape, h_kv, dv, q.dtype)
+    qi, ki = flash_uneven_pairs(t // block)
+    # Head block i holds query heads i * heads ...: KV head i * heads //
+    # group, in blocks of kv.
+    head_map = lambda i, s, qi, ki: (i, qi[s], 0)
+    kv_map = lambda i, s, qi, ki: (i * heads // (h // h_kv * kv), ki[s], 0)
+    kernel = functools.partial(_fwd_uneven_kernel, scale=scale,
+                               per_kv=heads // kv)
+    call = pl.pallas_call(
         kernel,
-        grid=(b * h, num_kb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, block, qk), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block, qk), kv_map),
-            pl.BlockSpec((1, block, dv), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, block, dv), lambda bi, i, j: (bi, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b * h // heads, len(qi)),
+            in_specs=[
+                pl.BlockSpec((heads, block, qk), head_map),
+                pl.BlockSpec((kv, block, qk), kv_map),
+                pl.BlockSpec((kv, block, dv), kv_map),
+            ],
+            out_specs=pl.BlockSpec((heads, block, dv), head_map),
+            scratch_shapes=[
+                pltpu.VMEM((heads, block, 1), jnp.float32),
+                pltpu.VMEM((heads, block, _LANES), jnp.float32),
+                pltpu.VMEM((heads, block, dv), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block, 1), jnp.float32),
-            pltpu.VMEM((block, 1), jnp.float32),
-            pltpu.VMEM((block, dv), jnp.float32),
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT),
         name="ff_flash_fwd_uneven",
         interpret=interpret,
-    )(q.reshape(b * h, t, qk), k.reshape(b * h_kv, t, qk),
-      v.reshape(b * h_kv, t, dv))
+    )
+    out = call(jnp.asarray(qi), jnp.asarray(ki), q.reshape(b * h, t, qk),
+               k.reshape(b * h_kv, t, qk), v.reshape(b * h_kv, t, dv))
     return out.reshape(b, h, t, dv)
 
 

@@ -1288,7 +1288,8 @@ class ServingExecutor:
                                   sampled=sample is not None,
                                   attention=self._attention_paths(False),
                                   head_rows="last" if slim else "all",
-                                  **self.kept_blocks(bucket))
+                                  **self.kept_blocks(bucket),
+                                  **self.causal_blocks(bucket))
         return fn
 
     #: A prefill whose whole-bucket logits would pass this many bytes
@@ -1321,6 +1322,19 @@ class ServingExecutor:
             kept_kernel=all(f["kept_kernel"] for f in found),
             **{k: sum(f[k] for f in found) // len(found)
                for k in ("kept_key_blocks", "kept_key_blocks_square")})
+
+    def causal_blocks(self, bucket: int) -> Dict[str, int]:
+        """What a prefill of ``bucket`` rows costs in its causal kernel
+        calls, as ``serving_program`` carries it
+        (``ops/attention.py::causal_blocks``, asked of each op by shape):
+        ``causal_blocks``, the key blocks a head visits in one layer's
+        call of ``ff_flash_fwd_uneven``, and ``causal_steps``, the grid
+        steps it rides (the mean over the ops that make the call).
+        Nothing where no op does."""
+        found = [f for f in (op.causal_blocks(bucket) for op in self.attn_ops
+                             if hasattr(op, "causal_blocks")) if f]
+        return {k: sum(f[k] for f in found) // len(found)
+                for k in ("causal_blocks", "causal_steps") if found}
 
     def _attention_paths(self, decode: bool) -> str:
         """Which attention formulation this program's cache-holding ops

@@ -233,6 +233,9 @@ CASES = {
     "flash_uneven-32x16384x192v128-bf16":
         lambda: _flash_uneven(16384, 32, 192, 128),
     "flash_uneven-4x256x96v64-bf16": lambda: _flash_uneven(256, 4, 96, 64),
+    # xing4.serve's shortest bucket: one block, the diagonal's static walk alone
+    "flash_uneven-32x512x192v128-bf16":
+        lambda: _flash_uneven(512, 32, 192, 128),
     "mla_decode-16x32x576x16384-bf16":
         lambda: _mla_decode(16, 32, 576, 512, 16384),
     "mla_decode-96x32x576x4096-bf16":
@@ -744,6 +747,9 @@ def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
                           (first, ("ff_flash_fwd_uneven", "ff_grouped_matmul"))):
         for name in kernels:
             assert chip_smoke.has_kernel(text, name), name
+    # One causal call a layer, over live blocks alone (one block here).
+    assert _kernel_calls(first, "ff_flash_fwd_uneven") == 3
+    assert sex.causal_blocks(seq) == dict(causal_blocks=1, causal_steps=1)
     assert chip_smoke.table_sized_relayouts(
         step, slots * 160 * seq, chip_smoke.CACHE_RELAYOUT_OPS) == []
     # The step's column is the kernel's to write: no update of XLA's on
@@ -753,6 +759,12 @@ def test_xing_smoke_programs_compile_for_the_chip(monkeypatch):
     layout = re.search(r"entry_computation_layout=\{(.*)\}\n", step).group(1)
     assert f"[{slots},1,4,256]" not in layout and "hc_defect" not in layout
     assert layout.count(f"bf16[{slots},160,{seq}]") == 6
+
+
+def _kernel_calls(compiled_text: str, name: str) -> int:
+    """How many instructions of a compiled program call the Pallas
+    kernel ``name`` (``chip_smoke.has_kernel``'s pattern, counted)."""
+    return len(re.findall(rf"%{re.escape(name)}[.\d]* = ", compiled_text))
 
 
 def _head_score_products(jaxpr, rows: int, stack: str = ""):
@@ -964,6 +976,12 @@ def test_laguna_smoke_programs_compile_for_the_chip(monkeypatch):
         for name in ("ff_flash_fwd_window", "ff_flash_fwd_uneven",
                      "ff_grouped_matmul"):
             assert chip_smoke.has_kernel(first, name), (bucket, name)
+        # The two full layers' call, over live blocks alone: five blocks
+        # of 256 at 1,280 rows, two of 1,024 at 2,048.
+        assert _kernel_calls(first, "ff_flash_fwd_uneven") == 2
+        live = {1280: 15, seq: 3}[bucket]
+        assert sex.causal_blocks(bucket) == dict(causal_blocks=live,
+                                                 causal_steps=live)
     assert chip_smoke.cache_shaped_relayouts(step, caches) == []
     assert [l for l in step.splitlines()
             if ("dynamic-update-slice(" in l or " scatter(" in l)

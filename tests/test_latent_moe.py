@@ -19,7 +19,6 @@ from flexflow_tpu.models.transformer import (
 from flexflow_tpu.ops import pallas_kernels
 from flexflow_tpu.ops.attention import (
     LatentAttention,
-    _einsum_attention,
     _latent_decode,
 )
 from flexflow_tpu.runtime import telemetry
@@ -261,17 +260,6 @@ def test_mla_decode_kernel_writes_the_steps_column(dtype, b):
         f32(got), f32(_latent_decode(q, want_cache, pos, dv, 0.2)), **tol)
     stale = f32(_latent_decode(q, cache, pos, dv, 0.2))
     assert np.abs(f32(got) - stale).max() > 0.3
-
-
-def test_flash_fwd_uneven_kernel_against_the_einsum_oracle():
-    rng = np.random.default_rng(6)
-    q, k = (jnp.asarray(rng.standard_normal((1, 2, 256, 24)), jnp.float32) for _ in range(2))
-    v = jnp.asarray(rng.standard_normal((1, 2, 256, 16)), jnp.float32)
-    assert pallas_kernels.flash_uneven_supported(q.shape, 16)
-    assert not pallas_kernels.flash_uneven_supported((1, 2, 200, 24), 16)
-    got = pallas_kernels.flash_fwd_uneven(q, k, v, 24 ** -0.5)
-    want = _einsum_attention(q, k, v, True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
 
 
 def test_multihead_attention_declares_the_cache_it_always_had():

@@ -20,7 +20,6 @@ from flexflow_tpu.models.transformer import (
 from flexflow_tpu.ops import pallas_kernels as pk
 from flexflow_tpu.ops.attention import (
     MultiHeadAttention,
-    _einsum_attention,
     _einsum_decode,
 )
 from flexflow_tpu.ops.base import TensorSpec
@@ -290,8 +289,9 @@ def test_the_op_ends_its_scan_at_the_length_on_both_paths(length):
 ])
 def test_grouped_query_kernels_equal_the_einsum_oracle(s, lens):
     """Decode on a positions-last cache (the step's column written on
-    the way) and the streamed prefill reading a group's K and V through
-    the index map, against attention over repeated heads."""
+    the way) against attention over repeated heads.  (The streamed
+    prefill over a group's one K and V:
+    ``tests/test_flash_uneven.py``.)"""
     r = np.random.default_rng(2)
     b, h, hkv, hd = 3, 8, 2, 128
     q = jnp.asarray(r.normal(size=(b, h, hd)), jnp.float32)
@@ -310,14 +310,6 @@ def test_grouped_query_kernels_equal_the_einsum_oracle(s, lens):
     want = _einsum_decode(q, wk.transpose(0, 3, 1, 2), wv.transpose(0, 3, 1, 2),
                           lengths - 1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
-    t = 256
-    qs = jnp.asarray(r.normal(size=(1, h, t, hd)), jnp.float32)
-    ks, vs = (jnp.asarray(r.normal(size=(1, hkv, t, hd)), jnp.float32) for _ in range(2))
-    got = pk.flash_fwd_uneven(qs, ks, vs, hd ** -0.5)
-    rep = lambda x: jnp.repeat(x, h // hkv, axis=1)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(_einsum_attention(qs, rep(ks), rep(vs), True)),
-        atol=2e-5)
 
 
 def test_plain_attention_is_the_program_it_was():
