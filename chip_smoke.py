@@ -87,6 +87,7 @@ class Sizes:
     serve_laguna: Tuple[str, ...]
     #: The A.X-K2 preset: prompts longer than its ``index_topk``.
     serve_axk2: Tuple[str, ...]
+    serve_lfm2: Tuple[str, ...]
     dlrm4: Tuple[str, ...]
     alexnet4: Tuple[str, ...]
     alexnet4_strategy: Tuple[str, ...]
@@ -152,6 +153,17 @@ FULL = Sizes(
     serve_axk2=("--model-config", "axk2-smoke", "--max-seq", "1024",
                 "--max-batch", "4", "--requests", "6", "--max-new", "12",
                 "--prompt-len", "600:900", "--buckets", "1024",
+                "--dtype", "bfloat16"),
+    # 512 positions: prompts of 200-450 end inside the 512 bucket, so the
+    # convolution windows are taken at the prompt's length and not at
+    # the bucket's end; heads of 64 in groups of four through both
+    # attention kernels, one chunk of the decode kernel.  Six slots: a
+    # cache of 6 x 512 x 2 x 64 values has no weight's element count (at
+    # four it has W_out's 512 x 512, and the relayout check counts
+    # elements: my chip run PR 51).
+    serve_lfm2=("--model-config", "lfm2-smoke", "--max-seq", "512",
+                "--max-batch", "6", "--requests", "8", "--max-new", "12",
+                "--prompt-len", "200:450", "--buckets", "512",
                 "--dtype", "bfloat16"),
     # The one-chip DLRM shape with a table a chip (``dlrm_strategy``:
     # the stacked dim at c = 4), MLPs data parallel at 256 a chip.
@@ -681,6 +693,48 @@ def solar_phase(argv: Sequence[str]) -> None:
     compare_tokens("serve/solar", run, oracle, tol=BF16_KERNEL_TOL)
 
 
+def lfm2_phase(argv: Sequence[str]) -> None:
+    """The LFM2-MoE family's preset through ``apps.serve``: a convolution
+    window beside a grouped-query KV cache of heads of 64 (positions-
+    major, the order the chip stores as ``flash_decode`` reads it), a
+    head that is the token table, the attention layers through both
+    kernels at that head width, no cache-sized relayout in the
+    superstep, and the tokens of the plain ``jnp`` paths."""
+    from flexflow_tpu.ops import GatedShortConv
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+
+    run = serve_run("serve/lfm2", argv)
+    sex = run.srv.ex
+    kinds = {type(op) for op in sex.attn_ops}
+    head = sex.model.find_op("lm_head")
+    check(kinds == {MultiHeadAttention, GatedShortConv}
+          and any(op.name.endswith("_moe") for op in sex._layers)
+          and head.tied == {"kernel": ("embed", "table")}
+          and not head.param_specs(),
+          f"serve/lfm2: the served graph holds {sorted(map(str, kinds))}, "
+          f"head tied {head.tied}")
+    caches = sex.init_cache()
+    gqa = next(op for op in sex.attn_ops if isinstance(op, MultiHeadAttention))
+    conv = next(op for op in sex.attn_ops if isinstance(op, GatedShortConv))
+    a, d = gqa.attrs, conv.inputs[0].shape[-1]
+    want = (sex.max_batch, sex.max_seq, a["num_kv_heads"], a["head_dim"])
+    got = (caches[gqa.name]["k"].shape, caches[conv.name]["conv"].shape)
+    check(got == (want, (sex.max_batch, conv.attrs["kernel_size"] - 1, d)),
+          f"serve/lfm2: caches {got}, expected K of {want} and a window")
+    check(sex._attention_paths(False) == "gqa_dense+short_conv"
+          and sex._attention_paths(True) == "gqa_decode+short_conv",
+          "serve/lfm2: the programs announce other paths")
+    decode = check_program_kernels(
+        "serve/lfm2", run, caches,
+        decode=("ff_flash_decode", "ff_grouped_matmul"),
+        prefill=("ff_flash_fwd_uneven", "ff_grouped_matmul"))
+    moved = cache_or_state_relayouts(decode, caches)
+    check(not moved, f"serve/lfm2: the compiled decode superstep moves a "
+                     f"whole cache: {moved[:3]}")
+    oracle = serve_run("serve/lfm2-oracle", [*argv, "--no-decode-kernel"])
+    compare_tokens("serve/lfm2", run, oracle, tol=BF16_KERNEL_TOL)
+
+
 def cache_shaped_relayouts(compiled_text: str, caches) -> List[str]:
     """``table_sized_relayouts`` over caches of any rank, by the cache's
     own shape (a weight of this preset has as many elements as a cache):
@@ -1011,6 +1065,7 @@ def one_chip_phases(sz: Sizes) -> List[Phase]:
         ("serve/keye", lambda: keye_phase(sz.serve_keye)),
         ("serve/laguna", lambda: laguna_phase(sz.serve_laguna)),
         ("serve/axk2", lambda: axk2_phase(sz.serve_axk2)),
+        ("serve/lfm2", lambda: lfm2_phase(sz.serve_lfm2)),
     ]
 
 

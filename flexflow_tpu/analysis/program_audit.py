@@ -323,6 +323,16 @@ def _stream_graph():
                     _tiny_config(batch_size=8))
 
 
+def _short_conv_graph():
+    """GatedShortConv beside grouped-query MultiHeadAttention under a
+    head tied to the token table (the LFM2-MoE family's first three
+    layers: two dense feed-forwards and an expert layer)."""
+    from flexflow_tpu.models.transformer import LFM2_TINY, build_lm
+
+    return build_lm({**LFM2_TINY, "num_hidden_layers": 3}, 8, 8,
+                    _tiny_config(batch_size=8))
+
+
 def _serving_graph():
     """The graph ServingExecutor is audited on (no MoE: serving drives
     the plain transformer LM, apps/serve.py)."""
@@ -381,6 +391,7 @@ def catalog_models():
         ("deepseek_v3", _latent_moe_graph()),
         ("solar_open2", _delta_graph()),
         ("xing4_0", _stream_graph()),
+        ("lfm2_moe", _short_conv_graph()),
     ]
 
 

@@ -27,6 +27,7 @@ from flexflow_tpu.ops import (
     Conv2D,
     Embedding,
     Flat,
+    GatedShortConv,
     HeteroEmbedding,
     HyperConnectionPost,
     HyperConnectionPre,
@@ -164,6 +165,16 @@ class FFModel:
         name: Optional[str] = None,
         **kw,
     ) -> TensorSpec:
+        """``tied_to`` names an embedding op built before this one whose
+        ``table`` is this op's kernel (a tied head): one leaf, that op's."""
+        tied_to = kw.get("tied_to")
+        if tied_to is not None:
+            table = self.find_op(tied_to).param_specs().get("table")
+            if table is None or tuple(table.shape) != (out_dim, x.shape[-1]):
+                raise ValueError(
+                    f"dense {name!r}: tied_to={tied_to!r} needs that op's "
+                    f"table to be ({out_dim}, {x.shape[-1]}), got "
+                    f"{None if table is None else tuple(table.shape)}")
         return self._add(
             Linear(self._unique("dense", name), x, out_dim,
                    activation=activation, use_bias=use_bias, **kw)
@@ -328,6 +339,16 @@ class FFModel:
         return self._add(
             KimiDeltaAttention(self._unique("delta_attention", name), x,
                                num_heads, head_dim, **kw)
+        )
+
+    def short_conv(self, x: TensorSpec, kernel_size: int = 3,
+                   name: Optional[str] = None, **kw) -> TensorSpec:
+        """Gated short convolution (``ops/short_conv.py``
+        ``GatedShortConv``: a depthwise causal filter of ``kernel_size``
+        taps between two gates)."""
+        return self._add(
+            GatedShortConv(self._unique("short_conv", name), x,
+                           kernel_size=kernel_size, **kw)
         )
 
     def hyper_connection_pre(self, x: TensorSpec, streams: int,

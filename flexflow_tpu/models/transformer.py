@@ -422,10 +422,84 @@ def _laguna_lm(ff: FFModel, tok, m: Dict[str, Any]):
     return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head")
 
 
+def _lfm2_moe_lm(ff: FFModel, tok, m: Dict[str, Any]):
+    """The LFM2-MoE block family (``model_type`` ``lfm2_moe``): RMSNorm
+    pre-norm blocks whose mixer goes by layer (``layer_types``: ``conv``
+    the gated short convolution of ``conv_L_cache`` taps,
+    ``ops/short_conv.py``; ``full_attention`` grouped-query attention
+    with an RMSNorm over each head of q and k and rotary positions on
+    the whole head, the head's width ``hidden_size /
+    num_attention_heads``), ``num_dense_layers`` leading gated SiLU MLPs
+    of ``intermediate_size``, then expert layers under a sigmoid top-k
+    router (a selection bias under ``use_expert_bias``, the chosen
+    scores divided by their sum + 1e-6 under ``norm_topk_prob``) with no
+    shared expert; no bias, one more RMSNorm, and a head tied to the
+    token table (``tie_embedding``, the family's default: one leaf)."""
+    layers = m["num_hidden_layers"]
+    kinds = list(m["layer_types"][:layers])
+    rope = m.get("rope_parameters") or {}
+    for key, got, built in (
+            ("conv_bias", m.get("conv_bias", False), (False,)),
+            ("tie_embedding", m.get("tie_embedding", True), (True,)),
+            ("tie_word_embeddings", m.get("tie_word_embeddings", True),
+             (True,)),
+            ("rope_parameters.rope_type", rope.get("rope_type", "default"),
+             ("default",)),
+            ("layer_types", sorted(set(kinds) - {"conv", "full_attention"}),
+             ([],))):
+        if got not in built:
+            raise ValueError(
+                f"lfm2_moe builder: {key}={got!r} is not built yet "
+                f"(only {built[0]!r})")
+    if len(kinds) < layers:
+        raise ValueError(
+            f"lfm2_moe builder: layer_types must name all {layers} layers")
+    d, eps, heads = m["hidden_size"], m["norm_eps"], m["num_attention_heads"]
+    if d % heads:
+        raise ValueError(
+            f"lfm2_moe builder: hidden_size {d} is not num_attention_heads "
+            f"{heads} heads of one width")
+    x = ff.word_embedding(tok, m["vocab_size"], d, name="embed",
+                          dtype=jnp.dtype(ff.config.compute_dtype))
+    for i in range(layers):
+        a = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln1")
+        if kinds[i] == "conv":
+            a = ff.short_conv(a, m["conv_L_cache"], name=f"blk{i}_conv")
+        else:
+            a = ff.multihead_attention(
+                a, heads, causal=True, use_bias=False,
+                num_kv_heads=m["num_key_value_heads"], head_dim=d // heads,
+                qk_norm=eps, rope={"theta": float(rope["rope_theta"])},
+                name=f"blk{i}_attn")
+        x = ff.add(x, a, name=f"blk{i}_res1")
+        h = ff.rms_norm(x, eps=eps, name=f"blk{i}_ln2")
+        if i < m["num_dense_layers"]:
+            g = ff.dense(h, m["intermediate_size"], activation="silu",
+                         use_bias=False, name=f"blk{i}_mlp_gate")
+            up = ff.dense(h, m["intermediate_size"], use_bias=False,
+                          name=f"blk{i}_mlp_up")
+            h = ff.dense(ff.multiply(g, up, name=f"blk{i}_mlp_act"), d,
+                         use_bias=False, name=f"blk{i}_mlp_down")
+        else:
+            h = ff.moe(
+                h, m["num_experts"], m["moe_intermediate_size"],
+                top_k=m["num_experts_per_tok"], dispatch="sorted",
+                router="sigmoid", gated=True, activation="silu",
+                selection_bias=bool(m.get("use_expert_bias", False)),
+                norm_topk_prob=m["norm_topk_prob"], norm_topk_eps=1e-6,
+                routed_scale=m["routed_scaling_factor"],
+                name=f"blk{i}_moe")
+        x = ff.add(x, h, name=f"blk{i}_res2")
+    x = ff.rms_norm(x, eps=eps, name="ln_f")
+    return ff.dense(x, m["vocab_size"], use_bias=False, name="lm_head",
+                    tied_to="embed")
+
+
 _BLOCKS = {"gpt2": _gpt2_lm, "deepseek_v3": _deepseek_v3_lm,
            "xing4_0": _deepseek_v3_lm, "axk2": _deepseek_v3_lm,
            "solar_open2": _solar_open2_lm,
-           "KeyeVL2": _keye_vl2_lm, "laguna": _laguna_lm}
+           "KeyeVL2": _keye_vl2_lm, "laguna": _laguna_lm,
+           "lfm2_moe": _lfm2_moe_lm}
 
 #: The DeepSeek-V3 family at unit-test size (tests, chip_smoke.py, the
 #: audit catalog): every mechanism of the block, no published width.
@@ -607,8 +681,37 @@ AXK2_SMOKE: Dict[str, Any] = {
     "index_head_dim": 64, "index_topk": 512,
 }
 
+#: The LFM2-MoE family at unit-test size: six layers (two dense then
+#: four expert feed-forwards; conv, conv, attention, conv, conv,
+#: attention, so that a convolution follows an attention layer and an
+#: attention layer an expert layer), 4 query heads over 2 cached ones, a
+#: selection bias; widths no kernel takes.
+LFM2_TINY: Dict[str, Any] = {
+    "model_type": "lfm2_moe", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 6, "num_dense_layers": 2,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                    "full_attention"],
+    "conv_L_cache": 3, "conv_bias": False, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "norm_eps": 1e-5,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "use_expert_bias": True,
+}
+
+#: The same family at the smallest widths every serving kernel takes on
+#: the chip (heads of 64 in groups of four as published, expert products
+#: of whole tiles): tests/test_chip_compile.py.
+LFM2_SMOKE: Dict[str, Any] = {
+    **LFM2_TINY, "vocab_size": 2048, "hidden_size": 512,
+    "intermediate_size": 1024, "num_attention_heads": 8,
+    "num_key_value_heads": 2, "moe_intermediate_size": 128,
+}
+
 PRESETS = {"axk2-tiny": AXK2_TINY,
            "axk2-smoke": AXK2_SMOKE,
+           "lfm2-tiny": LFM2_TINY,
+           "lfm2-smoke": LFM2_SMOKE,
            "laguna-tiny": LAGUNA_TINY,
            "laguna-smoke": LAGUNA_SMOKE,
            "keye-vl2-tiny": KEYE_VL2_TINY,

@@ -123,8 +123,8 @@ KERNEL_CATALOG = frozenset({
 
 #: ``jax.named_scope`` phases beside the per-op scopes (``op.name``):
 #: of a train step the loss ops and every optimizer update; of a serving
-#: program the two parts of a token selector, a gated norm's gate and the
-#: group step of a router.
+#: program the two parts of a token selector, a gated norm's gate, the
+#: group step of a router and a short convolution's window write.
 SCOPE_CATALOG = frozenset({
     "ff_loss",
     "ff_opt",
@@ -138,6 +138,9 @@ SCOPE_CATALOG = frozenset({
     # inside an expert layer's router under expert groups (ops/moe.py):
     # the groups' standing, the kept groups and the mask
     "ff_route_group",
+    # inside a gated short convolution (ops/short_conv.py): the window a
+    # slot keeps, shifted by a decode step or picked at a prefill's length
+    "ff_conv_state",
 })
 
 #: ``run_end.exit`` classifications (the reader adds ``truncated`` for

@@ -4,7 +4,7 @@ from flexflow_tpu.ops.attention import (
     MultiHeadAttention,
     PositionEmbedding,
 )
-from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
+from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec, op_params
 from flexflow_tpu.ops.conv import Conv2D, Flat, Pool2D
 from flexflow_tpu.ops.delta_attention import KimiDeltaAttention
 from flexflow_tpu.ops.hyper_connection import HyperConnectionPost, HyperConnectionPre
@@ -14,6 +14,7 @@ from flexflow_tpu.ops.losses import MSELoss, SoftmaxCrossEntropy
 from flexflow_tpu.ops.moe import MixtureOfExperts
 from flexflow_tpu.ops.norm import BatchNorm, RMSNorm
 from flexflow_tpu.ops.rnn import LSTM
+from flexflow_tpu.ops.short_conv import GatedShortConv
 from flexflow_tpu.ops.tensor_ops import (
     Add,
     Concat,
@@ -28,6 +29,7 @@ __all__ = [
     "Op",
     "ParamSpec",
     "TensorSpec",
+    "op_params",
     "Conv2D",
     "Pool2D",
     "Flat",
@@ -42,6 +44,7 @@ __all__ = [
     "Concat",
     "DotInteraction",
     "Dropout",
+    "GatedShortConv",
     "HyperConnectionPost",
     "HyperConnectionPre",
     "KimiDeltaAttention",

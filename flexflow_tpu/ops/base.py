@@ -110,6 +110,12 @@ class Op:
     def param_specs(self) -> Dict[str, ParamSpec]:
         return {}
 
+    #: Leaves of OTHER ops this op reads, under its own keys: ``{key:
+    #: (op name, that op's key)}`` (a head tied to the token table).  Such
+    #: a leaf exists once, in its owner's subtree; whoever walks the graph
+    #: hands ``forward`` the op's view through :func:`op_params`.
+    tied: Dict[str, Tuple[str, str]] = {}
+
     def state_specs(self) -> Dict[str, ParamSpec]:
         """Non-trained mutable state (e.g. batchnorm running stats)."""
         return {}
@@ -231,3 +237,19 @@ class Op:
         )
         self.outputs.append(t)
         return t
+
+
+def op_params(op: Op, params: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
+    """What ``op.forward`` reads of the parameter tree ``{op: {key:
+    leaf}}``: the op's own subtree and, under the op's keys, the leaves
+    it is tied to (``Op.tied``)."""
+    own = params.get(op.name, {})
+    if not op.tied:
+        return own
+    missing = [owner for owner, _ in op.tied.values() if owner not in params]
+    if missing:
+        raise KeyError(
+            f"{op.name}: tied to {missing}, whose leaves are not in this "
+            f"parameter tree (a pipeline stage holds its own ops' alone)")
+    return {**own, **{k: params[owner][key]
+                      for k, (owner, key) in op.tied.items()}}

@@ -3,7 +3,8 @@
 A linear-attention layer (Kimi Linear, arXiv:2510.26692) that keeps,
 instead of a cache that grows with the sequence, one **recurrent state**
 ``S`` (d_k x d_v, float32) a head and the last ``conv_size - 1`` inputs
-of its three short convolutions.  For a token with normed input ``u``:
+of its three short convolutions (the window ``ops/short_conv.py``'s two
+helpers walk and hand on).  For a token with normed input ``u``:
 
     q, k, v = SiLU(conv(u W_q)), SiLU(conv(u W_k)), SiLU(conv(u W_v))
         (depthwise, causal over the last ``conv_size`` positions; per
@@ -40,6 +41,7 @@ from flexflow_tpu.initializers import (
 )
 from flexflow_tpu.ops import pallas_kernels
 from flexflow_tpu.ops.base import CacheEntry, Op, ParamSpec, TensorSpec
+from flexflow_tpu.ops.short_conv import causal_taps, window_at
 
 #: Tokens a segment of a long prefill: projections, convolutions and
 #: the scan's operands exist for one segment at a time.
@@ -166,9 +168,7 @@ class KimiDeltaAttention(Op):
         behind the window before them.  Returns q, k, v (..., t, H, hd)
         in f32: convolved, SiLU, q and k normalised a head, q scaled."""
         a = self.attrs
-        wc = self._conv_weight(params)
-        ext = ext.astype(jnp.float32)
-        y = sum(ext[..., j:j + t, :] * wc[j] for j in range(a["conv_size"]))
+        y = causal_taps(ext, self._conv_weight(params), t)
         y = y * jax.nn.sigmoid(y)
         q, k, v = (s.reshape(s.shape[:-1] + (a["num_heads"], a["head_dim"]))
                    for s in jnp.split(y, 3, axis=-1))
@@ -209,7 +209,7 @@ class KimiDeltaAttention(Op):
         st, window)``."""
         a = self.attrs
         b, t, _ = x.shape
-        h, hd, kc = a["num_heads"], a["head_dim"], a["conv_size"] - 1
+        h, hd = a["num_heads"], a["head_dim"]
         length = jnp.int32(t) if length is None else length.astype(jnp.int32)
         chunk = pallas_kernels.KDA_CHUNK
         pad = -t % chunk if kernel else 0
@@ -238,9 +238,7 @@ class KimiDeltaAttention(Op):
                             st.reshape(b * h, hd, hd))
             o = jnp.moveaxis(o.reshape(seg, b, h, hd), 0, 1)
             # The window of the last real rows, where they end here.
-            at = length - start
-            cand = lax.dynamic_slice_in_dim(ext, jnp.clip(at, 0, seg), kc, axis=1)
-            window = jnp.where((at > 0) & (at <= seg), cand, window)
+            window = window_at(ext, window, length - start, seg)
             return ((st.reshape(b, h, hd, hd), ext[:, seg:], window),
                     self._output(params, xseg, o))
 

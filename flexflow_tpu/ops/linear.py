@@ -32,13 +32,20 @@ class Linear(Op):
         use_bias: bool = True,
         kernel_initializer=None,
         bias_initializer=None,
+        tied_to: Optional[str] = None,
     ):
+        """``tied_to`` (an embedding op's name): the kernel is that op's
+        ``table`` (``(out_dim, in_dim)``, the layout this op keeps its own
+        in), read and never owned: a tied head.  The op then declares no
+        kernel, and the leaf exists once in the parameter tree."""
         super().__init__(name, [x])
         assert x.ndim >= 2, f"linear input must be (batch, ..., features), got {x.shape}"
         check_activation(activation)
         cin = x.shape[-1]
         self.in_dim = cin
         self.attrs = dict(out_dim=out_dim, activation=activation, use_bias=use_bias)
+        if tied_to is not None:
+            self.tied = {"kernel": (tied_to, "table")}
         self.kernel_initializer = kernel_initializer or GlorotUniform()
         self.bias_initializer = bias_initializer or ZeroInitializer()
         # ND inputs (e.g. (batch, seq, features) in the NMT vocab
@@ -52,7 +59,7 @@ class Linear(Op):
         # Kernel is (out, in) — out-dim-major like the reference
         # (``linear.cu`` stores the kernel transposed) — and sharded on
         # its out-dim under a c-split.
-        specs = {
+        specs = {} if self.tied else {
             "kernel": ParamSpec(
                 (out_dim, self.in_dim),
                 self.outputs[0].dtype,
@@ -70,6 +77,11 @@ class Linear(Op):
         (x,) = xs
         plan = getattr(self, "_plan", None)
         if plan is not None and plan.assign(self._pc).get("c"):
+            if self.tied:
+                raise NotImplementedError(
+                    f"{self.name}: a kernel tied to {self.tied['kernel'][0]!r} "
+                    f"lies as that op holds it; no 'c' split of a tied head "
+                    f"is built (ROADMAP B-M)")
             # Pin the input REPLICATED along its contraction dim before
             # the dot.  Under a c-split the input arrives feature-
             # sharded, and GSPMD then has two algebraically-equal
@@ -91,7 +103,12 @@ class Linear(Op):
                 x, NamedSharding(plan.mesh, spec)
             )
         # bf16 operands accumulate in f32 on the MXU by default.
-        y = jnp.dot(x, params["kernel"].T)
+        kernel = params["kernel"]
+        if self.tied:
+            # The table keeps its owner's dtype (f32 under the embedding
+            # family's policy); the product runs in the activations'.
+            kernel = kernel.astype(x.dtype)
+        y = jnp.dot(x, kernel.T)
         if self.attrs["use_bias"]:
             y = y + params["bias"]
         return [apply_activation(y, self.attrs["activation"])], state

@@ -112,6 +112,7 @@ class MixtureOfExperts(Op):
         routed_scale: float = 1.0,
         n_group: int = 1,
         topk_group: int = 1,
+        norm_topk_eps: float = 1e-20,
     ):
         super().__init__(name, [x])
         if dispatch not in ("capacity", "sorted"):
@@ -182,6 +183,9 @@ class MixtureOfExperts(Op):
             routed_scale=float(routed_scale),
             n_group=int(n_group),
             topk_group=int(topk_group),
+            # What ``norm_topk_prob`` adds to the sum it divides by (a
+            # family's own: DeepSeek-V3's 1e-20, LFM2's 1e-6).
+            norm_topk_eps=float(norm_topk_eps),
         )
         self.d_model = d
         self.kernel_initializer = kernel_initializer or GlorotUniform()
@@ -347,7 +351,7 @@ class MixtureOfExperts(Op):
         _, idx = jax.lax.top_k(choice, a["top_k"])
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if a["top_k"] > 1 and a["norm_topk_prob"]:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + a["norm_topk_eps"])
         return idx, w * a["routed_scale"]
 
     def _kept_groups(self, choice):

@@ -895,15 +895,19 @@ def flash_decode_supported(cache_shape: Tuple[int, ...],
     of ``dtype``: whole lane tiles of positions, hd whole sublane tiles
     (tests/test_chip_compile.py holds the gate to what the TPU compiler
     accepts).  ``group`` query heads a cached head (grouped-query
-    attention) take the matrix-unit body, which wants ``hd`` in whole
-    lane tiles."""
+    attention) take the matrix-unit body, which takes ``hd`` in whole
+    lane tiles or half of one: a group's rows ``(g, 64)`` against a
+    chunk ``(64, chunk)`` are the same two products at half the
+    contraction (LFM2's 32 query heads over 8 cached heads of 64; the
+    cache then lies positions-major, as GPT-2's heads of 64 do, and the
+    chip stores it in the order the kernel reads)."""
     if len(cache_shape) != 4:
         return False
     _, s, h, hd = cache_shape
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
     if hd % sublanes or h > _LANES:
         return False
-    if group > 1 and hd % _LANES:
+    if group > 1 and hd % (_LANES // 2):
         return False
     return flash_decode_chunk(s, h, hd, dtype, group) >= _LANES
 
@@ -1254,7 +1258,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
             (b, s, h, hd), cache_k.dtype, group):
         raise ValueError(
             f"flash_decode needs a cache of whole 128-position lane "
-            f"tiles and whole sublane tiles of d_head (whole lane tiles "
+            f"tiles and whole sublane tiles of d_head (a multiple of 64 "
             f"under grouped queries); got q {q.shape}, cache shape "
             f"{cache_k.shape} {cache_k.dtype}.  Gate callers on "
             f"flash_decode_supported()."

@@ -26,7 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel
-from flexflow_tpu.ops.base import Op, TensorSpec
+from flexflow_tpu.ops.base import Op, TensorSpec, op_params
 from flexflow_tpu.optim import SGDOptimizer
 from flexflow_tpu.parallel.mesh import MeshPlan, build_mesh_plan
 from flexflow_tpu.parallel.strategy import ParallelConfig, StrategyStore
@@ -289,10 +289,14 @@ class Executor:
         if not getattr(self.optimizer, "supports_sparse_rows", False):
             return []
         input_names = {t.name for t in self.model.input_tensors}
+        # A table another op reads too (a tied head) gets that op's
+        # dense gradient as well: it stays on the dense path.
+        read_elsewhere = {owner for op in self.model.layers
+                          for owner, _ in op.tied.values()}
         out = []
         for op in self.model.layers:
             keys = op.sparse_keys()
-            if not keys:
+            if not keys or op.name in read_elsewhere:
                 continue
             if set(keys) != set(op.param_specs().keys()):
                 continue  # mixed dense+sparse params: keep dense
@@ -379,7 +383,7 @@ class Executor:
                     self._reshard_input(env[t.name], env_spec.get(t.name), t, op)
                     for t in op.inputs
                 ]
-                p = params.get(op.name, {})
+                p = op_params(op, params)
                 s = state.get(op.name, {})
                 if rows_override is not None and op.name in rows_override:
                     result, s_new = op.sparse_forward(

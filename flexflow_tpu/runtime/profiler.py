@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+from flexflow_tpu.ops.base import op_params
 from flexflow_tpu.runtime.executor import Executor
 
 
@@ -61,7 +62,7 @@ def profile_ops(
     for op in ex.model.layers:
         op.bind_mesh(ex.plan, ex._pc(op))
         xs = [env[t.name] for t in op.inputs]
-        p = params.get(op.name, {})
+        p = op_params(op, params)
         s = state.get(op.name, {})
 
         def run(p, xs, s, _op=op):

@@ -117,7 +117,7 @@ import numpy as np
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.graph import FFModel
 from flexflow_tpu.ops.attention import MultiHeadAttention, PositionEmbedding
-from flexflow_tpu.ops.base import SERVING_STATS_LARGEST
+from flexflow_tpu.ops.base import SERVING_STATS_LARGEST, op_params
 from flexflow_tpu.ops.linear import Linear
 from flexflow_tpu.ops.tensor_ops import Add
 from flexflow_tpu.runtime import telemetry as _telemetry
@@ -1013,7 +1013,8 @@ class ServingExecutor:
         ``LatentAttention`` one ``"ckr"`` of ``(max_batch, kv_rank +
         rope, max_seq)``, for ``KimiDeltaAttention`` a ``"state"`` of
         ``(max_batch, heads, d_head, d_head)`` float32 and a ``"conv"``
-        window, neither with a sequence axis.
+        window, neither with a sequence axis, for ``GatedShortConv`` its
+        ``"conv"`` window of ``(max_batch, taps - 1, dim)`` alone.
         Paged: ``{op: {"k"/"v": (kv_blocks, kv_block, heads,
         d_head)}}`` — the global block pool; slot structure lives in
         the block table."""
@@ -1073,15 +1074,16 @@ class ServingExecutor:
             op.positions_last = op.lane_tile_heads and self._positions_last
 
     def _refuse_stateful(self, what: str) -> None:
-        """A recurrent state has no rows: a rejected draft token cannot
-        be masked out of it and no prefix of it can be shared."""
+        """A recurrent state (or a convolution window) has no rows: a
+        rejected draft token cannot be masked out of it and no prefix of
+        it can be shared."""
         if self.stateful_ops:
             names = [op.name for op in self.stateful_ops]
             raise ValueError(
                 f"{what} needs caches whose every entry has a sequence "
                 f"axis; {names} ({type(self.stateful_ops[0]).__name__}) "
-                f"keep a recurrent state or a window's ring (ROADMAP "
-                f"Queue B)")
+                f"keep a recurrent state, a convolution window or a "
+                f"window's ring (ROADMAP Queue B)")
 
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.buckets:
@@ -1177,7 +1179,7 @@ class ServingExecutor:
                 if chunk:
                     s["chunk"] = int(chunk)
             with jax.named_scope(op.name):
-                ys, s_new = op.forward(params.get(op.name, {}), xs, s,
+                ys, s_new = op.forward(op_params(op, params), xs, s,
                                        training=False)
             if isinstance(op, Add) and \
                     ys[0].size * ys[0].dtype.itemsize > self.RESIDUAL_PIN_BYTES:

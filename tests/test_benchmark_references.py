@@ -13,7 +13,8 @@ _REFERENCES = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__fil
 
 
 @pytest.mark.parametrize("family", [
-    "deepseek_v3", "solar_open2", "xing4", "keye_vl2", "laguna", "axk2"])
+    "deepseek_v3", "solar_open2", "xing4", "keye_vl2", "laguna", "axk2",
+    "lfm2"])
 def test_a_reference_is_independent_and_exact(family):
     with open(os.path.join(_REFERENCES, family + ".py")) as fh:
         text = fh.read()

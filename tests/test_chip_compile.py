@@ -87,10 +87,7 @@ def _xent(n, v, dtype, grad):
 
 
 def _decode(b, s, h, hd, dtype):
-    fn = functools.partial(pk.flash_decode, interpret=False)
-    small, cache = _sds((b, h, hd), dtype), _sds((b, s, h, hd), dtype)
-    return (lambda: pk.flash_decode_supported((b, s, h, hd), dtype)), fn, (
-        small, small, small, cache, cache, _sds((b,), jnp.int32))
+    return _decode_grouped_major(b, s, h, h, hd, dtype)
 
 
 def _flash_uneven(t, h, qk, dv, h_kv=None):
@@ -121,6 +118,17 @@ def _decode_grouped(b, s, h, h_kv, hd, dtype):
     """Grouped queries over a positions-last cache, as the op declares it."""
     fn = functools.partial(pk.flash_decode, interpret=False, positions_last=True)
     new, cache = _sds((b, h_kv, hd), dtype), _sds((b, h_kv, hd, s), dtype)
+    gate = lambda: pk.flash_decode_supported((b, s, h_kv, hd), dtype, h // h_kv)
+    return gate, fn, (_sds((b, h, hd), dtype), new, new, cache, cache,
+                      _sds((b,), jnp.int32))
+
+
+def _decode_grouped_major(b, s, h, h_kv, hd, dtype):
+    """``h`` query heads over ``h_kv`` cached ones on a positions-major
+    cache: heads narrower than a lane tile, as the op declares them (the
+    chip stores that order as the kernel reads it: no cache-sized copy)."""
+    fn = functools.partial(pk.flash_decode, interpret=False)
+    new, cache = _sds((b, h_kv, hd), dtype), _sds((b, s, h_kv, hd), dtype)
     gate = lambda: pk.flash_decode_supported((b, s, h_kv, hd), dtype, h // h_kv)
     return gate, fn, (_sds((b, h, hd), dtype), new, new, cache, cache,
                       _sds((b,), jnp.int32))
@@ -336,6 +344,28 @@ CASES = {
         lambda: _attend_kept(1024, 4, 4, 512, 64, 64, shared=32),
     "attend_kept-gqa4x2-128x128-512-bf16":
         lambda: _attend_kept(512, 4, 2, 128, 128, 128),
+    # The lfm2.serve.closed192.p256-2k cell's kernels at its widths (32
+    # query heads over 8 key/value heads of 64, 64 experts of 2048 x
+    # 1536): 192 slots of 3072 positions, the 512 and 2048 prefill
+    # buckets, 768 assignments a decode step (1,728 rows of 16-row
+    # tiles) and a 2048-token prefill's 8192 (16,384 rows of 128-row
+    # tiles); and the smoke preset's.
+    "decode_grouped_major-192x3072x32x8x64-bf16":
+        lambda: _decode_grouped_major(192, 3072, 32, 8, 64, BF16),
+    "decode_grouped_major-4x512x8x2x64-bf16":
+        lambda: _decode_grouped_major(4, 512, 8, 2, 64, BF16),
+    "flash_uneven-gqa32x8-512x64-bf16":
+        lambda: _flash_uneven(512, 32, 64, 64, h_kv=8),
+    "flash_uneven-gqa32x8-2048x64-bf16":
+        lambda: _flash_uneven(2048, 32, 64, 64, h_kv=8),
+    "grouped_matmul-gated-decode-1728x2048x1536":
+        lambda: _grouped(1728, 64, 2048, 1536, 16, True),
+    "grouped_matmul-down-decode-1728x1536x2048":
+        lambda: _grouped(1728, 64, 1536, 2048, 16, False),
+    "grouped_matmul-gated-prefill-16384x2048x1536":
+        lambda: _grouped(16384, 64, 2048, 1536, 128, True),
+    "grouped_matmul-down-prefill-16384x1536x2048":
+        lambda: _grouped(16384, 64, 1536, 2048, 128, False),
     "gather_rows-1Mx64-1024ids":
         lambda: _rows("gather", (1 << 20, 64), 1024, "lane_major"),
     "scatter_add_rows-1Mx64-1024ids":
@@ -370,6 +400,7 @@ def _compiled_text(name: str) -> str:
     # caches, as the decode superstep donates them.
     donate = {"scatter_add_rows": (0,), "decode": (3, 4),
               "decode_grouped": (3, 4), "decode_ring": (3, 4),
+              "decode_grouped_major": (3, 4),
               "kda_chunk": (5,),
               "kda_decode": (5,), "mla_decode": (2,)}.get(
                   name.split("-")[0], ())
@@ -502,9 +533,10 @@ def test_supported_gates_match_the_compiler():
     # d_head on whole sublane tiles; what it refuses takes the einsum.
     assert not pk.flash_decode_supported((4, 1030, 8, 64), F32)
     assert not pk.flash_decode_supported((4, 512, 8, 8), BF16)
-    # Grouped queries and the delta rule's kernels want whole lane tiles
-    # of d_head.
-    assert not pk.flash_decode_supported((4, 512, 2, 64), BF16, group=4)
+    # Grouped queries want d_head in halves of a lane tile (PR 51: the
+    # matrix-unit body at 64), the delta rule's kernels whole lane tiles.
+    assert pk.flash_decode_supported((4, 512, 2, 64), BF16, group=4)
+    assert not pk.flash_decode_supported((4, 512, 2, 32), BF16, group=4)
     assert not pk.kda_supported(64, 64)
     # The latent kernels work on whole 128-position lane tiles and
     # whole 128-lane expert widths, and say so.
@@ -516,12 +548,13 @@ def test_supported_gates_match_the_compiler():
     assert not pk.attend_kept_supported((1, 4, 128, 32), (1, 4, 200, 24), 16, 8)
 
 
-#: The padded caches of the two cells whose decode step is
+#: The padded caches of the three cells whose decode step is
 #: ``ff_flash_decode``: (slots, max_seq, cached heads, d_head), query
 #: heads, the model width that gives them.
 _DECODE_CELLS = {
     "gpt2m.serve.closed48": ((48, 1024, 16, 64), 16, 1024),
     "solar2.serve.closed32.p4k-31k": ((32, 32768, 8, 128), 64, 4096),
+    "lfm2.serve.closed192.p256-2k": ((192, 3072, 8, 64), 32, 2048),
 }
 
 
@@ -541,7 +574,8 @@ def test_decode_gate_and_granule_hold_for_the_cells(cell):
 
 @pytest.mark.parametrize("cell,c", [("gpt2m.serve.closed48", 1),
                                     ("gpt2m.serve.closed48", 2),
-                                    ("solar2.serve.closed32.p4k-31k", 1)])
+                                    ("solar2.serve.closed32.p4k-31k", 1),
+                                    ("lfm2.serve.closed192.p256-2k", 1)])
 def test_decode_fetch_block_is_the_kernels_granule(cell, c):
     """``serve_kv_fetch_pct`` rounds a slot's length up to
     ``Op.decode_fetch_block``: for ``MultiHeadAttention``, with and
@@ -1148,6 +1182,11 @@ _TINY = chip_smoke.Sizes(
     serve_axk2=("--model-config", "axk2-tiny", "--max-seq", "128",
                 "--max-batch", "2", "--requests", "3", "--max-new", "6",
                 "--prompt-len", "40:100", "--buckets", "128"),
+    # Prompts of 20-60 end inside the 128 bucket: the windows are taken
+    # at the prompt's length.
+    serve_lfm2=("--model-config", "lfm2-tiny", "--max-seq", "128",
+                "--max-batch", "2", "--requests", "3", "--max-new", "6",
+                "--prompt-len", "20:60", "--buckets", "128"),
     dlrm4=("-b", "16", "-i", "3", "--momentum", "0", "--wd", "0",
            "--arch-sparse-feature-size", "8",
            "--arch-embedding-size", "100-100-100-100",
@@ -1184,7 +1223,7 @@ def _phases(which):
 @pytest.mark.parametrize(
     "phase", ["native", "train/alexnet", "train/transformer", "train/dlrm",
               "serve", "serve/latent", "serve/solar", "serve/xing",
-              "serve/keye", "serve/laguna", "serve/axk2"])
+              "serve/keye", "serve/laguna", "serve/axk2", "serve/lfm2"])
 def test_chip_smoke_one_chip_phase(phase, on_a_pretend_chip, capsys):
     """Each one-chip phase runs to its end at a tiny size: the apps'
     mains, the replayed loss trajectories, the sparse-vs-dense DLRM

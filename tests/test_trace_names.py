@@ -198,7 +198,7 @@ def test_lowered_serving_programs_carry_the_selectors_two_phases():
             jax.ShapeDtypeStruct((), np.int32)),
     }
     assert SCOPE_CATALOG == {"ff_loss", "ff_opt", "ff_index", "ff_select",
-                             "ff_gnorm", "ff_route_group"}
+                             "ff_gnorm", "ff_route_group", "ff_conv_state"}
     for kind, lowered in programs.items():
         # The compiled text's ``op_name``: what a trace's ``tf_op`` holds
         # (the lowering's ``loc`` forgets the op's scope inside a loop body).
