@@ -558,6 +558,24 @@ class MultiHeadAttention(Op):
         block = 0 if kernel is False else self._kernel_block(slots, max_seq, c)
         return block or max_seq
 
+    def decode_heads_per_step(self, slots: int, max_seq: int,
+                              kernel: Optional[bool]) -> int:
+        """The cached heads one step of ``flash_decode``'s grouped body
+        takes together on a device holding ``slots`` slots
+        (``pallas_kernels.flash_decode_heads_per_step``, by shape: the
+        ``serving_program`` event's ``decode_heads_per_step``); 0 where
+        this op's decode step does not run that body (one query head a
+        cached head, a selector's gather, a mesh, the einsum oracle)."""
+        a = self.attrs
+        if self.group == 1 or self.select is not None or kernel is False \
+                or not _on_one_device(self):
+            return 0
+        block = self._ring_block(slots, kernel) if a["window"] is not None \
+            else self._kernel_block(slots, max_seq)
+        return block and pallas_kernels.flash_decode_heads_per_step(
+            a["num_kv_heads"], a["head_dim"], self.group,
+            self.outputs[0].dtype)
+
     # -- helpers -----------------------------------------------------------
 
     def _project(self, params, x):

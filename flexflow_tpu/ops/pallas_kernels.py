@@ -842,7 +842,17 @@ def flash_attention_lse_chunked(q, k, v, causal: bool = True,
 # Several heads a loop iteration give the scheduler independent chains
 # to interleave; (m, l, acc) stay f32 in scratch.  Grouped queries
 # (``_decode_grouped_kernel``) take a whole chunk in two small matrix
-# products instead.
+# products instead, a cached head a loop turn.  Where a head is narrower
+# than a lane tile (``hd`` 64) a turn a head is a dependent chain of two
+# products on half-empty 128-deep weight tiles, paid 19 times a slot at
+# two or three chunks of eight heads: ``_decode_folded_kernel`` takes
+# ``flash_decode_heads_per_step`` cached heads a step through ONE pair
+# of products, the queries block diagonal against the heads' rows viewed
+# as one ``(heads * hd, chunk)`` operand, and a chunk is one basic block
+# (PERF.md §6 PR 52).  At ``hd`` >= 128 a head fills its weight tiles
+# and a block-diagonal query would only push more rows through each, so
+# those shapes keep the body they had: the fold is a function of the
+# shape, never a switch.
 #
 # The step's own K/V column is WRITTEN here too.  ``k_new``/``v_new``
 # enter as one ``(2, h, hd, slots -> lanes)`` operand resident in VMEM
@@ -869,6 +879,12 @@ _DECODE_RING_BYTES = 24 << 20
 _DECODE_VMEM_LIMIT = 64 << 20
 
 
+def _packed_rows(dtype) -> int:
+    """Rows of one vector register of ``dtype``: 8 sublanes, narrower
+    types packed two or four to a 32-bit word."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def flash_decode_chunk(s: int, h: int, hd: int, dtype, group: int = 1) -> int:
     """The granule the decode kernel fetches a slot's cache in, in
     positions: whole 128-lane tiles that divide the cache length and
@@ -880,7 +896,11 @@ def flash_decode_chunk(s: int, h: int, hd: int, dtype, group: int = 1) -> int:
     queries score a whole chunk in two matrix products, and what a
     chunk costs there is the loop's turn and the statistics, not its
     width (32 x 32768 x 8 x 128, 8 query heads a cached one: 2.37 /
-    1.29 / 0.73 ms at 128 / 256 / 512; PERF.md §6 PR 41)."""
+    1.29 / 0.73 ms at 128 / 256 / 512; PERF.md §6 PR 41).  The folded
+    body of heads of 64 (``flash_decode_heads_per_step``) pays one step
+    a chunk for several heads and keeps the 512: its call is then the
+    chunks' bytes (192 x 3072 x 8 x 64 at LFM2's mix: PERF.md §6 PR
+    52)."""
     item = jnp.dtype(dtype).itemsize
     fits = _DECODE_RING_BYTES // (2 * _DECODE_RING * h * hd * item)
     for chunk in ((512, 256, 128) if group > 1 else (128,)):
@@ -900,16 +920,39 @@ def flash_decode_supported(cache_shape: Tuple[int, ...],
     chunk ``(64, chunk)`` are the same two products at half the
     contraction (LFM2's 32 query heads over 8 cached heads of 64; the
     cache then lies positions-major, as GPT-2's heads of 64 do, and the
-    chip stores it in the order the kernel reads)."""
+    chip stores it in the order the kernel reads).  At half a lane tile
+    the body folds ``flash_decode_heads_per_step`` cached heads into
+    one step, so that a weight tile is full; at whole lane tiles a head
+    a step already fills it."""
     if len(cache_shape) != 4:
         return False
     _, s, h, hd = cache_shape
-    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    if hd % sublanes or h > _LANES:
+    if hd % _packed_rows(dtype) or h > _LANES:
         return False
     if group > 1 and hd % (_LANES // 2):
         return False
     return flash_decode_chunk(s, h, hd, dtype, group) >= _LANES
+
+
+def flash_decode_heads_per_step(h: int, hd: int, group: int, dtype) -> int:
+    """How many cached heads one step of the grouped decode body takes
+    together, from the shape alone.  A head of ``hd`` >= 128 fills the
+    matrix unit's 128-deep weight tiles by itself, and one query head a
+    cached head never reaches the matrix unit: 1, the bodies as they
+    were (``_decode_grouped_kernel``, ``_decode_kernel``).  Narrower
+    heads share a weight tile, ``128 // hd`` of them
+    (``_decode_folded_kernel``), and more while their groups' query
+    rows still fit one packed sublane tile of ``dtype``: rows the matrix
+    unit is handed and the statistics cover whether they hold a query
+    or pad (LFM2's 8 cached heads of 64 under groups of 4 in bfloat16:
+    4 a step, 16 rows and no pad)."""
+    unit = _LANES // max(hd, 1)
+    if group == 1 or unit < 2 or h % unit:
+        return 1
+    fold = unit
+    while 2 * fold * group <= _packed_rows(dtype) and h % (2 * fold) == 0:
+        fold *= 2
+    return fold
 
 
 def _lane_tile(t):
@@ -934,7 +977,9 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
     gets the chunk that holds ``pos`` (at offset ``at``): for every
     head ``i`` it calls ``place(0, k, i)`` and ``place(1, v, i)``, which
     store this step's column into the lane tile that holds ``at`` and
-    return that tile (hd, 128), and scores the chunk up to ``at``; the
+    return that tile (hd, 128) (or once ``place(0, k)`` and ``place(1,
+    v)``: every head's column in one select, nothing returned), and
+    scores the chunk up to ``at``; the
     tile then goes back to the cache while ``emit()`` writes the slot's
     output.  The columns come in ``new_ref`` (2, h, hd / p, slots ->
     lanes) as 32-bit words of p values each, the packing a vector
@@ -1005,7 +1050,19 @@ def _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
     tile, lane = _lane_tile(at // _LANES), lax.rem(at, _LANES)
     here = _lane_iota(new_ref.shape[2]) == lane
 
-    def place(which, ref, head):
+    def place(which, ref, head=None):
+        if head is None:
+            # Every head's column in one select: the tile (h, hd, 128)
+            # as (h * hd, 128), whole packed sublane tiles a head.
+            column = _move_lane(
+                new_ref[which, :, :, _lane_tile(b // _LANES)].reshape(
+                    -1, _LANES), lax.rem(b, _LANES), lane)
+            words = pltpu.bitcast(ref[:, :, tile].reshape(-1, _LANES),
+                                  jnp.uint32)
+            ref[:, :, tile] = pltpu.bitcast(
+                jnp.where(_lane_iota(words.shape[0]) == lane, column, words),
+                ref.dtype).reshape(*ref.shape[:2], _LANES)
+            return
         column = _move_lane(new_ref[which, head, :, _lane_tile(b // _LANES)],
                             lax.rem(b, _LANES), lane)
         words = pltpu.bitcast(ref[head, :, tile], jnp.uint32)
@@ -1212,10 +1269,81 @@ def _decode_grouped_kernel(len_ref, q_ref, new_ref, k_in, v_in,
                    lambda i: step(i, k_ref[i], v_ref[i])))
 
 
-def _decode_ring_kernel(len_ref, at_ref, *refs, scale):
-    """``_decode_grouped_kernel`` over a ring: a second scalar operand
-    says where each slot's column is written."""
-    _decode_grouped_kernel(len_ref, *refs, scale=scale, at_ref=at_ref)
+def _decode_folded_kernel(len_ref, q_ref, new_ref, k_in, v_in,
+                          o_ref, k_hbm, v_hbm, ring_k, ring_v, sem, wsem,
+                          cur, m_scr, l_scr, acc_scr, *, scale, group,
+                          at_ref=None):
+    """The grouped-query body where a head is narrower than a lane tile:
+    ``fold`` cached heads go through the matrix unit together, and a
+    chunk is one basic block for all of them, not a loop turn a head.
+
+    ``q_ref`` (1, h_kv / fold, rows, fold * hd) holds the queries block
+    diagonal: row ``i * group + j`` of a step is query head ``j`` of the
+    step's cached head ``i`` in columns ``i * hd ... (i + 1) * hd`` and
+    zeros elsewhere, so against the step's ``(fold * hd, chunk)`` view of
+    the ring (whole packed sublane tiles a head: no move) every row
+    scores its own head, the zeros adding exact 0.0 to the float32
+    accumulator.  ``p @ v^T`` is ``(rows, fold * hd)`` with the wanted
+    ``(group, hd)`` of each head on the diagonal; the other blocks ride
+    along in ``acc_scr`` and ``emit`` drops them.  The steps of a chunk
+    are independent chains the scheduler interleaves.  The stream, the
+    written tile (one select for all heads) and the aliasing are
+    ``_decode_kernel``'s."""
+    del k_in, v_in
+    _, _, hd, chunk = ring_k.shape
+    _, steps, rows, width = q_ref.shape
+    fold = width // hd
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def chunk_step(k_ref, v_ref, valid=None):
+        for t in range(steps):
+            heads = slice(t * fold, (t + 1) * fold)
+            k = k_ref[heads].reshape(width, chunk)
+            v = v_ref[heads].reshape(width, chunk)
+            m, l, acc = m_scr[t], l_scr[t], acc_scr[t]
+            s = jnp.dot(q_ref[0, t], k, precision=_mxu_precision(k.dtype),
+                        preferred_element_type=jnp.float32) * scale
+            if valid is not None:
+                s = jnp.where(valid, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            corr = jnp.exp(m - m_new)
+            pv = lax.dot_general(p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                                 precision=_mxu_precision(v.dtype),
+                                 preferred_element_type=jnp.float32)
+            m_scr[t] = m_new
+            l_scr[t] = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[t] = acc * corr[:, :1] + pv
+
+    def last(k_ref, v_ref, at, place):
+        place(0, k_ref)
+        place(1, v_ref)
+        chunk_step(k_ref, v_ref,
+                   lax.broadcasted_iota(jnp.int32, (1, chunk), 1) <= at)
+
+    def emit():
+        head = lax.broadcasted_iota(jnp.int32, (rows, hd), 0) // group
+        for t in range(steps):
+            acc = acc_scr[t]
+            own = acc[:, :hd]
+            for i in range(1, fold):
+                own = jnp.where(head == i, acc[:, i * hd:(i + 1) * hd], own)
+            o_ref[0, t] = (own / l_scr[t][:, :1]).astype(o_ref.dtype)
+
+    _kv_stream(len_ref, new_ref, k_hbm, v_hbm, ring_k, ring_v,
+               sem, wsem, cur, last=last, emit=emit, at_ref=at_ref,
+               whole=chunk_step)
+
+
+def _decode_ring_kernel(len_ref, at_ref, *refs, body):
+    """A grouped ``body`` over a ring: a second scalar operand says
+    where each slot's column is written."""
+    body(len_ref, *refs, at_ref=at_ref)
 
 
 def flash_decode(q, k_new, v_new, cache_k, cache_v, lengths,
@@ -1284,6 +1412,7 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
     b, h, hd, s = cache_k.shape
     group = q.shape[1] // h
     chunk = flash_decode_chunk(s, h, hd, cache_k.dtype, group)
+    fold = flash_decode_heads_per_step(h, hd, group, q.dtype)
     scale = 1.0 / math.sqrt(hd)
 
     def slot(bi, *_):
@@ -1316,21 +1445,37 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
         o_spec = pl.BlockSpec((1, hd, _LANES), lambda bi, *_: (bi, 0, 0))
         state = [(h, 1, _LANES), (h, 1, _LANES), (h, hd, _LANES),
                  (h, hd, _LANES)]
-    else:
+    elif fold == 1:
         # Queries and outputs (B, h, g, hd): a group's heads as rows,
         # padded to a whole packed sublane tile of the query's dtype.
         kernel = functools.partial(_decode_grouped_kernel, scale=scale)
-        rows = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
-        gp = _round_up(group, rows)
+        gp = _round_up(group, _packed_rows(q.dtype))
         q_in = jnp.pad(q.reshape(b, h, group, hd),
                        ((0, 0), (0, 0), (0, gp - group), (0, 0)))
         o_shape = (b, h, gp, hd)
         q_spec = o_spec = pl.BlockSpec((1, h, gp, hd), slot)
         state = [(h, gp, _LANES), (h, gp, _LANES), (h, gp, hd)]
+    else:
+        # ``fold`` cached heads a step: their groups' heads as rows of
+        # one block-diagonal operand (B, h / fold, fold * g, fold * hd),
+        # the rows padded as above; outputs (B, h / fold, fold * g, hd).
+        kernel = functools.partial(_decode_folded_kernel, scale=scale,
+                                   group=group)
+        steps, gp = h // fold, _round_up(fold * group, _packed_rows(q.dtype))
+        q_in = jnp.where(
+            jnp.eye(fold, dtype=bool)[:, None, :, None],
+            q.reshape(b, steps, fold, group, 1, hd), jnp.zeros((), q.dtype))
+        q_in = jnp.pad(q_in.reshape(b, steps, fold * group, fold * hd),
+                       ((0, 0), (0, 0), (0, gp - fold * group), (0, 0)))
+        o_shape = (b, steps, gp, hd)
+        q_spec = pl.BlockSpec((1, steps, gp, fold * hd), slot)
+        o_spec = pl.BlockSpec((1, steps, gp, hd), slot)
+        state = [(steps, gp, _LANES), (steps, gp, _LANES),
+                 (steps, gp, fold * hd)]
     cache = jax.ShapeDtypeStruct((b, h, hd, s), cache_k.dtype)
     scalars = (jnp.clip(lengths.astype(jnp.int32), 1, s),)
     if write_at is not None:
-        kernel = functools.partial(_decode_ring_kernel, scale=scale)
+        kernel = functools.partial(_decode_ring_kernel, body=kernel)
         scalars += (jnp.clip(write_at.astype(jnp.int32), 0, scalars[0] - 1),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -1362,7 +1507,7 @@ def _decode_call(q, k_new, v_new, cache_k, cache_v, lengths, interpret,
     if group == 1:
         out = jnp.swapaxes(out[:, :, :h], 1, 2)
     else:
-        out = out[:, :, :group].reshape(b, h * group, hd)
+        out = out[:, :, :fold * group].reshape(b, h * group, hd)
     if positions_last:
         return out, cache_k, cache_v
     return out, cache_k.transpose(0, 3, 1, 2), cache_v.transpose(0, 3, 1, 2)

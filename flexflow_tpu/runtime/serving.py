@@ -1338,6 +1338,21 @@ class ServingExecutor:
         return {k: sum(f[k] for f in found) // len(found)
                 for k in ("causal_blocks", "causal_steps") if found}
 
+    def decode_heads_per_step(self) -> Dict[str, int]:
+        """``decode_heads_per_step`` as the decode superstep's
+        ``serving_program`` carries it: the cached heads one step of
+        ``ff_flash_decode``'s grouped body takes together
+        (``ops/attention.py::decode_heads_per_step``, asked of each op
+        by shape; the most where layers differ), so that a run's stream
+        says which body was compiled.  Nothing where no op's decode step
+        runs the grouped body."""
+        n = (self.shard or (1, 1))[0]
+        found = [op.decode_heads_per_step(self.max_batch // n, self.max_seq,
+                                          self.decode_kernel)
+                 for op in self.attn_ops
+                 if hasattr(op, "decode_heads_per_step")]
+        return {"decode_heads_per_step": max(found)} if any(found) else {}
+
     def _attention_paths(self, decode: bool) -> str:
         """Which attention formulation this program's cache-holding ops
         compile (``serving_program.attention``)."""
@@ -1675,6 +1690,7 @@ class ServingExecutor:
             sharded=self.shard is not None,
             sampled=sample is not None,
             attention=self._attention_paths(True),
+            **self.decode_heads_per_step(),
         )
         return fn
 

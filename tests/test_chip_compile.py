@@ -696,6 +696,53 @@ def test_solar_decode_superstep_holds_no_cache_or_state_sized_relayout(
         l for l in text.splitlines() if "bf16[4096,24576]" in l)
 
 
+def test_lfm2_decode_superstep_folds_its_heads_and_moves_no_cache(monkeypatch):
+    """The scanned decode step of ``lfm2.serve.closed192.p256-2k`` over
+    its first three layers (convolution, convolution, grouped-query
+    attention over 8 cached heads of 64 under 32) at the cell's widths
+    and slots: Mosaic takes the decode kernel's folded body (four cached
+    heads a step, announced by the program's event) under the name the
+    benchmark's metrics read, and both caches go from parameter to
+    kernel to result positions-major, where they lie."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_lm
+    from flexflow_tpu.runtime.executor import Executor
+    from flexflow_tpu.runtime.serving import ServingExecutor
+
+    dev = _four_chips()[0]
+    monkeypatch.setattr(pk, "_interpret_default", lambda: False)
+    from benchmark import common
+
+    model = common.load_json(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "lfm2-24b-a2b-l10.json")
+    model.update(num_hidden_layers=3, vocab_size=1024)
+    slots, seq = 192, 3072
+    cfg = FFConfig(batch_size=slots, compute_dtype="bfloat16")
+    lm = build_lm(model, slots, seq, cfg)
+    sex = ServingExecutor(lm, cfg, max_batch=slots, max_seq=seq,
+                          buckets=(seq,), decode_kernel=True, device=dev)
+    assert sex.decode_heads_per_step() == {"decode_heads_per_step": 4}
+    params, _opt, state = Executor(lm, config=cfg,
+                                   devices=[dev])._abstract_init()
+    placed = lambda a: _sds(a.shape, a.dtype)
+    caches = sex._cache_tree(
+        sex._cache_specs,
+        lambda ce: _sds((slots,) + tuple(ce.shape), ce.dtype))
+    assert caches["blk2_attn"]["k"].shape == (slots, seq, 8, 64)
+    vec = _sds((slots,), jnp.int32)
+    text = sex.build_decode_superstep(8).lower(
+        jax.tree.map(placed, params), jax.tree.map(placed, state), caches,
+        vec, vec).compile().as_text()
+    for name in ("ff_flash_decode", "ff_grouped_matmul"):
+        assert chip_smoke.has_kernel(text, name), name
+    assert chip_smoke.cache_or_state_relayouts(text, caches) == []
+    layout = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
+    ins, outs = layout.split(")->(")
+    kv = f"bf16[{slots},{seq},8,64]{{1,3,2,0:T(8,128)(2,1)}}"
+    assert ins.count(kv) == outs.count(kv) == 2
+
+
 def test_solar_smoke_prefill_prepares_the_scan_inside_its_kernel(monkeypatch):
     """``chip_smoke.py``'s ``serve/solar`` prefill (the smoke preset: a
     bucket of 256 tokens, three delta layers of 2 heads of 128)

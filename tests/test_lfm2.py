@@ -44,6 +44,10 @@ _KERNEL_WIDTHS = dict(
     vocab_size=512, num_attention_heads=4, num_key_value_heads=1,
     intermediate_size=256)
 
+#: ... with two cached heads under eight: the decode kernel folds them.
+_TWO_CACHED_HEADS = dict(hidden_size=512, num_attention_heads=8,
+                         num_key_value_heads=2)
+
 
 def _cfg(dtype="float32", base=LFM2_TINY, **over):
     return dict(base, **over, assumed=dict(_ASSUMED, param_dtype=dtype))
@@ -181,6 +185,8 @@ def _serve_logits(params, ff, toks, plen, kernel, steps, bucket=S):
     (LFM2_TINY, "float32", None, 1e-5),
     (_KERNEL_WIDTHS, "float32", True, 2e-5),
     (_KERNEL_WIDTHS, "float32", False, 2e-5),
+    # Two cached heads: the decode kernel's folded body.
+    (dict(_KERNEL_WIDTHS, **_TWO_CACHED_HEADS), "float32", True, 2e-5),
     # bf16 weights, activations, KV cache and window against the f32
     # reference on the same (bf16-rounded) weights: judged by the median
     # and the share of logits far off (a flipped near-tie between two
@@ -361,6 +367,75 @@ def test_grouped_decode_kernel_at_heads_of_64_equals_the_einsum_oracle(s, lens):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+def _decode_oracle(q, k1, v1, ck, cv, live, at):
+    """One decode step in numpy over (B, S, h, hd) caches: the column
+    written at ``at``, the first ``live`` positions attended."""
+    q, k1, v1, ck, cv = (np.asarray(x.astype(jnp.float32)) for x in
+                         (q, k1, v1, ck, cv))
+    ck, cv = ck.copy(), cv.copy()
+    g = q.shape[1] // ck.shape[2]
+    out = np.zeros(q.shape, np.float32)
+    for i in range(q.shape[0]):
+        ck[i, at[i]], cv[i, at[i]] = k1[i], v1[i]
+        for j in range(q.shape[1]):
+            sc = ck[i, :live[i], j // g] @ q[i, j] / np.sqrt(q.shape[2])
+            w = np.exp(sc - sc.max())
+            out[i, j] = (w / w.sum()) @ cv[i, :live[i], j // g]
+    return out, ck, cv
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["full", "ring"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hkv,g", [(8, 4), (2, 4), (4, 2), (1, 8)])
+def test_decode_body_folded_over_heads_of_64_equals_the_oracle(hkv, g, dtype, ring):
+    """The grouped body at ``hd`` 64 with several cached heads a step
+    (``flash_decode_heads_per_step``; one cached head alone keeps the
+    body a head a turn) against a plain oracle: lengths on both sides of
+    a lane tile's and of a 512-position chunk's edge, over a full cache
+    and over a ring (``write_at``: not yet full, just full, wrapped).
+    The caches come back equal to the caches that went in, bit for bit,
+    but for each slot's one written column (so nothing outside the lane
+    tile that holds it moved, and inside it the column alone)."""
+    s, hd = 1024, 64
+    dt = jnp.dtype(dtype)
+    r = np.random.default_rng(hkv * 16 + g)
+    pos = np.asarray([0, 126, 127, 128, 511, 512, s - 1] if not ring else
+                     [5, 127, 128, 512, s - 1, s + 7, 3 * s + 200])
+    b = len(pos)
+    q = jnp.asarray(r.normal(size=(b, hkv * g, hd)), dt)
+    k1, v1 = (jnp.asarray(r.normal(size=(b, hkv, hd)), dt) for _ in range(2))
+    ck, cv = (jnp.asarray(r.normal(size=(b, s, hkv, hd)), dt) for _ in range(2))
+    live, at = np.minimum(pos + 1, s).astype(np.int32), (pos % s).astype(np.int32)
+    assert pk.flash_decode_supported((b, s, hkv, hd), dt, g)
+    fold = pk.flash_decode_heads_per_step(hkv, hd, g, dt)
+    assert (fold > 1) == (hkv > 1) and hkv % fold == 0
+    out, nk, nv = pk.flash_decode(
+        q, k1, v1, ck, cv, jnp.asarray(live),
+        write_at=jnp.asarray(at) if ring else None)
+    want, wk, wv = _decode_oracle(q, k1, v1, ck, cv, live, at)
+    np.testing.assert_array_equal(np.asarray(nk.astype(jnp.float32)), wk)
+    np.testing.assert_array_equal(np.asarray(nv.astype(jnp.float32)), wv)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), want,
+                               atol=2e-5 if dtype == "float32" else 2e-2)
+
+
+@pytest.mark.parametrize("h,hd,g,dtype,heads", [
+    (8, 64, 4, "bfloat16", 4),    # lfm2.serve: 16 query rows a step
+    (8, 64, 4, "float32", 2),     # a float32 tile holds 8 rows
+    (2, 64, 4, "bfloat16", 2),    # never more than the heads there are
+    (4, 64, 2, "bfloat16", 4),
+    (3, 64, 4, "bfloat16", 1),    # an odd head count does not pair
+    (8, 128, 8, "bfloat16", 1),   # solar2.serve
+    (8, 128, 6, "bfloat16", 1),   # laguna.serve's full caches
+    (8, 128, 9, "bfloat16", 1),   # ... and its rings
+    (16, 64, 1, "bfloat16", 1),   # gpt2m.serve: the vector body
+])
+def test_heads_a_decode_step_follow_the_shape_alone(h, hd, g, dtype, heads):
+    """Folded only where a head is narrower than a lane tile and
+    queries are grouped: the long-context cells keep a head a turn."""
+    assert pk.flash_decode_heads_per_step(h, hd, g, jnp.dtype(dtype)) == heads
+
+
 def test_the_decode_program_at_heads_of_64_calls_the_kernel_not_the_einsum():
     """The attention op at the published head width and group decodes
     through ``flash_decode`` (its gate takes the shape), over a cache
@@ -375,6 +450,35 @@ def test_the_decode_program_at_heads_of_64_calls_the_kernel_not_the_einsum():
     assert op.decode_fetch_block(192, 3072, None) == 512
     assert op.decode_fetch_block(192, 3072, False) == 3072
     assert op.serving_path(True) == "gqa_decode"
+    # Four cached heads a step of the kernel's grouped body; none of it
+    # under the einsum oracle.
+    assert op.decode_heads_per_step(192, 3072, None) == 4
+    assert op.decode_heads_per_step(192, 3072, False) == 0
+
+
+@pytest.mark.parametrize("over,kernel,want", [
+    (_TWO_CACHED_HEADS, None, {"decode_heads_per_step": 2}),
+    (_TWO_CACHED_HEADS, False, {}),              # the einsum oracle
+    ({}, None, {"decode_heads_per_step": 1}),    # one cached head: a head a turn
+])
+def test_the_decode_program_says_how_many_heads_a_step_folds(over, kernel, want,
+                                                            tmp_path):
+    """``serving_program`` of the decode superstep carries
+    ``decode_heads_per_step`` where an op decodes through the kernel's
+    grouped body, so a run's stream says which body was compiled; a
+    prefill program never does."""
+    ff = build_lm(dict(_KERNEL_WIDTHS, **over), 2, S, FFConfig(batch_size=2))
+    sex = ServingExecutor(ff, max_batch=2, max_seq=S, buckets=(S,),
+                          decode_kernel=kernel)
+    assert sex.decode_heads_per_step() == want
+    with telemetry.Telemetry(directory=str(tmp_path)) as tel:
+        sex.build_decode_superstep(2)
+        sex.build_prefill(S)
+    programs = {e["kind"]: e for e in common.read_events(tel.path)
+                if e["ev"] == "serving_program"}
+    assert {k: v for k, v in programs["decode"].items()
+            if k == "decode_heads_per_step"} == want
+    assert "decode_heads_per_step" not in programs["prefill"]
 
 
 def _req(rid, plen, max_new):

@@ -18,8 +18,9 @@ SIDE     ``change`` (this tree), ``parent`` (``_parent/``, a ``git archive``
          queued chip call copies the tree when it starts, not when it
          was asked for)
 SHAPE    ``gpt2`` (48, 1024, 16, 64) bf16; ``solar`` (32, 32768, 8, 128) bf16,
-         8 query heads a cached head, positions last; ``tiny``/``tinyg``
-         (a CPU rehearsal of the script, never a number)
+         8 query heads a cached head, positions last; ``lfm2`` (192, 3072,
+         8, 64) bf16, 4 query heads a cached head; ``tiny``/``tinyg``/
+         ``tinyf`` (a CPU rehearsal of the script, never a number)
 LENGTHS  ``ones`` | ``full`` | ``half`` | ``mix`` (the cell's: its occupancy's
          share of the slots hold the backlog's first requests half way
          through their budgets, the others idle) | a number
@@ -47,8 +48,10 @@ SHAPES = {
     # slots, max_seq, kv heads, hd, group, positions_last, traffic file
     "gpt2": (48, 1024, 16, 64, 1, False, "closed48"),
     "solar": (32, 32768, 8, 128, 8, True, "closed32.p4k-31k"),
+    "lfm2": (192, 3072, 8, 64, 4, False, "closed192.p256-2k"),
     "tiny": (6, 512, 4, 64, 1, False, "closed48"),
     "tinyg": (4, 1024, 2, 128, 4, True, "closed32.p4k-31k"),
+    "tinyf": (6, 1024, 4, 64, 4, False, "closed192.p256-2k"),
 }
 
 
@@ -71,7 +74,8 @@ def mix_lengths(shape):
     mix = json.load(open(os.path.join(ROOT, "benchmark", "traffic", traffic + ".json")))
     n = int(round(mix["requests_per_second"] * 30))
     reqs = workload_gen.closed_backlog(mix, n, 1, 16)
-    occupancy = 0.67 if traffic == "closed48" else 0.377
+    # Mean occupancy of each cell's window (PERF.md §2).
+    occupancy = {"closed48": 0.67, "closed192.p256-2k": 0.566}.get(traffic, 0.377)
     active = int(round(b * occupancy))
     lens = [min(len(r["prompt"]) * s // mix["max_seq"] + r["max_new_tokens"] // 2, s)
             for r in reqs[:active]]
@@ -174,7 +178,7 @@ def run(spec):
 
 if __name__ == "__main__":
     print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
-    for shape in ("gpt2", "solar"):
+    for shape in ("gpt2", "solar", "lfm2"):
         lens = mix_lengths(shape)
         s = SHAPES[shape][1]
         print(f"mix {shape}: mean {lens.mean():.1f} ({lens.mean() / s * 100:.2f}% live); fetched at 128/256/512: "
