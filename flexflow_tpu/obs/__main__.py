@@ -118,11 +118,37 @@ def cmd_report(args) -> int:
         for e in costs:
             extra = {k: v for k, v in e.data.items()
                      if k not in ("ts", "seq", "ev", "kind", "flops",
-                                  "bytes_accessed", "transcendentals")}
+                                  "bytes_accessed", "transcendentals",
+                                  "wall_s")}
             print(f"  {e.get('kind')}: "
                   f"{float(e.get('flops', 0.0)) / 1e9:.3f} GF, "
                   f"{float(e.get('bytes_accessed', 0.0)) / 1e6:.1f} MB"
+                  + (f", probe {float(e['wall_s']):.3f} s"
+                     if "wall_s" in e.data else "")
                   + (f"  {extra}" if extra else ""))
+    builds = log.program_builds()
+    if builds["rows"] or builds["small"]["n"]:
+        print("program builds (jax's own trace / lowering / compile "
+              "spans, s):")
+        for r in builds["rows"]:
+            name = r["fun"] + (f" [{r['shape']}]" if r["shape"] else "")
+            print(f"  {name:<40} lowered x{r['lowered']} compiled "
+                  f"x{r['compiled']}  trace {r['trace_s']:.3f}  lower "
+                  f"{r['lower_s']:.3f}  compile {r['compile_s']:.3f}  "
+                  f"cache {r['hits']} hit / {r['misses']} not")
+        sm = builds["small"]
+        if sm["n"]:
+            print(f"  small (under 10 ms each): {sm['n']} programs, trace "
+                  f"+ lower {sm['trace_lower_s']:.3f}, compile "
+                  f"{sm['compile_s']:.3f}, {sm['misses']} not from the "
+                  f"cache"
+                  + (f"; {sm['dropped']} records dropped before this "
+                     f"stream opened" if sm["dropped"] else ""))
+        for b in builds["steady"]:
+            print(f"  BUILD IN STEADY STATE: "
+                  f"{b.get('fun', str(b.get('n')) + ' small programs')} "
+                  f"{b.get('phase')} {float(b.get('wall_s', 0.0)):.3f} s "
+                  f"(cache {b.get('cache', '-')}) at t0 {b.get('t0')}")
     ts = log.trace_summary()
     if ts:
         print(f"trace summary (device busy "

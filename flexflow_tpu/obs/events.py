@@ -35,6 +35,7 @@ EVENT_CATALOG = frozenset({
     "fence",
     "compiled_step",
     "program_cost",
+    "program_build",
     "embedding_gather",
     "embedding_combine",
     "embedding_rows",

@@ -94,6 +94,22 @@ def round_steps(ev: Any) -> List[float]:
     return [float(ev["wall_s"]) / k] * k
 
 
+#: Events that mark steady state: a build made after the first of
+#: them (past the stream's last ``serve_run``, where it has one: the
+#: runs before the last are warm-ups) was paid inside the loop.
+STEADY_EVENTS = frozenset({"decode_superstep", "spec_verify", "step",
+                           "superstep"})
+
+#: Which ``serving_program`` kinds a serving program's ``fun`` may be:
+#: the report shows a build beside the last such line before it (its
+#: ``bucket`` / ``k`` / ``d``), a join by order.
+_SERVING_FUNS = {
+    "jit(prefill)": ("prefill", "prefill_from", "draft_prefill"),
+    "jit(superstep)": ("decode",),
+    "jit(spec)": ("spec",),
+}
+
+
 def _pct(sorted_vals: Sequence[float], p: float) -> float:
     """Nearest-rank percentile — EXACTLY ``Telemetry.step_summary``'s
     formula, so reconstruction is bit-identical."""
@@ -417,6 +433,62 @@ class RunLog:
         if end is not None and isinstance(end.get("calibration"), dict):
             return dict(end["calibration"])
         return {}
+
+    def program_builds(self) -> Dict[str, Any]:
+        """The ``program_build`` records folded for the report
+        (OBSERVABILITY.md "Program builds"): ``rows``, one a
+        ``(fun, shape)`` in order of first sight, with its lowerings
+        and compiles counted and their seconds summed (``shape`` the
+        ``bucket=`` / ``k=`` / ``d=`` of the last ``serving_program``
+        of the program's kind before the build, empty elsewhere);
+        ``small``, the ``small`` lines summed; ``steady``, every build
+        whose ``t0`` lies after the first steady-state event."""
+        rows: Dict[tuple, Dict[str, Any]] = {}
+        small = {"n": 0, "trace_lower_s": 0.0, "compile_s": 0.0,
+                 "misses": 0, "dropped": 0}
+        builds = sorted(self.select("program_build"),
+                        key=lambda e: float(e.get("t1", e.ts)))
+        programs = self.select("serving_program")
+        last_run = max((i for i, e in enumerate(self.events)
+                        if e.ev == "serve_run"), default=0)
+        steady_ts = next((e.ts for e in self.events[last_run:]
+                          if e.ev in STEADY_EVENTS), None)
+        steady = []
+        for b in builds:
+            if b.get("phase") == "small":
+                for k in small:
+                    small[k] += b.get(k, 0)
+                small["dropped"] = b.get("dropped", 0)  # a running count
+                if steady_ts is not None and float(b.get("t1", 0.0)) > steady_ts:
+                    steady.append(b)  # its last program, at the least
+                continue
+            fun = str(b.get("fun"))
+            shape = ""
+            for sp in reversed(programs):
+                if sp.ts <= float(b.get("t0", b.ts)) \
+                        and sp.get("kind") in _SERVING_FUNS.get(fun, ()):
+                    shape = ", ".join(f"{k}={sp[k]}" for k in
+                                      ("bucket", "k", "d") if k in sp.data)
+                    break
+            row = rows.setdefault((fun, shape), {
+                "fun": fun, "shape": shape, "lowered": 0, "compiled": 0,
+                "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                "hits": 0, "misses": 0})
+            wall = float(b.get("wall_s", 0.0))
+            if b.get("phase") == "compile":
+                row["compiled"] += 1
+                row["compile_s"] += wall
+                row["hits" if b.get("cache") == "hit" else "misses"] += 1
+            elif b.get("phase") == "lower":
+                row["lowered"] += 1
+                row["lower_s"] += wall
+                row["trace_s"] += float(b.get("trace_s", 0.0))
+            else:
+                row["trace_s"] += wall
+            if steady_ts is not None and float(b.get("t0", 0.0)) > steady_ts:
+                steady.append(b)
+        return {"rows": list(rows.values()), "small": small,
+                "steady": steady}
 
     def trace_summary(self) -> Dict[str, Any]:
         """The device-time attribution block on ``run_end`` (present

@@ -40,10 +40,20 @@ Design (OBSERVABILITY.md has the full event schema):
   (``DIR/heartbeat``, or ``FF_HEARTBEAT_FILE``) so an external
   supervisor shares the same liveness signal as the in-process
   monitor.
+- A process-level **build log** (:class:`BuildLog`, OBSERVABILITY.md
+  "Program builds"): jax times its own trace, lowering and backend
+  compile of every program and hands the spans to any
+  ``jax.monitoring`` listener; this module registers the listeners
+  once, at import, and folds the spans into ``program_build`` records.
+  The log is the process's, not a stream's — programs are built before
+  a stream opens — and a stream writes what the log holds when it
+  opens, then each record as it is made.  A listener fires only while
+  jax traces, lowers or compiles, never in a cached dispatch.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import logging
@@ -53,6 +63,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
+import jax.monitoring
 
 _log = logging.getLogger("ff.telemetry")
 
@@ -88,6 +99,255 @@ CALIBRATION_FENCE_EXCLUDE = frozenset({"warmup", "final"})
 #: quick fits in one process would otherwise append-interleave into the
 #: same JSONL file (breaking the one-file-per-run contract).
 _RUN_COUNTER = itertools.count()
+
+
+#: jax's own timers of a build's three phases
+#: (``jax._src.dispatch.LogElapsedTimeContextManager``: both edges are
+#: ``time.time()``, the clock of every event's ``ts``; ``fun_name`` is
+#: ``f`` on the trace and ``jit(f)`` on the other two).
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: What the persistent cache says of the compile request in flight.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/compile_requests_use_cache": "asked",
+}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class BuildLog:
+    """Every program the process builds, folded from jax's own spans
+    into ``program_build`` records (OBSERVABILITY.md "Program builds").
+
+    The three listeners are the whole write side.  Spans arrive at
+    their END, so whatever a span contains has arrived before it:
+
+    - a **trace** span drops the pending trace spans it contains (the
+      nested jits and ``jnp`` helpers traced inside a program) and
+      waits as pending; its seconds, like a lowering's, are its span
+      less any build made inside it, so that each second is booked
+      once;
+    - a **lower** span of ``jit(f)`` takes the pending trace of ``f``
+      as its ``trace_s``; any other pending trace of
+      :attr:`TRACE_MIN_S` or more is a program of its own (an
+      ``eval_shape``, a re-trace whose lowering was cached), the rest
+      (a cached trace looked up again) are dropped.  The record stays
+      open for its compile;
+    - a **compile** span labels itself from the cache events since the
+      previous compile span and closes the open record of its ``fun``.
+
+    A program whose records sum under :attr:`SMALL_S` (the eager
+    ``jit(convert_element_type)`` of set-up code) is counted into a
+    running ``small`` record, written as one line before the next
+    listed record and by :meth:`flush`.  At most :attr:`MAX_RECORDS`
+    records are kept, the newest; ``dropped`` on a ``small`` line
+    counts what a late stream no longer finds.
+
+    One building thread is what the fold assumes (top-level spans of
+    one thread cannot overlap); builders on several threads are all
+    counted, and a span may then be attributed to the wrong program.
+    A listener never raises: a fault is logged at debug and the span
+    dropped.
+    """
+
+    MAX_RECORDS = 1024
+    MAX_PENDING = 65536
+    SMALL_S = 0.010
+    TRACE_MIN_S = 0.001
+
+    def __init__(self):
+        self._lock = threading.RLock()
+        #: The newest records, in the order they were made.
+        self.records: collections.deque = collections.deque(
+            maxlen=self.MAX_RECORDS)
+        #: Records ever made; ``made - len(records)`` were dropped.
+        self.made = 0
+        #: Top-level trace spans no lowering has claimed:
+        #: ``(fun, t0, t1, own seconds)``.
+        self._pending: List[tuple] = []
+        #: ``(t0, seconds)`` of the newest booked spans: what a trace
+        #: span that contains them takes off its own seconds.
+        self._booked: collections.deque = collections.deque(maxlen=64)
+        self._open: Optional[Dict[str, Any]] = None
+        self._cache: Dict[str, Any] = {}
+        self._small: Optional[Dict[str, Any]] = None
+
+    # -- the listeners ------------------------------------------------------
+
+    def on_span(self, event: str, t0: float, t1: float, fun_name="",
+                **_kw) -> None:
+        try:
+            with self._lock:
+                if event == _TRACE_EVENT:
+                    # Nine spans in ten are a jnp helper traced inside
+                    # a program (42,000 of them in one serving cell's
+                    # set-up): this branch calls nothing.
+                    pending = self._pending
+                    while pending and pending[-1][1] >= t0:
+                        pending.pop()  # what this span contains
+                    own = t1 - t0
+                    if self._booked and self._booked[-1][0] >= t0:
+                        own = self._own(t0, t1)
+                    # What a lowering traces itself waits here until
+                    # the lowering's own span drops it.
+                    if own >= self.TRACE_MIN_S \
+                            or len(pending) < self.MAX_PENDING:
+                        pending.append((fun_name, t0, t1, own))
+                elif event == _LOWER_EVENT:
+                    self._lower(str(fun_name), t0, t1)
+                elif event == _COMPILE_EVENT:
+                    self._compile(str(fun_name), t0, t1)
+        except Exception as e:
+            _log.debug("build log: dropped %s: %r", event, e)
+
+    def on_event(self, event: str, **_kw) -> None:
+        kind = _CACHE_EVENTS.get(event)
+        if kind is not None:
+            self._cache[kind] = True
+
+    def on_duration(self, event: str, secs: float, **_kw) -> None:
+        if event == _RETRIEVAL_EVENT:
+            self._cache["retrieval_s"] = secs
+
+    # -- the fold -------------------------------------------------------------
+
+    def _drop_contained(self, t0: float) -> None:
+        while self._pending and self._pending[-1][1] >= t0:
+            self._pending.pop()
+
+    def _own(self, t0: float, t1: float) -> float:
+        """The span's seconds less those booked inside it (an eager
+        program built while an outer one is traced or lowered)."""
+        own = t1 - t0
+        for b0, secs in reversed(self._booked):
+            if b0 < t0:
+                break
+            own -= secs
+        return max(own, 0.0)
+
+    def _strays(self) -> None:
+        for fun, t0, t1, own in self._pending:
+            if own >= self.TRACE_MIN_S:
+                self._booked.append((t0, own))
+                self._program([{"fun": str(fun), "phase": "trace",
+                                "wall_s": own, "t0": t0, "t1": t1}])
+        self._pending.clear()
+
+    def _lower(self, fun: str, t0: float, t1: float) -> None:
+        self._close_open()
+        self._drop_contained(t0)  # what the lowering traced itself
+        traced = fun[fun.find("(") + 1:-1] if fun.endswith(")") else fun
+        trace_s = 0.0
+        for i in range(len(self._pending) - 1, -1, -1):
+            if self._pending[i][0] == traced:
+                _, p0, _, trace_s = self._pending.pop(i)
+                self._booked.append((p0, trace_s))
+                break
+        self._strays()
+        own = self._own(t0, t1)
+        self._booked.append((t0, own))
+        self._open = {"fun": fun, "phase": "lower", "wall_s": own,
+                      "trace_s": trace_s, "t0": t0, "t1": t1}
+
+    def _compile(self, fun: str, t0: float, t1: float) -> None:
+        said, self._cache = self._cache, {}
+        if "hit" in said:
+            cache = "hit"
+        elif "miss" in said or (
+                "asked" in said and jax.config.jax_compilation_cache_dir):
+            # asked of a cache that is there, not found, and perhaps
+            # not written (under the cache's own thresholds): a miss
+            cache = "miss"
+        else:
+            cache = "off"
+        rec = {"fun": fun, "phase": "compile", "wall_s": t1 - t0,
+               "cache": cache, "t0": t0, "t1": t1}
+        if "retrieval_s" in said:
+            rec["retrieval_s"] = said["retrieval_s"]
+        self._drop_contained(t0)
+        self._strays()
+        self._booked.append((t0, t1 - t0))
+        if self._open is not None and self._open["fun"] != fun:
+            self._close_open()
+        lowered, self._open = self._open, None
+        self._program([rec] if lowered is None else [lowered, rec])
+
+    def _close_open(self) -> None:
+        lowered, self._open = self._open, None
+        if lowered is not None:
+            self._program([lowered])
+
+    def _program(self, recs: List[Dict[str, Any]]) -> None:
+        """One program's records: listed, or counted into ``small``."""
+        compile_s = sum(r["wall_s"] for r in recs if r["phase"] == "compile")
+        trace_lower_s = sum(r["wall_s"] + r.get("trace_s", 0.0)
+                            for r in recs if r["phase"] != "compile")
+        if trace_lower_s + compile_s >= self.SMALL_S:
+            self._write_small()
+            for r in recs:
+                self._deliver(r)
+            return
+        if self._small is None:
+            self._small = {"phase": "small", "n": 0, "trace_lower_s": 0.0,
+                           "compile_s": 0.0, "misses": 0,
+                           "t0": recs[0]["t0"]}
+        s = self._small
+        s["n"] += 1
+        s["trace_lower_s"] += trace_lower_s
+        s["compile_s"] += compile_s
+        s["misses"] += sum(r.get("cache", "hit") != "hit" for r in recs)
+        s["t1"] = recs[-1]["t1"]
+
+    def _write_small(self) -> None:
+        s, self._small = self._small, None
+        if s is not None:
+            s["wall_s"] = s["trace_lower_s"] + s["compile_s"]
+            if self.made > len(self.records):
+                s["dropped"] = self.made - len(self.records)
+            self._deliver(s)
+
+    def _deliver(self, rec: Dict[str, Any]) -> None:
+        for k, v in rec.items():
+            if isinstance(v, float):
+                rec[k] = round(v, 6)
+        self.records.append(rec)
+        self.made += 1
+        tel = _current
+        if tel is not None:
+            tel._write_builds()
+
+    # -- the read side --------------------------------------------------------
+
+    def flush(self) -> None:
+        """Close what is open: the record waiting for a compile, the
+        pending traces, the running ``small`` line."""
+        try:
+            with self._lock:
+                self._close_open()
+                self._strays()
+                self._write_small()
+        except Exception as e:
+            _log.debug("build log: flush failed: %r", e)
+
+    def since(self, at: int) -> List[Dict[str, Any]]:
+        """The kept records from the ``at``-th ever made on."""
+        with self._lock:
+            new = min(self.made - at, len(self.records))
+            if new <= 0:
+                return []
+            if new == 1:
+                return [self.records[-1]]
+            return list(self.records)[-new:]
+
+
+#: The process's build log.  Registered once, here: a listener costs a
+#: dict store a span and runs only while jax traces, lowers or compiles.
+BUILD_LOG = BuildLog()
+jax.monitoring.register_event_time_span_listener(BUILD_LOG.on_span)
+jax.monitoring.register_event_listener(BUILD_LOG.on_event)
+jax.monitoring.register_event_duration_secs_listener(BUILD_LOG.on_duration)
 
 
 class _NullTelemetry:
@@ -354,6 +614,12 @@ class Telemetry:
             self._touch_heartbeat()
         self.emit("run_start", run_id=self.run_id, pid=os.getpid(),
                   fingerprint=self.fingerprint, **(meta or {}))
+        #: How many of the build log's records (counted from the first
+        #: ever made) this stream has written: what the log holds now
+        #: goes in as the backlog, each later record as it is made.
+        self._builds_at = 0
+        BUILD_LOG.flush()
+        self._write_builds(backlog=True)
         if self._stall_deadline > 0:
             self._watchdog = threading.Thread(
                 target=self._watch, name="ff-telemetry-watchdog", daemon=True
@@ -382,6 +648,16 @@ class Telemetry:
             self._last_label = ev
             if ev == "preempt":
                 self._preempted = True
+
+    def _write_builds(self, backlog: bool = False) -> None:
+        """One ``program_build`` event for every record of the build
+        log this stream has not written, in ``t1`` order; ``backlog``
+        marks those made before the stream opened."""
+        recs = BUILD_LOG.since(self._builds_at)
+        self._builds_at = BUILD_LOG.made
+        mark = {"backlog": True} if backlog else {}
+        for rec in sorted(recs, key=lambda r: r["t1"]):
+            self.emit("program_build", **rec, **mark)
 
     def record_step(self, step, loss=None, wall_s=None, **fields) -> None:
         """One completed training step: a ``step`` event plus the
@@ -471,17 +747,23 @@ class Telemetry:
 
     def program_cost(self, kind: str, fn, args=(), **meta) -> None:
         """One ``program_cost`` event per compiled program at first
-        build: XLA's static flops/bytes estimate from
+        sight: XLA's static flops/bytes estimate from
         ``Lowered.cost_analysis()`` — device-side attribution that
-        exists even without a trace (OBSERVABILITY.md).
+        exists even without a trace (OBSERVABILITY.md) — and
+        ``wall_s``, what the probe itself took.
 
-        ``Lowered`` (not ``Compiled``): probing this jaxlib showed
-        ``lowered.compile()`` performs a genuine SECOND XLA compile
-        (~36 ms, not shared with the jit call's cache) while
-        ``lower()`` after a warm call is ~1 ms and its cost_analysis
-        reports the same flops — the < 2% overhead bar decides.
-        Deduped per (kind, program identity); never raises — cost
-        attribution must not break the program it describes."""
+        ``Lowered`` (not ``Compiled``): ``lowered.compile()`` performs
+        a genuine SECOND XLA compile, not shared with the jit call's
+        cache.  ``lower()`` shares the jit's own trace and lowering
+        caches: called BEFORE the program's first call, as the serving
+        engine does, it IS the program's trace and lowering (0.9–2.7 s a
+        program of ``gpt2m.serve`` on the chip's host, PERF.md §5 (4))
+        and the call that follows reuses both; called after, it is two
+        cache lookups.  Where the backend gives no analysis (the TPU's
+        ``Lowered.cost_analysis()`` is ``None``) the event carries
+        ``wall_s`` and no estimate.  Deduped per (kind, program
+        identity); never raises — cost attribution must not break the
+        program it describes."""
         key = (kind, id(fn))
         if key in self._cost_seen:
             return
@@ -490,18 +772,17 @@ class Telemetry:
             lower = getattr(fn, "lower", None)
             if lower is None:
                 return
+            t0 = time.perf_counter()
             ca = lower(*args).cost_analysis()
+            fields = {"wall_s": round(time.perf_counter() - t0, 6)}
             if isinstance(ca, (list, tuple)):
                 ca = ca[0] if ca else {}
-            if not isinstance(ca, dict):
-                return
-            self.emit(
-                "program_cost", kind=kind,
-                flops=float(ca.get("flops", 0.0)),
-                bytes_accessed=float(ca.get("bytes accessed", 0.0)),
-                transcendentals=float(ca.get("transcendentals", 0.0)),
-                **meta,
-            )
+            if isinstance(ca, dict):
+                fields.update(
+                    flops=float(ca.get("flops", 0.0)),
+                    bytes_accessed=float(ca.get("bytes accessed", 0.0)),
+                    transcendentals=float(ca.get("transcendentals", 0.0)))
+            self.emit("program_cost", kind=kind, **fields, **meta)
         except Exception as e:
             _log.debug("program_cost(%s): cost analysis unavailable: %s",
                        kind, e)
@@ -731,6 +1012,8 @@ class Telemetry:
         }
         if self._trace_summary is not None:
             end_fields["trace_summary"] = self._trace_summary
+        BUILD_LOG.flush()
+        self._write_builds()
         self.emit("run_end", **end_fields)
         with self._lock:
             self._closed = True
@@ -746,6 +1029,7 @@ class Telemetry:
         global _current
         self._prev_current = _current
         _current = self
+        self._write_builds()  # made since it opened, installed nowhere
         return self
 
     def __exit__(self, exc_type=None, exc=None, tb=None) -> None:

@@ -14,6 +14,9 @@ Pins the observability layer's four contracts:
 - **Off-path purity**: telemetry off leaves trainer numerics and the
   stats dict bit-identical (and enabled telemetry adds no fences —
   fences/step is exactly the un-telemetered ``device_get`` count).
+- **Program builds**: every program the process builds leaves
+  ``program_build`` records folded from jax's own trace, lowering and
+  compile spans; a cached call leaves none.
 """
 
 import json
@@ -520,3 +523,382 @@ def test_perfmetrics_extras_and_report():
         "[Metrics] loss=1.000000 accuracy=75.00% (6/8)"
     )
     assert "grad_norm=3.000000" in pm2.report()
+
+
+# -- program builds (OBSERVABILITY.md "Program builds") --------------------
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from flexflow_tpu.runtime.telemetry import BUILD_LOG, BuildLog  # noqa: E402
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture
+def every_build_listed(monkeypatch):
+    """A CPU compile of a few operations may fall under the 10 ms that
+    separate a listed program from the ``small`` line: list them all."""
+    monkeypatch.setattr(BUILD_LOG, "SMALL_S", 0.0)
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """jax's persistent cache on, in a directory of the test's own,
+    writing every program (the suite runs with it off: conftest.py)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = {"jax_enable_compilation_cache": True,
+            "jax_compilation_cache_dir": str(tmp_path / "jax_cache"),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": -1}
+    old = {k: getattr(jax.config, k) for k in keys}
+    for k, v in keys.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def _program(name):
+    """A fresh jitted function called ``name``: nothing of it is in any
+    of jax's caches, and ``jit(name)`` finds its records."""
+    def f(x):
+        return jnp.where(x > 0, jnp.sin(x) @ x, 0.0).sum()
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f)
+
+
+def _builds(path_or_events, fun=None):
+    events = path_or_events if isinstance(path_or_events, list) \
+        else _events(path_or_events)
+    return [e for e in events if e["ev"] == "program_build"
+            and (fun is None or e.get("fun") == fun)]
+
+
+def test_build_before_the_stream_arrives_as_backlog(tmp_path,
+                                                    every_build_listed):
+    f = _program("built_before")
+    f(jnp.ones((8, 8)))
+    with Telemetry(str(tmp_path)) as tel:
+        pass
+    events = _events(tel.path)
+    assert events[0]["ev"] == "run_start" and events[-1]["ev"] == "run_end"
+    lower, comp = _builds(events, "jit(built_before)")
+    assert lower["backlog"] is True and comp["backlog"] is True
+    assert lower["phase"] == "lower" and lower["trace_s"] > 0
+    assert lower["wall_s"] > 0 and "cache" not in lower
+    assert comp["phase"] == "compile" and comp["wall_s"] > 0
+    assert "trace_s" not in comp
+    # the backlog follows run_start, in t1 order, before anything else
+    back = [e for e in events if e.get("backlog")]
+    assert events[1:1 + len(back)] == back
+    assert [e["t1"] for e in back] == sorted(e["t1"] for e in back)
+
+
+def test_build_inside_the_stream_arrives_live(tmp_path, every_build_listed):
+    f = _program("built_inside")
+    with Telemetry(str(tmp_path)) as tel:
+        tel.emit("analysis", clean=True, violations=[])
+        f(jnp.ones((8, 8)))
+    events = _events(tel.path)
+    lower, comp = _builds(events, "jit(built_inside)")
+    assert not lower.get("backlog") and not comp.get("backlog")
+    assert (lower["phase"], comp["phase"]) == ("lower", "compile")
+    mark = next(e["seq"] for e in events if e["ev"] == "analysis")
+    assert mark < lower["seq"] < comp["seq"]
+
+
+def test_cached_call_writes_no_event_and_the_log_does_not_grow(
+        tmp_path, every_build_listed):
+    """The zero-hot-path pin: a listener runs only while jax traces,
+    lowers or compiles."""
+    before, inside = _program("warm_before"), _program("warm_inside")
+    x = jnp.ones((8, 8))
+    before(x)
+    with Telemetry(str(tmp_path)) as tel:
+        inside(x)
+        BUILD_LOG.flush()
+        made, n = BUILD_LOG.made, len(_events(tel.path))
+        for _ in range(3):
+            before(x).block_until_ready()
+            inside(x).block_until_ready()
+        BUILD_LOG.flush()
+        assert BUILD_LOG.made == made
+        assert len(_events(tel.path)) == n
+
+
+def test_nested_jit_leaves_one_top_level_trace(tmp_path,
+                                               every_build_listed):
+    inner = _program("nested_inner")
+
+    def outer(x):
+        return inner(x) + inner(2 * x)
+    outer.__name__ = outer.__qualname__ = "nested_outer"
+    x = jnp.ones((8, 8))
+    with Telemetry(str(tmp_path)) as tel:
+        jax.jit(outer)(x)
+    builds = [b for b in _builds(tel.path) if not b.get("backlog")]
+    assert [b["phase"] for b in builds
+            if b.get("fun") == "jit(nested_outer)"] == ["lower", "compile"]
+    # the inner jit was traced inside the outer's trace span: no record
+    # of its own, and no stray ``trace`` record of any jnp helper
+    assert not [b for b in builds if "nested_inner" in str(b.get("fun"))]
+    assert not [b for b in builds if b["phase"] == "trace"]
+
+
+def test_cache_miss_then_hit_with_retrieval_s(tmp_path, compile_cache,
+                                              every_build_listed):
+    f = _program("cached_twice")
+    x = jnp.ones((8, 8))
+    with Telemetry(str(tmp_path / "tel")) as tel:
+        f(x)
+        jax.clear_caches()
+        f(x)
+    first, second = [b for b in _builds(tel.path, "jit(cached_twice)")
+                     if b["phase"] == "compile"]
+    assert first["cache"] == "miss" and "retrieval_s" not in first
+    assert second["cache"] == "hit" and second["retrieval_s"] > 0
+    assert second["retrieval_s"] <= second["wall_s"]
+
+
+def test_no_cache_directory_reads_off(tmp_path, every_build_listed):
+    assert not jax.config.jax_compilation_cache_dir
+    with Telemetry(str(tmp_path)) as tel:
+        _program("no_cache")(jnp.ones((8, 8)))
+    (comp,) = [b for b in _builds(tel.path, "jit(no_cache)")
+               if b["phase"] == "compile"]
+    assert comp["cache"] == "off" and "retrieval_s" not in comp
+
+
+def _feed(log, fun, t0, trace=0.0, lower=0.0, compile_s=None, events=()):
+    """One program's spans as jax hands them over: each at its end."""
+    t = t0
+    log.on_span(_TRACE, t, t + trace, fun_name=fun)
+    t += trace
+    log.on_span(_LOWER, t, t + lower, fun_name=f"jit({fun})")
+    t += lower
+    if compile_s is not None:
+        for ev in events:
+            log.on_event(f"/jax/compilation_cache/{ev}")
+        log.on_span(_COMPILE, t, t + compile_s, fun_name=f"jit({fun})")
+        t += compile_s
+    return t
+
+
+def test_a_microsecond_eager_primitive_is_in_the_small_line():
+    log = BuildLog()
+    t = _feed(log, "convert_element_type", 100.0, 1e-6, 1e-6, 1e-6,
+              events=("compile_requests_use_cache", "cache_hits"))
+    t = _feed(log, "broadcast_in_dim", t, 1e-3, 2e-3, 3e-3)
+    assert not log.records and log.made == 0  # counted, not listed
+    t = _feed(log, "train_step", t, 0.5, 0.25, 2.0)
+    small, lower, comp = log.records
+    assert small["phase"] == "small" and small["n"] == 2
+    assert small["trace_lower_s"] == pytest.approx(3e-3 + 2e-6)
+    assert small["compile_s"] == pytest.approx(3e-3 + 1e-6)
+    assert small["wall_s"] == pytest.approx(6e-3 + 3e-6)
+    assert small["misses"] == 1  # the hit is not one
+    # from the first program's lowering (its own edge) to the last's end
+    assert (small["t0"], small["t1"]) == (100.000001, 100.006003)
+    assert (lower["fun"], lower["phase"]) == ("jit(train_step)", "lower")
+    assert (lower["trace_s"], lower["wall_s"]) == (0.5, 0.25)
+    assert (comp["phase"], comp["wall_s"], comp["cache"]) == \
+        ("compile", 2.0, "off")
+    _feed(log, "add", t, 1e-6, 1e-6, 1e-6)
+    log.flush()  # the running line is written at close
+    assert log.records[-1]["phase"] == "small" and log.records[-1]["n"] == 1
+    assert log.made == 4
+
+
+def test_the_probe_lowers_and_the_call_compiles_one_program():
+    """``fn.lower()`` before the first call (``program_cost``): the
+    lowering waits for its compile, a cached trace looked up in between
+    is no record, and a second lowering of the same ``fun`` shows as a
+    second ``lower`` record."""
+    log = BuildLog()
+    t = _feed(log, "prefill", 10.0, 3.0, 1.0)          # the probe
+    log.on_span(_TRACE, t, t + 2e-5, fun_name="prefill")  # the call
+    log.on_span(_COMPILE, t + 1e-3, t + 5.0, fun_name="jit(prefill)")
+    assert [(r["phase"], r["wall_s"]) for r in log.records] == \
+        [("lower", 1.0), ("compile", pytest.approx(4.999))]
+    assert log.records[0]["trace_s"] == 3.0
+    t = _feed(log, "prefill", 20.0, 3.0, 1.0)          # lowered again,
+    _feed(log, "superstep", t, 0.5, 0.5, 1.0)          # never compiled
+    assert [(r["fun"], r["phase"]) for r in log.records][2:] == [
+        ("jit(prefill)", "lower"), ("jit(superstep)", "lower"),
+        ("jit(superstep)", "compile")]
+
+
+def test_a_trace_no_lowering_claims_is_a_record_of_its_own():
+    log = BuildLog()
+    log.on_span(_TRACE, 1.0, 1.5, fun_name="init")       # an eval_shape
+    log.on_span(_TRACE, 2.0, 2.00002, fun_name="step")   # a cache lookup
+    _feed(log, "step", 3.0, 0.1, 0.1, 0.1)
+    assert [(r["fun"], r["phase"], r["wall_s"]) for r in log.records] == [
+        ("init", "trace", 0.5), ("jit(step)", "lower", 0.1),
+        ("jit(step)", "compile", 0.1)]
+
+
+def test_a_build_inside_a_trace_is_not_counted_twice():
+    """An eager program built while an outer function is traced lies
+    inside the outer's trace span: the outer's ``trace_s`` is its span
+    less what was booked inside it."""
+    log = BuildLog()
+    _feed(log, "eager", 1.0, 0.1, 0.2, 0.3)              # 1.0 .. 1.6
+    log.on_span(_TRACE, 0.5, 2.0, fun_name="outer")
+    log.on_span(_LOWER, 2.0, 2.5, fun_name="jit(outer)")
+    log.flush()
+    outer = [r for r in log.records if r["fun"] == "jit(outer)"][0]
+    assert outer["trace_s"] == pytest.approx(1.5 - 0.6)
+    total = sum(r["wall_s"] + r.get("trace_s", 0.0) for r in log.records)
+    assert total == pytest.approx(2.0)  # 0.5 .. 2.5, each second once
+
+
+def test_the_log_keeps_the_newest_records_and_counts_the_rest():
+    log = BuildLog()
+    t = 0.0
+    for i in range(BuildLog.MAX_RECORDS // 2 + 3):
+        t = _feed(log, f"f{i}", t, 1.0, 1.0, 1.0)
+    assert len(log.records) == BuildLog.MAX_RECORDS
+    assert log.made - len(log.records) == 6
+    assert log.records[-1]["fun"] == f"jit(f{BuildLog.MAX_RECORDS // 2 + 2})"
+    _feed(log, "add", t, 1e-6, 1e-6, 1e-6)
+    log.flush()
+    assert log.records[-1]["dropped"] == 6  # before the line itself
+    assert [r["phase"] for r in log.since(log.made - 2)] == \
+        ["compile", "small"]
+    assert log.since(log.made) == [] and len(log.since(0)) == len(log.records)
+
+
+def test_a_listener_never_raises(caplog):
+    log = BuildLog()
+    with caplog.at_level(logging.DEBUG, logger="ff.telemetry"):
+        log.on_span(_LOWER, "not a time", None, fun_name=object())
+        log.on_span(_COMPILE, 1.0, 2.0)           # no fun_name at all
+        log.on_span("/some/other/span", 1.0, 2.0, fun_name="x")
+        log.on_event("/some/other/event", a=1)
+        log.on_duration("/some/other/duration", 1.0)
+    assert "dropped" in caplog.text
+    log.flush()
+    assert [r["phase"] for r in log.records] == ["compile"]
+
+
+def test_build_edges_lie_between_the_wall_clock_stamps(tmp_path,
+                                                       every_build_listed):
+    with Telemetry(str(tmp_path)) as tel:
+        before = time.time()
+        _program("edges")(jnp.ones((8, 8)))
+        after = time.time()
+    lower, comp = _builds(tel.path, "jit(edges)")
+    for rec in (lower, comp):
+        assert before <= rec["t0"] <= rec["t1"] <= after
+        assert rec["wall_s"] == pytest.approx(rec["t1"] - rec["t0"], abs=2e-6)
+        assert rec["t1"] <= rec["ts"]  # the line is written at the span's end
+    assert lower["t1"] <= comp["t0"]
+
+
+def test_two_streams_each_get_the_backlog_once(tmp_path,
+                                               every_build_listed):
+    _program("seen_by_both")(jnp.ones((8, 8)))
+    paths = []
+    for d in ("a", "b"):
+        with Telemetry(str(tmp_path / d)) as tel:
+            _program(f"only_{d}")(jnp.ones((8, 8)))
+        paths.append(tel.path)
+    a, b = (_builds(p) for p in paths)
+    for recs in (a, b):
+        both = [r for r in recs if r.get("fun") == "jit(seen_by_both)"]
+        assert [r["phase"] for r in both] == ["lower", "compile"]
+        assert all(r["backlog"] for r in both)
+    assert not _builds(a, "jit(only_b)")
+    live, again = _builds(a, "jit(only_a)"), _builds(b, "jit(only_a)")
+    assert not any(r.get("backlog") for r in live)
+    assert [r["phase"] for r in again] == ["lower", "compile"]
+    assert all(r["backlog"] for r in again)
+    assert [(r["t0"], r["t1"]) for r in live] == \
+        [(r["t0"], r["t1"]) for r in again]
+
+
+def test_a_stream_with_no_file_takes_builds_as_any_event(
+        every_build_listed):
+    with Telemetry(directory=None) as tel:
+        seq = tel._seq
+        _program("no_file")(jnp.ones((8, 8)))
+        assert tel._seq == seq + 2 and tel.path is None
+
+
+def test_program_cost_carries_wall_s(tmp_path, every_build_listed):
+    """The probe before the first call IS the program's lowering: one
+    ``lower`` record, and the call that follows only compiles."""
+    f = _program("probed")
+    x = jnp.ones((8, 8))
+    with Telemetry(str(tmp_path)) as tel:
+        tel.program_cost("train_step", f, (x,))
+        f(x)
+        tel.program_cost("train_step", f, (x,))  # deduped: no second event
+    events = _events(tel.path)
+    (cost,) = [e for e in events if e["ev"] == "program_cost"]
+    assert cost["wall_s"] > 0 and cost["flops"] > 0
+    lower, comp = _builds(events, "jit(probed)")
+    assert (lower["phase"], comp["phase"]) == ("lower", "compile")
+    assert lower["trace_s"] + lower["wall_s"] <= cost["wall_s"]
+
+
+def test_program_cost_without_an_analysis_still_says_what_it_took(tmp_path):
+    """The TPU's ``Lowered.cost_analysis()`` is ``None``."""
+    class NoAnalysis:
+        def lower(self, *args):
+            return self
+
+        def cost_analysis(self):
+            return None
+
+    with Telemetry(str(tmp_path)) as tel:
+        tel.program_cost("prefill", NoAnalysis(), (), bucket=512)
+    (cost,) = [e for e in _events(tel.path) if e["ev"] == "program_cost"]
+    assert cost["wall_s"] >= 0 and cost["bucket"] == 512
+    assert "flops" not in cost
+
+
+def test_obs_report_prints_builds_and_flags_steady_state(
+        tmp_path, capsys, every_build_listed):
+    from flexflow_tpu.obs.__main__ import main as obs_main
+    from flexflow_tpu.obs.reader import RunLog
+
+    x, x4 = jnp.ones((8, 8)), jnp.ones((4, 4))
+
+    with Telemetry(str(tmp_path)) as tel:
+        tel.emit("serve_run", requests=1, capacity=1, k=1)  # the warm-up
+        tel.emit("serving_program", kind="prefill", bucket=64)
+        _program("prefill")(x)
+        tel.emit("serving_program", kind="decode", k=4)
+        _program("superstep")(x)
+        tel.emit("decode_superstep", k=4, active=1, slots=[0], wall_s=0.01)
+        tel.emit("serve_run", requests=1, capacity=1, k=1)  # the window
+        tel.emit("decode_superstep", k=4, active=1, slots=[0], wall_s=0.01)
+        time.sleep(0.002)
+        tel.emit("serving_program", kind="prefill", bucket=128)
+        _program("prefill")(x4)
+    log = RunLog.load(tel.path)
+    assert not log.unknown_events
+    folded = log.program_builds()
+    rows = {(r["fun"], r["shape"]): r for r in folded["rows"]}
+    assert rows[("jit(prefill)", "bucket=64")]["lowered"] == 1
+    assert rows[("jit(prefill)", "bucket=128")]["compiled"] == 1
+    assert rows[("jit(superstep)", "k=4")]["compile_s"] > 0
+    assert rows[("jit(superstep)", "k=4")]["misses"] == 1
+    # only the build after the window's first round is in steady state
+    assert {(b["fun"], b["phase"]) for b in folded["steady"]} == {
+        ("jit(prefill)", "lower"), ("jit(prefill)", "compile")}
+    assert obs_main(["report", tel.path]) == 0
+    out = capsys.readouterr().out
+    assert "program builds" in out
+    assert "jit(superstep) [k=4]" in out and "lowered x1 compiled x1" in out
+    assert out.count("BUILD IN STEADY STATE: jit(prefill)") == 2
